@@ -79,30 +79,6 @@ def _write_probe_cache(tpu: bool):
         pass
 
 
-def cpu_fingerprint() -> str:
-    """Short hash of this host's CPU feature set. The persistent XLA
-    compile cache must not serve code compiled under a different CPU
-    profile (round-3 driver tail: "cached code's CPU features mismatch
-    the host ... could lead to execution errors such as SIGILL")."""
-    import hashlib
-    try:
-        with open("/proc/cpuinfo") as f:
-            flags = [ln for ln in f if ln.startswith("flags")][:1]
-        blob = (flags[0] if flags else "none").encode()
-    except OSError:
-        blob = b"none"
-    return hashlib.sha256(blob).hexdigest()[:10]
-
-
-def compile_cache_dir(platform: str) -> str:
-    """Per-backend persistent compile cache path. TPU executables are
-    host-independent (shared dir); CPU executables are keyed by the host
-    CPU feature fingerprint so they can never SIGILL another host."""
-    if platform == "cpu":
-        return os.path.join(HERE, ".jax_cache", f"cpu-{cpu_fingerprint()}")
-    return os.path.join(HERE, ".jax_cache", platform)
-
-
 def probe_tpu(attempts: int = 3, timeout_s: int = 75,
               retry_sleep_s: int = 10, force: bool = False) -> bool:
     """Probe TPU backend availability in a subprocess (cannot hang us).
@@ -3533,7 +3509,7 @@ CONFIGS = [
 
 #: configs that need a virtual multi-device fleet: run_one_config
 #: requests the CPU device count BEFORE the backend initializes
-#: (sagecal_tpu.compat; a real TPU host uses its visible chips)
+#: (utils.setup_backend; a real TPU host uses its visible chips)
 MULTI_DEVICE_CONFIGS = {"9-fleet-throughput": 2}
 
 
@@ -3723,15 +3699,15 @@ def write_table(results, platform, date=None, stamp=False):
 def run_one_config(name: str):
     """Child-process entry: run ONE config, print its result JSON."""
     import jax
-    if os.environ.get("SAGECAL_BENCH_CPU"):
-        jax.config.update("jax_platforms", "cpu")
-    ndev = MULTI_DEVICE_CONFIGS.get(name)
-    if ndev:
-        # BEFORE the first device use: the virtual-CPU device count
-        # only lands pre-backend-init (a TPU host's real chips are
-        # already visible; the request is a no-op there)
-        from sagecal_tpu import compat
-        compat.set_cpu_device_count(ndev)
+    from sagecal_tpu import utils
+    # BEFORE the first device use: platform, the virtual-CPU device
+    # count (a no-op on a TPU host, whose real chips are already
+    # visible) and the persistent compile cache — each config runs in
+    # a fresh process, so without the cache every run re-pays its
+    # compiles
+    utils.setup_backend(
+        "cpu" if os.environ.get("SAGECAL_BENCH_CPU") else None,
+        MULTI_DEVICE_CONFIGS.get(name))
     dev = jax.devices()[0]
     # platform assertion: a config expected on TPU must never silently
     # produce a CPU number under a TPU label (round-3 weak item 4)
@@ -3741,16 +3717,6 @@ def run_one_config(name: str):
             {"error": f"platform assertion: expected {expect}, "
                       f"got {dev.platform}", "platform": dev.platform}))
         return
-    try:
-        # persistent XLA compilation cache: each config runs in a fresh
-        # process (device-fault isolation), so without this every run
-        # re-pays ~50 s of compiles per config. Keyed per platform (+ CPU
-        # feature fingerprint) — see compile_cache_dir.
-        jax.config.update("jax_compilation_cache_dir",
-                          compile_cache_dir(dev.platform))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
-    except Exception as e:
-        log(f"# compilation cache unavailable: {e}")
     import jax.numpy as jnp
     fn = dict(CONFIGS)[name]
     r = fn(dev, jnp.float32)

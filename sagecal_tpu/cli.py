@@ -12,6 +12,7 @@ from __future__ import annotations
 import argparse
 import sys
 
+from sagecal_tpu import utils
 from sagecal_tpu.config import (BeamMode, RunConfig, SimulationMode,
                                 SolverMode)
 
@@ -145,8 +146,7 @@ def build_parser() -> argparse.ArgumentParser:
     a("--shard-baselines", action="store_true",
       help="shard the baseline row axis of the (single) subband over "
            "all devices (P1 intra-subband parallelism)")
-    # platform overrides (the JAX_PLATFORMS env var is ignored by some
-    # TPU plugins; the config-update route always works)
+    # platform overrides (utils.setup_backend)
     a("--platform", default=None,
       help="force the jax platform, e.g. 'cpu' for a virtual host mesh")
     a("--cpu-devices", type=int, default=0,
@@ -245,13 +245,7 @@ def config_from_args(args) -> RunConfig:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
-    if args.platform or args.cpu_devices:
-        import jax
-        if args.platform:
-            jax.config.update("jax_platforms", args.platform)
-        if args.cpu_devices:
-            from sagecal_tpu.compat import set_cpu_device_count
-            set_cpu_device_count(args.cpu_devices)
+    utils.setup_backend(args.platform, args.cpu_devices)
     cfg = config_from_args(args)
     if (not cfg.ms and not cfg.ms_list) or not cfg.sky_model \
             or not cfg.cluster_file:

@@ -501,7 +501,7 @@ def make_admm_runner(dsky, sta1, sta2, cidx, cmask, n_stations: int,
         JF, YF, Z, rhoF = carry[0], carry[1], carry[2], carry[3]
         return JF, Z, rhoF, res0, res1, r1s, duals, Y0F
 
-    from sagecal_tpu.compat import shard_map
+    from jax import shard_map
     spec_f = P(axis)
     spec_r = P()
     nin = 8 + (1 if dobeam else 0)     # beam pytree rides a prefix spec
@@ -783,7 +783,7 @@ def make_admm_runner_2d(dsky, sta1, sta2, cidx, cmask, n_stations: int,
         outs = tuple(o[None] for o in outs)     # leading local-time 1
         return (Jnext[:, None],) + outs
 
-    from sagecal_tpu.compat import shard_map
+    from jax import shard_map
     Pft = P("freq", "time")
     Pf = P("freq")
     # outputs stack a leading local-time axis: [Tl, ...]

@@ -94,6 +94,7 @@ def pallas_cost(jfn, args, kwargs=None) -> dict:
     HLO, which cost_analysis already prices — adding the estimate there
     would double-count (so CPU-banked rounds stay consistent)."""
     import jax
+    from jax.extend.core import ClosedJaxpr
     out = zero_cost()
 
     def walk(jx):
@@ -119,15 +120,14 @@ def pallas_cost(jfn, args, kwargs=None) -> dict:
                 # 'branches') — missing the tuple case would silently
                 # drop any kernel sitting under a solver-mode cond
                 for sub in (v if isinstance(v, (tuple, list)) else (v,)):
-                    if isinstance(sub, jax.core.ClosedJaxpr):
+                    if isinstance(sub, ClosedJaxpr):
                         walk(sub.jaxpr)
                     elif hasattr(sub, "eqns"):
                         walk(sub)
 
-    try:
-        walk(jax.make_jaxpr(jfn)(*args, **(kwargs or {})).jaxpr)
-    except Exception:           # pricing must never break a bench run
-        pass
+    # a pricing walk that breaks raises: a silent zero here would price
+    # every compiled Pallas call on the chip at nothing
+    walk(jax.make_jaxpr(jfn)(*args, **(kwargs or {})).jaxpr)
     return out
 
 

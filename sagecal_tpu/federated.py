@@ -67,7 +67,7 @@ def make_fed_outer(rn0, cfg: RunConfig, mesh, nslaves: int, alpha,
     r1h, feda) — feda is the federated dual residual
     sum_s ||Z_s - Zavg||^2 over real slaves (stochastic master :329-351).
     """
-    from sagecal_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     axis = "slave"

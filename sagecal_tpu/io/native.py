@@ -1,10 +1,14 @@
 """ctypes bridge to the native tile packer (src/native/tile_pack.cc).
 
-The shared library is compiled on demand with g++ (cached beside the
-source; rebuilt when the source is newer) and loaded via ctypes — no
-pybind11 needed. :func:`pack_tile` dispatches to the native kernel when
-available and otherwise to :func:`pack_tile_py`, a numpy implementation
-with identical semantics (the parity test compares them element-wise).
+The shared library is compiled on demand with g++ and loaded via ctypes
+— no pybind11 needed. It is cached beside the source under a name that
+carries the source's content hash, so what is loaded was always built
+from ``tile_pack.cc`` as it stands (a stale library left by another
+tree has another name and is never picked up). :func:`pack_tile`
+dispatches to the native kernel when available and otherwise to
+:func:`pack_tile_py`, a numpy implementation with identical semantics
+(the parity test compares them element-wise); :func:`packer_name` says
+which one a run uses.
 
 Reference: src/MS/data.cpp:522-664 (loadData hot loop).
 """
@@ -12,6 +16,7 @@ Reference: src/MS/data.cpp:522-664 (loadData hot loop).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import warnings
@@ -23,7 +28,6 @@ C_M_S = 299792458.0
 _SRC = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "src", "native",
     "tile_pack.cc")
-_LIB_PATH = os.path.join(os.path.dirname(_SRC), "libsagecal_io.so")
 _lib = None
 _lib_tried = False
 
@@ -31,14 +35,19 @@ _lib_tried = False
 def _build_lib() -> str | None:
     if not os.path.exists(_SRC):
         return None
-    if (os.path.exists(_LIB_PATH)
-            and os.path.getmtime(_LIB_PATH) >= os.path.getmtime(_SRC)):
-        return _LIB_PATH
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:12]
+    path = os.path.join(os.path.dirname(_SRC),
+                        f"libsagecal_io-{digest}.so")
+    if os.path.exists(path):
+        return path
+    tmp = f"{path}.{os.getpid()}.tmp"   # concurrent builders: atomic land
     try:
         subprocess.run(
-            ["g++", "-O3", "-shared", "-fPIC", "-o", _LIB_PATH, _SRC],
+            ["g++", "-O3", "-shared", "-fPIC", "-o", tmp, _SRC],
             check=True, capture_output=True, timeout=120)
-        return _LIB_PATH
+        os.replace(tmp, path)
+        return path
     except (OSError, subprocess.SubprocessError) as e:
         warnings.warn(f"native tile packer build failed ({e}); "
                       "using the Python fallback")
@@ -74,6 +83,12 @@ def get_lib():
     ]
     _lib = lib
     return _lib
+
+
+def packer_name() -> str:
+    """Which packer :func:`pack_tile` dispatches to in this process."""
+    return "native (src/native/tile_pack.cc)" if get_lib() is not None \
+        else "numpy (pack_tile_py)"
 
 
 def pack_tile_py(vis, cflags, u_m, v_m, nrow_total: int,

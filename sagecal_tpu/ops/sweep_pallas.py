@@ -102,6 +102,24 @@ def interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def check_kernel(kernel: str) -> None:
+    """Refuse ``--kernel pallas`` on the TPU backend BEFORE any solve
+    (called where SageConfig.kernel is filled: pipeline.py, cli_mpi.py).
+
+    The fused sweep (:func:`sweep_blocks`) lowers for Mosaic but its
+    TPU compile does not return (PERF.md "Bring-up on v5e": no answer
+    within 10 minutes for v5e even at N=8, T=4, K=1), so a run that
+    reached it would hang on its first cluster solve. The interpreter
+    path on CPU is unaffected."""
+    if kernel == "pallas" and not interpret_default():
+        raise ValueError(
+            "--kernel pallas is not available on the TPU backend: the "
+            "fused sweep kernel (ops/sweep_pallas.sweep_blocks) does "
+            "not compile under Mosaic yet (the compile never returns); "
+            "use the default --kernel xla, or --platform cpu for the "
+            "interpreter path")
+
+
 class GNBlocks(NamedTuple):
     """Per-(chunk, baseline) Gram blocks of the Gauss-Newton operator
     at the current point — the ``kernel='pallas'`` analogue of

@@ -88,12 +88,8 @@ def main(argv=None) -> int:
     if args.faults:
         from sagecal_tpu import faults
         faults.enable_spec(args.faults)
-    if args.platform:
-        import jax
-        jax.config.update("jax_platforms", args.platform)
-    if args.cpu_devices:
-        from sagecal_tpu import compat
-        compat.set_cpu_device_count(args.cpu_devices)
+    from sagecal_tpu import utils
+    utils.setup_backend(args.platform, args.cpu_devices)
     if args.diag:
         from sagecal_tpu.diag import trace as dtrace
         dtrace.enable(args.diag, entry="sagecal-serve",

@@ -357,9 +357,8 @@ def main(argv=None) -> int:
     p.add_argument("--platform", default=None,
                    help="force the jax platform for dataset synthesis")
     args = p.parse_args(argv)
-    if args.platform:
-        import jax
-        jax.config.update("jax_platforms", args.platform)
+    from sagecal_tpu import utils
+    utils.setup_backend(args.platform)
     import tempfile
     workdir = args.workdir or tempfile.mkdtemp(prefix="sagecal_loadgen_")
     spec = load_spec(args.spec)

@@ -19,13 +19,7 @@ if "xla_force_host_platform_device_count" not in flags:
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    # jax >= 0.5 spells the device-count override as a config option; on
-    # older versions the XLA_FLAGS route above (set before the jax
-    # import) is the only — and sufficient — mechanism
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:
-    pass
+jax.config.update("jax_num_cpu_devices", 8)
 jax.config.update("jax_enable_x64", True)
 
 assert jax.devices()[0].platform == "cpu"

@@ -9,14 +9,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-# jaxlib 0.4.x hard-aborts (C++ fatal, no exception — it kills the
-# whole pytest process) inside backend_compile on the -X spatial-reg
-# consensus program; the same program compiles and passes on current
-# jaxlib. Gate on version so one environment bug cannot zero the rest
-# of the suite's results.
-_JAXLIB_TOO_OLD = tuple(
-    int(x) for x in jax.__version__.split(".")[:2]) < (0, 5)
-
 from sagecal_tpu import skymodel
 from sagecal_tpu.consensus import mdl as mdlmod
 from sagecal_tpu.consensus import poly as cpoly
@@ -205,21 +197,11 @@ def test_federated_stochastic(tmp_path):
 
 
 def test_admm_spatialreg_runs(tmp_path):
-    # Previously version-skipped wholesale on jaxlib 0.4.x. The abort
-    # is now pinned down (ISSUE 14 satellite): XLA's SPMD partitioner
-    # hard-aborts (C++ fatal, no exception) with
-    #   array.h:511] Check failed: new_num_elements == num_elements()
-    #   (1 vs. 0)
-    # while compiling the MULTI-DEVICE -X consensus program — the same
-    # program compiles and passes on ONE device, and on current
-    # jaxlib on any mesh. So on old jaxlib the test runs the full -X
-    # path on a single-device mesh (--mesh-devices 1) instead of
-    # skipping: every spatial-reg claim below (FISTA solve, Z
-    # coupling, spatial_ solution-file format) is still exercised.
+    # the full -X path over the multi-device mesh: FISTA solve, Z
+    # coupling, spatial_ solution-file format
     from sagecal_tpu import cli_mpi
     paths, sky = _make_subband_datasets(tmp_path)
     solfile = tmp_path / "zsol.txt"
-    mesh_cap = ["--mesh-devices", "1"] if _JAXLIB_TOO_OLD else []
     rc = cli_mpi.main([
         "-f", str(tmp_path / "band*.ms"),
         "-s", str(tmp_path / "sky.txt"),
@@ -227,7 +209,7 @@ def test_admm_spatialreg_runs(tmp_path):
         "-p", str(solfile),
         "-A", "4", "-P", "2", "-r", "1.0", "-j", "2", "-e", "2",
         "-g", "4", "-l", "4", "--mdl",
-        "-u", "0.1", "-X", "0.01,0.001,2,20,2"] + mesh_cap)
+        "-u", "0.1", "-X", "0.01,0.001,2,20,2"])
     assert rc == 0
     # spatial model file ("spatial_"+solfile, master :472). The row
     # layout DEVIATES from the reference on purpose (MIGRATION.md

@@ -1,8 +1,7 @@
 """2-D (freq x time) mesh consensus + bounded-staleness tests (ISSUE 14).
 
 Coverage map:
-- compat.shard_map accepts multi-axis meshes on this jax (0.4.x) — the
-  satellite's "no shape failure deep in tracing" contract;
+- jax.shard_map over a multi-axis mesh reduces one named axis only;
 - pad_time / divergence_reset padding+seam primitives;
 - make_admm_runner_2d: wavefront host-loop == fully traced scan, and
   the time-shard-0 prefix reproduces the sequential warm-start chain
@@ -41,10 +40,10 @@ from sagecal_tpu.solvers import lm as lm_mod, sage
 # primitives
 # ---------------------------------------------------------------------------
 
-def test_compat_shard_map_multi_axis():
-    """The compat shim must accept a 2-D ('freq', 'time') mesh on this
-    jax — psum over ONE named axis reduces only that axis's groups."""
-    from sagecal_tpu.compat import shard_map
+def test_shard_map_multi_axis():
+    """On a 2-D ('freq', 'time') mesh, psum over ONE named axis reduces
+    only that axis's groups."""
+    from jax import shard_map
     mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2),
                 ("freq", "time"))
 

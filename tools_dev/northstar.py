@@ -147,8 +147,8 @@ def b_scaling(args):
     is honored as the default when --kernel is not given (bench.py
     parity)."""
     import jax
-    if args.cpu:
-        jax.config.update("jax_platforms", "cpu")
+    from sagecal_tpu import utils
+    utils.setup_backend("cpu" if args.cpu else None)
     import jax.numpy as jnp
     from sagecal_tpu.io import dataset as ds
     from sagecal_tpu.rime import predict as rp
@@ -377,22 +377,11 @@ def multichip(args):
     (c) per-subband residuals, which must still FALL under the
     matrix-free inner solver (--inner cg) for the record to count
     (VERDICT weak-multichip follow-up)."""
-    import os as _os
-    _os.environ["JAX_PLATFORMS"] = "cpu"
-    flags = _os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        _os.environ["XLA_FLAGS"] = (
-            flags + f" --xla_force_host_platform_device_count="
-            f"{args.devices}").strip()
     import jax
-    try:
-        jax.config.update("jax_platforms", "cpu")
-        jax.config.update("jax_num_cpu_devices", args.devices)
-    except Exception:
-        pass
+    from sagecal_tpu import utils
+    utils.setup_backend("cpu", args.devices)
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    from sagecal_tpu import utils
     from sagecal_tpu.consensus import admm as cadmm
     from sagecal_tpu.consensus import poly as cpoly
     from sagecal_tpu.io import dataset as ds
@@ -571,26 +560,13 @@ def mesh2d(args):
     compute scaling — the compute verdict awaits a TPU window (the
     full 64x100x32 defaults are wired for it; the CPU-banked shape is
     stated in the record, MULTICHIP r06 precedent)."""
-    import os as _os
     ndev = args.devices_f * args.devices_t
-    _os.environ["JAX_PLATFORMS"] = "cpu"
-    flags = _os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        _os.environ["XLA_FLAGS"] = (
-            flags + f" --xla_force_host_platform_device_count="
-            f"{ndev}").strip()
     import bench as _bench
-    _os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                           _bench.compile_cache_dir("cpu"))
     import jax
-    try:
-        jax.config.update("jax_platforms", "cpu")
-        jax.config.update("jax_num_cpu_devices", ndev)
-    except Exception:
-        pass
+    from sagecal_tpu import faults, utils
+    utils.setup_backend("cpu", ndev)
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    from sagecal_tpu import faults, utils
     from sagecal_tpu.consensus import admm as cadmm
     from sagecal_tpu.consensus import poly as cpoly
     from sagecal_tpu.io import dataset as ds
@@ -1085,6 +1061,10 @@ def main():
     if args.mesh2d:
         return mesh2d(args)
 
+    # this process only synthesizes data: it stays on the CPU platform,
+    # because the chip belongs to the one cli_mpi child started below
+    from sagecal_tpu import utils
+    utils.setup_backend("cpu")
     workdir = args.keep or tempfile.mkdtemp(prefix="northstar_")
     os.makedirs(workdir, exist_ok=True)
     if os.path.exists(os.path.join(workdir, "mslist.txt")):
@@ -1105,21 +1085,15 @@ def main():
            "--block-f", str(args.block_f),
            "--inflight", str(args.inflight),
            "--inner", args.inner, "--kernel", args.kernel]
-    env = dict(os.environ)
-    # persistent XLA compilation cache: re-runs (and the second tile's
-    # programs) skip the big solve compiles. Keyed per platform (+ CPU
-    # feature fingerprint) so code compiled under another host's CPU
-    # profile is never loaded here (bench.compile_cache_dir).
-    sys.path.insert(0, HERE)
-    import bench
-    env.setdefault("JAX_COMPILATION_CACHE_DIR",
-                   bench.compile_cache_dir("cpu" if args.cpu else "tpu"))
+    # the child picks its own backend and persistent compile cache
+    # (utils.setup_backend): re-runs, and the second tile's programs,
+    # skip the big solve compiles
     if args.cpu:
         cmd += ["--platform", "cpu", "--cpu-devices", "1"]
     print("running:", " ".join(cmd), flush=True)
     t0 = time.time()
     proc = subprocess.Popen(cmd, stdout=subprocess.PIPE,
-                            stderr=subprocess.STDOUT, text=True, env=env)
+                            stderr=subprocess.STDOUT, text=True)
     per_tile_iters = []
     residuals = []          # (initial, final) mean residual per tile —
     # the G=1 vs --inflight parity evidence (VERDICT r5 item 2)

@@ -536,18 +536,16 @@ def _main_consensus(args, dtrace) -> int:
     tslot_rows = jnp.asarray(t0.tslot)
 
     def residual_fn(J_r8, x_r, u, v, w, freq, *beam_rest):
-        J = nesolver.jones_r2c(J_r8)
-        x = utils.r2c(x_r)
-        res = rr.calculate_residuals_multifreq(
-            dsky, J, x, u, v, w, freq[None], meta0["fdelta"],
-            jnp.asarray(t0.sta1), jnp.asarray(t0.sta2), jnp.asarray(cidx),
-            jnp.asarray(sky.subtract_mask()), correct_idx=correct_idx,
+        # storage-dtype writeback emission (out_dtype): the d->h
+        # readback ships sdt bytes; identity at "f32"
+        return rr.calculate_residuals_pairs(
+            dsky, nesolver.jones_r2c(J_r8), x_r, u, v, w, freq[None],
+            meta0["fdelta"], jnp.asarray(t0.sta1), jnp.asarray(t0.sta2),
+            jnp.asarray(cidx), jnp.asarray(sky.subtract_mask()),
+            out_dtype=sdt, correct_idx=correct_idx,
             rho=args.mmse_rho, phase_only=bool(args.phase_only),
             beam=beam_rest[0] if beam_rest else None, dobeam=dobeam,
             tslot=tslot_rows)
-        # storage-dtype writeback emission (rr.residual_writeback):
-        # the d->h readback ships sdt bytes; identity at "f32"
-        return rr.residual_writeback(res, sdt)
 
     # jaxlint: disable=retrace -- one-shot per-process CLI driver; the
     # wrapper is constructed exactly once per run

@@ -65,6 +65,16 @@ def correct_by_cluster(res, J_m, sta1, sta2, chunk_idx_m, rho,
                       jnp.conj(jnp.swapaxes(Gq, -1, -2)))
 
 
+def _model_multifreq(sky, J, u, v, w, freqs, fdelta_chan, sta1, sta2,
+                     chunk_idx, subtract_mask, beam, dobeam, tslot):
+    """sum_m J_p C_m(f) J_q^H over subtractable clusters: [B, F, 2, 2]."""
+    coh = rp.coherencies(sky, u, v, w, freqs, fdelta_chan,
+                         per_channel_flux=True, beam=beam, dobeam=dobeam,
+                         tslot=tslot, sta1=sta1, sta2=sta2)
+    return rp.predict_model(coh, J, sta1, sta2, chunk_idx,
+                            cluster_mask=subtract_mask)
+
+
 def calculate_residuals_multifreq(sky: rp.SkyArrays, J, x, u, v, w, freqs,
                                   fdelta_chan, sta1, sta2, chunk_idx,
                                   subtract_mask, correct_idx: int | None = None,
@@ -81,17 +91,48 @@ def calculate_residuals_multifreq(sky: rp.SkyArrays, J, x, u, v, w, freqs,
     With ``beam``/``dobeam`` this is calculate_residuals_multifreq_withbeam
     (predict_withbeam.c:1895). Returns [B, F, 2, 2] residuals.
     """
-    coh = rp.coherencies(sky, u, v, w, freqs, fdelta_chan,
-                         per_channel_flux=True, beam=beam, dobeam=dobeam,
-                         tslot=tslot, sta1=sta1, sta2=sta2)
-    model = rp.predict_model(coh, J, sta1, sta2, chunk_idx,
-                             cluster_mask=subtract_mask)
-    res = x - model
+    res = x - _model_multifreq(sky, J, u, v, w, freqs, fdelta_chan, sta1,
+                               sta2, chunk_idx, subtract_mask, beam,
+                               dobeam, tslot)
     if correct_idx is not None:
         res = correct_by_cluster(res, J[correct_idx], sta1, sta2,
                                  chunk_idx[correct_idx], rho,
                                  phase_only=phase_only)
     return res
+
+
+def calculate_residuals_pairs(sky: rp.SkyArrays, J, x_r, u, v, w, freqs,
+                              fdelta_chan, sta1, sta2, chunk_idx,
+                              subtract_mask, out_dtype=None,
+                              correct_idx: int | None = None,
+                              rho: float = 1e-9,
+                              beam=None, dobeam: int = 0, tslot=None,
+                              phase_only: bool = False):
+    """:func:`calculate_residuals_multifreq` in the form the jit
+    boundaries use: visibilities in and residuals out as stacked real
+    pairs [B, F, 2, 2, 2] (``x_r`` in the storage dtype, the result
+    through the :func:`residual_writeback` ``out_dtype`` emission).
+
+    Without a correction the subtraction runs on the real pairs
+    themselves — bit-identical to the complex one, which subtracts the
+    two parts separately too. Forming a complex ``x`` from slices of
+    the minor axis only to restack ``res.real``/``res.imag`` on that
+    same axis is rewritten by XLA:TPU into an unaligned in-place update
+    of ``x_r``, and its fusion emitter then aborts the process (libtpu
+    0.0.34, ``Check failed: fusion_util::IsFusibleUnalignedDUS``; found
+    on the v5e, PERF.md "Bring-up on v5e")."""
+    from sagecal_tpu import dtypes as dtp
+    if correct_idx is not None:
+        return residual_writeback(calculate_residuals_multifreq(
+            sky, J, x_r[..., 0] + 1j * x_r[..., 1], u, v, w, freqs,
+            fdelta_chan, sta1, sta2, chunk_idx, subtract_mask,
+            correct_idx=correct_idx, rho=rho, beam=beam, dobeam=dobeam,
+            tslot=tslot, phase_only=phase_only), out_dtype)
+    model = _model_multifreq(sky, J, u, v, w, freqs, fdelta_chan, sta1,
+                             sta2, chunk_idx, subtract_mask, beam, dobeam,
+                             tslot)
+    out = dtp.acc(x_r) - jnp.stack([model.real, model.imag], axis=-1)
+    return out if out_dtype is None else dtp.to_storage(out, out_dtype)
 
 
 def calculate_residuals_interp(sky: rp.SkyArrays, J_old, J_new, x, u, v, w,

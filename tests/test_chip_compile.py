@@ -116,3 +116,43 @@ def test_cluster_update_fits(one_chip):
     need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert 0 < need < HBM_BYTES, mem
+
+
+def test_residual_program_compiles(one_chip):
+    """The per-tile residual program (pipeline._residuals /
+    cli_mpi residual_fn: real pairs in and out, donated input). Its
+    complex-subtract-then-restack form aborted the TPU compiler on the
+    v5e (rime/residual.calculate_residuals_pairs says why); a CHECK
+    failure there kills this worker, which is the test failing."""
+    from sagecal_tpu import skymodel
+    from sagecal_tpu.rime import predict as rp, residual as rr
+    from sagecal_tpu.solvers import normal_eq as ne
+    rng = np.random.default_rng(0)
+    srcs, clusters = {}, []
+    for m in range(M):
+        names = []
+        for s in range(3):
+            nm = f"P{m}_{s}"
+            ll, mm = rng.normal(0, 0.03, 2)
+            srcs[nm] = skymodel.Source(
+                name=nm, ra=0, dec=0, ll=ll, mm=mm,
+                nn=np.sqrt(1 - ll * ll - mm * mm) - 1, sI=1.0, sQ=0.0,
+                sU=0.0, sV=0.0, sI0=1.0, sQ0=0, sU0=0, sV0=0,
+                spec_idx=-0.7, spec_idx1=0.0, spec_idx2=0.0, f0=150e6)
+            names.append(nm)
+        clusters.append((m, 1, names))
+    sky = skymodel.build_cluster_sky(srcs, clusters)
+    dsky = rp.sky_to_device(sky, jnp.float32)
+    sd = _spec(one_chip)
+    f32, i32 = jnp.float32, jnp.int32
+
+    def residuals(J_r8, x_r, u, v, w, sta1, sta2, cidx):
+        return rr.calculate_residuals_pairs(
+            dsky, ne.jones_r2c(J_r8), x_r, u, v, w,
+            jnp.asarray([150e6], f32), 0.18e6, sta1, sta2, cidx,
+            jnp.ones((M,), bool), out_dtype=f32)
+
+    jax.jit(residuals, donate_argnums=(1,)).lower(
+        sd((M, 1, N, 8), f32), sd((B, 1, 2, 2, 2), f32), sd((B,), f32),
+        sd((B,), f32), sd((B,), f32), sd((B,), i32), sd((B,), i32),
+        sd((M, B), i32)).compile()

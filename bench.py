@@ -3749,7 +3749,7 @@ def main():
     # config): every fresh result is compared as it lands and the
     # violations ride the stamped record — the post-run half of the
     # obs/sentinel.py contract (CI runs the --fast half). Importing it
-    # initialises no JAX backend (tests/test_bench_driver.py).
+    # initialises no JAX backend, so this process stays off the chip.
     from sagecal_tpu.obs import sentinel as _sentinel
     sent_bank = {p: _sentinel.newest_bank_results(p)
                  for p in ("cpu", "tpu")}

@@ -16,8 +16,9 @@ process), at a LOFAR-sized problem: 62 stations (1891 baselines),
               one device, 3 ADMM iterations, the default traced plan
 
 ``--chips 4`` runs ONLY the mesh comparison: cli_mpi over a four-device
-('freq',) mesh with 8 subbands, and the same data with
-``--mesh-devices 1``.
+('freq',) mesh with 8 subbands, and the same data on one device
+(``--mesh-devices 1 --block-f 2``: the traced plan does not fit one
+chip's memory at 8 subbands).
 
 This process never imports jax. Any phase failing, or any child not on
 ``tpu``, ends the script non-zero without a result line. The last line
@@ -30,7 +31,6 @@ CPU and never prints an ``ok`` line.
 """
 
 import argparse
-import glob
 import json
 import os
 import re
@@ -412,8 +412,9 @@ def run_consensus(S, name, n_sub, n_tiles, extra=()):
     shards = re.search(r"^Shard devices: (.*)$", out, re.M)
     say(f"[{name}] platform {dev[0]} ({dev[1]}, {dev[2]} device(s)); "
         f"wall {wall:.1f} s, first interval after "
-        f"{first_tile_s(recs):.1f} s, longest single solve execution "
-        f"{longest_exec_s(recs):.2f} s; shard devices: "
+        f"{first_tile_s(recs):.1f} s, longest solve phase (ONE device "
+        f"execution under the traced plan) {longest_exec_s(recs):.2f} s; "
+        f"shard devices: "
         f"{shards.group(1) if shards else '?'}")
     for ln in re.findall(r"^Timeslot:.*$", out, re.M):
         say(f"[{name}] {ln}")
@@ -443,8 +444,15 @@ def phase_mesh4(S):
     if dev[2] != 4 or len(set(shards)) != 4:
         raise Failed(f"mesh4: expected shards on four distinct devices, "
                      f"got {dev[2]} visible and shards on {shards}")
-    _, res_1, z_1, _ = run_consensus(S, "mesh1", n_sub, n_tiles,
-                                     extra4 + ["--mesh-devices", "1"])
+    # one device cannot hold the traced plan at 8 subbands: compiled for
+    # a described v5e it needs 25.6 GB of temporaries against 16 GB of
+    # HBM (PERF.md "Bring-up on v5e"), so the one-device run takes the
+    # same mathematics in blocks of 2 subbands per execution
+    say("[mesh1] --mesh-devices 1 with --block-f 2: the traced plan "
+        "needs 25.6 GB for 8 subbands on one 16 GB device")
+    _, res_1, z_1, _ = run_consensus(
+        S, "mesh1", n_sub, n_tiles,
+        extra4 + ["--mesh-devices", "1", "--block-f", "2"])
     d_res = float(np.max(np.abs(res_m / res_1 - 1.0)))
     Zm, Z1 = read_z(z_m), read_z(z_1)
     if Zm.shape != Z1.shape:

@@ -15,7 +15,9 @@ from __future__ import annotations
 
 _STATE = {"installed": False, "count": 0}
 
-# one event per compile request across jax versions >= 0.4.x; keep as a
+# one event per compile request that goes through the persistent
+# compilation cache (jax 0.9.0 fires it only when the cache is in use,
+# utils.setup_backend turns it on); keep as a
 # tuple so a rename can be tracked by adding the new name
 _COMPILE_EVENTS = ("/jax/compilation_cache/compile_requests_use_cache",)
 

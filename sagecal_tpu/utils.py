@@ -54,7 +54,12 @@ def setup_backend(platform: str | None = None,
       host). Never a temporary name, a pid or a time;
     - programs that took >= 0.5 s to compile are kept (every solver
       program; JAX's default of 1 s would drop the staging programs a
-      cold chip run recompiles by the dozen).
+      cold chip run recompiles by the dozen);
+    - f32 contractions multiply in f32 (``jax_default_matmul_precision
+      = highest``). The TPU's default is ONE bf16 pass, which left the
+      f32 pipeline's final residuals at twice the CPU's on the same
+      data (PERF.md "Bring-up on v5e"); the CPU backend computes f32
+      either way.
     """
     import jax
     if platform:
@@ -69,6 +74,7 @@ def setup_backend(platform: str | None = None,
             cache = os.path.join(cache, f"cpu-{cpu_fingerprint()}")
         jax.config.update("jax_compilation_cache_dir", cache)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    jax.config.update("jax_default_matmul_precision", "highest")
     return cache
 
 

@@ -374,11 +374,7 @@ def _main_consensus(args, dtrace) -> int:
     _fleet.note_mesh(mesh)
     is_writer = args.process_id == 0   # mpirun-analogue output ownership
     if is_writer:
-        from sagecal_tpu.io import native
-        print(f"Platform: {jax.devices()[0].platform} "
-              f"({ndev_avail} device(s), "
-              f"{jax.devices()[0].device_kind}); tile packer: "
-              f"{native.packer_name()}")
+        print(utils.platform_line(ndev_avail))
         print(f"Subbands: {nf} over {ndev} device(s)"
               + (f" (padded to {fpad})" if fpad != nf else "")
               + f"; stations {n}, clusters {sky.n_clusters} "

@@ -115,10 +115,7 @@ class FullBatchPipeline:
         self.sky = sky
         self.log = log
         platform = _device_platform()
-        from sagecal_tpu.io import native
-        log(f"Platform: {platform} ({len(jax.devices())} device(s), "
-            f"{jax.devices()[0].device_kind}); tile packer: "
-            f"{native.packer_name()}")
+        log(utils.platform_line())
         if real_dtype is None:
             real_dtype = jnp.float64 if (
                 platform == "cpu" and jax.config.read("jax_enable_x64")
@@ -202,16 +199,16 @@ class FullBatchPipeline:
             and self.rdt == jnp.float32
             and coh_pallas.any_supported(sky))
         self._pallas_skies = None
+        coh_path = "xla"
         if self.use_pallas:
             sky_pg, sky_rest = skymodel.split_for_pallas(sky)
             self._pallas_skies = (
                 rp.sky_to_device(sky_pg, self.rdt),
                 None if sky_rest is None
                 else rp.sky_to_device(sky_rest, self.rdt))
-        log("Coherency path: "
-            + ("xla" if not self.use_pallas
-               else "pallas" if sky_rest is None
-               else "pallas (hybrid: shapelet/disk/ring via XLA)"))
+            coh_path = "pallas" if sky_rest is None else \
+                "pallas (hybrid: shapelet/disk/ring via XLA)"
+        log(f"Coherency path: {coh_path}")
         from sagecal_tpu.ops import sweep_pallas
         sweep_pallas.check_kernel(getattr(cfg, "solver_kernel", "xla"))
         mode = effective_solver_mode(int(cfg.solver_mode), self.n)

@@ -121,10 +121,10 @@ def calculate_residuals_pairs(sky: rp.SkyArrays, J, x_r, u, v, w, freqs,
     of ``x_r``, and its fusion emitter then aborts the process (libtpu
     0.0.34, ``Check failed: fusion_util::IsFusibleUnalignedDUS``; found
     on the v5e, PERF.md "Bring-up on v5e")."""
-    from sagecal_tpu import dtypes as dtp
+    from sagecal_tpu import dtypes as dtp, utils
     if correct_idx is not None:
         return residual_writeback(calculate_residuals_multifreq(
-            sky, J, x_r[..., 0] + 1j * x_r[..., 1], u, v, w, freqs,
+            sky, J, utils.r2c(x_r), u, v, w, freqs,
             fdelta_chan, sta1, sta2, chunk_idx, subtract_mask,
             correct_idx=correct_idx, rho=rho, beam=beam, dobeam=dobeam,
             tslot=tslot, phase_only=phase_only), out_dtype)

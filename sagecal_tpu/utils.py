@@ -78,6 +78,18 @@ def setup_backend(platform: str | None = None,
     return cache
 
 
+def platform_line(n_devices: int | None = None) -> str:
+    """The line every entry point prints once the backend is up: the
+    platform the run is on, its device count and kind, and which tile
+    packer is loaded (chip_smoke.py reads the device from it)."""
+    import jax
+    from sagecal_tpu.io import native
+    dev = jax.devices()
+    n = len(dev) if n_devices is None else n_devices
+    return (f"Platform: {dev[0].platform} ({n} device(s), "
+            f"{dev[0].device_kind}); tile packer: {native.packer_name()}")
+
+
 def c2r(x):
     """Complex [...,] -> real [..., 2] (device or host)."""
     if isinstance(x, np.ndarray):

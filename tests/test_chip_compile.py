@@ -18,12 +18,15 @@ per-test time limit installed, so one such case would hang the suite.
 """
 
 import os
+import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 N, TILESZ, M = 62, 10, 8
 NB = N * (N - 1) // 2
@@ -124,24 +127,10 @@ def test_residual_program_compiles(one_chip):
     complex-subtract-then-restack form aborted the TPU compiler on the
     v5e (rime/residual.calculate_residuals_pairs says why); a CHECK
     failure there kills this worker, which is the test failing."""
-    from sagecal_tpu import skymodel
+    import bench
     from sagecal_tpu.rime import predict as rp, residual as rr
     from sagecal_tpu.solvers import normal_eq as ne
-    rng = np.random.default_rng(0)
-    srcs, clusters = {}, []
-    for m in range(M):
-        names = []
-        for s in range(3):
-            nm = f"P{m}_{s}"
-            ll, mm = rng.normal(0, 0.03, 2)
-            srcs[nm] = skymodel.Source(
-                name=nm, ra=0, dec=0, ll=ll, mm=mm,
-                nn=np.sqrt(1 - ll * ll - mm * mm) - 1, sI=1.0, sQ=0.0,
-                sU=0.0, sV=0.0, sI0=1.0, sQ0=0, sU0=0, sV0=0,
-                spec_idx=-0.7, spec_idx1=0.0, spec_idx2=0.0, f0=150e6)
-            names.append(nm)
-        clusters.append((m, 1, names))
-    sky = skymodel.build_cluster_sky(srcs, clusters)
+    sky = bench.make_sky(M, srcs_per_cluster=3)
     dsky = rp.sky_to_device(sky, jnp.float32)
     sd = _spec(one_chip)
     f32, i32 = jnp.float32, jnp.int32

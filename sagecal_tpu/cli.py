@@ -65,7 +65,8 @@ def build_parser() -> argparse.ArgumentParser:
       help="accepted for parity; the reference's Jacobian-leverage "
            "call is disabled in v0.7.8 (fullbatch_mode.cpp:520)")
     a("--profile", default=None, metavar="DIR",
-      help="write a jax.profiler trace of the first solve interval")
+      help="write a jax.profiler trace of one warm solve interval "
+           "(the first tile after a tile that compiled nothing)")
     a("--diag", default=None, metavar="PATH",
       help="write a JSONL diagnostic trace (phase timers + per-iteration "
            "convergence records, sagecal_tpu.diag.trace) to PATH")

@@ -751,13 +751,12 @@ def _main_consensus(args, dtrace) -> int:
     aw = sched.AsyncWriter(enabled=pf_depth > 0)
     source = sched.Prefetcher(
         lambda i: [m.read_tile(start + i) for m in mss],
-        stop - start, depth=pf_depth)
+        stop - start, depth=pf_depth, tile0=start)
 
     try:
         for _i, tiles, io_wait in source:
             ti = start + _i
             aw.check()      # async write failure -> fail at this boundary
-            dtrace.emit("phase", name="io", tile=ti, dur_s=io_wait)
             x8F, uF, vF, wF, wtF, fratioF = _prep_tiles(tiles)
 
             padded, _, _ = cadmm.pad_subbands(

@@ -10,6 +10,10 @@ costing one attribute load and one ``is None`` test.
 File format: one JSON object per line. Every record carries
 
 - ``t``   — unix epoch seconds (float) at emit time,
+- ``tm``  — ``time.perf_counter()`` at emit time: the monotonic clock
+  that ``dur_s`` is timed on (and a benchmark's window runs on), so a
+  record can be placed inside an interval taken around it. A ``phase``
+  record's ``tm`` is the span's END; its start is ``tm - dur_s``,
 - ``ev``  — the event name (str),
 
 plus event-specific fields. The emitting sites keep a small stable
@@ -20,7 +24,8 @@ event            meaning / required extra fields
 ===============  ============================================================
 ``run_start``    first record; run metadata (argv, entry point)
 ``phase``        a timed host phase: ``name`` (io/stage/solve/residual/
-                 write/read/consensus/arrival_wait), ``dur_s``;
+                 write/read/consensus/arrival_wait, and the simulation
+                 loop's predict/fetch), ``dur_s``;
                  optional ``tile``, ``bg`` (True when the phase ran on
                  a background prefetch/writeback thread — under
                  overlapped execution the "io" phase records the
@@ -35,7 +40,9 @@ event            meaning / required extra fields
                  ``sweep``, ``wall_s``, ``fused``, ``err_reduction``,
                  ``solver_iters`` (cumulative executed inner trips)
 ``tile``         one solve interval's convergence summary (pipeline.py /
-                 cli_mpi.py): ``tile``, ``res_0``, ``res_1``; optional
+                 cli_mpi.py): ``tile``, ``res_0``, ``res_1`` (a
+                 simulated tile, ``run_simulation``, solves nothing and
+                 carries only ``tile`` and the overlap pair); optional
                  ``mean_nu``, ``solver_iters``, ``lbfgs_iters``,
                  ``minutes``, ``primal``, ``rho_mean``, and the
                  overlap accounting pair ``bubble_s`` (host seconds
@@ -51,8 +58,26 @@ event            meaning / required extra fields
                  ``res_0``, ``res_1``; optional ``admm``, ``iters``
 ``stage_bytes``  host->device staging accounting: ``bytes``, ``what``;
                  optional ``tile``
+``compile``      one XLA backend compile (or persistent-cache read) that
+                 ran while a tracer was active (diag/guard.py's duration
+                 listener): ``fun`` (the jitted function's name),
+                 ``dur_s``; ``tm`` is the compile's end
+``admm_stale``   bounded-staleness consensus (consensus/admm.py): the
+                 subbands that sat an iteration out: ``interval``,
+                 ``iter``, ``skipped``, ``dead``. Its reader is the
+                 operator of a ``--staleness`` run (MIGRATION.md
+                 "Bounded staleness"); no program reads it
 ``run_end``      last record; ``wall_s`` for the whole run
 ===============  ============================================================
+
+Profiler annotations: :func:`phase` wraps its body in an annotation
+``sagecal/<name>`` (with ``tile=`` where the site has one) on the
+profiler's clock, so the same span can be read from the JSONL and found
+in a ``jax.profiler`` trace. This module stays importable without jax:
+the annotation class is handed in once by ``utils.setup_backend``
+(:func:`set_annotator`). Annotations are made while a tracer is active
+or while ``cli --profile`` has a trace running (:func:`set_profiling`);
+otherwise :func:`phase` returns the shared null context.
 
 Values must be JSON-serializable scalars/strings (callers convert device
 arrays with ``float(...)``/``int(...)`` *after* checking :func:`active`,
@@ -71,6 +96,27 @@ from sagecal_tpu.analysis import threadsan
 REQUIRED_FIELDS = ("t", "ev")
 
 _TRACER = None          # module-level singleton; None = disabled
+
+# profiler annotations: the class (``jax.profiler.TraceAnnotation``) is
+# handed in by utils.setup_backend so that this module never imports
+# jax; _PROFILING is True while ``cli --profile`` has a trace running
+# with no tracer installed
+_ANNOTATOR = None
+_PROFILING = False
+ANNOTATION_PREFIX = "sagecal/"
+
+
+def set_annotator(cls) -> None:
+    """``cls(name, **kwargs)`` must be a context manager that marks a
+    span on the profiler's clock (``jax.profiler.TraceAnnotation``)."""
+    global _ANNOTATOR
+    _ANNOTATOR = cls
+
+
+def set_profiling(on: bool) -> None:
+    """While on, :func:`phase` annotates even with no tracer active."""
+    global _PROFILING
+    _PROFILING = bool(on)
 
 # thread-scoped tracer override (serve: per-job --diag routing). The
 # server runs many jobs through one process; each job's records go to
@@ -138,8 +184,8 @@ class Tracer:
         self.emit("run_start", **run_meta)
 
     def emit(self, ev: str, **fields) -> None:
-        rec = {"t": time.time(), "ev": ev}
-        rec.update(fields)
+        rec = {"t": time.time(), "tm": time.perf_counter(), "ev": ev}
+        rec.update(fields)          # a phase passes its own end as tm
         try:
             line = json.dumps(rec) + "\n"
         except (TypeError, ValueError):
@@ -163,22 +209,53 @@ class Tracer:
 
 
 class _Phase:
-    """Context manager timing one host phase; emits on exit."""
+    """Context manager timing one host phase: a profiler annotation
+    ``sagecal/<name>`` around the body (when an annotator is set) and
+    one ``phase`` record on exit (when a tracer is live)."""
 
-    __slots__ = ("_tr", "_name", "_fields", "_t0")
+    __slots__ = ("_tr", "_name", "_fields", "_t0", "_ann", "_less",
+                 "dur_s")
 
     def __init__(self, tracer, name, fields):
         self._tr = tracer
         self._name = name
         self._fields = fields
+        self._less = 0.0
+        self.dur_s = 0.0        # the span's seconds, once it has ended
+
+    def drop(self) -> None:
+        """Emit no record for this span (the wait turned out to be for
+        the end of input, not for a tile)."""
+        self._tr = None
+
+    def carve(self, name: str, dur_s: float) -> None:
+        """``dur_s`` of this span's seconds belong to phase ``name``
+        (a consumer's block that overlapped the producer's wait for a
+        tile to arrive): they are emitted as that phase, with this
+        span's fields, and taken off this span's own ``dur_s``."""
+        self._less += dur_s
+        if self._tr is not None:
+            self._tr.emit("phase", name=name, dur_s=dur_s, **self._fields)
 
     def __enter__(self):
+        self._ann = None
+        if _ANNOTATOR is not None:
+            f = self._fields
+            self._ann = _ANNOTATOR(ANNOTATION_PREFIX + self._name,
+                                   **({"tile": f["tile"]} if "tile" in f
+                                      else {}))
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self._tr.emit("phase", name=self._name,
-                      dur_s=time.perf_counter() - self._t0, **self._fields)
+        t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self.dur_s = t1 - self._t0 - self._less
+        if self._tr is not None:
+            self._tr.emit("phase", name=self._name, dur_s=self.dur_s,
+                          tm=t1, **self._fields)
         return False
 
 
@@ -186,12 +263,19 @@ class _NullPhase:
     """Shared do-nothing context manager for the disabled path."""
 
     __slots__ = ()
+    dur_s = 0.0
 
     def __enter__(self):
         return self
 
     def __exit__(self, *exc):
         return False
+
+    def drop(self) -> None:
+        pass
+
+    def carve(self, name, dur_s) -> None:
+        pass
 
 
 _NULL_PHASE = _NullPhase()
@@ -233,11 +317,13 @@ def emit(ev: str, **fields) -> None:
 
 
 def phase(name: str, **fields):
-    """Module-level phase timer; a shared null context when disabled."""
+    """THE way to time a host phase: ``with dtrace.phase("solve",
+    tile=ti): ...``. A shared null context when no tracer is active
+    and no ``--profile`` trace is running."""
     t = _current()
-    if t is None:
+    if t is None and not _PROFILING:
         return _NULL_PHASE
-    return t.phase(name, **fields)
+    return _Phase(t, name, fields)
 
 
 def overlap_stats(recs: list) -> dict:

@@ -140,6 +140,7 @@ def coherencies_points(uvw3, geom, flux, gauss, freqs, fdelta,
         out_specs=pl.BlockSpec((1, 1, 8, bt), lambda m, f, b: (m, f, 0, b)),
         out_shape=jax.ShapeDtypeStruct((M, F, 8, Bp), f32),
         interpret=interpret,
+        name="coh_points",
     )(jnp.asarray(freqs, f32).reshape(F, 1),
       jnp.asarray(fdelta, f32).reshape(1, 1),
       jnp.asarray(uvw3, f32), jnp.asarray(geom, f32),
@@ -222,6 +223,7 @@ def any_supported(sky) -> bool:
     return bool(np.any((live == STYPE_POINT) | (live == STYPE_GAUSSIAN)))
 
 
+@jax.named_scope("rime/phasor")
 def coherencies(sky, u, v, w, freqs, fdelta, per_channel_flux: bool = False,
                 block_b: int = 1024, interpret: bool = False):
     """Drop-in for rime.predict.coherencies on point/gaussian models.

@@ -701,7 +701,7 @@ class FullBatchPipeline:
                 return i, tile, stg
 
             pf = sched.Prefetcher(produce, None, depth=depth,
-                                  arrive=stream.wait_next)
+                                  arrive=stream.wait_next, tile0=start)
         else:
             n = self.ms.n_tiles
             if max_tiles is not None:
@@ -713,10 +713,11 @@ class FullBatchPipeline:
                 return i, tile, stage_fn(i, tile)
 
             pf = sched.Prefetcher(
-                produce, max(0, n - start), depth=depth,
+                produce, max(0, n - start), depth=depth, tile0=start,
                 pace_s=getattr(self.cfg, "tile_arrival_s", 0.0))
+        # the consumer's "io" phase (its wait for each item) is the
+        # Prefetcher's own, tile = start + j
         for _j, (ti, tile, stg), wait in pf:
-            dtrace.emit("phase", name="io", tile=ti, dur_s=wait)
             yield ti, tile, stg, wait
 
     def _write_residual_tile(self, ti, tile, res_r, bg=True):
@@ -775,6 +776,13 @@ class FullBatchPipeline:
 
         def stage(ti, tile):
             t_stage = time.perf_counter()
+            with dtrace.phase("stage", tile=ti, bg=depth > 0):
+                out = stage_tile(ti, tile)
+            obs.observe("tile_stage_seconds",
+                        time.perf_counter() - t_stage)
+            return out
+
+        def stage_tile(ti, tile):
             u = jnp.asarray(tile.u, self.rdt)
             v = jnp.asarray(tile.v, self.rdt)
             w = jnp.asarray(tile.w, self.rdt)
@@ -798,10 +806,6 @@ class FullBatchPipeline:
                 # input; the ring keeps overlapped staging from ever
                 # aliasing an in-flight donated buffer
                 ring.stage(ti, jnp.asarray(utils.c2r(tile.x), self.sdt))
-            dur = time.perf_counter() - t_stage
-            dtrace.emit("phase", name="stage", tile=ti,
-                        dur_s=dur, bg=depth > 0)
-            obs.observe("tile_stage_seconds", dur)
             return out
 
         def post(stg, res_0, res_1, mean_nu, Jnew, minutes):
@@ -824,15 +828,14 @@ class FullBatchPipeline:
                     writer.write_interval,
                     state["J"] if state["first"] else Jnew, sky.nchunk)
             if write_residuals:
-                t_res = time.perf_counter()
-                res_r = self._residual_fn(
-                    jnp.asarray(utils.jones_c2r_np(
-                        state["J"] if state["first"] else Jnew), self.rdt),
-                    ring.take(ti),
-                    stg["u"], stg["v"], stg["w"], stg["sta1"], stg["sta2"],
-                    stg["beam"])
-                dtrace.emit("phase", name="residual", tile=ti,
-                            dur_s=time.perf_counter() - t_res)
+                with dtrace.phase("residual", tile=ti):
+                    res_r = self._residual_fn(
+                        jnp.asarray(utils.jones_c2r_np(
+                            state["J"] if state["first"] else Jnew),
+                            self.rdt),
+                        ring.take(ti),
+                        stg["u"], stg["v"], stg["w"], stg["sta1"],
+                        stg["sta2"], stg["beam"])
                 if depth > 0:
                     # start the non-blocking device->host copy, hand
                     # fetch + MS write to the ordered writer thread
@@ -854,11 +857,11 @@ class FullBatchPipeline:
             t0 = time.time()
             solver = self._solve_first if boosted else self._solve_rest
             J_r8 = jnp.asarray(utils.jones_c2r_np(state["J"]), self.rdt)
-            Jd_r8, info = solver(stg["x8"], stg["u"], stg["v"], stg["w"],
-                                 stg["sta1"], stg["sta2"], stg["wt"],
-                                 J_r8, stg["beam"], tile_idx=stg["ti"])
-            dtrace.emit("phase", name="solve", tile=stg["ti"],
-                        dur_s=time.time() - t0)
+            with dtrace.phase("solve", tile=stg["ti"]):
+                Jd_r8, info = solver(
+                    stg["x8"], stg["u"], stg["v"], stg["w"], stg["sta1"],
+                    stg["sta2"], stg["wt"], J_r8, stg["beam"],
+                    tile_idx=stg["ti"])
             obs.observe("tile_solve_seconds", time.time() - t0)
             state["first"] = False
             post(stg, float(info["res_0"]), float(info["res_1"]),
@@ -881,20 +884,19 @@ class FullBatchPipeline:
             if self.dobeam:
                 beamT = group[0]["beam"]._replace(
                     gmst=jnp.stack([g["beam"].gmst for g in group]))
-            Jd, info = self._solve_tiles(
-                jnp.stack([g["x8"] for g in group]),
-                jnp.stack([g["u"] for g in group]),
-                jnp.stack([g["v"] for g in group]),
-                jnp.stack([g["w"] for g in group]),
-                group[0]["sta1"], group[0]["sta2"],
-                jnp.stack([g["wt"] for g in group]),
-                J0, [g["ti"] for g in group], beamT=beamT)
-            Jd = np.asarray(Jd)
-            r0 = np.asarray(info["res_0"])
-            r1 = np.asarray(info["res_1"])
-            mnu = np.asarray(info["mean_nu"])
-            dtrace.emit("phase", name="solve", tiles=T,
-                        dur_s=time.time() - t0)
+            with dtrace.phase("solve", tiles=T):
+                Jd, info = self._solve_tiles(
+                    jnp.stack([g["x8"] for g in group]),
+                    jnp.stack([g["u"] for g in group]),
+                    jnp.stack([g["v"] for g in group]),
+                    jnp.stack([g["w"] for g in group]),
+                    group[0]["sta1"], group[0]["sta2"],
+                    jnp.stack([g["wt"] for g in group]),
+                    J0, [g["ti"] for g in group], beamT=beamT)
+                Jd = np.asarray(Jd)
+                r0 = np.asarray(info["res_0"])
+                r1 = np.asarray(info["res_1"])
+                mnu = np.asarray(info["mean_nu"])
             if obs.active():
                 # one amortized observation PER TILE, so the histogram
                 # count stays equal to tiles_solved_total under
@@ -971,33 +973,24 @@ class FullBatchPipeline:
         depth = self._prefetch_depth(prefetch)
         st = self.stepper(write_residuals, solution_path, max_tiles,
                           log, prefetch=depth)
-        # --profile: capture an XLA/device timeline of the FIRST solve
+        # --profile: capture an XLA/device timeline of ONE WARM solve
         # interval (SURVEY.md section 5 tracing — the reference has only
-        # wall-clock prints; a jax.profiler trace is the superset).
-        # Bounded to one tile so trace size stays sane.
-        prof_dir = getattr(self.cfg, "profile_dir", None)
-        prof_live = False
-        if prof_dir:
-            import jax.profiler
-            jax.profiler.start_trace(prof_dir)
-            prof_live = True
-            log(f"profiling first solve interval -> {prof_dir}")
+        # wall-clock prints; a jax.profiler trace is the superset): the
+        # first tile that follows a tile in which nothing was traced or
+        # compiled. Bounded to one tile so trace size stays sane.
+        prof = _WarmTileProfile(getattr(self.cfg, "profile_dir", None),
+                                log)
         try:
             for ti, tile, stg, io_wait in self._tile_source(
                     st.stage, max_tiles, depth, start=st.start_tile):
+                prof.enter_tile(ti)
                 st.step(ti, tile, stg, io_wait)
-                if prof_live:
-                    import jax.profiler
-                    jax.profiler.stop_trace()
-                    prof_live = False
-                    log(f"profile trace written to {prof_dir}")
+                prof.leave_tile(ti)
         finally:
             try:
                 st.close()
             finally:
-                if prof_live:   # abnormal exit or 0-tile run:
-                    import jax.profiler
-                    jax.profiler.stop_trace()  # close the trace
+                prof.stop()     # abnormal exit: close a live trace
         return st.history
 
     def _run_stream(self, stream, write_residuals=True,
@@ -1061,20 +1054,91 @@ class FullBatchPipeline:
             "sim", lambda: jax.jit(sim_fn),
             pcache.token(ignore_mask, int(cfg.simulation)))
         sim_jit = self._sim_jit
-        for ti, tile in ms.tiles():
-            J_r8 = None
-            if blocks_iter:
-                J_r8 = jnp.asarray(utils.jones_c2r_np(
-                    blocks_iter[min(ti, len(blocks_iter) - 1)]), self.rdt)
-            out_r = sim_jit(
-                jnp.asarray(utils.c2r(tile.x), self.rdt),
-                jnp.asarray(tile.u, self.rdt), jnp.asarray(tile.v, self.rdt),
-                jnp.asarray(tile.w, self.rdt),
-                jnp.asarray(tile.sta1), jnp.asarray(tile.sta2), J_r8,
-                self._tile_beam(tile))
-            tile.x = utils.r2c(np.asarray(out_r)).astype(np.complex128)
-            ms.write_tile(ti, tile)
+        # a synchronous loop, in the calibrate path's vocabulary: the
+        # phases io / stage / predict (a dispatch) / fetch (the wait
+        # for the device and the copy) / write, and per tile one
+        # ``tile`` record with bubble_s = io + write at overlap 0
+        tiles = iter(ms.tiles())
+        while True:
+            with dtrace.phase("io") as ph_io:   # the tile id comes out
+                try:
+                    ti, tile = next(tiles)
+                except StopIteration:
+                    ph_io.drop()
+                    break
+            with dtrace.phase("stage", tile=ti):
+                J_r8 = None
+                if blocks_iter:
+                    J_r8 = jnp.asarray(utils.jones_c2r_np(
+                        blocks_iter[min(ti, len(blocks_iter) - 1)]),
+                        self.rdt)
+                args = (jnp.asarray(utils.c2r(tile.x), self.rdt),
+                        jnp.asarray(tile.u, self.rdt),
+                        jnp.asarray(tile.v, self.rdt),
+                        jnp.asarray(tile.w, self.rdt),
+                        jnp.asarray(tile.sta1), jnp.asarray(tile.sta2),
+                        J_r8, self._tile_beam(tile))
+            with dtrace.phase("predict", tile=ti):
+                out_r = sim_jit(*args)
+            with dtrace.phase("fetch", tile=ti):
+                out = np.asarray(out_r)
+            with dtrace.phase("write", tile=ti) as ph_write:
+                tile.x = utils.r2c(out).astype(np.complex128)
+                ms.write_tile(ti, tile)
+            if dtrace.active():
+                dtrace.emit("tile", tile=ti, overlap=0,
+                            bubble_s=ph_io.dur_s + ph_write.dur_s)
             log(f"Timeslot: {ti} simulated (mode={int(cfg.simulation)})")
+
+
+class _WarmTileProfile:
+    """``cli --profile DIR``: a ``jax.profiler`` trace of one warm tile.
+
+    A tile is warm when the tile before it logged no trace, lowering or
+    compile (``diag.guard.compiles_logged``): the first tile compiles,
+    a promoted program's first run compiles again, and a trace of
+    either is a trace of the compiler. With no directory every method
+    returns at its first test. While the trace runs, ``dtrace.phase``
+    annotates its spans (``sagecal/<name>``) even without ``--diag``.
+    A run whose every tile compiled writes no trace, and says so."""
+
+    def __init__(self, prof_dir, log):
+        self.dir, self.log = prof_dir, log
+        self.state = "cold" if prof_dir else "done"
+        self._n = 0
+
+    def enter_tile(self, ti):
+        if self.state == "done":
+            return
+        from sagecal_tpu.diag import guard
+        if self.state == "armed":
+            import jax.profiler
+            jax.profiler.start_trace(self.dir)
+            dtrace.set_profiling(True)
+            self.state = "live"
+            self.log(f"profiling solve interval {ti} (the first after "
+                     f"a tile that compiled nothing) -> {self.dir}")
+        self._n = guard.compiles_logged()
+
+    def leave_tile(self, ti):
+        if self.state == "done":
+            return
+        from sagecal_tpu.diag import guard
+        if self.state == "live":
+            self.stop()
+            self.log(f"profile trace written to {self.dir}")
+        elif guard.compiles_logged() == self._n:
+            self.state = "armed"
+
+    def stop(self):
+        if self.state == "live":
+            import jax.profiler
+            jax.profiler.stop_trace()
+            dtrace.set_profiling(False)
+        elif self.state != "done":
+            self.log(f"--profile: no tile followed a tile that compiled "
+                     f"nothing; no trace written to {self.dir}")
+        self.state = "done"
 
 
 def stream_tile_late(cfg, ti, stg, key=None):
@@ -1249,9 +1313,15 @@ class TileStepper:
     # -- reader-thread half -------------------------------------------------
 
     def stage(self, ti, tile):
+        t_stage = time.perf_counter()
+        with dtrace.phase("stage", tile=ti, bg=self.depth > 0):
+            stg = self._stage_tile(ti, tile)
+        obs.observe("tile_stage_seconds", time.perf_counter() - t_stage)
+        return stg
+
+    def _stage_tile(self, ti, tile):
         p = self.p
         cfg, meta = p.cfg, p.ms.meta
-        t_stage = time.perf_counter()
         pad = p.pad_rows
         u_np, v_np, w_np = tile.u, tile.v, tile.w
         sta1_np, sta2_np = tile.sta1, tile.sta2
@@ -1300,10 +1370,6 @@ class TileStepper:
             # program (ring: no read-after-donate, no aliasing)
             x_r = tile.x if not pad else pcache.pad_rows_zero(tile.x, pad)
             self.ring.stage(ti, jnp.asarray(utils.c2r(x_r), p.sdt))
-        dur = time.perf_counter() - t_stage
-        dtrace.emit("phase", name="stage", tile=ti,
-                    dur_s=dur, bg=self.depth > 0)
-        obs.observe("tile_stage_seconds", dur)
         return stg
 
     # -- device-owner half --------------------------------------------------
@@ -1345,15 +1411,15 @@ class TileStepper:
             J_prev = self.J          # the last-good chain (quarantine)
             J_r8 = jnp.asarray(utils.jones_c2r_np(self.J), p.rdt)
             t_solve = time.perf_counter()
-            Jd_r8, info = solver(x8, u, v, w, sta1, sta2, wt, J_r8,
-                                 tile_beam, tile_idx=ti)
-            self.first = False
-            res_0 = float(info["res_0"])
-            res_1 = float(info["res_1"])
-            mean_nu = float(info["mean_nu"])
-            self.J = utils.jones_r2c_np(np.asarray(Jd_r8))
-            dtrace.emit("phase", name="solve", tile=ti,
-                        dur_s=time.perf_counter() - t_solve)
+            # the span ends in the read-backs the step needs anyway
+            with dtrace.phase("solve", tile=ti):
+                Jd_r8, info = solver(x8, u, v, w, sta1, sta2, wt, J_r8,
+                                     tile_beam, tile_idx=ti)
+                self.first = False
+                res_0 = float(info["res_0"])
+                res_1 = float(info["res_1"])
+                mean_nu = float(info["mean_nu"])
+                self.J = utils.jones_r2c_np(np.asarray(Jd_r8))
             obs.observe("tile_solve_seconds",
                         time.perf_counter() - t_solve)
         # solve_nan: the poisoned-tile chaos seam (a NaN/nonfinite
@@ -1411,13 +1477,11 @@ class TileStepper:
                                          self.J, sky.nchunk)
 
             if self.write_residuals:
-                t_res = time.perf_counter()
-                res_r = p._residual_fn(
-                    jnp.asarray(utils.jones_c2r_np(self.J), p.rdt),
-                    self.ring.take(ti),
-                    u, v, w, sta1, sta2, tile_beam)
-                dtrace.emit("phase", name="residual", tile=ti,
-                            dur_s=time.perf_counter() - t_res)
+                with dtrace.phase("residual", tile=ti):   # a dispatch
+                    res_r = p._residual_fn(
+                        jnp.asarray(utils.jones_c2r_np(self.J), p.rdt),
+                        self.ring.take(ti),
+                        u, v, w, sta1, sta2, tile_beam)
                 if self.depth > 0:
                     # non-blocking d->h copy now; fetch + MS
                     # write on the ordered writer thread
@@ -1489,7 +1553,6 @@ class TileStepper:
         AsyncWriter ordering."""
         lat = time.monotonic() - t_arr
         obs.observe("stream_tile_latency_seconds", lat)
-        dtrace.emit("stream_latency", tile=ti, latency_s=lat)
 
     def _accum_prior_quality(self, res_r, n_rows) -> None:
         """Writer-queue job: fold one banked tile's written-residual
@@ -1623,9 +1686,6 @@ class TileStepper:
                     "(no bf16/f16) and stays at the pipeline dtype — "
                     f"~{unmelted / 1e6:.1f} MB/tile of residual "
                     "traffic is NOT melted by the storage policy")
-                dtrace.emit("dtype_fallback", what="per_channel_residual",
-                            policy=p.dtype_policy, tile=ti,
-                            unmelted_bytes_per_tile=unmelted)
             if Fp != F:
                 x_rC_full = jnp.concatenate(
                     [x_rC_full,

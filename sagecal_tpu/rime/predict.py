@@ -100,6 +100,11 @@ def _spectral_flux(s0, spec_idx, spec_idx1, spec_idx2, f0, freq):
     return jnp.where(spec_idx != 0.0, scaled, s0)
 
 
+# Names the profiler trace can find (PERF.md section 3; metadata only,
+# the compiled programs are the same): ``rime/phasor`` is the source
+# sum of one cluster (fringe phase, cos/sin, smearing, envelopes,
+# flux), ``rime/corrupt`` the Jones sandwich J_p C J_q^H.
+@jax.named_scope("rime/phasor")
 def _cluster_coherency(csky, u, v, w, freqs, fdelta, per_channel_flux: bool,
                        n0max: int, with_shapelets: bool,
                        af=None, E=None, tslot=None, sta1=None, sta2=None):
@@ -220,7 +225,8 @@ def coherencies(sky: SkyArrays, u, v, w, freqs, fdelta,
                                       per_channel_flux, n0max,
                                       with_shapelets)
 
-    return jax.lax.map(per_cluster, sky)
+    with jax.named_scope("rime/phasor"):    # the map's stacking too
+        return jax.lax.map(per_cluster, sky)
 
 
 def coherencies_split(sky_pg, sky_rest, u, v, w, freqs, fdelta,
@@ -279,6 +285,7 @@ def chunk_indices(tilesz: int, nbase: int, nchunk: np.ndarray) -> np.ndarray:
     return out
 
 
+@jax.named_scope("rime/corrupt")
 def model8(coh_m, J_m, sta1, sta2, chunk_idx_m, out_dtype=None):
     """One cluster's corrupted model as [B, 8] reals (solve-path data
     order: (Re, Im) of XX, XY, YX, YY — Dirac.h:1541-1546).
@@ -301,6 +308,7 @@ def model8(coh_m, J_m, sta1, sta2, chunk_idx_m, out_dtype=None):
     return out if out_dtype is None else dtp.to_storage(out, out_dtype)
 
 
+@jax.named_scope("rime/corrupt")
 def apply_jones(coh_m, J_m, sta1, sta2, chunk_idx_m):
     """One cluster's corrupted model: J_p C J_q^H per baseline.
 
@@ -313,6 +321,7 @@ def apply_jones(coh_m, J_m, sta1, sta2, chunk_idx_m):
     return jnp.einsum("bij,bfjk,bkl->bfil", Jp, coh_m, JqH)
 
 
+@jax.named_scope("rime/corrupt")
 def predict_model(coh, J, sta1, sta2, chunk_idx, cluster_mask=None):
     """Sum of corrupted cluster models: sum_m J_p C_m J_q^H -> [B, F, 2, 2].
 

@@ -131,8 +131,9 @@ def calculate_residuals_pairs(sky: rp.SkyArrays, J, x_r, u, v, w, freqs,
     model = _model_multifreq(sky, J, u, v, w, freqs, fdelta_chan, sta1,
                              sta2, chunk_idx, subtract_mask, beam, dobeam,
                              tslot)
-    out = dtp.acc(x_r) - jnp.stack([model.real, model.imag], axis=-1)
-    return out if out_dtype is None else dtp.to_storage(out, out_dtype)
+    with jax.named_scope("rime/residual"):   # subtraction + write-back
+        out = dtp.acc(x_r) - jnp.stack([model.real, model.imag], axis=-1)
+        return out if out_dtype is None else dtp.to_storage(out, out_dtype)
 
 
 def calculate_residuals_interp(sky: rp.SkyArrays, J_old, J_new, x, u, v, w,
@@ -185,13 +186,14 @@ def simulate_visibilities(sky: rp.SkyArrays, x, u, v, w, freqs, fdelta_chan,
     else:
         model = jnp.sum(jnp.where(mask[:, None, None, None, None], coh, 0.0),
                         axis=0)
-    if mode == 2:       # SIMUL_ADD
-        out = x + model
-    elif mode == 3:     # SIMUL_SUB
-        out = x - model
-    else:               # SIMUL_ONLY
-        out = model
-    if correct_idx is not None and J is not None:
-        out = correct_by_cluster(out, J[correct_idx], sta1, sta2,
-                                 chunk_idx[correct_idx], rho)
+    with jax.named_scope("rime/residual"):
+        if mode == 2:       # SIMUL_ADD
+            out = x + model
+        elif mode == 3:     # SIMUL_SUB
+            out = x - model
+        else:               # SIMUL_ONLY
+            out = model
+        if correct_idx is not None and J is not None:
+            out = correct_by_cluster(out, J[correct_idx], sta1, sta2,
+                                     chunk_idx[correct_idx], rho)
     return out

@@ -121,11 +121,14 @@ class Prefetcher:
     def __init__(self, fn, n: int | None, depth: int = 1,
                  name: str = "read", context=None, ready_event=None,
                  join_timeout_s: float = 5.0, pace_s: float = 0.0,
-                 arrive=None):
+                 arrive=None, tile0: int = 0):
         self.fn = fn
         self.n = None if n is None else int(n)
         self.depth = int(depth)
         self.name = name
+        # item i is the caller's tile tile0 + i (checkpoint resume
+        # starts past 0): the diag phases emitted here carry that id
+        self.tile0 = int(tile0)
         self.join_timeout_s = float(join_timeout_s)
         # streaming-ingest model (--tile-arrival): item i becomes
         # producible no earlier than start + i * pace_s, as if tiles
@@ -164,42 +167,40 @@ class Prefetcher:
 
     # -- producer thread ---------------------------------------------------
 
-    def _wait_arrival(self, i):
+    def _wait_arrival(self, i, bg):
         """Block until item ``i`` is AVAILABLE (the pace_s ingest
-        clock, or the ``arrive`` transport hook). Returns
-        ``(waited_s, t_arrival)`` with ``t_arrival`` in the
-        time.monotonic domain; raises :class:`EndOfStream` when the
-        arrive hook reports end of input. This wait is attributed as
-        the ``arrival_wait`` phase by the caller — NEVER as read/io
-        time: it measures the tenant's data rate, not our cost."""
-        if self._arrive is not None:
+        clock, or the ``arrive`` transport hook). Returns the arrival
+        stamp in the time.monotonic domain; raises :class:`EndOfStream`
+        when the arrive hook reports end of input. The wait is its own
+        diag phase, ``arrival_wait`` (+ metric) — NEVER read/io time:
+        it measures the tenant's data rate, not our cost. ``bg``: the
+        wait ran on the producer thread."""
+        if self._arrive is None and self.pace_s <= 0.0:
+            return time.monotonic()
+        with dtrace.phase("arrival_wait", tile=self.tile0 + i,
+                          bg=bg) as ph:
             t0 = time.monotonic()
-            t_arr = self._arrive(self._cancel)
-            return time.monotonic() - t0, t_arr
-        if self.pace_s > 0.0:
-            # ingest pacing: wait out the synthetic arrival time (the
-            # cancel event bounds the wait so close() stays prompt)
-            t0 = time.monotonic()
-            due = self._t0 + i * self.pace_s
-            while not self._cancel.is_set():
-                delay = due - time.monotonic()
-                if delay <= 0:
-                    break
-                self._cancel.wait(min(delay, 0.2))
-            now = time.monotonic()
-            return now - t0, max(due, t0)
-        return 0.0, time.monotonic()
-
-    def _emit_arrival(self, i, waited, bg, observe=True):
-        """The ``arrival_wait`` diag phase (+ metric). The consumer
-        side passes ``observe=False`` — its overlap with the producer's
-        wait is the SAME wall time, and the metric must count each
-        waited second once."""
+            if self._arrive is not None:
+                try:
+                    t_arr = self._arrive(self._cancel)
+                except EndOfStream:
+                    ph.drop()       # the end of input is no wait
+                    raise
+            else:
+                # ingest pacing: wait out the synthetic arrival time
+                # (the cancel event bounds the wait so close() stays
+                # prompt)
+                due = self._t0 + i * self.pace_s
+                while not self._cancel.is_set():
+                    delay = due - time.monotonic()
+                    if delay <= 0:
+                        break
+                    self._cancel.wait(min(delay, 0.2))
+                t_arr = max(due, t0)
+            waited = time.monotonic() - t0
         if waited > 0.0:
-            dtrace.emit("phase", name="arrival_wait", tile=i,
-                        dur_s=waited, bg=bg)
-            if observe:
-                obs.observe("tile_arrival_wait_seconds", waited)
+            obs.observe("tile_arrival_wait_seconds", waited)
+        return t_arr
 
     def _call(self, i):
         """One production, with the fault-tolerance layer around it:
@@ -238,24 +239,24 @@ class Prefetcher:
                 if self._cancel.is_set():
                     return
                 try:
-                    waited, t_arr = self._wait_arrival(i)
+                    t_arr = self._wait_arrival(i, bg=True)
                 except EndOfStream:
                     break
                 if self._cancel.is_set():
                     return
-                self._emit_arrival(i, waited, bg=True)
-                t0 = time.perf_counter()
-                try:
-                    item = self._call(i)
-                except EndOfStream:
-                    break
                 # the background production time — NOT the consumer's
-                # io wait, and NOT the arrival wait (emitted above);
-                # tagged bg so attribution stays honest
-                dur = time.perf_counter() - t0
-                dtrace.emit("phase", name=self.name, tile=i,
-                            dur_s=dur, bg=True)
-                obs.observe("prefetch_read_seconds", dur)
+                # io wait, and NOT the arrival wait (its own phase
+                # above); tagged bg so attribution stays honest
+                t0 = time.perf_counter()
+                with dtrace.phase(self.name, tile=self.tile0 + i,
+                                  bg=True) as ph:
+                    try:
+                        item = self._call(i)
+                    except EndOfStream:
+                        ph.drop()
+                        break
+                obs.observe("prefetch_read_seconds",
+                            time.perf_counter() - t0)
                 if not self._put((i, item, t_arr)):
                     return
                 i += 1
@@ -271,35 +272,45 @@ class Prefetcher:
             i = 0
             while self.n is None or i < self.n:
                 try:
-                    waited, _t_arr = self._wait_arrival(i)
+                    self._wait_arrival(i, bg=False)
                 except EndOfStream:
                     return
-                self._emit_arrival(i, waited, bg=False)
-                t0 = time.perf_counter()
-                try:
-                    item = self._call(i)
-                except EndOfStream:
-                    return
-                yield i, item, time.perf_counter() - t0
+                # inline production: the consumer's "io" phase is the
+                # whole read + stage
+                with dtrace.phase("io", tile=self.tile0 + i) as ph:
+                    t0 = time.perf_counter()
+                    try:
+                        item = self._call(i)
+                    except EndOfStream:
+                        ph.drop()
+                        return
+                    wait = time.perf_counter() - t0
+                yield i, item, wait
                 i += 1
             return
         try:
+            k = 0
             while True:
-                t0 = time.monotonic()
-                i, item, t_arr = self._q.get()
-                t1 = time.monotonic()
-                wait = t1 - t0
-                if i is None:
-                    if item is not None:
-                        raise item
-                    return
-                # split the block: the part spent while the item had
-                # not yet ARRIVED is arrival wait (the tenant's data
-                # rate), only the remainder is the io bubble (our
-                # read/stage cost)
-                arr = min(max(t_arr - t0, 0.0), wait)
-                self._emit_arrival(i, arr, bg=False, observe=False)
+                # the consumer's "io" phase: its wait for the next item
+                with dtrace.phase("io", tile=self.tile0 + k) as ph:
+                    t0 = time.monotonic()
+                    i, item, t_arr = self._q.get()
+                    wait = time.monotonic() - t0
+                    if i is None:
+                        ph.drop()       # the end marker is no tile
+                        if item is not None:
+                            raise item
+                        return
+                    # split the block: the part spent while the item
+                    # had not yet ARRIVED is arrival wait (the tenant's
+                    # data rate, counted by the producer's metric
+                    # already), only the remainder is the io bubble
+                    # (our read/stage cost)
+                    arr = min(max(t_arr - t0, 0.0), wait)
+                    if arr > 0.0:
+                        ph.carve("arrival_wait", arr)
                 yield i, item, wait - arr
+                k += 1
         finally:
             self.close()
 
@@ -322,8 +333,7 @@ class Prefetcher:
                 return self.DONE
             i = self._poll_next
             try:
-                waited, _t_arr = self._wait_arrival(i)
-                self._emit_arrival(i, waited, bg=False)
+                self._wait_arrival(i, bg=False)
                 t0 = time.perf_counter()
                 item = self._call(i)
             except EndOfStream:

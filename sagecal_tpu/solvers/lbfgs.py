@@ -57,6 +57,7 @@ def lbfgs_memory_reset(mem: LBFGSMemory) -> LBFGSMemory:
     return lbfgs_memory_init(mem.s.shape[1], mem.s.shape[0], mem.s.dtype)
 
 
+@jax.named_scope("direction")      # sage/refine/direction in a solve
 def mult_hessian(g, mem: LBFGSMemory):
     """Two-loop recursion: H_k g with implicit H0 = gamma I (lbfgs.c:33)."""
     M = mem.s.shape[0]
@@ -117,6 +118,7 @@ def linesearch_backtrack(cost_func: Callable, xk, pk, gk, alpha0,
     return alpha
 
 
+@jax.named_scope("linesearch")     # sage/refine/linesearch in a solve
 def linesearch_fletcher(cost_func, grad_func, xk, pk, gk=None,
                         alpha1: float = 10.0, sigma: float = 0.1,
                         rho: float = 0.01, t1: float = 9.0, t2: float = 0.1,

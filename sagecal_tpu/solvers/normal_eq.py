@@ -208,6 +208,7 @@ def _reduced_gram_baseline_major(wt, MA, MB, rw, T: int, nb: int, N: int,
     return JTJ.reshape(1, 8 * N, 8 * N), JTe.reshape(1, 8 * N)
 
 
+@jax.named_scope("assemble")     # sage/sweep/assemble in a solve
 def os_subset_equations(x8, J, coh, sta1, sta2, wt, os_id, subset,
                         ntper: int, row_period: int, n_stations: int,
                         cost_wt):
@@ -363,6 +364,7 @@ def _normal_equations_reduced(x8, J, coh, sta1, sta2, chunk_id, wt,
     return JTJ, JTe.reshape(kmax, 8 * N), cost
 
 
+@jax.named_scope("assemble")     # sage/sweep/assemble in a solve
 def normal_equations(x8, J, coh, sta1, sta2, chunk_id, wt, n_stations: int,
                      kmax: int, cost_wt=None, row_period: int = 0):
     """Weighted Gauss-Newton normal equations, batched over time chunks.
@@ -533,6 +535,7 @@ class GNFactors(NamedTuple):
     D: jax.Array
 
 
+@jax.named_scope("assemble")     # sage/sweep/assemble in a solve
 def gn_factors(x8, J, coh, sta1, sta2, chunk_id, wt, n_stations: int,
                kmax: int, cost_wt=None, row_period=0):
     """Matrix-free analogue of :func:`normal_equations`.
@@ -859,6 +862,7 @@ def _mode_dense(pp, qq, pq, jtep, jteq, sta1, sta2, chunk_id,
     return JTJ, JTe.reshape(kmax, npar * N)
 
 
+@jax.named_scope("assemble")     # sage/sweep/assemble in a solve
 def normal_equations_mode(x8, J, coh, sta1, sta2, chunk_id, wt,
                           n_stations: int, kmax: int, mode: str = "full",
                           cost_wt=None, row_period: int = 0):
@@ -904,6 +908,7 @@ def normal_equations_mode(x8, J, coh, sta1, sta2, chunk_id, wt,
     return JTJ, JTe, cost
 
 
+@jax.named_scope("assemble")     # sage/sweep/assemble in a solve
 def os_subset_equations_mode(x8, J, coh, sta1, sta2, wt, os_id, subset,
                              ntper: int, row_period: int,
                              n_stations: int, cost_wt,
@@ -971,6 +976,7 @@ class GNFactorsMode(NamedTuple):
     D: jax.Array
 
 
+@jax.named_scope("assemble")     # sage/sweep/assemble in a solve
 def gn_factors_mode(x8, J, coh, sta1, sta2, chunk_id, wt,
                     n_stations: int, kmax: int, mode: str = "full",
                     cost_wt=None, row_period=0):

@@ -439,17 +439,23 @@ def _cluster_update(cj, state, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
     cmask_m = jnp.take(chunk_mask, cj, axis=0)
     J_m = jnp.take(J, cj, axis=0)
 
-    xdummy = xres + _model8(J_m, coh_m, sta1, sta2, cidx_m,
-                            out_dtype=xres.dtype)
-    Jn, nu_new, dcost, its, cgs = _visit_solve(
-        cj, xdummy, coh_m, cidx_m, cmask_m, J_m, jnp.take(nuM, cj),
-        sta1, sta2, wt_base, n_stations, config, nerr_prev, weighted,
-        last, key, admm, os_id, total_iter, iter_bar)
-    nuM = nuM.at[cj].set(nu_new)
-    nerr_acc = nerr_acc.at[cj].set(dcost)
-    xres = xdummy - _model8(Jn, coh_m, sta1, sta2, cidx_m,
-                            out_dtype=xres.dtype)
-    J = J.at[cj].set(Jn)
+    # second-level scopes (sage/sweep/update, /inner, and /assemble
+    # from normal_eq.py): the model and running-residual update, the
+    # inner solve with its loop control, the normal-equation assembly
+    with jax.named_scope("update"):
+        xdummy = xres + _model8(J_m, coh_m, sta1, sta2, cidx_m,
+                                out_dtype=xres.dtype)
+    with jax.named_scope("inner"):
+        Jn, nu_new, dcost, its, cgs = _visit_solve(
+            cj, xdummy, coh_m, cidx_m, cmask_m, J_m, jnp.take(nuM, cj),
+            sta1, sta2, wt_base, n_stations, config, nerr_prev, weighted,
+            last, key, admm, os_id, total_iter, iter_bar)
+    with jax.named_scope("update"):
+        nuM = nuM.at[cj].set(nu_new)
+        nerr_acc = nerr_acc.at[cj].set(dcost)
+        xres = xdummy - _model8(Jn, coh_m, sta1, sta2, cidx_m,
+                                out_dtype=xres.dtype)
+        J = J.at[cj].set(Jn)
     return J, xres, nerr_acc, nuM, tk.at[0].add(its).at[2].add(cgs)
 
 
@@ -490,31 +496,34 @@ def _sweep_g1(perm, state, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
 
     c0 = cl_of(0)
     coh0, cidx0, _ = gather(c0)
-    xd = xres + _model8(jnp.take(J0_, c0, axis=0), coh0, sta1, sta2, cidx0,
-                        out_dtype=xres.dtype)
+    with jax.named_scope("update"):
+        xd = xres + _model8(jnp.take(J0_, c0, axis=0), coh0, sta1, sta2,
+                            cidx0, out_dtype=xres.dtype)
 
     def body(j, inner):
         J, xd, nerr_acc, nuM, tk = inner
         cj = cl_of(j)
         coh_m, cidx_m, cmask_m = gather(cj)
         J_m = jnp.take(J, cj, axis=0)
-        Jn, nu_new, dcost, its, cgs = _visit_solve(
-            cj, xd, coh_m, cidx_m, cmask_m, J_m, jnp.take(nuM, cj),
-            sta1, sta2, wt_base, n_stations, config, nerr_prev,
-            weighted, last, key, admm, os_id, total_iter, iter_bar)
-        nuM = nuM.at[cj].set(nu_new)
-        nerr_acc = nerr_acc.at[cj].set(dcost)
-        J = J.at[cj].set(Jn)
-        # next cluster's model from the UPDATED J (cl_of(j+1) != cj for
-        # j < M-1, so the update never aliases; the clamped last step's
-        # self-model is dropped by the where)
-        cn = cl_of(j + 1)
-        coh_n, cidx_n, _ = gather(cn)
-        model_next = _model8(jnp.take(J, cn, axis=0), coh_n, sta1, sta2,
-                             cidx_n, out_dtype=xd.dtype)
-        model_new = _model8(Jn, coh_m, sta1, sta2, cidx_m,
-                            out_dtype=xd.dtype)
-        xd = (xd - model_new) + jnp.where(j + 1 < M, model_next, 0.0)
+        with jax.named_scope("inner"):
+            Jn, nu_new, dcost, its, cgs = _visit_solve(
+                cj, xd, coh_m, cidx_m, cmask_m, J_m, jnp.take(nuM, cj),
+                sta1, sta2, wt_base, n_stations, config, nerr_prev,
+                weighted, last, key, admm, os_id, total_iter, iter_bar)
+        with jax.named_scope("update"):
+            nuM = nuM.at[cj].set(nu_new)
+            nerr_acc = nerr_acc.at[cj].set(dcost)
+            J = J.at[cj].set(Jn)
+            # next cluster's model from the UPDATED J (cl_of(j+1) != cj
+            # for j < M-1, so the update never aliases; the clamped last
+            # step's self-model is dropped by the where)
+            cn = cl_of(j + 1)
+            coh_n, cidx_n, _ = gather(cn)
+            model_next = _model8(jnp.take(J, cn, axis=0), coh_n, sta1,
+                                 sta2, cidx_n, out_dtype=xd.dtype)
+            model_new = _model8(Jn, coh_m, sta1, sta2, cidx_m,
+                                out_dtype=xd.dtype)
+            xd = (xd - model_new) + jnp.where(j + 1 < M, model_next, 0.0)
         return J, xd, nerr_acc, nuM, tk.at[0].add(its).at[2].add(cgs)
 
     J, xd, nerr_acc, nuM, tk = jax.lax.fori_loop(
@@ -610,75 +619,82 @@ def _group_update(cjs, state, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
                 os_id=ids, n_subsets=int(n_sub),
                 key=jax.random.fold_in(key, cj),
                 randomize=config.randomize)
-        xdummy = xres + _model8(J_m, coh_m, sta1, sta2, cidx_m,
-                                out_dtype=xres.dtype)
+        with jax.named_scope("update"):
+            xdummy = xres + _model8(J_m, coh_m, sta1, sta2, cidx_m,
+                                    out_dtype=xres.dtype)
         itcap = int(config.max_iter) + iter_bar
-        Jn, nu_new, init_cost, final_cost, its, cgs = _cluster_solve(
-            mode, xdummy, coh_m, sta1, sta2, cidx_m, cmask_m, wt_base,
-            J_m, n_stations, jnp.take(nuM, cj, mode="clip"), config,
-            itermax, itcap, admm_m, os_cfg, last)
+        with jax.named_scope("inner"):
+            Jn, nu_new, init_cost, final_cost, its, cgs = _cluster_solve(
+                mode, xdummy, coh_m, sta1, sta2, cidx_m, cmask_m, wt_base,
+                J_m, n_stations, jnp.take(nuM, cj, mode="clip"), config,
+                itermax, itcap, admm_m, os_cfg, last)
         return Jn, nu_new, init_cost, final_cost, its, cgs, xdummy
 
     Jn_g, nu_g, ic_g, fc_g, its_g, cgs_g, xd_g = jax.vmap(solve_one)(cjs)
-    Jo_g = jnp.take(J, cjs, axis=0)              # entering Jones (clipped)
-    coh_g = jnp.take(coh, cjs, axis=0)
-    cidx_g = jnp.take(chunk_idx, cjs, axis=0)
-    # entering models fall out of the solves' add-back (xdummy - xres):
-    # no second RIME evaluation needed
-    model_old = xd_g - xres[None]
-    vm = valid.astype(xres.dtype)
-    res_old = jnp.sum(dtp.acc(xres * wt_base) ** 2)
-    anchor = res_old if res_anchor is None else res_anchor
+    # the joint update: the damped trials, then the scatters of whatever
+    # was accepted
+    with jax.named_scope("update"):
+        Jo_g = jnp.take(J, cjs, axis=0)     # entering Jones (clipped)
+        coh_g = jnp.take(coh, cjs, axis=0)
+        cidx_g = jnp.take(chunk_idx, cjs, axis=0)
+        # entering models fall out of the solves' add-back (xdummy - xres):
+        # no second RIME evaluation needed
+        model_old = xd_g - xres[None]
+        vm = valid.astype(xres.dtype)
+        res_old = jnp.sum(dtp.acc(xres * wt_base) ** 2)
+        anchor = res_old if res_anchor is None else res_anchor
 
-    def try_omega(w):
-        # forwards to the module-level body: the cond branches below
-        # must not inline the model evaluations (priceability contract,
-        # see _omega_trial)
-        return _omega_trial(w, Jo_g, Jn_g, coh_g, cidx_g, sta1, sta2,
-                            xres, vm, model_old, wt_base, res_old,
-                            anchor)
+        def try_omega(w):
+            # forwards to the module-level body: the cond branches below
+            # must not inline the model evaluations (priceability contract,
+            # see _omega_trial)
+            return _omega_trial(w, Jo_g, Jn_g, coh_g, cidx_g, sta1, sta2,
+                                xres, vm, model_old, wt_base, res_old,
+                                anchor)
 
-    # first passing factor wins (largest safe step); the cond chain
-    # skips the smaller-step model evaluations when omega=1 passes —
-    # the common case (measured 3/8 at omega=1, 5/8 at 1/2)
-    ok1, x1, Jr1 = try_omega(1.0)
+        # first passing factor wins (largest safe step); the cond chain
+        # skips the smaller-step model evaluations when omega=1 passes —
+        # the common case (measured 3/8 at omega=1, 5/8 at 1/2)
+        ok1, x1, Jr1 = try_omega(1.0)
 
-    def fall1():
-        ok2, x2, Jr2 = try_omega(0.5)
+        def fall1():
+            ok2, x2, Jr2 = try_omega(0.5)
 
-        def fall2():
-            return try_omega(0.25)
+            def fall2():
+                return try_omega(0.25)
 
-        return jax.lax.cond(ok2, lambda: (ok2, x2, Jr2), fall2)
+            return jax.lax.cond(ok2, lambda: (ok2, x2, Jr2), fall2)
 
-    accept, xres_sel, Jr_sel = jax.lax.cond(
-        ok1, lambda: (ok1, x1, Jr1), fall1)
+        accept, xres_sel, Jr_sel = jax.lax.cond(
+            ok1, lambda: (ok1, x1, Jr1), fall1)
 
-    init_res = jnp.sum(ic_g, axis=-1)
-    final_res = jnp.sum(fc_g, axis=-1)
-    # dcost from the full-step solve costs: at omega < 1 this OVERSTATES
-    # the achieved reduction, but it only weights the next sweep's
-    # iteration allocation — acceptable
-    dcost = jnp.where(init_res > 0,
-                      jnp.maximum((init_res - final_res)
-                                  / jnp.maximum(init_res, 1e-30), 0.0),
-                      0.0)
-    # padded indices (cjs == M) are dropped by the scatters; a rejected
-    # group keeps the entering state entirely
-    nerr_acc = jnp.where(accept, nerr_acc.at[cjs].set(dcost), nerr_acc)
-    nuM = jnp.where(accept, nuM.at[cjs].set(nu_g), nuM)
-    J = jnp.where(accept, J.at[cjs].set(Jr_sel), J)
-    xres = jnp.where(accept, xres_sel, xres)
-    # tk[0]: useful-work iterations, summed over live lanes (a lower
-    # bound on executed trips — the G-wide batched loop runs until its
-    # slowest lane finishes; rejected groups still executed them).
-    # tk[1]: fully-rejected group steps — the observability hook for
-    # "groups are all vetoing" (info['rejected_groups']).
-    # tk[2]: executed PCG inner trips (inner="cg"), same live-lane sum.
-    tk = tk.at[0].add(jnp.sum(jnp.where(valid, its_g, 0)).astype(jnp.int32))
-    tk = tk.at[1].add((~accept).astype(jnp.int32))
-    tk = tk.at[2].add(jnp.sum(jnp.where(valid, cgs_g, 0)).astype(jnp.int32))
-    return J, xres, nerr_acc, nuM, tk
+        init_res = jnp.sum(ic_g, axis=-1)
+        final_res = jnp.sum(fc_g, axis=-1)
+        # dcost from the full-step solve costs: at omega < 1 this OVERSTATES
+        # the achieved reduction, but it only weights the next sweep's
+        # iteration allocation — acceptable
+        dcost = jnp.where(init_res > 0,
+                          jnp.maximum((init_res - final_res)
+                                      / jnp.maximum(init_res, 1e-30), 0.0),
+                          0.0)
+        # padded indices (cjs == M) are dropped by the scatters; a rejected
+        # group keeps the entering state entirely
+        nerr_acc = jnp.where(accept, nerr_acc.at[cjs].set(dcost), nerr_acc)
+        nuM = jnp.where(accept, nuM.at[cjs].set(nu_g), nuM)
+        J = jnp.where(accept, J.at[cjs].set(Jr_sel), J)
+        xres = jnp.where(accept, xres_sel, xres)
+        # tk[0]: useful-work iterations, summed over live lanes (a lower
+        # bound on executed trips — the G-wide batched loop runs until its
+        # slowest lane finishes; rejected groups still executed them).
+        # tk[1]: fully-rejected group steps — the observability hook for
+        # "groups are all vetoing" (info['rejected_groups']).
+        # tk[2]: executed PCG inner trips (inner="cg"), same live-lane sum.
+        tk = tk.at[0].add(
+            jnp.sum(jnp.where(valid, its_g, 0)).astype(jnp.int32))
+        tk = tk.at[1].add((~accept).astype(jnp.int32))
+        tk = tk.at[2].add(
+            jnp.sum(jnp.where(valid, cgs_g, 0)).astype(jnp.int32))
+        return J, xres, nerr_acc, nuM, tk
 
 
 _COLD_INFLIGHT = 2      # widest group proven safe from an identity start
@@ -804,15 +820,20 @@ def sagefit(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0, n_stations: int,
     if key is None:
         key = jax.random.PRNGKey(42)
 
-    xres0 = x8 - dtp.to_storage(
-        full_model8(J0, coh, sta1, sta2, chunk_idx), x8.dtype)
-    res_0 = jnp.linalg.norm(dtp.acc(xres0 * wt_base)) / n
+    # the four first-level scopes below (and the same names on the
+    # host-driven programs further down) are what a profiler trace of a
+    # solve is split by: metadata only, the programs are unchanged
+    with jax.named_scope("sage/prelude"):
+        xres0 = x8 - dtp.to_storage(
+            full_model8(J0, coh, sta1, sta2, chunk_idx), x8.dtype)
+        res_0 = jnp.linalg.norm(dtp.acc(xres0 * wt_base)) / n
 
     total_iter = M * config.max_iter
     iter_bar = int(-(-0.8 * total_iter // M))  # ceil(0.8/M * total), host-side
 
     G0, G = _inflight_widths(config, M)
 
+    @jax.named_scope("sage/sweep")
     def em_iter_width(ci, carry, Gi):
         J, xres, nerr, nuM, tk = carry
         weighted = (ci % 2 == 1) if config.randomize else jnp.asarray(False)
@@ -868,34 +889,36 @@ def sagefit(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0, n_stations: int,
     # skipped in ADMM mode (sagecal_slave.cpp passes max_lbfgs=0)
     lbfgs_k = jnp.zeros((), jnp.int32)
     if config.max_lbfgs > 0 and admm is None:
-        mode = config.jones_mode
-        npar8 = ne.jones_npar(mode)
-        shape = (M * kmax, n_stations, npar8)
-        Jflat = J.reshape(M * kmax, n_stations, 2, 2)
-        if mode == "full":
-            Jref = None
-            p0 = ne.jones_c2r(Jflat).reshape(-1).astype(dtype)
-        else:
-            Jref = ne.jones_constrain(Jflat, mode)
-            p0 = ne.params_from_jones(Jref, mode).reshape(-1).astype(dtype)
-        cost_fn = _refine_cost_fn(x8, coh, sta1, sta2, chunk_idx, wt_base,
-                                  shape, M, kmax, n_stations, robust,
-                                  mean_nu, mode=mode, Jref=Jref)
-        grad_fn = jax.grad(cost_fn)
-        p1, lbfgs_k = lbfgs_mod.lbfgs_fit(cost_fn, grad_fn, p0,
-                                          itmax=config.max_lbfgs,
-                                          M=config.lbfgs_m,
-                                          return_iters=True)
-        if mode == "full":
-            J = ne.jones_r2c(p1.reshape(shape)).reshape(
-                M, kmax, n_stations, 2, 2)
-        else:
-            J = ne.jones_from_params(p1.reshape(shape), mode,
-                                     Jref).reshape(M, kmax, n_stations,
-                                                   2, 2)
+        with jax.named_scope("sage/refine"):
+            mode = config.jones_mode
+            npar8 = ne.jones_npar(mode)
+            shape = (M * kmax, n_stations, npar8)
+            Jflat = J.reshape(M * kmax, n_stations, 2, 2)
+            if mode == "full":
+                Jref = None
+                p0 = ne.jones_c2r(Jflat).reshape(-1).astype(dtype)
+            else:
+                Jref = ne.jones_constrain(Jflat, mode)
+                p0 = ne.params_from_jones(Jref, mode).reshape(-1).astype(dtype)
+            cost_fn = _refine_cost_fn(x8, coh, sta1, sta2, chunk_idx, wt_base,
+                                      shape, M, kmax, n_stations, robust,
+                                      mean_nu, mode=mode, Jref=Jref)
+            grad_fn = jax.grad(cost_fn)
+            p1, lbfgs_k = lbfgs_mod.lbfgs_fit(cost_fn, grad_fn, p0,
+                                              itmax=config.max_lbfgs,
+                                              M=config.lbfgs_m,
+                                              return_iters=True)
+            if mode == "full":
+                J = ne.jones_r2c(p1.reshape(shape)).reshape(
+                    M, kmax, n_stations, 2, 2)
+            else:
+                J = ne.jones_from_params(p1.reshape(shape), mode,
+                                         Jref).reshape(M, kmax, n_stations,
+                                                       2, 2)
 
-    xres_f = x8 - full_model8(J, coh, sta1, sta2, chunk_idx)
-    res_1 = jnp.linalg.norm(dtp.acc(xres_f * wt_base)) / n
+    with jax.named_scope("sage/final"):
+        xres_f = x8 - full_model8(J, coh, sta1, sta2, chunk_idx)
+        res_1 = jnp.linalg.norm(dtp.acc(xres_f * wt_base)) / n
     return J, {"res_0": res_0, "res_1": res_1, "mean_nu": mean_nu,
                "nerr": nerr, "solver_iters": tk[0],
                "rejected_groups": tk[1], "cg_iters": tk[2],
@@ -910,6 +933,7 @@ def sagefit(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0, n_stations: int,
                    static_argnames=("n_stations", "config", "total_iter",
                                     "iter_bar", "os_nsub"),
                    donate_argnums=(1, 2, 3, 4))
+@jax.named_scope("sage/sweep")
 def _jit_cluster_update(cj, J, xres, nerr_acc, nuM, x8, coh, sta1, sta2,
                         chunk_idx, chunk_mask, wt_base, nerr_prev, weighted,
                         last, key, admm, os_ids, n_stations, config,
@@ -927,6 +951,7 @@ def _jit_cluster_update(cj, J, xres, nerr_acc, nuM, x8, coh, sta1, sta2,
                    static_argnames=("n_stations", "config", "total_iter",
                                     "iter_bar", "os_nsub"),
                    donate_argnums=(1, 2, 3, 4))
+@jax.named_scope("sage/sweep")
 def _jit_group_update(cjs, J, xres, nerr_acc, nuM, x8, coh, sta1, sta2,
                       chunk_idx, chunk_mask, wt_base, nerr_prev, weighted,
                       last, key, os_ids, n_stations, config, total_iter,
@@ -949,6 +974,7 @@ def _jit_group_update(cjs, J, xres, nerr_acc, nuM, x8, coh, sta1, sta2,
                    static_argnames=("n_stations", "config", "total_iter",
                                     "iter_bar", "os_nsub"),
                    donate_argnums=(0, 1, 2))
+@jax.named_scope("sage/sweep")
 def _jit_em_sweep(J, xres, nuM, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
                   wt_base, nerr_prev, weighted, last, kci, perm, os_ids,
                   n_stations, config, total_iter, iter_bar, os_nsub):
@@ -984,6 +1010,7 @@ def _jit_em_sweep(J, xres, nuM, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
 
 
 @jax.jit
+@jax.named_scope("sage/prelude")
 def _jit_prelude(x8, coh, sta1, sta2, chunk_idx, J0, wt_base):
     xres0 = x8 - dtp.to_storage(
         full_model8(J0, coh, sta1, sta2, chunk_idx), x8.dtype)
@@ -996,36 +1023,39 @@ def _jit_prelude(x8, coh, sta1, sta2, chunk_idx, J0, wt_base):
                    donate_argnums=(5,))
 def _jit_refine(x8, coh, sta1, sta2, chunk_idx, J, wt_base, mean_nu,
                 n_stations, config, robust):
-    M, kmax = J.shape[0], J.shape[1]
-    dtype = dtp.acc_dtype(x8.dtype)
-    mode = config.jones_mode
-    shape = (M * kmax, n_stations, ne.jones_npar(mode))
-    Jflat = J.reshape(M * kmax, n_stations, 2, 2)
-    if mode == "full":
-        Jref = None
-        p0 = ne.jones_c2r(Jflat).reshape(-1).astype(dtype)
-    else:
-        Jref = ne.jones_constrain(Jflat, mode)
-        p0 = ne.params_from_jones(Jref, mode).reshape(-1).astype(dtype)
-    cost_fn = _refine_cost_fn(x8, coh, sta1, sta2, chunk_idx, wt_base,
-                              shape, M, kmax, n_stations, robust, mean_nu,
-                              mode=mode, Jref=Jref)
-    p1, k = lbfgs_mod.lbfgs_fit(cost_fn, jax.grad(cost_fn), p0,
-                                itmax=config.max_lbfgs, M=config.lbfgs_m,
-                                return_iters=True)
-    if mode == "full":
-        Jn = ne.jones_r2c(p1.reshape(shape)).reshape(M, kmax, n_stations,
-                                                     2, 2)
-    else:
-        Jn = ne.jones_from_params(p1.reshape(shape), mode, Jref).reshape(
-            M, kmax, n_stations, 2, 2)
-    res = jnp.linalg.norm(dtp.acc(
-        (x8 - full_model8(Jn, coh, sta1, sta2, chunk_idx)) * wt_base)) \
-        / (x8.shape[0] * 8)
+    with jax.named_scope("sage/refine"):
+        M, kmax = J.shape[0], J.shape[1]
+        dtype = dtp.acc_dtype(x8.dtype)
+        mode = config.jones_mode
+        shape = (M * kmax, n_stations, ne.jones_npar(mode))
+        Jflat = J.reshape(M * kmax, n_stations, 2, 2)
+        if mode == "full":
+            Jref = None
+            p0 = ne.jones_c2r(Jflat).reshape(-1).astype(dtype)
+        else:
+            Jref = ne.jones_constrain(Jflat, mode)
+            p0 = ne.params_from_jones(Jref, mode).reshape(-1).astype(dtype)
+        cost_fn = _refine_cost_fn(x8, coh, sta1, sta2, chunk_idx, wt_base,
+                                  shape, M, kmax, n_stations, robust, mean_nu,
+                                  mode=mode, Jref=Jref)
+        p1, k = lbfgs_mod.lbfgs_fit(cost_fn, jax.grad(cost_fn), p0,
+                                    itmax=config.max_lbfgs, M=config.lbfgs_m,
+                                    return_iters=True)
+        if mode == "full":
+            Jn = ne.jones_r2c(p1.reshape(shape)).reshape(M, kmax, n_stations,
+                                                         2, 2)
+        else:
+            Jn = ne.jones_from_params(p1.reshape(shape), mode, Jref).reshape(
+                M, kmax, n_stations, 2, 2)
+    with jax.named_scope("sage/final"):
+        res = jnp.linalg.norm(dtp.acc(
+            (x8 - full_model8(Jn, coh, sta1, sta2, chunk_idx))
+            * wt_base)) / (x8.shape[0] * 8)
     return Jn, res, k
 
 
 @jax.jit
+@jax.named_scope("sage/final")
 def _jit_res(x8, coh, sta1, sta2, chunk_idx, J, wt_base):
     return jnp.linalg.norm(dtp.acc(
         (x8 - full_model8(J, coh, sta1, sta2, chunk_idx)) * wt_base)) \
@@ -1281,6 +1311,7 @@ def _jit_sagefit_tiles(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
                    static_argnames=("n_stations", "config", "total_iter",
                                     "iter_bar", "os_nsub"),
                    donate_argnums=(0, 1, 2))
+@jax.named_scope("sage/sweep")
 def _jit_em_sweep_tiles(J, xres, nuM, x8, coh, sta1, sta2, chunk_idx,
                         chunk_mask, wt_base, nerr_prev, weighted, last,
                         keys, perm, os_ids, n_stations, config, total_iter,
@@ -1547,6 +1578,7 @@ def sagefit_host_tiles(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
                    static_argnames=("n_stations", "config", "total_iter",
                                     "iter_bar", "os_nsub"),
                    donate_argnums=(1, 2, 3, 4))
+@jax.named_scope("sage/sweep")
 def _jit_cluster_update_tiles(cj, J, xres, nerr_acc, nuM, x8, coh, sta1,
                               sta2, chunk_idx, chunk_mask, wt_base,
                               nerr_prev, weighted, last, keys, os_ids,
@@ -1571,6 +1603,7 @@ def _jit_cluster_update_tiles(cj, J, xres, nerr_acc, nuM, x8, coh, sta1,
                    static_argnames=("n_stations", "config", "total_iter",
                                     "iter_bar", "os_nsub"),
                    donate_argnums=(1, 2, 3, 4))
+@jax.named_scope("sage/sweep")
 def _jit_group_update_tiles(cjs, J, xres, nerr_acc, nuM, x8, coh, sta1,
                             sta2, chunk_idx, chunk_mask, wt_base,
                             nerr_prev, weighted, last, keys, os_ids,

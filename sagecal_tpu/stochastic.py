@@ -623,10 +623,8 @@ def _tile_source(ms, cfg):
         n = min(n, cfg.max_timeslots)
 
     def src():
-        for ti, tile, wait in sched.Prefetcher(ms.read_tile, n,
-                                               depth=depth):
-            dtrace.emit("phase", name="io", tile=ti, dur_s=wait)
-            yield ti, tile, wait
+        # the io phase (the host's wait) is the Prefetcher's own
+        yield from sched.Prefetcher(ms.read_tile, n, depth=depth)
 
     return src(), depth
 
@@ -680,11 +678,8 @@ class StochasticStepper:
     # -- reader-thread half --------------------------------------------------
 
     def stage(self, ti, tile):
-        t_stage = time.perf_counter()
-        inputs, beam = self.rn.build_tile_inputs(tile)
-        dtrace.emit("phase", name="stage", tile=ti,
-                    dur_s=time.perf_counter() - t_stage,
-                    bg=self.depth > 0)
+        with dtrace.phase("stage", tile=ti, bg=self.depth > 0):
+            inputs, beam = self.rn.build_tile_inputs(tile)
         return {"inputs": inputs, "beam": beam}
 
     # -- device-owner half ---------------------------------------------------

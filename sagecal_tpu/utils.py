@@ -59,9 +59,17 @@ def setup_backend(platform: str | None = None,
       = highest``). The TPU's default is ONE bf16 pass, which left the
       f32 pipeline's final residuals at twice the CPU's on the same
       data (PERF.md "Bring-up on v5e"); the CPU backend computes f32
-      either way.
+      either way;
+    - the diag layer is wired to jax here, since it may not import it
+      itself: ``dtrace.phase`` gets ``jax.profiler.TraceAnnotation`` to
+      mark its spans on the profiler's clock, and ``diag.guard``'s
+      compile listeners are installed before anything compiles.
     """
     import jax
+    import jax.profiler
+    from sagecal_tpu.diag import guard, trace as dtrace
+    dtrace.set_annotator(jax.profiler.TraceAnnotation)
+    guard.install()
     if platform:
         jax.config.update("jax_platforms", platform)
     if cpu_devices:
@@ -74,6 +82,12 @@ def setup_backend(platform: str | None = None,
             cache = os.path.join(cache, f"cpu-{cpu_fingerprint()}")
         jax.config.update("jax_compilation_cache_dir", cache)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    # the cache key covers the operations' names (JAX strips them by
+    # default): an executable carries the op metadata it was compiled
+    # with, the profiler trace reads the scopes (sage/*, rime/*) from
+    # it, and a cache entry written before a scope existed must not be
+    # served in its place
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     jax.config.update("jax_default_matmul_precision", "highest")
     return cache
 

@@ -1,0 +1,467 @@
+"""ISSUE 26: names the trace can find.
+
+One host span on two clocks (``tm`` in the JSONL, a ``sagecal/<name>``
+annotation in the profiler's trace), the simulation loop's phases, a
+compile log that needs no persistent cache, ``cli --profile`` on a warm
+tile, and the device scopes (``sage/*``, ``rime/*``) in the lowered text
+of the programs the benchmark's cells run.
+"""
+
+import glob
+import os
+import re
+import subprocess
+import sys
+import textwrap
+import time
+
+import numpy as np
+import jax
+import jax.numpy as jnp
+import pytest
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+
+from sagecal_tpu.diag import guard, trace  # noqa: E402
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(autouse=True)
+def _clean_tracer():
+    """Every test leaves the tracer off and the annotator as it was."""
+    ann = trace._ANNOTATOR
+    yield
+    trace.disable()
+    trace.set_annotator(ann)
+    trace.set_profiling(False)
+
+
+# ---------------------------------------------------------------------------
+# part A: tm, the annotation, the null path
+# ---------------------------------------------------------------------------
+
+def test_every_record_carries_tm_on_the_perf_counter_clock(tmp_path):
+    path = tmp_path / "t.jsonl"
+    a = time.perf_counter()
+    trace.enable(str(path))
+    trace.emit("stage_bytes", bytes=3, what="x")
+    trace.disable()
+    b = time.perf_counter()
+    recs = trace.read(str(path))
+    assert [r["ev"] for r in recs] == ["run_start", "stage_bytes", "run_end"]
+    tms = [r["tm"] for r in recs]
+    assert tms == sorted(tms) and a <= tms[0] and tms[-1] <= b
+
+
+def test_phase_start_lies_inside_the_interval_taken_around_it(tmp_path):
+    path = tmp_path / "t.jsonl"
+    trace.enable(str(path))
+    a = time.perf_counter()
+    with trace.phase("solve", tile=7):
+        time.sleep(0.02)
+    b = time.perf_counter()
+    trace.disable()
+    ph = next(r for r in trace.read(str(path)) if r["ev"] == "phase")
+    assert ph["name"] == "solve" and ph["tile"] == 7
+    assert ph["dur_s"] >= 0.02
+    # tm is the span's end, tm - dur_s its start
+    assert a <= ph["tm"] - ph["dur_s"] <= ph["tm"] <= b
+
+
+def test_phase_is_the_shared_null_context_when_nothing_listens():
+    trace.set_annotator(jax.profiler.TraceAnnotation)
+    assert trace.phase("solve", tile=1) is trace._NULL_PHASE
+    assert trace.phase("io") is trace._NULL_PHASE
+    with trace.phase("io") as ph:       # the methods sites call exist
+        ph.drop()
+        ph.carve("arrival_wait", 0.1)
+    assert ph.dur_s == 0.0
+
+
+def test_phase_drop_and_carve(tmp_path):
+    path = tmp_path / "t.jsonl"
+    trace.enable(str(path))
+    with trace.phase("io", tile=1) as ph:
+        ph.drop()
+    with trace.phase("io", tile=2) as ph:
+        time.sleep(0.01)
+        ph.carve("arrival_wait", 0.004)
+    trace.disable()
+    phases = [r for r in trace.read(str(path)) if r["ev"] == "phase"]
+    assert [(r["name"], r["tile"]) for r in phases] == [
+        ("arrival_wait", 2), ("io", 2)]
+    assert phases[0]["dur_s"] == 0.004
+    assert 0.005 <= phases[1]["dur_s"] == pytest.approx(ph.dur_s)
+
+
+def test_trace_and_guard_import_with_jax_blocked():
+    """diag/trace.py's layering rule: stdlib only. The package's own
+    ``__init__`` imports jax, so a bare stand-in holds its place."""
+    code = textwrap.dedent(f"""
+        import importlib.abc, sys, types
+        class Block(importlib.abc.MetaPathFinder):
+            def find_spec(self, name, path, target=None):
+                if name.split(".")[0] in ("jax", "jaxlib", "numpy"):
+                    raise ImportError(name + " is blocked in this test")
+        sys.meta_path.insert(0, Block())
+        pkg = types.ModuleType("sagecal_tpu")
+        pkg.__path__ = [{os.path.join(ROOT, "sagecal_tpu")!r}]
+        sys.modules["sagecal_tpu"] = pkg
+        import sagecal_tpu.diag.trace as t
+        import sagecal_tpu.diag.guard as g
+        with t.phase("io"):
+            pass
+        assert not [m for m in sys.modules if m.split(".")[0] == "jax"]
+        print("imported")
+    """)
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=60)
+    assert out.returncode == 0 and "imported" in out.stdout, out.stderr
+
+
+def _host_spans(profile_dir):
+    from jax.profiler import ProfileData
+    found = glob.glob(os.path.join(profile_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    pd = ProfileData.from_file(max(found, key=os.path.getmtime))
+    return [(e.name, e.start_ns, e.duration_ns, dict(e.stats))
+            for pl in pd.planes if pl.name.startswith("/host:")
+            for ln in pl.lines for e in ln.events
+            if e.name.startswith("sagecal/")]
+
+
+def test_spans_appear_in_the_profile_and_agree_with_the_jsonl(tmp_path):
+    """The same span, once on each clock: the profiler's timestamps
+    start at the trace's own zero, so two spans are compared by the
+    distance between their starts."""
+    trace.set_annotator(jax.profiler.TraceAnnotation)
+    path = tmp_path / "t.jsonl"
+    prof = str(tmp_path / "prof")
+    jax.profiler.start_trace(prof)
+    trace.enable(str(path))
+    try:
+        with trace.phase("stage", tile=4):
+            time.sleep(0.01)
+        time.sleep(0.03)
+        with trace.phase("solve", tile=4):
+            jnp.ones((8,)).sum().block_until_ready()
+            time.sleep(0.01)
+    finally:
+        trace.disable()
+        jax.profiler.stop_trace()
+    spans = {name: (start, dur, stats)
+             for name, start, dur, stats in _host_spans(prof)}
+    assert {"sagecal/stage", "sagecal/solve"} <= set(spans)
+    assert spans["sagecal/solve"][2].get("tile") == 4
+    recs = {r["name"]: r for r in trace.read(str(path))
+            if r["ev"] == "phase"}
+    gap_jsonl = ((recs["solve"]["tm"] - recs["solve"]["dur_s"])
+                 - (recs["stage"]["tm"] - recs["stage"]["dur_s"]))
+    gap_prof = (spans["sagecal/solve"][0] - spans["sagecal/stage"][0]) * 1e-9
+    assert gap_jsonl >= 0.04
+    assert abs(gap_jsonl - gap_prof) < 5e-3
+    for name in ("stage", "solve"):
+        assert abs(spans["sagecal/" + name][1] * 1e-9
+                   - recs[name]["dur_s"]) < 5e-3
+
+
+def test_setup_backend_hands_the_annotator_and_installs_the_listeners():
+    """Checked in a child: setup_backend also picks a compile-cache
+    directory, which this process must not."""
+    code = textwrap.dedent("""
+        from sagecal_tpu import utils
+        from sagecal_tpu.diag import guard, trace
+        assert trace._ANNOTATOR is None and not guard._STATE["installed"]
+        utils.setup_backend("cpu")
+        import jax.profiler
+        assert trace._ANNOTATOR is jax.profiler.TraceAnnotation
+        assert guard._STATE["installed"]
+        print("wired")
+    """)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=os.devnull + ".d")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120, cwd=ROOT, env=env)
+    assert out.returncode == 0 and "wired" in out.stdout, out.stderr
+
+
+# ---------------------------------------------------------------------------
+# the simulation loop's vocabulary; the Prefetcher's own io phase
+# ---------------------------------------------------------------------------
+
+def test_run_simulation_emits_its_phases_and_one_tile_record_per_tile(
+        tmp_path):
+    from sagecal_tpu import cli
+    from test_diag import _make_sim_dataset
+
+    msdir, sky_file = _make_sim_dataset(tmp_path, n_tiles=3)
+    tr = tmp_path / "sim.jsonl"
+    rc = cli.main(["-d", str(msdir), "-s", str(sky_file),
+                   "-c", str(sky_file) + ".cluster", "-a", "1",
+                   "--diag", str(tr)])
+    assert rc == 0
+    recs = trace.read(str(tr))
+    phases = [r for r in recs if r["ev"] == "phase"]
+    for name in ("io", "stage", "predict", "fetch", "write"):
+        got = [r for r in phases if r["name"] == name]
+        assert len(got) == 3, (name, len(got))
+        if name != "io":        # the tile id comes out of next()
+            assert [r["tile"] for r in got] == [0, 1, 2]
+    tiles = [r for r in recs if r["ev"] == "tile"]
+    assert [r["tile"] for r in tiles] == [0, 1, 2]
+    by = {(r["name"], r.get("tile")): r["dur_s"] for r in phases}
+    ios = [r["dur_s"] for r in phases if r["name"] == "io"]
+    for k, r in enumerate(tiles):
+        assert r["overlap"] == 0
+        assert r["bubble_s"] == pytest.approx(ios[k] + by[("write", k)])
+    # every record of the run lies on the perf_counter clock, in order
+    tms = [r["tm"] for r in recs if r["ev"] != "phase"]
+    assert tms == sorted(tms)
+    st = trace.overlap_stats(recs)
+    assert st["tiles"] == 3 and st["overlap"] == 0 and st["bubble_s"] > 0
+
+
+def test_prefetcher_emits_the_consumers_io_phase_with_absolute_tile_ids(
+        tmp_path):
+    from sagecal_tpu import sched
+
+    for depth in (0, 1):
+        path = tmp_path / f"pf{depth}.jsonl"
+        trace.enable(str(path))
+        try:
+            pf = sched.Prefetcher(lambda i: i * 2, 3, depth=depth, tile0=5)
+            assert [(i, x) for i, x, _w in pf] == [(0, 0), (1, 2), (2, 4)]
+        finally:
+            trace.disable()
+        recs = [r for r in trace.read(str(path)) if r["ev"] == "phase"]
+        ios = [r for r in recs if r["name"] == "io"]
+        assert [r["tile"] for r in ios] == [5, 6, 7], depth
+        assert not any(r.get("bg") for r in ios)
+        bg = [r for r in recs if r.get("bg")]
+        assert [r["tile"] for r in bg] == ([5, 6, 7] if depth else [])
+        assert not any(r["name"] == "arrival_wait" for r in recs)
+
+
+# ---------------------------------------------------------------------------
+# part B: the compile log
+# ---------------------------------------------------------------------------
+
+def test_compile_log_names_a_fresh_function_once(tmp_path):
+    def issue26_fresh_function(a):
+        return a * 5 + 2
+
+    f = jax.jit(issue26_fresh_function)
+    n0 = guard.compiles_logged()
+    t0 = time.perf_counter()
+    trace.enable(str(tmp_path / "c.jsonl"))
+    f(jnp.ones((11,))).block_until_ready()
+    t1 = time.perf_counter()
+    mine = [r for r in guard.compile_log()
+            if "issue26_fresh_function" in r[2]]
+    stages = [r[1] for r in mine]
+    assert stages.count("backend_compile") == 1
+    assert stages.count("lower") == 1
+    tm, _stage, fun, dur = next(r for r in mine
+                                if r[1] == "backend_compile")
+    assert fun == "jit(issue26_fresh_function)"
+    assert dur > 0 and t0 <= tm - dur <= tm <= t1
+    n1 = guard.compiles_logged()
+    assert n1 > n0
+    f(jnp.ones((11,))).block_until_ready()      # cached: nothing logged
+    assert guard.compiles_logged() == n1
+    trace.disable()
+    # while a tracer was active the backend compile was also a record
+    comp = [r for r in trace.read(str(tmp_path / "c.jsonl"))
+            if r["ev"] == "compile" and "issue26_fresh" in r["fun"]]
+    assert len(comp) == 1 and comp[0]["tm"] == tm
+    assert comp[0]["dur_s"] == dur
+
+
+def test_compile_log_works_with_the_persistent_cache_off():
+    """``compile_count()`` counts cache requests and reads 0 with the
+    cache off; the log still names what was compiled."""
+    code = textwrap.dedent("""
+        import jax, jax.numpy as jnp
+        from sagecal_tpu.diag import guard
+        guard.install()
+        def cache_is_off(a):
+            return a - 1
+        f = jax.jit(cache_is_off)
+        f(jnp.ones((3,))).block_until_ready()
+        f(jnp.ones((3,))).block_until_ready()
+        mine = [r for r in guard.compile_log() if "cache_is_off" in r[2]]
+        print("COUNT", guard.compile_count())
+        # a trace is logged only past TRACE_LOG_FLOOR_S (a busy host)
+        print("LOGGED", sorted(r[1] for r in mine if r[1] != "trace"))
+    """)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_ENABLE_COMPILATION_CACHE="0")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120, cwd=ROOT, env=env)
+    assert out.returncode == 0, out.stderr
+    assert "COUNT 0" in out.stdout
+    assert "LOGGED ['backend_compile', 'lower']" in out.stdout
+
+
+def test_compile_log_is_bounded_and_skips_tiny_traces():
+    n = guard.compiles_logged()
+    guard._duration_listener("/jax/core/compile/jaxpr_trace_duration",
+                             guard.TRACE_LOG_FLOOR_S / 2, fun_name="tiny")
+    guard._duration_listener("/some/other/event", 3.0, fun_name="other")
+    assert guard.compiles_logged() == n
+    guard._duration_listener("/jax/core/compile/jaxpr_trace_duration",
+                             guard.TRACE_LOG_FLOOR_S * 2, fun_name="big")
+    assert guard.compiles_logged() == n + 1
+    assert guard.compile_log()[-1][1:3] == ("trace", "big")
+    assert guard._LOG.maxlen == guard.LOG_MAXLEN
+
+
+# ---------------------------------------------------------------------------
+# cli --profile: one warm tile
+# ---------------------------------------------------------------------------
+
+def test_profile_traces_the_first_tile_after_a_quiet_one(tmp_path,
+                                                         monkeypatch):
+    from sagecal_tpu import pipeline
+
+    calls, lines, logged = [], [], [0]
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda d: calls.append(("start", d)))
+    monkeypatch.setattr(jax.profiler, "stop_trace",
+                        lambda: calls.append(("stop",)))
+    monkeypatch.setattr(guard, "compiles_logged", lambda: logged[0])
+    prof = pipeline._WarmTileProfile("DIR", lines.append)
+    # tiles 0 and 1 compile, tile 2 is quiet, tile 3 is traced
+    for ti, compiles in enumerate((3, 1, 0, 0, 0)):
+        prof.enter_tile(ti)
+        if prof.state == "live":
+            assert trace.phase("solve") is not trace._NULL_PHASE
+        logged[0] += compiles
+        prof.leave_tile(ti)
+        calls.append(("tile", ti))
+    prof.stop()
+    assert calls == [("tile", 0), ("tile", 1), ("tile", 2),
+                     ("start", "DIR"), ("stop",), ("tile", 3), ("tile", 4)]
+    assert trace.phase("solve") is trace._NULL_PHASE
+    assert any("interval 3" in ln for ln in lines)
+    # a run whose every tile compiles says that no trace was written
+    lines.clear()
+    calls.clear()
+    prof = pipeline._WarmTileProfile("DIR", lines.append)
+    for ti in range(2):
+        prof.enter_tile(ti)
+        logged[0] += 1
+        prof.leave_tile(ti)
+    prof.stop()
+    assert calls == [] and "no trace written" in lines[-1]
+    # without --profile nothing is touched
+    off = pipeline._WarmTileProfile(None, lines.append)
+    off.enter_tile(0), off.leave_tile(0), off.stop()
+    assert calls == []
+
+
+# ---------------------------------------------------------------------------
+# part C: the scopes are in the lowered text
+# ---------------------------------------------------------------------------
+
+def _solve_args():
+    from sagecal_tpu.solvers import sage
+    rng = np.random.default_rng(3)
+    N, M, K, tsz = 5, 2, 1, 4
+    pairs = [(i, j) for i in range(N) for j in range(i + 1, N)]
+    B = len(pairs) * tsz
+    sta1 = jnp.asarray(np.tile([p[0] for p in pairs], tsz), jnp.int32)
+    sta2 = jnp.asarray(np.tile([p[1] for p in pairs], tsz), jnp.int32)
+    coh = jnp.asarray(rng.normal(size=(M, B, 2, 2))
+                      + 1j * rng.normal(size=(M, B, 2, 2)))
+    cidx = jnp.zeros((M, B), jnp.int32)
+    cmask = jnp.ones((M, K), bool)
+    J0 = jnp.asarray(np.tile(np.eye(2, dtype=np.complex128),
+                             (M, K, N, 1, 1)))
+    x8 = sage.full_model8(J0, coh, sta1, sta2, cidx)
+    wt = jnp.ones((B, 8), jnp.float64)
+    return x8, coh, sta1, sta2, cidx, cmask, J0, N, wt
+
+
+@pytest.fixture(scope="module")
+def lowered_programs(tmp_path_factory):
+    """The compiled text (its ``op_name`` metadata is what the profiler
+    shows) of every program a tiny solve ran, under the promoted plan
+    and the host-driven one (``sage._PROGRAM_CALLS`` keeps
+    each program's jitted function and argument skeleton), and of a
+    simulate program."""
+    from sagecal_tpu.config import SolverMode
+    from sagecal_tpu.rime import predict as rp, residual as rr
+    from sagecal_tpu.solvers import sage
+
+    x8, coh, sta1, sta2, cidx, cmask, J0, N, wt = _solve_args()
+    texts = {}
+    for plan in (dict(promote="on"), dict(promote="off", fuse="on"),
+                 dict(promote="off", fuse="off")):
+        sage.program_stats_reset()
+        cfg = sage.SageConfig(max_emiter=1, max_iter=2, max_lbfgs=2,
+                              solver_mode=int(SolverMode.RTR_OSRLM_RLBFGS),
+                              **plan)
+        J, _info = sage.sagefit_host(x8, coh, sta1, sta2, cidx, cmask, J0,
+                                     N, wt, config=cfg)
+        jax.block_until_ready(J)
+        for name, (jfn, (args, kwargs), _n) in sage.program_stats().items():
+            texts[name] = jfn.lower(*args, **kwargs).compile().as_text()
+    sage.program_stats_reset()
+
+    import math
+    from sagecal_tpu import skymodel
+    d = tmp_path_factory.mktemp("sky")
+    (d / "sky.txt").write_text(
+        "P0A 0 40 0 40 0 0 3.0 0 0 0 0 0 0 0 0 150e6\n"
+        "P1A 0 42 0 41 0 0 1.0 0 0 0 0 0 0 0 0 150e6\n")
+    (d / "sky.txt.cluster").write_text("0 1 P0A\n1 1 P1A\n")
+    sky = skymodel.read_sky_cluster(
+        str(d / "sky.txt"), str(d / "sky.txt.cluster"),
+        (41 / 60) * math.pi / 12, 40 * math.pi / 180, 150e6)
+    dsky = rp.sky_to_device(sky, jnp.float64)
+    B = x8.shape[0]
+    u = jnp.linspace(1e-6, 2e-6, B)
+    Jc = jnp.tile(jnp.eye(2, dtype=jnp.complex128),
+                  (sky.n_clusters, 1, N, 1, 1))
+
+    def sim(x, J):
+        return rr.simulate_visibilities(
+            dsky, x, u, 2 * u, 0.1 * u, jnp.asarray([150e6]), 1e5, sta1,
+            sta2, mode=3, J=J)
+
+    texts["simulate"] = jax.jit(sim).lower(
+        jnp.zeros((B, 1, 2, 2), jnp.complex128), Jc).compile().as_text()
+    return texts
+
+
+@pytest.mark.parametrize("program, scopes", [
+    ("sagefit", ("sage/prelude", "sage/sweep", "sage/refine", "sage/final",
+                 "sage/sweep/inner", "sage/sweep/update",
+                 "sage/sweep/assemble", "sage/refine/linesearch",
+                 "sage/refine/direction")),
+    ("em_sweep", ("sage/sweep", "sage/sweep/inner", "sage/sweep/update",
+                  "sage/sweep/assemble")),
+    ("cluster_update", ("sage/sweep", "sage/sweep/inner",
+                        "sage/sweep/update", "sage/sweep/assemble")),
+    ("prelude", ("sage/prelude",)),
+    ("refine", ("sage/refine", "sage/refine/linesearch",
+                "sage/refine/direction", "sage/final")),
+    ("simulate", ("rime/phasor", "rime/corrupt", "rime/residual")),
+])
+def test_lowered_text_names_the_scopes(lowered_programs, program, scopes):
+    """A second-level name sits further down its first level's path
+    (``sage/refine/while/body/linesearch/mul``): loops and calls come
+    between."""
+    text = "\n".join(re.findall(r'op_name="([^"]*)"',
+                                lowered_programs[program]))
+    for scope in scopes:
+        first, _, second = scope.partition("/")[2].partition("/")
+        first = scope.split("/")[0] + "/" + first
+        pattern = re.escape(first) + (
+            r'/[^"\n]*\b' + re.escape(second) + "/" if second else "/")
+        assert re.search(pattern, text), (program, scope)
+    if program != "simulate":
+        # the solve programs' operations sit under sage/*, not beside it
+        assert "rime/residual" not in text

@@ -854,8 +854,10 @@ def cg_trip_cost(kmax, n_stations, B, dtype, nbase=0, kernel="xla",
 
 def refine_trip_cost(M, kmax, n_stations, B, robust, dtype):
     """FLOPs + bytes of ONE joint-refine LBFGS iteration: cost + gradient
-    of the all-cluster objective (sage._refine_cost_fn). Line-search
-    evaluations beyond the mandatory one per iteration are not counted."""
+    of the all-cluster objective (sage._refine_cost_fn). The line search
+    is not counted: its restriction (a jvp and a model evaluation, about
+    one more gradient's worth) in the linear Jones modes, its trials
+    through the whole model in ``phase`` mode."""
     key = ("refine", M, kmax, n_stations, B, bool(robust), str(dtype))
     if key in _TRIP_CACHE:
         return _TRIP_CACHE[key]
@@ -871,7 +873,7 @@ def refine_trip_cost(M, kmax, n_stations, B, robust, dtype):
     shape = (M * kmax, n_stations, 8)
     try:
         def cg(p, x8, coh, s1, s2, cidx, wt):
-            cost_fn = sage_mod._refine_cost_fn(
+            cost_fn, _line_fn = sage_mod._refine_cost_fn(
                 x8, coh, s1, s2, cidx, wt, shape, M, kmax, n_stations,
                 robust, 5.0)
             return jax.value_and_grad(cost_fn)(p)

@@ -44,7 +44,9 @@ event            meaning / required extra fields
                  simulated tile, ``run_simulation``, solves nothing and
                  carries only ``tile`` and the overlap pair); optional
                  ``mean_nu``, ``solver_iters``, ``lbfgs_iters``,
-                 ``minutes``, ``primal``, ``rho_mean``, and the
+                 ``refine_passes`` (passes through the model the joint
+                 refine made: solvers/lbfgs.py), ``minutes``,
+                 ``primal``, ``rho_mean``, and the
                  overlap accounting pair ``bubble_s`` (host seconds
                  blocked on data movement for this tile: io wait +
                  write wait/backpressure) / ``overlap`` (the prefetch

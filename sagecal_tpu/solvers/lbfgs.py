@@ -17,6 +17,13 @@ through ``lax.while_loop``; cost/grad are arbitrary jit-traceable closures
 full-batch path uses the Fletcher cubic/zoom line search with the
 reference's parameters; the stochastic path uses Armijo backtracking, the
 variant the reference uses in production minibatch mode.
+
+A caller whose cost is cheap to restrict to a line may hand the full-batch
+path a third closure, ``line_func(xk, pk) -> (a -> (phi(a), dphi(a)))``,
+built once per iteration; the Fletcher search then evaluates its trial
+steps on that restriction and calls cost/grad for no trial (the joint
+refine of ``solvers/sage.py`` does, where the model is a polynomial in the
+step). The loop counts its passes through the caller's model either way.
 """
 
 from __future__ import annotations
@@ -27,6 +34,11 @@ import jax
 import jax.numpy as jnp
 
 _EPS = 1e-15
+#: what the pass counter charges for one ``line_func(xk, pk)``: the cost's
+#: first directional derivative at ``xk`` (one ``jvp``) and one plain
+#: evaluation at ``pk``, which is all an exact restriction of a model
+#: quadratic in the step needs
+LINE_FUNC_PASSES = 2
 
 
 class LBFGSMemory(NamedTuple):
@@ -122,25 +134,42 @@ def linesearch_backtrack(cost_func: Callable, xk, pk, gk, alpha0,
 def linesearch_fletcher(cost_func, grad_func, xk, pk, gk=None,
                         alpha1: float = 10.0, sigma: float = 0.1,
                         rho: float = 0.01, t1: float = 9.0, t2: float = 0.1,
-                        t3: float = 0.5):
+                        t3: float = 0.5, on_line=None):
     """Fletcher line search with cubic interpolation (lbfgs.c:116-443:
     ``cubic_interp`` / ``linesearch_zoom`` / ``linesearch``), used by the
     full-batch path with the reference's parameters (lbfgs.c:572).
+    Returns ``(alpha, passes)``: the step, and how many times the search
+    went through ``cost_func`` or ``grad_func`` (int32).
 
     Deviations from the reference: directional derivatives are exact
     (``grad . pk``) instead of central finite differences, and the cubic
     minimizer evaluates the trial point at ``z0`` itself (the reference's
     mixed absolute/fractional use of ``z0`` evaluates at a+z0(b-a) while
-    bounds-checking z0 in alpha units).
+    bounds-checking z0 in alpha units). Where the caller has the cost
+    restricted to this line (``on_line``: ``a -> (phi(a), dphi(a))``, what
+    ``line_func(xk, pk)`` returned), every trial reads the two halves of
+    that one function, ``cost_func`` and ``grad_func`` are not called and
+    ``passes`` is 0: the same search over the same function, evaluated
+    where it is cheap (the reference walks the full cost at every trial).
     """
     dtype = xk.dtype
     eps = jnp.asarray(1e-30, dtype)
 
-    def phi(a):
-        return cost_func(xk + a * pk)
+    if on_line is None:
+        def phi(a):
+            return cost_func(xk + a * pk)
 
-    def dphi(a):
-        return jnp.dot(grad_func(xk + a * pk), pk)
+        def dphi(a):
+            return jnp.dot(grad_func(xk + a * pk), pk)
+    else:
+        # XLA drops the half a site does not use
+        def phi(a):
+            return on_line(a)[0]
+
+        def dphi(a):
+            return on_line(a)[1]
+    # passes through the caller's model that one phi or dphi makes
+    unit = 1 if on_line is None else 0
 
     phi_0 = phi(jnp.asarray(0.0, dtype))
     # reuse the caller's gradient at xk when given (saves one full
@@ -174,11 +203,11 @@ def linesearch_fletcher(cost_func, grad_func, xk, pk, gk=None,
     # --- phase 1: bracketing (linesearch:298-420). state codes:
     # 0 continue, 1 found alphak, 2 zoom(aj, bj)
     def p1_cond(s):
-        ci, alphai, alphai1, phi_i1, alphak, code, aj, bj = s
+        ci, alphai, alphai1, phi_i1, alphak, code, aj, bj, npass = s
         return (ci < 10) & (code == 0)
 
     def p1_body(s):
-        ci, alphai, alphai1, phi_i1, alphak, code, aj, bj = s
+        ci, alphai, alphai1, phi_i1, alphak, code, aj, bj, npass = s
         phi_i = phi(alphai)
         cond0 = phi_i < tol
         cond1 = (phi_i > phi_0 + alphai * gphi_0) | ((ci > 1)
@@ -198,38 +227,46 @@ def linesearch_fletcher(cost_func, grad_func, xk, pk, gk=None,
         bj_n = jnp.where(cond1, alphai, jnp.where(cond3, alphai1, bj))
 
         # advance: next alpha by mu or cubic in the extended interval;
-        # cubic costs ~5 cost/grad evals, so only run it when the branch
-        # is live (linesearch:409-416 evaluates it only in the else)
+        # cubic is 3 cost and 2 gradient evaluations, so only run it when
+        # the branch is live (linesearch:409-416 evaluates it only in the
+        # else)
         take_mu = mu <= (2.0 * alphai - alphai1)
         lo = 2.0 * alphai - alphai1
         hi = jnp.minimum(mu, alphai + t1 * (alphai - alphai1))
+        skip_cubic = take_mu | (code_n != 0)
         alpha_adv = jax.lax.cond(
-            take_mu | (code_n != 0), lambda: mu,
+            skip_cubic, lambda: mu,
             # jaxlint: disable=cond-cost -- cubic's phi/dphi are
-            # closure-bound (cost_func), so a module-level split could
-            # not be priced standalone either; the both-branches
-            # overstatement is bounded by ~5 small cost evals per trip
-            # and noted in bench refine_trip_cost
+            # closure-bound, so a module-level split could not be priced
+            # standalone either. What pricing both branches overstates:
+            # without ``on_line`` five passes through the caller's whole
+            # model a trip (the joint refine's: all clusters, twice with
+            # a backward pass), with it five passes over the restricted
+            # residual, which is nothing; noted in bench refine_trip_cost
             lambda: cubic(lo, hi))
         alphai1_n = jnp.where(code_n == 0, alphai, alphai1)
         alphai_n = jnp.where(code_n == 0, alpha_adv, alphai)
         phi_i1_n = jnp.where(code_n == 0, phi_i, phi_i1)
+        npass_n = npass + unit * jnp.where(skip_cubic, 2, 7)
         return (ci + 1, alphai_n, alphai1_n, phi_i1_n, alphak_n, code_n,
-                aj_n, bj_n)
+                aj_n, bj_n, npass_n)
 
     z = jnp.asarray(0.0, dtype)
-    ci, alphai, alphai1, phi_i1, alphak, code, aj, bj = jax.lax.while_loop(
+    # phi_0 above is the first pass; gphi_0 one more where gk is not given
+    npass0 = jnp.asarray(unit * (1 if gk is not None else 2), jnp.int32)
+    (ci, alphai, alphai1, phi_i1, alphak, code, aj, bj,
+     npass) = jax.lax.while_loop(
         p1_cond, p1_body,
         (jnp.asarray(1, jnp.int32), jnp.asarray(alpha1, dtype), z, phi_0,
-         jnp.asarray(1.0, dtype), jnp.asarray(0, jnp.int32), z, z))
+         jnp.asarray(1.0, dtype), jnp.asarray(0, jnp.int32), z, z, npass0))
 
     # --- phase 2: zoom (linesearch_zoom:211-284), only when code == 2
     def p2_cond(s):
-        cj, aj, bj, alphaj, found = s
+        cj, aj, bj, alphaj, found, npass = s
         return (cj < 10) & ~found
 
     def p2_body(s):
-        cj, aj, bj, alphaj, found = s
+        cj, aj, bj, alphaj, found, npass = s
         alphaj_n = cubic(aj + t2 * (bj - aj), bj - t3 * (bj - aj))
         phi_j = phi(alphaj_n)
         phi_aj = phi(aj)
@@ -247,19 +284,22 @@ def linesearch_fletcher(cost_func, grad_func, xk, pk, gk=None,
         # every batch element finds its alpha, and a found alphaj must
         # not drift with further bracket updates
         upd = ~found
+        # cubic's five, phi_j, phi_aj and gphi_j
         return (cj + 1, jnp.where(upd, aj_n, aj), jnp.where(upd, bj_n, bj),
-                jnp.where(upd, alphaj_n, alphaj), found | found_n)
+                jnp.where(upd, alphaj_n, alphaj), found | found_n,
+                npass + unit * jnp.where(upd, 8, 0))
 
-    _, _, _, alphaj, _ = jax.lax.while_loop(
+    _, _, _, alphaj, _, npass = jax.lax.while_loop(
         p2_cond, p2_body,
         (jnp.asarray(0, jnp.int32), aj, bj, jnp.asarray(1.0, dtype),
-         code != 2))
+         code != 2, npass))
 
     alpha_out = jnp.where(code == 1, alphak,
                           jnp.where(code == 2, alphaj, alphai))
     # degenerate slope: hand back mu (caller's bad-alpha check stops the
     # iteration, matching the reference's !isnormal(mu) early return)
-    return jnp.where(jnp.isfinite(mu) & (jnp.abs(mu) > 0), alpha_out, mu)
+    return jnp.where(jnp.isfinite(mu) & (jnp.abs(mu) > 0), alpha_out,
+                     mu), npass
 
 
 class _IterState(NamedTuple):
@@ -269,10 +309,17 @@ class _IterState(NamedTuple):
     alphabar: jax.Array
     k: jax.Array
     done: jax.Array
+    passes: jax.Array        # passes through the caller's model so far
 
 
 def _lbfgs_loop(cost_func, grad_func, x0, mem0: LBFGSMemory, itmax: int,
-                stochastic: bool, force_backtrack: bool = False):
+                stochastic: bool, force_backtrack: bool = False,
+                line_func=None):
+    """Returns (x, memory, iterations, passes). ``passes`` counts what
+    went through the caller's model: every cost and gradient evaluation
+    of the loop and of the Fletcher search, and ``LINE_FUNC_PASSES`` for
+    every restriction built (the Armijo search returns no count: its
+    cost evaluations are left out)."""
     g0 = grad_func(x0)
 
     def cond(s: _IterState):
@@ -305,11 +352,16 @@ def _lbfgs_loop(cost_func, grad_func, x0, mem0: LBFGSMemory, itmax: int,
             # production stochastic path uses Armijo backtracking
             # (lbfgs.c:444 linesearch_backtrack)
             alphak = linesearch_backtrack(cost_func, s.x, pk, s.g, alphabar)
+            ls_passes = 0
         else:
             # full-batch path uses the Fletcher search with the
-            # reference's parameters (lbfgs.c:572)
-            alphak = linesearch_fletcher(cost_func, grad_func, s.x, pk,
-                                         gk=s.g)
+            # reference's parameters (lbfgs.c:572), on the caller's
+            # restriction of the cost to this line where there is one
+            on_line = None if line_func is None else line_func(s.x, pk)
+            alphak, ls_passes = linesearch_fletcher(
+                cost_func, grad_func, s.x, pk, gk=s.g, on_line=on_line)
+            if line_func is not None:
+                ls_passes = ls_passes + LINE_FUNC_PASSES
         bad_alpha = ~jnp.isfinite(alphak) | (jnp.abs(alphak) < 1e-12)
         x1 = s.x + alphak * pk
         g1 = grad_func(x1)
@@ -340,30 +392,38 @@ def _lbfgs_loop(cost_func, grad_func, x0, mem0: LBFGSMemory, itmax: int,
         frozen = bad_alpha | s.done
         x_out = jnp.where(frozen, s.x, x1)
         g_out = jnp.where(frozen, s.g, g1)
+        # the search's passes and g1's, frozen like the rest once done
+        passes = s.passes + jnp.where(s.done, 0, ls_passes + 1)
         return _IterState(x=x_out, g=g_out, mem=mem, alphabar=alphabar,
-                          k=s.k + 1, done=done)
+                          k=s.k + 1, done=done, passes=passes)
 
     init = _IterState(
         x=x0, g=g0, mem=mem0,
         alphabar=jnp.asarray(1.0, x0.dtype),
         k=jnp.zeros((), jnp.int32),
-        done=jnp.linalg.norm(g0) < _EPS)
+        done=jnp.linalg.norm(g0) < _EPS,
+        passes=jnp.ones((), jnp.int32))     # g0
     out = jax.lax.while_loop(cond, body, init)
-    return out.x, out.mem, out.k
+    return out.x, out.mem, out.k, out.passes
 
 
 def lbfgs_fit(cost_func, grad_func, p0, itmax: int = 20, M: int = 7,
-              linesearch: str = "fletcher", return_iters: bool = False):
+              linesearch: str = "fletcher", return_iters: bool = False,
+              line_func=None):
     """Full-batch LBFGS (lbfgs_fit, lbfgs.c:933): fresh memory each call.
 
     ``linesearch``: "fletcher" (reference full-batch default) or
-    "backtrack" (Armijo). ``return_iters`` additionally returns the
-    executed iteration count (bench.py MFU trip accounting)."""
+    "backtrack" (Armijo). ``line_func(xk, pk)``, where the caller has
+    one, returns the cost restricted to the line ``xk + a pk`` as
+    ``a -> (phi(a), dphi(a))``; the Fletcher search then runs its trials
+    on it. ``return_iters`` additionally returns the executed iteration
+    count (bench.py MFU trip accounting) and the passes through the
+    caller's model (``_lbfgs_loop``)."""
     mem = lbfgs_memory_init(p0.shape[0], M, p0.dtype)
-    x, _, k = _lbfgs_loop(cost_func, grad_func, p0, mem, itmax,
-                          stochastic=False,
-                          force_backtrack=(linesearch == "backtrack"))
-    return (x, k) if return_iters else x
+    x, _, k, passes = _lbfgs_loop(
+        cost_func, grad_func, p0, mem, itmax, stochastic=False,
+        force_backtrack=(linesearch == "backtrack"), line_func=line_func)
+    return (x, k, passes) if return_iters else x
 
 
 def lbfgs_fit_minibatch(cost_func, grad_func, p0, mem: LBFGSMemory,
@@ -372,4 +432,4 @@ def lbfgs_fit_minibatch(cost_func, grad_func, p0, mem: LBFGSMemory,
     (lbfgs_fit_minibatch, lbfgs.c:717). Returns (p, updated memory,
     executed iteration count)."""
     return _lbfgs_loop(cost_func, grad_func, p0, mem, itmax,
-                       stochastic=True)
+                       stochastic=True)[:3]

@@ -41,7 +41,7 @@ from sagecal_tpu.solvers import normal_eq as ne
 #: executed-iteration counters a solver info dict may carry; the keys
 #: the host-side telemetry (diag tile records, obs trip counters, the
 #: bench's trip-corrected roofline) reads through executed_trips()
-TRIP_KEYS = ("solver_iters", "cg_iters", "lbfgs_iters",
+TRIP_KEYS = ("solver_iters", "cg_iters", "lbfgs_iters", "refine_passes",
              "rejected_groups")
 
 

@@ -748,27 +748,55 @@ def _cluster_perm(ci, nerr_prev, weighted, key, M: int,
 def _refine_cost_fn(x8, coh, sta1, sta2, chunk_idx, wt_base, shape, M, kmax,
                     n_stations, robust: bool, mean_nu, mode: str = "full",
                     Jref=None):
+    """The joint refine's cost and its restriction to a search line:
+    ``(cost_fn, line_func)``, the two closures ``lbfgs.lbfgs_fit`` takes.
+
+    ``line_func`` is None where the parameters do not enter the Jones
+    matrices linearly (``phase``: J = Jref exp(i theta)). Where they do
+    (``full``, ``diag``) the model ``sum_m J_p C_m J_q^H`` is a
+    homogeneous quadratic in them, so along ``p = xk + a pk`` every
+    row's weighted residual is exactly ``r0 - a V1 - a^2 V2`` and a trial
+    step of the search is one elementwise pass over three [B, 8] arrays.
+    """
     # mode != "full": ``shape`` is the reduced (M*kmax, N, npar) layout
     # and Jref [M*kmax, N, 2, 2] carries the constrained reference
     # point (amplitudes for the phase retraction J = Jref * exp(i θ))
-    def p_to_Jr(p):
-        if mode == "full":
-            return ne.jones_r2c(p.reshape(shape)).reshape(
-                M, kmax, n_stations, 2, 2)
-        return ne.jones_from_params(p.reshape(shape), mode, Jref).reshape(
+    def model(p):
+        Jr = ne.jones_from_params(p.reshape(shape), mode, Jref).reshape(
             M, kmax, n_stations, 2, 2)
+        return full_model8(Jr, coh, sta1, sta2, chunk_idx)
 
-    if robust:
-        def cost_fn(p):
-            Jr = p_to_Jr(p)
-            r = (x8 - full_model8(Jr, coh, sta1, sta2, chunk_idx)) * wt_base
+    def cost_of(r):
+        if robust:
             return jnp.sum(jnp.log1p(r * r / mean_nu))
-    else:
-        def cost_fn(p):
-            Jr = p_to_Jr(p)
-            r = (x8 - full_model8(Jr, coh, sta1, sta2, chunk_idx)) * wt_base
-            return jnp.sum(r * r)
-    return cost_fn
+        return jnp.sum(r * r)
+
+    def cost_fn(p):
+        return cost_of((x8 - model(p)) * wt_base)
+
+    if mode == "phase":
+        return cost_fn, None
+
+    def line_func(xk, pk):
+        with jax.named_scope("restrict"):
+            # V1 from the derivative itself: model(xk + pk) - model(xk)
+            # - model(pk) cancels in float32 when |pk| << |xk|
+            m0, dm = jax.jvp(model, (xk,), (pk,))
+            # the model accumulates in the accumulation dtype
+            # (full_model8), so the three arrays are in it whatever
+            # x8 and wt_base are stored in
+            r0 = (x8 - m0) * wt_base
+            v1 = dm * wt_base
+            v2 = model(pk) * wt_base
+
+        def on_line(a):
+            r = r0 - a * (v1 + a * v2)
+            # d/da of cost_of(r(a)), r' = -(V1 + 2 a V2)
+            w = r / (mean_nu + r * r) if robust else r
+            return cost_of(r), -2.0 * jnp.sum(w * (v1 + 2.0 * a * v2))
+        return on_line
+
+    return cost_fn, line_func
 
 
 def sagefit(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0, n_stations: int,
@@ -887,7 +915,7 @@ def sagefit(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0, n_stations: int,
 
     # joint LBFGS refine over all parameters (lmfit.c:1019-1037);
     # skipped in ADMM mode (sagecal_slave.cpp passes max_lbfgs=0)
-    lbfgs_k = jnp.zeros((), jnp.int32)
+    lbfgs_k = passes = jnp.zeros((), jnp.int32)
     if config.max_lbfgs > 0 and admm is None:
         with jax.named_scope("sage/refine"):
             mode = config.jones_mode
@@ -900,14 +928,12 @@ def sagefit(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0, n_stations: int,
             else:
                 Jref = ne.jones_constrain(Jflat, mode)
                 p0 = ne.params_from_jones(Jref, mode).reshape(-1).astype(dtype)
-            cost_fn = _refine_cost_fn(x8, coh, sta1, sta2, chunk_idx, wt_base,
-                                      shape, M, kmax, n_stations, robust,
-                                      mean_nu, mode=mode, Jref=Jref)
-            grad_fn = jax.grad(cost_fn)
-            p1, lbfgs_k = lbfgs_mod.lbfgs_fit(cost_fn, grad_fn, p0,
-                                              itmax=config.max_lbfgs,
-                                              M=config.lbfgs_m,
-                                              return_iters=True)
+            cost_fn, line_fn = _refine_cost_fn(
+                x8, coh, sta1, sta2, chunk_idx, wt_base, shape, M, kmax,
+                n_stations, robust, mean_nu, mode=mode, Jref=Jref)
+            p1, lbfgs_k, passes = lbfgs_mod.lbfgs_fit(
+                cost_fn, jax.grad(cost_fn), p0, itmax=config.max_lbfgs,
+                M=config.lbfgs_m, return_iters=True, line_func=line_fn)
             if mode == "full":
                 J = ne.jones_r2c(p1.reshape(shape)).reshape(
                     M, kmax, n_stations, 2, 2)
@@ -922,7 +948,7 @@ def sagefit(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0, n_stations: int,
     return J, {"res_0": res_0, "res_1": res_1, "mean_nu": mean_nu,
                "nerr": nerr, "solver_iters": tk[0],
                "rejected_groups": tk[1], "cg_iters": tk[2],
-               "lbfgs_iters": lbfgs_k}
+               "lbfgs_iters": lbfgs_k, "refine_passes": passes}
 
 
 # ---------------------------------------------------------------------------
@@ -1035,12 +1061,12 @@ def _jit_refine(x8, coh, sta1, sta2, chunk_idx, J, wt_base, mean_nu,
         else:
             Jref = ne.jones_constrain(Jflat, mode)
             p0 = ne.params_from_jones(Jref, mode).reshape(-1).astype(dtype)
-        cost_fn = _refine_cost_fn(x8, coh, sta1, sta2, chunk_idx, wt_base,
-                                  shape, M, kmax, n_stations, robust, mean_nu,
-                                  mode=mode, Jref=Jref)
-        p1, k = lbfgs_mod.lbfgs_fit(cost_fn, jax.grad(cost_fn), p0,
-                                    itmax=config.max_lbfgs, M=config.lbfgs_m,
-                                    return_iters=True)
+        cost_fn, line_fn = _refine_cost_fn(
+            x8, coh, sta1, sta2, chunk_idx, wt_base, shape, M, kmax,
+            n_stations, robust, mean_nu, mode=mode, Jref=Jref)
+        p1, k, passes = lbfgs_mod.lbfgs_fit(
+            cost_fn, jax.grad(cost_fn), p0, itmax=config.max_lbfgs,
+            M=config.lbfgs_m, return_iters=True, line_func=line_fn)
         if mode == "full":
             Jn = ne.jones_r2c(p1.reshape(shape)).reshape(M, kmax, n_stations,
                                                          2, 2)
@@ -1051,7 +1077,7 @@ def _jit_refine(x8, coh, sta1, sta2, chunk_idx, J, wt_base, mean_nu,
         res = jnp.linalg.norm(dtp.acc(
             (x8 - full_model8(Jn, coh, sta1, sta2, chunk_idx))
             * wt_base)) / (x8.shape[0] * 8)
-    return Jn, res, k
+    return Jn, res, k, passes
 
 
 @jax.jit
@@ -1260,18 +1286,18 @@ def sagefit_host(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
         _learned("promote", promote_key, True)
 
     mean_nu = jnp.clip(jnp.mean(nuM), config.nulow, config.nuhigh)
-    lbfgs_k = jnp.zeros((), jnp.int32)
+    lbfgs_k = passes = jnp.zeros((), jnp.int32)
     if config.max_lbfgs > 0:
-        J, res_1, lbfgs_k = _call("refine", _jit_refine, x8, coh, sta1,
-                                  sta2, chunk_idx, J, wt_base, mean_nu,
-                                  n_stations, dev_config, robust)
+        J, res_1, lbfgs_k, passes = _call(
+            "refine", _jit_refine, x8, coh, sta1, sta2, chunk_idx, J,
+            wt_base, mean_nu, n_stations, dev_config, robust)
     else:
         res_1 = _call("res", _jit_res, x8, coh, sta1, sta2, chunk_idx, J,
                       wt_base)
     return J, {"res_0": res_0, "res_1": res_1, "mean_nu": mean_nu,
                "nerr": nerr, "solver_iters": tk_total[0],
                "rejected_groups": tk_total[1], "cg_iters": tk_total[2],
-               "lbfgs_iters": lbfgs_k}
+               "lbfgs_iters": lbfgs_k, "refine_passes": passes}
 
 
 # ---------------------------------------------------------------------------
@@ -1559,11 +1585,11 @@ def sagefit_host_tiles(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
         _learned("promote", promote_key, True)
 
     mean_nu = jnp.clip(jnp.mean(nuM, axis=1), config.nulow, config.nuhigh)
-    lbfgs_k = jnp.zeros((T,), jnp.int32)
+    lbfgs_k = passes = jnp.zeros((T,), jnp.int32)
     if config.max_lbfgs > 0:
-        J, res_1, lbfgs_k = _call("refine_tiles", _jit_refine_tiles, x8,
-                                  coh, sta1, sta2, chunk_idx, J, wt_base,
-                                  mean_nu, n_stations, dev_config, robust)
+        J, res_1, lbfgs_k, passes = _call(
+            "refine_tiles", _jit_refine_tiles, x8, coh, sta1, sta2,
+            chunk_idx, J, wt_base, mean_nu, n_stations, dev_config, robust)
     else:
         res_1 = _call("res_tiles", _jit_res_tiles, x8, coh, sta1, sta2,
                       chunk_idx, J, wt_base)
@@ -1571,7 +1597,7 @@ def sagefit_host_tiles(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
                "nerr": nerr, "solver_iters": tk_total[:, 0],
                "rejected_groups": tk_total[:, 1],
                "cg_iters": tk_total[:, 2],
-               "lbfgs_iters": lbfgs_k}
+               "lbfgs_iters": lbfgs_k, "refine_passes": passes}
 
 
 @functools.partial(jax.jit,
@@ -1652,24 +1678,14 @@ def bfgsfit(x8, coh, sta1, sta2, chunk_idx, J0, n_stations: int,
         Jref = Jflat0
         p0 = ne.params_from_jones(Jref, mode).reshape(-1).astype(dtype)
 
-    def cost_fn(p):
-        if mode == "full":
-            Jr = ne.jones_r2c(p.reshape(shape)).reshape(
-                M, kmax, n_stations, 2, 2)
-        else:
-            Jr = ne.jones_from_params(p.reshape(shape), mode,
-                                      Jref).reshape(M, kmax, n_stations,
-                                                    2, 2)
-        r = (x8 - full_model8(Jr, coh, sta1, sta2, chunk_idx)) * wt_base
-        if robust:
-            return jnp.sum(jnp.log1p(r * r / nu))
-        return jnp.sum(r * r)
-
+    cost_fn, line_fn = _refine_cost_fn(
+        x8, coh, sta1, sta2, chunk_idx, wt_base, shape, M, kmax, n_stations,
+        robust, nu, mode=mode, Jref=Jref)
     res_0 = jnp.linalg.norm(dtp.acc(
         (x8 - full_model8(J0, coh, sta1, sta2, chunk_idx)) * wt_base)) / n
-    p1, k = lbfgs_mod.lbfgs_fit(cost_fn, jax.grad(cost_fn), p0,
-                                itmax=config.max_lbfgs, M=config.lbfgs_m,
-                                return_iters=True)
+    p1, k, passes = lbfgs_mod.lbfgs_fit(
+        cost_fn, jax.grad(cost_fn), p0, itmax=config.max_lbfgs,
+        M=config.lbfgs_m, return_iters=True, line_func=line_fn)
     if mode == "full":
         J = ne.jones_r2c(p1.reshape(shape)).reshape(M, kmax, n_stations,
                                                     2, 2)
@@ -1678,4 +1694,5 @@ def bfgsfit(x8, coh, sta1, sta2, chunk_idx, J0, n_stations: int,
             M, kmax, n_stations, 2, 2)
     res_1 = jnp.linalg.norm(dtp.acc(
         (x8 - full_model8(J, coh, sta1, sta2, chunk_idx)) * wt_base)) / n
-    return J, {"res_0": res_0, "res_1": res_1, "lbfgs_iters": k}
+    return J, {"res_0": res_0, "res_1": res_1, "lbfgs_iters": k,
+               "refine_passes": passes}

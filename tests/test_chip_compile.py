@@ -121,6 +121,30 @@ def test_cluster_update_fits(one_chip):
     assert 0 < need < HBM_BYTES, mem
 
 
+def test_refine_program_searches_on_the_line(one_chip):
+    """The host-driven plan's joint refine at the ``cal-m8x3`` cell's
+    shape (robust, ``-l 10 -m 7``). While the Fletcher search walked the
+    whole model at every trial, the cost and its gradient were inlined
+    into the program five times over (phase 1, ``cubic``, phase 2) and
+    the compiled text held 967 fusions (PERF.md section 5, PR 26; 975
+    as counted here); on the restricted line the model is there for the
+    restriction and the loop's gradients only: 315."""
+    from sagecal_tpu.config import SolverMode
+    from sagecal_tpu.solvers import sage
+    sd = _spec(one_chip)
+    f32, i32, c64 = jnp.float32, jnp.int32, jnp.complex64
+    cfg = sage.SageConfig(nbase=NB)._replace(
+        max_lbfgs=10, lbfgs_m=7,
+        solver_mode=int(SolverMode.RTR_OSRLM_RLBFGS))
+    text = sage._jit_refine.lower(
+        sd((B, 8), f32), sd((M, B, 2, 2), c64),         # x8, coh
+        sd((B,), i32), sd((B,), i32), sd((M, B), i32),  # sta1/2, chunk idx
+        sd((M, 1, N, 2, 2), c64), sd((B, 8), f32),      # J, wt
+        sd((), f32), N, cfg, True).compile().as_text()
+    assert "restrict" in text
+    assert 0 < text.count(" fusion(") < 967 // 2
+
+
 def test_residual_program_compiles(one_chip):
     """The per-tile residual program (pipeline._residuals /
     cli_mpi residual_fn: real pairs in and out, donated input). Its

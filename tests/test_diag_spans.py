@@ -440,14 +440,15 @@ def lowered_programs(tmp_path_factory):
     ("sagefit", ("sage/prelude", "sage/sweep", "sage/refine", "sage/final",
                  "sage/sweep/inner", "sage/sweep/update",
                  "sage/sweep/assemble", "sage/refine/linesearch",
-                 "sage/refine/direction")),
+                 "sage/refine/direction", "sage/refine/restrict")),
     ("em_sweep", ("sage/sweep", "sage/sweep/inner", "sage/sweep/update",
                   "sage/sweep/assemble")),
     ("cluster_update", ("sage/sweep", "sage/sweep/inner",
                         "sage/sweep/update", "sage/sweep/assemble")),
     ("prelude", ("sage/prelude",)),
     ("refine", ("sage/refine", "sage/refine/linesearch",
-                "sage/refine/direction", "sage/final")),
+                "sage/refine/direction", "sage/refine/restrict",
+                "sage/final")),
     ("simulate", ("rime/phasor", "rime/corrupt", "rime/residual")),
 ])
 def test_lowered_text_names_the_scopes(lowered_programs, program, scopes):

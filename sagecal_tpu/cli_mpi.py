@@ -242,16 +242,6 @@ def main(argv=None) -> int:
 
 
 def _main_consensus(args, dtrace) -> int:
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    from sagecal_tpu.consensus import admm as cadmm
-    from sagecal_tpu.consensus import poly as cpoly
-    from sagecal_tpu.io import dataset as ds, solutions as sol
-    from sagecal_tpu.rime import predict as rp
-    from sagecal_tpu.rime import residual as rr
-    from sagecal_tpu.solvers import lm as lm_mod, normal_eq as nesolver, sage
-
     if getattr(args, "jones", "full") != "full":
         # the polynomial consensus state (y, Bz) is parameterized in
         # full-Jones coordinates; a constrained subspace would need its
@@ -294,351 +284,494 @@ def _main_consensus(args, dtrace) -> int:
         federated.run_federated(cfg, paths)
         return 0
 
-    # each subband path may be a SimMS directory or a real CASA table
-    mss = [ds.open_part(p, tilesz=args.tile_size,
-                        data_column=args.input_column,
-                        out_column=args.output_column) for p in paths]
-    nf = len(mss)
-    meta0 = mss[0].meta
-    # metadata consistency check (master :239-284)
-    for msx in mss[1:]:
-        if len(msx.meta["freqs"]) != len(meta0["freqs"]):
-            raise ValueError(
-                f"dataset {msx.path}: channel count mismatch "
-                f'({len(msx.meta["freqs"])} vs {len(meta0["freqs"])}) '
-                "— the mesh program needs a uniform channel count per "
-                "subband")
-        for key in ("n_stations", "nbase", "tilesz"):
-            if msx.meta[key] != meta0[key]:
-                raise ValueError(
-                    f"dataset {msx.path}: {key} mismatch "
-                    f"({msx.meta[key]} != {meta0[key]})")
-    freqs = np.array([m.meta["freq0"] for m in mss])
-    order = np.argsort(freqs)
-    mss = [mss[i] for i in order]
-    freqs = freqs[order]
-
-    platform = jax.devices()[0].platform
-    rdt = jnp.float64 if (platform == "cpu"
-                          and jax.config.read("jax_enable_x64")) else jnp.float32
-    # --dtype-policy storage dtype for staged visibilities/weights and
-    # the residual readback (sagecal_tpu.dtypes; "f32" -> sdt == rdt)
-    from sagecal_tpu import dtypes as dtp
-    if getattr(args, "dtype_policy", "f32") != "f32" and rdt == jnp.float64:
-        # reduced policies pair with the f32/c64 pipeline (accumulator
-        # contract is f32; see pipeline.py)
-        rdt = jnp.float32
-    sdt = dtp.storage_dtype(getattr(args, "dtype_policy", "f32"), rdt)
-
-    sky = skymodel.read_sky_cluster(
-        args.sky_model, args.cluster_file, meta0["ra0"], meta0["dec0"],
-        float(freqs.mean()), bool(args.format))
-    dsky = rp.sky_to_device(sky, rdt)
-    dobeam = int(args.beam)
-    beams_static = None
-    if dobeam:
-        from sagecal_tpu.rime import beam as bm
-        beams_static = [
-            bm.beam_to_device(bm.resolve_beaminfo(dobeam, m, m.meta),
-                              m.meta["freq0"], rdt)
-            for m in mss]
-    n = meta0["n_stations"]
-    kmax = int(sky.nchunk.max())
-    cmask = np.arange(kmax)[None, :] < sky.nchunk[:, None]
-    cidx = rp.chunk_indices(meta0["tilesz"], meta0["nbase"], sky.nchunk)
-
-    # mesh: use ALL devices up to Nf; when Nf doesn't divide (or, multi-
-    # host, when Nf < the global device count), pad the subband axis to
-    # Fl*ndev with masked zero-weight slots (admm.pad_subbands) instead
-    # of shrinking the mesh to a divisor. Multi-host: never slice the
-    # device list below a process boundary — every process must own mesh
-    # devices or the SPMD programs desynchronize.
-    multihost = args.num_processes > 1
-    ndev_avail = len(jax.devices())
-    if args.mesh_devices and not multihost:
-        # --mesh-devices: never slice below a process boundary, so the
-        # cap is single-process only (multi-host meshes must span all
-        # processes' devices or the SPMD programs desynchronize)
-        ndev_avail = min(ndev_avail, max(1, args.mesh_devices))
-    ndev = ndev_avail if multihost else min(ndev_avail, nf)
-    if args.staleness > 0 and not multihost:
-        # bounded-staleness consensus is the single-device host-driven
-        # plan (per-subband executions it can actually skip) — fold all
-        # subbands onto one device regardless of what is visible
-        ndev = 1
-    fpad = -(-max(nf, ndev) // ndev) * ndev
-    mesh = Mesh(np.array(jax.devices()[:ndev]), ("freq",))
-    # running as a serve job: surface the mesh's device span to the
-    # fleet view (no-op outside a job scope — solo CLI runs)
-    from sagecal_tpu.serve import fleet as _fleet
-    _fleet.note_mesh(mesh)
-    is_writer = args.process_id == 0   # mpirun-analogue output ownership
-    if is_writer:
-        print(utils.platform_line(ndev_avail))
-        print(f"Subbands: {nf} over {ndev} device(s)"
-              + (f" (padded to {fpad})" if fpad != nf else "")
-              + f"; stations {n}, clusters {sky.n_clusters} "
-              f"(Mt={sky.n_eff_clusters})")
-
-    # --prior-cache read/readwrite: seed this run from the solution
-    # prior store (serve/priors.py, family "admm"). All-or-nothing
-    # across subbands — any band refusing (station-set/cluster
-    # mismatch) cold-starts EVERY band, a prior never partially seeds.
-    # An explicit -q solution file or -G rho file always wins.
-    prior_mode = getattr(args, "prior_cache", "off")
-    prior_k = None
-    prior_J0 = None
-    prior_rho = None
-    if prior_mode != "off":
-        prior_k = ppriors.prior_key(
-            args.sky_model, args.cluster_file, n, float(freqs.mean()),
-            "admm")
-    if ppriors.reads(prior_mode) and not args.init_solutions:
-        span = float(meta0["tilesz"]) * float(meta0["tdelta"])
-        pt = (float(args.skip_timeslots)
-              + (np.arange(kmax) + 0.5) / kmax) * span
-        seeds = []
-        for f in range(nf):
-            Jf, rho_p = ppriors.PRIORS.seed(
-                prior_k, pt, float(freqs[f]), n, sky.n_clusters)
-            if Jf is None:
-                seeds = []
-                prior_rho = None
-                break
-            seeds.append(Jf)
-            if prior_rho is None:
-                prior_rho = rho_p
-        if seeds:
-            prior_J0 = np.stack(seeds)   # [nf, M, kmax, n, 2, 2]
-            if is_writer:
-                print(f"prior-cache: J0 seeded for {nf} subband(s) "
-                      "from the solution prior store")
-
-    rho0 = args.rho
-    if args.rho_file:
-        # per-cluster regularization (readsky.c:780): passed through as an
-        # [M] array; admm.py broadcasts it per subband
-        rho0 = skymodel.read_cluster_rho(args.rho_file, sky.cluster_ids,
-                                         default_rho=args.rho)
-    elif prior_rho is not None:
-        # banked per-cluster consensus rho seeds the schedule (the
-        # previous run's converged regularization beats the scalar -r
-        # default; -G stays authoritative when given)
-        rho0 = prior_rho
-
-    Bpoly = cpoly.setup_polynomials(freqs, float(freqs.mean()),
-                                    args.npoly, args.polytype)
-    # padded basis for the mesh program; Bpoly keeps the real rows for
-    # host-side uses (use_global_solution, solution writing)
-    _, Bpoly_pad, _ = cadmm.pad_subbands([], Bpoly, nf, ndev)
-    spatialreg = None
-    spatial_coords = None
-    if args.spatialreg:
-        from sagecal_tpu.consensus import spatial as csp
-        vals = [float(x) for x in args.spatialreg.split(",")]
-        if len(vals) != 5:
-            raise ValueError("-X needs l2,l1,order,fista_iters,cadence")
-        if args.federated_alpha <= 0.0:
-            raise ValueError(
-                "-X spatial regularization couples into the consensus Z "
-                "only through the -u prior strength; give -u > 0 "
-                "(master :768-775 adds alpha*Zbar - X to the Z update)")
-        spatialreg = (vals[0], vals[1], int(vals[2]), int(vals[3]),
-                      max(int(vals[4]), 1))
-        spatial_coords = csp.cluster_polar_coords(sky)
-    from sagecal_tpu.ops import sweep_pallas
-    sweep_pallas.check_kernel(args.kernel)
-    cfg = cadmm.ADMMConfig(
-        n_admm=args.admm, npoly=args.npoly, poly_type=args.polytype,
-        rho=rho0, adaptive_rho=bool(args.adaptive_rho),
-        spatialreg=spatialreg, federated_alpha=args.federated_alpha,
-        sage=sage.SageConfig(
-            max_emiter=args.max_em_iter, max_iter=args.max_iter,
-            max_lbfgs=args.max_lbfgs, lbfgs_m=args.lbfgs_m,
-            solver_mode=int(SolverMode(args.solver_mode)),
-            nulow=args.nulow, nuhigh=args.nuhigh,
-            randomize=bool(args.randomize),
-            inflight=args.inflight, inner=args.inner,
-            kernel=args.kernel,
-            dtype_policy=getattr(args, "dtype_policy", "f32")))
-
-    t0 = mss[0].read_tile(0)
-    plans = [nm for nm, on in (("--block-f", args.block_f),
-                               ("--host-loop", args.host_loop),
-                               ("--time-shard", args.time_shard > 1),
-                               ("--staleness", args.staleness > 0))
-             if on]
-    if len(plans) > 1:
-        raise ValueError(f"{' and '.join(plans)} are different "
-                         "execution plans; pick one")
-    blk_timer = [] if args.block_f else None
-    if args.time_shard == 1:
-        raise ValueError("--time-shard 1 is ambiguous: use 0 (off, "
-                         "the per-interval loop) or >= 2 time-mesh "
-                         "devices")
+    st = ConsensusStepper(args, paths)
     if args.time_shard > 1:
-        # 2-D ('freq', 'time') mesh: handled by its own driver below —
-        # the whole selected observation is one SPMD program, so the
-        # per-interval prefetch loop never runs
-        if multihost:
-            raise ValueError("--time-shard stages the whole "
-                             "observation from one host; it cannot "
-                             "run multi-host yet (the mesh would span "
-                             "non-addressable devices)")
+        return _consensus_time_sharded(
+            args, dtrace, mss=st.mss, meta0=st.meta0, freqs=st.freqs,
+            sky=st.sky, dsky=st.dsky, cfg=st.cfg, Bpoly=st.Bpoly,
+            rdt=st.rdt, sdt=st.sdt, cidx=st.cidx, cmask=st.cmask, n=st.n,
+            t0=st.t0, start=st.start, stop=st.stop, Jinit=st.Jinit,
+            res_jit=st.res_jit, writer=st.writer,
+            worker_writers=st.worker_writers, is_writer=st.is_writer,
+            prep_tiles=st._prep_tiles)
+
+    # overlapped execution (sagecal_tpu.sched): all subbands of interval
+    # t+N are read and staged on a background thread while interval t
+    # solves, and residual/solution writes drain on the stepper's
+    # ordered writer thread; --prefetch 0 is the synchronous escape
+    # hatch. Bit-identical: the warm-start chain (J0 carry) stays
+    # sequential in step(), only data movement overlaps.
+    from sagecal_tpu import sched
+
+    def produce(i):
+        tiles = st.read(i)
+        return tiles, st.stage(i, tiles)
+
+    source = sched.Prefetcher(produce, st.n_intervals, depth=st.depth,
+                              tile0=st.start)
+    try:
+        for i, (tiles, staged), io_wait in source:
+            st.step(st.start + i, tiles, staged, io_wait)
+    finally:
+        # a mid-loop failure (solver error, reader-thread or async
+        # writer exception) must still cancel the prefetch thread and
+        # drain/raise the ordered write queue — otherwise completed
+        # intervals' queued writes are silently dropped, diverging
+        # from the --prefetch 0 inline-write behavior
+        source.close()
+        st.close()
+    return 0
+
+
+class ConsensusStepper:
+    """The consensus interval loop behind a seam a driver can step: the
+    ``cli_mpi`` twin of ``pipeline.TileStepper``.
+
+    Built from parsed ``cli_mpi`` arguments (set-up: datasets, sky,
+    mesh, the ADMM runner of the chosen execution plan, the residual
+    program, the solution files). ``read(i)`` and ``stage(i, tiles)``
+    may run on a background reader thread (``sched.Prefetcher``);
+    ``step(ti, tiles, staged, io_wait)`` runs on the device-owner
+    thread, strictly in interval order: runner, fetch, divergence
+    reset, warm-start carry, residual program, and the interval's
+    ordered writes (per-subband solutions, residual tiles, global Z)
+    submitted to the stepper's ``AsyncWriter``; ``close()`` drains the
+    writer, banks the prior and closes the solution files. All mutable
+    solve state (the warm-start chain ``J0``, the writer) lives here,
+    so ``main()`` and any other driver run ONE loop.
+
+    ``i`` counts the selected intervals from 0 (``n_intervals`` of
+    them); ``ti = start + i`` is the dataset's tile number (``-K``).
+    ``--time-shard`` builds no runner: that plan has its own driver and
+    takes only the set-up from here.
+    """
+
+    def __init__(self, args, paths=None, log=print):
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        from sagecal_tpu import dtypes as dtp, sched
+        from sagecal_tpu.consensus import admm as cadmm
+        from sagecal_tpu.consensus import poly as cpoly
+        from sagecal_tpu.diag import trace as dtrace
+        from sagecal_tpu.io import dataset as ds, solutions as sol
+        from sagecal_tpu.rime import predict as rp
+        from sagecal_tpu.rime import residual as rr
+        from sagecal_tpu.solvers import normal_eq as nesolver, sage
+
+        self.args, self.log = args, log
+        if paths is None:
+            paths = discover_datasets(args.ms_pattern)
+        # each subband path may be a SimMS directory or a real CASA table
+        mss = [ds.open_part(p, tilesz=args.tile_size,
+                            data_column=args.input_column,
+                            out_column=args.output_column) for p in paths]
+        nf = len(mss)
+        meta0 = mss[0].meta
+        # metadata consistency check (master :239-284)
+        for msx in mss[1:]:
+            if len(msx.meta["freqs"]) != len(meta0["freqs"]):
+                raise ValueError(
+                    f"dataset {msx.path}: channel count mismatch "
+                    f'({len(msx.meta["freqs"])} vs {len(meta0["freqs"])}) '
+                    "— the mesh program needs a uniform channel count per "
+                    "subband")
+            for key in ("n_stations", "nbase", "tilesz"):
+                if msx.meta[key] != meta0[key]:
+                    raise ValueError(
+                        f"dataset {msx.path}: {key} mismatch "
+                        f"({msx.meta[key]} != {meta0[key]})")
+        freqs = np.array([m.meta["freq0"] for m in mss])
+        order = np.argsort(freqs)
+        mss = [mss[i] for i in order]
+        freqs = freqs[order]
+        self.mss, self.nf, self.meta0, self.freqs = mss, nf, meta0, freqs
+
+        platform = jax.devices()[0].platform
+        rdt = jnp.float64 if (
+            platform == "cpu"
+            and jax.config.read("jax_enable_x64")) else jnp.float32
+        # --dtype-policy storage dtype for staged visibilities/weights and
+        # the residual readback (sagecal_tpu.dtypes; "f32" -> sdt == rdt)
+        if (getattr(args, "dtype_policy", "f32") != "f32"
+                and rdt == jnp.float64):
+            # reduced policies pair with the f32/c64 pipeline (accumulator
+            # contract is f32; see pipeline.py)
+            rdt = jnp.float32
+        sdt = dtp.storage_dtype(getattr(args, "dtype_policy", "f32"), rdt)
+        self.rdt, self.sdt = rdt, sdt
+
+        sky = skymodel.read_sky_cluster(
+            args.sky_model, args.cluster_file, meta0["ra0"], meta0["dec0"],
+            float(freqs.mean()), bool(args.format))
+        dsky = rp.sky_to_device(sky, rdt)
+        self.sky, self.dsky = sky, dsky
+        dobeam = self.dobeam = int(args.beam)
+        beams_static = None
         if dobeam:
-            raise ValueError("--time-shard does not support -B beam "
-                             "tables yet; use the per-interval loop")
+            from sagecal_tpu.rime import beam as bm
+            beams_static = [
+                bm.beam_to_device(bm.resolve_beaminfo(dobeam, m, m.meta),
+                                  m.meta["freq0"], rdt)
+                for m in mss]
+        n = self.n = meta0["n_stations"]
+        kmax = self.kmax = int(sky.nchunk.max())
+        cmask = self.cmask = (np.arange(kmax)[None, :]
+                              < sky.nchunk[:, None])
+        cidx = self.cidx = rp.chunk_indices(
+            meta0["tilesz"], meta0["nbase"], sky.nchunk)
+
+        # mesh: use ALL devices up to Nf; when Nf doesn't divide (or, multi-
+        # host, when Nf < the global device count), pad the subband axis to
+        # Fl*ndev with masked zero-weight slots (admm.pad_subbands) instead
+        # of shrinking the mesh to a divisor. Multi-host: never slice the
+        # device list below a process boundary — every process must own mesh
+        # devices or the SPMD programs desynchronize.
+        multihost = self.multihost = args.num_processes > 1
+        ndev_avail = len(jax.devices())
+        if args.mesh_devices and not multihost:
+            # --mesh-devices: never slice below a process boundary, so the
+            # cap is single-process only (multi-host meshes must span all
+            # processes' devices or the SPMD programs desynchronize)
+            ndev_avail = min(ndev_avail, max(1, args.mesh_devices))
+        ndev = ndev_avail if multihost else min(ndev_avail, nf)
+        if args.staleness > 0 and not multihost:
+            # bounded-staleness consensus is the single-device host-driven
+            # plan (per-subband executions it can actually skip) — fold all
+            # subbands onto one device regardless of what is visible
+            ndev = 1
+        self.ndev = ndev
+        fpad = self.fpad = -(-max(nf, ndev) // ndev) * ndev
+        mesh = self.mesh = Mesh(np.array(jax.devices()[:ndev]), ("freq",))
+        # running as a serve job: surface the mesh's device span to the
+        # fleet view (no-op outside a job scope — solo CLI runs)
+        from sagecal_tpu.serve import fleet as _fleet
+        _fleet.note_mesh(mesh)
+        # mpirun-analogue output ownership
+        is_writer = self.is_writer = args.process_id == 0
+        if is_writer:
+            log(utils.platform_line(ndev_avail))
+            log(f"Subbands: {nf} over {ndev} device(s)"
+                + (f" (padded to {fpad})" if fpad != nf else "")
+                + f"; stations {n}, clusters {sky.n_clusters} "
+                f"(Mt={sky.n_eff_clusters})")
+
+        # --prior-cache read/readwrite: seed this run from the solution
+        # prior store (serve/priors.py, family "admm"). All-or-nothing
+        # across subbands — any band refusing (station-set/cluster
+        # mismatch) cold-starts EVERY band, a prior never partially seeds.
+        # An explicit -q solution file or -G rho file always wins.
+        self.prior_mode = getattr(args, "prior_cache", "off")
+        self.prior_k = None
+        prior_J0 = None
+        prior_rho = None
+        if self.prior_mode != "off":
+            self.prior_k = ppriors.prior_key(
+                args.sky_model, args.cluster_file, n, float(freqs.mean()),
+                "admm")
+        if ppriors.reads(self.prior_mode) and not args.init_solutions:
+            span = float(meta0["tilesz"]) * float(meta0["tdelta"])
+            pt = (float(args.skip_timeslots)
+                  + (np.arange(kmax) + 0.5) / kmax) * span
+            seeds = []
+            for f in range(nf):
+                Jf, rho_p = ppriors.PRIORS.seed(
+                    self.prior_k, pt, float(freqs[f]), n, sky.n_clusters)
+                if Jf is None:
+                    seeds = []
+                    prior_rho = None
+                    break
+                seeds.append(Jf)
+                if prior_rho is None:
+                    prior_rho = rho_p
+            if seeds:
+                prior_J0 = np.stack(seeds)   # [nf, M, kmax, n, 2, 2]
+                if is_writer:
+                    log(f"prior-cache: J0 seeded for {nf} subband(s) "
+                        "from the solution prior store")
+
+        rho0 = args.rho
+        if args.rho_file:
+            # per-cluster regularization (readsky.c:780): passed through as an
+            # [M] array; admm.py broadcasts it per subband
+            rho0 = skymodel.read_cluster_rho(args.rho_file, sky.cluster_ids,
+                                             default_rho=args.rho)
+        elif prior_rho is not None:
+            # banked per-cluster consensus rho seeds the schedule (the
+            # previous run's converged regularization beats the scalar -r
+            # default; -G stays authoritative when given)
+            rho0 = prior_rho
+        self.rho0 = rho0
+
+        Bpoly = self.Bpoly = cpoly.setup_polynomials(
+            freqs, float(freqs.mean()), args.npoly, args.polytype)
+        # padded basis for the mesh program; Bpoly keeps the real rows for
+        # host-side uses (use_global_solution, solution writing)
+        _, Bpoly_pad, _ = cadmm.pad_subbands([], Bpoly, nf, ndev)
+        spatialreg = None
+        spatial_coords = None
         if args.spatialreg:
-            raise ValueError("--time-shard does not support -X spatial "
-                             "regularization; use the mesh runner")
-        if args.mdl:
-            raise ValueError("--time-shard does not support --mdl")
-        runner = None
-    elif args.staleness > 0:
-        if multihost:
-            raise ValueError("--staleness is a single-device host-"
-                             "driven plan; it cannot run multi-host "
-                             "(every process would redundantly drive "
-                             "the same chain)")
+            from sagecal_tpu.consensus import spatial as csp
+            vals = [float(x) for x in args.spatialreg.split(",")]
+            if len(vals) != 5:
+                raise ValueError("-X needs l2,l1,order,fista_iters,cadence")
+            if args.federated_alpha <= 0.0:
+                raise ValueError(
+                    "-X spatial regularization couples into the consensus Z "
+                    "only through the -u prior strength; give -u > 0 "
+                    "(master :768-775 adds alpha*Zbar - X to the Z update)")
+            spatialreg = (vals[0], vals[1], int(vals[2]), int(vals[3]),
+                          max(int(vals[4]), 1))
+            spatial_coords = csp.cluster_polar_coords(sky)
+        self.spatialreg = spatialreg
+        from sagecal_tpu.ops import sweep_pallas
+        sweep_pallas.check_kernel(args.kernel)
+        cfg = self.cfg = cadmm.ADMMConfig(
+            n_admm=args.admm, npoly=args.npoly, poly_type=args.polytype,
+            rho=rho0, adaptive_rho=bool(args.adaptive_rho),
+            spatialreg=spatialreg, federated_alpha=args.federated_alpha,
+            sage=sage.SageConfig(
+                max_emiter=args.max_em_iter, max_iter=args.max_iter,
+                max_lbfgs=args.max_lbfgs, lbfgs_m=args.lbfgs_m,
+                solver_mode=int(SolverMode(args.solver_mode)),
+                nulow=args.nulow, nuhigh=args.nuhigh,
+                randomize=bool(args.randomize),
+                inflight=args.inflight, inner=args.inner,
+                kernel=args.kernel,
+                dtype_policy=getattr(args, "dtype_policy", "f32")))
+
+        t0 = self.t0 = mss[0].read_tile(0)
+        plans = [nm for nm, on in (("--block-f", args.block_f),
+                                   ("--host-loop", args.host_loop),
+                                   ("--time-shard", args.time_shard > 1),
+                                   ("--staleness", args.staleness > 0))
+                 if on]
+        if len(plans) > 1:
+            raise ValueError(f"{' and '.join(plans)} are different "
+                             "execution plans; pick one")
+        self.blk_timer = [] if args.block_f else None
+        if args.time_shard == 1:
+            raise ValueError("--time-shard 1 is ambiguous: use 0 (off, "
+                             "the per-interval loop) or >= 2 time-mesh "
+                             "devices")
+        if args.time_shard > 1:
+            # 2-D ('freq', 'time') mesh: handled by its own driver
+            # (_consensus_time_sharded) — the whole selected observation
+            # is one SPMD program, so the per-interval loop never runs
+            if multihost:
+                raise ValueError("--time-shard stages the whole "
+                                 "observation from one host; it cannot "
+                                 "run multi-host yet (the mesh would span "
+                                 "non-addressable devices)")
+            if dobeam:
+                raise ValueError("--time-shard does not support -B beam "
+                                 "tables yet; use the per-interval loop")
+            if args.spatialreg:
+                raise ValueError("--time-shard does not support -X spatial "
+                                 "regularization; use the mesh runner")
+            if args.mdl:
+                raise ValueError("--time-shard does not support --mdl")
+            self.runner = None
+        elif args.staleness > 0:
+            if multihost:
+                raise ValueError("--staleness is a single-device host-"
+                                 "driven plan; it cannot run multi-host "
+                                 "(every process would redundantly drive "
+                                 "the same chain)")
+            if dobeam:
+                raise ValueError("--staleness does not support -B beam "
+                                 "tables")
+            self.runner = cadmm.make_admm_runner_stale(
+                dsky, t0.sta1, t0.sta2, cidx, cmask, n, meta0["fdelta"],
+                Bpoly_pad, cfg, nf, staleness=args.staleness,
+                nbase=meta0["nbase"])
+        elif args.block_f:
+            if args.block_f < 1:
+                raise ValueError(f"--block-f {args.block_f}: must be >= 1")
+            if ndev != 1:
+                raise ValueError("--block-f is the single-device execution "
+                                 "plan; it needs a 1-device mesh")
+            self.runner = cadmm.make_admm_runner_blocked(
+                dsky, t0.sta1, t0.sta2, cidx, cmask, n, meta0["fdelta"],
+                Bpoly_pad, cfg, nf, block_f=args.block_f,
+                dobeam=dobeam, nbase=meta0["nbase"], timer=self.blk_timer)
+        else:
+            self.runner = cadmm.make_admm_runner(
+                dsky, t0.sta1, t0.sta2, cidx, cmask, n, meta0["fdelta"],
+                Bpoly_pad, cfg, mesh, nf, spatial_coords=spatial_coords,
+                host_loop=args.host_loop,
+                dobeam=dobeam, nbase=meta0["nbase"])
+
+        # residual program (per subband, local J); -k correction uses the
+        # subband's own solutions (sagecal_slave.cpp residual path)
+        correct_idx = skymodel.correct_cluster_index(
+            sky, args.correct_cluster)
+
+        tslot_rows = jnp.asarray(t0.tslot)
+
+        def residual_fn(J_r8, x_r, u, v, w, freq, *beam_rest):
+            # storage-dtype writeback emission (out_dtype): the d->h
+            # readback ships sdt bytes; identity at "f32"
+            return rr.calculate_residuals_pairs(
+                dsky, nesolver.jones_r2c(J_r8), x_r, u, v, w, freq[None],
+                meta0["fdelta"], jnp.asarray(t0.sta1), jnp.asarray(t0.sta2),
+                jnp.asarray(cidx), jnp.asarray(sky.subtract_mask()),
+                out_dtype=sdt, correct_idx=correct_idx,
+                rho=args.mmse_rho, phase_only=bool(args.phase_only),
+                beam=beam_rest[0] if beam_rest else None, dobeam=dobeam,
+                tslot=tslot_rows)
+
+        # constructed exactly once per stepper
+        self.res_jit = jax.jit(jax.vmap(residual_fn))
+
+        self.writer = None
+        if args.solutions_file and is_writer:
+            self.writer = sol.SolutionWriter(
+                args.solutions_file, float(freqs.mean()),
+                float(freqs.max() - freqs.min()),
+                meta0["tilesz"] * meta0["tdelta"] / 60.0, n, sky.n_clusters,
+                sky.n_eff_clusters * args.npoly)
+
+        self._sh = NamedSharding(mesh, P("freq"))
+
+        # ragged real-MS subbands (a lost trailing scan) truncate to the
+        # common prefix, like the federated path
+        n_tiles = min(m.n_tiles for m in mss)
+        if is_writer and any(m.n_tiles != n_tiles for m in mss):
+            log(f"Warning: subband tile counts differ; calibrating the "
+                f"common {n_tiles} tiles")
+        self.start = args.skip_timeslots
+        self.stop = n_tiles if not args.max_timeslots else min(
+            n_tiles, self.start + args.max_timeslots)
+
+        Jinit = utils.jones_c2r_np(np.tile(
+            np.eye(2, dtype=complex), (nf, sky.n_clusters, kmax, n, 1, 1)))
+        if args.init_solutions:
+            # -q: warm-start every subband from one interval of J solutions
+            # (MPI/main.cpp -q; J format, not the Z/polynomial output file)
+            Jq = sol.read_warm_start(args.init_solutions, sky, n)
+            if Jq is not None:
+                Jinit = np.tile(utils.jones_c2r_np(np.asarray(Jq))[None],
+                                (nf, 1, 1, 1, 1))
+        self.Jinit = Jinit
+        self.J0 = Jinit.copy()
+        if prior_J0 is not None:
+            # prior-cache warm chain start. Jinit stays the cold identity:
+            # the per-subband divergence reset in step() still recovers
+            # to the reference cold start, so a bad prior costs one reset,
+            # never the run (same contract as pipeline.TileStepper).
+            self.J0 = utils.jones_c2r_np(prior_J0)
+
+        # spatial-model solution file ("spatial_"+solfile,
+        # sagecal_master.cpp:472-498): header + two centroid-coordinate
+        # rows, then per interval the global SH coefficient matrix Zspat
+        # recomputed host-side from the final consensus Z (spatial_step's
+        # FISTA is a pure function of Z, so no extra runner state).
+        self.spatial_file = None
+        if spatialreg is not None and args.solutions_file and is_writer:
+            import os as _os
+            d, b = _os.path.split(args.solutions_file)
+            self.spatial_file = open(_os.path.join(d, "spatial_" + b), "w")
+            G_sp = int(spatialreg[2]) ** 2
+            rr_c, tt_c = spatial_coords
+            self.spatial_file.write(
+                "# spatial regularization solution file (Zspat)\n"
+                "# Top two rows are the polar coordinates of the "
+                "centroids (rad)\n"
+                "# reference_freq(MHz) polynomial_order(freq) "
+                "polynomial_order(spatial) stations clusters "
+                "effective_clusters\n")
+            self.spatial_file.write(
+                f"{float(freqs.mean()) * 1e-6:f} {args.npoly} {G_sp} {n} "
+                f"{sky.n_clusters} {sky.n_eff_clusters}\n")
+            self.spatial_file.write(
+                " ".join(f"{x:f}" for x in np.asarray(rr_c)) + "\n")
+            self.spatial_file.write(
+                " ".join(f"{x:f}" for x in np.asarray(tt_c)) + "\n")
+
+        self._spatial_phi = None
+        if self.spatial_file is not None:
+            from sagecal_tpu.consensus import spatial as sp
+            # loop-invariant basis: built once, kept for the writer
+            self._spatial_phi = sp.phi_padded(cmask, *spatial_coords,
+                                              spatialreg[2], spatialreg[0])
+
+        # -B beam: the element/array-factor tables are tile-invariant, so
+        # the static leaves are stacked + staged ONCE here; per interval
+        # only the [tilesz] gmst time track is restaged (round-5
+        # ADVICE: the old loop re-transferred every leaf each interval).
+        # The diag stage_bytes records quantify the saving per tile.
+        self._beamF_static = None
+        self._beam_static_dev = None
         if dobeam:
-            raise ValueError("--staleness does not support -B beam "
-                             "tables")
-        runner = cadmm.make_admm_runner_stale(
-            dsky, t0.sta1, t0.sta2, cidx, cmask, n, meta0["fdelta"],
-            Bpoly_pad, cfg, nf, staleness=args.staleness,
-            nbase=meta0["nbase"])
-    elif args.block_f:
-        if args.block_f < 1:
-            raise ValueError(f"--block-f {args.block_f}: must be >= 1")
-        if ndev != 1:
-            raise ValueError("--block-f is the single-device execution "
-                             "plan; it needs a 1-device mesh")
-        runner = cadmm.make_admm_runner_blocked(
-            dsky, t0.sta1, t0.sta2, cidx, cmask, n, meta0["fdelta"],
-            Bpoly_pad, cfg, nf, block_f=args.block_f,
-            dobeam=dobeam, nbase=meta0["nbase"], timer=blk_timer)
-    else:
-        runner = cadmm.make_admm_runner(
-            dsky, t0.sta1, t0.sta2, cidx, cmask, n, meta0["fdelta"],
-            Bpoly_pad, cfg, mesh, nf, spatial_coords=spatial_coords,
-            host_loop=args.host_loop,
-            dobeam=dobeam, nbase=meta0["nbase"])
+            self._beamF_static = jax.tree.map(
+                lambda *xs: np.stack([np.asarray(x) for x in xs]),
+                *beams_static)
+            beamF_pad = self._beamF_static
+            if fpad > nf:       # padded mesh slots reuse subband 0's beam
+                beamF_pad = jax.tree.map(lambda a: np.concatenate(
+                    [a, np.repeat(a[:1], fpad - nf, axis=0)]),
+                    self._beamF_static)
+            self._beam_static_dev = jax.tree.map(self._to_device, beamF_pad)
+            dtrace.emit("stage_bytes", what="beam_static",
+                        bytes=int(sum(np.asarray(l).nbytes
+                                      for l in jax.tree.leaves(beamF_pad))))
 
-    # residual program (per subband, local J); -k correction uses the
-    # subband's own solutions (sagecal_slave.cpp residual path)
-    correct_idx = skymodel.correct_cluster_index(
-        sky, args.correct_cluster)
+        # per-subband worker files, written unconditionally like the
+        # reference slaves ("always create default solution file name
+        # MS+'.solutions'", sagecal_slave.cpp:167-168). Opened only AFTER
+        # -q is read: a previous run's worker file is a valid warm-start
+        # source and must not be truncated before read_warm_start sees it.
+        # Multi-host note: unlike the reference's per-node slave writes,
+        # ONLY process 0 writes these files (shared-filesystem assumption;
+        # see MIGRATION.md "per-subband worker files").
+        self.worker_writers = []
+        if is_writer:
+            interval_min = meta0["tilesz"] * meta0["tdelta"] / 60.0
+            self.worker_writers = [
+                sol.SolutionWriter(
+                    m.path.rstrip("/") + ".solutions",
+                    float(m.meta["freq0"]), float(m.meta["fdelta"]),
+                    interval_min, n, sky.n_clusters, sky.n_eff_clusters)
+                for m in mss]
 
-    tslot_rows = jnp.asarray(t0.tslot)
+        # --prefetch N: read/stage depth of the caller's Prefetcher and
+        # whether the ordered writer runs on its own thread; 0 = the
+        # synchronous escape hatch (writes inline at their site)
+        self.depth = max(0, int(getattr(args, "prefetch", 1)))
+        self.aw = sched.AsyncWriter(enabled=self.depth > 0)
+        self.history = []       # per interval: res_0, res_1, primal, dual
+        self._rhoF = None       # the last interval's [F, M] rho schedule
+        self._last = self.start - 1
 
-    def residual_fn(J_r8, x_r, u, v, w, freq, *beam_rest):
-        # storage-dtype writeback emission (out_dtype): the d->h
-        # readback ships sdt bytes; identity at "f32"
-        return rr.calculate_residuals_pairs(
-            dsky, nesolver.jones_r2c(J_r8), x_r, u, v, w, freq[None],
-            meta0["fdelta"], jnp.asarray(t0.sta1), jnp.asarray(t0.sta2),
-            jnp.asarray(cidx), jnp.asarray(sky.subtract_mask()),
-            out_dtype=sdt, correct_idx=correct_idx,
-            rho=args.mmse_rho, phase_only=bool(args.phase_only),
-            beam=beam_rest[0] if beam_rest else None, dobeam=dobeam,
-            tslot=tslot_rows)
+    @property
+    def n_intervals(self) -> int:
+        return max(0, self.stop - self.start)
 
-    # jaxlint: disable=retrace -- one-shot per-process CLI driver; the
-    # wrapper is constructed exactly once per run
-    res_jit = jax.jit(jax.vmap(residual_fn))
+    # -- host <-> device ------------------------------------------------------
 
-    writer = None
-    if args.solutions_file and is_writer:
-        writer = sol.SolutionWriter(
-            args.solutions_file, float(freqs.mean()),
-            float(freqs.max() - freqs.min()),
-            meta0["tilesz"] * meta0["tdelta"] / 60.0, n, sky.n_clusters,
-            sky.n_eff_clusters * args.npoly)
-
-    sh = NamedSharding(mesh, P("freq"))
-
-    def stage(a):
+    def _to_device(self, a):
         """Host [Fpad, ...] -> sharded device array. Single process:
         device_put; multi-host: every process holds the full host array
         and each device picks out its shard via the callback (the
         multi-host-safe staging path)."""
-        if multihost:
+        import jax
+        if self.multihost:
             return jax.make_array_from_callback(
-                a.shape, sh, lambda idx: a[idx])
-        return jax.device_put(a, sh)
+                a.shape, self._sh, lambda idx: a[idx])
+        return jax.device_put(a, self._sh)
 
-    def fetch(a):
+    def _fetch(self, a):
         """Device -> host numpy. Multi-host: runner outputs span
         non-addressable devices, so gather them to every process first
         (the master's Y-gather analogue, over ICI/DCN instead of MPI)."""
-        if multihost:
+        if self.multihost:
             from jax.experimental import multihost_utils
             return np.asarray(
                 multihost_utils.process_allgather(a, tiled=True))
         return np.asarray(a)
 
-    # ragged real-MS subbands (a lost trailing scan) truncate to the
-    # common prefix, like the federated path
-    n_tiles = min(m.n_tiles for m in mss)
-    if is_writer and any(m.n_tiles != n_tiles for m in mss):
-        print(f"Warning: subband tile counts differ; calibrating the "
-              f"common {n_tiles} tiles")
-    start = args.skip_timeslots
-    stop = n_tiles if not args.max_timeslots else min(
-        n_tiles, start + args.max_timeslots)
-
-    Jinit = utils.jones_c2r_np(np.tile(
-        np.eye(2, dtype=complex), (nf, sky.n_clusters, kmax, n, 1, 1)))
-    if args.init_solutions:
-        # -q: warm-start every subband from one interval of J solutions
-        # (MPI/main.cpp -q; J format, not the Z/polynomial output file)
-        Jq = sol.read_warm_start(args.init_solutions, sky, n)
-        if Jq is not None:
-            Jinit = np.tile(utils.jones_c2r_np(np.asarray(Jq))[None],
-                            (nf, 1, 1, 1, 1))
-    J0 = Jinit.copy()
-    if prior_J0 is not None:
-        # prior-cache warm chain start. Jinit stays the cold identity:
-        # the per-subband divergence reset below still recovers to the
-        # reference cold start, so a bad prior costs one reset, never
-        # the run (same contract as pipeline.TileStepper).
-        J0 = utils.jones_c2r_np(prior_J0)
-
-    # spatial-model solution file ("spatial_"+solfile,
-    # sagecal_master.cpp:472-498): header + two centroid-coordinate
-    # rows, then per interval the global SH coefficient matrix Zspat
-    # recomputed host-side from the final consensus Z (spatial_step's
-    # FISTA is a pure function of Z, so no extra runner state).
-    spatial_file = None
-    if spatialreg is not None and args.solutions_file and is_writer:
-        import os as _os
-        d, b = _os.path.split(args.solutions_file)
-        spatial_file = open(_os.path.join(d, "spatial_" + b), "w")
-        G_sp = int(spatialreg[2]) ** 2
-        rr_c, tt_c = spatial_coords
-        spatial_file.write(
-            "# spatial regularization solution file (Zspat)\n"
-            "# Top two rows are the polar coordinates of the "
-            "centroids (rad)\n"
-            "# reference_freq(MHz) polynomial_order(freq) "
-            "polynomial_order(spatial) stations clusters "
-            "effective_clusters\n")
-        spatial_file.write(
-            f"{float(freqs.mean()) * 1e-6:f} {args.npoly} {G_sp} {n} "
-            f"{sky.n_clusters} {sky.n_eff_clusters}\n")
-        spatial_file.write(
-            " ".join(f"{x:f}" for x in np.asarray(rr_c)) + "\n")
-        spatial_file.write(
-            " ".join(f"{x:f}" for x in np.asarray(tt_c)) + "\n")
-
-    spatial_phi = None
-    if spatial_file is not None:
-        from sagecal_tpu.consensus import spatial as sp
-        # loop-invariant basis: built once, closed over by the writer
-        spatial_phi = sp.phi_padded(cmask, *spatial_coords,
-                                    spatialreg[2], spatialreg[0])
-
-    def write_spatial_model(Z_np):
+    def _write_spatial_model(self, Z_np):
         """One interval's Zspat rows — DELIBERATE format deviation from
         the reference (see MIGRATION.md "spatial_ solution files"):
         the reference (master :986-994) dumps the complex Zspat buffer
@@ -647,64 +780,32 @@ def _main_consensus(args, dtrace) -> int:
         carries its row index then 2G re/im pairs in FORWARD cluster
         order — self-describing text instead of a memory-layout dump.
         tests/test_aux.py::test_admm_spatialreg_runs pins this format."""
+        import jax.numpy as jnp
         from sagecal_tpu.consensus import spatial as sp
-        _l2, sh_mu, _n0, fista_iters, _cad = spatialreg
-        Phi, Phikk = spatial_phi
+        _l2, sh_mu, _n0, fista_iters, _cad = self.spatialreg
+        Phi, Phikk = self._spatial_phi
         Zb = sp.z_r8_to_blocks(jnp.asarray(Z_np)).astype(jnp.complex64)
         Zspat = np.asarray(sp.fista_spatialreg(
             Zb, jnp.asarray(Phikk, jnp.complex64),
             jnp.asarray(Phi, jnp.complex64), sh_mu, int(fista_iters)))
         for p in range(Zspat.shape[0]):
-            spatial_file.write(
+            self.spatial_file.write(
                 f"{p} " + " ".join(f"{z.real:e} {z.imag:e}"
                                    for z in Zspat[p]) + "\n")
 
-    # -B beam: the element/array-factor tables are tile-invariant, so
-    # the static leaves are stacked + staged ONCE here; inside the tile
-    # loop only the [tilesz] gmst time track is restaged (round-5
-    # ADVICE: the old loop re-transferred every leaf each interval).
-    # The diag stage_bytes records quantify the saving per tile.
-    beamF_static = None
-    beam_static_dev = None
-    if dobeam:
-        from sagecal_tpu import coords as _coords
-        beamF_static = jax.tree.map(
-            lambda *xs: np.stack([np.asarray(x) for x in xs]),
-            *beams_static)
-        beamF_pad = beamF_static
-        if fpad > nf:       # padded mesh slots reuse subband 0's beam
-            beamF_pad = jax.tree.map(lambda a: np.concatenate(
-                [a, np.repeat(a[:1], fpad - nf, axis=0)]), beamF_static)
-        beam_static_dev = jax.tree.map(stage, beamF_pad)
-        dtrace.emit("stage_bytes", what="beam_static",
-                    bytes=int(sum(np.asarray(l).nbytes
-                                  for l in jax.tree.leaves(beamF_pad))))
+    # -- reader-thread half ---------------------------------------------------
 
-    # per-subband worker files, written unconditionally like the
-    # reference slaves ("always create default solution file name
-    # MS+'.solutions'", sagecal_slave.cpp:167-168). Opened only AFTER
-    # -q is read: a previous run's worker file is a valid warm-start
-    # source and must not be truncated before read_warm_start sees it.
-    # Multi-host note: unlike the reference's per-node slave writes,
-    # ONLY process 0 writes these files (shared-filesystem assumption;
-    # see MIGRATION.md "per-subband worker files").
-    worker_writers = []
-    if is_writer:
-        interval_min = meta0["tilesz"] * meta0["tdelta"] / 60.0
-        worker_writers = [
-            sol.SolutionWriter(
-                m.path.rstrip("/") + ".solutions",
-                float(m.meta["freq0"]), float(m.meta["fdelta"]),
-                interval_min, n, sky.n_clusters, sky.n_eff_clusters)
-            for m in mss]
-
-    def _prep_tiles(tiles):
+    def _prep_tiles(self, tiles):
         """One interval's solve inputs from its subband tiles: the
         shared staging decision (VisTile.solve_input — per-channel
         packing when cflags exist, plain mean else), solve-scoped
         uv-cut flags (predict.c:876 rule; originals restored before
         write-back), optional -W whitening, and the per-subband
         unflagged fraction that scales rho (master :646-650)."""
+        import jax.numpy as jnp
+        from sagecal_tpu.rime import predict as rp
+        from sagecal_tpu.solvers import lm as lm_mod
+        args, rdt = self.args, self.rdt
         x8_l, wt_l, fr_l = [], [], []
         uvcut_on = args.uvmin > 0.0 or args.uvmax < 1e9
         orig_flags = [t.flags for t in tiles]
@@ -730,244 +831,282 @@ def _main_consensus(args, dtrace) -> int:
                 np.stack([t.w for t in tiles]), np.stack(wt_l),
                 np.array(fr_l))
 
-    if args.time_shard > 1:
-        return _consensus_time_sharded(
-            args, dtrace, mss=mss, meta0=meta0, freqs=freqs, sky=sky,
-            dsky=dsky, cfg=cfg, Bpoly=Bpoly, rdt=rdt, sdt=sdt,
-            cidx=cidx, cmask=cmask, n=n, t0=t0, start=start, stop=stop,
-            Jinit=Jinit, res_jit=res_jit, writer=writer,
-            worker_writers=worker_writers, is_writer=is_writer,
-            prep_tiles=_prep_tiles)
+    def read(self, i):
+        """All subbands' tiles of selected interval ``i``."""
+        return [m.read_tile(self.start + i) for m in self.mss]
 
-    # overlapped execution (sagecal_tpu.sched): read all subbands of
-    # interval t+N on a background thread while interval t solves, and
-    # drain residual/solution writes on an ordered writer thread;
-    # --prefetch 0 is the synchronous escape hatch. Bit-identical: the
-    # warm-start chain (J0 carry) stays sequential, only data movement
-    # overlaps.
-    from sagecal_tpu import sched
-
-    pf_depth = max(0, int(getattr(args, "prefetch", 1)))
-    aw = sched.AsyncWriter(enabled=pf_depth > 0)
-    source = sched.Prefetcher(
-        lambda i: [m.read_tile(start + i) for m in mss],
-        stop - start, depth=pf_depth, tile0=start)
-
-    try:
-        for _i, tiles, io_wait in source:
-            ti = start + _i
-            aw.check()      # async write failure -> fail at this boundary
-            x8F, uF, vF, wF, wtF, fratioF = _prep_tiles(tiles)
-
+    def stage(self, i, tiles):
+        """Interval ``i``'s solve inputs on the mesh: ``_prep_tiles``,
+        ``pad_subbands``, host to device. Everything but the warm-start
+        J0, which is the previous step's to give (``step`` stages it)."""
+        from sagecal_tpu.consensus import admm as cadmm
+        from sagecal_tpu.diag import trace as dtrace
+        ti = self.start + i
+        nf, rdt, sdt = self.nf, self.rdt, self.sdt
+        with dtrace.phase("stage", tile=ti, bg=self.depth > 0):
+            x8F, uF, vF, wF, wtF, fratioF = self._prep_tiles(tiles)
             padded, _, _ = cadmm.pad_subbands(
-                (x8F, uF, vF, wF, freqs, wtF, fratioF, J0), Bpoly, nf, ndev)
+                (x8F, uF, vF, wF, self.freqs, wtF, fratioF), self.Bpoly,
+                nf, self.ndev)
             # dtype policy: visibilities + weights stage in the storage
-            # dtype; geometry/frequencies/J0 keep the pipeline dtype
-            pdts = (sdt, rdt, rdt, rdt, rdt, sdt, rdt, rdt)
-            args_dev = [stage(np.asarray(a, np.dtype(d)))
+            # dtype; geometry/frequencies keep the pipeline dtype
+            pdts = (sdt, rdt, rdt, rdt, rdt, sdt, rdt)
+            args_dev = [self._to_device(np.asarray(a, np.dtype(d)))
                         for a, d in zip(padded, pdts)]
-            if dtrace.active():
-                dtrace.emit("stage_bytes", what="tile_inputs", tile=ti,
-                            bytes=int(sum(
-                                np.asarray(a).size * np.dtype(d).itemsize
-                                for a, d in zip(padded, pdts))))
-            gmstF = None
-            if dobeam:
+            nbytes = int(sum(np.asarray(a).size * np.dtype(d).itemsize
+                             for a, d in zip(padded, pdts)))
+            gmstF = beam_dev = None
+            if self.dobeam:
                 # only the per-tile gmst time track crosses host->device
-                # here; the static tables were staged once before the loop
+                # here; the static tables were staged once at set-up
+                from sagecal_tpu import coords as _coords
                 gmstF = np.stack(
                     [np.asarray(_coords.jd2gmst_np(t.time_jd))
                      for t in tiles]).astype(np.dtype(rdt))
-                if fpad > nf:   # padded mesh slots reuse subband 0's track
+                if self.fpad > nf:  # padded mesh slots reuse subband 0's
                     gmstF = np.concatenate(
-                        [gmstF, np.repeat(gmstF[:1], fpad - nf, axis=0)])
-                args_dev.append(beam_static_dev._replace(gmst=stage(gmstF)))
-                dtrace.emit("stage_bytes", what="beam_gmst", tile=ti,
-                            bytes=int(gmstF.nbytes))
-            if blk_timer is not None:
-                blk_timer.clear()
-            with dtrace.phase("solve", tile=ti):
-                JF_r8, Z, rhoF, res0, res1, r1s, duals, Y0F = runner(
-                    *args_dev)
-                if dtrace.active():
-                    # the traced plan is ONE device execution per
-                    # interval: time it to its end (the fetch below
-                    # would block on it anyway)
-                    jax.block_until_ready(JF_r8)
-            if (ti == start and is_writer
-                    and hasattr(JF_r8, "addressable_shards")):
-                # where the subband shards of the solve's output live
-                print("Shard devices: " + " ".join(sorted(
-                    {str(s.device) for s in JF_r8.addressable_shards})))
-            if blk_timer is not None and is_writer:
-                # per-ADMM-iteration wall-clock from the blocked runner's
-                # per-execution telemetry (solve blocks + consensus); the
-                # first tile's numbers include compilation
-                nblk = -(-fpad // args.block_f)
-                times = [t for _, t in blk_timer]
-                per_iter = [sum(times[i * (nblk + 1):(i + 1) * (nblk + 1)])
-                            for i in range(cfg.n_admm)]
-                print("ADMM wall-clock/iter: "
-                      + " ".join(f"{t:.2f}s" for t in per_iter)
-                      + f" (blocks of {args.block_f} subbands, "
-                      f"{nblk} solve executions + 1 consensus each)")
-            # slice padded subband rows off every per-subband output
-            JF_r8 = fetch(JF_r8)[:nf]
-            JF_r8_5 = np.asarray(JF_r8).reshape(nf, sky.n_clusters, kmax, n, 8)
-            if worker_writers:
-                J_all = utils.jones_r2c_np(JF_r8_5)
+                        [gmstF, np.repeat(gmstF[:1], self.fpad - nf,
+                                          axis=0)])
+                beam_dev = self._beam_static_dev._replace(
+                    gmst=self._to_device(gmstF))
+        return dict(args_dev=args_dev, nbytes=nbytes, uvw=(uF, vF, wF),
+                    fratio=fratioF, gmst=gmstF, beam=beam_dev)
 
-                def _write_workers(J_all=J_all):
-                    for f, ww in enumerate(worker_writers):
-                        ww.write_interval(J_all[f], sky.nchunk)
-                aw.submit(_write_workers)
+    # -- device-owner half ----------------------------------------------------
+
+    def step(self, ti, tiles, staged, io_wait=0.0):
+        """Solve interval ``ti`` (dataset tile number), carry the warm
+        start, submit its writes in order. Returns the interval's
+        history record."""
+        import jax
+        import jax.numpy as jnp
+        from sagecal_tpu import sched
+        from sagecal_tpu.consensus import admm as cadmm
+        from sagecal_tpu.diag import trace as dtrace
+        args, cfg, sky, log = self.args, self.cfg, self.sky, self.log
+        nf, n, kmax, rdt, sdt = self.nf, self.n, self.kmax, self.rdt, self.sdt
+        Bpoly, freqs, is_writer = self.Bpoly, self.freqs, self.is_writer
+        aw = self.aw
+        aw.check()      # async write failure -> fail at this boundary
+        bubble = io_wait
+        uF, vF, wF = staged["uvw"]
+        fratioF = staged["fratio"]
+
+        # the warm-start chain is this thread's: J0 is staged here, the
+        # interval's data came staged from the reader
+        (J0p,), _, _ = cadmm.pad_subbands((self.J0,), Bpoly, nf, self.ndev)
+        args_dev = list(staged["args_dev"]) + [self._to_device(
+            np.asarray(J0p, np.dtype(rdt)))]
+        if dtrace.active():
+            dtrace.emit("stage_bytes", what="tile_inputs", tile=ti,
+                        bytes=staged["nbytes"] + int(
+                            np.asarray(J0p).size * np.dtype(rdt).itemsize))
+        gmstF = staged["gmst"]
+        if self.dobeam:
+            args_dev.append(staged["beam"])
+            dtrace.emit("stage_bytes", what="beam_gmst", tile=ti,
+                        bytes=int(gmstF.nbytes))
+        blk_timer = self.blk_timer
+        if blk_timer is not None:
+            blk_timer.clear()
+        with dtrace.phase("solve", tile=ti):
+            JF_r8, Z, rhoF, res0, res1, r1s, duals, Y0F = self.runner(
+                *args_dev)
+            if dtrace.active():
+                # the traced plan is ONE device execution per
+                # interval: time it to its end (the fetch below
+                # would block on it anyway)
+                jax.block_until_ready(JF_r8)
+        self._rhoF = rhoF
+        if (ti == self.start and is_writer
+                and hasattr(JF_r8, "addressable_shards")):
+            # where the subband shards of the solve's output live
+            log("Shard devices: " + " ".join(sorted(
+                {str(s.device) for s in JF_r8.addressable_shards})))
+        if blk_timer is not None and is_writer:
+            # per-ADMM-iteration wall-clock from the blocked runner's
+            # per-execution telemetry (solve blocks + consensus); the
+            # first tile's numbers include compilation
+            nblk = -(-self.fpad // args.block_f)
+            times = [t for _, t in blk_timer]
+            per_iter = [sum(times[i * (nblk + 1):(i + 1) * (nblk + 1)])
+                        for i in range(cfg.n_admm)]
+            log("ADMM wall-clock/iter: "
+                + " ".join(f"{t:.2f}s" for t in per_iter)
+                + f" (blocks of {args.block_f} subbands, "
+                f"{nblk} solve executions + 1 consensus each)")
+        with dtrace.phase("fetch", tile=ti):
+            # slice padded subband rows off every per-subband output
+            fetch = self._fetch
+            JF_r8 = fetch(JF_r8)[:nf]
             Z = fetch(Z)
             res0, res1 = fetch(res0)[:nf], fetch(res1)[:nf]
             r1s = fetch(r1s)[:, :nf]
             duals = fetch(duals)
             Y0F = fetch(Y0F)[:nf]
+        JF_r8_5 = np.asarray(JF_r8).reshape(nf, sky.n_clusters, kmax, n, 8)
+        if self.worker_writers:
+            J_all = utils.jones_r2c_np(JF_r8_5)
 
-            if args.mdl and ti == start and is_writer:
-                # model-order report from iteration-0 rho*J (master :815-822)
-                from sagecal_tpu.consensus import mdl as mdlmod
-                res = mdlmod.minimum_description_length(
-                    np.asarray(Y0F), np.broadcast_to(
-                        np.asarray(rho0, float), (sky.n_clusters,)),
-                    freqs, float(freqs.mean()), weight=fratioF,
-                    polytype=args.polytype, kstart=1, kfinish=args.npoly)
-                mdlmod.report(res)
+            def _write_workers(J_all=J_all):
+                for f, ww in enumerate(self.worker_writers):
+                    ww.write_interval(J_all[f], sky.nchunk)
+            bubble += aw.submit(_write_workers)
 
-            res0 = np.asarray(res0)
-            res1 = np.asarray(r1s)[-1] if cfg.n_admm > 1 else np.asarray(res1)
-            duals = np.asarray(duals)
+        if args.mdl and ti == self.start and is_writer:
+            # model-order report from iteration-0 rho*J (master :815-822)
+            from sagecal_tpu.consensus import mdl as mdlmod
+            res = mdlmod.minimum_description_length(
+                np.asarray(Y0F), np.broadcast_to(
+                    np.asarray(self.rho0, float), (sky.n_clusters,)),
+                freqs, float(freqs.mean()), weight=fratioF,
+                polytype=args.polytype, kstart=1, kfinish=args.npoly)
+            mdlmod.report(res)
 
-            if dtrace.active() or obs.active():
-                # per-ADMM-iteration convergence records from the fetched
-                # telemetry. The host-loop, blocked and stale runners
-                # already emit live per-iteration records (admm.py feeds
-                # BOTH the trace and the obs gauges there), so only the
-                # fully traced mesh program needs the post-hoc emission.
-                if (not args.host_loop and not args.block_f
-                        and not args.staleness):
-                    for k in range(np.asarray(r1s).shape[0]):
-                        r1m = float(np.asarray(r1s)[k].mean())
-                        du = float(duals[k]) if len(duals) else 0.0
-                        dtrace.emit("admm_iter", interval=ti, iter=k + 1,
-                                    r1_mean=r1m, dual=du)
-                        if obs.active():
-                            obs.inc("admm_iterations_total")
-                            obs.set_gauge("admm_primal_residual", r1m)
-                            obs.set_gauge("admm_dual_residual", du)
-                # interval summary with the consensus primal residual
-                # ||J - BZ|| (the reference master's convergence axis)
-                BZf = np.einsum("fp,mpknr->fmknr", Bpoly, np.asarray(Z))
-                primal = float(
-                    np.linalg.norm(JF_r8_5 - BZf) / np.sqrt(BZf.size))
-                dtrace.emit("tile", tile=ti, res_0=float(res0.mean()),
-                            res_1=float(res1.mean()), primal=primal,
-                            rho_mean=float(np.asarray(fetch(rhoF))[:nf]
-                                           .mean()))
-                if obs.active():
-                    obs.inc("tiles_solved_total")
-                    obs.set_gauge("consensus_primal_residual", primal)
+        res0 = np.asarray(res0)
+        res1_it0 = np.asarray(res1)     # iteration 0's plain solve
+        res1 = np.asarray(r1s)[-1] if cfg.n_admm > 1 else res1_it0
+        duals = np.asarray(duals)
+        # the consensus primal residual ||J - BZ|| (the reference
+        # master's convergence axis)
+        BZf = np.einsum("fp,mpknr->fmknr", Bpoly, np.asarray(Z))
+        primal = float(np.linalg.norm(JF_r8_5 - BZf) / np.sqrt(BZf.size))
+        rec = {"tile": ti, "res_0": float(res0.mean()),
+               "res_1": float(res1.mean()), "primal": primal,
+               "dual": float(duals[-1]) if len(duals) else 0.0}
+        self.history.append(rec)
 
-            # warm-start the next interval; per-subband divergence reset
-            # (slave :680-683 res_ratio check; fullbatch warm-start analogue)
-            J_new = np.asarray(JF_r8)
-            bad = (~np.isfinite(res1)) | (res1 == 0.0) | (res1 > 5.0 * res0)
-            for f in range(nf):
-                J0[f] = Jinit[f] if bad[f] else J_new[f]
-                if bad[f] and is_writer:
-                    print(f"  subband {f}: diverged; Resetting Solution")
-            if is_writer:
-                print(f"Timeslot:{ti} ADMM:{cfg.n_admm} residual "
-                      f"initial={res0.mean():.6g} final={res1.mean():.6g} "
-                      f"dual={duals[-1] if len(duals) else 0:.3g}")
-                if args.verbose:
-                    for f in range(nf):
-                        print(f"  subband {f}: {res0[f]:.6g} -> {res1[f]:.6g}")
+        if dtrace.active() or obs.active():
+            # per-ADMM-iteration convergence records from the fetched
+            # telemetry. The host-loop, blocked and stale runners
+            # already emit live per-iteration records (admm.py feeds
+            # BOTH the trace and the obs gauges there), so only the
+            # fully traced mesh program needs the post-hoc emission.
+            if (not args.host_loop and not args.block_f
+                    and not args.staleness):
+                # one record an ADMM iteration, iteration 0 (the plain
+                # solve, no dual yet) included, as the host loop's
+                r1_it = [res1_it0] + list(np.asarray(r1s))
+                for k, r1k in enumerate(r1_it):
+                    r1m = float(r1k.mean())
+                    du = float(duals[k - 1]) if k else 0.0
+                    dtrace.emit("admm_iter", interval=ti, iter=k,
+                                r1_mean=r1m, dual=du)
+                    if obs.active():
+                        obs.inc("admm_iterations_total")
+                        obs.set_gauge("admm_primal_residual", r1m)
+                        obs.set_gauge("admm_dual_residual", du)
+            if obs.active():
+                obs.inc("tiles_solved_total")
+                obs.set_gauge("consensus_primal_residual", primal)
 
-            # residuals + write back (slave :832-869); multi-host: process 0
-            # owns all outputs (shared-filesystem assumption, like the
-            # reference's slaves-glob-the-same-paths setup)
-            if is_writer:
-                if args.use_global_solution:
-                    # evaluate BZ at each subband: smooth consensus solutions
-                    BZ = np.einsum("fp,mpknr->fmknr", Bpoly, np.asarray(Z))
-                    J_res = BZ.reshape(nf, sky.n_clusters, kmax, n, 8)
-                else:
-                    J_res = JF_r8_5
+        # warm-start the next interval; per-subband divergence reset
+        # (slave :680-683 res_ratio check; fullbatch warm-start analogue)
+        J_new = np.asarray(JF_r8)
+        bad = (~np.isfinite(res1)) | (res1 == 0.0) | (res1 > 5.0 * res0)
+        for f in range(nf):
+            self.J0[f] = self.Jinit[f] if bad[f] else J_new[f]
+            if bad[f] and is_writer:
+                log(f"  subband {f}: diverged; Resetting Solution")
+        if is_writer:
+            log(f"Timeslot:{ti} ADMM:{cfg.n_admm} residual "
+                f"initial={res0.mean():.6g} final={res1.mean():.6g} "
+                f"dual={duals[-1] if len(duals) else 0:.3g}")
+            if args.verbose:
+                for f in range(nf):
+                    log(f"  subband {f}: {res0[f]:.6g} -> {res1[f]:.6g}")
+
+        # residuals + write back (slave :832-869); multi-host: process 0
+        # owns all outputs (shared-filesystem assumption, like the
+        # reference's slaves-glob-the-same-paths setup)
+        if is_writer:
+            if args.use_global_solution:
+                # evaluate BZ at each subband: smooth consensus solutions
+                J_res = BZf.reshape(nf, sky.n_clusters, kmax, n, 8)
+            else:
+                J_res = JF_r8_5
+            with dtrace.phase("residual", tile=ti):     # a dispatch
                 xF_r = np.stack([utils.c2r(t.x) for t in tiles])
                 bargs = ()
-                if dobeam:
+                if self.dobeam:
                     # residual beam: the UNPADDED nf subbands with this
                     # tile's gmst track
                     bargs = (jax.tree.map(
                         lambda a: jnp.asarray(a),
-                        beamF_static._replace(gmst=gmstF[:nf])),)
-                res_r = res_jit(jnp.asarray(J_res, rdt),
-                                jnp.asarray(xF_r, sdt),
-                                jnp.asarray(uF, rdt), jnp.asarray(vF, rdt),
-                                jnp.asarray(wF, rdt), jnp.asarray(freqs, rdt),
-                                *bargs)
+                        self._beamF_static._replace(gmst=gmstF[:nf])),)
+                res_r = self.res_jit(
+                    jnp.asarray(J_res, rdt), jnp.asarray(xF_r, sdt),
+                    jnp.asarray(uF, rdt), jnp.asarray(vF, rdt),
+                    jnp.asarray(wF, rdt), jnp.asarray(freqs, rdt), *bargs)
+            mss, bg = self.mss, self.depth > 0
 
-                def _write_res(ti=ti, tiles=tiles, res_r=res_r):
-                    with dtrace.phase("write", tile=ti, bg=pf_depth > 0):
-                        # fetch through float64 (numpy-side r2c has no
-                        # ml_dtypes bf16 path; the MS is complex128)
-                        res_np = utils.r2c(np.asarray(res_r, np.float64))
-                        for f, (msx, t) in enumerate(zip(mss, tiles)):
-                            t.x = res_np[f].astype(np.complex128)
-                            msx.write_tile(ti, t)
-                # non-blocking d->h copy now; fetch + per-subband write on
-                # the ordered writer thread
-                sched.start_host_copy(res_r)
-                aw.submit(_write_res)
+            def _write_res(ti=ti, tiles=tiles, res_r=res_r):
+                with dtrace.phase("write", tile=ti, bg=bg):
+                    # fetch through float64 (numpy-side r2c has no
+                    # ml_dtypes bf16 path; the MS is complex128)
+                    res_np = utils.r2c(np.asarray(res_r, np.float64))
+                    for f, (msx, t) in enumerate(zip(mss, tiles)):
+                        t.x = res_np[f].astype(np.complex128)
+                        msx.write_tile(ti, t)
+            # non-blocking d->h copy now; fetch + per-subband write on
+            # the ordered writer thread
+            sched.start_host_copy(res_r)
+            bubble += aw.submit(_write_res)
 
-            if spatial_file is not None:
-                write_spatial_model(np.asarray(Z))
-            if writer:
-                # Z coefficient columns: [M, P, K, N, 8] -> Jones-like blocks
-                Zr = np.asarray(Z)
-                Zj = utils.jones_r2c_np(
-                    Zr.transpose(0, 2, 1, 3, 4).reshape(
-                        sky.n_clusters, kmax * args.npoly, n, 8))
-                nchunk_poly = sky.nchunk * args.npoly
-                aw.submit(writer.write_interval, Zj, nchunk_poly)
+        if self.spatial_file is not None:
+            self._write_spatial_model(np.asarray(Z))
+        if self.writer:
+            # Z coefficient columns: [M, P, K, N, 8] -> Jones-like blocks
+            Zr = np.asarray(Z)
+            Zj = utils.jones_r2c_np(
+                Zr.transpose(0, 2, 1, 3, 4).reshape(
+                    sky.n_clusters, kmax * args.npoly, n, 8))
+            nchunk_poly = sky.nchunk * args.npoly
+            bubble += aw.submit(self.writer.write_interval, Zj, nchunk_poly)
 
-    finally:
-        # a mid-loop failure (solver error, reader-thread or async
-        # writer exception) must still cancel the prefetch thread and
-        # drain/raise the ordered write queue — otherwise completed
-        # intervals' queued writes are silently dropped, diverging
-        # from the --prefetch 0 inline-write behavior
-        source.close()
-        aw.close()
-    if ppriors.writes(prior_mode) and stop > start:
-        # bank the last accepted chain (J0 already has the divergence
-        # resets applied) + the final per-cluster rho, subband-mean of
-        # the mesh's [F, M] schedule. Runs only after aw.close() — the
-        # banked prior can only name durably written outputs.
-        try:
-            span = float(meta0["tilesz"]) * float(meta0["tdelta"])
-            pt = (float(stop - 1)
-                  + (np.arange(kmax) + 0.5) / kmax) * span
-            Jc = utils.jones_r2c_np(np.asarray(J0))  # [F, M, K, N, 2, 2]
-            rho_f = np.asarray(fetch(rhoF))[:nf]
-            rho_m = rho_f.mean(axis=0) if rho_f.ndim == 2 else None
-            ppriors.PRIORS.bank(
-                prior_k, np.transpose(Jc, (0, 2, 1, 3, 4, 5)), pt,
-                freqs.astype(np.float64), rho=rho_m)
-        except Exception as e:
-            if is_writer:
-                print(f"prior-cache: bank skipped ({e})")
-    if writer:
-        writer.close()
-    if spatial_file is not None:
-        spatial_file.close()
-    for ww in worker_writers:
-        ww.close()
-    return 0
+        if dtrace.active():
+            # interval summary: bubble_s is the host seconds this step
+            # was blocked on data movement (the wait for the staged
+            # interval, writer back-pressure), overlap the prefetch depth
+            dtrace.emit("tile", tile=ti, res_0=rec["res_0"],
+                        res_1=rec["res_1"], primal=primal,
+                        rho_mean=float(np.asarray(self._fetch(rhoF))[:nf]
+                                       .mean()),
+                        bubble_s=float(bubble), overlap=self.depth)
+        self._last = ti
+        return rec
+
+    def close(self):
+        """Drain the ordered writer (re-raising a pending write
+        failure), bank the prior where every selected interval was
+        stepped, close the solution files."""
+        self.aw.close()
+        if (ppriors.writes(self.prior_mode) and self.stop > self.start
+                and self._last == self.stop - 1):
+            # bank the last accepted chain (J0 already has the divergence
+            # resets applied) + the final per-cluster rho, subband-mean of
+            # the mesh's [F, M] schedule. Runs only after aw.close() — the
+            # banked prior can only name durably written outputs.
+            try:
+                meta0, kmax = self.meta0, self.kmax
+                span = float(meta0["tilesz"]) * float(meta0["tdelta"])
+                pt = (float(self.stop - 1)
+                      + (np.arange(kmax) + 0.5) / kmax) * span
+                Jc = utils.jones_r2c_np(np.asarray(self.J0))
+                rho_f = np.asarray(self._fetch(self._rhoF))[:self.nf]
+                rho_m = rho_f.mean(axis=0) if rho_f.ndim == 2 else None
+                ppriors.PRIORS.bank(
+                    self.prior_k, np.transpose(Jc, (0, 2, 1, 3, 4, 5)), pt,
+                    self.freqs.astype(np.float64), rho=rho_m)
+            except Exception as e:
+                if self.is_writer:
+                    self.log(f"prior-cache: bank skipped ({e})")
+        if self.writer:
+            self.writer.close()
+        if self.spatial_file is not None:
+            self.spatial_file.close()
+        for ww in self.worker_writers:
+            ww.close()
 
 
 def _consensus_time_sharded(args, dtrace, *, mss, meta0, freqs, sky,

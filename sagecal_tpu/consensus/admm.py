@@ -66,6 +66,14 @@ class ADMMConfig(NamedTuple):
     federated_alpha: float = 0.0  # -u : alpha of the spatial/federated prior
 
 
+#: device scope (``jax.named_scope``, metadata only) of what consensus
+#: adds to a J update: the z-sum ``psum``, the ``Bii`` solve, the dual
+#: and rho updates. Under ``sage/`` beside the solver's own scopes
+#: (``sage/prelude|sweep|refine|final``) and ``sage/manifold``, where a
+#: profiler trace's reader looks for them.
+CONSENSUS_SCOPE = "sage/consensus"
+
+
 def pad_subbands(arrays, B_poly, nf: int, ndev: int):
     """THE padding contract for uneven F over the mesh, in one place.
 
@@ -106,6 +114,7 @@ def _unblocks(X, m, k, n):
     return ne.jones_c2r(J)
 
 
+@jax.named_scope("sage/manifold")    # beside sage/consensus in a trace
 def manifold_average_mesh(Y_r8, axis_name, nf_total: int, m: int,
                           k: int, n: int, niter: int = 20):
     """Mesh version of calculate_manifold_average over the freq axis.
@@ -260,8 +269,14 @@ def make_admm_runner(dsky, sta1, sta2, cidx, cmask, n_stations: int,
     def coh_for(u, v, w, freq, beam=None):
         # with -B: per-subband beam tables folded into the source sum
         # (precalculate_coherencies_multifreq_withbeam, the slaves'
-        # predict path predict_withbeam.c:690)
+        # predict path predict_withbeam.c:690). Fluxes at THIS subband's
+        # frequency along each source's spectral index, as the residual
+        # program takes them and as an upstream slave reads its sky at
+        # its own MS's frequency: ``dsky`` holds one scaling, at the mean
+        # of all subbands, and a J solved against that absorbs the flux
+        # ratio, which the residual then applies a second time
         return rp.coherencies(dsky, u, v, w, freq[None], fdelta,
+                              per_channel_flux=True,
                               with_shapelets=with_shapelets,
                               beam=beam, dobeam=dobeam, tslot=tslot_j,
                               sta1=sta1_j, sta2=sta2_j)[:, :, 0]
@@ -388,12 +403,13 @@ def make_admm_runner(dsky, sta1, sta2, cidx, cmask, n_stations: int,
         Xd = jnp.zeros_like(Zbar)
 
         # iteration 0 Z update: Y currently = rho*J (manifold-aligned)
-        Z = z_update(Brow, YF, rhoF, alpha_vec, ax=ax)
-        if spat is not None:
-            # admm==0 matches !(admm % cadence) (master :789)
-            Zbar, Xd = spatial_step(Z, Zbar, Xd, dtype)
-        BZ = jnp.einsum("fp,mpknr->fmknr", Brow, Z)
-        YF = YF - rhoF[..., None, None, None] * BZ   # dual (slave :750)
+        with jax.named_scope(CONSENSUS_SCOPE):
+            Z = z_update(Brow, YF, rhoF, alpha_vec, ax=ax)
+            if spat is not None:
+                # admm==0 matches !(admm % cadence) (master :789)
+                Zbar, Xd = spatial_step(Z, Zbar, Xd, dtype)
+            BZ = jnp.einsum("fp,mpknr->fmknr", Brow, Z)
+            YF = YF - rhoF[..., None, None, None] * BZ  # dual (slave :750)
 
         carry = (JF, YF, Z, rhoF, YF, JF.reshape(Fl, M, K, N, 8),
                  Zbar, Xd, rhoF)
@@ -422,6 +438,7 @@ def make_admm_runner(dsky, sta1, sta2, cidx, cmask, n_stations: int,
             x8F, uF, vF, wF, wtF, J0F, freqF, beamF)
         return iter0_post(JF, res0, res1, fratioF)
 
+    @jax.named_scope(CONSENSUS_SCOPE)
     def body_post(Jr, r0, r1, carry, it, ax=axis):
         """Everything after iteration k>0's solves (slave :686-770)."""
         JF, YF, Z, rhoF, Yhat_prev, Jprev, Zbar, Xd, rho_upper = carry
@@ -468,8 +485,8 @@ def make_admm_runner(dsky, sta1, sta2, cidx, cmask, n_stations: int,
     def body_local(x8F, uF, vF, wF, freqF, wtF, carry, it, beamF=None):
         """One ADMM iteration k>0 on the LOCAL shard (slave :686-770)."""
         Fl = x8F.shape[0]
-        Brow = _brow(Fl)
-        BZ = jnp.einsum("fp,mpknr->fmknr", Brow, carry[2])
+        with jax.named_scope(CONSENSUS_SCOPE):
+            BZ = jnp.einsum("fp,mpknr->fmknr", _brow(Fl), carry[2])
         Jr, r0, r1 = _per_subband(local_solve_admm)(
             x8F, uF, vF, wF, wtF, carry[0], freqF, carry[1], BZ,
             carry[3], beamF)

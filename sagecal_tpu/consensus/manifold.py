@@ -137,6 +137,7 @@ def extract_phases(J, niter: int = 10):
                       jnp.stack([zero, d1], -1)], -2)
 
 
+@jax.named_scope("sage/manifold")
 def manifold_average(J, niter: int = 3, ref_index: int = 0):
     """Frequency-average solutions up to unitary ambiguity.
 

@@ -296,8 +296,11 @@ def solver_trip_cost(solver_mode, kmax, n_stations, B, dtype, nbase=0,
     + cost + projected gradient, plus tcg_iters Hessian-vector products
     ([K,8N,8N]@[K,8N] matvec + tangent projection each, rtr.py _tcg;
     under inner="cg" the product is the matrix-free gn_matvec and the
-    assembly is gn_factors — the trip count stays static, so the whole
-    correction still rides this one price).
+    assembly is gn_factors). NOTE: tcg_iters is the CAP of _tcg's loop,
+    not the count it executes — the loop ends when every chunk has
+    stopped (a tenth of the cap at the benchmark's shapes) and the
+    executed bodies are info["cg_iters"]; this price still charges the
+    cap a trip and so overstates an RTR solve's work.
     NSD (mode 6): one Nesterov step = projected gradient + the static
     ls_tries backtracking cost evaluations (rtr.py nsd_solve_robust) —
     no Cholesky/assembly, which the LM price would wrongly charge.
@@ -1087,7 +1090,12 @@ def time_sage(device, dtype, sky, dsky, tiles, solver_mode, reps=2,
         cost_step = rl.trip_correct(cost_step, tf, trips)
         cost_step = rl.trip_correct(cost_step, rf, refine_trips)
         cf = None
-        if inner == "cg" and cg_trips:
+        from sagecal_tpu.config import SolverMode
+        # an RTR mode's info["cg_iters"] are tCG bodies, which
+        # solver_trip_cost already prices inside a trip
+        is_rtr = int(solver_mode) in (int(SolverMode.RTR_OSLM_LBFGS),
+                                      int(SolverMode.RTR_OSRLM_RLBFGS))
+        if inner == "cg" and cg_trips and not is_rtr:
             # the matrix-free path's Krylov traffic: executed PCG trips
             # (info["cg_iters"]) x one matvec + preconditioner apply
             cf = cg_trip_cost(kmax, n, tile.nrows, sdt,

@@ -43,7 +43,9 @@ event            meaning / required extra fields
                  cli_mpi.py): ``tile``, ``res_0``, ``res_1`` (a
                  simulated tile, ``run_simulation``, solves nothing and
                  carries only ``tile`` and the overlap pair); optional
-                 ``mean_nu``, ``solver_iters``, ``lbfgs_iters``,
+                 ``mean_nu``, ``solver_iters``, ``cg_iters`` (inner CG
+                 trips under them: LM's PCG, RTR's truncated-CG
+                 bodies), ``lbfgs_iters``,
                  ``refine_passes`` (passes through the model the joint
                  refine made: solvers/lbfgs.py), ``minutes``,
                  ``primal``, ``rho_mean``, and the

@@ -29,7 +29,10 @@ TPU re-architecture vs. the reference:
   fns_fcount / iw weights, Dirac.h:1114) is kept as a diagonal
   preconditioner on the euclidean differentials;
 - the truncated-CG inner iteration (rtr_solve.c:886-1155) runs under
-  ``lax.fori_loop`` with convergence masks per chunk.
+  ``lax.while_loop`` with convergence masks per chunk: it ends when
+  every chunk has stopped, as the reference breaks out of its loop, and
+  ``tcg_iters`` is the cap; the bodies executed are counted
+  (info["cg_iters"]).
 
 Robust variants follow the IRLS structure of robust.py: rounds of
 {weighted RTR solve -> Student's-t E-step weight update -> nu grid update}
@@ -250,12 +253,17 @@ def _tcg(hess_fn, rgrad, delta, cfg: RTRConfig):
     """Batched Steihaug-Toint truncated CG (rtr_solve.c:886-1155).
 
     hess_fn: [K, D] -> [K, D] (projected, preconditioned Hessian-vector).
-    Returns (eta [K, D], model_decrease [K]).
+    The loop ends when every chunk is ``done`` (boundary hit, negative
+    curvature or residual target) or at ``cfg.tcg_iters`` bodies; the
+    body freezes a ``done`` chunk, so the state on exit is the one a
+    fixed ``tcg_iters`` trips end with.
+    Returns (eta [K, D], model_decrease [K], bodies executed i32).
     """
     r0n = jnp.sqrt(_dot(rgrad, rgrad))
     target = r0n * jnp.minimum(cfg.kappa, r0n ** cfg.theta)
 
-    def body(_, s: _TCGState):
+    def body(carry):
+        i, s = carry
         Hd = hess_fn(s.d)
         d_Hd = _dot(s.d, Hd)
         alpha = s.r_r / jnp.where(d_Hd != 0, d_Hd, 1.0)
@@ -277,7 +285,7 @@ def _tcg(hess_fn, rgrad, delta, cfg: RTRConfig):
         d_new = -r_new + beta[:, None] * s.d
         done_new = s.done | hit | (jnp.sqrt(rr_new) <= target)
         upd = ~s.done
-        return _TCGState(
+        return i + 1, _TCGState(
             eta=jnp.where(upd[:, None], eta_new, s.eta),
             r=jnp.where(upd[:, None], r_new, s.r),
             d=jnp.where(upd[:, None], d_new, s.d),
@@ -291,8 +299,10 @@ def _tcg(hess_fn, rgrad, delta, cfg: RTRConfig):
                      r_r=r0n * r0n, e_e=jnp.zeros((K,), rgrad.dtype),
                      mdot=jnp.zeros((K,), rgrad.dtype),
                      done=r0n <= 1e-30)
-    out = jax.lax.fori_loop(0, cfg.tcg_iters, body, init)
-    return out.eta, out.mdot
+    trips, out = jax.lax.while_loop(
+        lambda c: (c[0] < cfg.tcg_iters) & jnp.any(~c[1].done),
+        body, (jnp.zeros((), jnp.int32), init))
+    return out.eta, out.mdot, trips
 
 
 class _RTRState(NamedTuple):
@@ -302,6 +312,7 @@ class _RTRState(NamedTuple):
     delta: jax.Array
     stop: jax.Array
     k: jax.Array
+    cg: jax.Array       # i32 tCG bodies executed so far
 
 
 def rtr_solve(x8, coh, sta1, sta2, chunk_id, wt, J0, n_stations: int,
@@ -312,7 +323,8 @@ def rtr_solve(x8, coh, sta1, sta2, chunk_id, wt, J0, n_stations: int,
 
     Same call convention as lm.lm_solve; ``robust_nu`` switches the
     objective to fixed-nu Student's t (the robust wrapper re-estimates nu
-    between calls). Returns (J [K,N,2,2], info).
+    between calls). Returns (J [K,N,2,2], info); ``info["cg_iters"]`` is
+    the tCG bodies executed, summed over the outer iterations.
     """
     kmax = J0.shape[0]
     # dtype policy: storage-quantize the data at entry (identity under
@@ -478,7 +490,7 @@ def rtr_solve(x8, coh, sta1, sta2, chunk_id, wt, J0, n_stations: int,
 
     def body(s: _RTRState):
         hess = make_hess(s.p)
-        eta, md = _tcg(hess, s.g, s.delta, config)
+        eta, md, trips = _tcg(hess, s.g, s.delta, config)
         p_new = s.p + eta
         c_new = cost_fn(p_new)
         rho = (s.cost - c_new + config.rho_regularize) \
@@ -503,17 +515,18 @@ def rtr_solve(x8, coh, sta1, sta2, chunk_id, wt, J0, n_stations: int,
             | (delta <= 1e-12 * jnp.maximum(xnorm0, 1e-30)) \
             | (s.k + 1 >= itmax)
         return _RTRState(p=p, g=g_next, cost=cost, delta=delta, stop=stop,
-                         k=s.k + 1)
+                         k=s.k + 1, cg=s.cg + trips)
 
     init = _RTRState(p=p0, g=g0, cost=cost0, delta=delta0,
                      stop=jnp.zeros((kmax,), bool),
-                     k=jnp.zeros((), jnp.int32))
+                     k=jnp.zeros((), jnp.int32),
+                     cg=jnp.zeros((), jnp.int32))
     final = jax.lax.while_loop(cond, body, init)
     J = p_to_J(final.p)
     J = jnp.where(chunk_mask[:, None, None, None], J,
                   J0 if mode == "full" else Jref)
     return J, {"init_cost": cost0, "final_cost": final.cost,
-               "iters": final.k}
+               "iters": final.k, "cg_iters": final.cg}
 
 
 def rtr_solve_robust(x8, coh, sta1, sta2, chunk_id, wt_base, J0,
@@ -542,15 +555,16 @@ def rtr_solve_robust(x8, coh, sta1, sta2, chunk_id, wt_base, J0,
         nu_new = rb.update_nu_aecm(rb.mean_logsumw(w, mask), nu, p=2,
                                    nulow=nulow, nuhigh=nuhigh)
         return (Jn, nu_new), (info["init_cost"], info["final_cost"],
-                              info["iters"])
+                              info["iters"], info["cg_iters"])
 
     (J, nu), costs = jax.lax.scan(
         round_body, (J0, jnp.asarray(nu0, dtp.acc_dtype(x8.dtype))), None,
         length=wt_rounds)
     # "iters": executed outer TR iterations summed over IRLS rounds
-    # (bench.py MFU trip accounting)
+    # (bench.py MFU trip accounting); "cg_iters": their tCG bodies
     info = {"init_cost": costs[0][0], "final_cost": costs[1][-1],
-            "iters": jnp.sum(costs[2]).astype(jnp.int32)}
+            "iters": jnp.sum(costs[2]).astype(jnp.int32),
+            "cg_iters": jnp.sum(costs[3]).astype(jnp.int32)}
     return J, nu, info
 
 

@@ -293,9 +293,10 @@ def _cluster_solve(mode: int, xdummy, coh_m, sta1, sta2, cidx_m, cmask_m,
     is an lm.OSConfig or None (static). Returns
     (Jn [K,N,2,2], nu_new scalar, init_cost [K], final_cost [K],
     iters i32 scalar — executed inner-solver iterations — and
-    cg_iters i32 scalar — executed PCG trips under inner="cg" (0 on the
-    chol path and on RTR/NSD, whose tCG trip count is static), both for
-    the bench's roofline trip accounting).
+    cg_iters i32 scalar — executed inner CG trips: LM's PCG trips under
+    inner="cg" (0 on its chol path), RTR's truncated-CG bodies (its loop
+    ends when every chunk has stopped, rtr._tcg), 0 for NSD, which has
+    no inner CG; both for the telemetry's trip accounting).
     """
     lm_cfg = lm_mod.LMConfig(itmax=itcap, inner=config.inner,
                              cg_tol=config.cg_tol,
@@ -334,7 +335,7 @@ def _cluster_solve(mode: int, xdummy, coh_m, sta1, sta2, cidx_m, cmask_m,
             chunk_mask=cmask_m, config=rtr_cfg, itmax_dynamic=itermax,
             admm=admm_m, row_period=nbase)
         return (Jn, nu_cj, info["init_cost"], info["final_cost"],
-                info["iters"], zero_i)
+                info["iters"], info["cg_iters"])
 
     if mode == int(SolverMode.RTR_OSRLM_RLBFGS):
         rtr_cfg = rtr_mod.RTRConfig(itmax=itcap, inner=config.inner,
@@ -350,7 +351,7 @@ def _cluster_solve(mode: int, xdummy, coh_m, sta1, sta2, cidx_m, cmask_m,
             chunk_mask=cmask_m, config=rtr_cfg, wt_rounds=2,
             itmax_dynamic=itermax, admm=admm_m, row_period=nbase)
         return (Jn, nu_new, info["init_cost"], info["final_cost"],
-                info["iters"], zero_i)
+                info["iters"], info["cg_iters"])
 
     if mode == int(SolverMode.NSD_RLBFGS):
         nsd_cfg = rtr_mod.NSDConfig(itmax=2 * itcap,
@@ -432,7 +433,8 @@ def _cluster_update(cj, state, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
     ``tk`` an i32[3] counter triple: [0] executed inner-solver
     iterations (roofline trip accounting), [1] rejected group steps
     (always 0 here — only :func:`_group_update` can reject), [2]
-    executed PCG inner trips (SageConfig.inner="cg" only)."""
+    executed inner CG trips (LM's PCG under SageConfig.inner="cg", RTR's
+    truncated-CG bodies; :func:`_cluster_solve`)."""
     J, xres, nerr_acc, nuM, tk = state
     coh_m = jnp.take(coh, cj, axis=0)
     cidx_m = jnp.take(chunk_idx, cj, axis=0)
@@ -688,7 +690,7 @@ def _group_update(cjs, state, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
         # slowest lane finishes; rejected groups still executed them).
         # tk[1]: fully-rejected group steps — the observability hook for
         # "groups are all vetoing" (info['rejected_groups']).
-        # tk[2]: executed PCG inner trips (inner="cg"), same live-lane sum.
+        # tk[2]: executed inner CG trips (LM PCG, RTR tCG), same live-lane sum.
         tk = tk.at[0].add(
             jnp.sum(jnp.where(valid, its_g, 0)).astype(jnp.int32))
         tk = tk.at[1].add((~accept).astype(jnp.int32))

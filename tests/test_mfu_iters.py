@@ -14,7 +14,10 @@ import jax.numpy as jnp
 import pytest
 
 from sagecal_tpu.config import SolverMode
+from sagecal_tpu.solvers import rtr as rtr_mod
 from sagecal_tpu.solvers import sage
+
+TCG_CAP = rtr_mod.RTRConfig().tcg_iters
 
 
 @pytest.fixture(scope="module")
@@ -66,6 +69,51 @@ def test_iters_rtr_bounded(problem):
     cap = M * cfg.max_emiter * 2 * (cfg.max_iter + iter_bar)
     assert 0 < int(info["solver_iters"]) <= cap
     assert int(info["lbfgs_iters"]) == 0
+    # executed tCG bodies: at least one an outer trip, and the cap
+    # (RTRConfig.tcg_iters a trip) is not what a solve runs
+    its = int(info["solver_iters"])
+    assert its <= int(info["cg_iters"]) < its * TCG_CAP
+
+
+# name: (driver, solver mode, inner) -> what ``cg_iters`` has to hold
+_CG_CASES = {
+    "rtr": ("sagefit", SolverMode.RTR_OSLM_LBFGS, "chol"),
+    "rtr-robust-host": ("sagefit_host", SolverMode.RTR_OSRLM_RLBFGS,
+                        "chol"),
+    "lm-chol": ("sagefit", SolverMode.LM_LBFGS, "chol"),
+    "lm-cg": ("sagefit", SolverMode.LM_LBFGS, "cg"),
+    "nsd": ("sagefit", SolverMode.NSD_RLBFGS, "chol"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CG_CASES))
+def test_cg_iters_reach_the_tile_record(problem, case, tmp_path):
+    """``cg_iters`` = executed inner CG trips: RTR's truncated-CG bodies
+    (the traced and the host-driven plan alike), LM's PCG trips under
+    ``inner="cg"``, 0 where there is no inner CG; the ``tile`` record
+    carries the key beside ``solver_iters``."""
+    from sagecal_tpu import pipeline
+    from sagecal_tpu.diag import trace as dtrace
+    driver, mode, inner = _CG_CASES[case]
+    cfg = sage.SageConfig(max_emiter=1, max_iter=3, max_lbfgs=0,
+                          solver_mode=int(mode), inner=inner)
+    _, info = getattr(sage, driver)(*problem, config=cfg)
+    its, cgs = int(info["solver_iters"]), int(info["cg_iters"])
+    assert its > 0
+    if case.startswith("rtr"):
+        assert its <= cgs <= its * TCG_CAP
+    elif case == "lm-cg":
+        assert cgs > 0
+    else:
+        assert cgs == 0
+    path = str(tmp_path / "diag.jsonl")
+    dtrace.enable(path, entry="test", argv=[])
+    try:
+        pipeline._emit_tile_record(0, 1.0, 0.5, 2.0, info, 0.1)
+    finally:
+        dtrace.disable()
+    tile, = [r for r in dtrace.read(path) if r.get("ev") == "tile"]
+    assert (tile["solver_iters"], tile["cg_iters"]) == (its, cgs)
 
 
 @pytest.mark.slow

@@ -163,6 +163,191 @@ def test_nsd_reduces_cost():
     assert float(info["final_cost"][0]) < 0.2 * float(info["init_cost"][0])
 
 
+def _tcg_thirty(hess_fn, rgrad, delta, cfg):
+    """The parent's ``rtr._tcg``, kept as the reference: the same body
+    under ``lax.fori_loop(0, cfg.tcg_iters, ...)``, every trip executed
+    whatever ``done`` says. Returns (eta, mdot)."""
+    _dot = rtr_mod._dot
+    r0n = jnp.sqrt(_dot(rgrad, rgrad))
+    target = r0n * jnp.minimum(cfg.kappa, r0n ** cfg.theta)
+
+    def body(_, s):
+        Hd = hess_fn(s.d)
+        d_Hd = _dot(s.d, Hd)
+        alpha = s.r_r / jnp.where(d_Hd != 0, d_Hd, 1.0)
+        e_d = _dot(s.eta, s.d)
+        d_d = _dot(s.d, s.d)
+        disc = jnp.maximum(e_d * e_d + d_d * (delta * delta - s.e_e), 0.0)
+        tau = (-e_d + jnp.sqrt(disc)) / jnp.maximum(d_d, 1e-30)
+        hit = (d_Hd <= 0) | (s.e_e + 2 * alpha * e_d
+                             + alpha * alpha * d_d >= delta * delta)
+        step = jnp.where(hit, tau, alpha)
+        eta_new = s.eta + step[:, None] * s.d
+        dm = -step * _dot(s.r, s.d) - 0.5 * step * step * d_Hd
+        r_new = s.r + step[:, None] * Hd
+        rr_new = _dot(r_new, r_new)
+        beta = rr_new / jnp.maximum(s.r_r, 1e-30)
+        d_new = -r_new + beta[:, None] * s.d
+        done_new = s.done | hit | (jnp.sqrt(rr_new) <= target)
+        upd = ~s.done
+        return rtr_mod._TCGState(
+            eta=jnp.where(upd[:, None], eta_new, s.eta),
+            r=jnp.where(upd[:, None], r_new, s.r),
+            d=jnp.where(upd[:, None], d_new, s.d),
+            r_r=jnp.where(upd, rr_new, s.r_r),
+            e_e=jnp.where(upd, _dot(eta_new, eta_new), s.e_e),
+            mdot=jnp.where(upd, s.mdot + dm, s.mdot),
+            done=done_new)
+
+    K, D = rgrad.shape
+    init = rtr_mod._TCGState(
+        eta=jnp.zeros_like(rgrad), r=rgrad, d=-rgrad, r_r=r0n * r0n,
+        e_e=jnp.zeros((K,), rgrad.dtype),
+        mdot=jnp.zeros((K,), rgrad.dtype), done=r0n <= 1e-30)
+    out = jax.lax.fori_loop(0, cfg.tcg_iters, body, init)
+    return out.eta, out.mdot
+
+
+def _assert_same(new, ref):
+    """The kept arithmetic is the same, so the two loops agree BITWISE on
+    this backend (the CPU); should a compiler fuse a ``while`` body
+    otherwise than a counted loop's, a few ulp would still pass."""
+    new, ref = np.asarray(new), np.asarray(ref)
+    if np.array_equal(new, ref):
+        return
+    eps = np.finfo(ref.real.dtype).eps
+    np.testing.assert_allclose(new, ref, rtol=8 * eps,
+                               atol=8 * eps * float(np.abs(ref).max()))
+    pytest.fail("equal to a few ulp, NOT bitwise: the CPU used to be")
+
+
+def _spd_problem(K, D, seed, cond, dtype):
+    """K symmetric positive definite [D, D] operators with eigenvalues
+    spread log-uniformly over ``cond`` decades, a gradient each."""
+    rng = np.random.default_rng(seed)
+    H = []
+    for _ in range(K):
+        Q, _ = np.linalg.qr(rng.normal(size=(D, D)))
+        H.append((Q * np.logspace(0, cond, D)) @ Q.T)
+    H = jnp.asarray(np.stack(H), dtype)
+    g = jnp.asarray(rng.normal(size=(K, D)), dtype)
+    return H, g
+
+
+# name: (K, D, eigenvalue decades, kappa, delta per chunk, expected trips)
+_TCG_CASES = {
+    # well conditioned, the radius far away: the residual target stops it
+    "early": (1, 40, 0.3, 0.1, [1e6], "below"),
+    # a residual target nothing reaches and 48 distinct eigenvalues: the
+    # cap is the count
+    "cap": (1, 48, 4.0, 1e-30, [1e6], "cap"),
+    # two chunks of one call that stop at different trips (one on its
+    # radius): the loop runs for the slower, the faster stays frozen
+    "chunks": (2, 40, 1.0, 0.1, [1e6, 1e-3], "below"),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64],
+                         ids=["f32", "f64"])
+@pytest.mark.parametrize("case", sorted(_TCG_CASES))
+def test_tcg_equals_thirty_trip_loop(case, dtype):
+    """``_tcg`` ends when every chunk is done and returns what the
+    parent's thirty blind trips returned, with the bodies it executed."""
+    K, D, cond, kappa, delta, expect = _TCG_CASES[case]
+    H, g = _spd_problem(K, D, seed=11, cond=cond, dtype=dtype)
+    delta = jnp.asarray(delta, dtype)
+    cfg = rtr_mod.RTRConfig(kappa=kappa)
+    hv = lambda v: jnp.einsum("kij,kj->ki", H, v)
+    eta, md, trips = jax.jit(lambda g, d: rtr_mod._tcg(hv, g, d, cfg))(
+        g, delta)
+    eta_r, md_r = jax.jit(lambda g, d: _tcg_thirty(hv, g, d, cfg))(g, delta)
+    _assert_same(eta, eta_r)
+    _assert_same(md, md_r)
+    assert trips.dtype == jnp.int32 and trips.shape == ()
+    if expect == "cap":
+        assert int(trips) == cfg.tcg_iters
+    else:
+        assert 1 <= int(trips) < cfg.tcg_iters // 2
+    if case == "chunks":
+        # the slow chunk alone needs as many; the fast one fewer
+        alone = [int(rtr_mod._tcg(
+            lambda v, k=k: jnp.einsum("ij,kj->ki", H[k], v),
+            g[k:k + 1], delta[k:k + 1], cfg)[2]) for k in range(K)]
+        assert int(trips) == max(alone) and min(alone) < max(alone)
+
+
+def test_tcg_under_vmap_counts_each_element():
+    """A batched ``while_loop`` runs until its last element is done and
+    freezes the others: each element's eta, mdot AND count are the ones
+    it has alone."""
+    T, D = 4, 40
+    cfg = rtr_mod.RTRConfig()
+    Hs, gs = _spd_problem(T, D, seed=12, cond=1.0, dtype=jnp.float64)
+    # element 0 stops on its radius at once, element 3 never moves
+    deltas = jnp.asarray([1e-3, 1e6, 3.0, 1e6])
+    gs = gs.at[3].set(0.0)
+
+    def one(H, g, d):
+        return rtr_mod._tcg(lambda v: v @ H.T, g[None], d[None], cfg)
+
+    def one_ref(H, g, d):
+        return _tcg_thirty(lambda v: v @ H.T, g[None], d[None], cfg)
+
+    eta, md, trips = jax.jit(jax.vmap(one))(Hs, gs, deltas)
+    eta_r, md_r = jax.jit(jax.vmap(one_ref))(Hs, gs, deltas)
+    _assert_same(eta, eta_r)
+    _assert_same(md, md_r)
+    alone = [int(one(Hs[t], gs[t], deltas[t])[2]) for t in range(T)]
+    assert np.asarray(trips).tolist() == alone
+    assert alone[3] == 0 and alone[0] == 1
+    assert len(set(alone)) >= 3 and max(alone) < cfg.tcg_iters
+
+
+# name: (chunks, mask, robust)
+_SOLVE_CASES = {"K1": (1, None, False), "K2-masked": (2, [True, False],
+                                                      False),
+                "K1-robust": (1, None, True), "K2-robust": (2, None, True)}
+
+
+@pytest.mark.parametrize("case", sorted(_SOLVE_CASES))
+def test_rtr_solve_equals_thirty_trip_loop(case, monkeypatch):
+    """``rtr_solve`` / ``rtr_solve_robust`` over the early-stopping tCG
+    return the parent's Jones, costs and outer trip count, and the
+    executed tCG bodies: at least one an outer trip, fewer than the cap's."""
+    K, mask, robust = _SOLVE_CASES[case]
+    N = 6
+    x8, coh, sta1, sta2, chunk_id, _ = _toy_problem_scalar(
+        N=N, T=4, K=K, seed=13, noise=0.02)
+    J0 = jnp.tile(jnp.eye(2, dtype=jnp.complex128), (K, N, 1, 1))
+    wt = lm_mod.make_weights(jnp.zeros(x8.shape[0], jnp.int32), x8.dtype)
+    cfg = rtr_mod.RTRConfig(itmax=8)
+    cmask = None if mask is None else jnp.asarray(mask)
+
+    def solve():
+        if robust:
+            J, nu, info = rtr_mod.rtr_solve_robust(
+                x8, coh, sta1, sta2, chunk_id, wt, J0, N, chunk_mask=cmask,
+                config=cfg)
+            return J, nu, info
+        J, info = rtr_mod.rtr_solve(x8, coh, sta1, sta2, chunk_id, wt, J0,
+                                    N, chunk_mask=cmask, config=cfg)
+        return J, 0.0, info
+
+    J, nu, info = solve()
+    monkeypatch.setattr(
+        rtr_mod, "_tcg",
+        lambda *a: _tcg_thirty(*a) + (jnp.asarray(a[3].tcg_iters,
+                                                  jnp.int32),))
+    J_r, nu_r, info_r = solve()
+    _assert_same(J, J_r)
+    _assert_same(nu, nu_r)
+    _assert_same(info["final_cost"], info_r["final_cost"])
+    its = int(info["iters"])
+    assert its == int(info_r["iters"]) > 0
+    assert int(info_r["cg_iters"]) == its * cfg.tcg_iters
+    assert its <= int(info["cg_iters"]) < its * cfg.tcg_iters
+
+
 @pytest.mark.slow
 def test_sage_dispatches_rtr_modes():
     from sagecal_tpu.config import SolverMode

@@ -1,7 +1,7 @@
 """cond-cost honesty: lax.cond branches must be priceable.
 
-XLA's ``cost_analysis`` sums BOTH branches of a ``lax.cond`` — the
-bench's bytes/FLOPs accounting charges every execution for work the
+XLA's ``cost_analysis`` sums BOTH branches of a ``lax.cond`` — a
+bytes/FLOPs account of it charges every execution for work the
 common case never runs (the phantom-bytes class: PR 3 measured +31%
 on LM damping trips until ``_chol_solve_shift`` was split out of
 ``_solve_damped`` so pricing could lower the executed body alone).

@@ -210,8 +210,8 @@ class RunConfig:
     # sitting on local disk. A resumed/migrated job re-paces from its
     # resume point (the stream clock is per process run — original
     # job-start wall time does not survive a restart). Pure wait —
-    # outputs are bit-identical at any pacing; the serve fleet bench
-    # uses it to measure ingest-limited scaling (MIGRATION.md "Fleet
+    # outputs are bit-identical at any pacing; serve.loadgen uses it
+    # to make a replay ingest-limited (MIGRATION.md "Fleet
     # mode"). 0 = off (the default).
     tile_arrival_s: float = 0.0
 
@@ -221,7 +221,7 @@ class RunConfig:
     # latency rather than job makespan.
     # stream_source : transport spec — "gen[:interval_s]" (seeded
     # in-process generator over the MS at --ms, released on an arrival
-    # clock; the tests/bench transport), "tail[:path]" (follow a
+    # clock; the tests' transport), "tail[:path]" (follow a
     # spool directory a feeder writes tiles into; default path = the
     # MS itself), "socket:host:port" (length-prefixed npz tile frames
     # over TCP; tiles spool into the MS directory as they land).
@@ -248,8 +248,8 @@ class RunConfig:
     # "readwrite": additionally bank this run's final chain on
     # completion. Tolerance-work, not bit-work: seeding changes
     # iteration counts, never the convergence target (gated warm-vs-
-    # cold at bench time, WARM_r*.json). "off" (the default) never
-    # touches the store — every existing banked record and bit-parity
+    # cold in tests/test_priors.py). "off" (the default) never
+    # touches the store — every bit-parity
     # gate stays frozen.
     prior_cache: str = "off"
 

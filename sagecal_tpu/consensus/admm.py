@@ -213,8 +213,8 @@ def make_admm_runner(dsky, sta1, sta2, cidx, cmask, n_stations: int,
     telemetry contract as make_admm_runner_blocked. The returned
     runner also exposes ``run.consensus_program`` — the per-iteration
     consensus half (Z psum + duals + BB rho) as its OWN mesh program,
-    so the multichip harness (tools_dev/northstar.py --multichip) can
-    time the collective overhead separately from the J-update solves.
+    so a caller can time the collective overhead apart from the J-update
+    solves (tests/test_krylov.py does; ROADMAP "harness hooks").
 
     Dtype policy (MIGRATION.md "Dtype policy"): ``x8F``/``wtF`` may
     arrive in the reduced storage dtype (cli_mpi stages them per
@@ -697,7 +697,7 @@ def make_admm_runner_2d(dsky, sta1, sta2, cidx, cmask, n_stations: int,
       divergence-reset rule in-program), and the FIRST interval of
       each block cold-starts from ``J0F`` — the one deliberate
       numerical deviation from the sequential chain, gated by the
-      residual-parity envelope at bank time (MESH2D record).
+      residual-parity envelope of tests/test_mesh2d.py.
 
     Dtype policy: identical contract to the 1-D mesh runner — ``x8``
     and ``wt`` may arrive in the reduced storage dtype and

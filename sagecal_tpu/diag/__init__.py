@@ -1,4 +1,4 @@
-"""``sagecal_tpu.diag`` — runtime telemetry, bytes-accounting roofline,
+"""``sagecal_tpu.diag`` — runtime telemetry, program pricing for tests,
 and convergence tracing.
 
 Three small modules, layered so the hot paths stay clean:
@@ -12,11 +12,9 @@ Three small modules, layered so the hot paths stay clean:
   retrace a program.
 - :mod:`sagecal_tpu.diag.roofline` — FLOPs and bytes-accessed
   extraction from XLA's per-program cost analysis
-  (``lowered.compile().cost_analysis()``), combined with measured
-  wall-clock into achieved GFLOP/s + GB/s and a compute- vs
-  bandwidth-bound verdict against device peaks. This replaces MFU as
-  the reported axis (round-5 VERDICT: "MFU is the wrong roofline axis
-  for this workload").
+  (``lowered.compile().cost_analysis()``): what tests price a program
+  with (``lower_cost``, ``pallas_cost``). Nothing measures a speed with
+  it: ``benchmarks/`` does that, from shapes and a chip's trace.
 - :mod:`sagecal_tpu.diag.guard` — a jit-compilation counter (via
   ``jax.monitoring``) so tests can assert that telemetry-off — and
   telemetry-on — add zero retraces.

@@ -1,20 +1,24 @@
-"""Bytes-accounting roofline: which hardware limit is each hot path on?
+"""Program pricing from XLA's cost analysis, for tests.
 
-Round-5 VERDICT rejected MFU as the reported axis: 2x2-Jones calibration
-does tiny matmuls, so "% of bf16 matmul peak" is structurally ~0 and
-says nothing about whether a program is fast. The right question is the
-roofline one — per compiled program, how many FLOPs and how many HBM
-bytes does one execution touch (XLA's own cost analysis via
-``lowered.compile().cost_analysis()``), what does measured wall-clock
+What is left of the retired round's roofline account (its driver and
+records went in PR 32): tests price a program with ``lower_cost`` /
+``program_cost`` / ``pallas_cost`` to pin WHICH body executes, and
+``roofline_fields`` classifies a priced step. No speed is measured
+here: ``benchmarks/`` does that on the chip, with its own peaks file
+(``benchmarks/peaks.json``, which the table below doubles).
+
+The question it answers, per compiled program: how many FLOPs and how
+many HBM bytes does one execution touch (XLA's own cost analysis via
+``lowered.compile().cost_analysis()``), what does a given wall-clock
 make of that in achieved GFLOP/s and GB/s, and which side of the device
 ridge point (peak FLOP/s ÷ peak bytes/s) does the program's operational
 intensity fall on. Both CubiCal (arXiv:1805.03410) and the SAGECal GPU
-work (arXiv:1910.13908) ground their speedup claims in exactly this
-per-kernel op/byte accounting.
+work (arXiv:1910.13908) ground their speedup claims in this per-kernel
+op/byte accounting.
 
 Known slack, inherited from XLA's static analysis: loop bodies are
-priced once regardless of trip count (callers add the dynamic-trip
-correction — see bench.py's trip-accounting block), and "bytes accessed"
+priced once regardless of trip count (the solvers' executed-trip
+counters, lm.TRIP_KEYS, say how often), and "bytes accessed"
 is the optimistic each-buffer-moves-once figure, so achieved GB/s is a
 lower bound on real traffic.
 """
@@ -37,7 +41,7 @@ _PEAKS = (
     ("v2", 45e12, 700e9),
 )
 
-# Nominal single-core host fallback so the CPU bench still classifies:
+# Nominal single-core host fallback so a CPU run still classifies:
 # ~one AVX2 core (16 f32 FLOP/cycle x ~3 GHz) against ~25 GB/s of the
 # socket's memory bandwidth. Coarse on purpose — the *ridge* (~2
 # FLOP/byte) is what the bound verdict needs, and CPU ridges sit within
@@ -55,12 +59,6 @@ def device_peaks(device):
         if key in kind:
             return pf, pb, False
     return None, None, False
-
-
-def peak_flops(device):
-    """bf16 peak FLOP/s (the legacy MFU denominator); None if unknown."""
-    pf, _, nominal = device_peaks(device)
-    return None if nominal else pf
 
 
 # ---------------------------------------------------------------------------
@@ -92,7 +90,7 @@ def pallas_cost(jfn, args, kwargs=None) -> dict:
     convention as XLA's own figure, with flops unknown = 0).
     INTERPRET-mode calls are skipped: the interpreter lowering is plain
     HLO, which cost_analysis already prices — adding the estimate there
-    would double-count (so CPU-banked rounds stay consistent)."""
+    would double-count."""
     import jax
     from jax.extend.core import ClosedJaxpr
     out = zero_cost()
@@ -167,33 +165,6 @@ def scale(cost, k) -> dict:
             "bytes_accessed": cost["bytes_accessed"] * k}
 
 
-def trip_correct(cost, per_trip, trips) -> dict:
-    """Dynamic-trip correction: ``cost`` + ``trips`` x ``per_trip``.
-
-    XLA cost analysis prices loop bodies ONCE regardless of trip count,
-    so per-program figures undercount iterative solvers by orders of
-    magnitude. Callers price one body trip (:func:`lower_cost` at the
-    solve shapes) and multiply by the solver's EXECUTED iteration
-    counter. Two counter families exist: outer damping/TR/LBFGS trips
-    (``info["solver_iters"]``/``info["lbfgs_iters"]``) and — under the
-    matrix-free ``inner="cg"`` path — the PCG inner trips
-    (``info["cg_iters"]``), each priced as one gn_matvec +
-    preconditioner application; pricing the damping trip alone would
-    hide the Krylov traffic the inexact-Newton path actually moves.
-    ``per_trip=None`` (pricing unavailable) returns ``cost`` unchanged
-    rather than silently zeroing the base figure.
-
-    Pallas note: per-trip prices that contain a Mosaic-compiled
-    ``pallas_call`` must come from :func:`program_cost`/
-    :func:`lower_cost` (which fold in :func:`pallas_cost`) — raw
-    cost_analysis figures silently drop the kernel's bytes/FLOPs, and
-    multiplying a dropped cost by the trip count here would compound
-    the hole."""
-    if cost is None or per_trip is None:
-        return cost
-    return combine(cost, scale(per_trip, trips))
-
-
 def nbytes_of(tree) -> int:
     """Total host bytes of every array leaf in a pytree — the staging
     accountant (how much crosses host->device per tile)."""
@@ -211,7 +182,7 @@ def roofline_fields(cost, wall_s, device) -> dict:
 
     ``cost``: {"flops", "bytes_accessed"} of the step (trip-corrected by
     the caller); ``wall_s``: measured seconds per step. Returns a dict
-    ready to merge into a bench record:
+    of:
 
     - ``flops``, ``bytes_accessed`` — the step's totals;
     - ``achieved_flops_per_s``, ``achieved_gbps`` — vs wall-clock;

@@ -1,7 +1,7 @@
 """Mixed-precision dtype policy: reduced storage, f32 accumulation.
 
-The roofline verdict (PERF.md: 0.73 FLOP/B, bandwidth-bound) makes bytes
-the only currency that buys wall-clock, and after the structural wins of
+An XLA-priced roofline of rounds 5-7 (cpu: 0.73 FLOP/B; no chip reading
+bears it out, PERF.md section 5) took bytes as what buys wall-clock, and after the wins of
 rounds 6-7 the remaining factor-of-2 on the dominant [B]-pass traffic is
 the storage dtype. The policy here is the storage/accumulate split the
 CubiCal per-kernel op/byte accounting motivates (arXiv:1805.03410) and

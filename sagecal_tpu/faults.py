@@ -13,7 +13,7 @@ module holds the two halves of the fault-tolerance layer:
   (``faults.active()`` is a blessed telemetry-style gate for the
   jaxlint host-sync checker, like ``dtrace.active()``): faults off is
   bit-identical and compile-count-identical, gated in
-  tests/test_faults.py and the sentinel's live probe. Determinism is
+  tests/test_faults.py. Determinism is
   order-independent: probabilistic rules draw from a stable hash of
   ``(seed, point, key, occurrence)`` so thread interleaving can never
   change which calls fire.

@@ -1,6 +1,6 @@
 """sagecal_tpu.obs: production observability over the diag tracer.
 
-Four pieces, one contract:
+Three pieces, one contract:
 
 - :mod:`obs.metrics` — a zero-dependency, thread-safe metrics registry
   (counters, gauges, fixed-bucket histograms with percentile readout)
@@ -17,17 +17,11 @@ Four pieces, one contract:
 - :mod:`obs.export` — Prometheus text exposition of a registry plus
   the stdlib HTTP endpoint serving ``/metrics`` and ``/healthz`` for
   the serve daemon (``--metrics-port``).
-- :mod:`obs.sentinel` — the perf-regression sentinel: loads the newest
-  round-stamped ``BENCH_<PLAT>_rNN.json`` bank and fails (non-zero
-  exit, named metric) on regression beyond per-metric tolerances —
-  the Δbytes/Δwall discipline CHANGES.md used to enforce by hand,
-  machine-enforced (CI lane + bench.py post-run check).
 
 Layering: stdlib only, like ``diag.trace`` — the solver and pipeline
 layers import ``obs.metrics`` unconditionally and an import that
 pulled in jax from inside ``sagecal_tpu.solvers.sage`` would be a
-layering inversion. (``obs.sentinel``'s full mode imports bench
-lazily; ``--fast`` stays stdlib + the repo's own modules.)
+layering inversion.
 """
 
 from sagecal_tpu.obs import metrics  # noqa: F401  (the common entry)

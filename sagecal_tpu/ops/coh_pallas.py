@@ -25,7 +25,7 @@ remains the reference implementation the kernel is tested against.
 
 Recorded decision on the beam path (VERDICT r2 item 2): the kernel's
 measured win over pure XLA is 1.25x on config 1 and 1.03x on config 4
-(bench_results.json, TPU). Beam mode multiplies every source term by
+(older chip record, 2026-07). Beam mode multiplies every source term by
 per-(source, station, time) 2x2 E-Jones gathered from station tables —
 a gather-dominated access pattern whose intermediates XLA already keeps
 fused, and whose kernel port would restructure the whole VMEM layout for

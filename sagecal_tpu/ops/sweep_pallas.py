@@ -1,8 +1,8 @@
 """Pallas fused-sweep kernel: the cluster visit's [B]-pass in ONE grid.
 
 The per-cluster solve floor is data movement over the baseline axis, not
-arithmetic (arXiv:1910.13908, arXiv:1410.8706; this repo's own
-BSCALING_r07.json: a ~34 ms/cluster B-independent floor under ``chol``
+arithmetic (arXiv:1910.13908, arXiv:1410.8706; this repo's own older
+CPU record, in git before PR 32: a ~34 ms/cluster B-independent floor under ``chol``
 and a 13.6-16.6x loss for ``cg`` because every PCG trip re-pays a full
 [B]-row pass). The XLA assembly (solvers/normal_eq.py) walks the rows
 several times per damping iteration — model eval, residual, Wirtinger
@@ -77,8 +77,8 @@ from sagecal_tpu import dtypes as dtp
 
 #: flop estimate per visibility-row visit for one fused sweep pass
 #: (model eval + residual + factor Grams + gradients + cost); feeds the
-#: pl.CostEstimate AND diag/roofline's pallas pricing (bench satellite:
-#: cost_analysis cannot see inside a compiled pallas_call)
+#: pl.CostEstimate AND diag/roofline's pallas pricing
+#: (cost_analysis cannot see inside a compiled pallas_call)
 SWEEP_FLOPS_PER_ROW = 1100
 #: flop estimate per (chunk, baseline) block for one blocks matvec
 MATVEC_FLOPS_PER_BASELINE = 300
@@ -874,7 +874,7 @@ def chol_solve_blocks_shift(fac: GNBlocks, JTe, shift, sta1, sta2,
     (dp, ok) with ok = dp all-finite per chunk.
 
     This is the executed all-ok body of :func:`solve_damped_blocks` —
-    bench.solver_trip_cost prices THIS function under
+    whoever prices a trip prices THIS function under
     (kernel='pallas', inner='chol') because XLA cost analysis sums
     both branches of the retry lax.cond (the same phantom-bytes class
     lm._chol_solve_shift exists for). The assembled matrix is exactly

@@ -1535,7 +1535,7 @@ class TileStepper:
         if isinstance(info, dict) and "solver_iters" in info:
             # executed inner-solver trips — the sweeps-to-convergence
             # signal the serve layer aggregates per job (loadgen
-            # replay rows; the warm-vs-cold bench). The solve already
+            # replay rows) and benchmarks/ reads. The solve already
             # synced on res_0/res_1, so this fetch adds no wait.
             rec["solver_iters"] = int(
                 np.asarray(info["solver_iters"]).sum())

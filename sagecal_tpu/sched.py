@@ -2,7 +2,7 @@
 
 The calibration host loops (pipeline.py, stochastic.py, cli_mpi.py)
 execute io -> stage -> solve -> residual-fetch -> write per solve
-interval. PR 1's roofline measured the solve as bandwidth-bound, so
+interval. Whatever bounds the solve, it runs on the device, so
 the device idles through every host-side phase of that chain. This
 module holds the three primitives that hide those phases behind the
 solve without changing a single computed bit:

@@ -20,7 +20,7 @@ program away at exit. This package keeps the device busy across *jobs*:
 - :mod:`sagecal_tpu.serve.fleet` — device scopes, shape-bucket
   affinity tokens and the placement layer (``--devices N``);
 - :mod:`sagecal_tpu.serve.loadgen` — the seedable traffic-replay
-  load generator behind the banked FLEET records;
+  load generator (open-loop arrival processes, job templates);
 - :mod:`sagecal_tpu.serve.api` — a zero-dependency JSON-lines protocol
   over a local socket (submit/status/cancel/migrate/drain/metrics)
   with graceful drain on SIGTERM, and a client with persistent

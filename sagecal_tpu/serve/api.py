@@ -425,7 +425,7 @@ class Server:
 
 
 class Client:
-    """Line-oriented client for the protocol above (tests, bench,
+    """Line-oriented client for the protocol above (tests, loadgen,
     embedders). One socket, requests answered in order.
 
     Robustness: a transient socket failure (connection reset, dropped

@@ -8,8 +8,8 @@ are measured against a *defined* traffic mix instead of hand-run
 jobs. Everything is deterministic from the spec: the same ``seed``
 produces the same datasets (content seeds), the same arrival times,
 the same priorities/deadlines — replaying a spec against two fleet
-sizes is an apples-to-apples comparison (bench config
-``9-fleet-throughput``, FLEET_r12.json).
+sizes is an apples-to-apples comparison (what ROADMAP's
+``serve-jobs`` cell is to be built from).
 
 A spec is a JSON object (all fields defaulted — ``{}`` is valid)::
 
@@ -31,8 +31,8 @@ A spec is a JSON object (all fields defaulted — ``{}`` is valid)::
 ``"burst"`` (everything at t=0 — the backlog-drain regime whose
 queue-wait tail shows fleet capacity). A template's ``repeat``
 (default 0) grows its draw weight with every draw — repeat-field
-traffic, the regime the warm-start prior cache (serve/priors.py,
-bench ``12-warm-start``) is built for. Template ``config`` fields are
+traffic, the regime the warm-start prior cache (serve/priors.py)
+is built for. Template ``config`` fields are
 RunConfig names (serve ``submit`` semantics); ``tile_arrival_s``
 there turns on streaming-ingest pacing (config.py) — the
 ingest-limited regime where per-device throughput is bounded by
@@ -41,7 +41,7 @@ tenant data rate, not device compute.
 Each scheduled job gets its OWN copy of its template's dataset (jobs
 write residuals in place), so per-job outputs are independently
 comparable against a solo run of the same template — the
-bit-identity gate the bench refuses to bank without.
+bit-identity gate of tests/test_fleet.py.
 
 Layering: stdlib + numpy + the serve Client; jax only inside
 :func:`build_fixtures` (dataset synthesis).
@@ -59,8 +59,8 @@ import time
 
 import numpy as np
 
-#: small two-cluster sky shared by every template (the bench's serve
-#: sky): enough structure for a real solve, cheap enough for a replay
+#: small two-cluster sky shared by every template:
+#: enough structure for a real solve, cheap enough for a replay
 SKY = """\
 P0A 0 40 0 40 0 0 3.0 0 0 0 0 0 0 0 0 150e6
 P1A 1 20 0 38 0 0 2.5 0 0 0 0 0 0 0 0 150e6
@@ -215,15 +215,15 @@ def replay(client, spec, fixtures, workdir: str, log=print,
     server-side drain wait (no status polling stealing host cycles
     mid-replay); ``drain=False`` instead polls with ONE pipelined
     status batch per interval, leaving the server accepting, so a
-    bench can run several replays against one warm fleet (the
-    10-scaleout legs). Returns the replay record: wall, throughput,
+    caller can run several replays against one warm fleet.
+    Returns the replay record: wall, throughput,
     queue-wait/e2e percentiles, per-job rows, and the output paths
     for the caller's bit-identity gate."""
     spec = load_spec(spec)
     sched_rows = schedule(spec)
     if tag:
         # several replays of ONE spec against one long-lived server
-        # (the scaleout bench's warm legs) need distinct job ids —
+        # (warm legs after a first replay) need distinct job ids —
         # registries, daemon and router alike, refuse duplicates
         sched_rows = [dict(row, job_id=f"{row['job_id']}-{tag}")
                       for row in sched_rows]
@@ -290,7 +290,7 @@ def replay(client, spec, fixtures, workdir: str, log=print,
         if snap.get("kind") == "stream" or snap.get("tiles_late"):
             # streaming tenants (a template whose config carries
             # stream_source): per-tile lateness rides the row so a
-            # bench can gate on it without re-polling
+            # caller can gate on it without re-polling
             row["tiles_late"] = snap.get("tiles_late", 0)
             row["tiles_degraded"] = snap.get("tiles_degraded", 0)
         if "worker" in snap:
@@ -302,7 +302,7 @@ def replay(client, spec, fixtures, workdir: str, log=print,
     n_done = states.get("done", 0)
     # per-template sweeps-to-convergence: total executed solver sweeps
     # per finished job of each template (Job.snapshot solver_iters) —
-    # the warm-start bench's primary axis (warm vs cold at equal
+    # the warm start's primary axis (warm vs cold at equal
     # convergence quality is fewer sweeps, not a different answer)
     sweeps = {}
     for row in rows:

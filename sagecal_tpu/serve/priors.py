@@ -5,8 +5,8 @@ jobs; this module does the same for the *solution state*. Production
 traffic re-observes the same fields constantly — same sky model, same
 station set, same band — and every such job used to cold-start its
 Jones chain from identity even though the previous job on that field
-already measured a good J (the warm-vs-cold gap is the forgone-
-advantage number banked in MESH2D_r13.json). The store banks a
+already measured a good J (the warm-vs-cold gap is what the
+store is for; cpu counts only, no chip reading). The store banks a
 finished job's final per-(station, cluster, interval) Jones chain plus
 its per-cluster ADMM ρ schedule, keyed by everything that determines
 solution compatibility, and seeds the NEXT job on that key by
@@ -35,10 +35,10 @@ Interpolation contract (:func:`interpolate`):
   start (returns None) so serving never fails on a bad prior.
 
 Tolerance contract: seeding changes iteration COUNTS, never the
-convergence target — warm runs are gated against a cold control at
-bank time (bench config ``12-warm-start``, WARM_r*.json) and
+convergence target — warm runs are gated against a cold control in
+tests/test_priors.py (residual ratio within 0.05) and
 ``prior_cache="off"`` (the default) never touches this module, so
-every pre-existing banked record and bit-parity gate stays frozen.
+every bit-parity gate stays frozen.
 
 Layering: numpy + stdlib + serve.cache (token) only — importable from
 the router/placement layer, no jax.

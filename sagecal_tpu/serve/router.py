@@ -1,8 +1,8 @@
 """Cross-process fleet router: one API front-end over many worker daemons.
 
 PR 11 scaled the daemon to a device fleet INSIDE one process (virtual
-devices timeslicing one host core — the FLEET_r12 record explicitly
-disclaims compute scaling). This module is the horizontal remainder:
+devices timeslicing one host core — no compute scaling was ever
+measured there). This module is the horizontal remainder:
 a :class:`Router` is a front-end PROCESS that speaks the SAME
 JSON-lines API as the daemon (serve/api.py: submit / status / cancel /
 drain / migrate / metrics / metrics_full / ping) and owns a WORKER
@@ -988,7 +988,7 @@ class Router:
                 pass
 
     def stop(self) -> None:
-        """Hard stop (tests/bench): no drain, just exit."""
+        """Hard stop (tests): no drain, just exit."""
         self._drained.set()
         self.close()
 

@@ -300,7 +300,7 @@ class Scheduler:
                    unhealthy_jobs=self.unhealthy_jobs(),
                    # warm-start prior store (serve/priors.py):
                    # process-wide hit/bank/refusal accounting — the
-                   # serve half of the warm-vs-cold bench record
+                   # serve half of a warm-vs-cold comparison
                    priors=ppriors.PRIORS.stats())
         if spans:
             out["mesh_spans"] = spans
@@ -597,8 +597,8 @@ class Scheduler:
         ``reason="preempt"`` is the stream-priority path: the target
         is None (re-queue UNPINNED on this same device's queue, behind
         the higher-priority stream in the priority FIFO) and the
-        migrations record carries the reason so the bench's zero-rerun
-        gate can find the preemption legs."""
+        migrations record carries the reason so a zero-rerun
+        gate (tests/test_stream.py) can find the preemption legs."""
         job = rj.job
         target = job.migrate_to
         job.migrate_to = None
@@ -648,7 +648,7 @@ class Scheduler:
         then the pass moves on even if more are ready. Jobs in
         different shape buckets run different compiled programs, so
         per-tile alternation thrashes the host's code/data caches
-        (measured +5% on the serve bench) — but UNbounded stickiness
+        (measured +5% on a CPU host, 2026-08) — but UNbounded stickiness
         would let a job whose reader keeps pace with the device run to
         completion, starving its neighbours' staged tiles and
         deferring cancel/stop/drain/migration for its whole runtime.
@@ -853,7 +853,7 @@ class Scheduler:
                 >= self.MIGRATE_MIN_REMAINING_TILES)
 
     def request_migration(self, job_id: str, target: int) -> str:
-        """Manual migration (the api ``migrate`` op, and the bench's
+        """Manual migration (the api ``migrate`` op, and the tests'
         deterministic lever): ask the owner loop to yield the job to
         ``target`` at its next tile boundary. Validates the job is a
         RUNNING migratable fullbatch job and the target exists."""

@@ -242,7 +242,7 @@ def linesearch_fletcher(cost_func, grad_func, xk, pk, gk=None,
             # without ``on_line`` five passes through the caller's whole
             # model a trip (the joint refine's: all clusters, twice with
             # a backward pass), with it five passes over the restricted
-            # residual, which is nothing; noted in bench refine_trip_cost
+            # residual, which is nothing (``refine_passes`` counts them)
             lambda: cubic(lo, hi))
         alphai1_n = jnp.where(code_n == 0, alphai, alphai1)
         alphai_n = jnp.where(code_n == 0, alpha_adv, alphai)
@@ -417,7 +417,7 @@ def lbfgs_fit(cost_func, grad_func, p0, itmax: int = 20, M: int = 7,
     one, returns the cost restricted to the line ``xk + a pk`` as
     ``a -> (phi(a), dphi(a))``; the Fletcher search then runs its trials
     on it. ``return_iters`` additionally returns the executed iteration
-    count (bench.py MFU trip accounting) and the passes through the
+    count (the tile record's ``lbfgs_iters``) and the passes through the
     caller's model (``_lbfgs_loop``)."""
     mem = lbfgs_memory_init(p0.shape[0], M, p0.dtype)
     x, _, k, passes = _lbfgs_loop(

@@ -19,8 +19,8 @@ inner solvers (``LMConfig.inner``):
   (normal_eq.gn_matvec) under the station-block preconditioner
   (gn_precond_factor), stopped at the inexact-Newton forcing tolerance
   ||r|| <= cg_tol * ||JTe|| with per-chunk early-stop masking. Executed
-  CG trips are counted (info["cg_iters"]) for the bench's roofline
-  trip accounting.
+  CG trips are counted (info["cg_iters"]) and reach the tile record
+  (benchmarks/ reads them as ``tcg_trips``).
 
 Damping schedule = classic levmar (as cloned by clmfit.c):
   mu0 = tau * max(diag(JTJ)); accept if gain rho > 0 with
@@ -39,8 +39,8 @@ from sagecal_tpu import dtypes as dtp
 from sagecal_tpu.solvers import normal_eq as ne
 
 #: executed-iteration counters a solver info dict may carry; the keys
-#: the host-side telemetry (diag tile records, obs trip counters, the
-#: bench's trip-corrected roofline) reads through executed_trips()
+#: the host-side telemetry (diag tile records, obs trip counters,
+#: benchmarks/' solver_trips) reads through executed_trips()
 TRIP_KEYS = ("solver_iters", "cg_iters", "lbfgs_iters", "refine_passes",
              "rejected_groups")
 
@@ -166,7 +166,7 @@ def _chol_solve_shift(JTJ, JTe, shift):
     """ONE batched shifted-Cholesky attempt: solve (JTJ + shift I) dp =
     JTe over chunks; returns dp, ok (dp all-finite per chunk — the f32
     analogue of LAPACK potrf info). This is the executed all-ok body of
-    :func:`_solve_damped`; bench.py's trip pricing lowers THIS function
+    :func:`_solve_damped`; a test that prices a trip lowers THIS function
     rather than ``_solve_damped`` because XLA cost analysis sums BOTH
     branches of a lax.cond — pricing the wrapper would charge every
     damping trip for a jitter-retry factorization the common case never
@@ -248,7 +248,7 @@ def _solve_damped_cg(fac, JTe, mu, jitter, rho, sta1, sta2, chunk_id,
     ||r||^2 <= (eta ||JTe||)^2; converged chunks freeze (masked
     updates) while the batch runs to the slowest live chunk, and
     ``trips`` counts the executed loop iterations — the number the
-    roofline trip accounting multiplies by the per-matvec price. A
+    tile record carries as ``cg_iters``. A
     chunk with JTe == 0 (dead OS subset) starts converged and returns
     dp = 0 exactly, preserving the carried-equation semantics the OS
     body builds on. ``active`` [K] masks chunks out entirely (their rhs

@@ -394,7 +394,7 @@ def normal_equations(x8, J, coh, sta1, sta2, chunk_id, wt, n_stations: int,
     4x4 contractions of the [B, 2, 2, 4] factors with the per-component
     sqrt-weights folded in, and the station-pair cross blocks are
     aggregated ONCE and symmetrized densely afterwards. Measured at the
-    bench config-1 shape (K=1, N=62, B=18910, f32, XLA cost analysis):
+    LOFAR shape (K=1, N=62, B=18910, f32, XLA cost analysis, cpu):
     dense assembly 93 MB accessed per evaluation, structured scatter
     path 88 MB, baseline-major path 56 MB (tests/test_lm.py gates all
     three for equivalence).

@@ -121,7 +121,7 @@ def robust_lm_solve(x8, coh, sta1, sta2, chunk_id, wt_base, J0,
         jnp.arange(wt_rounds))
     # "iters": executed inner-LM damping iterations summed over IRLS
     # rounds; "cg_iters": executed PCG trips under config.inner="cg"
-    # (0 otherwise) — both feed the bench's roofline trip accounting
+    # (0 otherwise) — both reach the tile record through lm.TRIP_KEYS
     info = {"init_cost": costs[0][0], "final_cost": costs[1][-1],
             "iters": jnp.sum(costs[2]).astype(jnp.int32),
             "cg_iters": jnp.sum(costs[3]).astype(jnp.int32)}

@@ -561,7 +561,7 @@ def rtr_solve_robust(x8, coh, sta1, sta2, chunk_id, wt_base, J0,
         round_body, (J0, jnp.asarray(nu0, dtp.acc_dtype(x8.dtype))), None,
         length=wt_rounds)
     # "iters": executed outer TR iterations summed over IRLS rounds
-    # (bench.py MFU trip accounting); "cg_iters": their tCG bodies
+    # (the tile record's solver_iters); "cg_iters": their tCG bodies
     info = {"init_cost": costs[0][0], "final_cost": costs[1][-1],
             "iters": jnp.sum(costs[2]).astype(jnp.int32),
             "cg_iters": jnp.sum(costs[3]).astype(jnp.int32)}

@@ -60,10 +60,10 @@ from sagecal_tpu.solvers import rtr as rtr_mod
 # sagefit_host sweep-fusion verdicts, per problem shape (see its
 # docstring); process-lifetime cache, entries are tiny
 _FUSION_CACHE: dict = {}
-# device-program call log for FLOP accounting (bench.py MFU column):
-# name -> [jitted_fn, (args, kwargs of the last call), n_calls]. The
-# bench resets this around its timed reps, then prices each program once
-# via compiled.cost_analysis() and multiplies by the call count.
+# device-program call log (read by tests only since PR 32; ROADMAP
+# "harness hooks"): name -> [jitted_fn, (args, kwargs of the last
+# call), n_calls]. A reader resets it, runs, then lowers or prices each
+# program once from the stored skeleton and multiplies by the count.
 _PROGRAM_CALLS: dict = {}
 
 
@@ -180,7 +180,7 @@ class SageConfig(NamedTuple):
     # write-back to xres and a fresh add-back per visit. Identical
     # math — the +/- association order is preserved, so the residual
     # stream is bit-identical (parity-gated in tests/test_sage.py).
-    # Measured 2026-08-03 at the bench config-1 shape on the host CPU
+    # Measured 2026-08-03 at the LOFAR smoke-test shape on the host CPU
     # (M=8, B=18910, -j3, interleaved warm sweeps): median 7.96 s/sweep
     # fused vs 8.01 unfused — a wall-clock wash on a latency-rich CPU —
     # while the fused program runs one [B, 8] traversal less per
@@ -197,8 +197,8 @@ class SageConfig(NamedTuple):
     # bit-reference path), "cg" is matrix-free (Wirtinger-factor
     # matvecs under the station-block preconditioner; inexact Newton on
     # the LM path, exact-operator tCG on the RTR path). Default stays
-    # "chol", decided from measurement 2026-08-03 (BSCALING_r07.json,
-    # CPU): at the north-star -j5 shape (N=64, M=100) cg LOSES at
+    # "chol", decided from measurement 2026-08-03 (older chip record, in
+    # git before PR 32; CPU): at the -j5 shape N=64, M=100 cg LOSES at
     # every B rung — 506 -> 8420 ms/cluster at full B (+1564%), still
     # +1383% at quarter B — because every PCG trip re-pays a full
     # [B]-row matvec pass, and on CPU's ridge the trip chain's row
@@ -224,8 +224,8 @@ class SageConfig(NamedTuple):
     # chunk count (sweep_pallas.supported — nbase set, kmax <=
     # MAX_CHUNKS); other shapes fall back to the XLA path. Parity is
     # tolerance-gated
-    # (MIGRATION.md "Pallas kernels"; BSCALING_r11.json for the
-    # measured floor/trip-price deltas)
+    # (MIGRATION.md "Pallas kernels"; the measured floor/trip-price
+    # deltas: older chip record, in git before PR 32)
     kernel: str = "xla"
     # storage dtype policy (--dtype-policy; sagecal_tpu.dtypes): "f32"
     # is the bit-frozen identity; "bf16"/"f16" store the visibility
@@ -431,7 +431,7 @@ def _cluster_update(cj, state, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
     """Visit one cluster: add model back to residual, solve, re-subtract
     (lmfit.c:890-981). ``state`` = (J, xres, nerr_acc, nuM, tk) with
     ``tk`` an i32[3] counter triple: [0] executed inner-solver
-    iterations (roofline trip accounting), [1] rejected group steps
+    iterations (the tile record's solver_iters), [1] rejected group steps
     (always 0 here — only :func:`_group_update` can reject), [2]
     executed inner CG trips (LM's PCG under SageConfig.inner="cg", RTR's
     truncated-CG bodies; :func:`_cluster_solve`)."""
@@ -1439,7 +1439,7 @@ def sagefit_host_tiles(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
     if keys is None:
         keys = tile_keys(T)
     if T == 1:
-        # Measured on-chip (2026-07-31, bench config-3 shape): the
+        # Measured on-chip (2026-07-31, older chip record, 16 clusters): the
         # vmapped UNIT tile axis alone costs ~40% (16.2 vs 11.5 s warm
         # step) — every latency-bound solver op carries a [1, ...]
         # leading dim that changes TPU layouts without adding work. A

@@ -144,8 +144,8 @@ def make_band_cost(chunk_idx, chunk_mask, n_stations: int, nu: float,
     """Build the band objective used by :func:`make_band_solver`:
     ``cost_of(x8F, coh, wtF, sta1, sta2, Y, BZ, rho) -> cost_fn(pflat)``.
 
-    Factored out so the bench's per-LBFGS-iteration FLOP price
-    (bench.py config2) lowers the SAME objective the solver minimizes —
+    Factored out so that whoever prices or lowers an LBFGS iteration
+    lowers the SAME objective the solver minimizes —
     a hand-copied objective would silently drift if this one changes.
     """
     M, kmax = chunk_mask.shape

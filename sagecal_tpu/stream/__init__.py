@@ -27,7 +27,7 @@ Three transports:
 
 - :class:`GeneratorStream` — seeded in-process generator over an
   on-disk SimMS, releasing tile i at ``start + i * interval_s`` (the
-  tests/bench transport: deterministic arrivals, and bit-identity
+  tests' transport: deterministic arrivals, and bit-identity
   against the same MS run as a batch job is trivially checkable);
 - :class:`~sagecal_tpu.stream.transport.TailStream` — follow a spool
   directory that a feeder writes SimMS tile files into (atomic

@@ -26,7 +26,7 @@ ranged: a reader that could half-parse a newer writer is the failure
 mode the refusal exists to prevent.
 
 The feeders (:class:`SocketFeeder`, :class:`TailFeeder`) are the
-test/bench harness side: they replay an existing on-disk SimMS on an
+tests' harness side: they replay an existing on-disk SimMS on an
 arrival clock, applying the ``tile_dropped`` fault point so loss is a
 first-class, deterministic chaos lever. A dropped tile is an index
 gap on the wire; the consumer transports count the gap

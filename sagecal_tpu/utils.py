@@ -41,7 +41,7 @@ def setup_backend(platform: str | None = None,
                   cpu_devices: int | None = 0) -> str:
     """Pick the JAX platform and the persistent compile cache, before
     first device use. Called at the top of every entry point (cli,
-    cli_mpi, serve, serve.loadgen, bench children, tools_dev/northstar)
+    cli_mpi, serve, serve.loadgen, benchmarks/run.py, chip_smoke.py)
     so they all agree; returns the cache directory in force.
 
     - ``platform`` / ``cpu_devices`` are the ``--platform`` /

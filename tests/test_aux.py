@@ -1,8 +1,11 @@
 """Tests for auxiliary algorithms: whitening, MDL model order, spatial
 regularization (spherical harmonics + FISTA), federated averaging."""
 
+import importlib
 import math
 import os
+import re
+import shlex
 
 import numpy as np
 import jax
@@ -281,3 +284,53 @@ def test_federated_mesh_matches_sequential(tmp_path):
     sol_s = (seqdir / "sol.txt").read_text()
     sol_m = (meshdir / "sol.txt").read_text()
     assert sol_s == sol_m
+
+
+# --- README's command lines -------------------------------------------------
+
+_README = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "README.md")
+#: what ``python -m`` runs -> the module whose ``build_parser()`` it uses
+_ENTRY_POINTS = {"sagecal_tpu.cli": "sagecal_tpu.cli",
+                 "sagecal_tpu.cli_mpi": "sagecal_tpu.cli_mpi",
+                 "sagecal_tpu.serve": "sagecal_tpu.serve.__main__"}
+
+
+def _readme_commands():
+    """(module, arguments) of every command line in README.md's code
+    blocks that runs one of the three entry points: continuation lines
+    joined, whatever stands before ``python -m``, comments and a trailing
+    ``&`` dropped."""
+    cmds = []
+    for block in re.findall(r"```(?:bash)?\n(.*?)```", open(_README).read(),
+                            re.S):
+        for m in re.finditer(r"python -m (sagecal_tpu[\w.]*)(.*)",
+                             block.replace("\\\n", " ")):
+            if m.group(1) in _ENTRY_POINTS:
+                cmds.append((m.group(1), [
+                    w for w in shlex.split(m.group(2), comments=True)
+                    if w != "&"]))
+    return cmds
+
+
+_COMMANDS = _readme_commands()
+
+
+@pytest.mark.parametrize(
+    "module, argv", _COMMANDS,
+    ids=lambda v: v.rsplit(".", 1)[-1] if isinstance(v, str)
+    else " ".join(w for w in v if w.startswith("-")))
+def test_readme_command_line_is_accepted(module, argv):
+    """README cannot drift from the parsers: every flag and every
+    required argument of a command it shows is one ``build_parser()`` of
+    that module takes."""
+    parser = importlib.import_module(_ENTRY_POINTS[module]).build_parser()
+    try:
+        parser.parse_args(argv)
+    except SystemExit as e:
+        pytest.fail(f"python -m {module} {' '.join(argv)}: the parser "
+                    f"exits {e.code}", pytrace=False)
+
+
+def test_readme_shows_every_entry_point():
+    assert {m for m, _ in _COMMANDS} == set(_ENTRY_POINTS)

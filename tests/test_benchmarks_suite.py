@@ -1,26 +1,100 @@
 """The benchmark's own tests (``benchmarks/tests``: the yardstick, the
 tiny rehearsal cells, the controls that have to come out not correct)
 are not collected by this suite: they build whole tiny runs, share one
-work directory and take minutes.  This runs them once, in a process of
-its own with a time limit of its own, so that a PR's test run guards the
-harness too."""
+work directory and take minutes.  This runs them ONCE, in a process of
+its own with a time limit of its own, and reports one case here for
+every test there, under that test's name and with that test's message,
+so that a PR's count of passes guards the harness test by test.
+
+The names come from a ``--collect-only`` call in a process of its own
+(nothing of ``benchmarks/tests`` is imported here), the results from
+the JUnit file the one run writes.  All cases of this file land on one
+worker under ``--dist loadfile``, so the module's fixture runs once."""
 
 import os
 import subprocess
 import sys
+import xml.etree.ElementTree as ET
+
+import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SUITE = "benchmarks/tests"
 LIMIT_S = 900       # 140 s alone on this sandbox (PR 30), beside 5 workers
+PYTEST = [sys.executable, "-m", "pytest", SUITE, "-q",
+          "-p", "no:cacheprovider", "-p", "no:randomly"]
 
 
-def test_the_benchmarks_own_tests_pass():
+def _env():
     env = {k: v for k, v in os.environ.items()
            if not k.startswith("PYTEST_")}       # not a worker of ours
     env["JAX_PLATFORMS"] = "cpu"
-    run = subprocess.run(
-        [sys.executable, "-m", "pytest", "benchmarks/tests", "-q", "-x",
-         "-p", "no:cacheprovider", "-p", "no:randomly"],
-        cwd=ROOT, env=env, timeout=LIMIT_S, text=True,
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
-    assert run.returncode == 0, run.stdout[-4000:]
-    assert " passed" in run.stdout.splitlines()[-1]
+    return env
+
+
+def _collect():
+    """(node ids below SUITE, the call's output where it failed)."""
+    run = subprocess.run(PYTEST + ["--collect-only"], cwd=ROOT, env=_env(),
+                         timeout=LIMIT_S, text=True,
+                         stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
+    ids = [ln[len(SUITE) + 1:] for ln in run.stdout.splitlines()
+           if ln.startswith(SUITE + "/") and "::" in ln]
+    return ids, ("" if run.returncode == 0 else run.stdout[-4000:])
+
+
+IDS, COLLECT_ERROR = _collect()
+
+
+def _junit_key(node_id):
+    """A node id as JUnit spells it: (classname, name)."""
+    path, *inner = node_id.split("::")
+    module = (SUITE + "/" + path)[:-len(".py")].replace("/", ".")
+    return ".".join([module] + inner[:-1]), inner[-1]
+
+
+@pytest.fixture(scope="module")
+def suite(tmp_path_factory):
+    """The one run: {(classname, name): (outcome, message)} and its output;
+    a run that met its time limit has no results."""
+    xml = tmp_path_factory.mktemp("benchmarks_suite") / "junit.xml"
+    try:
+        run = subprocess.run(PYTEST + [f"--junitxml={xml}"], cwd=ROOT,
+                             env=_env(), timeout=LIMIT_S, text=True,
+                             stdout=subprocess.PIPE,
+                             stderr=subprocess.STDOUT)
+        rc, out = run.returncode, run.stdout
+    except subprocess.TimeoutExpired as e:
+        out = e.stdout or ""
+        if isinstance(out, bytes):      # what a timed-out run hands back
+            out = out.decode(errors="replace")
+        return {}, None, f"still running after {LIMIT_S} s\n{out[-4000:]}"
+    results = {}
+    if xml.exists():
+        for case in ET.parse(xml).getroot().iter("testcase"):
+            bad = [c for c in case if c.tag in ("failure", "error", "skipped")]
+            results[case.get("classname"), case.get("name")] = (
+                (bad[0].tag, (bad[0].text or bad[0].get("message") or ""))
+                if bad else ("passed", ""))
+    return results, rc, out[-4000:]
+
+
+def test_the_benchmarks_own_tests_ran_to_their_end(suite):
+    """Collected without an error, and the run ended by itself with every
+    collected test in its report (rc 1 is a failing test: its own case
+    says so)."""
+    results, rc, tail = suite
+    assert not COLLECT_ERROR, COLLECT_ERROR
+    assert IDS and rc in (0, 1), tail
+    assert {_junit_key(i) for i in IDS} <= set(results), tail
+
+
+@pytest.mark.parametrize("node_id", IDS)
+def test_benchmark(suite, node_id):
+    results, _, tail = suite
+    outcome, message = results.get(_junit_key(node_id),
+                                   ("error", "no result of this test:\n"
+                                    + tail))
+    if outcome == "skipped":
+        pytest.skip(message)
+    if outcome != "passed":
+        pytest.fail(message, pytrace=False)

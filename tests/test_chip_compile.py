@@ -151,10 +151,10 @@ def test_residual_program_compiles(one_chip):
     complex-subtract-then-restack form aborted the TPU compiler on the
     v5e (rime/residual.calculate_residuals_pairs says why); a CHECK
     failure there kills this worker, which is the test failing."""
-    import bench
+    from problems import make_sky
     from sagecal_tpu.rime import predict as rp, residual as rr
     from sagecal_tpu.solvers import normal_eq as ne
-    sky = bench.make_sky(M, srcs_per_cluster=3)
+    sky = make_sky(M, srcs_per_cluster=3)
     dsky = rp.sky_to_device(sky, jnp.float32)
     sd = _spec(one_chip)
     f32, i32 = jnp.float32, jnp.int32

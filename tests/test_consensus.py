@@ -210,7 +210,7 @@ def test_mesh_admm_roundtrip(ndev):
 @pytest.mark.slow
 def test_host_loop_admm_matches_traced():
     """host_loop=True (one bounded execution per ADMM iteration, the
-    single-chip bench path) must reproduce the fully traced runner."""
+    single-chip plan) must reproduce the fully traced runner."""
     nf = 4
     sky, dsky, freqs, tiles, Jtrue = _subband_problem(nf=nf)
     n = tiles[0].n_stations

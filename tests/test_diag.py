@@ -131,8 +131,8 @@ def test_device_peaks_table():
         device_kind = "TPU v5p"
     pf, pb, nominal = roofline.device_peaks(FakeDev())
     assert pf == 459e12 and pb == 2765e9 and not nominal
-    # the CPU fallback is nominal but present (the bench's bound column
-    # must classify on the CPU fallback too)
+    # the CPU fallback is nominal but present (classify() must have
+    # peaks to divide by on the CPU too)
     pf, pb, nominal = roofline.device_peaks(jax.devices()[0])
     if jax.devices()[0].platform == "cpu":
         assert nominal and pf and pb

@@ -13,6 +13,8 @@ allocations every dispatch. Donation must be invisible to the math:
 MIGRATION.md "Buffer donation" documents the embedder-facing contract.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -25,17 +27,14 @@ from sagecal_tpu.rime import predict as rp
 from sagecal_tpu.solvers import normal_eq as ne
 from sagecal_tpu.solvers import sage
 
+from problems import build_fullbatch, make_sky
+
 
 N_STA, M, TILESZ = 8, 3, 4
 
 
 @pytest.fixture(scope="module")
 def problem():
-    import sys
-    import os
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    from bench import build_fullbatch
     sky, dsky, tiles = build_fullbatch(jnp.float32, n_stations=N_STA,
                                        n_clusters=M, tilesz=TILESZ,
                                        n_tiles=1)
@@ -188,7 +187,7 @@ def test_admm_host_loop_donation_bit_identical(problem):
     for donate in (True, False):
         runner = cadmm.make_admm_runner(
             rp.sky_to_device(  # fresh dsky is cheap at this shape
-                __import__("bench").make_sky(M, seed=17), jnp.float32),
+                make_sky(M, seed=17), jnp.float32),
             tile.sta1, tile.sta2, np.asarray(pb["cidx"]),
             np.asarray(pb["cmask"]), N_STA, tile.fdelta, Bpoly, cfg,
             mesh, F, host_loop=True, nbase=tile.nbase, donate=donate)
@@ -235,10 +234,10 @@ def test_program_log_keeps_no_live_buffers(problem):
     """jaxlint use-after-donate regression (ANALYSIS.md, PR 4): the
     sage program log stored the raw args of every logged program;
     several of those programs DONATE their carries, so the log pinned —
-    and bench's cost accounting later re-read — buffers XLA had
+    and a later cost accounting re-read — buffers XLA had
     already reclaimed. The log must keep shape/dtype skeletons only,
-    and those skeletons must still satisfy the bench contract
-    (program lowers + prices from the stored record)."""
+    and those skeletons must still lower and price from the stored
+    record (what tests/test_mfu_iters.py does with them)."""
     args, kw = _sweep_args(problem, int(SolverMode.OSLM_LBFGS))
     sage.program_stats_reset()
     try:
@@ -256,3 +255,68 @@ def test_program_log_keeps_no_live_buffers(problem):
         assert float(ca.get("flops", 0.0)) > 0
     finally:
         sage.program_stats_reset()
+
+
+def _aliased_params(compiled) -> set:
+    """Parameter indices the compiled executable's
+    ``input_output_alias`` attribute names as donated-and-aliased,
+    parsed from the HLO text by a balanced-brace scan of the attribute
+    (entries look like ``{ {}: (1, {}, may-alias) }``: output-index
+    tree, then (param, param-index-tree, kind))."""
+    txt = compiled.as_text()
+    key = "input_output_alias={"
+    start = txt.find(key)
+    if start < 0:
+        return set()
+    i = start + len(key) - 1
+    depth, j = 0, i
+    while j < len(txt):
+        if txt[j] == "{":
+            depth += 1
+        elif txt[j] == "}":
+            depth -= 1
+            if depth == 0:
+                break
+        j += 1
+    return {int(m.group(1))
+            for m in re.finditer(r"\(\s*(\d+)\s*,", txt[i:j + 1])}
+
+
+def test_donated_visibilities_are_aliased_in_the_executable():
+    """Donation ground truth (ISSUE 19): the jaxlint use-after-donate
+    checker and the DonatedRing both PROMISE that ``donate_argnums``
+    aliases the donated input into the output, but only the compiled
+    program knows whether XLA honoured it. A residual-shaped program
+    (Jones consulted, visibilities rewritten in place: parameter 1,
+    donated in pipeline.py's ``_residuals`` jit) compiled twice: the
+    donated twin aliases parameter 1, the plain twin aliases nothing."""
+    rng = np.random.default_rng(0)
+    B = 64
+    J = jnp.asarray(rng.normal(size=(B, 2, 2))
+                    + 1j * rng.normal(size=(B, 2, 2)), jnp.complex64)
+    V = jnp.asarray(rng.normal(size=(B, 2, 2))
+                    + 1j * rng.normal(size=(B, 2, 2)), jnp.complex64)
+
+    def residuals(J, V):
+        return V - J @ V @ jnp.conj(jnp.swapaxes(J, -1, -2))
+
+    donated = jax.jit(residuals, donate_argnums=(1,)).lower(J, V).compile()
+    plain = jax.jit(residuals).lower(J, V).compile()
+    assert 1 in _aliased_params(donated)
+    assert _aliased_params(plain) == set()
+
+
+def test_alias_parse_is_not_vacuous():
+    """The reader's own control: the first of two parameters donated
+    reads as {0}, the same program undonated as the empty set, so an
+    empty reading means missing aliasing and not a parse that matches
+    nothing."""
+    x = jnp.ones((8,), jnp.float32)
+
+    def f(a, b):
+        return a + b
+
+    donated = jax.jit(f, donate_argnums=(0,)).lower(x, x).compile()
+    plain = jax.jit(f).lower(x, x).compile()
+    assert _aliased_params(donated) == {0}
+    assert _aliased_params(plain) == set()

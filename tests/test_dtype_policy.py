@@ -1,6 +1,6 @@
 """Dtype-policy gates (ISSUE 6): storage/accumulate contract.
 
-Three contract families, mirroring the PR 3 cg-vs-chol pattern
+Two contract families, mirroring the PR 3 cg-vs-chol pattern
 (MIGRATION.md "Dtype policy"):
 
 - **f32 identity**: the policy plumbing must cost the default path
@@ -9,10 +9,7 @@ Three contract families, mirroring the PR 3 cg-vs-chol pattern
 - **trajectory tolerance**: reduced policies (bf16/f16) are gated by
   per-policy residual envelopes against the f32 chain, NOT bit parity —
   the reduced path is free to re-lay contractions (normal_eq reduced
-  assembly, LU damped solve, OS subset slicing);
-- **traffic**: the priced config-1 LM trip's ``bytes_accessed`` must
-  drop >= 30% under bf16 at equal trip counts (the roofline is
-  dtype-aware; bench.solver_trip_cost prices the body lm.py executes).
+  assembly, LU damped solve, OS subset slicing).
 
 All tests run f32 DATA built explicitly (the suite enables x64; the
 policy entry-cast covers the staging half of the contract).
@@ -496,59 +493,3 @@ def test_pipeline_sharded_no_f32_fallback(tmp_path):
     assert not any("policy-exempt" in str(line) for line in logs)
     assert pipe.dtype_policy == "bf16"
     assert pipe.sdt == jnp.dtype(jnp.bfloat16)
-
-
-# ---------------------------------------------------------------------------
-# traffic: the priced config-1 trip melts >= 30% under bf16
-# ---------------------------------------------------------------------------
-
-def test_config1_trip_bytes_drop_30pct():
-    """Equal-trip-count roofline gate: one priced LM damping trip at the
-    bench config-1 shape (N=62, B=18910, mode 3, baseline-major) must
-    cost >= 30% fewer bytes under bf16 than the f32 reference — the
-    XLA cost analysis is dtype-aware, so this asserts the melt the
-    bank (BENCH_CPU_r09.json) records, without running the bench."""
-    import importlib.util, os, sys
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    sys.modules.setdefault("bench", bench)
-    spec.loader.exec_module(bench)
-    f32 = bench.solver_trip_cost(3, 1, 62, 18910, jnp.float32, nbase=1891)
-    bf16 = bench.solver_trip_cost(3, 1, 62, 18910, jnp.bfloat16,
-                                  nbase=1891)
-    assert f32 and bf16, "trip pricing unavailable"
-    drop = 1.0 - bf16["bytes_accessed"] / f32["bytes_accessed"]
-    assert drop >= 0.30, f"bf16 trip bytes drop {drop:.1%} < 30%"
-
-
-def test_pallas_chol_trip_prices_fused_body():
-    """ISSUE 17 satellite: solver_trip_cost(kernel='pallas',
-    inner='chol') must price the EXECUTED fused block-Cholesky body
-    (gn_blocks sweep + chol_solve_blocks_shift), not the dead dense-XLA
-    branch — the same phantom-bytes class the PR 3 gate above pins for
-    the dtype melt. Gated structurally: the pallas-chol price exists,
-    differs from the xla-chol price (a shared dead program would price
-    identically), and differs from the pallas-cg price (the two inner
-    bodies are different programs)."""
-    import importlib.util, os, sys
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    sys.modules.setdefault("bench", bench)
-    spec.loader.exec_module(bench)
-    shape = dict(kmax=1, n_stations=62, B=18910, nbase=1891)
-    xla = bench.solver_trip_cost(3, dtype=jnp.float32, kernel="xla",
-                                 inner="chol", **shape)
-    pal = bench.solver_trip_cost(3, dtype=jnp.float32, kernel="pallas",
-                                 inner="chol", **shape)
-    pcg = bench.solver_trip_cost(3, dtype=jnp.float32, kernel="pallas",
-                                 inner="cg", **shape)
-    assert xla and pal and pcg, "trip pricing unavailable"
-    assert pal["bytes_accessed"] > 0 and pal["flops"] > 0
-    assert pal["bytes_accessed"] != xla["bytes_accessed"], \
-        "pallas-chol priced identically to the dense XLA branch"
-    assert pal["bytes_accessed"] != pcg["bytes_accessed"], \
-        "pallas-chol priced identically to the pallas-cg body"

@@ -379,8 +379,7 @@ def test_fleet_loadgen_replay_end_to_end(tmp_path):
     """The loadgen drives a live 2-device fleet with a mixed-bucket
     burst spec; every job completes, the replay record carries the
     measured queue-wait percentiles, and per-job outputs are
-    bit-identical to solo runs of the same template configs (the
-    FLEET bench's refuse-to-bank gate, exercised at test scale)."""
+    bit-identical to solo runs of the same template configs."""
     assert len(jax.devices()) >= 2
     spec = {
         "seed": 21, "n_jobs": 4,

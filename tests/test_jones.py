@@ -12,7 +12,7 @@ The tentpole contract, tested from the algebra up:
 - the pallas sweep's reduced per-baseline Gram blocks agree with the
   xla mode path per mode (interpret lane), chol/matvec included;
 - on constrained-truth data every constrained solver path converges
-  (the quality envelope the banked JONES_r20.json rides on);
+  (the quality envelope of the constrained modes);
 - consensus ADMM refuses constrained modes loudly (the y/bz vectors
   are full-Jones parameters);
 - the gauge projection removes exactly the global-phase direction.
@@ -22,7 +22,6 @@ rule; the fast subset stays in the CI fail-fast lane.
 """
 
 import os
-import sys
 
 import numpy as np
 import jax
@@ -269,7 +268,7 @@ def test_gn_factors_matvec_shift_parity(mode):
 def test_full_mode_entry_points_bit_identical():
     """jones_mode='full' is the bit-frozen default: the mode entry
     points reproduce the pre-mode normal equations bit-for-bit (the
-    acceptance gate the bench re-checks per round)."""
+    acceptance gate)."""
     g = _geometry()
     wt = _weights("irls", g)
     cwt = jnp.asarray(np.abs(g["rng"].normal(size=(g["B"], 8))) + 0.1)
@@ -288,8 +287,7 @@ def test_full_mode_solver_bit_identical():
     """The mode-threaded SOLVER at jones_mode='full' reproduces the
     default-config solve bit-for-bit (compile-path twin of the
     normal-equations gate above; slow-marked — two solver compiles —
-    per the round-17 tier-1 budget rule; the bench re-runs this gate
-    on every banked round)."""
+    per the round-17 tier-1 budget rule)."""
     g = _geometry()
     x8, J0, _ = _constrained_truth(g, "diag")
     w1 = jnp.ones((g["B"], 8))
@@ -525,32 +523,3 @@ def test_gauge_projection_removes_global_phase_only():
         # projected output is orthogonal to the gauge direction
         assert float(np.abs(np.sum(once * gauge, -1)).max()) \
             < 1e-9 * float(np.abs(v).max() * np.abs(gauge).max())
-
-
-# ---------------------------------------------------------------------------
-# mode-aware trip pricing
-# ---------------------------------------------------------------------------
-
-@pytest.mark.slow
-def test_trip_cost_is_mode_aware():
-    """solver_trip_cost prices the reduced lanes cheaper: phase <
-    diag < full on bytes/trip for the same shape, on both kernels —
-    the honesty gate behind the banked JONES_r20.json ratios."""
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
-    from sagecal_tpu.config import SolverMode
-    K, N, nb, T = 1, 8, 28, 4
-    B = nb * T
-    for kernel in ("xla", "pallas"):
-        costs = {}
-        for jm in ("full", "diag", "phase"):
-            c = bench.solver_trip_cost(
-                int(SolverMode.OSLM_OSRLM_RLBFGS), K, N, B,
-                jnp.float32, nbase=nb, inner="chol", kernel=kernel,
-                jones=jm)
-            costs[jm] = c["bytes_accessed"]
-        assert costs["phase"] < costs["diag"] < costs["full"], \
-            (kernel, costs)

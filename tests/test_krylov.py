@@ -271,8 +271,8 @@ def test_jitter_retry_recovers_singular_system():
 
 def test_sage_threads_inner_flag():
     """SageConfig.inner reaches the per-cluster solves and the executed
-    PCG trips surface in info["cg_iters"] (the bench's roofline
-    trip-accounting hook)."""
+    PCG trips surface in info["cg_iters"] (the tile record's
+    trip count)."""
     from sagecal_tpu.config import SolverMode
     from sagecal_tpu.solvers import sage
     x8, coh, s1, s2, cid, _, nbase = _toy(N=5, T=2, K=1, seed=16,
@@ -298,8 +298,8 @@ def test_sage_threads_inner_flag():
 
 @pytest.mark.slow
 def test_gn_matvec_heavy_shape():
-    """Bench-config-1-sized equivalence (N=62, K=2): the heavy-shape
-    gate for the paths the bench and the north-star actually run."""
+    """LOFAR-sized equivalence (N=62, K=2): the heavy-shape gate
+    at the station count the benchmark's cells run."""
     x8, coh, s1, s2, cid, _, nbase = _toy(N=62, T=2, K=2, seed=17)
     N, K = 62, 2
     rng = np.random.default_rng(18)
@@ -320,8 +320,7 @@ def test_multichip_admm_cg_residuals_fall():
     """The multichip gate of the PR-3 acceptance: the full consensus-
     ADMM program on the (conftest-provided) virtual 8-device CPU mesh
     with the matrix-free inner solver — per-subband residuals must
-    still fall. Mirrors tools_dev/northstar.py --multichip at a small
-    shape."""
+    still fall, at a small shape."""
     import jax
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from sagecal_tpu import utils

@@ -1,11 +1,12 @@
-"""Executed-iteration counters for the bench's MFU trip accounting.
+"""Executed-iteration counters of the solvers.
 
-VERDICT r4 weak 2: XLA cost analysis prices loop bodies once, so the
-solvers now report how many iterations actually ran
-(info["solver_iters"] / info["lbfgs_iters"]); bench.py multiplies these
-by per-trip FLOP prices. These tests pin the counter contract: present,
-positive, and identical between the fully traced and host-driven
-drivers (same math -> same trip counts).
+XLA cost analysis prices loop bodies once, so the solvers report how
+many iterations actually ran (info["solver_iters"] / "lbfgs_iters" /
+"cg_iters" / "refine_passes"); the pipeline writes them into each
+``tile`` record, where benchmarks/ reads ``solver_trips``,
+``tcg_trips`` and ``refine_passes``. These tests pin the counter
+contract: present, positive, and identical between the fully traced
+and host-driven drivers (same math -> same trip counts).
 """
 
 import numpy as np

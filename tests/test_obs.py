@@ -1,15 +1,11 @@
 """sagecal_tpu.obs gates (ISSUE 9): the metrics registry's no-op /
 thread-safety / percentile contracts, Prometheus exposition, the
-convergence-health state machine, and the perf-regression sentinel —
-including the acceptance pair: metrics OFF is bit-identical with zero
-added compiles (retrace-guard gated), and the sentinel passes on the
-clean tree while demonstrably failing (non-zero exit, named metric)
-on a doctored bank.
+convergence-health state machine, and the acceptance gate: metrics OFF
+is bit-identical with zero added compiles (retrace-guard gated).
 """
 
 import json
 import os
-import shutil
 import sys
 import threading
 
@@ -23,7 +19,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 from sagecal_tpu.obs import export as oexport  # noqa: E402
 from sagecal_tpu.obs import health as ohealth  # noqa: E402
 from sagecal_tpu.obs import metrics as omet  # noqa: E402
-from sagecal_tpu.obs import sentinel  # noqa: E402
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -355,524 +350,3 @@ def test_obs_emission_zero_retrace(retrace_guard):
         assert omet.get().get("steps_total").value() >= 2
     finally:
         omet.disable()
-
-
-# ---------------------------------------------------------------------------
-# sentinel.py
-# ---------------------------------------------------------------------------
-
-def _rec(**kw):
-    base = {"shape": "N=8 test", "step_s": 10.0,
-            "bytes_accessed": 1e9, "device_busy_frac": 0.95,
-            "cache_hit_rate": 1.0}
-    base.update(kw)
-    return base
-
-
-def test_sentinel_compare_directions_and_tolerances():
-    bank = {"cfg": _rec()}
-    # identical: clean
-    assert sentinel.compare({"cfg": _rec()}, bank) == []
-    # improvements NEVER fail (faster, fewer bytes, busier, hotter)
-    good = _rec(step_s=5.0, bytes_accessed=5e8, device_busy_frac=0.99,
-                cache_hit_rate=1.0)
-    assert sentinel.compare({"cfg": good}, bank) == []
-    # each metric regresses past its tolerance -> one NAMED violation
-    for field, bad_val, metric in (
-            ("step_s", 14.0, "wall"),                # +40% > 30%
-            ("bytes_accessed", 1.03e9, "bytes"),     # +3% > 2%
-            ("device_busy_frac", 0.88, "bubble"),    # -0.07 > 0.05
-            ("cache_hit_rate", 0.9, "cache")):       # -0.1 > 0.02
-        v = sentinel.compare({"cfg": _rec(**{field: bad_val})}, bank)
-        assert len(v) == 1, (field, v)
-        assert v[0]["metric"] == metric and v[0]["field"] == field
-        assert metric in v[0]["msg"] and "cfg" in v[0]["msg"]
-    # within tolerance: clean
-    ok = _rec(step_s=12.9, bytes_accessed=1.019e9,
-              device_busy_frac=0.91, cache_hit_rate=0.985)
-    assert sentinel.compare({"cfg": ok}, bank) == []
-    # a re-shaped config is a different experiment: no claim either way
-    v = sentinel.compare({"cfg": _rec(shape="N=16 test",
-                                      step_s=99.0)}, bank)
-    assert v == []
-    # FAILED records and absent fields are skipped
-    assert sentinel.compare({"cfg": {"error": "x"}}, bank) == []
-    assert sentinel.compare({"cfg": {"shape": "N=8 test"}}, bank) == []
-
-
-def test_sentinel_table_contract():
-    # the real header passes (bench.write_table calls this on render)
-    sentinel.assert_table_contract(
-        "| config | value | unit | res_0 -> res_1 | step | compile | "
-        "GFLOP/s | GB/s | Δbytes | bound | MFU≥ | shape |")
-    with pytest.raises(AssertionError, match="step"):
-        sentinel.assert_table_contract("| config | value | Δbytes |")
-    # every toleranced metric must have a column mapping entry
-    assert set(sentinel.TABLE_COLUMNS) == set(sentinel.TOLERANCES)
-
-
-def _write_bank(dirpath, rnd, results, platform="cpu"):
-    with open(os.path.join(
-            dirpath, f"BENCH_{platform.upper()}_r{rnd:02d}.json"),
-            "w") as f:
-        json.dump({"platform": platform, "date": "2026-08-04",
-                   "results": results}, f)
-
-
-def test_sentinel_cross_round_newest_pair_only(tmp_path):
-    """The cross-round check judges each config's NEWEST banked pair:
-    a fresh regression fails; an old (pre-sentinel) one deep in the
-    history does not re-litigate."""
-    d = str(tmp_path)
-    _write_bank(d, 1, {"cfg": _rec(step_s=5.0)})
-    _write_bank(d, 2, {"cfg": _rec(step_s=20.0)})   # old jump: ignored
-    _write_bank(d, 3, {"cfg": _rec(step_s=19.0)})
-    assert sentinel.cross_round_check("cpu", d) == []
-    # now the newest round regresses bytes: caught and named
-    _write_bank(d, 4, {"cfg": _rec(step_s=19.0, bytes_accessed=1.1e9)})
-    v = sentinel.cross_round_check("cpu", d)
-    assert len(v) == 1 and v[0]["metric"] == "bytes"
-    assert v[0]["round"] == 4 and "r03" in v[0]["msg"]
-
-
-def test_sentinel_newest_bank_results_merges_rounds(tmp_path):
-    d = str(tmp_path)
-    _write_bank(d, 1, {"a": _rec(step_s=1.0), "b": _rec()})
-    _write_bank(d, 2, {"a": _rec(step_s=2.0)})
-    merged = sentinel.newest_bank_results("cpu", d)
-    assert merged["a"]["step_s"] == 2.0     # newest occurrence wins
-    assert "b" in merged                    # absent configs persist
-    assert sentinel.newest_bank_results("tpu", d) == {}
-
-
-def test_sentinel_fast_passes_on_clean_tree_bank(capsys):
-    """The committed bank obeys the tolerances (the CI lane's bank
-    half; the live probes run there and in the probe tests below)."""
-    rc = sentinel.main(["--fast", "--no-probes"])
-    assert rc == 0
-    assert "OK" in capsys.readouterr().out
-
-
-def test_sentinel_fails_on_doctored_bank(tmp_path, capsys):
-    """The acceptance leg: a doctored bank record makes the sentinel
-    exit non-zero and NAME the regressed metric."""
-    d = str(tmp_path)
-    shutil.copy(os.path.join(REPO, "BENCH_CPU_r09.json"),
-                os.path.join(d, "BENCH_CPU_r09.json"))
-    with open(os.path.join(REPO, "BENCH_CPU_r09.json")) as f:
-        doc = json.load(f)
-    doc["results"]["1-fullbatch-lm"]["bytes_accessed"] *= 1.10
-    with open(os.path.join(d, "BENCH_CPU_r10.json"), "w") as f:
-        json.dump(doc, f)
-    rc = sentinel.main(["--fast", "--no-probes", "--bank-dir", d,
-                        "--platform", "cpu"])
-    assert rc == 1
-    err = capsys.readouterr().err
-    assert "SENTINEL REGRESSION" in err
-    assert "bytes" in err and "1-fullbatch-lm" in err
-    # and an empty bank dir is a usage error, not a silent pass
-    assert sentinel.main(["--fast", "--no-probes", "--bank-dir",
-                          str(tmp_path / "empty")]) == 2
-
-
-def test_sentinel_overlap_probe_green():
-    assert sentinel.probe_overlap() == []
-
-
-@pytest.mark.slow
-def test_sentinel_cache_probe_green():
-    """The live cache probe (also exercised by the CI sentinel lane):
-    a second bucket-compatible pipeline adds zero compiles."""
-    assert sentinel.probe_cache() == []
-
-
-def test_sentinel_donation_probe_green():
-    """ISSUE 19 satellite: the lowered hot program really aliases its
-    donated visibility parameter (donation ground truth — the AST
-    use-after-donate checker only promises it)."""
-    assert sentinel.probe_donation() == []
-
-
-def test_sentinel_donation_alias_parse_not_vacuous():
-    """The probe's own negative control, exercised directly: the
-    undonated twin compiles with NO aliased parameters, so an empty
-    parse on the donated twin means missing aliasing, not a parser
-    that matches nothing."""
-    import jax
-    import jax.numpy as jnp
-    x = jnp.ones((8,), jnp.float32)
-
-    def f(a, b):
-        return a + b
-
-    donated = jax.jit(f, donate_argnums=(0,)).lower(x, x).compile()
-    plain = jax.jit(f).lower(x, x).compile()
-    assert sentinel._aliased_params(donated) == {0}
-    assert sentinel._aliased_params(plain) == set()
-
-
-def _write_fleet_bank(dirpath, rnd, rec, platform="cpu"):
-    with open(os.path.join(dirpath, f"FLEET_r{rnd:02d}.json"), "w") as f:
-        json.dump({"platform": platform, "date": "2026-08-04",
-                   "results": {"9-fleet-throughput": rec}}, f)
-
-
-def _fleet_rec(**kw):
-    rec = dict(scaling_1to2=1.85,
-               throughput_per_device_2dev_jobs_h=2470.0,
-               p99_queue_wait_2dev_s=2.9, cache_hit_rate_min_2dev=1.0,
-               shape="fleet test")
-    rec.update(kw)
-    return rec
-
-
-def test_sentinel_fleet_cross_round(tmp_path):
-    """ISSUE 12 satellite: the fleet bank (FLEET_rNN.json) is judged
-    like the BENCH banks — newest pair, named metric, improvements
-    never fail; a collapsed 1->2-device scaling or a cold per-device
-    compile cache fails with the metric named."""
-    d = str(tmp_path)
-    _write_fleet_bank(d, 12, _fleet_rec())
-    assert sentinel.fleet_cross_round_check("cpu", d) == []
-    _write_fleet_bank(d, 13, _fleet_rec(scaling_1to2=1.95))
-    assert sentinel.fleet_cross_round_check("cpu", d) == []
-    _write_fleet_bank(d, 14, _fleet_rec(scaling_1to2=1.2))
-    v = sentinel.fleet_cross_round_check("cpu", d)
-    assert len(v) == 1 and v[0]["metric"] == "scaling"
-    assert "FLEET r14" in v[0]["msg"]
-    _write_fleet_bank(d, 15, _fleet_rec(scaling_1to2=1.95,
-                                        cache_hit_rate_min_2dev=0.5))
-    v = sentinel.fleet_cross_round_check("cpu", d)
-    assert {x["metric"] for x in v} == {"fleet_cache"}
-    assert sentinel.load_fleet_banks("tpu", d) == []
-
-
-def _write_mesh_bank(dirpath, rnd, rec, platform="cpu"):
-    with open(os.path.join(dirpath, f"MESH2D_r{rnd:02d}.json"),
-              "w") as f:
-        json.dump({"platform": platform, "date": "2026-08-04",
-                   "results": {"10-mesh2d-northstar": rec}}, f)
-
-
-def _mesh_rec(**kw):
-    rec = dict(wall_per_admm_iter_s=12.0,
-               collective_overhead_frac=0.001, parity_ok=1,
-               shape="mesh test")
-    rec.update(kw)
-    return rec
-
-
-def test_sentinel_mesh_cross_round(tmp_path):
-    """ISSUE 14 satellite: the 2-D mesh bank (MESH2D_rNN.json) is
-    judged like the FLEET bank — newest pair, named metric,
-    improvements never fail; a regressed wall/iter, a fattened
-    collective-overhead fraction, or a LOST residual-parity flag
-    fails with the metric named."""
-    d = str(tmp_path)
-    _write_mesh_bank(d, 13, _mesh_rec())
-    assert sentinel.mesh_cross_round_check("cpu", d) == []
-    _write_mesh_bank(d, 14, _mesh_rec(wall_per_admm_iter_s=10.0))
-    assert sentinel.mesh_cross_round_check("cpu", d) == []
-    _write_mesh_bank(d, 15, _mesh_rec(wall_per_admm_iter_s=20.0))
-    v = sentinel.mesh_cross_round_check("cpu", d)
-    assert len(v) == 1 and v[0]["metric"] == "mesh_wall"
-    assert "MESH2D r15" in v[0]["msg"]
-    _write_mesh_bank(d, 16, _mesh_rec(wall_per_admm_iter_s=10.0,
-                                      parity_ok=0,
-                                      collective_overhead_frac=0.2))
-    v = sentinel.mesh_cross_round_check("cpu", d)
-    assert {x["metric"] for x in v} == {"mesh_parity",
-                                        "mesh_collective"}
-    assert sentinel.load_mesh_banks("tpu", d) == []
-
-
-def test_sentinel_mesh_committed_bank_loads():
-    """The committed MESH2D round parses, declares its platform,
-    carries every toleranced field, banked with parity OK, a bf16
-    (non-fallback) dtype policy, and the staleness experiment's
-    convergence delta as numbers."""
-    banks = sentinel.load_mesh_banks("cpu", REPO)
-    assert banks, "no committed MESH2D_rNN.json"
-    rec = banks[-1][2]["10-mesh2d-northstar"]
-    for spec in sentinel.MESH_TOLERANCES.values():
-        assert spec["field"] in rec, spec["field"]
-    assert rec["parity_ok"] == 1
-    assert rec["dtype_policy"] != "f32" and not rec["f32_fallback"]
-    st = rec["staleness"]
-    assert st["skipped_solves"] > 0
-    assert "convergence_delta_rel_mean" in st
-    assert st["stale_still_falling"] is True
-
-
-def test_sentinel_fleet_committed_bank_loads():
-    """The committed FLEET round parses, declares its platform, and
-    carries every toleranced field (a renamed bench field can never
-    silently orphan a fleet tolerance)."""
-    banks = sentinel.load_fleet_banks("cpu", REPO)
-    assert banks, "no committed FLEET_rNN.json"
-    rec = banks[-1][2]["9-fleet-throughput"]
-    for spec in sentinel.FLEET_TOLERANCES.values():
-        assert spec["field"] in rec, spec["field"]
-    assert rec["bit_identical"] is True
-    assert rec["migration"]["tiles_rerun"] == 0
-
-
-def _write_stream_bank(dirpath, rnd, rec, platform="cpu"):
-    with open(os.path.join(dirpath, f"STREAM_r{rnd:02d}.json"),
-              "w") as f:
-        json.dump({"platform": platform, "date": "2026-08-07",
-                   "results": {"11-stream-latency": rec}}, f)
-
-
-def _stream_rec(**kw):
-    rec = dict(p99_latency_s=0.58, late_frac=0.0,
-               batch_tiles_rerun=0, shape="stream test")
-    rec.update(kw)
-    return rec
-
-
-def test_sentinel_stream_cross_round(tmp_path, capsys):
-    """ISSUE 16 satellite: the streaming bank (STREAM_rNN.json) is
-    judged like the FLEET/MESH2D/SCALEOUT banks — newest pair, named
-    metric, improvements never fail; a fattened p99 arrival->write
-    tail, ANY missed per-tile deadline, or batch tiles RE-RUN across
-    stream preemptions fails with the metric named."""
-    d = str(tmp_path)
-    _write_stream_bank(d, 16, _stream_rec())
-    assert sentinel.stream_cross_round_check("cpu", d) == []
-    _write_stream_bank(d, 17, _stream_rec(p99_latency_s=0.4))
-    assert sentinel.stream_cross_round_check("cpu", d) == []
-    _write_stream_bank(d, 18, _stream_rec(p99_latency_s=1.5))
-    v = sentinel.stream_cross_round_check("cpu", d)
-    assert len(v) == 1 and v[0]["metric"] == "stream_p99_latency"
-    assert "STREAM r18" in v[0]["msg"]
-    _write_stream_bank(d, 19, _stream_rec(late_frac=0.25,
-                                          batch_tiles_rerun=2))
-    v = sentinel.stream_cross_round_check("cpu", d)
-    assert {x["metric"] for x in v} == {"stream_late_frac",
-                                        "stream_batch_rerun"}
-    # the CLI lane fails with the metric named (needs any BENCH bank
-    # present so main() has a platform to check)
-    shutil.copy(os.path.join(REPO, "BENCH_CPU_r09.json"),
-                os.path.join(d, "BENCH_CPU_r09.json"))
-    rc = sentinel.main(["--fast", "--no-probes", "--platform", "cpu",
-                        "--bank-dir", d])
-    assert rc == 1
-    err = capsys.readouterr().err
-    assert "stream_late_frac" in err or "late" in err
-    assert sentinel.load_stream_banks("tpu", d) == []
-
-
-def test_sentinel_stream_committed_bank_loads():
-    """The committed STREAM round parses, declares its platform,
-    carries every toleranced field, and banked the acceptance gates:
-    p99 arrival->write under the stated budget while a batch job
-    shared the device, ZERO late tiles, ZERO batch tiles re-run
-    across preemptions (>= 1 preemption actually exercised), and
-    per-job bit-identity vs the batch path."""
-    banks = sentinel.load_stream_banks("cpu", REPO)
-    assert banks, "no committed STREAM_rNN.json"
-    rec = banks[-1][2]["11-stream-latency"]
-    for spec in sentinel.STREAM_TOLERANCES.values():
-        assert spec["field"] in rec, spec["field"]
-    assert rec["p99_latency_s"] <= rec["budget_s"]
-    assert rec["late_frac"] == 0.0
-    assert rec["batch_tiles_rerun"] == 0
-    assert rec["preemptions"] >= 1
-    assert rec["bit_identical"] is True
-
-
-def _write_kmelt_bank(dirpath, rnd, rec, platform="cpu"):
-    # BSCALING records are banked BARE (northstar.py b_scaling), not
-    # in the {"results": ...} envelope — the loader wraps them
-    with open(os.path.join(dirpath, f"BSCALING_r{rnd:02d}.json"),
-              "w") as f:
-        json.dump(dict(rec, platform=platform), f)
-
-
-def _kmelt_rec(**kw):
-    rec = dict(shape="N=64 M=48 -j5 -g 3 hybrid-chunks",
-               full_pallas_vs_xla_pct_chol=-10.9,
-               floor_pallas_vs_xla_pct_chol=9.4,
-               floor_pallas_vs_xla_pct_cg=-53.3,
-               cg_vs_chol_pct_pallas=173.2)
-    rec.update(kw)
-    return rec
-
-
-def test_sentinel_kmelt_cross_round(tmp_path, capsys):
-    """ISSUE 17 satellite: the kernel-melt bank (BSCALING_rNN.json)
-    is judged like the other families — newest pair, named metric,
-    improvements never fail; a melted full-B chol win, a regressed
-    small-rung floor, or an exploded cg-on-kernel price fails with
-    the metric named."""
-    d = str(tmp_path)
-    _write_kmelt_bank(d, 17, _kmelt_rec())
-    assert sentinel.kmelt_cross_round_check("cpu", d) == []
-    _write_kmelt_bank(d, 18, _kmelt_rec(
-        full_pallas_vs_xla_pct_chol=-14.0,
-        floor_pallas_vs_xla_pct_chol=4.0))
-    assert sentinel.kmelt_cross_round_check("cpu", d) == []
-    _write_kmelt_bank(d, 19, _kmelt_rec(
-        full_pallas_vs_xla_pct_chol=2.0,       # kernel lost its win
-        floor_pallas_vs_xla_pct_cg=-20.0,      # cg floor regressed
-        cg_vs_chol_pct_pallas=300.0))          # cg price exploded
-    v = sentinel.kmelt_cross_round_check("cpu", d)
-    assert {x["metric"] for x in v} == {"kmelt_full_chol",
-                                        "kmelt_floor_cg",
-                                        "kmelt_cg_price"}
-    assert all("KMELT r19" in x["msg"] for x in v)
-    # the CLI lane fails with the metric named — and a bank dir with
-    # ONLY family records (the burn-down scratch dir) is still checked
-    rc = sentinel.main(["--fast", "--no-probes", "--platform", "cpu",
-                        "--bank-dir", d])
-    assert rc == 1
-    assert "kmelt_full_chol" in capsys.readouterr().err
-    assert sentinel.load_kmelt_banks("tpu", d) == []
-
-
-def test_sentinel_kmelt_committed_bank_loads():
-    """The committed kernel-melt round parses, declares its platform,
-    and the newest round carries every toleranced field (r07 predates
-    the headline fields and is skipped by the absent-field guard, not
-    crashed on)."""
-    banks = sentinel.load_kmelt_banks("cpu", REPO)
-    assert banks, "no committed BSCALING_rNN.json"
-    rec = banks[-1][2]["b-scaling"]
-    for spec in sentinel.KMELT_TOLERANCES.values():
-        assert spec["field"] in rec, spec["field"]
-    # the priced small-rung regression is ON the record, per rung
-    assert isinstance(rec["small_rung_pallas_vs_xla_pct_chol"], list)
-
-
-def _write_warm_bank(dirpath, rnd, rec, platform="cpu"):
-    with open(os.path.join(dirpath, f"WARM_r{rnd:02d}.json"),
-              "w") as f:
-        json.dump({"platform": platform, "date": "2026-08-07",
-                   "results": {"12-warm-start": rec}}, f)
-
-
-def _warm_rec(**kw):
-    rec = dict(sweeps_reduction_frac=0.5, wall_per_job_warm_s=1.0,
-               residual_ratio_warm_vs_cold=1.0, prior_hit_rate=1.0,
-               router_prior_affinity_hit_rate=1.0, shape="warm test")
-    rec.update(kw)
-    return rec
-
-
-def test_sentinel_warm_cross_round(tmp_path, capsys):
-    """ISSUE 18 satellite: the warm-start bank (WARM_rNN.json) is
-    judged like the STREAM/KMELT banks — newest pair, named metric,
-    improvements never fail; a shrunken sweeps saving, a fattened
-    warm wall, a degraded warm residual envelope, or a dropped
-    prior/router hit rate fails with the metric named."""
-    d = str(tmp_path)
-    _write_warm_bank(d, 18, _warm_rec())
-    assert sentinel.warm_cross_round_check("cpu", d) == []
-    _write_warm_bank(d, 19, _warm_rec(sweeps_reduction_frac=0.6,
-                                      wall_per_job_warm_s=0.8))
-    assert sentinel.warm_cross_round_check("cpu", d) == []
-    _write_warm_bank(d, 20, _warm_rec(
-        sweeps_reduction_frac=0.1,             # saving shrank
-        residual_ratio_warm_vs_cold=1.2,       # warm quality degraded
-        prior_hit_rate=0.5))                   # store stopped hitting
-    v = sentinel.warm_cross_round_check("cpu", d)
-    assert {x["metric"] for x in v} == {"warm_sweeps_reduction",
-                                        "warm_residual_ratio",
-                                        "warm_prior_hit_rate"}
-    assert all("WARM r20" in x["msg"] for x in v)
-    # the CLI lane fails with the metric named
-    rc = sentinel.main(["--fast", "--no-probes", "--platform", "cpu",
-                        "--bank-dir", d])
-    assert rc == 1
-    assert "warm_sweeps_reduction" in capsys.readouterr().err
-    assert sentinel.load_warm_banks("tpu", d) == []
-
-
-def test_sentinel_warm_committed_bank_loads():
-    """The committed WARM round parses, declares its platform,
-    carries every toleranced field, and banked the acceptance gates:
-    warm jobs spend measurably fewer sweeps than the cold control at
-    equal residual quality (within the envelope), the store actually
-    hit, the router's prior affinity actually routed, and the off
-    lane stayed bit-identical to the frozen cold start."""
-    banks = sentinel.load_warm_banks("cpu", REPO)
-    assert banks, "no committed WARM_rNN.json"
-    rec = banks[-1][2]["12-warm-start"]
-    for spec in sentinel.WARM_TOLERANCES.values():
-        assert spec["field"] in rec, spec["field"]
-    assert rec["sweeps_reduction_frac"] > 0.0
-    assert (rec["residual_ratio_warm_vs_cold"]
-            <= 1.0 + rec["res_envelope"])
-    assert rec["prior_hit_rate"] > 0.0
-    assert rec["router_prior_affinity_hits"] >= 1
-    assert rec["off_bit_identical"] is True
-
-
-def _write_jones_bank(dirpath, rnd, rec, platform="cpu"):
-    with open(os.path.join(dirpath, f"JONES_r{rnd:02d}.json"),
-              "w") as f:
-        json.dump({"platform": platform, "date": "2026-08-07",
-                   "results": {"13-jones-melt": rec}}, f)
-
-
-def _jones_rec(**kw):
-    rec = dict(phase_bytes_ratio_xla=0.26, phase_bytes_ratio_pallas=0.09,
-               diag_bytes_ratio_xla=0.54, diag_bytes_ratio_pallas=0.31,
-               residual_envelope_met=True, full_mode_bit_identical=True,
-               shape="jones test")
-    rec.update(kw)
-    return rec
-
-
-def test_sentinel_jones_cross_round(tmp_path, capsys):
-    """ISSUE 20 satellite: the constrained-Jones bank (JONES_rNN.json)
-    is judged like the WARM/KMELT banks — newest pair, named metric,
-    improvements never fail; a fattened phase or diag bytes/trip
-    ratio (the reduced Gram path re-densifying), a dropped residual
-    envelope, or lost full-mode bit-identity fails with the metric
-    named."""
-    d = str(tmp_path)
-    _write_jones_bank(d, 20, _jones_rec())
-    assert sentinel.jones_cross_round_check("cpu", d) == []
-    _write_jones_bank(d, 21, _jones_rec(phase_bytes_ratio_xla=0.22,
-                                        diag_bytes_ratio_pallas=0.28))
-    assert sentinel.jones_cross_round_check("cpu", d) == []
-    _write_jones_bank(d, 22, _jones_rec(
-        phase_bytes_ratio_xla=0.35,            # phase re-densified
-        diag_bytes_ratio_pallas=0.60,          # diag kernel ratio blew
-        residual_envelope_met=False))          # quality gate dropped
-    v = sentinel.jones_cross_round_check("cpu", d)
-    assert {x["metric"] for x in v} == {"jones_phase_bytes_xla",
-                                        "jones_diag_bytes_pallas",
-                                        "jones_residual_envelope"}
-    assert all("JONES r22" in x["msg"] for x in v)
-    # the CLI lane fails with the metric named — and a bank dir with
-    # ONLY family records (the burn-down scratch dir) is still checked
-    rc = sentinel.main(["--fast", "--no-probes", "--platform", "cpu",
-                        "--bank-dir", d])
-    assert rc == 1
-    assert "jones_phase_bytes_xla" in capsys.readouterr().err
-    assert sentinel.load_jones_banks("tpu", d) == []
-
-
-def test_sentinel_jones_committed_bank_loads():
-    """The committed JONES round parses, declares its platform,
-    carries every toleranced field, and banked the acceptance gates:
-    phase-mode bytes/trip <= 0.35x full on BOTH kernels at equal
-    executed trips, the constrained-truth residual envelope held, and
-    jones_mode='full' stayed bit-identical to the pre-mode solver."""
-    banks = sentinel.load_jones_banks("cpu", REPO)
-    assert banks, "no committed JONES_rNN.json"
-    rec = banks[-1][2]["13-jones-melt"]
-    for spec in sentinel.JONES_TOLERANCES.values():
-        assert spec["field"] in rec, spec["field"]
-    assert rec["phase_bytes_ratio_xla"] <= rec["phase_gate"]
-    assert rec["phase_bytes_ratio_pallas"] <= rec["phase_gate"]
-    assert rec["diag_bytes_ratio_xla"] < 1.0
-    assert rec["diag_bytes_ratio_pallas"] < 1.0
-    assert rec["residual_envelope_met"] is True
-    assert rec["full_mode_bit_identical"] is True
-    for leg in rec["legs"].values():
-        trips = {m["executed_trips"] for m in leg["modes"].values()}
-        assert len(trips) == 1      # equal executed trips per leg

@@ -161,3 +161,59 @@ def test_fullbatch_shard_baselines(simdir):
     t0 = ds.SimMS(msdir,
                   data_column="CORRECTED_DATA").read_tile(0)
     assert np.abs(t0.x).mean() < 1.0
+
+
+def test_child_uses_the_environments_compile_cache(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, a child (the setup_backend
+    call every entry point makes, in a fresh process)
+    leaves JAX's own reading of it standing; unset, the cache goes to
+    the fixed <checkout>/.jax_cache."""
+    src = ("from sagecal_tpu import utils; import jax; "
+           "print(utils.setup_backend('cpu')); "
+           "print(jax.config.jax_compilation_cache_dir)")
+    root = os.path.join(os.path.dirname(__file__), "..")
+
+    def child(env_dir):
+        env = dict(os.environ)
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        if env_dir:
+            env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+        r = subprocess.run([sys.executable, "-c", src], cwd=root, env=env,
+                           capture_output=True, text=True, timeout=120)
+        assert r.returncode == 0, r.stderr[-2000:]
+        return r.stdout.split()
+
+    assert child(str(tmp_path)) == [str(tmp_path)] * 2
+    got = child(None)
+    assert got[0] == got[1]
+    assert os.path.dirname(got[0]) == os.path.join(
+        os.path.realpath(root), ".jax_cache")
+
+
+def test_coherency_kernel_failure_raises_no_fallback(monkeypatch,
+                                                     tmp_path):
+    """On a tpu platform the Pallas coherency path is CHOSEN, not
+    probed: when the kernel call fails (here: a compiled pallas_call
+    cannot run on the CPU backend) the solve raises; it never carries
+    on with the XLA path."""
+    from sagecal_tpu.config import RunConfig
+    sky_p = tmp_path / "sky.txt"
+    sky_p.write_text("P0A 0 0 0.0 40 0 0.0 1.0 0 0 0 0 0 0 0 0 150e6\n")
+    (tmp_path / "sky.txt.cluster").write_text("0 1 P0A\n")
+    sky = skymodel.read_sky_cluster(str(sky_p), str(sky_p) + ".cluster",
+                                    0.0, 0.7, 150e6)
+    tile = ds.simulate_dataset(rp.sky_to_device(sky, jnp.float32),
+                               n_stations=5, tilesz=2, freqs=[150e6],
+                               ra0=0.0, dec0=0.7)
+    ms = ds.SimMS.create(str(tmp_path / "a.ms"), [tile])
+    cfg = RunConfig(ms=str(tmp_path / "a.ms"), sky_model=str(sky_p),
+                    cluster_file=str(sky_p) + ".cluster", tile_size=2)
+    monkeypatch.setattr(pipeline, "_device_platform", lambda: "tpu")
+    lines = []
+    pipe = pipeline.FullBatchPipeline(cfg, ms, sky, real_dtype=jnp.float32,
+                                      log=lines.append)
+    assert pipe.use_pallas
+    assert "Coherency path: pallas" in lines
+    with pytest.raises(Exception):
+        pipe.run(log=lines.append)
+    assert not any("XLA path" in ln for ln in lines)

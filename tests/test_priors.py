@@ -47,7 +47,7 @@ CLUSTER = """\
 """
 
 #: warm must CONVERGE as well as cold, just in fewer sweeps — the
-#: final-residual ratio envelope the bench (12-warm-start) also gates
+#: final-residual ratio envelope
 RES_ENVELOPE = 0.05
 
 

@@ -10,16 +10,9 @@ The contracts under test (MIGRATION.md "Multi-process fleet"):
   admits on its pin; strict head-of-line fleet-wide;
 - the api.Client persistent-connection request pipelining (N status
   round-trips collapse to one write+read batch, same replies);
-- `bench.stamp_family` exact-match families (the PR 14 stray
-  MESH_r13.json regression): underscores refused, prefix-colliding
-  family names refused, round numbering never cross-reads;
-- the sentinel SCALEOUT family: a doctored bank regressing scaling /
-  recovery re-runs fails the cross-round check with the metric named;
 - jaxlint hot-path scope covers serve/router.py;
 - LIVE (worker subprocesses, spawn-safe, hard timeouts; slow-marked
-  to hold the tier-1 wall — CI's full-suite step runs them, and the
-  same crash/migration recovery legs gate the banked SCALEOUT record
-  at bench time): a worker killed mid-job by the `worker_crash`
+  to hold the tier-1 wall — CI's full-suite step runs them): a worker killed mid-job by the `worker_crash`
   fault point is lease-evicted, its job recovers onto the survivor
   from the durable checkpoint watermark with ZERO completed tiles
   re-run, and the outputs are byte-for-byte identical to an
@@ -495,123 +488,6 @@ def test_client_pipelining_matches_sequential_and_orders():
                 c.status_many(["missing-job"])
     finally:
         srv.stop()
-
-
-# ---------------------------------------------------------------------------
-# bench.stamp_family exact-match (the PR 14 stray-bank regression)
-# ---------------------------------------------------------------------------
-
-def test_stamp_family_exact_match_and_prefix_refusal(tmp_path):
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
-    bank = str(tmp_path)
-    rec = {"value": 1.0, "shape": "x"}
-    p = bench.stamp_family(rec, "cpu", "MESH2D", "cfg", 13,
-                           bank_dir=bank)
-    assert os.path.basename(p) == "MESH2D_r13.json"
-    # numbering is exact-match per family, never cross-read
-    p = bench.stamp_family(rec, "cpu", "MESH2D", "cfg", 13,
-                           bank_dir=bank)
-    assert os.path.basename(p) == "MESH2D_r14.json"
-    # the regression: a family that PREFIXES a banked one is refused
-    with pytest.raises(ValueError, match="prefix-collides"):
-        bench.stamp_family(rec, "cpu", "MESH", "cfg", 13,
-                           bank_dir=bank)
-    # ... and one a banked family prefixes, equally
-    with pytest.raises(ValueError, match="prefix-collides"):
-        bench.stamp_family(rec, "cpu", "MESH2D2", "cfg", 13,
-                           bank_dir=bank)
-    # underscores cannot parse out of <FAMILY>_rNN.json
-    with pytest.raises(ValueError, match="A-Z"):
-        bench.stamp_family(rec, "cpu", "MESH_2D", "cfg", 13,
-                           bank_dir=bank)
-    # non-colliding families coexist
-    p = bench.stamp_family(rec, "cpu", "SCALEOUT", "cfg", 15,
-                           bank_dir=bank)
-    assert os.path.basename(p) == "SCALEOUT_r15.json"
-    # the repo bank itself holds no prefix-colliding families (the
-    # stray MESH_r13.json was folded into MESH2D_r13.json)
-    assert not os.path.exists(os.path.join(REPO, "MESH_r13.json"))
-    import re
-    fams = set()
-    for f in os.listdir(REPO):
-        m = re.fullmatch(r"([A-Z][A-Z0-9]*)_r(\d+)\.json", f)
-        if m:
-            fams.add(m.group(1))
-    for a in fams:
-        for b in fams:
-            assert a == b or not a.startswith(b), (a, b)
-
-
-# ---------------------------------------------------------------------------
-# sentinel SCALEOUT family (doctored-bank negative test)
-# ---------------------------------------------------------------------------
-
-def _scaleout_rec(**kw):
-    rec = dict(shape="8 jobs router", scaling_1to2=1.8,
-               p99_queue_wait_2w_s=2.0, cache_hit_rate_min_2w=1.0,
-               recovery_wall_s=2.5, recovery_tiles_rerun=0)
-    rec.update(kw)
-    return rec
-
-
-def _write_bank(d, fname, cfg, rec):
-    with open(os.path.join(d, fname), "w") as f:
-        json.dump({"platform": "cpu", "results": {cfg: rec}}, f)
-
-
-def test_sentinel_scaleout_cross_round_check(tmp_path):
-    from sagecal_tpu.obs import sentinel
-    bank = str(tmp_path)
-    _write_bank(bank, "SCALEOUT_r15.json", "10-scaleout",
-                _scaleout_rec())
-    # a clean later round: no violations
-    _write_bank(bank, "SCALEOUT_r16.json", "10-scaleout",
-                _scaleout_rec(scaling_1to2=1.75))
-    assert sentinel.scaleout_cross_round_check("cpu", bank) == []
-    # doctored: collapsed scaling + a recovery that re-ran tiles
-    _write_bank(bank, "SCALEOUT_r16.json", "10-scaleout",
-                _scaleout_rec(scaling_1to2=1.0,
-                              recovery_tiles_rerun=3))
-    viol = sentinel.scaleout_cross_round_check("cpu", bank)
-    metrics = {v["metric"] for v in viol}
-    assert "scaleout_scaling" in metrics
-    assert "scaleout_recovery_rerun" in metrics
-    # ... and the CLI lane fails with the metric named (needs any
-    # BENCH bank present so main() has a platform to check)
-    _write_bank(bank, "BENCH_CPU_r01.json", "cfg",
-                {"shape": "x", "step_s": 1.0})
-    rc = sentinel.main(["--fast", "--no-probes", "--platform", "cpu",
-                        "--bank-dir", bank])
-    assert rc == 1
-    # the committed repo bank must be clean for the new family
-    assert sentinel.scaleout_cross_round_check("cpu") == []
-
-
-def test_sentinel_scaleout_committed_bank_loads():
-    """The committed SCALEOUT round parses, declares its platform,
-    carries every toleranced field, and banked the acceptance gates:
-    1->2-worker scaling >= 1.6, a recovery leg with ZERO tiles re-run
-    and a measured cost, per-job bit-identity, and the regime stated
-    (host core count + which legs left the ingest floor)."""
-    from sagecal_tpu.obs import sentinel
-    banks = sentinel.load_scaleout_banks("cpu", REPO)
-    assert banks, "no committed SCALEOUT_rNN.json"
-    rec = banks[-1][2]["10-scaleout"]
-    for spec in sentinel.SCALEOUT_TOLERANCES.values():
-        assert spec["field"] in rec, spec["field"]
-    assert rec["scaling_1to2"] >= 1.6
-    assert rec["recovery_tiles_rerun"] == 0
-    assert rec["recovery_wall_s"] > 0
-    assert rec["migration"]["tiles_rerun"] == 0
-    assert rec["bit_identical"] is True
-    assert rec["recovery"]["bit_identical"] is True
-    assert isinstance(rec["host_cores"], int)
-    assert "legs_over_floor" in rec["ingest"]
-    assert rec["client_pipelining"]["n_ops"] > 0
 
 
 def test_jaxlint_hot_path_covers_router():

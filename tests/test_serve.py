@@ -89,8 +89,7 @@ def _base_config(skyf, clusf, **kw):
     # the auto heuristics LEARN from sweep wall-clock in module-global
     # state, so an auto run can flip the plan at its last sweep and
     # hand the NEXT job one compile of the newly-promoted program —
-    # exactly the nondeterminism a zero-compile gate must exclude (the
-    # bench settles plans before timing for the same reason)
+    # exactly the nondeterminism a zero-compile gate must exclude
     cfg = dict(sky_model=skyf, cluster_file=clusf, solver_mode=0,
                max_em_iter=1, max_iter=4, max_lbfgs=2, tile_size=4,
                solve_fuse="on", solve_promote="off")

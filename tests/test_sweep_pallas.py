@@ -22,7 +22,7 @@ every gate here is an interpret-mode gate:
 - reduced dtype policies (bf16/f16) hold the same per-policy envelopes
   as the XLA reduced path (tests/test_dtype_policy.py ENVELOPE);
 - diag/roofline.pallas_cost prices a compiled pallas_call from its
-  cost_estimate and skips interpret-mode calls (the bench satellite).
+  cost_estimate and skips interpret-mode calls.
 
 Fast subset (everything not slow-marked) joins the CI fail-fast step.
 """
@@ -280,7 +280,7 @@ def test_unsupported_shapes_fall_back_bit_identical():
 def test_sage_threads_kernel_flag():
     """SageConfig.kernel reaches the per-cluster solves: PCG trips are
     counted under inner="cg" for both kernels and the sweep completes
-    (the bench/roofline trip-accounting hook)."""
+    (the tile record's trip count)."""
     from sagecal_tpu.config import SolverMode
     from sagecal_tpu.solvers import sage
     x8, coh, s1, s2, cid, _, nbase = _toy(N=5, T=2, K=1, seed=17,
@@ -332,7 +332,7 @@ def test_roofline_pallas_cost():
     """diag/roofline.pallas_cost: a COMPILED pallas_call is priced from
     its cost_estimate via the jaxpr walk; an interpret-mode call is
     skipped (cost_analysis already prices its HLO lowering) — the
-    silent-drop fix for the bench's per-trip pricing."""
+    silent-drop fix for a priced program."""
     from sagecal_tpu.diag import roofline as rl
     x8, coh, s1, s2, cid, _, nbase = _toy(N=5, T=4, K=1, seed=19)
     x8 = x8.astype(jnp.float32)
@@ -485,8 +485,8 @@ def test_visits_batched_stations_fall_back():
 
 @pytest.mark.slow
 def test_fused_equations_heavy_shape():
-    """Bench-config-1-sized equivalence (N=62, K=2): the heavy-shape
-    gate for the shapes the bench and the north-star ladder run."""
+    """LOFAR-sized equivalence (N=62, K=2): the heavy-shape gate
+    at the station count the benchmark's cells run."""
     x8, coh, s1, s2, cid, _, nbase = _toy(N=62, T=2, K=2, seed=20)
     N, K = 62, 2
     rng = np.random.default_rng(21)
@@ -507,3 +507,15 @@ def test_fused_equations_heavy_shape():
     np.testing.assert_allclose(
         np.asarray(mv), np.asarray(ref),
         atol=1e-8 * (float(jnp.abs(ref).max()) + 1e-30))
+
+
+def test_kernel_pallas_refused_on_tpu_backend(monkeypatch):
+    """The fused sweep's TPU compile never returns, so --kernel pallas
+    is refused before any solve when the backend reports tpu; on CPU
+    (the interpreter path) the same call passes."""
+    swp.check_kernel("pallas")          # cpu: fine
+    monkeypatch.setattr(swp.jax, "default_backend",
+                        lambda: "tpu")
+    swp.check_kernel("xla")
+    with pytest.raises(ValueError, match="does not compile under Mosaic"):
+        swp.check_kernel("pallas")

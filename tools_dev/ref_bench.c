@@ -1,11 +1,11 @@
-/* Reference libdirac CPU baseline for bench config 1.
+/* Reference libdirac CPU timing at BASELINE.json's config 1.
  *
  * Times sagefit_visibilities (src/lib/Dirac/lmfit.c:778) on the same
- * problem shape as bench.py config 1 (N=62 stations, M=8 clusters, one
+ * problem shape as the cell cal-m8x3 (N=62 stations, M=8 clusters, one
  * chunk each, tilesz=10, solver mode SM_OSLM_OSRLM_RLBFGS = 3) with the
  * same iteration budget (max_emiter=3, max_iter=10, max_lbfgs=10, m=7).
  * Coherencies are synthetic (random smooth phases); data = J_true x coh
- * x J_true^H + noise, like the bench's simulate_dataset oracle.
+ * x J_true^H + noise, like io/dataset.simulate_dataset.
  *
  * Build (objects compiled from the read-only reference checkout):
  *   gcc -O3 -c <reference>/src/lib/Dirac/{...}.c && \

@@ -45,7 +45,8 @@ event            meaning / required extra fields
                  carries only ``tile`` and the overlap pair); optional
                  ``mean_nu``, ``solver_iters``, ``cg_iters`` (inner CG
                  trips under them: LM's PCG, RTR's truncated-CG
-                 bodies), ``lbfgs_iters``,
+                 bodies), ``row_passes`` (RTR's evaluations of the
+                 row model: solvers/rtr.py), ``lbfgs_iters``,
                  ``refine_passes`` (passes through the model the joint
                  refine made: solvers/lbfgs.py), ``minutes``,
                  ``primal``, ``rho_mean``, and the

@@ -85,9 +85,10 @@ def _emit_tile_record(ti, res_0, res_1, mean_nu, info, minutes,
         rec["overlap"] = int(overlap or 0)
     # host-driver extras (the sharded solver reports only residuals):
     # the trace schema's two trip fields, the inner CG trips under them
-    # (LM's PCG, RTR's truncated CG), and the joint refine's passes
-    # through the model (lbfgs._lbfgs_loop)
-    for k in ("solver_iters", "cg_iters", "lbfgs_iters", "refine_passes"):
+    # (LM's PCG, RTR's truncated CG) and RTR's passes over the rows, and
+    # the joint refine's passes through the model (lbfgs._lbfgs_loop)
+    for k in ("solver_iters", "cg_iters", "row_passes", "lbfgs_iters",
+              "refine_passes"):
         if k in trips:
             rec[k] = trips[k]
     dtrace.emit("tile", **rec)

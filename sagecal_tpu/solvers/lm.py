@@ -41,8 +41,8 @@ from sagecal_tpu.solvers import normal_eq as ne
 #: executed-iteration counters a solver info dict may carry; the keys
 #: the host-side telemetry (diag tile records, obs trip counters,
 #: benchmarks/' solver_trips) reads through executed_trips()
-TRIP_KEYS = ("solver_iters", "cg_iters", "lbfgs_iters", "refine_passes",
-             "rejected_groups")
+TRIP_KEYS = ("solver_iters", "cg_iters", "row_passes", "lbfgs_iters",
+             "refine_passes", "rejected_groups")
 
 
 def executed_trips(info) -> dict:

@@ -4,8 +4,14 @@ The measurement model per baseline b=(p,q) is V_b = J_p C_b J_q^H with one
 2x2 complex Jones per station. The reference evaluates derivative kernels
 per 8-parameter station blocks (mderiv.cu:30 ``kernel_deriv``; CPU
 ``mylm_jac_single_pth`` lmfit.c); here the same closed forms are assembled
-as batched einsums + scatter-adds into block-sparse normal equations —
-everything maps onto the MXU, no per-parameter loops.
+as batched einsums + scatter-adds into block-sparse normal equations,
+no per-parameter loops. The Gram assemblies (:func:`normal_equations`,
+:func:`gn_factors`) are contractions and run on the matrix unit; the row
+MODEL itself, V and its two Wirtinger factors, is sixteen complex
+multiply-adds a row and is written out as real elementwise arithmetic
+on planes with the rows on the minor axis (:func:`row_model`,
+:class:`RowPlanes`): a 2 x 2 product fed to a 128 x 128 systolic array
+at f32 ``highest`` costs a hundred times its arithmetic.
 
 Derivatives (Wirtinger):
   with A = C_b J_q^H:  dV/d(J_p)_{cd}       = e_c e_d^T A   (complex-linear)
@@ -42,20 +48,131 @@ def jones_r2c(p):
     return (pr[..., 0] + 1j * pr[..., 1]).reshape(p.shape[:-1] + (2, 2))
 
 
+def _planes_mm(a, b, adj_a: bool = False, adj_b: bool = False):
+    """The eight real planes of the 2 x 2 complex product op(a) op(b),
+    op = identity or conjugate transpose, by written-out multiply-adds.
+
+    ``a``, ``b``: eight real planes each ((Re, Im) of 00, 01, 10, 11,
+    the :func:`jones_c2r` order) that broadcast against each other."""
+    def entry(m, i, j, adj):
+        k = 2 * (2 * j + i if adj else 2 * i + j)
+        return m[k], (-m[k + 1] if adj else m[k + 1])
+
+    out = []
+    for i in range(2):
+        for j in range(2):
+            (ar, ai), (br, bi) = entry(a, i, 0, adj_a), entry(b, 0, j, adj_b)
+            (cr, ci), (dr, di) = entry(a, i, 1, adj_a), entry(b, 1, j, adj_b)
+            out += [ar * br - ai * bi + cr * dr - ci * di,
+                    ar * bi + ai * br + cr * di + ci * dr]
+    return jnp.stack(out)
+
+
+def row_model(jp8, jq8, c8):
+    """The row model on real planes: (V, A, Bm), eight planes each, of
+    V = J_p C J_q^H and the Wirtinger factors A = C J_q^H, Bm = J_p C it
+    computes on the way.
+
+    ``jp8``, ``jq8``, ``c8``: the gathered Jones of both stations and the
+    coherency as ``[8, *rows]`` real planes (:func:`jones_c2r` order, the
+    ROWS ON THE MINOR AXES; they may broadcast against each other).
+    Real elementwise arithmetic only: nothing here is a contraction, so
+    nothing reaches the matrix unit and the planes tile without the 64x
+    padding of a ``[B, 2, 2]`` array."""
+    a8 = _planes_mm(c8, jq8, adj_b=True)
+    return _planes_mm(jp8, a8), a8, _planes_mm(jp8, c8)
+
+
+def row_grad(g8, a8, bm8):
+    """(G A^H, G^H Bm) on planes: with G the complex form of a row's
+    cost derivative dc/dV, the row's share of dc/dJ_p and of dc/dJ_q
+    (dV = dJ_p A + Bm dJ_q^H, Re tr(G^H dV) = Re tr((G A^H)^H dJ_p)
+    + Re tr((G^H Bm)^H dJ_q))."""
+    return _planes_mm(g8, a8, adj_b=True), _planes_mm(g8, bm8, adj_a=True)
+
+
+def _take_planes(P, idx):
+    """Station planes P [K, N, 8], flat station indices -> [8, len(idx)]."""
+    return jnp.take(P.reshape(-1, 8).T, idx, axis=1)
+
+
+class RowPlanes:
+    """One cluster's row data as real planes, the rows on the minor axes,
+    with the gather from and the segment sum to the stations.
+
+    ``x``, ``w``: data and sqrt-weights ``[8, *rows]`` in their (storage)
+    dtype; ``c``: the coherency planes. With one chunk and a
+    ``row_period`` (rows laid out ``[tilesz, nbase]``, stations
+    repeating every ``nbase``: the invariant of
+    :func:`normal_equations`) ``rows`` is ``(tilesz, nbase)``: the
+    Jones are gathered for ``nbase`` rows and broadcast over time, and a
+    per-row gradient is summed over time before ``nbase`` rows are
+    scattered; otherwise ``rows`` is ``(B,)``."""
+
+    def __init__(self, x8, coh, wt, sta1, sta2, chunk_id, kmax: int,
+                 n_stations: int, row_period: int = 0):
+        B = x8.shape[0]
+        self.kmax, self.n_stations, self.chunk_id = kmax, n_stations, chunk_id
+        self.periodic = kmax == 1 and row_period > 0 and B % row_period == 0
+        R = row_period if self.periodic else B
+        self.rows = (B // R, R) if self.periodic else (B,)
+        self.i1 = (chunk_id * n_stations + sta1)[:R]
+        self.i2 = (chunk_id * n_stations + sta2)[:R]
+        self.x, self.w = self.planes(x8), self.planes(wt)
+        self.c = self.planes(jones_c2r(coh))
+
+    def planes(self, a):
+        """[B, 8] -> [8, *rows]."""
+        return jnp.moveaxis(a, -1, 0).reshape((8,) + self.rows)
+
+    def to_rows(self, a):
+        """[8, *rows] -> [B, 8]."""
+        return jnp.moveaxis(a.reshape(8, -1), 0, -1)
+
+    def gather(self, P):
+        """Station planes P [K, N, 8] -> (jp8, jq8) for :func:`row_model`."""
+        jp, jq = _take_planes(P, self.i1), _take_planes(P, self.i2)
+        return (jp[:, None], jq[:, None]) if self.periodic else (jp, jq)
+
+    def time_sum(self, a):
+        """The part of :meth:`station_sum` that is elementwise with the
+        rows: [8, *rows] -> [8, R]."""
+        return jnp.sum(a, axis=1) if self.periodic else a
+
+    def station_sum(self, gp, gq):
+        """Per-row shares [8, R] of the first and of the second station
+        (after :meth:`time_sum`) -> [K, N, 8]."""
+        out = jnp.zeros((self.kmax * self.n_stations, 8), gp.dtype)
+        out = out.at[self.i1].add(gp.T).at[self.i2].add(gq.T)
+        return out.reshape(self.kmax, self.n_stations, 8)
+
+    def chunk_sum(self, a):
+        """[8, *rows] -> per-chunk sums [K]."""
+        if self.kmax == 1:
+            return jnp.sum(a).reshape(1)
+        return jax.ops.segment_sum(jnp.sum(a, axis=0), self.chunk_id,
+                                   num_segments=self.kmax)
+
+    def select(self, take, new, old):
+        """Rows of the chunks where ``take`` [K] holds from ``new``, the
+        others from ``old`` (both [8, *rows])."""
+        return jnp.where(take[0] if self.kmax == 1
+                         else take[self.chunk_id], new, old)
+
+
 def residual8(x8, J, coh, sta1, sta2, chunk_id):
     """Real residual r = x - vec(J_p C J_q^H): [B, 8].
 
     x8: [B, 8]; J: [K, N, 2, 2] complex; coh: [B, 2, 2]; chunk_id: [B].
     """
-    Jp = J[chunk_id, sta1]
-    Jq = J[chunk_id, sta2]
-    V = Jp @ coh @ jnp.conj(jnp.swapaxes(Jq, -1, -2))
-    vflat = V.reshape(-1, 4)
-    v8 = jnp.stack([vflat.real, vflat.imag], axis=-1).reshape(-1, 8)
+    P, N = jones_c2r(J), J.shape[-3]
+    v8, _, _ = row_model(_take_planes(P, chunk_id * N + sta1),
+                         _take_planes(P, chunk_id * N + sta2),
+                         jones_c2r(coh).T)
     # dtype-policy storage/accumulate contract: the model EMITS the
     # data's storage dtype (a no-op for f32/f64 data), so the residual
     # stream stays storage-sized; reductions over it upcast (dtp.acc)
-    return x8 - dtp.to_storage(v8, x8.dtype)
+    return x8 - dtp.to_storage(v8.T, x8.dtype)
 
 
 def _real_jac(D, conj_param: bool):

@@ -19,12 +19,18 @@ TPU re-architecture vs. the reference:
   vector is [K, 8N] real with per-chunk scalars (costs, radii, tCG
   coefficients) as [K] arrays — one batched computation instead of a
   sequential chunk loop;
-- the euclidean gradient comes from autodiff of the (weighted, optionally
-  ADMM-augmented) objective; tCG Hessian-vector products use an analytic
-  Gauss-Newton normal matrix assembled once per outer TR point from the
-  Wirtinger block Jacobians (normal_eq.py) — one batched MXU matvec per
-  product instead of re-traversing the residual graph (the autodiff
-  analogue of the reference's hand-derived fns_fhess);
+- the row model V = J_p C J_q^H is real elementwise arithmetic on planes
+  with the rows on the minor axis (normal_eq.row_model) and is evaluated
+  ONCE per point: a trial point's pass yields its cost and, if the point
+  is accepted, the euclidean gradient (written out from the same pass's
+  Wirtinger factors: -(G A^H) and -(G^H Bm) summed to the stations) and
+  the residual the next iteration's curvature weights and the robust
+  E-step read; the passes executed are counted (info["row_passes"]);
+- tCG Hessian-vector products use an analytic Gauss-Newton normal matrix
+  assembled once per outer TR point from the Wirtinger factors
+  (normal_eq.py) — one batched matrix-unit matvec per product instead of
+  re-traversing the residual graph (the analogue of the reference's
+  hand-derived fns_fhess);
 - per-station gradient normalization by baseline counts (rtr_solve.c
   fns_fcount / iw weights, Dirac.h:1114) is kept as a diagonal
   preconditioner on the euclidean differentials;
@@ -239,6 +245,70 @@ def make_cost(x8, coh, sta1, sta2, chunk_id, wt, kmax, n_stations,
     return cost
 
 
+def make_row_pass(rows: ne.RowPlanes, kmax, n_stations, admm=None,
+                  robust_nu=None, mode: str = "full", Jref=None):
+    """:func:`make_cost`'s cost with its gradient written out, on row data
+    in plane form, so that ONE evaluation of the row model at a point
+    serves both. Returns (row_pass, egrad):
+
+    ``row_pass(p)`` -> (cost [K], e, shares): ``e`` the weighted residual
+    planes at ``p`` (storage dtype), ``shares`` the rows' shares of the
+    cost's gradient with respect to the Jones of their first and second
+    station, elementwise on the planes the pass holds (with G the complex
+    form of wt * f'(e), f' = 2e Gaussian, 2e/(nu + e^2) robust:
+    dc/dJ_p = -(G A^H), dc/dJ_q = -(G^H Bm)) and already summed over
+    time where the rows have a period;
+
+    ``egrad(p, shares)`` -> the Euclidean gradient [K, D] at ``p``: the
+    segment sum of the shares to the stations, the transpose of the
+    station-sized p -> J map for the constrained modes, the ADMM term
+    2 y + 2 rho (p - bz)."""
+    p_to_J = _mode_p2j(mode, Jref, kmax, n_stations)
+    if admm is not None:
+        admm_y, admm_bz, admm_rho = admm
+        admm_y = admm_y.reshape(kmax, -1)
+        admm_bz = admm_bz.reshape(kmax, -1)
+
+    def station_planes(p):
+        """p [K, D] -> the stations' Jones as real planes [K, N, 8]."""
+        if mode == "full":
+            return p.reshape(kmax, n_stations, 8)
+        return ne.jones_c2r(p_to_J(p))
+
+    def row_pass(p):
+        jp, jq = rows.gather(station_planes(p))
+        v, a, bm = ne.row_model(jp, jq, rows.c)
+        # the residual stream stays in the data's storage dtype; the
+        # norm/robust reductions and the gradient upcast
+        e = (rows.x - dtp.to_storage(v, rows.x.dtype)) * rows.w
+        ea = dtp.acc(e)
+        if robust_nu is None:
+            per, fp = ea * ea, 2.0 * ea
+        else:
+            per = jnp.log1p(ea * ea / robust_nu)
+            fp = 2.0 * ea / (robust_nu + ea * ea)
+        ck = rows.chunk_sum(per)
+        if admm is not None:
+            d = p - admm_bz
+            ck = ck + 2.0 * jnp.sum(admm_y * d, axis=-1) \
+                + admm_rho * jnp.sum(d * d, axis=-1)
+        gp, gq = ne.row_grad(dtp.acc(rows.w) * fp, a, bm)
+        return ck, e, (rows.time_sum(gp), rows.time_sum(gq))
+
+    def egrad(p, shares):
+        gJ = -rows.station_sum(*shares)
+        if mode == "full":
+            eg = gJ.reshape(kmax, -1)
+        else:
+            eg, = jax.vjp(station_planes, p)[1](gJ)
+        if admm is not None:
+            eg = eg + 2.0 * admm_y \
+                + 2.0 * jnp.asarray(admm_rho)[..., None] * (p - admm_bz)
+        return eg
+
+    return row_pass, egrad
+
+
 class _TCGState(NamedTuple):
     eta: jax.Array      # [K, D] current inner solution
     r: jax.Array        # [K, D] residual
@@ -309,30 +379,22 @@ class _RTRState(NamedTuple):
     p: jax.Array
     g: jax.Array        # Riemannian gradient at p (computed once per point)
     cost: jax.Array
+    e: jax.Array        # weighted residual planes at p (storage dtype)
     delta: jax.Array
     stop: jax.Array
     k: jax.Array
     cg: jax.Array       # i32 tCG bodies executed so far
 
 
-def rtr_solve(x8, coh, sta1, sta2, chunk_id, wt, J0, n_stations: int,
-              chunk_mask=None, config: RTRConfig = RTRConfig(),
-              itmax_dynamic=None, admm=None, robust_nu=None,
-              row_period: int = 0):
-    """Trust-region solve of all chunks of one cluster (rtr_solve.c:1208).
-
-    Same call convention as lm.lm_solve; ``robust_nu`` switches the
-    objective to fixed-nu Student's t (the robust wrapper re-estimates nu
-    between calls). Returns (J [K,N,2,2], info); ``info["cg_iters"]`` is
-    the tCG bodies executed, summed over the outer iterations.
-    """
+def _rtr_rows(rows: ne.RowPlanes, x8, coh, sta1, sta2, chunk_id, wt, J0,
+              n_stations: int, chunk_mask, config: RTRConfig,
+              itmax_dynamic, admm, robust_nu, row_period: int):
+    """:func:`rtr_solve` on row data already in plane form (``rows`` holds
+    the storage-quantized ``x8`` and ``wt``, which ``make_hess`` hands to
+    the assembly as they are). Returns (J, info, e): ``e`` the weighted
+    residual planes at the returned J, which the one row pass that
+    reached it left behind."""
     kmax = J0.shape[0]
-    # dtype policy: storage-quantize the data at entry (identity under
-    # "f32"); manifold point/tangents/costs live in the accumulator
-    # dtype (see lm.lm_solve)
-    stq = dtp.storage_dtype(config.dtype_policy, x8.dtype)
-    x8 = dtp.to_storage(x8, stq)
-    wt = dtp.to_storage(wt, stq)
     dtype = dtp.acc_dtype(x8.dtype)
     mode = config.jones_mode
     npar = ne.jones_npar(mode)
@@ -351,12 +413,19 @@ def rtr_solve(x8, coh, sta1, sta2, chunk_id, wt, J0, n_stations: int,
     p_to_J = _mode_p2j(mode, Jref, kmax, n_stations)
     if chunk_mask is None:
         chunk_mask = jnp.ones((kmax,), bool)
+    row_pass, egrad = make_row_pass(rows, kmax, n_stations, admm=admm,
+                                    robust_nu=robust_nu, mode=mode,
+                                    Jref=Jref)
 
-    cost_fn = make_cost(x8, coh, sta1, sta2, chunk_id, wt, kmax,
-                        n_stations, admm=admm, robust_nu=robust_nu,
-                        mode=mode, Jref=Jref)
-    total = lambda p: jnp.sum(cost_fn(p))
-    egrad_fn = jax.grad(total)
+    # NOTE: the reference's per-station iw scaling (fns_fcount) is a
+    # diagonal preconditioner; applied one-sidedly it would destroy the
+    # symmetry tCG requires, so the TR path uses the exact (projected)
+    # gradient/Hessian pair instead — station balance enters through the
+    # row weights ``wt``.
+    def rgrad(p, shares):
+        return project_tangent_mode(p, egrad(p, shares), kmax, n_stations,
+                                    mode)
+
     # kernel="pallas": fused-sweep assembly + blocks tCG products when
     # the shape supports it (see RTRConfig.kernel); XLA otherwise
     swp = None
@@ -365,46 +434,39 @@ def rtr_solve(x8, coh, sta1, sta2, chunk_id, wt, J0, n_stations: int,
         if swp_mod.supported(kmax, row_period, x8.shape[0]):
             swp = swp_mod
 
-    # NOTE: the reference's per-station iw scaling (fns_fcount) is a
-    # diagonal preconditioner; applied one-sidedly it would destroy the
-    # symmetry tCG requires, so the TR path uses the exact (projected)
-    # gradient/Hessian pair instead — station balance enters through the
-    # row weights ``wt``.
-    def rgrad_at(p):
-        return project_tangent_mode(p, egrad_fn(p), kmax, n_stations,
-                                    mode)
-
     admm_rho2 = None if admm is None else 2.0 * admm[2]
 
-    def make_hess(p):
-        """Gauss-Newton Hessian operator at the outer TR point ``p``.
+    def make_hess(p, e):
+        """Gauss-Newton Hessian operator at the outer TR point ``p``,
+        ``e`` the weighted residual planes the point's row pass left.
 
         The reference evaluates a cheap hand-derived Hessian inside tCG
         (rtr_solve.c:886-1155); the autodiff analogue (forward-over-
         reverse through the gradient) re-traverses the whole residual
-        graph for EVERY tCG product and dominated robust-RTR wall clock.
-        Here the block-sparse Gauss-Newton normal matrix is assembled
-        ONCE per outer iteration from the analytic Wirtinger Jacobians
-        (normal_eq.baseline_jacobians) and each tCG product is a single
-        batched [K,8N,8N]@[K,8N] matvec on the MXU.
+        graph for EVERY tCG product. Here the block-sparse Gauss-Newton
+        normal matrix is assembled ONCE per outer iteration from the
+        analytic Wirtinger factors (normal_eq.normal_equations, which
+        makes its own pass over the rows) and each tCG product is a
+        single batched [K,8N,8N]@[K,8N] matvec on the matrix unit.
 
         Curvature model per residual element e (e already includes wt):
           gaussian  sum e^2:          f'' = 2          -> weights wt
           robust    sum log1p(e^2/nu): f''(e) = 2(nu - e^2)/(nu + e^2)^2,
             approximated by its PSD surrogate 2*nu/(nu + e^2)^2, folded
-            in as sqrt-curvature row weights wt*sqrt(nu)/(nu + e^2).
+            in as sqrt-curvature row weights wt*sqrt(nu)/(nu + e^2),
+            read from the carried ``e``: no pass of their own.
         The ADMM augmentation contributes its exact Hessian 2*rho*I.
         """
         Jm = p_to_J(p)
         if robust_nu is None:
             wt_eff = wt
         else:
-            e = ne.residual8(x8, Jm, coh, sta1, sta2, chunk_id) * wt
             # keep the curvature weights in the storage dtype so the
             # GN assembly below stays on the reduced path (identity
             # for f32/f64)
-            wt_eff = dtp.to_storage(
-                wt * jnp.sqrt(robust_nu) / (robust_nu + e * e), wt.dtype)
+            wt_eff = dtp.to_storage(rows.to_rows(
+                rows.w * jnp.sqrt(robust_nu) / (robust_nu + e * e)),
+                wt.dtype)
         if config.inner == "cg":
             if swp is not None:
                 # blocks operator: the fused sweep contracts the time
@@ -470,7 +532,7 @@ def rtr_solve(x8, coh, sta1, sta2, chunk_id, wt, J0, n_stations: int,
             return project_tangent_mode(p, Hv, kmax, n_stations, mode)
         return hv
 
-    cost0 = cost_fn(p0)
+    cost0, e0, shares0 = row_pass(p0)
     xnorm0 = jnp.sqrt(_dot(p0, p0))
     if mode == "phase":
         # phase parameters start at theta = 0, so ||p0|| cannot seed
@@ -479,7 +541,7 @@ def rtr_solve(x8, coh, sta1, sta2, chunk_id, wt, J0, n_stations: int,
                              jnp.sqrt(jnp.asarray(float(D), dtype)))
     delta_bar = config.delta_bar_frac * xnorm0
     delta0 = config.delta0_frac * xnorm0
-    g0 = rgrad_at(p0)
+    g0 = rgrad(p0, shares0)
     g0n = jnp.sqrt(_dot(g0, g0))
 
     itmax = (jnp.minimum(jnp.asarray(itmax_dynamic, jnp.int32), config.itmax)
@@ -489,10 +551,12 @@ def rtr_solve(x8, coh, sta1, sta2, chunk_id, wt, J0, n_stations: int,
         return (s.k < itmax) & jnp.any(~s.stop & chunk_mask)
 
     def body(s: _RTRState):
-        hess = make_hess(s.p)
+        hess = make_hess(s.p, s.e)
         eta, md, trips = _tcg(hess, s.g, s.delta, config)
         p_new = s.p + eta
-        c_new = cost_fn(p_new)
+        # the iteration's ONE row pass: the trial point's cost now, its
+        # residual and gradient if the point is accepted
+        c_new, e_new, shares = row_pass(p_new)
         rho = (s.cost - c_new + config.rho_regularize) \
             / (md + config.rho_regularize)
         good = (md > 0) & jnp.all(jnp.isfinite(p_new), axis=-1)
@@ -505,8 +569,12 @@ def rtr_solve(x8, coh, sta1, sta2, chunk_id, wt, J0, n_stations: int,
                                                       delta_bar), s.delta))
         p = jnp.where(accept[:, None], p_new, s.p)
         cost = jnp.where(accept, c_new, s.cost)
-        g_next = jax.lax.cond(jnp.any(accept), lambda: rgrad_at(p),
-                              lambda: s.g)
+        # chunks are independent: an accepted chunk's gradient is the
+        # trial point's, a rejected one keeps its own
+        g_next = jax.lax.cond(
+            jnp.any(accept),
+            lambda: jnp.where(accept[:, None], rgrad(p_new, shares), s.g),
+            lambda: s.g)
         gn = jnp.sqrt(_dot(g_next, g_next))
         # budget exhaustion joins the stop mask (vmap-exactness: see
         # lm.py body note — a finished tile must freeze while other
@@ -514,10 +582,11 @@ def rtr_solve(x8, coh, sta1, sta2, chunk_id, wt, J0, n_stations: int,
         stop = s.stop | (gn <= config.eps_grad * jnp.maximum(g0n, 1e-30)) \
             | (delta <= 1e-12 * jnp.maximum(xnorm0, 1e-30)) \
             | (s.k + 1 >= itmax)
-        return _RTRState(p=p, g=g_next, cost=cost, delta=delta, stop=stop,
-                         k=s.k + 1, cg=s.cg + trips)
+        return _RTRState(p=p, g=g_next, cost=cost,
+                         e=rows.select(accept, e_new, s.e), delta=delta,
+                         stop=stop, k=s.k + 1, cg=s.cg + trips)
 
-    init = _RTRState(p=p0, g=g0, cost=cost0, delta=delta0,
+    init = _RTRState(p=p0, g=g0, cost=cost0, e=e0, delta=delta0,
                      stop=jnp.zeros((kmax,), bool),
                      k=jnp.zeros((), jnp.int32),
                      cg=jnp.zeros((), jnp.int32))
@@ -525,8 +594,48 @@ def rtr_solve(x8, coh, sta1, sta2, chunk_id, wt, J0, n_stations: int,
     J = p_to_J(final.p)
     J = jnp.where(chunk_mask[:, None, None, None], J,
                   J0 if mode == "full" else Jref)
+    # one row pass at p0 and one per trial point
     return J, {"init_cost": cost0, "final_cost": final.cost,
-               "iters": final.k, "cg_iters": final.cg}
+               "iters": final.k, "cg_iters": final.cg,
+               "row_passes": final.k + 1}, final.e
+
+
+def _storage_rows(x8, coh, sta1, sta2, chunk_id, wt, J0, n_stations,
+                  config: RTRConfig, row_period: int):
+    """dtype policy: storage-quantize the data at entry (identity under
+    "f32"; manifold point/tangents/costs live in the accumulator dtype,
+    see lm.lm_solve) and bring the row data to plane form, ONCE for all
+    the evaluations of a solve. Returns (x8, wt, rows)."""
+    stq = dtp.storage_dtype(config.dtype_policy, x8.dtype)
+    x8 = dtp.to_storage(x8, stq)
+    wt = dtp.to_storage(wt, stq)
+    return x8, wt, ne.RowPlanes(x8, coh, wt, sta1, sta2, chunk_id,
+                                J0.shape[0], n_stations, row_period)
+
+
+def rtr_solve(x8, coh, sta1, sta2, chunk_id, wt, J0, n_stations: int,
+              chunk_mask=None, config: RTRConfig = RTRConfig(),
+              itmax_dynamic=None, admm=None, robust_nu=None,
+              row_period: int = 0):
+    """Trust-region solve of all chunks of one cluster (rtr_solve.c:1208).
+
+    Same call convention as lm.lm_solve; ``robust_nu`` switches the
+    objective to fixed-nu Student's t (the robust wrapper re-estimates nu
+    between calls). The row model is evaluated ONCE per point: at the
+    start, and at each trial point, whose pass yields the cost, and on
+    acceptance the gradient and the residual the next iteration's
+    curvature weights read. Returns (J [K,N,2,2], info);
+    ``info["cg_iters"]`` is the tCG bodies executed, summed over the
+    outer iterations, ``info["row_passes"]`` the evaluations of the row
+    model (1 + iters; the assembly's own not counted),
+    ``info["residual"]`` [B, 8] the weighted residual at the returned J.
+    """
+    x8, wt, rows = _storage_rows(x8, coh, sta1, sta2, chunk_id, wt, J0,
+                                 n_stations, config, row_period)
+    J, info, e = _rtr_rows(rows, x8, coh, sta1, sta2, chunk_id, wt, J0,
+                           n_stations, chunk_mask, config, itmax_dynamic,
+                           admm, robust_nu, row_period)
+    return J, {**info, "residual": rows.to_rows(e)}
 
 
 def rtr_solve_robust(x8, coh, sta1, sta2, chunk_id, wt_base, J0,
@@ -537,17 +646,20 @@ def rtr_solve_robust(x8, coh, sta1, sta2, chunk_id, wt_base, J0,
     """Student's-t robust RTR (rtr_solve_nocuda_robust,
     rtr_solve_robust.c:1441; ADMM variant rtr_solve_robust_admm.c:1425):
     IRLS rounds of {fixed-nu robust RTR -> weight E-step -> nu grid update}.
+    The row data go to plane form once for all rounds, and a round's
+    E-step reads the residual its solve ended on.
 
     Returns (J, nu, info)."""
-    mask = wt_base > 0
+    x8, wt_base, rows = _storage_rows(x8, coh, sta1, sta2, chunk_id,
+                                      wt_base, J0, n_stations, config,
+                                      row_period)
+    mask = rows.w > 0
 
     def round_body(carry, _):
         J, nu = carry
-        Jn, info = rtr_solve(x8, coh, sta1, sta2, chunk_id, wt_base, J,
-                             n_stations, chunk_mask, config,
-                             itmax_dynamic=itmax_dynamic, admm=admm,
-                             robust_nu=nu, row_period=row_period)
-        e = ne.residual8(x8, Jn, coh, sta1, sta2, chunk_id) * wt_base
+        Jn, info, e = _rtr_rows(rows, x8, coh, sta1, sta2, chunk_id,
+                                wt_base, J, n_stations, chunk_mask, config,
+                                itmax_dynamic, admm, nu, row_period)
         w = rb.update_weights(e, nu)
         # AECM nu update with p=2, matching the robust-RTR family
         # (rtr_solve_robust.c:374, rtr_solve_robust_admm.c:394 call
@@ -555,16 +667,19 @@ def rtr_solve_robust(x8, coh, sta1, sta2, chunk_id, wt_base, J0,
         nu_new = rb.update_nu_aecm(rb.mean_logsumw(w, mask), nu, p=2,
                                    nulow=nulow, nuhigh=nuhigh)
         return (Jn, nu_new), (info["init_cost"], info["final_cost"],
-                              info["iters"], info["cg_iters"])
+                              info["iters"], info["cg_iters"],
+                              info["row_passes"])
 
     (J, nu), costs = jax.lax.scan(
         round_body, (J0, jnp.asarray(nu0, dtp.acc_dtype(x8.dtype))), None,
         length=wt_rounds)
     # "iters": executed outer TR iterations summed over IRLS rounds
-    # (the tile record's solver_iters); "cg_iters": their tCG bodies
+    # (the tile record's solver_iters); "cg_iters": their tCG bodies;
+    # "row_passes": their evaluations of the row model (rounds + iters)
     info = {"init_cost": costs[0][0], "final_cost": costs[1][-1],
             "iters": jnp.sum(costs[2]).astype(jnp.int32),
-            "cg_iters": jnp.sum(costs[3]).astype(jnp.int32)}
+            "cg_iters": jnp.sum(costs[3]).astype(jnp.int32),
+            "row_passes": jnp.sum(costs[4]).astype(jnp.int32)}
     return J, nu, info
 
 

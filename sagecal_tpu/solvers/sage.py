@@ -98,6 +98,9 @@ def _call(name, jfn, *args, **kwargs):
 
 _LOG = logging.getLogger(__name__)
 
+#: length of a sweep's i32 counter vector ``tk`` (:func:`_cluster_update`)
+N_TK = 4
+
 
 def _learned(kind: str, key, verdict) -> None:
     """Execution-plan verdicts are logged per shape so perf runs can be
@@ -296,7 +299,9 @@ def _cluster_solve(mode: int, xdummy, coh_m, sta1, sta2, cidx_m, cmask_m,
     cg_iters i32 scalar — executed inner CG trips: LM's PCG trips under
     inner="cg" (0 on its chol path), RTR's truncated-CG bodies (its loop
     ends when every chunk has stopped, rtr._tcg), 0 for NSD, which has
-    no inner CG; both for the telemetry's trip accounting).
+    no inner CG — and row_passes i32 scalar — evaluations of the row
+    model RTR's solve executed (rtr.rtr_solve; 0 for the solvers that do
+    not count theirs); all for the telemetry's trip accounting).
     """
     lm_cfg = lm_mod.LMConfig(itmax=itcap, inner=config.inner,
                              cg_tol=config.cg_tol,
@@ -313,7 +318,7 @@ def _cluster_solve(mode: int, xdummy, coh_m, sta1, sta2, cidx_m, cmask_m,
             chunk_mask=cmask_m, config=lm_cfg, itmax_dynamic=itermax,
             admm=admm_m, os=os, row_period=nbase)
         return (Jn, nu_cj, info["init_cost"], info["final_cost"],
-                info["iters"], info["cg_iters"])
+                info["iters"], info["cg_iters"], zero_i)
 
     def robust_lm(os=None):
         Jn, nu_new, info = rb.robust_lm_solve(
@@ -323,7 +328,7 @@ def _cluster_solve(mode: int, xdummy, coh_m, sta1, sta2, cidx_m, cmask_m,
             itmax_dynamic=itermax, admm=admm_m, os=os,       # robustlm.c:103
             row_period=nbase)
         return (Jn, nu_new, info["init_cost"], info["final_cost"],
-                info["iters"], info["cg_iters"])
+                info["iters"], info["cg_iters"], zero_i)
 
     if mode == int(SolverMode.RTR_OSLM_LBFGS):
         rtr_cfg = rtr_mod.RTRConfig(itmax=itcap, inner=config.inner,
@@ -335,7 +340,7 @@ def _cluster_solve(mode: int, xdummy, coh_m, sta1, sta2, cidx_m, cmask_m,
             chunk_mask=cmask_m, config=rtr_cfg, itmax_dynamic=itermax,
             admm=admm_m, row_period=nbase)
         return (Jn, nu_cj, info["init_cost"], info["final_cost"],
-                info["iters"], info["cg_iters"])
+                info["iters"], info["cg_iters"], info["row_passes"])
 
     if mode == int(SolverMode.RTR_OSRLM_RLBFGS):
         rtr_cfg = rtr_mod.RTRConfig(itmax=itcap, inner=config.inner,
@@ -351,7 +356,7 @@ def _cluster_solve(mode: int, xdummy, coh_m, sta1, sta2, cidx_m, cmask_m,
             chunk_mask=cmask_m, config=rtr_cfg, wt_rounds=2,
             itmax_dynamic=itermax, admm=admm_m, row_period=nbase)
         return (Jn, nu_new, info["init_cost"], info["final_cost"],
-                info["iters"], info["cg_iters"])
+                info["iters"], info["cg_iters"], info["row_passes"])
 
     if mode == int(SolverMode.NSD_RLBFGS):
         nsd_cfg = rtr_mod.NSDConfig(itmax=2 * itcap,
@@ -362,7 +367,7 @@ def _cluster_solve(mode: int, xdummy, coh_m, sta1, sta2, cidx_m, cmask_m,
             chunk_mask=cmask_m, config=nsd_cfg, itmax_dynamic=2 * itermax,
             admm=admm_m)
         return (Jn, nu_new, info["init_cost"], info["final_cost"],
-                info["iters"], zero_i)
+                info["iters"], zero_i, zero_i)
 
     if mode == int(SolverMode.LM_LBFGS) or os_cfg is None:
         # without OS machinery, the OS modes (0/3) degrade to
@@ -391,7 +396,7 @@ def _visit_solve(cj, xdummy, coh_m, cidx_m, cmask_m, J_m, nu_cj,
     """The solve half of one cluster visit (shared by the plain and the
     residual-fused sweeps): per-cluster gathers already done, ``xdummy``
     = residual + this cluster's model. Returns (Jn, nu_new, dcost,
-    its, cgs)."""
+    its, cgs, rps)."""
     mode = int(config.solver_mode)
     itermax = jnp.where(
         weighted,
@@ -412,7 +417,7 @@ def _visit_solve(cj, xdummy, coh_m, cidx_m, cmask_m, J_m, nu_cj,
             key=jax.random.fold_in(key, cj), randomize=config.randomize)
 
     itcap = int(config.max_iter) + iter_bar  # static while-loop cap
-    Jn, nu_new, init_cost, final_cost, its, cgs = _cluster_solve(
+    Jn, nu_new, init_cost, final_cost, its, cgs, rps = _cluster_solve(
         mode, xdummy, coh_m, sta1, sta2, cidx_m, cmask_m, wt_base, J_m,
         n_stations, nu_cj, config, itermax, itcap, admm_m,
         os_cfg, last)
@@ -421,7 +426,7 @@ def _visit_solve(cj, xdummy, coh_m, cidx_m, cmask_m, J_m, nu_cj,
     dcost = jnp.where(init_res > 0,
                       jnp.maximum((init_res - final_res) / init_res, 0.0),
                       0.0)
-    return Jn, nu_new, dcost, its, cgs
+    return Jn, nu_new, dcost, its, cgs, rps
 
 
 def _cluster_update(cj, state, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
@@ -430,11 +435,12 @@ def _cluster_update(cj, state, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
                     total_iter: int, iter_bar: int):
     """Visit one cluster: add model back to residual, solve, re-subtract
     (lmfit.c:890-981). ``state`` = (J, xres, nerr_acc, nuM, tk) with
-    ``tk`` an i32[3] counter triple: [0] executed inner-solver
+    ``tk`` an i32[N_TK] counter vector: [0] executed inner-solver
     iterations (the tile record's solver_iters), [1] rejected group steps
     (always 0 here — only :func:`_group_update` can reject), [2]
     executed inner CG trips (LM's PCG under SageConfig.inner="cg", RTR's
-    truncated-CG bodies; :func:`_cluster_solve`)."""
+    truncated-CG bodies), [3] RTR's row passes
+    (:func:`_cluster_solve`)."""
     J, xres, nerr_acc, nuM, tk = state
     coh_m = jnp.take(coh, cj, axis=0)
     cidx_m = jnp.take(chunk_idx, cj, axis=0)
@@ -448,7 +454,7 @@ def _cluster_update(cj, state, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
         xdummy = xres + _model8(J_m, coh_m, sta1, sta2, cidx_m,
                                 out_dtype=xres.dtype)
     with jax.named_scope("inner"):
-        Jn, nu_new, dcost, its, cgs = _visit_solve(
+        Jn, nu_new, dcost, its, cgs, rps = _visit_solve(
             cj, xdummy, coh_m, cidx_m, cmask_m, J_m, jnp.take(nuM, cj),
             sta1, sta2, wt_base, n_stations, config, nerr_prev, weighted,
             last, key, admm, os_id, total_iter, iter_bar)
@@ -458,7 +464,8 @@ def _cluster_update(cj, state, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
         xres = xdummy - _model8(Jn, coh_m, sta1, sta2, cidx_m,
                                 out_dtype=xres.dtype)
         J = J.at[cj].set(Jn)
-    return J, xres, nerr_acc, nuM, tk.at[0].add(its).at[2].add(cgs)
+    tk = tk.at[0].add(its).at[2].add(cgs).at[3].add(rps)
+    return J, xres, nerr_acc, nuM, tk
 
 
 def _sweep_g1(perm, state, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
@@ -508,7 +515,7 @@ def _sweep_g1(perm, state, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
         coh_m, cidx_m, cmask_m = gather(cj)
         J_m = jnp.take(J, cj, axis=0)
         with jax.named_scope("inner"):
-            Jn, nu_new, dcost, its, cgs = _visit_solve(
+            Jn, nu_new, dcost, its, cgs, rps = _visit_solve(
                 cj, xd, coh_m, cidx_m, cmask_m, J_m, jnp.take(nuM, cj),
                 sta1, sta2, wt_base, n_stations, config, nerr_prev,
                 weighted, last, key, admm, os_id, total_iter, iter_bar)
@@ -526,7 +533,8 @@ def _sweep_g1(perm, state, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
             model_new = _model8(Jn, coh_m, sta1, sta2, cidx_m,
                                 out_dtype=xd.dtype)
             xd = (xd - model_new) + jnp.where(j + 1 < M, model_next, 0.0)
-        return J, xd, nerr_acc, nuM, tk.at[0].add(its).at[2].add(cgs)
+        return (J, xd, nerr_acc, nuM,
+                tk.at[0].add(its).at[2].add(cgs).at[3].add(rps))
 
     J, xd, nerr_acc, nuM, tk = jax.lax.fori_loop(
         0, M, body, (J0_, xd, nerr_acc0, nuM0, tk0))
@@ -626,13 +634,15 @@ def _group_update(cjs, state, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
                                     out_dtype=xres.dtype)
         itcap = int(config.max_iter) + iter_bar
         with jax.named_scope("inner"):
-            Jn, nu_new, init_cost, final_cost, its, cgs = _cluster_solve(
+            (Jn, nu_new, init_cost, final_cost, its, cgs,
+             rps) = _cluster_solve(
                 mode, xdummy, coh_m, sta1, sta2, cidx_m, cmask_m, wt_base,
                 J_m, n_stations, jnp.take(nuM, cj, mode="clip"), config,
                 itermax, itcap, admm_m, os_cfg, last)
-        return Jn, nu_new, init_cost, final_cost, its, cgs, xdummy
+        return Jn, nu_new, init_cost, final_cost, its, cgs, rps, xdummy
 
-    Jn_g, nu_g, ic_g, fc_g, its_g, cgs_g, xd_g = jax.vmap(solve_one)(cjs)
+    (Jn_g, nu_g, ic_g, fc_g, its_g, cgs_g, rps_g,
+     xd_g) = jax.vmap(solve_one)(cjs)
     # the joint update: the damped trials, then the scatters of whatever
     # was accepted
     with jax.named_scope("update"):
@@ -690,12 +700,12 @@ def _group_update(cjs, state, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
         # slowest lane finishes; rejected groups still executed them).
         # tk[1]: fully-rejected group steps — the observability hook for
         # "groups are all vetoing" (info['rejected_groups']).
-        # tk[2]: executed inner CG trips (LM PCG, RTR tCG), same live-lane sum.
-        tk = tk.at[0].add(
-            jnp.sum(jnp.where(valid, its_g, 0)).astype(jnp.int32))
+        # tk[2]: executed inner CG trips (LM PCG, RTR tCG), tk[3]: RTR's
+        # row passes, the same live-lane sums.
+        live = lambda c: jnp.sum(jnp.where(valid, c, 0)).astype(jnp.int32)
+        tk = tk.at[0].add(live(its_g))
         tk = tk.at[1].add((~accept).astype(jnp.int32))
-        tk = tk.at[2].add(
-            jnp.sum(jnp.where(valid, cgs_g, 0)).astype(jnp.int32))
+        tk = tk.at[2].add(live(cgs_g)).at[3].add(live(rps_g))
         return J, xres, nerr_acc, nuM, tk
 
 
@@ -901,7 +911,7 @@ def sagefit(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0, n_stations: int,
 
     nuM0 = jnp.full((M,), jnp.asarray(nu0, dtype))
     carry0 = (J0, xres0, jnp.zeros((M,), dtype), nuM0,
-              jnp.zeros((3,), jnp.int32))
+              jnp.zeros((N_TK,), jnp.int32))
     if G0 == G or config.max_emiter < 1:
         J, xres, nerr, nuM, tk = jax.lax.fori_loop(
             0, config.max_emiter, lambda ci, c: em_iter_width(ci, c, G),
@@ -950,6 +960,7 @@ def sagefit(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0, n_stations: int,
     return J, {"res_0": res_0, "res_1": res_1, "mean_nu": mean_nu,
                "nerr": nerr, "solver_iters": tk[0],
                "rejected_groups": tk[1], "cg_iters": tk[2],
+               "row_passes": tk[3],
                "lbfgs_iters": lbfgs_k, "refine_passes": passes}
 
 
@@ -968,7 +979,7 @@ def _jit_cluster_update(cj, J, xres, nerr_acc, nuM, x8, coh, sta1, sta2,
                         total_iter, iter_bar, os_nsub):
     os_id = None if os_ids is None else (os_ids, os_nsub)
     return _cluster_update(cj, (J, xres, nerr_acc, nuM,
-                                jnp.zeros((3,), jnp.int32)),
+                                jnp.zeros((N_TK,), jnp.int32)),
                            x8, coh, sta1,
                            sta2, chunk_idx, chunk_mask, wt_base, n_stations,
                            config, nerr_prev, weighted, last, key, admm,
@@ -990,7 +1001,7 @@ def _jit_group_update(cjs, J, xres, nerr_acc, nuM, x8, coh, sta1, sta2,
     group-step safeguard."""
     os_id = None if os_ids is None else (os_ids, os_nsub)
     return _group_update(cjs, (J, xres, nerr_acc, nuM,
-                               jnp.zeros((3,), jnp.int32)),
+                               jnp.zeros((N_TK,), jnp.int32)),
                          x8, coh, sta1,
                          sta2, chunk_idx, chunk_mask, wt_base, n_stations,
                          config, nerr_prev, weighted, last, key, None,
@@ -1016,7 +1027,7 @@ def _jit_em_sweep(J, xres, nuM, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
     if G == 1:
         return _sweep_g1(
             perm, (J, xres, jnp.zeros((M,), dtp.acc_dtype(x8.dtype)), nuM,
-                   jnp.zeros((3,), jnp.int32)),
+                   jnp.zeros((N_TK,), jnp.int32)),
             x8, coh, sta1, sta2, chunk_idx, chunk_mask, wt_base,
             n_stations, config, nerr_prev, weighted, last, kci, None,
             os_id, total_iter, iter_bar)
@@ -1034,7 +1045,7 @@ def _jit_em_sweep(J, xres, nuM, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
     return jax.lax.fori_loop(
         0, n_groups, group_step,
         (J, xres, jnp.zeros((M,), dtp.acc_dtype(x8.dtype)), nuM,
-         jnp.zeros((3,), jnp.int32)))
+         jnp.zeros((N_TK,), jnp.int32)))
 
 
 @jax.jit
@@ -1186,7 +1197,7 @@ def sagefit_host(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
     fused = (fuse_mode == "on" or
              (fuse_mode == "auto" and _FUSION_CACHE.get(fuse_key, False)))
     sweep_times: list = []
-    tk_total = jnp.zeros((3,), jnp.int32)
+    tk_total = jnp.zeros((N_TK,), jnp.int32)
     for ci in range(config.max_emiter):
         weighted = config.randomize and (ci % 2 == 1)
         last = ci == config.max_emiter - 1
@@ -1299,6 +1310,7 @@ def sagefit_host(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
     return J, {"res_0": res_0, "res_1": res_1, "mean_nu": mean_nu,
                "nerr": nerr, "solver_iters": tk_total[0],
                "rejected_groups": tk_total[1], "cg_iters": tk_total[2],
+               "row_passes": tk_total[3],
                "lbfgs_iters": lbfgs_k, "refine_passes": passes}
 
 
@@ -1355,7 +1367,7 @@ def _jit_em_sweep_tiles(J, xres, nuM, x8, coh, sta1, sta2, chunk_idx,
             return _sweep_g1(
                 perm_t, (J_t, xres_t,
                          jnp.zeros((M,), dtp.acc_dtype(x8.dtype)), nuM_t,
-                         jnp.zeros((3,), jnp.int32)),
+                         jnp.zeros((N_TK,), jnp.int32)),
                 x8_t, coh_t, sta1, sta2, chunk_idx, chunk_mask, wt_t,
                 n_stations, config, nerr_t, weighted, last, key_t, None,
                 os_id, total_iter, iter_bar)
@@ -1373,7 +1385,7 @@ def _jit_em_sweep_tiles(J, xres, nuM, x8, coh, sta1, sta2, chunk_idx,
         return jax.lax.fori_loop(
             0, n_groups, group_step,
             (J_t, xres_t, jnp.zeros((M,), dtp.acc_dtype(x8.dtype)), nuM_t,
-             jnp.zeros((3,), jnp.int32)))
+             jnp.zeros((N_TK,), jnp.int32)))
     return jax.vmap(one)(J, xres, nuM, x8, coh, wt_base, nerr_prev, keys,
                          perm)
 
@@ -1496,7 +1508,7 @@ def sagefit_host_tiles(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
     fused = (fuse_mode == "on" or
              (fuse_mode == "auto" and _FUSION_CACHE.get(fuse_key, False)))
     sweep_times: list = []
-    tk_total = jnp.zeros((T, 3), jnp.int32)
+    tk_total = jnp.zeros((T, N_TK), jnp.int32)
     for ci in range(config.max_emiter):
         weighted = config.randomize and (ci % 2 == 1)
         last = ci == config.max_emiter - 1
@@ -1599,6 +1611,7 @@ def sagefit_host_tiles(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
                "nerr": nerr, "solver_iters": tk_total[:, 0],
                "rejected_groups": tk_total[:, 1],
                "cg_iters": tk_total[:, 2],
+               "row_passes": tk_total[:, 3],
                "lbfgs_iters": lbfgs_k, "refine_passes": passes}
 
 
@@ -1618,7 +1631,7 @@ def _jit_cluster_update_tiles(cj, J, xres, nerr_acc, nuM, x8, coh, sta1,
             nerr_t, key_t):
         os_id = None if os_ids is None else (os_ids, os_nsub)
         return _cluster_update(cj_t, (J_t, xres_t, nerr_acc_t, nuM_t,
-                                      jnp.zeros((3,), jnp.int32)),
+                                      jnp.zeros((N_TK,), jnp.int32)),
                                x8_t, coh_t, sta1, sta2, chunk_idx,
                                chunk_mask, wt_t, n_stations, config,
                                nerr_t, weighted, last, key_t, None, os_id,
@@ -1644,7 +1657,7 @@ def _jit_group_update_tiles(cjs, J, xres, nerr_acc, nuM, x8, coh, sta1,
             key_t, anch_t):
         os_id = None if os_ids is None else (os_ids, os_nsub)
         return _group_update(cjs_t, (J_t, xres_t, na_t, nuM_t,
-                                     jnp.zeros((3,), jnp.int32)), x8_t,
+                                     jnp.zeros((N_TK,), jnp.int32)), x8_t,
                              coh_t, sta1, sta2, chunk_idx, chunk_mask,
                              wt_t, n_stations, config, nerr_t, weighted,
                              last, key_t, None, os_id, total_iter,

@@ -348,6 +348,218 @@ def test_rtr_solve_equals_thirty_trip_loop(case, monkeypatch):
     assert its <= int(info["cg_iters"]) < its * cfg.tcg_iters
 
 
+def _complex_model(Jp, coh, Jq):
+    """The row model as the parent wrote it: complex 2 x 2 products."""
+    return Jp @ coh @ jnp.conj(jnp.swapaxes(Jq, -1, -2))
+
+
+@pytest.mark.parametrize("dtype, rtol", [(np.float64, 1e-12),
+                                         (np.float32, 1e-5)],
+                         ids=["f64", "f32"])
+def test_row_model_matches_complex_products(dtype, rtol):
+    """``normal_eq.row_model``: V = Jp C Jq^H and the Wirtinger factors
+    A = C Jq^H, Bm = Jp C on real planes, rows on the minor axis, against
+    the complex matrix products."""
+    rng = np.random.default_rng(21)
+    B = 37
+    cplx = lambda: jnp.asarray(
+        (rng.normal(size=(B, 2, 2)) + 1j * rng.normal(size=(B, 2, 2))
+         ).astype(np.complex128 if dtype == np.float64 else np.complex64))
+    Jp, Jq, C = cplx(), cplx(), cplx()
+    planes = lambda M: ne.jones_c2r(M).T
+    v, a, bm = ne.row_model(planes(Jp), planes(Jq), planes(C))
+    assert v.dtype == a.dtype == bm.dtype == dtype and v.shape == (8, B)
+    JqH = jnp.conj(jnp.swapaxes(Jq, -1, -2))
+    for got, want in ((v, _complex_model(Jp, C, Jq)), (a, C @ JqH),
+                      (bm, Jp @ C)):
+        want = np.asarray(planes(want))
+        np.testing.assert_allclose(np.asarray(got), want,
+                                   atol=rtol * np.abs(want).max())
+
+
+def _reference_cost(x8, coh, sta1, sta2, chunk_id, wt, K, N, mode, Jref,
+                    nu, admm):
+    """``rtr.make_cost``'s cost written once more HERE, in complex, for
+    ``jax.grad`` to differentiate: the written-out gradient is held
+    against autodiff of this and of nothing in the package."""
+    def cost(p):
+        if mode == "full":
+            J = ne.jones_r2c(p.reshape(K, N, 8))
+        else:
+            J = ne.jones_from_params(
+                p.reshape(K, N, ne.jones_npar(mode)), mode, Jref)
+        V = _complex_model(J[chunk_id, sta1], coh, J[chunk_id, sta2])
+        vf = V.reshape(-1, 4)
+        e = (x8 - jnp.stack([vf.real, vf.imag], -1).reshape(-1, 8)) * wt
+        per = e * e if nu is None else jnp.log1p(e * e / nu)
+        ck = jax.ops.segment_sum(jnp.sum(per, -1), chunk_id,
+                                 num_segments=K)
+        if admm is not None:
+            y, bz, rho = admm
+            d = p - bz
+            ck = ck + 2.0 * jnp.sum(y * d, -1) + rho * jnp.sum(d * d, -1)
+        return ck
+    return cost
+
+
+# name: (chunks, row_period given): two chunks take the general scatter;
+# one chunk with the rows' period sums over time first and scatters
+# nbase rows; one chunk without a period scatters every row
+_ROW_LAYOUTS = {"K2": (2, True), "K1-period": (1, True),
+                "K1-flat": (1, False)}
+_GRAD_CASES = [(c, m) for c in ("gauss", "robust") for m in
+               ("full", "diag", "phase")] + [("admm", "full"),
+                                             ("robust-admm", "full")]
+
+
+@pytest.mark.parametrize("cost, mode", _GRAD_CASES,
+                         ids=["-".join(c) for c in _GRAD_CASES])
+@pytest.mark.parametrize("layout", sorted(_ROW_LAYOUTS))
+def test_written_out_gradient_matches_autodiff(layout, cost, mode):
+    """``rtr.make_row_pass``: its cost and its Euclidean gradient
+    (elementwise shares from the pass's own Wirtinger factors, one
+    segment sum, the ADMM term, ``jax.vjp`` of the station-sized p -> J
+    map for the constrained modes) against ``jax.grad`` of the complex
+    cost above, some rows flagged."""
+    K, period = _ROW_LAYOUTS[layout]
+    N, T = 5, 4
+    nbase = N * (N - 1) // 2
+    x8, coh, sta1, sta2, chunk_id, _ = _toy_problem(N=N, T=T, K=K, seed=22,
+                                                    noise=0.3)
+    rng = np.random.default_rng(23)
+    flags = jnp.asarray(rng.random(x8.shape[0]) < 0.2, jnp.int32)
+    wt = lm_mod.make_weights(flags, x8.dtype) \
+        * jnp.asarray(rng.uniform(0.5, 1.5, size=x8.shape))
+    J = jnp.asarray(rng.normal(size=(K, N, 2, 2)) * 0.4
+                    + 1j * rng.normal(size=(K, N, 2, 2)) * 0.4 + np.eye(2))
+    Jref = None if mode == "full" else ne.jones_constrain(J, mode)
+    D = N * ne.jones_npar(mode)
+    if mode == "full":
+        p = ne.jones_c2r(J).reshape(K, D)
+    else:
+        p = ne.params_from_jones(Jref, mode).reshape(K, D) \
+            + jnp.asarray(rng.normal(size=(K, D)) * 0.1)
+    nu = 3.5 if "robust" in cost else None
+    admm = None
+    if "admm" in cost:
+        admm = (jnp.asarray(rng.normal(size=(K, D))),
+                jnp.asarray(rng.normal(size=(K, D))),
+                jnp.asarray(rng.uniform(1.0, 5.0, size=K)))
+    rows = ne.RowPlanes(x8, coh, wt, sta1, sta2, chunk_id, K, N,
+                        nbase if period else 0)
+    assert rows.periodic == (layout == "K1-period")
+    row_pass, egrad = rtr_mod.make_row_pass(
+        rows, K, N, admm=admm, robust_nu=nu, mode=mode, Jref=Jref)
+    ck, e, shares = jax.jit(row_pass)(p)
+    g = jax.jit(egrad)(p, shares)
+    ref = _reference_cost(x8, coh, sta1, sta2, chunk_id, wt, K, N, mode,
+                          Jref, nu, admm)
+    g_ref = jax.grad(lambda q: jnp.sum(ref(q)))(p)
+    np.testing.assert_allclose(np.asarray(ck), np.asarray(ref(p)),
+                               rtol=1e-12)
+    np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref),
+                               rtol=1e-10,
+                               atol=1e-12 * float(jnp.abs(g_ref).max()))
+    # the pass's residual is residual8's, in plane form
+    J_at = ne.jones_r2c(p.reshape(K, N, 8)) if mode == "full" else \
+        ne.jones_from_params(p.reshape(K, N, -1), mode, Jref)
+    np.testing.assert_allclose(
+        np.asarray(rows.to_rows(e)),
+        np.asarray(ne.residual8(x8, J_at, coh, sta1, sta2, chunk_id) * wt),
+        atol=1e-12)
+
+
+def _fresh_residual(x8, J, coh, sta1, sta2, chunk_id, wt):
+    return np.asarray(ne.residual8(x8, J, coh, sta1, sta2, chunk_id) * wt)
+
+
+# name: (chunks, mask, row_period given, robust nu)
+_ONCE_CASES = {"K1-period": (1, None, True, None),
+               "K1-flat-robust": (1, None, False, 4.0),
+               "K2-masked": (2, [True, False], True, None),
+               "K2-robust": (2, None, True, 4.0)}
+
+
+@pytest.mark.parametrize("case", sorted(_ONCE_CASES))
+def test_rtr_solve_one_row_pass_per_point(case):
+    """``rtr_solve`` evaluates the row model once at the start and once
+    per trial point, and the residual it hands back is the one at the
+    Jones it returns (a masked chunk's at its J0)."""
+    K, mask, period, nu = _ONCE_CASES[case]
+    N = 6
+    x8, coh, sta1, sta2, chunk_id, _ = _toy_problem(N=N, T=4, K=K, seed=24,
+                                                    noise=0.05)
+    J0 = jnp.tile(jnp.eye(2, dtype=jnp.complex128), (K, N, 1, 1))
+    wt = lm_mod.make_weights(jnp.zeros(x8.shape[0], jnp.int32), x8.dtype)
+    J, info = rtr_mod.rtr_solve(
+        x8, coh, sta1, sta2, chunk_id, wt, J0, N,
+        chunk_mask=None if mask is None else jnp.asarray(mask),
+        config=rtr_mod.RTRConfig(itmax=7), robust_nu=nu,
+        row_period=N * (N - 1) // 2 if period else 0)
+    its = int(info["iters"])
+    assert 0 < its <= 7 and int(info["row_passes"]) == 1 + its
+    assert info["row_passes"].dtype == jnp.int32
+    assert float(info["final_cost"][0]) < float(info["init_cost"][0])
+    np.testing.assert_allclose(
+        np.asarray(info["residual"]),
+        _fresh_residual(x8, J, coh, sta1, sta2, chunk_id, wt), atol=1e-12)
+
+
+@pytest.mark.parametrize("rounds", [1, 3])
+def test_rtr_solve_robust_estep_reads_the_solves_residual(rounds):
+    """``rtr_solve_robust``: rounds + iters row passes, and a round's nu
+    (its weights' statistic) is the one a fresh ``residual8`` at the
+    round's Jones gives."""
+    from sagecal_tpu.solvers import robust as rb
+    N = 6
+    x8, coh, sta1, sta2, chunk_id, _ = _toy_problem(
+        N=N, T=4, K=1, seed=25, noise=0.05, nu=3.0)
+    J0 = jnp.tile(jnp.eye(2, dtype=jnp.complex128), (1, N, 1, 1))
+    flags = jnp.asarray(np.arange(x8.shape[0]) % 7 == 0, jnp.int32)
+    wt = lm_mod.make_weights(flags, x8.dtype)
+    cfg = rtr_mod.RTRConfig(itmax=5)
+    kw = dict(config=cfg, row_period=N * (N - 1) // 2)
+    J, nu, info = rtr_mod.rtr_solve_robust(
+        x8, coh, sta1, sta2, chunk_id, wt, J0, N, nu0=2.0,
+        wt_rounds=rounds, **kw)
+    its = int(info["iters"])
+    assert its >= rounds and int(info["row_passes"]) == rounds + its
+    # the rounds once more by hand, the E-step from a fresh residual
+    Jh, nuh = J0, jnp.asarray(2.0)
+    for _ in range(rounds):
+        Jh, _ = rtr_mod.rtr_solve(x8, coh, sta1, sta2, chunk_id, wt, Jh, N,
+                                  robust_nu=nuh, **kw)
+        w = rb.update_weights(
+            _fresh_residual(x8, Jh, coh, sta1, sta2, chunk_id, wt), nuh)
+        nuh = rb.update_nu_aecm(rb.mean_logsumw(w, np.asarray(wt) > 0),
+                                nuh, p=2)
+    np.testing.assert_allclose(np.asarray(J), np.asarray(Jh), atol=1e-12)
+    np.testing.assert_allclose(float(nu), float(nuh), rtol=1e-12)
+
+
+def test_row_passes_under_vmap_counts_each_element():
+    """Batched solves freeze an element that is done: its ``row_passes``
+    is the count it has alone, one more than its own iterations."""
+    N, caps = 6, [0, 2, 6]
+    x8, coh, sta1, sta2, chunk_id, _ = _toy_problem(N=N, T=4, K=1, seed=26,
+                                                    noise=0.05)
+    J0 = jnp.tile(jnp.eye(2, dtype=jnp.complex128), (1, N, 1, 1))
+    wt = lm_mod.make_weights(jnp.zeros(x8.shape[0], jnp.int32), x8.dtype)
+
+    def one(cap):
+        return rtr_mod.rtr_solve(
+            x8, coh, sta1, sta2, chunk_id, wt, J0, N,
+            config=rtr_mod.RTRConfig(itmax=6), itmax_dynamic=cap,
+            row_period=N * (N - 1) // 2)[1]
+
+    info = jax.jit(jax.vmap(one))(jnp.asarray(caps, jnp.int32))
+    assert np.asarray(info["iters"]).tolist() == caps
+    assert np.asarray(info["row_passes"]).tolist() == [c + 1 for c in caps]
+    alone = [int(one(jnp.asarray(c, jnp.int32))["row_passes"])
+             for c in caps]
+    assert alone == [c + 1 for c in caps]
+
+
 @pytest.mark.slow
 def test_sage_dispatches_rtr_modes():
     from sagecal_tpu.config import SolverMode

@@ -48,7 +48,10 @@ event            meaning / required extra fields
                  bodies), ``row_passes`` (RTR's evaluations of the
                  row model: solvers/rtr.py), ``lbfgs_iters``,
                  ``refine_passes`` (passes through the model the joint
-                 refine made: solvers/lbfgs.py), ``minutes``,
+                 refine made: solvers/lbfgs.py), ``plan`` and
+                 ``solve_dispatches`` (what sagefit_host's last sweep
+                 executed, "promoted", "fused" or "per_cluster", and
+                 the device executions the solve issued), ``minutes``,
                  ``primal``, ``rho_mean``, and the
                  overlap accounting pair ``bubble_s`` (host seconds
                  blocked on data movement for this tile: io wait +

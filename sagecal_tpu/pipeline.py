@@ -75,7 +75,8 @@ def _emit_tile_record(ti, res_0, res_1, mean_nu, info, minutes,
         if bubble_s is not None:
             obs.inc("tile_bubble_seconds_total", float(bubble_s))
         for k, v in trips.items():
-            obs.inc(f"solver_{k}_total", v)
+            name = "dispatches" if k == "solve_dispatches" else k
+            obs.inc(f"solver_{name}_total", v)
     if not dtrace.active():
         return
     rec = dict(tile=ti, res_0=res_0, res_1=res_1, mean_nu=mean_nu,
@@ -86,11 +87,14 @@ def _emit_tile_record(ti, res_0, res_1, mean_nu, info, minutes,
     # host-driver extras (the sharded solver reports only residuals):
     # the trace schema's two trip fields, the inner CG trips under them
     # (LM's PCG, RTR's truncated CG) and RTR's passes over the rows, and
-    # the joint refine's passes through the model (lbfgs._lbfgs_loop)
+    # the joint refine's passes through the model (lbfgs._lbfgs_loop),
+    # and which plan sagefit_host ran in how many device executions
     for k in ("solver_iters", "cg_iters", "row_passes", "lbfgs_iters",
-              "refine_passes"):
+              "refine_passes", "solve_dispatches"):
         if k in trips:
             rec[k] = trips[k]
+    if isinstance(info, dict) and "plan" in info:
+        rec["plan"] = info["plan"]
     dtrace.emit("tile", **rec)
 
 

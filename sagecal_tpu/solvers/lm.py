@@ -42,7 +42,7 @@ from sagecal_tpu.solvers import normal_eq as ne
 #: the host-side telemetry (diag tile records, obs trip counters,
 #: benchmarks/' solver_trips) reads through executed_trips()
 TRIP_KEYS = ("solver_iters", "cg_iters", "row_passes", "lbfgs_iters",
-             "refine_passes", "rejected_groups")
+             "refine_passes", "rejected_groups", "solve_dispatches")
 
 
 def executed_trips(info) -> dict:

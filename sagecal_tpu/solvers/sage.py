@@ -40,6 +40,7 @@ from __future__ import annotations
 
 import functools
 import logging
+import threading
 import time
 from typing import NamedTuple
 
@@ -88,12 +89,32 @@ def _spec_of(a):
     return a
 
 
+# device executions this THREAD has issued through _call: a solve reads
+# it on entry and on return (info["solve_dispatches"]), so solves on
+# other threads (serve) are not counted into it
+_DISPATCHED = threading.local()
+
+
+def _dispatched() -> int:
+    return getattr(_DISPATCHED, "n", 0)
+
+
 def _call(name, jfn, *args, **kwargs):
     rec = _PROGRAM_CALLS.setdefault(name, [jfn, None, 0])
     rec[1] = (tuple(_spec_of(a) for a in args),
               {k: _spec_of(v) for k, v in kwargs.items()})
     rec[2] += 1
+    _DISPATCHED.n = _dispatched() + 1
     return jfn(*args, **kwargs)
+
+
+def _plan_info(info: dict, plan: str, n0: int) -> dict:
+    """``info`` with what a host-driven solve ran: ``plan`` is what its
+    LAST sweep executed ("promoted": the whole solve as one program,
+    "fused": a program a sweep, "per_cluster": a program a cluster or
+    group), ``solve_dispatches`` the device executions it issued through
+    :func:`_call` since ``n0``.  Host values: nothing is fetched."""
+    return {**info, "plan": plan, "solve_dispatches": _dispatched() - n0}
 
 
 _LOG = logging.getLogger(__name__)
@@ -1175,15 +1196,17 @@ def sagefit_host(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
     promote_key = fuse_key + (config.max_emiter, config.max_lbfgs)
     promoted = promote_mode == "on" or (
         promote_mode == "auto" and _PROMOTE_CACHE.get(promote_key, False))
+    n0 = _dispatched()
     if promoted:
         # whole solve proven to fit the per-execution budget: one
         # traced program, minimal device round-trips
-        return _call("sagefit", _jit_sagefit, x8, coh, sta1, sta2,
-                     chunk_idx, chunk_mask, J0, n_stations, wt_base,
-                     jnp.asarray(nu0, dtype),
-                     config._replace(fuse="auto", promote="auto"),
-                     os_ids if os_id is not None else None,
-                     os_nsub, key)
+        J, info = _call("sagefit", _jit_sagefit, x8, coh, sta1, sta2,
+                        chunk_idx, chunk_mask, J0, n_stations, wt_base,
+                        jnp.asarray(nu0, dtype),
+                        config._replace(fuse="auto", promote="auto"),
+                        os_ids if os_id is not None else None,
+                        os_nsub, key)
+        return J, _plan_info(info, "promoted", n0)
     xres, res_0 = _call("prelude", _jit_prelude, x8, coh, sta1, sta2,
                         chunk_idx, J0, wt_base)
     # the per-sweep/per-cluster programs DONATE their state carries
@@ -1196,6 +1219,7 @@ def sagefit_host(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
     nuM = jnp.full((M,), jnp.asarray(nu0, dtype))
     fused = (fuse_mode == "on" or
              (fuse_mode == "auto" and _FUSION_CACHE.get(fuse_key, False)))
+    ran_fused = fused   # what a solve of no sweeps would have run
     sweep_times: list = []
     tk_total = jnp.zeros((N_TK,), jnp.int32)
     for ci in range(config.max_emiter):
@@ -1307,11 +1331,13 @@ def sagefit_host(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
     else:
         res_1 = _call("res", _jit_res, x8, coh, sta1, sta2, chunk_idx, J,
                       wt_base)
-    return J, {"res_0": res_0, "res_1": res_1, "mean_nu": mean_nu,
-               "nerr": nerr, "solver_iters": tk_total[0],
-               "rejected_groups": tk_total[1], "cg_iters": tk_total[2],
-               "row_passes": tk_total[3],
-               "lbfgs_iters": lbfgs_k, "refine_passes": passes}
+    return J, _plan_info(
+        {"res_0": res_0, "res_1": res_1, "mean_nu": mean_nu,
+         "nerr": nerr, "solver_iters": tk_total[0],
+         "rejected_groups": tk_total[1], "cg_iters": tk_total[2],
+         "row_passes": tk_total[3],
+         "lbfgs_iters": lbfgs_k, "refine_passes": passes},
+        "fused" if ran_fused else "per_cluster", n0)
 
 
 # ---------------------------------------------------------------------------
@@ -1461,7 +1487,8 @@ def sagefit_host_tiles(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
                                  chunk_mask, J0[0], n_stations,
                                  wt_base[0], nu0=nu0, config=config,
                                  os_id=os_id, key=keys[0])
-        info = {k: jnp.asarray(v)[None] for k, v in info1.items()}
+        info = {k: v if k in ("plan", "solve_dispatches")
+                else jnp.asarray(v)[None] for k, v in info1.items()}
         return J1[None], info
     x8 = dtp.to_storage(x8, dtp.storage_dtype(config.dtype_policy,
                                               x8.dtype))
@@ -1491,13 +1518,15 @@ def sagefit_host_tiles(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
     promote_key = fuse_key + (config.max_emiter, config.max_lbfgs)
     promoted = promote_mode == "on" or (
         promote_mode == "auto" and _PROMOTE_CACHE.get(promote_key, False))
+    n0 = _dispatched()
     if promoted:
-        return _call("sagefit_tiles", _jit_sagefit_tiles, x8, coh,
-                     sta1, sta2, chunk_idx, chunk_mask, J0, n_stations,
-                     wt_base, jnp.asarray(nu0, dtype),
-                     config._replace(fuse="auto", promote="auto"),
-                     os_ids if os_id is not None else None,
-                     os_nsub, keys)
+        J, info = _call("sagefit_tiles", _jit_sagefit_tiles, x8, coh,
+                        sta1, sta2, chunk_idx, chunk_mask, J0, n_stations,
+                        wt_base, jnp.asarray(nu0, dtype),
+                        config._replace(fuse="auto", promote="auto"),
+                        os_ids if os_id is not None else None,
+                        os_nsub, keys)
+        return J, _plan_info(info, "promoted", n0)
     xres, res_0 = _call("prelude_tiles", _jit_prelude_tiles, x8, coh,
                         sta1, sta2, chunk_idx, J0, wt_base)
     # donation guard: see sagefit_host — the sweep programs consume
@@ -1507,6 +1536,7 @@ def sagefit_host_tiles(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
     nuM = jnp.full((T, M), jnp.asarray(nu0, dtype))
     fused = (fuse_mode == "on" or
              (fuse_mode == "auto" and _FUSION_CACHE.get(fuse_key, False)))
+    ran_fused = fused   # what a solve of no sweeps would have run
     sweep_times: list = []
     tk_total = jnp.zeros((T, N_TK), jnp.int32)
     for ci in range(config.max_emiter):
@@ -1607,12 +1637,14 @@ def sagefit_host_tiles(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
     else:
         res_1 = _call("res_tiles", _jit_res_tiles, x8, coh, sta1, sta2,
                       chunk_idx, J, wt_base)
-    return J, {"res_0": res_0, "res_1": res_1, "mean_nu": mean_nu,
-               "nerr": nerr, "solver_iters": tk_total[:, 0],
-               "rejected_groups": tk_total[:, 1],
-               "cg_iters": tk_total[:, 2],
-               "row_passes": tk_total[:, 3],
-               "lbfgs_iters": lbfgs_k, "refine_passes": passes}
+    return J, _plan_info(
+        {"res_0": res_0, "res_1": res_1, "mean_nu": mean_nu,
+         "nerr": nerr, "solver_iters": tk_total[:, 0],
+         "rejected_groups": tk_total[:, 1],
+         "cg_iters": tk_total[:, 2],
+         "row_passes": tk_total[:, 3],
+         "lbfgs_iters": lbfgs_k, "refine_passes": passes},
+        "fused" if ran_fused else "per_cluster", n0)
 
 
 @functools.partial(jax.jit,

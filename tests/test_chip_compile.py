@@ -3,7 +3,8 @@
 The TPU compiler is installed in the CPU sandbox and compiles for a chip
 that is described, not attached. These cases hold the kernels and the
 solve that ``chip_smoke.py`` runs, at its shapes (N=62 stations -> 1891
-baselines, tilesz=10, M=8 clusters, f32): they catch a Mosaic refusal
+baselines, tilesz=10, M=8 clusters, f32; ``test_production_tile_fits``
+at tilesz=120, upstream's default): they catch a Mosaic refusal
 (unaligned slice, VMEM overflow) or a program that cannot fit the
 device's memory, at no chip time. A compile that passes is not a chip
 run.
@@ -17,6 +18,7 @@ compile does not return (PERF.md "Bring-up on v5e"): there is no
 per-test time limit installed, so one such case would hang the suite.
 """
 
+import functools
 import os
 import sys
 
@@ -145,12 +147,7 @@ def test_refine_program_searches_on_the_line(one_chip):
     assert 0 < text.count(" fusion(") < 967 // 2
 
 
-def test_residual_program_compiles(one_chip):
-    """The per-tile residual program (pipeline._residuals /
-    cli_mpi residual_fn: real pairs in and out, donated input). Its
-    complex-subtract-then-restack form aborted the TPU compiler on the
-    v5e (rime/residual.calculate_residuals_pairs says why); a CHECK
-    failure there kills this worker, which is the test failing."""
+def _lower_residual_program(one_chip, rows):
     from problems import make_sky
     from sagecal_tpu.rime import predict as rp, residual as rr
     from sagecal_tpu.solvers import normal_eq as ne
@@ -165,7 +162,103 @@ def test_residual_program_compiles(one_chip):
             jnp.asarray([150e6], f32), 0.18e6, sta1, sta2, cidx,
             jnp.ones((M,), bool), out_dtype=f32)
 
-    jax.jit(residuals, donate_argnums=(1,)).lower(
-        sd((M, 1, N, 8), f32), sd((B, 1, 2, 2, 2), f32), sd((B,), f32),
-        sd((B,), f32), sd((B,), f32), sd((B,), i32), sd((B,), i32),
-        sd((M, B), i32)).compile()
+    return jax.jit(residuals, donate_argnums=(1,)).lower(
+        sd((M, 1, N, 8), f32), sd((rows, 1, 2, 2, 2), f32),
+        sd((rows,), f32), sd((rows,), f32), sd((rows,), f32),
+        sd((rows,), i32), sd((rows,), i32), sd((M, rows), i32))
+
+
+def test_residual_program_compiles(one_chip):
+    """The per-tile residual program (pipeline._residuals /
+    cli_mpi residual_fn: real pairs in and out, donated input). Its
+    complex-subtract-then-restack form aborted the TPU compiler on the
+    v5e (rime/residual.calculate_residuals_pairs says why); a CHECK
+    failure there kills this worker, which is the test failing."""
+    _lower_residual_program(one_chip, B).compile()
+
+
+# -- the production solve interval: -t 120, 226 920 rows a tile --------------
+
+TILESZ_120 = 120
+B_120 = NB * TILESZ_120
+#: what the chip reported as its own in PR 25's RESOURCE_EXHAUSTED
+CHIP_BYTES = int(15.75 * 2 ** 30)
+@functools.cache
+def _need_120(one_chip, name):
+    """Bytes (argument + output + temp) the program ``name`` asks of a
+    described v5e at 226 920 rows, with ``cal-t120``'s flags (``-e 4 -g
+    2 -l 10 -m 7 -j 5``, N 62, M 8) and f32 contractions in f32 as
+    ``utils.setup_backend`` gives every entry point (at the one-pass
+    default the solve asks 0.44 GiB less); compiled once a session."""
+    from sagecal_tpu.config import SolverMode
+    from sagecal_tpu.solvers import lm as lm_mod, sage
+    sd = _spec(one_chip)
+    f32, i32, c64 = jnp.float32, jnp.int32, jnp.complex64
+    rows = B_120
+    cfg = sage.SageConfig(nbase=NB)._replace(
+        max_emiter=4, max_iter=2, max_lbfgs=10, lbfgs_m=7,
+        solver_mode=int(SolverMode.RTR_OSRLM_RLBFGS))
+    os_ids, os_nsub = lm_mod.os_subset_ids(TILESZ_120, NB)
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    flag = sd((), jnp.bool_)
+    # x8, coh, sta1, sta2, chunk idx: what every solve program is handed
+    data = (sd((rows, 8), f32), sd((M, rows, 2, 2), c64),
+            sd((rows,), i32), sd((rows,), i32), sd((M, rows), i32))
+    J, wt = sd((M, 1, N, 2, 2), c64), sd((rows, 8), f32)
+    with jax.default_matmul_precision("highest"):
+        if name == "sagefit":
+            lowered = sage._jit_sagefit.lower(
+                *data, sd((M, 1), jnp.bool_), J, N, wt, sd((), f32), cfg,
+                sd(np.shape(os_ids), i32), os_nsub,
+                sd(key.shape, key.dtype))
+        elif name == "refine":
+            lowered = sage._jit_refine.lower(
+                *data, J, wt, sd((), f32), N, cfg._replace(max_emiter=0),
+                True)
+        elif name == "cluster_update":
+            cfg0 = cfg._replace(max_emiter=0)
+            lowered = sage._jit_cluster_update.lower(
+                sd((), i32), J, wt, sd((M,), f32), sd((M,), f32),
+                *data, sd((M, 1), jnp.bool_), wt, sd((M,), f32), flag,
+                flag, sd(key.shape, key.dtype), None,
+                sd(np.shape(os_ids), i32), N, cfg0, M * cfg0.max_iter, 2,
+                os_nsub)
+        else:
+            lowered = _lower_residual_program(one_chip, rows)
+        mem = lowered.compile().memory_analysis()
+    return (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+
+
+@pytest.mark.parametrize("program", ["sagefit", "refine", "cluster_update",
+                                     "residual"])
+def test_production_tile_fits(one_chip, program):
+    """``-t 120`` (upstream's default solve interval: 226 920 rows a
+    tile at N 62) compiles for the described v5e and fits the 15.75 GiB
+    a chip reports as its own: the promoted whole-solve program (what a
+    warm ``cal-t120`` tile runs), the host-driven plan's joint refine
+    and per-cluster update (tile 0's first sweep), and the residual
+    program.  Argument + output + temp as compiled here at PR 34, with
+    the temporaries the chip's own compile asked for beside them
+    (PERF.md section 5; ISSUE 34's table, 13.04 and 13.03 GiB, was
+    compiled at the one-pass default):
+
+    ==============  ===========  ================
+    program         -t 120 here  the chip's temp
+    ==============  ===========  ================
+    sagefit         13.56 GiB    13.48 GiB
+    refine          13.55 GiB    13.47 GiB
+    cluster_update   7.02 GiB     not read
+    residual         2.30 GiB     2.27 GiB
+    ==============  ===========  ================
+
+    Arguments are 0.076 GiB: nearly all of it is ``f32[8, 226920, 2,
+    2]`` temporaries tiled ``T(2,128)``, 1.73 GiB for 27 MB of data
+    each, about seven live at once.  One more of them in the refine and
+    production no longer compiles: this case is what notices.  The
+    solve's and the residual's TOGETHER are 15.86 GiB, more than the
+    chip has, and every ``cal-t120`` run was ``correct``: the residual
+    is dispatched once the solve's result is fetched, and the two are
+    not live together."""
+    need = _need_120(one_chip, program)
+    assert 0 < need < CHIP_BYTES, need / 2 ** 30

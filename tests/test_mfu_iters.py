@@ -146,3 +146,82 @@ def test_band_solver_reports_iters():
                                                 mem, itmax=6)
     assert 0 < int(k) <= 6
     assert np.allclose(np.asarray(p1), 2.0, atol=1e-3)
+
+
+# plan -> (forced knobs, device executions of a solve of E sweeps over M
+# clusters with a refine: the prelude, the sweeps' programs, the refine)
+_PLANS = {
+    "promoted": (dict(promote="on"), lambda M, E: 1),
+    "fused": (dict(fuse="on", promote="off"), lambda M, E: 1 + E + 1),
+    "per_cluster": (dict(fuse="off", promote="off"),
+                    lambda M, E: 1 + E * M + 1),
+}
+
+
+@pytest.mark.parametrize("tiles", [0, 1], ids=["solo", "tiles-1"])
+@pytest.mark.parametrize("plan", sorted(_PLANS))
+def test_plan_and_dispatches_reach_the_tile_record(problem, plan, tiles,
+                                                   tmp_path):
+    """``sagefit_host`` says which plan its last sweep ran and how many
+    device executions the solve issued through ``sage._call``, under the
+    forced knobs; ``sagefit_host_tiles`` hands a lone tile's through as
+    host values; the ``tile`` record and the obs counter carry both."""
+    from sagecal_tpu import pipeline
+    from sagecal_tpu.diag import trace as dtrace
+    from sagecal_tpu.obs import metrics as obs
+    knobs, count = _PLANS[plan]
+    cfg = sage.SageConfig(max_emiter=2, max_iter=2, max_lbfgs=2,
+                          solver_mode=int(SolverMode.RTR_OSRLM_RLBFGS),
+                          **knobs)
+    M = problem[1].shape[0]
+    if tiles:
+        x8, coh, s1, s2, cidx, cmask, J0, N, wt = problem
+        _, info = sage.sagefit_host_tiles(
+            x8[None], coh[None], s1, s2, cidx, cmask, J0[None], N, wt[None],
+            config=cfg)
+        assert info["res_1"].shape == (1,)
+    else:
+        sage.program_stats_reset()
+        _, info = sage.sagefit_host(*problem, config=cfg)
+        # the count is of _call's executions, program by program
+        assert info["solve_dispatches"] == sum(
+            n for _, _, n in sage.program_stats().values())
+    assert info["plan"] == plan
+    assert info["solve_dispatches"] == count(M, cfg.max_emiter)
+    assert type(info["solve_dispatches"]) is int     # nothing to fetch
+    path = str(tmp_path / "diag.jsonl")
+    was_on = obs.active()
+    reg = obs.enable()
+    before = reg.get("solver_dispatches_total")
+    before = before.value() if before is not None else 0
+    dtrace.enable(path, entry="test", argv=[])
+    try:
+        pipeline._emit_tile_record(0, 1.0, 0.5, 2.0, info, 0.1)
+        after = reg.get("solver_dispatches_total").value()
+    finally:
+        dtrace.disable()
+        if not was_on:
+            obs.disable()
+    tile, = [r for r in dtrace.read(path) if r.get("ev") == "tile"]
+    assert (tile["plan"], tile["solve_dispatches"]) == (
+        plan, count(M, cfg.max_emiter))
+    assert after - before == count(M, cfg.max_emiter)
+
+
+@pytest.mark.parametrize("info", [
+    None,                                   # the mesh program's record
+    {"solver_iters": 7, "lbfgs_iters": 3},  # a solver without a plan
+], ids=["no-info", "no-plan"])
+def test_tile_record_has_a_plan_only_where_the_solver_said_one(info,
+                                                               tmp_path):
+    from sagecal_tpu import pipeline
+    from sagecal_tpu.diag import trace as dtrace
+    path = str(tmp_path / "diag.jsonl")
+    dtrace.enable(path, entry="test", argv=[])
+    try:
+        pipeline._emit_tile_record(0, 1.0, 0.5, 2.0, info, 0.1)
+    finally:
+        dtrace.disable()
+    tile, = [r for r in dtrace.read(path) if r.get("ev") == "tile"]
+    assert "plan" not in tile and "solve_dispatches" not in tile
+    assert set(tile) >= {"tile", "res_0", "res_1", "mean_nu", "minutes"}

@@ -9,7 +9,9 @@ the output column, solutions to a solutions file.
 
 Warm-up is the mix's ``warmup_tiles`` first tiles; the window opens as
 the next tile's step is entered and closes at the first tile boundary
-after ``--seconds``; then the writer is drained.
+after ``--seconds``, or with the observation's last tile where that comes
+first (``left`` tells the harness how far that is); then the writer is
+drained.
 """
 
 import os
@@ -62,7 +64,8 @@ def run(run):
                 if run.window.due():
                     break
                 run.enter_tile(
-                    ti, int((tile.flags == 0).sum()) * len(tile.freqs))
+                    ti, int((tile.flags == 0).sum()) * len(tile.freqs),
+                    left=ms.n_tiles - 1 - ti)
             with run.annotate("step"):
                 st.step(ti, tile, stg, wait)
     finally:

@@ -14,7 +14,9 @@ there are as many devices as subbands; fewer devices hold several each
 
 Warm-up is the mix's ``warmup_tiles`` first intervals; the window opens
 as the next interval's step is entered and closes at the first interval
-boundary after ``--seconds``; then the writer is drained.  A tile of the
+boundary after ``--seconds``, or with the observation's last interval
+where that comes first (``left`` tells the harness how far that is); then
+the writer is drained.  A tile of the
 harness is an interval here: ``n_vis`` of it is the unflagged samples of
 all subbands.
 """
@@ -104,7 +106,7 @@ def run(run):
                     break
                 run.enter_tile(ti, sum(
                     int((t.flags == 0).sum()) * len(t.freqs)
-                    for t in tiles))
+                    for t in tiles), left=st.n_intervals - 1 - i)
             with run.annotate("step"):
                 st.step(ti, tiles, stg, wait)
     finally:

@@ -13,8 +13,16 @@ cycle wrote is gone when the window closes.  ``write_tile`` therefore
 keeps, of EVERY cycle of the window, a few rows drawn from the seed of
 the array it is handed to write (``kept_rows``); the check compares all
 of them, and reads back from disk the cycles that are still there.
+
+Which cycle a written tile belongs to follows the tile, not the loop:
+``tiles()`` queues each cycle as it reads and ``write_tile`` takes the
+oldest one off the queue.  The program writes tiles in the order it read
+them (an ordered writer's guarantee, and what a file on disk means), so a
+loop that reads ahead of its writes, or writes from another thread, is
+checked cycle by cycle all the same.
 """
 
+import collections
 import os
 import time
 
@@ -23,8 +31,6 @@ import numpy as np
 import datagen
 import harness
 import reference
-
-
 
 
 def kept_rows(run, k):
@@ -41,7 +47,7 @@ def cycling_ms(path, run, warm):
 
     class CyclingMS(ds.SimMS):
         io_s = 0.0          # read + write seconds inside the window
-        cycle = 0           # the cycle whose tile is out
+        out = collections.deque()   # the cycles read and not yet written
         kept = {}           # cycle -> [2 * check_rows_per_cycle, 2, 2]
 
         def _timed(self, name, fn, *a, **kw):
@@ -56,7 +62,7 @@ def cycling_ms(path, run, warm):
             return self._timed("read_tile", super().read_tile, i)
 
         def write_tile(self, i, tile, column=None):
-            k = CyclingMS.cycle
+            k = CyclingMS.out.popleft()
             if k >= warm:
                 CyclingMS.kept[k] = np.array(tile.x[kept_rows(run, k), 0])
             return self._timed("write_tile", super().write_tile, i, tile,
@@ -72,7 +78,7 @@ def cycling_ms(path, run, warm):
                                    * self.meta["tilesz"]
                                    * len(self.meta["freqs"]))
                 i = k % self.n_tiles
-                CyclingMS.cycle = k
+                CyclingMS.out.append(k)
                 yield i, self.read_tile(i)
                 k += 1
 

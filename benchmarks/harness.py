@@ -232,10 +232,10 @@ class Comparison:
         # a NaN compares false: not correct
         return self.value <= self.limit
 
-    def line(self) -> str:
+    def line(self, note: bool = True) -> str:
         return (f"[check] {self.name} = {self.value:.6g}  (limit "
                 f"{self.limit:.6g}) {'ok' if self.ok else 'NOT CORRECT'}"
-                + (f"  {self.note}" if self.note else ""))
+                + (f"  {self.note}" if note and self.note else ""))
 
 
 def worse(a: float, b: float) -> float:

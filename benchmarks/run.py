@@ -95,18 +95,21 @@ class Run:
             self._ann.__exit__(None, None, None)
             self._ann = None
 
-    def enter_tile(self, tile: int, n_vis: int) -> None:
+    def enter_tile(self, tile: int, n_vis: int,
+                   left: int | None = None) -> None:
         """A tile boundary of the window: the driver calls this as the
         tile's cycle begins, after asking ``window.due()``, and outside
         any span of the program's where it can: the profiler is started
-        here and, with ``profile_tiles``, stopped here."""
+        here and, with ``profile_tiles``, stopped here.  A driver whose
+        observation ends says how many tiles it has ``left`` AFTER this
+        one; one that cycles its tiles says nothing."""
         self._close_annotation()
         w = self.window
         if w.t_open is None:
             from sagecal_tpu.diag import guard
             self.compiles[0] = guard.compile_count()
         elif self.trace and self._prof_t0 is None:
-            if self._slice_is_next():
+            if self._slice_is_next(left):
                 import jax.profiler
                 jax.profiler.start_trace(self.profile_dir)
                 self._prof_t0 = w.clock()
@@ -121,15 +124,23 @@ class Run:
             self._ann = self.annotate("tile_cycle")
             self._ann.__enter__()
 
-    def _slice_is_next(self) -> bool:
+    def _slice_is_next(self, left: int | None) -> bool:
         """At a tile boundary: whether the profiler should start now so
         that it holds about the mix's ``profile_slice_s`` last seconds
         of the window, in whole tiles.  True once a cycle as long as the
-        last one would end inside those seconds."""
+        last one would end inside those seconds.  The window ends at its
+        ``seconds`` or, where the observation has only ``left`` tiles
+        after this one, when those have run at the last cycle's pace,
+        whichever comes first: at the observation's last tile at the
+        latest."""
         w = self.window
         now, last_entry = w.clock(), w.entries[-1][1]
-        return (now - w.t_open) + (now - last_entry) >= (
-            w.seconds - float(self.traffic["profile_slice_s"]))
+        elapsed, t_last = now - w.t_open, now - last_entry
+        end = w.seconds
+        if left is not None:
+            end = min(end, elapsed + (left + 1) * t_last)
+        return elapsed + t_last >= end - float(
+            self.traffic["profile_slice_s"])
 
     def _stop_profile(self) -> None:
         """At the drain, or where the mix says ``profile_tiles`` at the
@@ -311,8 +322,6 @@ def main(argv=None) -> int:
         "attempted": outcome["attempted"], "failed": outcome["failed"],
         "metrics": metrics, "device": device_block(run, devices),
         "workload": cell.name, "seed": run.seed,
-        "checks": {c.name: {"value": c.value, "limit": c.limit}
-                   for c in checks},
     }
     if args.precision != "highest":
         result["control"] = f"matmul precision {args.precision}"
@@ -320,8 +329,15 @@ def main(argv=None) -> int:
         result["breakdown"] = {
             "device_ops": run.profile["device_ops"][:10],
             "idle_gaps": run.profile["idle_gaps"][:10]}
+    # each number compared beside its limit: last in the line, and the
+    # last lines on standard error
+    result["checks"] = {c.name: {"value": c.value, "limit": c.limit}
+                        for c in checks}
     print(clock.line())
     print(json.dumps(result))
+    sys.stdout.flush()
+    for c in checks:
+        print(c.line(note=False), file=sys.stderr)
     return 0
 
 

@@ -12,6 +12,8 @@ timed path broken underneath the harness (every answer, one early answer
 of many, one answer not finite, a solver that returns its state).
 """
 
+import collections
+import concurrent.futures
 import glob
 import json
 import math
@@ -383,7 +385,15 @@ def run_cell(capsys, workload, seconds="0.5"):
                       "--seed", str(2 ** 31 + 5), "--seconds", seconds,
                       "--trace", "0", "--allow-cpu"])
     assert rc == 0
-    return json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    cap = capsys.readouterr()
+    line = json.loads(cap.out.strip().splitlines()[-1])
+    # each number compared beside its limit: the line's last key, and
+    # the last lines on standard error
+    assert list(line)[-1] == "checks"
+    said = cap.err.strip().splitlines()[-len(line["checks"]):]
+    assert [ln.split()[:2] for ln in said] == [
+        ["[check]", name] for name in line["checks"]]
+    return line
 
 
 def test_sound_tiny_cells_are_correct(capsys):
@@ -481,6 +491,76 @@ def test_a_cycle_that_is_not_finite_counts_as_failed(capsys, monkeypatch):
                         jit_cached)
     line = run_cell(capsys, "predict-tiny")
     assert line["failed"] == 1 and line["correct"] is False
+
+
+class Overlapped:
+    """The predict cell's dataset as a loop with overlap drives it, the
+    program's own loop underneath: ``tiles()`` has read ``ahead`` tiles
+    beyond the one it hands out, and with ``threaded`` the writes run on
+    a thread of their own, in the order they were handed in."""
+
+    def __init__(self, ms, ahead, threaded):
+        self._ms, self._ahead = ms, ahead
+        self._writer = concurrent.futures.ThreadPoolExecutor(1) \
+            if threaded else None
+        self._writes = []
+
+    def __getattr__(self, name):
+        return getattr(self._ms, name)
+
+    def tiles(self):
+        read = collections.deque()
+        for item in self._ms.tiles():
+            read.append(item)
+            if len(read) > self._ahead:
+                yield read.popleft()
+        yield from read
+
+    def write_tile(self, i, tile, column=None):
+        if self._writer is None:
+            return self._ms.write_tile(i, tile, column)
+        self._writes.append(
+            self._writer.submit(self._ms.write_tile, i, tile, column))
+
+    def close(self):
+        if self._writer is not None:
+            self._writer.shutdown(wait=True)
+        for w in self._writes:
+            w.result()              # a write that raised raises here
+
+
+@pytest.mark.parametrize("threaded", [False, True],
+                         ids=["two-ahead", "second-thread"])
+def test_a_loop_that_reads_ahead_of_its_writes_is_correct(
+        capsys, monkeypatch, threaded):
+    """Predict with every line of the program right and its loop
+    overlapped: tile k + 2 is read before tile k is written (and the
+    write may come from another thread).  The kept rows follow the tile
+    that is written; filed by the loop's position, as they were, every
+    cycle's rows sat under the wrong key and this run was not correct."""
+    from sagecal_tpu import pipeline
+    real = pipeline.FullBatchPipeline.run_simulation
+    seen = []
+
+    def overlapped(self, log=print):
+        inner = self.ms
+        self.ms = Overlapped(inner, 2, threaded)
+        try:
+            return real(self, log=log)
+        finally:
+            self.ms.close()
+            seen.append(type(inner))
+            self.ms = inner
+
+    monkeypatch.setattr(pipeline.FullBatchPipeline, "run_simulation",
+                        overlapped)
+    line = run_cell(capsys, "predict-tiny")
+    assert line["correct"] is True and line["failed"] == 0, line
+    assert line["attempted"] >= 12
+    # every cycle read was written, each under its own key
+    (ms,) = seen
+    assert not ms.out
+    assert sorted(ms.kept) == list(range(5, 5 + line["attempted"]))
 
 
 def test_without_a_chip_and_without_allow_cpu_it_fails(capsys):

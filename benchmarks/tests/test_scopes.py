@@ -474,12 +474,15 @@ def traced_run(monkeypatch, seconds, **keys):
     return run, now, calls
 
 
-def drive(run, now, cycle, drain_s=0.5):
+def drive(run, now, cycle, drain_s=0.5, n_tiles=None):
     """What a driver does: ask ``due()`` at each boundary, enter the
-    tile, let ``cycle`` seconds pass; then drain."""
+    tile, let ``cycle`` seconds pass; then drain.  One whose observation
+    has ``n_tiles`` window tiles ends with them, and says at each
+    boundary how many are left."""
     k = 0
-    while not run.window.due():
-        run.enter_tile(k, 100)
+    while k != n_tiles and not run.window.due():
+        run.enter_tile(k, 100,
+                       left=None if n_tiles is None else n_tiles - 1 - k)
         now[0] += cycle
         k += 1
     now[0] += drain_s
@@ -525,6 +528,58 @@ def test_profile_tiles_stops_at_the_boundary_that_many_tiles_on(monkeypatch):
     assert run.slice_tiles == 2
     # the clock's phases still add up to the wall
     assert sum(run.clock.phases.values()) == pytest.approx(run.clock.total())
+
+
+@pytest.mark.parametrize(
+    "cycle, n_tiles, keys, start, stop, slice_tiles", [
+        # cal-t120's shape at 5.3 s a tile: on at the fourth window
+        # tile's boundary, two whole tiles, off at the drain
+        (5.3, 5, {}, 3 * 5.3, 5 * 5.3 + 0.5, 2),
+        # admm-f4-mesh's at 1.68 s: on at the 19th interval, off one on
+        (1.68, 22, {"profile_slice_s": 6.6, "profile_tiles": 1},
+         18 * 1.68, 19 * 1.68, 1),
+        # cal-m8x3's at 0.55 s: on at 25.3 s of a 33.55 s window
+        (0.55, 61, {}, 46 * 0.55, 61 * 0.55 + 0.5, 15),
+        # two tiles: the last one, whatever the slice asks for
+        (30.0, 2, {}, 30.0, 60.5, 1),
+    ], ids=["cal-t120", "admm-f4-mesh", "cal-m8x3", "two-tiles"])
+def test_a_window_the_observation_ends_has_its_last_tiles_profiled(
+        monkeypatch, cycle, n_tiles, keys, start, stop, slice_tiles):
+    """The solver cells once a tile is fast: the observation's window
+    tiles are over before ``seconds - profile_slice_s`` (51 s less 8 or
+    6.6), so the parent's rule records no ``start`` call at all in any
+    of these shapes and the traced run has no profile.  With ``left``
+    the profiler starts against the observation's projected end."""
+    run, now, calls = traced_run(monkeypatch, 51.0, **keys)
+    assert drive(run, now, cycle, n_tiles=n_tiles) == n_tiles
+    assert run.window.t_due is None         # the observation ended it
+    assert calls == [("start", pytest.approx(1000 + start)),
+                     ("stop", pytest.approx(1000 + stop))]
+    assert run.slice_tiles == slice_tiles
+    assert ("stop_trace_in_window_s" in run.clock.notes) \
+        == ("profile_tiles" in keys)
+    assert run.clock.notes["tiles"] == n_tiles
+    assert sum(run.clock.phases.values()) == pytest.approx(run.clock.total())
+
+
+def test_left_changes_nothing_where_the_window_ends_on_its_seconds(
+        monkeypatch):
+    """Today's cells: 61 tiles of 6.45 s (or of 2.9 s) are far past
+    51 s, so the projected end is ``seconds`` and the profiler starts
+    and stops where it does without ``left``."""
+    run, now, calls = traced_run(monkeypatch, 51.0)
+    assert drive(run, now, 6.45, n_tiles=61) == 8
+    assert calls == [("start", pytest.approx(1038.7)),
+                     ("stop", pytest.approx(1052.1))]
+    assert run.slice_tiles == 2
+    run, now, calls = traced_run(monkeypatch, 51.0)
+    assert drive(run, now, 2.9, n_tiles=61) == 18
+    assert calls[0] == ("start", pytest.approx(1000 + 14 * 2.9))
+    assert run.slice_tiles == 4
+    # a window of one tile has no boundary inside it to start at
+    run, now, calls = traced_run(monkeypatch, 51.0)
+    assert drive(run, now, 6.45, n_tiles=1) == 1
+    assert calls == [] and run.slice_tiles == 0
 
 
 def test_profile_tiles_is_refused_where_the_boundary_lies_in_a_span(
@@ -628,23 +683,33 @@ def test_new_metrics_are_appended_and_found_by_name():
             m["name"], m["unit"], m["layer"], m["moves"])
 
 
-@pytest.mark.parametrize("workload, metrics, tables", [
-    ("predict-tiny", ("phasor_dev_ms", "corrupt_dev_ms", "bubble_ms.predict",
-                      "recompiles_in_window", "compile_s.setup"),
+CAL_TINY = (("sweep_dev_s", "refine_dev_s", "solve_ops_per_tile",
+             "refine_passes", "recompiles_in_window", "compile_s.setup"),
+            ("[scope] sage/sweep", "[scope] sage/refine", "sage/sweep/inner",
+             "sage/refine/linesearch", "sage/refine/restrict",
+             "[compile] set-up"))
+
+
+@pytest.mark.parametrize("workload, seconds, metrics, tables", [
+    ("predict-tiny", "5",
+     ("phasor_dev_ms", "corrupt_dev_ms", "bubble_ms.predict",
+      "recompiles_in_window", "compile_s.setup"),
      ("[scope] rime/phasor", "[span] sagecal/write", "[span] sagecal/fetch",
       "[compile] set-up")),
-    ("cal-tiny", ("sweep_dev_s", "refine_dev_s", "solve_ops_per_tile",
-                  "refine_passes", "recompiles_in_window", "compile_s.setup"),
-     ("[scope] sage/sweep", "[scope] sage/refine", "sage/sweep/inner",
-      "sage/refine/linesearch", "sage/refine/restrict", "[compile] set-up")),
-])
-def test_tiny_cell_traced_end_to_end(workload, metrics, tables):
+    ("cal-tiny", "5", *CAL_TINY),
+    # far beyond the observation's nine window tiles AND its
+    # profile_slice_s: the window ends with the observation.  On the
+    # parent of PR 35 this run printed "no profiler trace in this run"
+    # and had no busy_s, window_s, breakdown or device_idle_pct
+    ("cal-tiny", "600", *CAL_TINY),
+], ids=["predict-tiny", "cal-tiny", "cal-tiny-beyond-its-observation"])
+def test_tiny_cell_traced_end_to_end(workload, seconds, metrics, tables):
     """A child, as the driver runs it: the profiler and the program's
     tracer are the process's own."""
     out = subprocess.run(
         [sys.executable, os.path.join(BENCH, "run.py"), "--cells", CELLS,
          "--workload", workload, "--seed", str(2 ** 31 + 26),
-         "--seconds", "5", "--trace", "1", "--allow-cpu"],
+         "--seconds", seconds, "--trace", "1", "--allow-cpu"],
         capture_output=True, text=True, timeout=900, cwd=ROOT,
         env=dict(os.environ, JAX_PLATFORMS="cpu"))
     assert out.returncode == 0, out.stderr[-2000:]
@@ -661,6 +726,15 @@ def test_tiny_cell_traced_end_to_end(workload, metrics, tables):
     assert m["recompiles_in_window"]["value"] == 0 \
         == m["compiles_in_window"]["value"]
     assert m["compile_s.setup"]["value"] > 0
+    # the profile: whole tiles from the end of the window, wherever the
+    # window ended
+    assert "no profiler trace in this run" not in out.stdout
+    dev = line["device"]
+    assert 0 < dev["busy_s"] < dev["window_s"] < float(seconds)
+    assert line["breakdown"]["device_ops"] and line["breakdown"]["idle_gaps"]
+    assert 0 <= m["device_idle_pct"]["value"] < 100
+    if seconds == "600":
+        assert line["attempted"] == 9
     # the line before the result line: every phase of the run, adding up
     clock = out.stdout.strip().splitlines()[-2]
     assert clock.startswith("[clock] backend ")

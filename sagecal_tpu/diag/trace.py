@@ -48,7 +48,9 @@ event            meaning / required extra fields
                  bodies), ``row_passes`` (RTR's evaluations of the
                  row model: solvers/rtr.py), ``lbfgs_iters``,
                  ``refine_passes`` (passes through the model the joint
-                 refine made: solvers/lbfgs.py), ``plan`` and
+                 refine made: solvers/lbfgs.py), ``refine_rows`` (the
+                 row layout those passes worked on, "periodic" or
+                 "flat": solvers/sage.py), ``plan`` and
                  ``solve_dispatches`` (what sagefit_host's last sweep
                  executed, "promoted", "fused" or "per_cluster", and
                  the device executions the solve issued), ``minutes``,

@@ -91,17 +91,39 @@ def row_grad(g8, a8, bm8):
     return _planes_mm(g8, a8, adj_b=True), _planes_mm(g8, bm8, adj_a=True)
 
 
+def row_tangent(dp8, dq8, a8, bm8):
+    """dV = dJ_p A + Bm dJ_q^H on planes: the row model's derivative
+    along a change (dJ_p, dJ_q) of its two stations' Jones, from the
+    Wirtinger factors :func:`row_model` returned. Exact: V is bilinear
+    in (J_p, conj J_q)."""
+    return _planes_mm(dp8, a8) + _planes_mm(bm8, dq8, adj_b=True)
+
+
+def periodic_rows(kmax: int, row_period: int, B: int) -> bool:
+    """Whether ``B`` rows of clusters with ``kmax`` chunks each lie
+    ``[tilesz, row_period]`` with the stations repeating every
+    ``row_period`` rows (what :class:`RowPlanes` decides its layout
+    by)."""
+    return kmax == 1 and row_period > 0 and B % row_period == 0
+
+
 def _take_planes(P, idx):
-    """Station planes P [K, N, 8], flat station indices -> [8, len(idx)]."""
+    """Station planes P [K, N, 8], flat station indices -> [8, *idx.shape]."""
     return jnp.take(P.reshape(-1, 8).T, idx, axis=1)
 
 
 class RowPlanes:
-    """One cluster's row data as real planes, the rows on the minor axes,
-    with the gather from and the segment sum to the stations.
+    """Row data as real planes, the rows on the minor axes, with the
+    gather from and the segment sum to the stations: one cluster's
+    (``coh [B, 2, 2]``, ``chunk_id [B]``: :func:`rtr.make_row_pass`) or
+    all clusters' at once (``coh [M, B, 2, 2]``, ``chunk_id [M, B]``: the
+    joint refine of ``solvers/sage.py``), the clusters then a leading
+    axis of every plane array but ``x`` and ``w`` and cluster ``m``'s
+    chunks the Jones slots ``m * kmax ..`` of ``M * kmax``.
 
     ``x``, ``w``: data and sqrt-weights ``[8, *rows]`` in their (storage)
-    dtype; ``c``: the coherency planes. With one chunk and a
+    dtype (None where none were given); ``c``: the coherency planes
+    ``[8, (M,) *rows]``. With one chunk and a
     ``row_period`` (rows laid out ``[tilesz, nbase]``, stations
     repeating every ``nbase``: the invariant of
     :func:`normal_equations`) ``rows`` is ``(tilesz, nbase)``: the
@@ -111,19 +133,25 @@ class RowPlanes:
 
     def __init__(self, x8, coh, wt, sta1, sta2, chunk_id, kmax: int,
                  n_stations: int, row_period: int = 0):
-        B = x8.shape[0]
-        self.kmax, self.n_stations, self.chunk_id = kmax, n_stations, chunk_id
-        self.periodic = kmax == 1 and row_period > 0 and B % row_period == 0
+        B, lead = coh.shape[-3], coh.shape[:-3]
+        self.periodic = periodic_rows(kmax, row_period, B)
         R = row_period if self.periodic else B
         self.rows = (B // R, R) if self.periodic else (B,)
-        self.i1 = (chunk_id * n_stations + sta1)[:R]
-        self.i2 = (chunk_id * n_stations + sta2)[:R]
-        self.x, self.w = self.planes(x8), self.planes(wt)
+        if lead:
+            chunk_id = chunk_id + kmax * jnp.arange(
+                lead[0], dtype=chunk_id.dtype)[:, None]
+            kmax *= lead[0]
+        self.kmax, self.n_stations, self.chunk_id = kmax, n_stations, chunk_id
+        self.i1 = (chunk_id * n_stations + sta1)[..., :R]
+        self.i2 = (chunk_id * n_stations + sta2)[..., :R]
+        self.x, self.w = (None if a is None else self.planes(a)
+                          for a in (x8, wt))
         self.c = self.planes(jones_c2r(coh))
 
     def planes(self, a):
-        """[B, 8] -> [8, *rows]."""
-        return jnp.moveaxis(a, -1, 0).reshape((8,) + self.rows)
+        """[(M,) B, 8] -> [8, (M,) *rows]."""
+        return jnp.moveaxis(a, -1, 0).reshape(
+            (8,) + a.shape[:-2] + self.rows)
 
     def to_rows(self, a):
         """[8, *rows] -> [B, 8]."""
@@ -132,30 +160,32 @@ class RowPlanes:
     def gather(self, P):
         """Station planes P [K, N, 8] -> (jp8, jq8) for :func:`row_model`."""
         jp, jq = _take_planes(P, self.i1), _take_planes(P, self.i2)
-        return (jp[:, None], jq[:, None]) if self.periodic else (jp, jq)
+        return ((jp[..., None, :], jq[..., None, :]) if self.periodic
+                else (jp, jq))
 
     def time_sum(self, a):
         """The part of :meth:`station_sum` that is elementwise with the
-        rows: [8, *rows] -> [8, R]."""
-        return jnp.sum(a, axis=1) if self.periodic else a
+        rows: [8, (M,) *rows] -> [8, (M,) R]."""
+        return jnp.sum(a, axis=-2) if self.periodic else a
 
     def station_sum(self, gp, gq):
-        """Per-row shares [8, R] of the first and of the second station
-        (after :meth:`time_sum`) -> [K, N, 8]."""
+        """Per-row shares [8, (M,) R] of the first and of the second
+        station (after :meth:`time_sum`) -> [K, N, 8]."""
         out = jnp.zeros((self.kmax * self.n_stations, 8), gp.dtype)
-        out = out.at[self.i1].add(gp.T).at[self.i2].add(gq.T)
+        out = (out.at[self.i1].add(jnp.moveaxis(gp, 0, -1))
+               .at[self.i2].add(jnp.moveaxis(gq, 0, -1)))
         return out.reshape(self.kmax, self.n_stations, 8)
 
     def chunk_sum(self, a):
-        """[8, *rows] -> per-chunk sums [K]."""
+        """One cluster's [8, *rows] -> per-chunk sums [K]."""
         if self.kmax == 1:
             return jnp.sum(a).reshape(1)
         return jax.ops.segment_sum(jnp.sum(a, axis=0), self.chunk_id,
                                    num_segments=self.kmax)
 
     def select(self, take, new, old):
-        """Rows of the chunks where ``take`` [K] holds from ``new``, the
-        others from ``old`` (both [8, *rows])."""
+        """One cluster's rows of the chunks where ``take`` [K] holds from
+        ``new``, the others from ``old`` (both [8, *rows])."""
         return jnp.where(take[0] if self.kmax == 1
                          else take[self.chunk_id], new, old)
 

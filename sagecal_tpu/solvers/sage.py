@@ -21,9 +21,10 @@ TPU re-architecture:
   (sequencing is algorithmic — SAGE needs it, SURVEY.md P2);
 - within a cluster all hybrid chunks solve simultaneously (batched LM,
   lm.py) instead of the reference's sequential chunk loop;
-- the joint refine cost/gradient come from autodiff of the Student's-t
-  (or Gaussian) objective instead of hand-written kernels
-  (robust_lbfgs.c:94-155).
+- the joint refine's cost, gradient and restriction to a search line are
+  written out on real planes with the rows on the minor axes
+  (:func:`_refine_cost_fn`; the Student's-t or Gaussian objective of
+  robust_lbfgs.c:94-155), all clusters in one elementwise expression.
 
 Two drivers share the same per-cluster update:
 - :func:`sagefit` — fully traced (one XLA program), used inside the mesh
@@ -108,13 +109,27 @@ def _call(name, jfn, *args, **kwargs):
     return jfn(*args, **kwargs)
 
 
-def _plan_info(info: dict, plan: str, n0: int) -> dict:
+#: what :func:`_plan_info` adds to a host-driven solve's info dict: host
+#: values (a str, an int), not arrays
+_PLAN_KEYS = ("plan", "solve_dispatches", "refine_rows")
+
+
+def _plan_info(info: dict, plan: str, n0: int, config, J0, x8) -> dict:
     """``info`` with what a host-driven solve ran: ``plan`` is what its
     LAST sweep executed ("promoted": the whole solve as one program,
     "fused": a program a sweep, "per_cluster": a program a cluster or
     group), ``solve_dispatches`` the device executions it issued through
-    :func:`_call` since ``n0``.  Host values: nothing is fetched."""
-    return {**info, "plan": plan, "solve_dispatches": _dispatched() - n0}
+    :func:`_call` since ``n0``, ``refine_rows`` (where a refine ran) the
+    row layout its model passes worked on, which the mechanism decides
+    from its input (``"periodic"``: ``[tilesz, nbase]`` planes, the
+    Jones gathered for ``nbase`` rows; ``"flat"``: ``[B]``), here from
+    the shapes of ``J0 [(T,) M, kmax, N, 2, 2]`` and ``x8 [(T,) B, 8]``.
+    Host values: nothing is fetched."""
+    out = {**info, "plan": plan, "solve_dispatches": _dispatched() - n0}
+    if config.max_lbfgs > 0:
+        out["refine_rows"] = ("periodic" if ne.periodic_rows(
+            J0.shape[-4], config.nbase, x8.shape[-2]) else "flat")
+    return out
 
 
 _LOG = logging.getLogger(__name__)
@@ -294,18 +309,28 @@ def _model8(J_m, coh_m, sta1, sta2, cidx_m, out_dtype=None):
     return rp.model8(coh_m, J_m, sta1, sta2, cidx_m, out_dtype=out_dtype)
 
 
-def full_model8(J, coh, sta1, sta2, chunk_idx):
-    """Sum of all clusters' corrupted models [B, 8] (minimize_viz_full_pth).
+def _joint_model(rows: ne.RowPlanes, P):
+    """The joint model on planes: ``(V, A, Bm)`` with ``V = sum_m J_p,m
+    C_m J_q,m^H`` as ``[8, *rows]`` and the clusters' Wirtinger factors
+    ``[8, M, *rows]``, from the stations' Jones planes ``P [M * kmax, N,
+    8]``. One elementwise expression with the clusters a leading axis
+    (:func:`normal_eq.row_model`), reduced over them."""
+    v, a, bm = ne.row_model(*rows.gather(P), rows.c)
+    return jnp.sum(v, axis=1), a, bm
+
+
+def full_model8(J, coh, sta1, sta2, chunk_idx, row_period: int = 0):
+    """Sum of all clusters' corrupted models [B, 8] (minimize_viz_full_pth):
+    the joint refine's model (:func:`_joint_model`), handed back as rows.
 
     The cluster sum ACCUMULATES in the model-eval dtype (f32 from c64)
     regardless of the storage policy — callers emit to storage at the
     residual subtraction (dtp.to_storage), not inside the sum."""
-    def body(acc, xs):
-        J_m, coh_m, cidx_m = xs
-        return acc + _model8(J_m, coh_m, sta1, sta2, cidx_m), None
-    init = jnp.zeros((coh.shape[1], 8), coh.real.dtype)
-    out, _ = jax.lax.scan(body, init, (J, coh, chunk_idx))
-    return out
+    M, kmax, n_stations = J.shape[:3]
+    rows = ne.RowPlanes(None, coh, None, sta1, sta2, chunk_idx, kmax,
+                        n_stations, row_period)
+    P = ne.jones_c2r(J).reshape(M * kmax, n_stations, 8)
+    return rows.to_rows(_joint_model(rows, P)[0])
 
 
 def _cluster_solve(mode: int, xdummy, coh_m, sta1, sta2, cidx_m, cmask_m,
@@ -778,26 +803,40 @@ def _cluster_perm(ci, nerr_prev, weighted, key, M: int,
     return jnp.where(weighted, perm_sort, perm_rand).astype(jnp.int32)
 
 
-def _refine_cost_fn(x8, coh, sta1, sta2, chunk_idx, wt_base, shape, M, kmax,
+def _refine_cost_fn(x8, coh, sta1, sta2, chunk_idx, wt_base, shape, kmax,
                     n_stations, robust: bool, mean_nu, mode: str = "full",
-                    Jref=None):
-    """The joint refine's cost and its restriction to a search line:
-    ``(cost_fn, line_func)``, the two closures ``lbfgs.lbfgs_fit`` takes.
+                    Jref=None, row_period: int = 0):
+    """The joint refine's cost, its gradient and its restriction to a
+    search line: ``(cost_fn, grad_fn, line_func)``, the three closures
+    ``lbfgs.lbfgs_fit`` takes. All three evaluate the model of all
+    clusters on real planes with the rows on the minor axes
+    (:func:`_joint_model`; the planes are made here, once a refine), the
+    gradient and the restriction written out from its Wirtinger factors:
+    no autodiff passes over a row.
 
     ``line_func`` is None where the parameters do not enter the Jones
     matrices linearly (``phase``: J = Jref exp(i theta)). Where they do
     (``full``, ``diag``) the model ``sum_m J_p C_m J_q^H`` is a
     homogeneous quadratic in them, so along ``p = xk + a pk`` every
     row's weighted residual is exactly ``r0 - a V1 - a^2 V2`` and a trial
-    step of the search is one elementwise pass over three [B, 8] arrays.
+    step of the search is one elementwise pass over three plane arrays.
     """
+    rows = ne.RowPlanes(x8, coh, wt_base, sta1, sta2, chunk_idx, kmax,
+                        n_stations, row_period)
+    # the model accumulates in the accumulation dtype (full_model8), and
+    # so do the residual and its weighting here, whatever x8 and wt_base
+    # are stored in
+    x, w = dtp.acc(rows.x), dtp.acc(rows.w)
+
     # mode != "full": ``shape`` is the reduced (M*kmax, N, npar) layout
     # and Jref [M*kmax, N, 2, 2] carries the constrained reference
     # point (amplitudes for the phase retraction J = Jref * exp(i θ))
-    def model(p):
-        Jr = ne.jones_from_params(p.reshape(shape), mode, Jref).reshape(
-            M, kmax, n_stations, 2, 2)
-        return full_model8(Jr, coh, sta1, sta2, chunk_idx)
+    def station_planes(p):
+        """p [D] -> the stations' Jones as real planes [M*kmax, N, 8]."""
+        if mode == "full":
+            return p.reshape(shape)
+        return ne.jones_c2r(ne.jones_from_params(p.reshape(shape), mode,
+                                                 Jref))
 
     def cost_of(r):
         if robust:
@@ -805,31 +844,43 @@ def _refine_cost_fn(x8, coh, sta1, sta2, chunk_idx, wt_base, shape, M, kmax,
         return jnp.sum(r * r)
 
     def cost_fn(p):
-        return cost_of((x8 - model(p)) * wt_base)
+        return cost_of((x - _joint_model(rows, station_planes(p))[0]) * w)
+
+    def grad_fn(p):
+        v, a, bm = _joint_model(rows, station_planes(p))
+        e = (x - v) * w
+        # G = w f'(e), the complex form of -dc/dV; a row's shares of
+        # dc/dJ_p and dc/dJ_q are -(G A^H) and -(G^H Bm)
+        g = w * (2.0 * e / (mean_nu + e * e) if robust else 2.0 * e)
+        gp, gq = ne.row_grad(g[:, None], a, bm)
+        gP = -rows.station_sum(rows.time_sum(gp), rows.time_sum(gq))
+        if mode == "full":
+            return gP.reshape(-1)
+        return jax.vjp(station_planes, p)[1](gP)[0]
 
     if mode == "phase":
-        return cost_fn, None
+        return cost_fn, grad_fn, None
 
     def line_func(xk, pk):
         with jax.named_scope("restrict"):
-            # V1 from the derivative itself: model(xk + pk) - model(xk)
-            # - model(pk) cancels in float32 when |pk| << |xk|
-            m0, dm = jax.jvp(model, (xk,), (pk,))
-            # the model accumulates in the accumulation dtype
-            # (full_model8), so the three arrays are in it whatever
-            # x8 and wt_base are stored in
-            r0 = (x8 - m0) * wt_base
-            v1 = dm * wt_base
-            v2 = model(pk) * wt_base
+            v0, a, bm = _joint_model(rows, station_planes(xk))
+            Pk = station_planes(pk)            # p -> J is linear
+            # V1 is the derivative itself, dV = P_p A + Bm P_q^H: exact,
+            # where model(xk + pk) - model(xk) - model(pk) cancels in
+            # float32 when |pk| << |xk|
+            dv = ne.row_tangent(*rows.gather(Pk), a, bm)
+            r0 = (x - v0) * w
+            v1 = jnp.sum(dv, axis=1) * w
+            v2 = _joint_model(rows, Pk)[0] * w
 
         def on_line(a):
             r = r0 - a * (v1 + a * v2)
             # d/da of cost_of(r(a)), r' = -(V1 + 2 a V2)
-            w = r / (mean_nu + r * r) if robust else r
-            return cost_of(r), -2.0 * jnp.sum(w * (v1 + 2.0 * a * v2))
+            fr = r / (mean_nu + r * r) if robust else r
+            return cost_of(r), -2.0 * jnp.sum(fr * (v1 + 2.0 * a * v2))
         return on_line
 
-    return cost_fn, line_func
+    return cost_fn, grad_fn, line_func
 
 
 def sagefit(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0, n_stations: int,
@@ -886,7 +937,8 @@ def sagefit(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0, n_stations: int,
     # solve is split by: metadata only, the programs are unchanged
     with jax.named_scope("sage/prelude"):
         xres0 = x8 - dtp.to_storage(
-            full_model8(J0, coh, sta1, sta2, chunk_idx), x8.dtype)
+            full_model8(J0, coh, sta1, sta2, chunk_idx, config.nbase),
+            x8.dtype)
         res_0 = jnp.linalg.norm(dtp.acc(xres0 * wt_base)) / n
 
     total_iter = M * config.max_iter
@@ -961,11 +1013,12 @@ def sagefit(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0, n_stations: int,
             else:
                 Jref = ne.jones_constrain(Jflat, mode)
                 p0 = ne.params_from_jones(Jref, mode).reshape(-1).astype(dtype)
-            cost_fn, line_fn = _refine_cost_fn(
-                x8, coh, sta1, sta2, chunk_idx, wt_base, shape, M, kmax,
-                n_stations, robust, mean_nu, mode=mode, Jref=Jref)
+            cost_fn, grad_fn, line_fn = _refine_cost_fn(
+                x8, coh, sta1, sta2, chunk_idx, wt_base, shape, kmax,
+                n_stations, robust, mean_nu, mode=mode, Jref=Jref,
+                row_period=config.nbase)
             p1, lbfgs_k, passes = lbfgs_mod.lbfgs_fit(
-                cost_fn, jax.grad(cost_fn), p0, itmax=config.max_lbfgs,
+                cost_fn, grad_fn, p0, itmax=config.max_lbfgs,
                 M=config.lbfgs_m, return_iters=True, line_func=line_fn)
             if mode == "full":
                 J = ne.jones_r2c(p1.reshape(shape)).reshape(
@@ -976,7 +1029,8 @@ def sagefit(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0, n_stations: int,
                                                        2, 2)
 
     with jax.named_scope("sage/final"):
-        xres_f = x8 - full_model8(J, coh, sta1, sta2, chunk_idx)
+        xres_f = x8 - full_model8(J, coh, sta1, sta2, chunk_idx,
+                                  config.nbase)
         res_1 = jnp.linalg.norm(dtp.acc(xres_f * wt_base)) / n
     return J, {"res_0": res_0, "res_1": res_1, "mean_nu": mean_nu,
                "nerr": nerr, "solver_iters": tk[0],
@@ -1069,11 +1123,11 @@ def _jit_em_sweep(J, xres, nuM, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
          jnp.zeros((N_TK,), jnp.int32)))
 
 
-@jax.jit
+@functools.partial(jax.jit, static_argnames=("row_period",))
 @jax.named_scope("sage/prelude")
-def _jit_prelude(x8, coh, sta1, sta2, chunk_idx, J0, wt_base):
+def _jit_prelude(x8, coh, sta1, sta2, chunk_idx, J0, wt_base, row_period=0):
     xres0 = x8 - dtp.to_storage(
-        full_model8(J0, coh, sta1, sta2, chunk_idx), x8.dtype)
+        full_model8(J0, coh, sta1, sta2, chunk_idx, row_period), x8.dtype)
     return xres0, jnp.linalg.norm(dtp.acc(xres0 * wt_base)) \
         / (x8.shape[0] * 8)
 
@@ -1095,11 +1149,12 @@ def _jit_refine(x8, coh, sta1, sta2, chunk_idx, J, wt_base, mean_nu,
         else:
             Jref = ne.jones_constrain(Jflat, mode)
             p0 = ne.params_from_jones(Jref, mode).reshape(-1).astype(dtype)
-        cost_fn, line_fn = _refine_cost_fn(
-            x8, coh, sta1, sta2, chunk_idx, wt_base, shape, M, kmax,
-            n_stations, robust, mean_nu, mode=mode, Jref=Jref)
+        cost_fn, grad_fn, line_fn = _refine_cost_fn(
+            x8, coh, sta1, sta2, chunk_idx, wt_base, shape, kmax,
+            n_stations, robust, mean_nu, mode=mode, Jref=Jref,
+            row_period=config.nbase)
         p1, k, passes = lbfgs_mod.lbfgs_fit(
-            cost_fn, jax.grad(cost_fn), p0, itmax=config.max_lbfgs,
+            cost_fn, grad_fn, p0, itmax=config.max_lbfgs,
             M=config.lbfgs_m, return_iters=True, line_func=line_fn)
         if mode == "full":
             Jn = ne.jones_r2c(p1.reshape(shape)).reshape(M, kmax, n_stations,
@@ -1109,17 +1164,17 @@ def _jit_refine(x8, coh, sta1, sta2, chunk_idx, J, wt_base, mean_nu,
                 M, kmax, n_stations, 2, 2)
     with jax.named_scope("sage/final"):
         res = jnp.linalg.norm(dtp.acc(
-            (x8 - full_model8(Jn, coh, sta1, sta2, chunk_idx))
+            (x8 - full_model8(Jn, coh, sta1, sta2, chunk_idx, config.nbase))
             * wt_base)) / (x8.shape[0] * 8)
     return Jn, res, k, passes
 
 
-@jax.jit
+@functools.partial(jax.jit, static_argnames=("row_period",))
 @jax.named_scope("sage/final")
-def _jit_res(x8, coh, sta1, sta2, chunk_idx, J, wt_base):
+def _jit_res(x8, coh, sta1, sta2, chunk_idx, J, wt_base, row_period=0):
     return jnp.linalg.norm(dtp.acc(
-        (x8 - full_model8(J, coh, sta1, sta2, chunk_idx)) * wt_base)) \
-        / (x8.shape[0] * 8)
+        (x8 - full_model8(J, coh, sta1, sta2, chunk_idx, row_period))
+        * wt_base)) / (x8.shape[0] * 8)
 
 
 @jax.jit
@@ -1206,9 +1261,9 @@ def sagefit_host(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
                         config._replace(fuse="auto", promote="auto"),
                         os_ids if os_id is not None else None,
                         os_nsub, key)
-        return J, _plan_info(info, "promoted", n0)
+        return J, _plan_info(info, "promoted", n0, config, J0, x8)
     xres, res_0 = _call("prelude", _jit_prelude, x8, coh, sta1, sta2,
-                        chunk_idx, J0, wt_base)
+                        chunk_idx, J0, wt_base, row_period=config.nbase)
     # the per-sweep/per-cluster programs DONATE their state carries
     # (J, xres, nerr_acc, nuM) so XLA reuses the buffers in place
     # instead of allocating fresh HBM every dispatch; the first sweep
@@ -1330,14 +1385,14 @@ def sagefit_host(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
             wt_base, mean_nu, n_stations, dev_config, robust)
     else:
         res_1 = _call("res", _jit_res, x8, coh, sta1, sta2, chunk_idx, J,
-                      wt_base)
+                      wt_base, row_period=config.nbase)
     return J, _plan_info(
         {"res_0": res_0, "res_1": res_1, "mean_nu": mean_nu,
          "nerr": nerr, "solver_iters": tk_total[0],
          "rejected_groups": tk_total[1], "cg_iters": tk_total[2],
          "row_passes": tk_total[3],
          "lbfgs_iters": lbfgs_k, "refine_passes": passes},
-        "fused" if ran_fused else "per_cluster", n0)
+        "fused" if ran_fused else "per_cluster", n0, config, J0, x8)
 
 
 # ---------------------------------------------------------------------------
@@ -1416,11 +1471,12 @@ def _jit_em_sweep_tiles(J, xres, nuM, x8, coh, sta1, sta2, chunk_idx,
                          perm)
 
 
-@jax.jit
-def _jit_prelude_tiles(x8, coh, sta1, sta2, chunk_idx, J0, wt_base):
+@functools.partial(jax.jit, static_argnames=("row_period",))
+def _jit_prelude_tiles(x8, coh, sta1, sta2, chunk_idx, J0, wt_base,
+                       row_period=0):
     return jax.vmap(
         lambda x8_t, coh_t, J0_t, wt_t: _jit_prelude.__wrapped__(
-            x8_t, coh_t, sta1, sta2, chunk_idx, J0_t, wt_t)
+            x8_t, coh_t, sta1, sta2, chunk_idx, J0_t, wt_t, row_period)
     )(x8, coh, J0, wt_base)
 
 
@@ -1436,11 +1492,11 @@ def _jit_refine_tiles(x8, coh, sta1, sta2, chunk_idx, J, wt_base, mean_nu,
     )(x8, coh, J, wt_base, mean_nu)
 
 
-@jax.jit
-def _jit_res_tiles(x8, coh, sta1, sta2, chunk_idx, J, wt_base):
+@functools.partial(jax.jit, static_argnames=("row_period",))
+def _jit_res_tiles(x8, coh, sta1, sta2, chunk_idx, J, wt_base, row_period=0):
     return jax.vmap(
         lambda x8_t, coh_t, J_t, wt_t: _jit_res.__wrapped__(
-            x8_t, coh_t, sta1, sta2, chunk_idx, J_t, wt_t)
+            x8_t, coh_t, sta1, sta2, chunk_idx, J_t, wt_t, row_period)
     )(x8, coh, J, wt_base)
 
 
@@ -1487,7 +1543,7 @@ def sagefit_host_tiles(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
                                  chunk_mask, J0[0], n_stations,
                                  wt_base[0], nu0=nu0, config=config,
                                  os_id=os_id, key=keys[0])
-        info = {k: v if k in ("plan", "solve_dispatches")
+        info = {k: v if k in _PLAN_KEYS
                 else jnp.asarray(v)[None] for k, v in info1.items()}
         return J1[None], info
     x8 = dtp.to_storage(x8, dtp.storage_dtype(config.dtype_policy,
@@ -1526,9 +1582,10 @@ def sagefit_host_tiles(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
                         config._replace(fuse="auto", promote="auto"),
                         os_ids if os_id is not None else None,
                         os_nsub, keys)
-        return J, _plan_info(info, "promoted", n0)
+        return J, _plan_info(info, "promoted", n0, config, J0, x8)
     xres, res_0 = _call("prelude_tiles", _jit_prelude_tiles, x8, coh,
-                        sta1, sta2, chunk_idx, J0, wt_base)
+                        sta1, sta2, chunk_idx, J0, wt_base,
+                        row_period=config.nbase)
     # donation guard: see sagefit_host — the sweep programs consume
     # their state-carry buffers in place
     J = J0.copy() if isinstance(J0, jax.Array) else J0
@@ -1636,7 +1693,7 @@ def sagefit_host_tiles(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
             chunk_idx, J, wt_base, mean_nu, n_stations, dev_config, robust)
     else:
         res_1 = _call("res_tiles", _jit_res_tiles, x8, coh, sta1, sta2,
-                      chunk_idx, J, wt_base)
+                      chunk_idx, J, wt_base, row_period=config.nbase)
     return J, _plan_info(
         {"res_0": res_0, "res_1": res_1, "mean_nu": mean_nu,
          "nerr": nerr, "solver_iters": tk_total[:, 0],
@@ -1644,7 +1701,7 @@ def sagefit_host_tiles(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
          "cg_iters": tk_total[:, 2],
          "row_passes": tk_total[:, 3],
          "lbfgs_iters": lbfgs_k, "refine_passes": passes},
-        "fused" if ran_fused else "per_cluster", n0)
+        "fused" if ran_fused else "per_cluster", n0, config, J0, x8)
 
 
 @functools.partial(jax.jit,
@@ -1725,13 +1782,14 @@ def bfgsfit(x8, coh, sta1, sta2, chunk_idx, J0, n_stations: int,
         Jref = Jflat0
         p0 = ne.params_from_jones(Jref, mode).reshape(-1).astype(dtype)
 
-    cost_fn, line_fn = _refine_cost_fn(
-        x8, coh, sta1, sta2, chunk_idx, wt_base, shape, M, kmax, n_stations,
-        robust, nu, mode=mode, Jref=Jref)
+    cost_fn, grad_fn, line_fn = _refine_cost_fn(
+        x8, coh, sta1, sta2, chunk_idx, wt_base, shape, kmax, n_stations,
+        robust, nu, mode=mode, Jref=Jref, row_period=config.nbase)
     res_0 = jnp.linalg.norm(dtp.acc(
-        (x8 - full_model8(J0, coh, sta1, sta2, chunk_idx)) * wt_base)) / n
+        (x8 - full_model8(J0, coh, sta1, sta2, chunk_idx, config.nbase))
+        * wt_base)) / n
     p1, k, passes = lbfgs_mod.lbfgs_fit(
-        cost_fn, jax.grad(cost_fn), p0, itmax=config.max_lbfgs,
+        cost_fn, grad_fn, p0, itmax=config.max_lbfgs,
         M=config.lbfgs_m, return_iters=True, line_func=line_fn)
     if mode == "full":
         J = ne.jones_r2c(p1.reshape(shape)).reshape(M, kmax, n_stations,
@@ -1740,6 +1798,7 @@ def bfgsfit(x8, coh, sta1, sta2, chunk_idx, J0, n_stations: int,
         J = ne.jones_from_params(p1.reshape(shape), mode, Jref).reshape(
             M, kmax, n_stations, 2, 2)
     res_1 = jnp.linalg.norm(dtp.acc(
-        (x8 - full_model8(J, coh, sta1, sta2, chunk_idx)) * wt_base)) / n
+        (x8 - full_model8(J, coh, sta1, sta2, chunk_idx, config.nbase))
+        * wt_base)) / n
     return J, {"res_0": res_0, "res_1": res_1, "lbfgs_iters": k,
                "refine_passes": passes}

@@ -58,9 +58,9 @@ def _closures(pb, robust, mode):
     kmax = pb["kmax"]
     shape = (M * kmax, N, ne.jones_npar(mode))
     Jref = ne.jones_constrain(pb["J0"].reshape(M * kmax, N, 2, 2), mode)
-    cost, line = sage._refine_cost_fn(
+    cost, _grad, line = sage._refine_cost_fn(
         pb["x8"], pb["coh"], pb["sta1"], pb["sta2"], pb["cidx"], pb["wt"],
-        shape, M, kmax, N, robust, 2.5, mode=mode,
+        shape, kmax, N, robust, 2.5, mode=mode,
         Jref=None if mode == "full" else Jref)
     p0 = ne.params_from_jones(Jref, mode).reshape(-1).astype(pb["x8"].dtype)
     return cost, line, p0

@@ -1031,28 +1031,31 @@ class FullBatchPipeline:
         """Simulation modes -a 1/2/3 (fullbatch_mode.cpp:524-578)."""
         cfg, ms, sky = self.cfg, self.ms, self.sky
         meta = ms.meta
-        J = None
+        mode = int(cfg.simulation)
         blocks_iter = None
         ignore_mask = None
         if cfg.solutions_file:
             _, blocks = sol.read_solutions(cfg.solutions_file, sky.nchunk)
             blocks_iter = blocks
-            if cfg.ignore_clusters_file:
-                ignore = skymodel.read_ignore_list(cfg.ignore_clusters_file)
-                ignore_mask = np.array(
-                    [int(cid) not in ignore for cid in sky.cluster_ids])
+        # -z is honoured without -p too (upstream reads the list only
+        # beside a solutions file: fullbatch_mode.cpp:524-578)
+        if cfg.ignore_clusters_file:
+            ignore = skymodel.read_ignore_list(cfg.ignore_clusters_file)
+            ignore_mask = np.array(
+                [int(cid) not in ignore for cid in sky.cluster_ids])
+        clusters_in_model = (sky.n_clusters if ignore_mask is None
+                             else int(ignore_mask.sum()))
 
         def sim_fn(x_r, u, v, w, sta1, sta2, J_r8, beam):
-            J = ne.jones_r2c(J_r8) if J_r8 is not None else None
-            out = rr.simulate_visibilities(
-                self.dsky, utils.r2c(x_r), u, v, w,
+            # pairs in and pairs out: rr.simulate_pairs says why
+            return rr.simulate_pairs(
+                self.dsky, x_r, u, v, w,
                 jnp.asarray(meta["freqs"], self.rdt),
                 meta["fdelta"] / len(meta["freqs"]), sta1, sta2,
-                mode=int(cfg.simulation), J=J,
+                mode=mode, J=None if J_r8 is None else ne.jones_r2c(J_r8),
                 chunk_idx=jnp.asarray(self.cidx), ignore_mask=ignore_mask,
                 beam=beam, dobeam=self.dobeam,
                 tslot=jnp.asarray(self.tslot))
-            return utils.c2r(out)
 
         # keyed through the process-wide program cache (serve/cache.py)
         # instead of the old per-instance lazy attribute: a second job
@@ -1062,7 +1065,7 @@ class FullBatchPipeline:
         # already covers sky/shape/dtype), so neither can happen
         self._sim_jit = self._jit_cached(
             "sim", lambda: jax.jit(sim_fn),
-            pcache.token(ignore_mask, int(cfg.simulation)))
+            pcache.token(ignore_mask, mode))
         sim_jit = self._sim_jit
         # a synchronous loop, in the calibrate path's vocabulary: the
         # phases io / stage / predict (a dispatch) / fetch (the wait
@@ -1097,8 +1100,9 @@ class FullBatchPipeline:
                 ms.write_tile(ti, tile)
             if dtrace.active():
                 dtrace.emit("tile", tile=ti, overlap=0,
-                            bubble_s=ph_io.dur_s + ph_write.dur_s)
-            log(f"Timeslot: {ti} simulated (mode={int(cfg.simulation)})")
+                            bubble_s=ph_io.dur_s + ph_write.dur_s,
+                            mode=mode, clusters_in_model=clusters_in_model)
+            log(f"Timeslot: {ti} simulated (mode={mode})")
 
 
 class _WarmTileProfile:

@@ -170,7 +170,9 @@ def simulate_visibilities(sky: rp.SkyArrays, x, u, v, w, freqs, fdelta_chan,
 
     ``J`` (optional) corrupts the model with solutions; ``ignore_mask`` [M]
     True = keep cluster in the simulated model (reference ignorelist holds
-    clusters to skip).
+    clusters to skip).  ``x`` [B, F, 2, 2] complex is not looked at in
+    mode 1 (it may be None): that call is the model itself, which
+    :func:`simulate_pairs` forms through here.
     """
     coh = rp.coherencies(sky, u, v, w, freqs, fdelta_chan,
                          per_channel_flux=True, beam=beam, dobeam=dobeam,
@@ -197,3 +199,38 @@ def simulate_visibilities(sky: rp.SkyArrays, x, u, v, w, freqs, fdelta_chan,
             out = correct_by_cluster(out, J[correct_idx], sta1, sta2,
                                      chunk_idx[correct_idx], rho)
     return out
+
+
+def simulate_pairs(sky: rp.SkyArrays, x_r, u, v, w, freqs, fdelta_chan,
+                   sta1, sta2, mode: int, J=None, chunk_idx=None,
+                   ignore_mask=None, correct_idx: int | None = None,
+                   rho: float = 1e-9,
+                   beam=None, dobeam: int = 0, tslot=None):
+    """:func:`simulate_visibilities` in the form the jit boundary uses:
+    the input column in and the simulated one out as stacked real pairs
+    [B, F, 2, 2, 2].
+
+    The model is formed complex, as everywhere, and its two parts are
+    stacked once; the add and the subtract then run on the pairs
+    themselves, which is what the complex ones do part by part.  No
+    complex ``x`` is made from slices of ``x_r``'s minor axis: with the
+    restack of the result on that same axis XLA:TPU turns that into an
+    unaligned in-place update of ``x_r`` and aborts the process in modes
+    2 and 3 (:func:`calculate_residuals_pairs` says more).  A correction
+    by a cluster needs the complex residual and keeps that path."""
+    from sagecal_tpu import utils
+    kw = dict(J=J, chunk_idx=chunk_idx, ignore_mask=ignore_mask, beam=beam,
+              dobeam=dobeam, tslot=tslot)
+    if correct_idx is not None and J is not None:
+        return utils.c2r(simulate_visibilities(
+            sky, utils.r2c(x_r), u, v, w, freqs, fdelta_chan, sta1, sta2,
+            mode, correct_idx=correct_idx, rho=rho, **kw))
+    model = simulate_visibilities(sky, None, u, v, w, freqs, fdelta_chan,
+                                  sta1, sta2, 1, **kw)
+    with jax.named_scope("rime/residual"):
+        m_r = jnp.stack([model.real, model.imag], axis=-1)
+        if mode == 2:       # SIMUL_ADD
+            return x_r + m_r
+        if mode == 3:       # SIMUL_SUB
+            return x_r - m_r
+        return m_r          # SIMUL_ONLY

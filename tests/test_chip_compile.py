@@ -177,6 +177,52 @@ def test_residual_program_compiles(one_chip):
     _lower_residual_program(one_chip, B).compile()
 
 
+def _lower_simulate_program(one_chip, tilesz, mode):
+    """``run_simulation``'s ``sim_fn`` as the pipeline builds it for
+    ``-a <mode> -p -z`` on an 8 x 128 sky: pairs in and out, the tile's
+    Jones as [M, K, N, 8], no beam, and the chunk map, the timeslots,
+    the channel and the ignore mask (one cluster, the target, left out)
+    closed over as constants."""
+    from problems import make_sky
+    from sagecal_tpu.io import dataset as ds
+    from sagecal_tpu.rime import predict as rp, residual as rr
+    from sagecal_tpu.solvers import normal_eq as ne
+    sky = make_sky(M, srcs_per_cluster=128)
+    dsky = rp.sky_to_device(sky, jnp.float32)
+    rows = NB * tilesz
+    cidx = rp.chunk_indices(tilesz, NB, sky.nchunk)
+    tslot = ds.row_tslot(rows, NB)
+    ignore_mask = np.arange(M) != 0
+    sd = _spec(one_chip)
+    f32, i32 = jnp.float32, jnp.int32
+
+    def sim_fn(x_r, u, v, w, sta1, sta2, J_r8, beam):
+        return rr.simulate_pairs(
+            dsky, x_r, u, v, w, jnp.asarray([150e6], f32), 0.18e6, sta1,
+            sta2, mode=mode, J=ne.jones_r2c(J_r8),
+            chunk_idx=jnp.asarray(cidx), ignore_mask=ignore_mask,
+            beam=beam, dobeam=0, tslot=jnp.asarray(tslot))
+
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(sim_fn).lower(
+            sd((rows, 1, 2, 2, 2), f32), sd((rows,), f32), sd((rows,), f32),
+            sd((rows,), f32), sd((rows,), i32), sd((rows,), i32),
+            sd((M, 1, N, 8), f32), None)
+
+
+@pytest.mark.parametrize("mode", [1, 2, 3])
+def test_simulate_program_compiles(one_chip, mode):
+    """The simulation modes' program (``pipeline.run_simulation``:
+    ``-a`` 1 replace, 2 add, 3 subtract, under a solutions file).  While
+    ``sim_fn`` made a complex ``x`` of the pairs and restacked the
+    result, modes 2 and 3 aborted the TPU compiler after half a minute
+    (``Check failed: fusion_util::IsFusibleUnalignedDUS``, exit 134;
+    mode 1 never reads ``x``); a CHECK failure there kills this worker,
+    which is the test failing."""
+    text = _lower_simulate_program(one_chip, TILESZ, mode).compile().as_text()
+    assert "rime/residual" in text      # the scope subtract_dev_ms reads
+
+
 # -- the production solve interval: -t 120, 226 920 rows a tile --------------
 
 TILESZ_120 = 120
@@ -228,6 +274,8 @@ def _need_120(one_chip, name):
                 flag, sd(key.shape, key.dtype), None,
                 sd(np.shape(os_ids), i32), N, cfg0, M * cfg0.max_iter, 2,
                 os_nsub)
+        elif name == "simulate":
+            lowered = _lower_simulate_program(one_chip, TILESZ_120, 3)
         else:
             lowered = _lower_residual_program(one_chip, rows)
         mem = lowered.compile().memory_analysis()
@@ -236,14 +284,17 @@ def _need_120(one_chip, name):
 
 
 @pytest.mark.parametrize("program", ["sagefit", "refine", "cluster_update",
-                                     "residual"])
+                                     "residual", "simulate"])
 def test_production_tile_fits(one_chip, program):
     """``-t 120`` (upstream's default solve interval: 226 920 rows a
     tile at N 62) compiles for the described v5e and fits the 15.75 GiB
     a chip reports as its own: the promoted whole-solve program (what a
     warm ``cal-t120`` tile runs), the host-driven plan's joint refine
-    and per-cluster update (tile 0's first sweep), and the residual
-    program.  Argument + output + temp as compiled here at PR 36 (PR 34's
+    and per-cluster update (tile 0's first sweep), the residual
+    program, and the simulation modes' (``-a 3 -p -z`` over 8 x 128
+    sources, PR 37: no cell runs it at this size, because the reference
+    takes 53 s to make one such tile's sky).  Argument + output + temp
+    as compiled here at PR 36 (``simulate`` at PR 37; PR 34's
     beside them, with the temporaries the chip's own compile asked for
     then: PERF.md section 5):
 
@@ -254,6 +305,7 @@ def test_production_tile_fits(one_chip, program):
     refine           0.42 GiB    13.55 GiB    13.47 GiB
     cluster_update   7.02 GiB     7.02 GiB     not read
     residual         2.30 GiB     2.30 GiB     2.27 GiB
+    simulate         2.29 GiB     aborts       not run
     ==============  ===========  ===========  =======================
 
     Arguments are 0.076 GiB.  Until PR 36 nearly all of the solve was

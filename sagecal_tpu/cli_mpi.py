@@ -541,6 +541,11 @@ class ConsensusStepper:
                 dtype_policy=getattr(args, "dtype_policy", "f32")))
 
         t0 = self.t0 = mss[0].read_tile(0)
+        # host value for the interval's tile record: the row layout the
+        # J updates assemble their Gauss-Newton matrix from
+        self.assemble_rows = sage.assemble_rows(
+            cfg.sage._replace(nbase=int(meta0["nbase"])), kmax,
+            len(t0.sta1))
         plans = [nm for nm, on in (("--block-f", args.block_f),
                                    ("--host-loop", args.host_loop),
                                    ("--time-shard", args.time_shard > 1),
@@ -1072,7 +1077,9 @@ class ConsensusStepper:
                         res_1=rec["res_1"], primal=primal,
                         rho_mean=float(np.asarray(self._fetch(rhoF))[:nf]
                                        .mean()),
-                        bubble_s=float(bubble), overlap=self.depth)
+                        bubble_s=float(bubble), overlap=self.depth,
+                        **({} if self.assemble_rows is None else
+                           {"assemble_rows": self.assemble_rows}))
         self._last = ti
         return rec
 

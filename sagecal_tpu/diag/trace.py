@@ -50,7 +50,10 @@ event            meaning / required extra fields
                  ``refine_passes`` (passes through the model the joint
                  refine made: solvers/lbfgs.py), ``refine_rows`` (the
                  row layout those passes worked on, "periodic" or
-                 "flat": solvers/sage.py), ``plan`` and
+                 "flat": solvers/sage.py), ``assemble_rows`` (the
+                 row layout the sweeps' Gauss-Newton matrix was
+                 assembled from, "periodic" or "generic":
+                 sage.assemble_rows), ``plan`` and
                  ``solve_dispatches`` (what sagefit_host's last sweep
                  executed, "promoted", "fused" or "per_cluster", and
                  the device executions the solve issued), ``minutes``,

@@ -4,14 +4,16 @@ The measurement model per baseline b=(p,q) is V_b = J_p C_b J_q^H with one
 2x2 complex Jones per station. The reference evaluates derivative kernels
 per 8-parameter station blocks (mderiv.cu:30 ``kernel_deriv``; CPU
 ``mylm_jac_single_pth`` lmfit.c); here the same closed forms are assembled
-as batched einsums + scatter-adds into block-sparse normal equations,
-no per-parameter loops. The Gram assemblies (:func:`normal_equations`,
-:func:`gn_factors`) are contractions and run on the matrix unit; the row
-MODEL itself, V and its two Wirtinger factors, is sixteen complex
+into block-sparse normal equations, no per-parameter loops. The row
+MODEL, V and its two Wirtinger factors, is sixteen complex
 multiply-adds a row and is written out as real elementwise arithmetic
 on planes with the rows on the minor axis (:func:`row_model`,
 :class:`RowPlanes`): a 2 x 2 product fed to a 128 x 128 systolic array
-at f32 ``highest`` costs a hundred times its arithmetic.
+at f32 ``highest`` costs a hundred times its arithmetic. So is the
+Gauss-Newton matrix of rows with a period (:func:`plane_equations`:
+a baseline's Gram blocks are 4 x 4, as small); the generic assembly
+of :func:`normal_equations`, :func:`gn_factors` and the constrained
+modes' are batched einsums + scatter-adds over ``[B, 2, 2, 4]`` factors.
 
 Derivatives (Wirtinger):
   with A = C_b J_q^H:  dV/d(J_p)_{cd}       = e_c e_d^T A   (complex-linear)
@@ -29,6 +31,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from sagecal_tpu import dtypes as dtp
 
@@ -169,12 +172,14 @@ class RowPlanes:
         return jnp.sum(a, axis=-2) if self.periodic else a
 
     def station_sum(self, gp, gq):
-        """Per-row shares [8, (M,) R] of the first and of the second
-        station (after :meth:`time_sum`) -> [K, N, 8]."""
-        out = jnp.zeros((self.kmax * self.n_stations, 8), gp.dtype)
+        """Per-row shares [W, (M,) R] of the first and of the second
+        station (after :meth:`time_sum`) -> [K, N, W] (W = 8 for a
+        gradient's planes)."""
+        W = gp.shape[0]
+        out = jnp.zeros((self.kmax * self.n_stations, W), gp.dtype)
         out = (out.at[self.i1].add(jnp.moveaxis(gp, 0, -1))
                .at[self.i2].add(jnp.moveaxis(gq, 0, -1)))
-        return out.reshape(self.kmax, self.n_stations, 8)
+        return out.reshape(self.kmax, self.n_stations, W)
 
     def chunk_sum(self, a):
         """One cluster's [8, *rows] -> per-chunk sums [K]."""
@@ -430,9 +435,9 @@ def _normal_equations_reduced(x8, J, coh, sta1, sta2, chunk_id, wt,
       f32 dot plus converts) — the weighted Gram operands are
       materialized DIRECTLY in f32, in a baseline-major batch layout
       whose dots read each operand exactly once. That re-lay is free to
-      differ from the f32 path's contraction order: the reduced policy
+      differ from the f32 path's summation order: the reduced policy
       is trajectory-tolerance-gated (MIGRATION.md "Dtype policy"), not
-      bit-gated, while the f32 path above stays byte- and bit-frozen;
+      bit-gated;
     - the JTe gradient rides the Gram as a 5th column (one dot yields
       pp AND jtep), and the station-pair cross blocks scatter straight
       into the FINAL [K, N, 8, N, 8] layout (symmetrized by a second
@@ -511,6 +516,130 @@ def _normal_equations_reduced(x8, J, coh, sta1, sta2, chunk_id, wt,
     return JTJ, JTe.reshape(kmax, 8 * N), cost
 
 
+def _ma_entry(a8, o: int, ri: int, i: int):
+    """:func:`_ma_factor`'s MA[o, ri, i = (d, ci)] as (plane of ``a8``,
+    sign): the (Re, Im) rows (Ar, -Ai) / (Ai, Ar) of A[d, o]."""
+    d, ci = divmod(i, 2)
+    return a8[2 * (2 * d + o) + (ri ^ ci)], -1 if (ri, ci) == (0, 1) else 1
+
+
+def _mb_entry(bm8, a: int, ri: int, j: int):
+    """:func:`_mb_factor`'s MB[a, ri, j = (d, ci)] as (plane of ``bm8``,
+    sign): the (Re, Im) rows (Br, Bi) / (Bi, -Br) of Bm[a, d]."""
+    d, ci = divmod(j, 2)
+    return bm8[2 * (2 * a + d) + (ri ^ ci)], -1 if (ri, ci) == (1, 1) else 1
+
+
+def _signed_sum(terms):
+    """Sum of (sign, plane) terms without a negation per term."""
+    pos = [t for sg, t in terms if sg > 0]
+    neg = [t for sg, t in terms if sg < 0]
+    if not neg:
+        return sum(pos[1:], pos[0])
+    return sum(pos[1:], pos[0]) - sum(neg[1:], neg[0]) if pos \
+        else -sum(neg[1:], neg[0])
+
+
+#: the ten (i, j), i <= j, of a symmetric 4 x 4 block in the order
+#: :func:`_gram_planes` emits them, and each (i, j)'s place among them
+_TRI = [(i, j) for i in range(4) for j in range(i, 4)]
+_TRI_OF = np.array([[_TRI.index((min(i, j), max(i, j))) for j in range(4)]
+                    for i in range(4)])
+
+
+def _gram_planes(w2, a8, bm8, tsum):
+    """A baseline's Gram blocks from planes: (pp [2 * 10, R], qq
+    [2 * 10, R], pq [64, R]), each entry ``tsum`` (the sum over time) of
+    a sum over (o, ri) (pp), (a, ri) (qq) or ri (pq) of products
+    ``w2 * MA * MA``, ``w2 * MB * MB``, ``w2 * MA * MB`` of the squared
+    sqrt-weight planes ``w2`` (index 2 (2 a + o) + ri) with the entries
+    of :func:`_ma_factor` and :func:`_mb_factor`, read off the Wirtinger
+    factors' planes ``a8``, ``bm8`` with their signs. pp[a] and qq[o]
+    are symmetric: their ten entries ``_TRI``; pq is ordered
+    (a, i, o, j). A weighted factor that several entries share is
+    written once per entry and left to the compiler's
+    common-subexpression pass."""
+    def entry(terms):
+        # terms: (a, o, ri, (plane, sign) of one factor's column, the
+        # same of the other's)
+        return tsum(_signed_sum(
+            [(si * sj, w2[2 * (2 * a + o) + ri] * pi * pj)
+             for a, o, ri, (pi, si), (pj, sj) in terms]))
+
+    pp = [entry([(a, o, ri, _ma_entry(a8, o, ri, i), _ma_entry(a8, o, ri, j))
+                 for o in range(2) for ri in range(2)])
+          for a in range(2) for i, j in _TRI]
+    qq = [entry([(a, o, ri, _mb_entry(bm8, a, ri, i),
+                  _mb_entry(bm8, a, ri, j))
+                 for a in range(2) for ri in range(2)])
+          for o in range(2) for i, j in _TRI]
+    pq = [entry([(a, o, ri, _ma_entry(a8, o, ri, i),
+                  _mb_entry(bm8, a, ri, j)) for ri in range(2)])
+          for a in range(2) for i in range(4)
+          for o in range(2) for j in range(4)]
+    return jnp.stack(pp), jnp.stack(qq), jnp.stack(pq)
+
+
+@jax.named_scope("assemble")     # sage/sweep/assemble in a solve
+def plane_equations(rows: RowPlanes, P, w8=None, cost_w8=None):
+    """:func:`normal_equations` from row data in plane form: the weighted
+    Gauss-Newton (JTJ [1, 8N, 8N], JTe [1, 8N], cost [1]) of ONE cluster
+    with ONE chunk whose rows lie ``[tilesz, nbase]``
+    (``rows.periodic``), at the stations' Jones ``P [1, N, 8]`` (real
+    planes, :func:`jones_c2r` order).
+
+    ``w8``: the sqrt-weight planes ``[8, *rows.rows]`` JTJ and JTe use
+    (default ``rows.w``; a robust solve hands its curvature weights);
+    ``cost_w8``: an optional second set for the cost alone
+    (:func:`normal_equations`' ``cost_wt``).
+
+    Real elementwise arithmetic on planes with the rows on the minor
+    axes and a sum over time, nothing else at row size: the Jones are
+    gathered for ``nbase`` rows and broadcast over time, ``A = C J_q^H``,
+    ``Bm = J_p C`` and ``V`` come from :func:`row_model`, JTe is
+    :func:`row_grad` of the twice-weighted residual, and the Gram blocks
+    of a baseline are :func:`_gram_planes`' written-out products in the
+    accumulation dtype. Only the ``nbase``-sized blocks are placed: the
+    station-diagonal ones by one segment sum, the cross blocks by one
+    scatter of ``nbase`` rows of 64 into ``[N, N]`` station pairs, which
+    a transposition brings to ``[8N, 8N]`` and a second one
+    symmetrizes. A caller that keeps JTJ alone (``rtr.make_hess``)
+    leaves V, the residual, JTe and the cost to the compiler's
+    dead-code pass."""
+    if not rows.periodic or rows.c.ndim != 3:
+        raise ValueError("plane_equations wants one cluster's rows laid "
+                         "[tilesz, nbase] with one chunk "
+                         "(RowPlanes.periodic)")
+    N = rows.n_stations
+    w8 = rows.w if w8 is None else w8
+    jp8, jq8 = rows.gather(P)
+    v8, a8, bm8 = row_model(jp8, jq8, rows.c)
+    # the residual stream stays in the data's storage dtype; every
+    # product below is made in the accumulation dtype
+    r = rows.x - dtp.to_storage(v8, rows.x.dtype)
+    rw = r * w8
+    rca = dtp.acc(rw if cost_w8 is None else r * cost_w8)
+    cost = rows.chunk_sum(rca * rca)
+    wa = dtp.acc(w8)
+    gp, gq = row_grad(wa * dtp.acc(rw), a8, bm8)
+    JTe = rows.station_sum(rows.time_sum(gp), rows.time_sum(gq))
+    pp, qq, pq = _gram_planes(wa * wa, a8, bm8, rows.time_sum)
+    dt = pq.dtype
+    # station-diagonal blocks: ten entries a block summed to the
+    # stations, mirrored afterwards (so D is symmetric to the last digit)
+    D = rows.station_sum(pp, qq).reshape(N, 2, 10)[..., _TRI_OF]
+    eye2, eyeN = jnp.eye(2, dtype=dt), jnp.eye(N, dtype=dt)
+    Dfull = (D[:, :, :, None, :]
+             * eye2[None, :, None, :, None]).reshape(N, 8, 8)
+    # cross blocks: [(a, i), (o, j)] of baseline (p, q) at (p, q); the
+    # other triangle is the transpose of the whole matrix
+    U = jnp.zeros((N * N, 64), dt).at[rows.i1 * N + rows.i2].add(pq.T)
+    Mx = U.reshape(N, N, 8, 8).transpose(0, 2, 1, 3).reshape(8 * N, 8 * N)
+    JTJ = Mx + Mx.T + (Dfull[:, :, None, :]
+                       * eyeN[:, None, :, None]).reshape(8 * N, 8 * N)
+    return JTJ[None], JTe.reshape(1, 8 * N), cost
+
+
 @jax.named_scope("assemble")     # sage/sweep/assemble in a solve
 def normal_equations(x8, J, coh, sta1, sta2, chunk_id, wt, n_stations: int,
                      kmax: int, cost_wt=None, row_period: int = 0):
@@ -530,26 +659,26 @@ def normal_equations(x8, J, coh, sta1, sta2, chunk_id, wt, n_stations: int,
     rows out as [tilesz, nbase] with sta1/sta2 repeating every ``nbase``
     rows (the same invariant :func:`lm.os_subset_ids` builds on). When
     set and a cluster has a single hybrid chunk (kmax == 1, every
-    timeslot in chunk 0), the station aggregation becomes a clean
-    contraction over the time axis straight into [nbase, ...] blocks.
-    0 disables the fast path (generic scatter aggregation).
+    timeslot in chunk 0: :func:`periodic_rows`), the rows go to plane
+    form and the equations are :func:`plane_equations`': real
+    elementwise arithmetic on ``[tilesz, nbase]`` planes summed over
+    time, ``nbase``-sized blocks placed. 0, several chunks or a row
+    count the period does not divide take the generic assembly below.
 
-    Traffic-lean assembly: the per-baseline real Jacobians are never
+    The generic assembly: the per-baseline real Jacobians are never
     materialized. The Wirtinger blocks have only 16 independent reals
     each — Gp = I_2 (x) MA(A) over a == c and Gq = I_2 (x) MB(B) over
     o == c (A = C J_q^H, B = J_p C) — so all Gram products reduce to
-    4x4 contractions of the [B, 2, 2, 4] factors with the per-component
-    sqrt-weights folded in, and the station-pair cross blocks are
-    aggregated ONCE and symmetrized densely afterwards. Measured at the
-    LOFAR shape (K=1, N=62, B=18910, f32, XLA cost analysis, cpu):
-    dense assembly 93 MB accessed per evaluation, structured scatter
-    path 88 MB, baseline-major path 56 MB (tests/test_lm.py gates all
-    three for equivalence).
+    4x4 contractions of the [B, 2, 2, 4] factors with the squared
+    weights folded in, scattered per (chunk, station[, station]), and
+    the station-pair cross blocks are aggregated ONCE and symmetrized
+    densely afterwards. tests/test_lm.py and
+    tests/test_assemble_planes.py hold both against
+    :func:`_normal_equations_dense`.
 
     Dtype policy: data arriving in a reduced storage dtype (bf16/f16,
     sagecal_tpu.dtypes) dispatches to the storage/accumulate assembly
-    :func:`_normal_equations_reduced`; this f32/f64 path below is byte-
-    and bit-frozen (the default policy costs nothing).
+    :func:`_normal_equations_reduced`.
     """
     if dtp.is_reduced(x8.dtype):
         return _normal_equations_reduced(x8, J, coh, sta1, sta2, chunk_id,
@@ -558,6 +687,12 @@ def normal_equations(x8, J, coh, sta1, sta2, chunk_id, wt, n_stations: int,
                                          row_period=row_period)
     N = n_stations
     B = x8.shape[0]
+    if periodic_rows(kmax, row_period, B):
+        rows = RowPlanes(x8, coh, wt, sta1, sta2, chunk_id, kmax, N,
+                         row_period)
+        return plane_equations(
+            rows, jones_c2r(J),
+            cost_w8=None if cost_wt is None else rows.planes(cost_wt))
     Jp = J[chunk_id, sta1]                         # [B, 2, 2]
     Jq = J[chunk_id, sta2]
     A = coh @ jnp.conj(jnp.swapaxes(Jq, -1, -2))   # dV/dJp factor
@@ -570,61 +705,35 @@ def normal_equations(x8, J, coh, sta1, sta2, chunk_id, wt, n_stations: int,
     MB = _mb_factor(Bm)                            # [B, a, ri, 4]
     rc = rw if cost_wt is None else r * cost_wt
 
-    if kmax == 1 and row_period > 0 and B % row_period == 0:
-        # baseline-major path: sqrt-weighted factors carried per
-        # residual component; every Gram product is then one
-        # dot_general over (time, shared complex/ri axes) landing
-        # directly on [nbase, ...] station-pair blocks — no [B, .., 4,
-        # 4] per-row Gram materialization and no B-length scatters.
-        T = B // row_period
-        nb = row_period
-        wv = wt.reshape(T, nb, 2, 2, 2)            # [T, nb, a, o, ri]
-        WMAh = wv[..., None] * MA.reshape(T, nb, 1, 2, 2, 4)
-        WMBh = wv[..., None] * MB.reshape(T, nb, 2, 1, 2, 4)
-        rwv = rw.reshape(T, nb, 2, 2, 2)
-        pp = jnp.einsum("tnaori,tnaorj->naij", WMAh, WMAh)
-        qq = jnp.einsum("tnaori,tnaorj->noij", WMBh, WMBh)
-        pq = jnp.einsum("tnaori,tnaorj->naoij", WMAh, WMBh)
-        jtep = jnp.einsum("tnaori,tnaor->nai", WMAh, rwv)
-        jteq = jnp.einsum("tnaori,tnaor->noi", WMBh, rwv)
-        s1b, s2b = sta1[:nb], sta2[:nb]
-        D = jnp.zeros((1, N, 2, 4, 4), rw.dtype)
-        D = D.at[0, s1b].add(pp).at[0, s2b].add(qq)
-        O = jnp.zeros((1, N, N, 2, 2, 4, 4), rw.dtype)
-        O = O.at[0, s1b, s2b].add(pq)
-        JTe = jnp.zeros((1, N, 2, 4), rw.dtype)
-        JTe = JTe.at[0, s1b].add(jtep).at[0, s2b].add(jteq)
-        cost = jnp.sum(rc * rc).reshape(1)
-    else:
-        w2 = (wt * wt).reshape(B, 2, 2, 2)         # [B, a, o, ri]
-        rw2 = (rw * wt).reshape(B, 2, 2, 2)        # w^2 r
-        # Gram blocks: station-diagonal [4, 4] sub-blocks (block-diag
-        # over the first complex index) + the full [2, 2, 4, 4] cross
-        # block. The weights are folded into ONE [B, 2, 2, 2, 4]
-        # product each so every contraction below is a plain batched
-        # dot_general — a naive 3-operand einsum materializes
-        # [B, .., 4, 4] broadcast intermediates that double the traffic
-        # of this whole function.
-        WMA = w2[..., None] * MA[:, None]          # [B, a, o, ri, 4]
-        WMB = w2[..., None] * MB[:, :, None]       # [B, a, o, ri, 4]
-        pp = jnp.einsum("baori,borj->baij", WMA, MA)   # [B, 2, 4, 4]
-        qq = jnp.einsum("baorj,bari->boij", WMB, MB)
-        pq = jnp.einsum("baori,barj->baoij", WMA, MB)  # [B,2,2,4,4]
-        jtep = jnp.einsum("baor,bori->bai", rw2, MA)   # [B, 2, 4]
-        jteq = jnp.einsum("baor,bari->boi", rw2, MB)
+    w2 = (wt * wt).reshape(B, 2, 2, 2)             # [B, a, o, ri]
+    rw2 = (rw * wt).reshape(B, 2, 2, 2)            # w^2 r
+    # Gram blocks: station-diagonal [4, 4] sub-blocks (block-diag
+    # over the first complex index) + the full [2, 2, 4, 4] cross
+    # block. The weights are folded into ONE [B, 2, 2, 2, 4]
+    # product each so every contraction below is a plain batched
+    # dot_general — a naive 3-operand einsum materializes
+    # [B, .., 4, 4] broadcast intermediates that double the traffic
+    # of this whole function.
+    WMA = w2[..., None] * MA[:, None]              # [B, a, o, ri, 4]
+    WMB = w2[..., None] * MB[:, :, None]           # [B, a, o, ri, 4]
+    pp = jnp.einsum("baori,borj->baij", WMA, MA)   # [B, 2, 4, 4]
+    qq = jnp.einsum("baorj,bari->boij", WMB, MB)
+    pq = jnp.einsum("baori,barj->baoij", WMA, MB)  # [B,2,2,4,4]
+    jtep = jnp.einsum("baor,bori->bai", rw2, MA)   # [B, 2, 4]
+    jteq = jnp.einsum("baor,bari->boi", rw2, MB)
 
-        # aggregate per (chunk, station[, station]) BEFORE the 8x8
-        # expansion
-        D = jnp.zeros((kmax, N, 2, 4, 4), rw.dtype)
-        D = D.at[chunk_id, sta1].add(pp)
-        D = D.at[chunk_id, sta2].add(qq)
-        O = jnp.zeros((kmax, N, N, 2, 2, 4, 4), rw.dtype)
-        O = O.at[chunk_id, sta1, sta2].add(pq)
-        JTe = jnp.zeros((kmax, N, 2, 4), rw.dtype)
-        JTe = JTe.at[chunk_id, sta1].add(jtep)
-        JTe = JTe.at[chunk_id, sta2].add(jteq)
-        cost = jnp.zeros((kmax,), rw.dtype).at[chunk_id].add(
-            jnp.sum(rc * rc, axis=1))
+    # aggregate per (chunk, station[, station]) BEFORE the 8x8
+    # expansion
+    D = jnp.zeros((kmax, N, 2, 4, 4), rw.dtype)
+    D = D.at[chunk_id, sta1].add(pp)
+    D = D.at[chunk_id, sta2].add(qq)
+    O = jnp.zeros((kmax, N, N, 2, 2, 4, 4), rw.dtype)
+    O = O.at[chunk_id, sta1, sta2].add(pq)
+    JTe = jnp.zeros((kmax, N, 2, 4), rw.dtype)
+    JTe = JTe.at[chunk_id, sta1].add(jtep)
+    JTe = JTe.at[chunk_id, sta2].add(jteq)
+    cost = jnp.zeros((kmax,), rw.dtype).at[chunk_id].add(
+        jnp.sum(rc * rc, axis=1))
 
     # dense expansion (tiny next to the [B]-length passes above):
     # off-diagonal station blocks [8, 8] = pq blocks at (row c, col c'),

@@ -194,6 +194,20 @@ def _mode_p2j(mode, Jref, kmax, n_stations):
     return p_to_J
 
 
+def _mode_p2planes(mode, Jref, kmax, n_stations):
+    """params [K, npar*N] -> the stations' Jones as real planes
+    [K, N, 8] (:func:`ne.jones_c2r` order) for a jones_mode: what
+    :meth:`ne.RowPlanes.gather` reads."""
+    p_to_J = _mode_p2j(mode, Jref, kmax, n_stations)
+
+    def station_planes(p):
+        if mode == "full":
+            return p.reshape(kmax, n_stations, 8)
+        return ne.jones_c2r(p_to_J(p))
+
+    return station_planes
+
+
 def station_precond(wt, sta1, sta2, chunk_id, kmax, n_stations,
                     npar: int = 8):
     """iw diagonal preconditioner: 1 / (# live baselines per station) per
@@ -263,17 +277,11 @@ def make_row_pass(rows: ne.RowPlanes, kmax, n_stations, admm=None,
     segment sum of the shares to the stations, the transpose of the
     station-sized p -> J map for the constrained modes, the ADMM term
     2 y + 2 rho (p - bz)."""
-    p_to_J = _mode_p2j(mode, Jref, kmax, n_stations)
+    station_planes = _mode_p2planes(mode, Jref, kmax, n_stations)
     if admm is not None:
         admm_y, admm_bz, admm_rho = admm
         admm_y = admm_y.reshape(kmax, -1)
         admm_bz = admm_bz.reshape(kmax, -1)
-
-    def station_planes(p):
-        """p [K, D] -> the stations' Jones as real planes [K, N, 8]."""
-        if mode == "full":
-            return p.reshape(kmax, n_stations, 8)
-        return ne.jones_c2r(p_to_J(p))
 
     def row_pass(p):
         jp, jq = rows.gather(station_planes(p))
@@ -435,6 +443,22 @@ def _rtr_rows(rows: ne.RowPlanes, x8, coh, sta1, sta2, chunk_id, wt, J0,
             swp = swp_mod
 
     admm_rho2 = None if admm is None else 2.0 * admm[2]
+    station_planes = _mode_p2planes(mode, Jref, kmax, n_stations)
+
+    def dense_hv(p, JTJ):
+        """v -> the projected (2 JTJ [+ 2 rho I]) v at the point ``p``."""
+        def hv(v):
+            Hv = 2.0 * jnp.einsum("kij,kj->ki", JTJ, v)
+            if admm_rho2 is not None:
+                Hv = Hv + admm_rho2 * v
+            return project_tangent_mode(p, Hv, kmax, n_stations, mode)
+        return hv
+
+    # the one assembly of a full-Jones f32/f64 solve on rows with a
+    # period: straight from the planes the solve already holds
+    planes_hess = (config.inner == "chol" and swp is None
+                   and mode == "full" and rows.periodic
+                   and not dtp.is_reduced(x8.dtype))
 
     def make_hess(p, e):
         """Gauss-Newton Hessian operator at the outer TR point ``p``,
@@ -445,9 +469,17 @@ def _rtr_rows(rows: ne.RowPlanes, x8, coh, sta1, sta2, chunk_id, wt, J0,
         reverse through the gradient) re-traverses the whole residual
         graph for EVERY tCG product. Here the block-sparse Gauss-Newton
         normal matrix is assembled ONCE per outer iteration from the
-        analytic Wirtinger factors (normal_eq.normal_equations, which
-        makes its own pass over the rows) and each tCG product is a
-        single batched [K,8N,8N]@[K,8N] matvec on the matrix unit.
+        analytic Wirtinger factors and each tCG product is a single
+        batched [K,8N,8N]@[K,8N] matvec on the matrix unit. On rows
+        with a period (one chunk, ``[tilesz, nbase]``: every cluster
+        solve of a calibration with ``nbase`` set) the assembly is
+        normal_eq.plane_equations on the planes this solve holds
+        (``rows``, the point's station planes, the curvature weights as
+        planes): its own evaluation of the row model's Wirtinger
+        factors, elementwise, and a sum over time, of which XLA keeps
+        what JTJ needs. Several chunks, ``inner="cg"``,
+        ``kernel="pallas"`` and the constrained modes take the
+        ``[B, 8]`` assemblies of normal_eq / sweep_pallas.
 
         Curvature model per residual element e (e already includes wt):
           gaussian  sum e^2:          f'' = 2          -> weights wt
@@ -457,16 +489,20 @@ def _rtr_rows(rows: ne.RowPlanes, x8, coh, sta1, sta2, chunk_id, wt, J0,
             read from the carried ``e``: no pass of their own.
         The ADMM augmentation contributes its exact Hessian 2*rho*I.
         """
-        Jm = p_to_J(p)
         if robust_nu is None:
-            wt_eff = wt
+            w8 = rows.w
         else:
-            # keep the curvature weights in the storage dtype so the
-            # GN assembly below stays on the reduced path (identity
-            # for f32/f64)
-            wt_eff = dtp.to_storage(rows.to_rows(
-                rows.w * jnp.sqrt(robust_nu) / (robust_nu + e * e)),
-                wt.dtype)
+            # the curvature weights stay in the storage dtype, so that
+            # a reduced policy's assembly stays on its reduced path
+            # (identity for f32/f64)
+            w8 = dtp.to_storage(
+                rows.w * jnp.sqrt(robust_nu) / (robust_nu + e * e),
+                rows.w.dtype)
+        if planes_hess:
+            JTJ, _, _ = ne.plane_equations(rows, station_planes(p), w8)
+            return dense_hv(p, JTJ)
+        Jm = p_to_J(p)
+        wt_eff = wt if robust_nu is None else rows.to_rows(w8)
         if config.inner == "cg":
             if swp is not None:
                 # blocks operator: the fused sweep contracts the time
@@ -525,12 +561,7 @@ def _rtr_rows(rows: ne.RowPlanes, x8, coh, sta1, sta2, chunk_id, wt, J0,
                 x8, Jm, coh, sta1, sta2, chunk_id, wt_eff, n_stations,
                 kmax, mode, row_period=row_period)
 
-        def hv(v):
-            Hv = 2.0 * jnp.einsum("kij,kj->ki", JTJ, v)
-            if admm_rho2 is not None:
-                Hv = Hv + admm_rho2 * v
-            return project_tangent_mode(p, Hv, kmax, n_stations, mode)
-        return hv
+        return dense_hv(p, JTJ)
 
     cost0, e0, shares0 = row_pass(p0)
     xnorm0 = jnp.sqrt(_dot(p0, p0))

@@ -111,7 +111,25 @@ def _call(name, jfn, *args, **kwargs):
 
 #: what :func:`_plan_info` adds to a host-driven solve's info dict: host
 #: values (a str, an int), not arrays
-_PLAN_KEYS = ("plan", "solve_dispatches", "refine_rows")
+_PLAN_KEYS = ("plan", "solve_dispatches", "refine_rows", "assemble_rows")
+
+
+def assemble_rows(config, kmax: int, B: int):
+    """The row layout the per-cluster solves' dense Gauss-Newton matrix
+    is assembled from, which ``normal_eq.normal_equations`` and
+    ``rtr.make_hess`` decide from their input: ``"periodic"`` (one
+    chunk a cluster and ``config.nbase`` dividing the ``B`` rows: the
+    assembly on ``[tilesz, nbase]`` planes, ``normal_eq.plane_equations``)
+    or ``"generic"`` (the ``[B, 8]`` scatter assembly). None where the
+    solves assemble no such matrix or another code does (NSD,
+    ``inner="cg"``, ``kernel="pallas"``, a constrained Jones mode, a
+    reduced storage dtype)."""
+    if (config.inner != "chol" or config.kernel != "xla"
+            or config.jones_mode != "full" or config.dtype_policy != "f32"
+            or int(config.solver_mode) == int(SolverMode.NSD_RLBFGS)):
+        return None
+    return "periodic" if ne.periodic_rows(kmax, config.nbase, B) \
+        else "generic"
 
 
 def _plan_info(info: dict, plan: str, n0: int, config, J0, x8) -> dict:
@@ -122,13 +140,19 @@ def _plan_info(info: dict, plan: str, n0: int, config, J0, x8) -> dict:
     :func:`_call` since ``n0``, ``refine_rows`` (where a refine ran) the
     row layout its model passes worked on, which the mechanism decides
     from its input (``"periodic"``: ``[tilesz, nbase]`` planes, the
-    Jones gathered for ``nbase`` rows; ``"flat"``: ``[B]``), here from
-    the shapes of ``J0 [(T,) M, kmax, N, 2, 2]`` and ``x8 [(T,) B, 8]``.
+    Jones gathered for ``nbase`` rows; ``"flat"``: ``[B]``), and
+    ``assemble_rows`` (:func:`assemble_rows`, where it says one) the
+    same of the sweeps' assembly, both here from the shapes of
+    ``J0 [(T,) M, kmax, N, 2, 2]`` and ``x8 [(T,) B, 8]``.
     Host values: nothing is fetched."""
     out = {**info, "plan": plan, "solve_dispatches": _dispatched() - n0}
+    kmax, B = J0.shape[-4], x8.shape[-2]
     if config.max_lbfgs > 0:
         out["refine_rows"] = ("periodic" if ne.periodic_rows(
-            J0.shape[-4], config.nbase, x8.shape[-2]) else "flat")
+            kmax, config.nbase, B) else "flat")
+    rows = assemble_rows(config, kmax, B)
+    if rows is not None:
+        out["assemble_rows"] = rows
     return out
 
 

@@ -95,10 +95,11 @@ def test_blocks_matvec_kernel_compiles(one_chip, md):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_cluster_update_fits(one_chip):
+@functools.cache
+def _compiled_cluster_update(one_chip):
     """The XLA per-cluster solve of the default fullbatch path
     (sagefit_host -> _jit_cluster_update; robust RTR at N > LMCUT),
-    with the pipeline's SageConfig, compiles for v5e and fits its HBM."""
+    with the pipeline's SageConfig, compiled once a session."""
     from sagecal_tpu.solvers import lm as lm_mod, sage
     sd = _spec(one_chip)
     f32, i32, c64 = jnp.float32, jnp.int32, jnp.complex64
@@ -106,7 +107,7 @@ def test_cluster_update_fits(one_chip):
     os_ids, os_nsub = lm_mod.os_subset_ids(TILESZ, NB)
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
     flag = sd((), jnp.bool_)
-    lowered = sage._jit_cluster_update.lower(
+    return sage._jit_cluster_update.lower(
         sd((), i32),                                    # cj
         sd((M, 1, N, 2, 2), c64), sd((B, 8), f32),      # J, xres
         sd((M,), f32), sd((M,), f32),                   # nerr_acc, nuM
@@ -116,11 +117,32 @@ def test_cluster_update_fits(one_chip):
         sd((B, 8), f32), sd((M,), f32),                 # wt, nerr_prev
         flag, flag, sd(key.shape, key.dtype),           # weighted/last/key
         None, sd(np.shape(os_ids), i32),                # admm, os_ids
-        N, cfg, M * cfg.max_iter, 8, os_nsub)
-    mem = lowered.compile().memory_analysis()
+        N, cfg, M * cfg.max_iter, 8, os_nsub).compile()
+
+
+def test_cluster_update_fits(one_chip):
+    """The per-cluster solve compiles for v5e and fits its HBM."""
+    mem = _compiled_cluster_update(one_chip).memory_analysis()
     need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert 0 < need < HBM_BYTES, mem
+
+
+def test_cluster_update_assembles_on_planes(one_chip):
+    """The Gauss-Newton matrix of the per-cluster solve comes from
+    ``normal_eq.plane_equations``: real elementwise arithmetic on
+    ``[8, tilesz, nbase]`` planes and a sum over time, so nothing whose
+    name holds the scope ``assemble`` is a contraction (the TPU compiler
+    writes a ``dot`` as a ``convolution``; until PR 39 the scope held
+    nine of them, six with ``[B, 2, 2]`` complex operands and three
+    Gram contractions batched over 1891 baselines: PERF.md section 5)."""
+    text = _compiled_cluster_update(one_chip).as_text()
+    scoped = [ln for ln in text.splitlines() if "/assemble/" in ln]
+    assert scoped, "no operation names the scope sage/sweep/.../assemble"
+    assert any(" reduce(" in ln for ln in scoped)
+    contractions = [ln.strip()[:160] for ln in scoped
+                    if " convolution(" in ln or " dot(" in ln]
+    assert not contractions, contractions
 
 
 def test_refine_program_searches_on_the_line(one_chip):
@@ -233,7 +255,10 @@ CHIP_BYTES = int(15.75 * 2 ** 30)
 PADDED_TEMP_BYTES = int(1.73 * 2 ** 30)
 #: a ceiling of its own where a program has been given room to lose:
 #: what it compiled to (PR 36) plus ONE such temporary
-CEILING = {"refine": int(0.42 * 2 ** 30) + PADDED_TEMP_BYTES}
+CEILING = {"refine": int(0.42 * 2 ** 30) + PADDED_TEMP_BYTES,
+           "sagefit": int(4.95 * 2 ** 30) + PADDED_TEMP_BYTES}
+
+
 @functools.cache
 def _need_120(one_chip, name):
     """Bytes (argument + output + temp) the program ``name`` asks of a
@@ -294,31 +319,32 @@ def test_production_tile_fits(one_chip, program):
     program, and the simulation modes' (``-a 3 -p -z`` over 8 x 128
     sources, PR 37: no cell runs it at this size, because the reference
     takes 53 s to make one such tile's sky).  Argument + output + temp
-    as compiled here at PR 36 (``simulate`` at PR 37; PR 34's
-    beside them, with the temporaries the chip's own compile asked for
-    then: PERF.md section 5):
+    as compiled here at PR 39 (PR 36's and PR 34's beside them, with
+    the temporaries the chip's own compile asked for then: PERF.md
+    section 5):
 
-    ==============  ===========  ===========  =======================
-    program         -t 120 here  at PR 34     the chip's temp, PR 34
-    ==============  ===========  ===========  =======================
-    sagefit          5.64 GiB    13.56 GiB    13.48 GiB
-    refine           0.42 GiB    13.55 GiB    13.47 GiB
-    cluster_update   7.02 GiB     7.02 GiB     not read
-    residual         2.30 GiB     2.30 GiB     2.27 GiB
-    simulate         2.29 GiB     aborts       not run
-    ==============  ===========  ===========  =======================
+    ==============  ===========  =========  =========  ==============
+    program         -t 120 here  at PR 36   at PR 34   the chip, PR 34
+    ==============  ===========  =========  =========  ==============
+    sagefit          4.95 GiB     5.64 GiB  13.56 GiB  13.48 GiB
+    refine           0.42 GiB     0.42 GiB  13.55 GiB  13.47 GiB
+    cluster_update   5.50 GiB     7.02 GiB   7.02 GiB  not read
+    residual         2.30 GiB     2.30 GiB   2.30 GiB  2.27 GiB
+    simulate         2.29 GiB     2.29 GiB   aborts    not run
+    ==============  ===========  =========  =========  ==============
 
     Arguments are 0.076 GiB.  Until PR 36 nearly all of the solve was
     ``f32[8, 226920, 2, 2]`` temporaries tiled ``T(2,128)``, 1.73 GiB
     for 27 MB of data each, about seven live at once in the joint
-    refine's model passes.  The refine now works on ``[8, 8, 120,
-    1891]`` planes (58 MB each, no padding) and holds NO such temporary:
-    its ceiling is what it compiled to plus one of them, so the old
-    construction coming back into it is what this case notices.  What
-    ``sagefit`` still asks is the sweep's (``assemble`` holds ``[.., 2,
-    2]`` temporaries; no promise there beyond the chip's size).  The
-    solve's and the residual's TOGETHER are 7.94 GiB: they fit side by
-    side now, though the residual is only dispatched once the solve's
-    result is fetched."""
+    refine's model passes.  The refine (PR 36) and the sweeps' assembly
+    (PR 39) work on ``[8, (8,) 120, 1891]`` planes (7 MB a cluster, no
+    padding) and hold NO such temporary: each has a ceiling of what it
+    compiled to plus one of them, so the old construction coming back
+    into the refine or into the assembly is what this case notices.
+    What ``sagefit`` and ``cluster_update`` still ask is the sweep's
+    ``update`` (``sage._model8`` holds ``[.., 2, 2]`` temporaries; no
+    promise there beyond these ceilings).  The solve's and the
+    residual's TOGETHER are 7.25 GiB: they fit side by side, though the
+    residual is only dispatched once the solve's result is fetched."""
     need = _need_120(one_chip, program)
     assert 0 < need < CEILING.get(program, CHIP_BYTES), need / 2 ** 30

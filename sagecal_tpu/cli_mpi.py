@@ -808,6 +808,7 @@ class ConsensusStepper:
         write-back), optional -W whitening, and the per-subband
         unflagged fraction that scales rho (master :646-650)."""
         import jax.numpy as jnp
+        from sagecal_tpu.diag import trace as dtrace
         from sagecal_tpu.rime import predict as rp
         from sagecal_tpu.solvers import lm as lm_mod
         args, rdt = self.args, self.rdt
@@ -815,19 +816,26 @@ class ConsensusStepper:
         uvcut_on = args.uvmin > 0.0 or args.uvmax < 1e9
         orig_flags = [t.flags for t in tiles]
         for t in tiles:
+            # the read-backs queue behind the solve on the first chip:
+            # that part of "stage" is a wait, not the reader's work (the
+            # uv cut's is the long one: PERF.md section 5, PR 40)
             if uvcut_on:
-                t.flags = rp.apply_uvcut(t.flags, t,
-                                         args.uvmin, args.uvmax)
+                with dtrace.phase("wait"):
+                    t.flags = rp.apply_uvcut(t.flags, t,
+                                             args.uvmin, args.uvmax)
             x8_t, flags_t, good = t.solve_input()
             fr_l.append(good)
             if args.whiten:
                 from sagecal_tpu.solvers import robust as rb
-                x8_t = np.asarray(rb.whiten_data(
+                x8_d = rb.whiten_data(
                     jnp.asarray(x8_t, rdt), jnp.asarray(t.u, rdt),
-                    jnp.asarray(t.v, rdt), t.freq0))
+                    jnp.asarray(t.v, rdt), t.freq0)
+                with dtrace.phase("wait"):
+                    x8_t = np.asarray(x8_d)
             x8_l.append(x8_t)
-            wt_l.append(np.asarray(lm_mod.make_weights(
-                jnp.asarray(flags_t, jnp.int32), rdt)))
+            wt_d = lm_mod.make_weights(jnp.asarray(flags_t, jnp.int32), rdt)
+            with dtrace.phase("wait"):
+                wt_l.append(np.asarray(wt_d))
         if uvcut_on:
             for t, fl in zip(tiles, orig_flags):
                 t.flags = fl
@@ -883,6 +891,13 @@ class ConsensusStepper:
         """Solve interval ``ti`` (dataset tile number), carry the warm
         start, submit its writes in order. Returns the interval's
         history record."""
+        from sagecal_tpu.diag import trace as dtrace
+        # the root span of an interval's cycle on this thread: with the
+        # consumer's "io" it covers the cycle (diag/trace.py)
+        with dtrace.phase("step", tile=ti):
+            return self._step(ti, tiles, staged, io_wait)
+
+    def _step(self, ti, tiles, staged, io_wait):
         import jax
         import jax.numpy as jnp
         from sagecal_tpu import sched
@@ -899,9 +914,11 @@ class ConsensusStepper:
 
         # the warm-start chain is this thread's: J0 is staged here, the
         # interval's data came staged from the reader
-        (J0p,), _, _ = cadmm.pad_subbands((self.J0,), Bpoly, nf, self.ndev)
-        args_dev = list(staged["args_dev"]) + [self._to_device(
-            np.asarray(J0p, np.dtype(rdt)))]
+        with dtrace.phase("carry"):
+            (J0p,), _, _ = cadmm.pad_subbands((self.J0,), Bpoly, nf,
+                                              self.ndev)
+            args_dev = list(staged["args_dev"]) + [self._to_device(
+                np.asarray(J0p, np.dtype(rdt)))]
         if dtrace.active():
             dtrace.emit("stage_bytes", what="tile_inputs", tile=ti,
                         bytes=staged["nbytes"] + int(
@@ -915,13 +932,13 @@ class ConsensusStepper:
         if blk_timer is not None:
             blk_timer.clear()
         with dtrace.phase("solve", tile=ti):
-            JF_r8, Z, rhoF, res0, res1, r1s, duals, Y0F = self.runner(
-                *args_dev)
-            if dtrace.active():
-                # the traced plan is ONE device execution per
-                # interval: time it to its end (the fetch below
-                # would block on it anyway)
-                jax.block_until_ready(JF_r8)
+            with dtrace.phase("dispatch", prog="admm"):
+                JF_r8, Z, rhoF, res0, res1, r1s, duals, Y0F = self.runner(
+                    *args_dev)
+            # the traced plan is ONE device execution per interval:
+            # while tracing, time it to its end (the fetch below would
+            # block on it anyway)
+            sched.wait_device(JF_r8)
         self._rhoF = rhoF
         if (ti == self.start and is_writer
                 and hasattr(JF_r8, "addressable_shards")):
@@ -958,24 +975,27 @@ class ConsensusStepper:
                     ww.write_interval(J_all[f], sky.nchunk)
             bubble += aw.submit(_write_workers)
 
-        if args.mdl and ti == self.start and is_writer:
-            # model-order report from iteration-0 rho*J (master :815-822)
-            from sagecal_tpu.consensus import mdl as mdlmod
-            res = mdlmod.minimum_description_length(
-                np.asarray(Y0F), np.broadcast_to(
-                    np.asarray(self.rho0, float), (sky.n_clusters,)),
-                freqs, float(freqs.mean()), weight=fratioF,
-                polytype=args.polytype, kstart=1, kfinish=args.npoly)
-            mdlmod.report(res)
+        with dtrace.phase("primal"):
+            if args.mdl and ti == self.start and is_writer:
+                # model-order report from iteration-0 rho*J (master
+                # :815-822)
+                from sagecal_tpu.consensus import mdl as mdlmod
+                res = mdlmod.minimum_description_length(
+                    np.asarray(Y0F), np.broadcast_to(
+                        np.asarray(self.rho0, float), (sky.n_clusters,)),
+                    freqs, float(freqs.mean()), weight=fratioF,
+                    polytype=args.polytype, kstart=1, kfinish=args.npoly)
+                mdlmod.report(res)
 
-        res0 = np.asarray(res0)
-        res1_it0 = np.asarray(res1)     # iteration 0's plain solve
-        res1 = np.asarray(r1s)[-1] if cfg.n_admm > 1 else res1_it0
-        duals = np.asarray(duals)
-        # the consensus primal residual ||J - BZ|| (the reference
-        # master's convergence axis)
-        BZf = np.einsum("fp,mpknr->fmknr", Bpoly, np.asarray(Z))
-        primal = float(np.linalg.norm(JF_r8_5 - BZf) / np.sqrt(BZf.size))
+            res0 = np.asarray(res0)
+            res1_it0 = np.asarray(res1)     # iteration 0's plain solve
+            res1 = np.asarray(r1s)[-1] if cfg.n_admm > 1 else res1_it0
+            duals = np.asarray(duals)
+            # the consensus primal residual ||J - BZ|| (the reference
+            # master's convergence axis)
+            BZf = np.einsum("fp,mpknr->fmknr", Bpoly, np.asarray(Z))
+            primal = float(np.linalg.norm(JF_r8_5 - BZf)
+                           / np.sqrt(BZf.size))
         rec = {"tile": ti, "res_0": float(res0.mean()),
                "res_1": float(res1.mean()), "primal": primal,
                "dual": float(duals[-1]) if len(duals) else 0.0}
@@ -987,23 +1007,25 @@ class ConsensusStepper:
             # already emit live per-iteration records (admm.py feeds
             # BOTH the trace and the obs gauges there), so only the
             # fully traced mesh program needs the post-hoc emission.
-            if (not args.host_loop and not args.block_f
-                    and not args.staleness):
-                # one record an ADMM iteration, iteration 0 (the plain
-                # solve, no dual yet) included, as the host loop's
-                r1_it = [res1_it0] + list(np.asarray(r1s))
-                for k, r1k in enumerate(r1_it):
-                    r1m = float(r1k.mean())
-                    du = float(duals[k - 1]) if k else 0.0
-                    dtrace.emit("admm_iter", interval=ti, iter=k,
-                                r1_mean=r1m, dual=du)
-                    if obs.active():
-                        obs.inc("admm_iterations_total")
-                        obs.set_gauge("admm_primal_residual", r1m)
-                        obs.set_gauge("admm_dual_residual", du)
-            if obs.active():
-                obs.inc("tiles_solved_total")
-                obs.set_gauge("consensus_primal_residual", primal)
+            with dtrace.phase("record"):
+                if (not args.host_loop and not args.block_f
+                        and not args.staleness):
+                    # one record an ADMM iteration, iteration 0 (the
+                    # plain solve, no dual yet) included, as the host
+                    # loop's
+                    r1_it = [res1_it0] + list(np.asarray(r1s))
+                    for k, r1k in enumerate(r1_it):
+                        r1m = float(r1k.mean())
+                        du = float(duals[k - 1]) if k else 0.0
+                        dtrace.emit("admm_iter", interval=ti, iter=k,
+                                    r1_mean=r1m, dual=du)
+                        if obs.active():
+                            obs.inc("admm_iterations_total")
+                            obs.set_gauge("admm_primal_residual", r1m)
+                            obs.set_gauge("admm_dual_residual", du)
+                if obs.active():
+                    obs.inc("tiles_solved_total")
+                    obs.set_gauge("consensus_primal_residual", primal)
 
         # warm-start the next interval; per-subband divergence reset
         # (slave :680-683 res_ratio check; fullbatch warm-start analogue)
@@ -1014,12 +1036,14 @@ class ConsensusStepper:
             if bad[f] and is_writer:
                 log(f"  subband {f}: diverged; Resetting Solution")
         if is_writer:
-            log(f"Timeslot:{ti} ADMM:{cfg.n_admm} residual "
-                f"initial={res0.mean():.6g} final={res1.mean():.6g} "
-                f"dual={duals[-1] if len(duals) else 0:.3g}")
-            if args.verbose:
-                for f in range(nf):
-                    log(f"  subband {f}: {res0[f]:.6g} -> {res1[f]:.6g}")
+            with dtrace.phase("record"):
+                log(f"Timeslot:{ti} ADMM:{cfg.n_admm} residual "
+                    f"initial={res0.mean():.6g} final={res1.mean():.6g} "
+                    f"dual={duals[-1] if len(duals) else 0:.3g}")
+                if args.verbose:
+                    for f in range(nf):
+                        log(f"  subband {f}: {res0[f]:.6g} -> "
+                            f"{res1[f]:.6g}")
 
         # residuals + write back (slave :832-869); multi-host: process 0
         # owns all outputs (shared-filesystem assumption, like the
@@ -1030,23 +1054,28 @@ class ConsensusStepper:
                 J_res = BZf.reshape(nf, sky.n_clusters, kmax, n, 8)
             else:
                 J_res = JF_r8_5
-            with dtrace.phase("residual", tile=ti):     # a dispatch
+            with dtrace.phase("residual", tile=ti):
                 xF_r = np.stack([utils.c2r(t.x) for t in tiles])
-                bargs = ()
-                if self.dobeam:
-                    # residual beam: the UNPADDED nf subbands with this
-                    # tile's gmst track
-                    bargs = (jax.tree.map(
-                        lambda a: jnp.asarray(a),
-                        self._beamF_static._replace(gmst=gmstF[:nf])),)
-                res_r = self.res_jit(
-                    jnp.asarray(J_res, rdt), jnp.asarray(xF_r, sdt),
-                    jnp.asarray(uF, rdt), jnp.asarray(vF, rdt),
-                    jnp.asarray(wF, rdt), jnp.asarray(freqs, rdt), *bargs)
+                with dtrace.phase("carry"):     # host to device
+                    rargs = [jnp.asarray(J_res, rdt),
+                             jnp.asarray(xF_r, sdt), jnp.asarray(uF, rdt),
+                             jnp.asarray(vF, rdt), jnp.asarray(wF, rdt),
+                             jnp.asarray(freqs, rdt)]
+                    if self.dobeam:
+                        # residual beam: the UNPADDED nf subbands with
+                        # this tile's gmst track
+                        rargs.append(jax.tree.map(
+                            lambda a: jnp.asarray(a),
+                            self._beamF_static._replace(gmst=gmstF[:nf])))
+                with dtrace.phase("dispatch", prog="residual"):
+                    res_r = self.res_jit(*rargs)
             mss, bg = self.mss, self.depth > 0
 
             def _write_res(ti=ti, tiles=tiles, res_r=res_r):
                 with dtrace.phase("write", tile=ti, bg=bg):
+                    # blocked on the residual program's execution;
+                    # the copy and the disk are write's own
+                    sched.wait_device(res_r)
                     # fetch through float64 (numpy-side r2c has no
                     # ml_dtypes bf16 path; the MS is complex128)
                     res_np = utils.r2c(np.asarray(res_r, np.float64))
@@ -1073,13 +1102,14 @@ class ConsensusStepper:
             # interval summary: bubble_s is the host seconds this step
             # was blocked on data movement (the wait for the staged
             # interval, writer back-pressure), overlap the prefetch depth
-            dtrace.emit("tile", tile=ti, res_0=rec["res_0"],
-                        res_1=rec["res_1"], primal=primal,
-                        rho_mean=float(np.asarray(self._fetch(rhoF))[:nf]
-                                       .mean()),
-                        bubble_s=float(bubble), overlap=self.depth,
-                        **({} if self.assemble_rows is None else
-                           {"assemble_rows": self.assemble_rows}))
+            with dtrace.phase("record"):
+                dtrace.emit("tile", tile=ti, res_0=rec["res_0"],
+                            res_1=rec["res_1"], primal=primal,
+                            rho_mean=float(np.asarray(
+                                self._fetch(rhoF))[:nf].mean()),
+                            bubble_s=float(bubble), overlap=self.depth,
+                            **({} if self.assemble_rows is None else
+                               {"assemble_rows": self.assemble_rows}))
         self._last = ti
         return rec
 

@@ -23,10 +23,14 @@ vocabulary so downstream tooling can rely on it:
 event            meaning / required extra fields
 ===============  ============================================================
 ``run_start``    first record; run metadata (argv, entry point)
-``phase``        a timed host phase: ``name`` (io/stage/solve/residual/
-                 write/read/consensus/arrival_wait, and the simulation
-                 loop's predict/fetch), ``dur_s``;
-                 optional ``tile``, ``bg`` (True when the phase ran on
+``phase``        a timed host phase (a span): ``name``, ``dur_s``, ``id``
+                 (unique in the process, taken as the span is entered),
+                 ``parent`` (the ``id`` of the span open on the SAME
+                 thread when this one was entered, else null),
+                 ``thread`` (the thread's name); optional ``tile`` (what
+                 the spans of one tile share: a span without one takes
+                 its parent's), ``prog`` (a ``dispatch``'s program),
+                 ``bg`` (True when the phase ran on
                  a background prefetch/writeback thread — under
                  overlapped execution the "io" phase records the
                  host's WAIT for the next tile, the bubble, while the
@@ -83,6 +87,33 @@ event            meaning / required extra fields
 ``run_end``      last record; ``wall_s`` for the whole run
 ===============  ============================================================
 
+Span names (the three tile loops: ``pipeline.TileStepper.step``,
+``cli_mpi.ConsensusStepper.step``, ``FullBatchPipeline.run_simulation``).
+The loop's thread is at every instant of a tile's cycle inside one of two
+ROOT spans: ``io`` (the consumer's wait for the next tile) or ``step``.
+Under ``step``: ``carry`` (the warm-start Jones made ready and copied to
+the device), ``solve`` (the whole solve, to its read-backs), ``residual``
+(the residual program's ``carry`` and ``dispatch``), ``fetch``
+(read-backs), ``submit`` (``sched.AsyncWriter.submit``: a job handed to
+the ordered writer, back-pressure included), ``record`` (info read-backs,
+history, ``tile`` and ``admm_iter`` records, log lines), ``primal`` (the
+consensus loop's ``B Z`` and norms on the host), and the simulation
+loop's ``stage``, ``predict``, ``fetch``, ``write``.  ``dispatch`` is one
+device execution enqueued (``solvers/sage.py:_call`` makes one per
+execution, ``prog=`` its label; the mesh runner and the residual
+programs have theirs).  ``wait`` is the ONE name under which a host
+thread is blocked on the device's execution, on the loop's thread (under
+``solve``, ``fetch``) and on a background one (under ``stage``,
+``write``; ``sched.wait_device``): a span's seconds less its ``wait``
+children are the host's own.  Background threads keep ``read``,
+``stage``, ``write``, ``arrival_wait`` with ``bg``.  A span's self time
+is its ``dur_s`` less its children's, by ``id``/``parent``.
+
+Records are kept in memory and written by :meth:`Tracer.close` (so
+:func:`disable`), whenever :data:`FLUSH_AT` of them are held, and by an
+``atexit`` hook for a run that ends without closing: an emit on a hot
+path appends to a list and returns.
+
 Profiler annotations: :func:`phase` wraps its body in an annotation
 ``sagecal/<name>`` (with ``tile=`` where the site has one) on the
 profiler's clock, so the same span can be read from the JSONL and found
@@ -99,6 +130,8 @@ so the disabled path never forces a device sync).
 
 from __future__ import annotations
 
+import atexit
+import itertools
 import json
 import threading
 import time
@@ -109,6 +142,17 @@ from sagecal_tpu.analysis import threadsan
 REQUIRED_FIELDS = ("t", "ev")
 
 _TRACER = None          # module-level singleton; None = disabled
+
+#: records a tracer holds before it writes them out (a constant, not an
+#: option: an operator's ``--diag`` over hours stays bounded, a
+#: benchmark's traced window never reaches it)
+FLUSH_AT = 1 << 16
+
+# span ids (``next`` on a count is atomic) and, per thread, its name and
+# the stack of the spans open on it: a span's parent is the one entered
+# before it on its OWN thread, never the spawning thread's
+_IDS = itertools.count(1)
+_OPEN = threading.local()
 
 # profiler annotations: the class (``jax.profiler.TraceAnnotation``) is
 # handed in by utils.setup_backend so that this module never imports
@@ -183,51 +227,68 @@ def scope(tracer):
 
 
 class Tracer:
-    """Append-only JSONL event writer with monotonic phase timers."""
+    """JSONL event writer with monotonic phase timers: records are kept
+    in memory and appended to ``path`` at :meth:`close`, whenever
+    :data:`FLUSH_AT` of them are held, and at interpreter exit."""
 
     def __init__(self, path, **run_meta):
         self.path = path
-        self._f = open(path, "a", buffering=1)   # line-buffered
+        self._f = open(path, "a")
         # overlapped execution (sagecal_tpu.sched) emits from the
-        # prefetch and writer threads concurrently with the main loop;
-        # TextIOWrapper.write is not thread-safe, so one lock keeps
-        # every JSONL line atomic
+        # prefetch and writer threads concurrently with the main loop:
+        # one lock keeps the buffer whole across a flush
         self._lock = threadsan.make_lock("Tracer._lock")
+        self._buf = []
         self._t0 = time.time()
+        atexit.register(self.flush)     # what a crashed run holds
         self.emit("run_start", **run_meta)
 
     def emit(self, ev: str, **fields) -> None:
         rec = {"t": time.time(), "tm": time.perf_counter(), "ev": ev}
         rec.update(fields)          # a phase passes its own end as tm
-        try:
-            line = json.dumps(rec) + "\n"
-        except (TypeError, ValueError):
-            # a non-serializable field must not kill a calibration run;
-            # keep the record with offenders stringified
-            rec = {k: (v if isinstance(v, (int, float, str, bool,
-                                           type(None))) else repr(v))
-                   for k, v in rec.items()}
-            line = json.dumps(rec) + "\n"
         with self._lock:
-            self._f.write(line)
+            self._buf.append(rec)
+            full = len(self._buf) >= FLUSH_AT
+        if full:
+            self.flush()
 
-    def phase(self, name: str, **fields):
-        return _Phase(self, name, fields)
+    def flush(self) -> None:
+        """Serialise and write what is held (no-op once closed)."""
+        with self._lock:
+            if self._f.closed:
+                return
+            buf, self._buf = self._buf, []
+            for rec in buf:
+                try:
+                    line = json.dumps(rec)
+                except (TypeError, ValueError):
+                    # a non-serializable field must not kill a
+                    # calibration run; keep the record with offenders
+                    # stringified
+                    line = json.dumps({
+                        k: (v if isinstance(v, (int, float, str, bool,
+                                                type(None))) else repr(v))
+                        for k, v in rec.items()})
+                self._f.write(line + "\n")
+            self._f.flush()
 
     def close(self) -> None:
         if self._f.closed:
             return
         self.emit("run_end", wall_s=time.time() - self._t0)
+        self.flush()
         self._f.close()
+        atexit.unregister(self.flush)
 
 
 class _Phase:
     """Context manager timing one host phase: a profiler annotation
     ``sagecal/<name>`` around the body (when an annotator is set) and
-    one ``phase`` record on exit (when a tracer is live)."""
+    one ``phase`` record on exit (when a tracer is live), with the
+    span's ``id``, its ``parent`` on this thread and the ``thread``."""
 
     __slots__ = ("_tr", "_name", "_fields", "_t0", "_ann", "_less",
-                 "dur_s")
+                 "_id", "_parent", "dur_s")
 
     def __init__(self, tracer, name, fields):
         self._tr = tracer
@@ -241,19 +302,39 @@ class _Phase:
         the end of input, not for a tile)."""
         self._tr = None
 
+    def set_tile(self, tile) -> None:
+        """The span learned its tile inside its body (the tile id comes
+        out of the ``next()`` an ``io`` span waits in)."""
+        self._fields["tile"] = tile
+
     def carve(self, name: str, dur_s: float) -> None:
         """``dur_s`` of this span's seconds belong to phase ``name``
         (a consumer's block that overlapped the producer's wait for a
-        tile to arrive): they are emitted as that phase, with this
-        span's fields, and taken off this span's own ``dur_s``."""
+        tile to arrive): they are emitted as that phase, beside this
+        span (its parent, its fields), and taken off this span's own
+        ``dur_s``."""
         self._less += dur_s
         if self._tr is not None:
-            self._tr.emit("phase", name=name, dur_s=dur_s, **self._fields)
+            self._tr.emit("phase", name=name, dur_s=dur_s, id=next(_IDS),
+                          parent=self._parent, thread=_OPEN.thread,
+                          **self._fields)
 
     def __enter__(self):
+        stack = getattr(_OPEN, "stack", None)
+        if stack is None:
+            stack = _OPEN.stack = []
+            _OPEN.thread = threading.current_thread().name
+        f = self._fields
+        self._id = next(_IDS)
+        self._parent = None
+        if stack:
+            above = stack[-1]
+            self._parent = above._id
+            if "tile" not in f and "tile" in above._fields:
+                f["tile"] = above._fields["tile"]
+        stack.append(self)
         self._ann = None
         if _ANNOTATOR is not None:
-            f = self._fields
             self._ann = _ANNOTATOR(ANNOTATION_PREFIX + self._name,
                                    **({"tile": f["tile"]} if "tile" in f
                                       else {}))
@@ -265,10 +346,12 @@ class _Phase:
         t1 = time.perf_counter()
         if self._ann is not None:
             self._ann.__exit__(*exc)
+        _OPEN.stack.pop()
         self.dur_s = t1 - self._t0 - self._less
         if self._tr is not None:
             self._tr.emit("phase", name=self._name, dur_s=self.dur_s,
-                          tm=t1, **self._fields)
+                          tm=t1, id=self._id, parent=self._parent,
+                          thread=_OPEN.thread, **self._fields)
         return False
 
 
@@ -285,6 +368,9 @@ class _NullPhase:
         return False
 
     def drop(self) -> None:
+        pass
+
+    def set_tile(self, tile) -> None:
         pass
 
     def carve(self, name, dur_s) -> None:

@@ -743,6 +743,9 @@ class FullBatchPipeline:
             # atomic MS write), so the writer retry layer recovers a
             # transient fault here
             faults.inject("residual_fetch", key=ti)
+            # what blocks on the residual program's execution, apart
+            # from the copy and the disk
+            sched.wait_device(res_r)
             n_rows = tile.x.shape[0]
             # fetch through float64: numpy-side r2c on ml_dtypes bf16
             # arrays is not supported, and the MS stores complex128
@@ -1070,7 +1073,8 @@ class FullBatchPipeline:
         # a synchronous loop, in the calibrate path's vocabulary: the
         # phases io / stage / predict (a dispatch) / fetch (the wait
         # for the device and the copy) / write, and per tile one
-        # ``tile`` record with bubble_s = io + write at overlap 0
+        # ``tile`` record with bubble_s = io + write at overlap 0; the
+        # loop's thread is in "io" or in the root "step" at every instant
         tiles = iter(ms.tiles())
         while True:
             with dtrace.phase("io") as ph_io:   # the tile id comes out
@@ -1079,30 +1083,36 @@ class FullBatchPipeline:
                 except StopIteration:
                     ph_io.drop()
                     break
-            with dtrace.phase("stage", tile=ti):
-                J_r8 = None
-                if blocks_iter:
-                    J_r8 = jnp.asarray(utils.jones_c2r_np(
-                        blocks_iter[min(ti, len(blocks_iter) - 1)]),
-                        self.rdt)
-                args = (jnp.asarray(utils.c2r(tile.x), self.rdt),
-                        jnp.asarray(tile.u, self.rdt),
-                        jnp.asarray(tile.v, self.rdt),
-                        jnp.asarray(tile.w, self.rdt),
-                        jnp.asarray(tile.sta1), jnp.asarray(tile.sta2),
-                        J_r8, self._tile_beam(tile))
-            with dtrace.phase("predict", tile=ti):
-                out_r = sim_jit(*args)
-            with dtrace.phase("fetch", tile=ti):
-                out = np.asarray(out_r)
-            with dtrace.phase("write", tile=ti) as ph_write:
-                tile.x = utils.r2c(out).astype(np.complex128)
-                ms.write_tile(ti, tile)
-            if dtrace.active():
-                dtrace.emit("tile", tile=ti, overlap=0,
-                            bubble_s=ph_io.dur_s + ph_write.dur_s,
-                            mode=mode, clusters_in_model=clusters_in_model)
-            log(f"Timeslot: {ti} simulated (mode={mode})")
+                ph_io.set_tile(ti)
+            with dtrace.phase("step", tile=ti):
+                with dtrace.phase("stage"):
+                    J_r8 = None
+                    if blocks_iter:
+                        J_r8 = jnp.asarray(utils.jones_c2r_np(
+                            blocks_iter[min(ti, len(blocks_iter) - 1)]),
+                            self.rdt)
+                    args = (jnp.asarray(utils.c2r(tile.x), self.rdt),
+                            jnp.asarray(tile.u, self.rdt),
+                            jnp.asarray(tile.v, self.rdt),
+                            jnp.asarray(tile.w, self.rdt),
+                            jnp.asarray(tile.sta1), jnp.asarray(tile.sta2),
+                            J_r8, self._tile_beam(tile))
+                with dtrace.phase("predict"):
+                    out_r = sim_jit(*args)
+                with dtrace.phase("fetch"):
+                    # blocked on the program's execution; the copy is
+                    # fetch's own
+                    sched.wait_device(out_r)
+                    out = np.asarray(out_r)
+                with dtrace.phase("write") as ph_write:
+                    tile.x = utils.r2c(out).astype(np.complex128)
+                    ms.write_tile(ti, tile)
+                if dtrace.active():
+                    dtrace.emit("tile", tile=ti, overlap=0,
+                                bubble_s=ph_io.dur_s + ph_write.dur_s,
+                                mode=mode,
+                                clusters_in_model=clusters_in_model)
+                log(f"Timeslot: {ti} simulated (mode={mode})")
 
 
 class _WarmTileProfile:
@@ -1389,6 +1399,12 @@ class TileStepper:
     # -- device-owner half --------------------------------------------------
 
     def step(self, ti, tile, stg, io_wait=0.0, degrade=False):
+        # the root span of a tile's cycle on the device-owner thread:
+        # with the consumer's "io" it covers the cycle (diag/trace.py)
+        with dtrace.phase("step", tile=ti):
+            return self._step(ti, tile, stg, io_wait, degrade)
+
+    def _step(self, ti, tile, stg, io_wait, degrade):
         p = self.p
         cfg, ms, sky, meta = p.cfg, p.ms, p.sky, p.ms.meta
         log = self.log
@@ -1423,17 +1439,20 @@ class TileStepper:
         else:
             solver = p._solve_first if self.first else p._solve_rest
             J_prev = self.J          # the last-good chain (quarantine)
-            J_r8 = jnp.asarray(utils.jones_c2r_np(self.J), p.rdt)
+            with dtrace.phase("carry"):
+                J_r8 = jnp.asarray(utils.jones_c2r_np(self.J), p.rdt)
             t_solve = time.perf_counter()
             # the span ends in the read-backs the step needs anyway
             with dtrace.phase("solve", tile=ti):
                 Jd_r8, info = solver(x8, u, v, w, sta1, sta2, wt, J_r8,
                                      tile_beam, tile_idx=ti)
                 self.first = False
-                res_0 = float(info["res_0"])
-                res_1 = float(info["res_1"])
-                mean_nu = float(info["mean_nu"])
-                self.J = utils.jones_r2c_np(np.asarray(Jd_r8))
+                with dtrace.phase("wait"):      # blocked on the device
+                    res_0 = float(info["res_0"])
+                    res_1 = float(info["res_1"])
+                    mean_nu = float(info["mean_nu"])
+                    Jd_r8 = np.asarray(Jd_r8)
+                self.J = utils.jones_r2c_np(Jd_r8)
             obs.observe("tile_solve_seconds",
                         time.perf_counter() - t_solve)
         # solve_nan: the poisoned-tile chaos seam (a NaN/nonfinite
@@ -1491,11 +1510,14 @@ class TileStepper:
                                          self.J, sky.nchunk)
 
             if self.write_residuals:
-                with dtrace.phase("residual", tile=ti):   # a dispatch
-                    res_r = p._residual_fn(
-                        jnp.asarray(utils.jones_c2r_np(self.J), p.rdt),
-                        self.ring.take(ti),
-                        u, v, w, sta1, sta2, tile_beam)
+                with dtrace.phase("residual", tile=ti):
+                    with dtrace.phase("carry"):
+                        J_r8 = jnp.asarray(utils.jones_c2r_np(self.J),
+                                           p.rdt)
+                    x_r = self.ring.take(ti)
+                    with dtrace.phase("dispatch", prog="residual"):
+                        res_r = p._residual_fn(J_r8, x_r, u, v, w, sta1,
+                                               sta2, tile_beam)
                 if self.depth > 0:
                     # non-blocking d->h copy now; fetch + MS
                     # write on the ordered writer thread
@@ -1538,26 +1560,27 @@ class TileStepper:
 
         self._last_tile = ti
         dt = (time.time() - t0) / 60.0
-        if not degraded:
-            log(f"Timeslot: {ti} Residual: initial={res_0:.6g}, "
-                f"final={res_1:.6g}, Time spent={dt:.3g} minutes, "
-                f"nu={mean_nu:.2f}")
-        rec = {"tile": ti, "res_0": res_0, "res_1": res_1,
-               "mean_nu": mean_nu, "minutes": dt}
-        if isinstance(info, dict) and "solver_iters" in info:
-            # executed inner-solver trips — the sweeps-to-convergence
-            # signal the serve layer aggregates per job (loadgen
-            # replay rows) and benchmarks/ reads. The solve already
-            # synced on res_0/res_1, so this fetch adds no wait.
-            rec["solver_iters"] = int(
-                np.asarray(info["solver_iters"]).sum())
-        if quarantined:
-            rec["quarantined"] = True
-        if degraded:
-            rec["degraded"] = True
-        self.history.append(rec)
-        _emit_tile_record(ti, res_0, res_1, mean_nu, info, dt,
-                          bubble_s=bubble, overlap=self.depth)
+        with dtrace.phase("record"):
+            if not degraded:
+                log(f"Timeslot: {ti} Residual: initial={res_0:.6g}, "
+                    f"final={res_1:.6g}, Time spent={dt:.3g} minutes, "
+                    f"nu={mean_nu:.2f}")
+            rec = {"tile": ti, "res_0": res_0, "res_1": res_1,
+                   "mean_nu": mean_nu, "minutes": dt}
+            if isinstance(info, dict) and "solver_iters" in info:
+                # executed inner-solver trips — the sweeps-to-convergence
+                # signal the serve layer aggregates per job (loadgen
+                # replay rows) and benchmarks/ reads. The solve already
+                # synced on res_0/res_1, so this fetch adds no wait.
+                rec["solver_iters"] = int(
+                    np.asarray(info["solver_iters"]).sum())
+            if quarantined:
+                rec["quarantined"] = True
+            if degraded:
+                rec["degraded"] = True
+            self.history.append(rec)
+            _emit_tile_record(ti, res_0, res_1, mean_nu, info, dt,
+                              bubble_s=bubble, overlap=self.depth)
         return rec
 
     def _observe_stream_latency(self, ti, t_arr):

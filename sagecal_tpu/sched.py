@@ -81,6 +81,18 @@ def start_host_copy(*arrays) -> None:
             fn()
 
 
+def wait_device(*arrays) -> None:
+    """While a tracer is on, block until the arrays' execution has
+    ended, under a "wait" span (diag/trace.py: the one name for a host
+    thread blocked on the device), so that the read-back that follows
+    is its caller's own time. Without a tracer: nothing, the read-back
+    waits as it always did."""
+    if dtrace.active():
+        with dtrace.phase("wait"):
+            for a in arrays:
+                a.block_until_ready()
+
+
 class EndOfStream(Exception):
     """Raised by an open-ended producer (``n=None``) — by ``fn`` or by
     the ``arrive`` hook — to signal clean end of input. NOT an error:
@@ -447,6 +459,12 @@ class AsyncWriter:
             raise exc
 
     def submit(self, fn, *args, **kwargs) -> float:
+        # the caller's "submit" span: the hand-over, back-pressure and,
+        # at depth 0, the inline job
+        with dtrace.phase("submit"):
+            return self._submit(fn, args, kwargs)
+
+    def _submit(self, fn, args, kwargs) -> float:
         self.check()
         if not self.enabled:
             # inline (--prefetch 0) execution keeps the SAME transient

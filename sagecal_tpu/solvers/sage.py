@@ -106,7 +106,10 @@ def _call(name, jfn, *args, **kwargs):
               {k: _spec_of(v) for k, v in kwargs.items()})
     rec[2] += 1
     _DISPATCHED.n = _dispatched() + 1
-    return jfn(*args, **kwargs)
+    # one device execution enqueued: the host's share of a solve that
+    # is not a wait (the null context without --diag or --profile)
+    with dtrace.phase("dispatch", prog=name):
+        return jfn(*args, **kwargs)
 
 
 #: what :func:`_plan_info` adds to a host-driven solve's info dict: host
@@ -1327,8 +1330,9 @@ def sagefit_host(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
                 kci, jnp.asarray(order, jnp.int32), os_ids,
                 n_stations, cfg_i, total_iter, iter_bar, os_nsub)
             tk_total = tk_total + tk
-            # jaxlint: disable=host-sync -- deliberate ONE-per-sweep timing barrier: the auto fuse/promote plan learns from real sweep wall-clock (bounded-execution contract)
-            jax.block_until_ready(J)
+            with dtrace.phase("wait"):
+                # jaxlint: disable=host-sync -- deliberate ONE-per-sweep timing barrier: the auto fuse/promote plan learns from real sweep wall-clock (bounded-execution contract)
+                jax.block_until_ready(J)
             sweep_times.append(time.perf_counter() - t_sweep)
         else:
             t_sweep = time.perf_counter()
@@ -1357,8 +1361,9 @@ def sagefit_host(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
                         jnp.asarray(last), kci, os_ids, n_stations,
                         cfg_i, total_iter, iter_bar, os_nsub, anchor)
                     tk_total = tk_total + tk
-            # jaxlint: disable=host-sync -- deliberate ONE-per-sweep timing barrier: the fuse=auto verdict needs the unfused sweep's real wall-clock
-            jax.block_until_ready(J)
+            with dtrace.phase("wait"):
+                # jaxlint: disable=host-sync -- deliberate ONE-per-sweep timing barrier: the fuse=auto verdict needs the unfused sweep's real wall-clock
+                jax.block_until_ready(J)
             # the fused program does the same work minus dispatch overhead,
             # so a 25 s per-cluster sweep bounds its single execution
             if fuse_mode == "auto":
@@ -1647,8 +1652,9 @@ def sagefit_host_tiles(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
                 kci, order, os_ids, n_stations, cfg_i, total_iter,
                 iter_bar, os_nsub)
             tk_total = tk_total + tk
-            # jaxlint: disable=host-sync -- deliberate ONE-per-sweep timing barrier: the auto fuse/promote plan learns from real sweep wall-clock (bounded-execution contract)
-            jax.block_until_ready(J)
+            with dtrace.phase("wait"):
+                # jaxlint: disable=host-sync -- deliberate ONE-per-sweep timing barrier: the auto fuse/promote plan learns from real sweep wall-clock (bounded-execution contract)
+                jax.block_until_ready(J)
             sweep_times.append(time.perf_counter() - t_sweep)
         else:
             nerr_acc = jnp.zeros((T, M), dtype)
@@ -1677,8 +1683,9 @@ def sagefit_host_tiles(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
                         jnp.asarray(last), kci, os_ids, n_stations,
                         cfg_i, total_iter, iter_bar, os_nsub, anchor)
                     tk_total = tk_total + tk
-            # jaxlint: disable=host-sync -- deliberate ONE-per-sweep timing barrier: the fuse=auto verdict needs the unfused sweep's real wall-clock
-            jax.block_until_ready(J)
+            with dtrace.phase("wait"):
+                # jaxlint: disable=host-sync -- deliberate ONE-per-sweep timing barrier: the fuse=auto verdict needs the unfused sweep's real wall-clock
+                jax.block_until_ready(J)
             if fuse_mode == "auto":
                 fused = time.perf_counter() - t_sweep < 25.0
                 _FUSION_CACHE[fuse_key] = fused

@@ -20,7 +20,7 @@ import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SUITE = "benchmarks/tests"
-LIMIT_S = 900       # 140 s alone on this sandbox (PR 30), beside 5 workers
+LIMIT_S = 1100      # 140 s alone (PR 30), 520-640 s beside 5 workers (PR 40)
 PYTEST = [sys.executable, "-m", "pytest", SUITE, "-q",
           "-p", "no:cacheprovider", "-p", "no:randomly"]
 
@@ -55,6 +55,17 @@ IDS, COLLECT_ERROR = _collect()
 OVERTAKEN = {
     "test_tcg_trips.py::test_entry_is_appended_for_the_one_cell":
         "per_layer[-1] is no longer tcg_trips: new entries go at the end",
+    # PR 40 appended two entries that list every cell: these three hold a
+    # cell's WHOLE per-layer list (one of them the manifest's last
+    # entries too) and fail from the first entry appended for their cell.
+    # benchmarks/tests/test_host_spans.py runs each of them whole on the
+    # manifest less the two new entries
+    # (test_what_pins_a_cells_list_by_place_holds_less_the_new_entries),
+    # so what they guard stays guarded, case for case.
+    **{f"{module}.py::test_the_cell_is_files_and_entries":
+       "the cell's per-layer list has grown by host_serial_ms and "
+       "chip_wait_ms: new entries go at the end, for every cell"
+       for module in ("test_subtract", "test_t120", "test_consensus")},
 }
 
 

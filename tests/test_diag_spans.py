@@ -244,6 +244,303 @@ def test_prefetcher_emits_the_consumers_io_phase_with_absolute_tile_ids(
 
 
 # ---------------------------------------------------------------------------
+# ISSUE 40: a span can be placed in a tree (id, parent, thread, tile), the
+# records are kept in memory, and the three loops are covered by spans
+# ---------------------------------------------------------------------------
+
+def _phases(path):
+    return [r for r in trace.read(str(path)) if r["ev"] == "phase"]
+
+
+def test_nested_spans_carry_id_parent_and_thread_across_two_threads(
+        tmp_path):
+    """A child's parent is the span open on its OWN thread: a thread
+    spawned inside a span starts with no parent, never the spawner's."""
+    import threading
+
+    path = tmp_path / "t.jsonl"
+    trace.enable(str(path))
+
+    def worker():
+        with trace.phase("write", tile=3, bg=True):
+            with trace.phase("wait"):
+                pass
+
+    with trace.phase("step", tile=3):
+        with trace.phase("solve"):
+            th = threading.Thread(target=worker, name="async-writer")
+            th.start()
+            th.join(timeout=30)
+            assert not th.is_alive()
+            with trace.phase("dispatch", prog="sagefit"):
+                pass
+    trace.disable()
+    by = {r["name"]: r for r in _phases(path)}
+    ids = [r["id"] for r in by.values()]
+    assert len(set(ids)) == 5 and all(isinstance(i, int) for i in ids)
+    assert by["step"]["parent"] is None
+    assert by["solve"]["parent"] == by["step"]["id"]
+    assert by["dispatch"]["parent"] == by["solve"]["id"]
+    assert by["dispatch"]["prog"] == "sagefit"
+    # the spawned thread's root has no parent; its child is its own
+    assert by["write"]["parent"] is None and by["write"]["bg"] is True
+    assert by["wait"]["parent"] == by["write"]["id"]
+    main = threading.current_thread().name
+    assert {n: by[n]["thread"] for n in by} == {
+        "step": main, "solve": main, "dispatch": main,
+        "write": "async-writer", "wait": "async-writer"}
+    # ids are taken as a span is entered: a parent's is the smaller
+    assert by["step"]["id"] < by["solve"]["id"] < by["dispatch"]["id"]
+    # a span without a tile takes the tile of the span that holds it
+    assert {by[n]["tile"] for n in by} == {3}
+    # what the records held before is still there
+    for r in by.values():
+        assert r["dur_s"] >= 0 and r["tm"] >= r["dur_s"] and "t" in r
+
+
+def test_a_span_sets_its_tile_late_and_a_carved_phase_stands_beside_it(
+        tmp_path):
+    path = tmp_path / "t.jsonl"
+    trace.enable(str(path))
+    with trace.phase("io") as ph:       # the tile id comes out of next()
+        ph.set_tile(11)
+        ph.carve("arrival_wait", 0.0)
+    with trace.phase("io") as ph:
+        ph.drop()
+    trace.disable()
+    io, = [r for r in _phases(path) if r["name"] == "io"]
+    carved, = [r for r in _phases(path) if r["name"] == "arrival_wait"]
+    assert io["tile"] == 11 and io["parent"] is None
+    assert carved["tile"] == 11 and carved["parent"] is None
+    assert carved["id"] != io["id"] and carved["thread"] == io["thread"]
+
+
+def test_records_are_kept_in_memory_and_written_at_close(tmp_path):
+    path = tmp_path / "t.jsonl"
+    trace.enable(str(path), entry="test")
+    with trace.phase("solve", tile=0):
+        trace.emit("em_sweep", sweep=0, wall_s=0.1, fused=True,
+                   err_reduction=1.0, solver_iters=3)
+    assert path.read_text() == ""       # nothing written on the hot path
+    trace.disable()
+    recs = trace.read(str(path))
+    assert [r["ev"] for r in recs] == ["run_start", "em_sweep", "phase",
+                                       "run_end"]
+    assert recs[0]["entry"] == "test"
+    tms = [r["t"] for r in recs]
+    assert tms == sorted(tms)           # the order they were emitted in
+
+
+def test_records_are_written_when_the_buffer_reaches_its_cap(
+        tmp_path, monkeypatch):
+    monkeypatch.setattr(trace, "FLUSH_AT", 4)
+    path = tmp_path / "t.jsonl"
+    trace.enable(str(path))             # run_start is the first record
+    for k in range(2):
+        trace.emit("stage_bytes", bytes=k, what="x")
+    assert path.read_text() == ""
+    trace.emit("stage_bytes", bytes=2, what="x")        # the fourth
+    assert len(path.read_text().splitlines()) == 4
+    trace.emit("stage_bytes", bytes=3, what="x")
+    assert len(path.read_text().splitlines()) == 4
+    trace.disable()
+    recs = trace.read(str(path))
+    assert [r.get("bytes") for r in recs] == [None, 0, 1, 2, 3, None]
+
+
+def test_records_are_written_at_exit_where_a_run_never_closes(tmp_path):
+    """A crashed run: the tracer is never closed, the interpreter's
+    exit writes what it holds (no ``run_end``)."""
+    path = tmp_path / "crash.jsonl"
+    code = textwrap.dedent(f"""
+        import sys, types
+        pkg = types.ModuleType("sagecal_tpu")
+        pkg.__path__ = [{os.path.join(ROOT, "sagecal_tpu")!r}]
+        sys.modules["sagecal_tpu"] = pkg
+        import sagecal_tpu.diag.trace as t
+        t.enable({str(path)!r}, entry="crash")
+        with t.phase("solve", tile=2):
+            pass
+        raise SystemExit(7)
+    """)
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=60)
+    assert out.returncode == 7, out.stderr
+    recs = trace.read(str(path))
+    assert [r["ev"] for r in recs] == ["run_start", "phase"]
+    assert recs[1]["name"] == "solve" and recs[1]["tile"] == 2
+
+
+@pytest.mark.parametrize("name, fields", [
+    ("step", {"tile": 1}), ("carry", {}), ("dispatch", {"prog": "sagefit"}),
+    ("wait", {}), ("submit", {}), ("record", {}), ("primal", {}),
+])
+def test_every_new_site_is_the_shared_null_phase_when_nothing_listens(
+        name, fields):
+    trace.set_annotator(jax.profiler.TraceAnnotation)
+    ph = trace.phase(name, **fields)
+    assert ph is trace._NULL_PHASE
+    with ph as inside:
+        inside.set_tile(4)              # the method sites call exists
+    assert not getattr(trace._OPEN, "stack", None)
+
+
+def test_tracer_phase_method_is_gone_and_sage_call_makes_a_dispatch(
+        tmp_path):
+    """``Tracer.phase`` had no caller: every site calls the module's
+    ``phase()``.  ``sage._call`` wraps one device execution in a
+    ``dispatch`` span named by the label it already has."""
+    from sagecal_tpu.solvers import sage
+
+    assert not hasattr(trace.Tracer, "phase")
+    f = jax.jit(lambda a: a + 1)
+    assert float(sage._call("issue40_off", f, jnp.ones(()))) == 2.0
+    path = tmp_path / "t.jsonl"
+    trace.enable(str(path))
+    with trace.phase("solve", tile=5):
+        n0 = sage._dispatched()
+        assert float(sage._call("issue40_on", f, jnp.ones(()))) == 2.0
+        assert sage._dispatched() == n0 + 1
+    trace.disable()
+    sage.program_stats_reset()
+    d, = [r for r in _phases(path) if r["name"] == "dispatch"]
+    assert d["prog"] == "issue40_on" and d["tile"] == 5
+    assert d["parent"] == next(r["id"] for r in _phases(path)
+                               if r["name"] == "solve")
+
+
+def _cover(recs):
+    """(largest share of a cycle under no root span, the cycles, the
+    ``wait`` spans with their ancestors' names) of one run's records:
+    a cycle runs from one root ``step``'s start to the next one's."""
+    ph = {r["id"]: r for r in recs if r["ev"] == "phase"}
+    steps = sorted((r for r in ph.values()
+                    if r["name"] == "step" and r["parent"] is None),
+                   key=lambda r: r["tm"])
+    assert len({r["thread"] for r in steps}) == 1
+    roots = [r for r in ph.values() if r["parent"] is None
+             and r["thread"] == steps[0]["thread"] and not r.get("bg")]
+    assert {r["name"] for r in roots} <= {"io", "step", "arrival_wait"}
+    shares = []
+    for a, b in zip(steps, steps[1:]):
+        t0, t1 = a["tm"] - a["dur_s"], b["tm"] - b["dur_s"]
+        covered = sum(r["dur_s"] for r in roots
+                      if t0 <= r["tm"] - r["dur_s"] < t1)
+        shares.append(1.0 - covered / (t1 - t0))
+    waits = []
+    for r in ph.values():
+        if r["name"] == "wait":
+            up, names = r, []
+            while up["parent"] is not None:
+                up = ph[up["parent"]]
+                names.append(up["name"])
+            waits.append(names)
+    return shares, steps, waits
+
+
+def _calibrate(root, extra):
+    from sagecal_tpu import cli
+    from sagecal_tpu.io import dataset as ds
+
+    rc = cli.main(["-d", os.path.join(root, "sim.ms"),
+                   "-s", os.path.join(root, "sky.txt"),
+                   "-c", os.path.join(root, "sky.txt.cluster"),
+                   "-p", os.path.join(root, "sol.txt"),
+                   "-e", "1", "-g", "2", "-l", "2", "-j", "1", "-B", "0",
+                   # the plan learner is module-global and would hand
+                   # each run another plan: the host-driven one, pinned
+                   "--solve-fuse", "on", "--solve-promote", "off",
+                   *extra])
+    assert rc == 0
+    ms = ds.SimMS(os.path.join(root, "sim.ms"),
+                  data_column="CORRECTED_DATA")
+    return [ms.read_tile(t).x.tobytes() for t in range(ms.n_tiles)] + [
+        open(os.path.join(root, "sol.txt"), "rb").read()]
+
+
+def _simulate(root, extra):
+    from sagecal_tpu import cli
+    from sagecal_tpu.io import dataset as ds
+
+    rc = cli.main(["-d", os.path.join(root, "sim.ms"),
+                   "-s", os.path.join(root, "sky.txt"),
+                   "-c", os.path.join(root, "sky.txt.cluster"),
+                   "-a", "1", *extra])
+    assert rc == 0
+    ms = ds.SimMS(os.path.join(root, "sim.ms"),
+                  data_column="CORRECTED_DATA")
+    return [ms.read_tile(t).x.tobytes() for t in range(ms.n_tiles)]
+
+
+def _consensus(root, extra):
+    from sagecal_tpu import cli_mpi
+    from sagecal_tpu.io import dataset as ds
+    import test_consensus_stepper as tcs
+
+    assert cli_mpi.main(tcs.argv(root) + list(extra)) == 0
+    out = [open(os.path.join(root, "zsol.txt"), "rb").read()]
+    for k in range(tcs.NF):
+        ms = ds.SimMS(os.path.join(root, f"sb{k}.ms"),
+                      data_column="CORRECTED_DATA")
+        out += [ms.read_tile(t).x.tobytes() for t in range(ms.n_tiles)]
+        out.append(open(os.path.join(root, f"sb{k}.ms.solutions"),
+                        "rb").read())
+    return out
+
+
+def _make_calibrate(root):
+    from test_diag import _make_sim_dataset
+    os.makedirs(root)
+    _make_sim_dataset(type(root)(root), n_tiles=4)
+
+
+def _make_consensus(root):
+    import test_consensus_stepper as tcs
+    tcs.make_observation(str(root))
+
+
+@pytest.mark.parametrize("make, drive, parents", [
+    (_make_calibrate, _calibrate, {"solve", "write"}),
+    (_make_consensus, _consensus, {"solve", "stage", "write"}),
+    (_make_calibrate, _simulate, {"fetch"}),
+], ids=["calibrate", "consensus", "simulate"])
+def test_step_and_io_cover_the_cycle_and_the_tracer_changes_nothing(
+        tmp_path, make, drive, parents):
+    """On the tiny CPU run of each of the three loops: the outputs and
+    ``guard.compile_count()`` are the same with the tracer on and off
+    (run 1 compiles; runs 2 and 3 are compared); the loop's thread is in
+    ``io`` or in the root ``step`` for all but 5 % of a cycle; every
+    ``wait`` is under a ``solve``, ``fetch``, ``stage`` or ``write``."""
+    import shutil
+
+    src = tmp_path / "src"
+    make(src)
+    outs, counts = [], []
+    tr = tmp_path / "diag.jsonl"
+    for k, extra in enumerate(([], ["--diag", str(tr)], [])):
+        root = str(tmp_path / f"run{k}")
+        shutil.copytree(str(src), root)
+        c0 = guard.compile_count()
+        outs.append(drive(root, extra))
+        counts.append(guard.compile_count() - c0)
+    assert outs[0] == outs[1] == outs[2]
+    assert counts[1] == counts[2], counts
+    assert not trace.active()
+
+    shares, steps, waits = _cover(trace.read(str(tr)))
+    # work under no span would show in EVERY cycle; on a busy host the
+    # writer thread takes the interpreter between two spans of a 15 ms
+    # tile now and then, which is no work of the loop's
+    assert len(steps) >= 3 and min(shares) < 0.05, shares
+    assert waits and {w[0] for w in waits} <= parents, waits
+    assert all({"solve", "fetch", "stage", "write"} & set(w) for w in waits)
+    # every span of the loops carries its tile
+    recs = [r for r in trace.read(str(tr)) if r["ev"] == "phase"]
+    assert all("tile" in r for r in recs), [
+        r["name"] for r in recs if "tile" not in r]
+
+
+# ---------------------------------------------------------------------------
 # part B: the compile log
 # ---------------------------------------------------------------------------
 

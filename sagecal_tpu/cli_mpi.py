@@ -541,11 +541,13 @@ class ConsensusStepper:
                 dtype_policy=getattr(args, "dtype_policy", "f32")))
 
         t0 = self.t0 = mss[0].read_tile(0)
-        # host value for the interval's tile record: the row layout the
-        # J updates assemble their Gauss-Newton matrix from
-        self.assemble_rows = sage.assemble_rows(
-            cfg.sage._replace(nbase=int(meta0["nbase"])), kmax,
-            len(t0.sta1))
+        # host values for the interval's tile record: the row layouts
+        # the J updates carry their running residual on and assemble
+        # their Gauss-Newton matrix from
+        cfg_rows = cfg.sage._replace(nbase=int(meta0["nbase"]))
+        self.sweep_rows = sage.sweep_rows(cfg_rows, kmax, len(t0.sta1))
+        self.assemble_rows = sage.assemble_rows(cfg_rows, kmax,
+                                                len(t0.sta1))
         plans = [nm for nm, on in (("--block-f", args.block_f),
                                    ("--host-loop", args.host_loop),
                                    ("--time-shard", args.time_shard > 1),
@@ -1108,6 +1110,7 @@ class ConsensusStepper:
                             rho_mean=float(np.asarray(
                                 self._fetch(rhoF))[:nf].mean()),
                             bubble_s=float(bubble), overlap=self.depth,
+                            sweep_rows=self.sweep_rows,
                             **({} if self.assemble_rows is None else
                                {"assemble_rows": self.assemble_rows}))
         self._last = ti

@@ -54,7 +54,10 @@ event            meaning / required extra fields
                  ``refine_passes`` (passes through the model the joint
                  refine made: solvers/lbfgs.py), ``refine_rows`` (the
                  row layout those passes worked on, "periodic" or
-                 "flat": solvers/sage.py), ``assemble_rows`` (the
+                 "flat": solvers/sage.py), ``sweep_rows`` (the row
+                 layout the sweeps carried their running residual and
+                 evaluated the cluster models on, "periodic" or
+                 "flat": sage.sweep_rows), ``assemble_rows`` (the
                  row layout the sweeps' Gauss-Newton matrix was
                  assembled from, "periodic" or "generic":
                  sage.assemble_rows), ``plan`` and

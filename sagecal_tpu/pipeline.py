@@ -89,13 +89,13 @@ def _emit_tile_record(ti, res_0, res_1, mean_nu, info, minutes,
     # (LM's PCG, RTR's truncated CG) and RTR's passes over the rows, and
     # the joint refine's passes through the model (lbfgs._lbfgs_loop),
     # which plan sagefit_host ran in how many device executions, and
-    # the row layouts its refine and its sweeps' assembly worked on
+    # the row layouts its refine, its sweeps and their assembly worked on
     for k in ("solver_iters", "cg_iters", "row_passes", "lbfgs_iters",
               "refine_passes", "solve_dispatches"):
         if k in trips:
             rec[k] = trips[k]
     if isinstance(info, dict):
-        for k in ("plan", "refine_rows", "assemble_rows"):
+        for k in ("plan", "refine_rows", "sweep_rows", "assemble_rows"):
             if k in info:
                 rec[k] = info[k]
     dtrace.emit("tile", **rec)

@@ -294,10 +294,12 @@ def model8(coh_m, J_m, sta1, sta2, chunk_idx_m, out_dtype=None):
     (sagecal_tpu.dtypes): the model EVALUATION is complex (c64 — J and
     the coherencies never quantize) and the emitted real stream casts
     to the storage dtype exactly where it joins the [B]-residual
-    traffic; a no-op for f32/f64. The solver-side twins
-    (solvers.sage._model8 / normal_eq.residual8) follow the same
-    contract — this is the rime-layer entry point for embedders that
-    build their own residual streams.
+    traffic; a no-op for f32/f64. The solvers evaluate the same
+    bilinear form on real planes under the same contract
+    (normal_eq.row_model, normal_eq.residual8, the sweep of
+    solvers/sage.py) — this is the rime-layer entry point for embedders
+    that build their own residual streams, and the plain reference the
+    tests hold the planes against.
     """
     from sagecal_tpu import dtypes as dtp
     Jp = J_m[chunk_idx_m, sta1]

@@ -27,6 +27,7 @@ layout (Dirac.h:1541-1546).
 
 from __future__ import annotations
 
+import copy
 from typing import NamedTuple
 
 import jax
@@ -145,6 +146,7 @@ class RowPlanes:
                 lead[0], dtype=chunk_id.dtype)[:, None]
             kmax *= lead[0]
         self.kmax, self.n_stations, self.chunk_id = kmax, n_stations, chunk_id
+        self.sta1, self.sta2 = sta1, sta2
         self.i1 = (chunk_id * n_stations + sta1)[..., :R]
         self.i2 = (chunk_id * n_stations + sta2)[..., :R]
         self.x, self.w = (None if a is None else self.planes(a)
@@ -159,6 +161,36 @@ class RowPlanes:
     def to_rows(self, a):
         """[8, *rows] -> [B, 8]."""
         return jnp.moveaxis(a.reshape(8, -1), 0, -1)
+
+    def cluster(self, m):
+        """Cluster ``m``'s rows of an instance over all clusters, as an
+        instance over one: what a cluster solve takes
+        (:func:`rtr.rtr_rows`) and a cluster's model is evaluated on
+        (the sweep of ``solvers/sage.py``); the sqrt-weights stay, the
+        data are the caller's to give (:meth:`with_x`). Slices of what
+        this instance holds: nothing is laid out again."""
+        out = copy.copy(self)
+        out.kmax = self.kmax // self.c.shape[1]
+        off = m * out.kmax
+        out.chunk_id = self.chunk_id[m] - off
+        out.i1 = self.i1[m] - off * self.n_stations
+        out.i2 = self.i2[m] - off * self.n_stations
+        out.c = self.c[:, m]
+        return out
+
+    def with_x(self, x):
+        """The same rows with ``x [8, *rows]`` for their data."""
+        out = copy.copy(self)
+        out.x = x
+        return out
+
+    def flat(self):
+        """One cluster's row data back as rows, ``(x8 [B, 8], coh
+        [B, 2, 2], wt [B, 8])``, for the assemblies that take those
+        (:func:`normal_equations`, :func:`gn_factors`, the constrained
+        modes', ``ops/sweep_pallas``)."""
+        return (self.to_rows(self.x), jones_r2c(self.to_rows(self.c)),
+                self.to_rows(self.w))
 
     def gather(self, P):
         """Station planes P [K, N, 8] -> (jp8, jq8) for :func:`row_model`."""

@@ -394,16 +394,16 @@ class _RTRState(NamedTuple):
     cg: jax.Array       # i32 tCG bodies executed so far
 
 
-def _rtr_rows(rows: ne.RowPlanes, x8, coh, sta1, sta2, chunk_id, wt, J0,
-              n_stations: int, chunk_mask, config: RTRConfig,
-              itmax_dynamic, admm, robust_nu, row_period: int):
-    """:func:`rtr_solve` on row data already in plane form (``rows`` holds
-    the storage-quantized ``x8`` and ``wt``, which ``make_hess`` hands to
-    the assembly as they are). Returns (J, info, e): ``e`` the weighted
-    residual planes at the returned J, which the one row pass that
-    reached it left behind."""
+def _rtr_rows(rows: ne.RowPlanes, J0, n_stations: int, chunk_mask,
+              config: RTRConfig, itmax_dynamic, admm, robust_nu,
+              row_period: int):
+    """:func:`rtr_solve` on row data already in plane form (``rows``
+    holds the storage-quantized data and sqrt-weights, which
+    ``make_hess`` hands to the assembly as they are). Returns (J, info,
+    e): ``e`` the weighted residual planes at the returned J, which the
+    one row pass that reached it left behind."""
     kmax = J0.shape[0]
-    dtype = dtp.acc_dtype(x8.dtype)
+    dtype = dtp.acc_dtype(rows.x.dtype)
     mode = config.jones_mode
     npar = ne.jones_npar(mode)
     D = n_stations * npar
@@ -439,7 +439,7 @@ def _rtr_rows(rows: ne.RowPlanes, x8, coh, sta1, sta2, chunk_id, wt, J0,
     swp = None
     if config.kernel == "pallas":
         from sagecal_tpu.ops import sweep_pallas as swp_mod
-        if swp_mod.supported(kmax, row_period, x8.shape[0]):
+        if swp_mod.supported(kmax, row_period, rows.chunk_id.shape[0]):
             swp = swp_mod
 
     admm_rho2 = None if admm is None else 2.0 * admm[2]
@@ -458,7 +458,11 @@ def _rtr_rows(rows: ne.RowPlanes, x8, coh, sta1, sta2, chunk_id, wt, J0,
     # period: straight from the planes the solve already holds
     planes_hess = (config.inner == "chol" and swp is None
                    and mode == "full" and rows.periodic
-                   and not dtp.is_reduced(x8.dtype))
+                   and not dtp.is_reduced(rows.x.dtype))
+    if not planes_hess:
+        # the other assemblies take rows: laid out once a solve
+        x8, coh, wt = rows.flat()
+        sta1, sta2, chunk_id = rows.sta1, rows.sta2, rows.chunk_id
 
     def make_hess(p, e):
         """Gauss-Newton Hessian operator at the outer TR point ``p``,
@@ -636,12 +640,11 @@ def _storage_rows(x8, coh, sta1, sta2, chunk_id, wt, J0, n_stations,
     """dtype policy: storage-quantize the data at entry (identity under
     "f32"; manifold point/tangents/costs live in the accumulator dtype,
     see lm.lm_solve) and bring the row data to plane form, ONCE for all
-    the evaluations of a solve. Returns (x8, wt, rows)."""
+    the evaluations of a solve."""
     stq = dtp.storage_dtype(config.dtype_policy, x8.dtype)
-    x8 = dtp.to_storage(x8, stq)
-    wt = dtp.to_storage(wt, stq)
-    return x8, wt, ne.RowPlanes(x8, coh, wt, sta1, sta2, chunk_id,
-                                J0.shape[0], n_stations, row_period)
+    return ne.RowPlanes(dtp.to_storage(x8, stq), coh,
+                        dtp.to_storage(wt, stq), sta1, sta2, chunk_id,
+                        J0.shape[0], n_stations, row_period)
 
 
 def rtr_solve(x8, coh, sta1, sta2, chunk_id, wt, J0, n_stations: int,
@@ -661,12 +664,24 @@ def rtr_solve(x8, coh, sta1, sta2, chunk_id, wt, J0, n_stations: int,
     model (1 + iters; the assembly's own not counted),
     ``info["residual"]`` [B, 8] the weighted residual at the returned J.
     """
-    x8, wt, rows = _storage_rows(x8, coh, sta1, sta2, chunk_id, wt, J0,
-                                 n_stations, config, row_period)
-    J, info, e = _rtr_rows(rows, x8, coh, sta1, sta2, chunk_id, wt, J0,
-                           n_stations, chunk_mask, config, itmax_dynamic,
-                           admm, robust_nu, row_period)
+    rows = _storage_rows(x8, coh, sta1, sta2, chunk_id, wt, J0,
+                         n_stations, config, row_period)
+    J, info, e = _rtr_rows(rows, J0, n_stations, chunk_mask, config,
+                           itmax_dynamic, admm, robust_nu, row_period)
     return J, {**info, "residual": rows.to_rows(e)}
+
+
+def rtr_rows(rows: ne.RowPlanes, J0, n_stations: int, chunk_mask=None,
+             config: RTRConfig = RTRConfig(), itmax_dynamic=None,
+             admm=None, row_period: int = 0):
+    """:func:`rtr_solve` for a caller that holds one cluster's row data
+    on planes already, storage-quantized (the sweep of
+    ``solvers/sage.py``: ``rows.x`` its running residual with the
+    cluster's model added, ``rows.w`` the sqrt-weights). Returns
+    (J, info) without ``info["residual"]``."""
+    J, info, _ = _rtr_rows(rows, J0, n_stations, chunk_mask, config,
+                           itmax_dynamic, admm, None, row_period)
+    return J, info
 
 
 def rtr_solve_robust(x8, coh, sta1, sta2, chunk_id, wt_base, J0,
@@ -681,15 +696,24 @@ def rtr_solve_robust(x8, coh, sta1, sta2, chunk_id, wt_base, J0,
     E-step reads the residual its solve ended on.
 
     Returns (J, nu, info)."""
-    x8, wt_base, rows = _storage_rows(x8, coh, sta1, sta2, chunk_id,
-                                      wt_base, J0, n_stations, config,
-                                      row_period)
+    rows = _storage_rows(x8, coh, sta1, sta2, chunk_id, wt_base, J0,
+                         n_stations, config, row_period)
+    return rtr_rows_robust(rows, J0, n_stations, nu0, nulow, nuhigh,
+                           chunk_mask, config, wt_rounds, itmax_dynamic,
+                           admm, row_period)
+
+
+def rtr_rows_robust(rows: ne.RowPlanes, J0, n_stations: int, nu0=2.0,
+                    nulow=2.0, nuhigh=30.0, chunk_mask=None,
+                    config: RTRConfig = RTRConfig(), wt_rounds: int = 2,
+                    itmax_dynamic=None, admm=None, row_period: int = 0):
+    """:func:`rtr_solve_robust` on one cluster's row data already on
+    planes (see :func:`rtr_rows`)."""
     mask = rows.w > 0
 
     def round_body(carry, _):
         J, nu = carry
-        Jn, info, e = _rtr_rows(rows, x8, coh, sta1, sta2, chunk_id,
-                                wt_base, J, n_stations, chunk_mask, config,
+        Jn, info, e = _rtr_rows(rows, J, n_stations, chunk_mask, config,
                                 itmax_dynamic, admm, nu, row_period)
         w = rb.update_weights(e, nu)
         # AECM nu update with p=2, matching the robust-RTR family
@@ -702,7 +726,8 @@ def rtr_solve_robust(x8, coh, sta1, sta2, chunk_id, wt_base, J0,
                               info["row_passes"])
 
     (J, nu), costs = jax.lax.scan(
-        round_body, (J0, jnp.asarray(nu0, dtp.acc_dtype(x8.dtype))), None,
+        round_body,
+        (J0, jnp.asarray(nu0, dtp.acc_dtype(rows.x.dtype))), None,
         length=wt_rounds)
     # "iters": executed outer TR iterations summed over IRLS rounds
     # (the tile record's solver_iters); "cg_iters": their tCG bodies;

@@ -114,7 +114,18 @@ def _call(name, jfn, *args, **kwargs):
 
 #: what :func:`_plan_info` adds to a host-driven solve's info dict: host
 #: values (a str, an int), not arrays
-_PLAN_KEYS = ("plan", "solve_dispatches", "refine_rows", "assemble_rows")
+_PLAN_KEYS = ("plan", "solve_dispatches", "refine_rows", "assemble_rows",
+              "sweep_rows")
+
+
+def sweep_rows(config, kmax: int, B: int) -> str:
+    """The row layout a solve's sweeps carry their running residual and
+    evaluate a cluster's model on, which :class:`normal_eq.RowPlanes`
+    decides from its input: ``"periodic"`` (one chunk a cluster and
+    ``config.nbase`` dividing the ``B`` rows: ``[8, tilesz, nbase]``
+    planes, the Jones gathered for ``nbase`` rows) or ``"flat"``
+    (``[8, B]``)."""
+    return "periodic" if ne.periodic_rows(kmax, config.nbase, B) else "flat"
 
 
 def assemble_rows(config, kmax: int, B: int):
@@ -143,16 +154,19 @@ def _plan_info(info: dict, plan: str, n0: int, config, J0, x8) -> dict:
     :func:`_call` since ``n0``, ``refine_rows`` (where a refine ran) the
     row layout its model passes worked on, which the mechanism decides
     from its input (``"periodic"``: ``[tilesz, nbase]`` planes, the
-    Jones gathered for ``nbase`` rows; ``"flat"``: ``[B]``), and
-    ``assemble_rows`` (:func:`assemble_rows`, where it says one) the
-    same of the sweeps' assembly, both here from the shapes of
+    Jones gathered for ``nbase`` rows; ``"flat"``: ``[B]``),
+    ``sweep_rows`` (:func:`sweep_rows`, where a sweep ran) the same of
+    the sweeps' running residual and cluster models, and
+    ``assemble_rows`` (:func:`assemble_rows`, where it says one) of the
+    sweeps' assembly, all here from the shapes of
     ``J0 [(T,) M, kmax, N, 2, 2]`` and ``x8 [(T,) B, 8]``.
     Host values: nothing is fetched."""
     out = {**info, "plan": plan, "solve_dispatches": _dispatched() - n0}
     kmax, B = J0.shape[-4], x8.shape[-2]
     if config.max_lbfgs > 0:
-        out["refine_rows"] = ("periodic" if ne.periodic_rows(
-            kmax, config.nbase, B) else "flat")
+        out["refine_rows"] = sweep_rows(config, kmax, B)
+    if config.max_emiter > 0:
+        out["sweep_rows"] = sweep_rows(config, kmax, B)
     rows = assemble_rows(config, kmax, B)
     if rows is not None:
         out["assemble_rows"] = rows
@@ -241,7 +255,7 @@ class SageConfig(NamedTuple):
     # identical results).
     nbase: int = 0
     # fold each cluster visit's residual re-subtract and the NEXT
-    # visit's add-back into ONE pass over the [B, 8] running residual
+    # visit's add-back into ONE pass over the running residual's planes
     # (the augmented residual rides the sweep carry), instead of a
     # write-back to xres and a fresh add-back per visit. Identical
     # math — the +/- association order is preserved, so the residual
@@ -249,7 +263,7 @@ class SageConfig(NamedTuple):
     # Measured 2026-08-03 at the LOFAR smoke-test shape on the host CPU
     # (M=8, B=18910, -j3, interleaved warm sweeps): median 7.96 s/sweep
     # fused vs 8.01 unfused — a wall-clock wash on a latency-rich CPU —
-    # while the fused program runs one [B, 8] traversal less per
+    # while the fused program runs one traversal of the planes less per
     # cluster visit, so it defaults ON along the traffic axis the
     # roofline gates (PERF.md: the hot path is bandwidth-bound; the
     # TPU wall-clock verdict lands with the next healthy chip window).
@@ -324,16 +338,32 @@ def _is_robust(mode: int) -> bool:
                     int(SolverMode.NSD_RLBFGS))
 
 
-def _model8(J_m, coh_m, sta1, sta2, cidx_m, out_dtype=None):
-    """One cluster's corrupted model as [B, 8] reals.
+def _cluster_model(rows: ne.RowPlanes, J_m, out_dtype):
+    """One cluster's corrupted model ``J_p C J_q^H`` as ``[8, *rows]``
+    planes, from the cluster's rows (:meth:`normal_eq.RowPlanes.cluster`)
+    and its Jones ``J_m [kmax, N, 2, 2]``: real elementwise arithmetic
+    with the rows on the minor axes (:func:`normal_eq.row_model`), the
+    same bilinear form ``rime.predict.model8`` evaluates as ``[B, 2, 2]``
+    complex products.
 
-    Delegates to the rime-layer kernel (:func:`rime.predict.model8`) so
-    the storage-emission contract lives in ONE place: the model
-    quantizes to the running residual's storage dtype (``out_dtype``)
-    at the point it joins the [B]-stream — a no-op for f32/f64 — while
-    the complex evaluation stays c64."""
-    from sagecal_tpu.rime import predict as rp
-    return rp.model8(coh_m, J_m, sta1, sta2, cidx_m, out_dtype=out_dtype)
+    The storage-emission contract of ``rime.predict.model8``: the model
+    is evaluated in the Jones' precision (f32 from c64) and quantizes
+    to the running residual's storage dtype (``out_dtype``) at the
+    point it joins the residual's planes, a no-op for f32/f64.
+
+    The model's three inputs pass a barrier: what made them (the solve's
+    ``r2c``, a slice of the program's planes or a cluster's own) stays
+    out of the multiply-adds' fusion, so how those round (which of them
+    contract, on the CPU) is the same in every program that holds a
+    visit, and the per-cluster, the per-sweep and the promoted plan of
+    :func:`sagefit_host` agree to the bit, as they did when the model
+    was a library product (MIGRATION.md's bit-identity of ``--prefetch``,
+    resume and ``serve`` rests on it: the learner may change the plan
+    between two runs of one tile)."""
+    jp8, jq8, c8 = jax.lax.optimization_barrier(
+        (*rows.gather(ne.jones_c2r(J_m)), rows.c))
+    v, _, _ = ne.row_model(jp8, jq8, c8)
+    return dtp.to_storage(v, out_dtype)
 
 
 def _joint_model(rows: ne.RowPlanes, P):
@@ -346,6 +376,13 @@ def _joint_model(rows: ne.RowPlanes, P):
     return jnp.sum(v, axis=1), a, bm
 
 
+def _joint_planes(rows: ne.RowPlanes, J):
+    """:func:`_joint_model`'s V ``[8, *rows]`` under the Jones
+    ``J [M, kmax, N, 2, 2]``, in the model-eval dtype."""
+    return _joint_model(
+        rows, ne.jones_c2r(J).reshape(-1, rows.n_stations, 8))[0]
+
+
 def full_model8(J, coh, sta1, sta2, chunk_idx, row_period: int = 0):
     """Sum of all clusters' corrupted models [B, 8] (minimize_viz_full_pth):
     the joint refine's model (:func:`_joint_model`), handed back as rows.
@@ -353,18 +390,52 @@ def full_model8(J, coh, sta1, sta2, chunk_idx, row_period: int = 0):
     The cluster sum ACCUMULATES in the model-eval dtype (f32 from c64)
     regardless of the storage policy — callers emit to storage at the
     residual subtraction (dtp.to_storage), not inside the sum."""
-    M, kmax, n_stations = J.shape[:3]
-    rows = ne.RowPlanes(None, coh, None, sta1, sta2, chunk_idx, kmax,
-                        n_stations, row_period)
-    P = ne.jones_c2r(J).reshape(M * kmax, n_stations, 8)
-    return rows.to_rows(_joint_model(rows, P)[0])
+    rows = ne.RowPlanes(None, coh, None, sta1, sta2, chunk_idx,
+                        J.shape[1], J.shape[2], row_period)
+    return rows.to_rows(_joint_planes(rows, J))
 
 
-def _cluster_solve(mode: int, xdummy, coh_m, sta1, sta2, cidx_m, cmask_m,
-                   wt_base, J_m, n_stations: int, nu_cj, config: SageConfig,
+def _wnorm(r, w):
+    """||r w||_2 over all planes, in the accumulation dtype."""
+    return jnp.linalg.norm(dtp.acc(r * w))
+
+
+def _prelude(rows: ne.RowPlanes, J0):
+    """(xres0, res_0): the data less the storage-quantized model under
+    ``J0`` on the planes of ``rows`` (all clusters, with data and
+    weights), which is what the sweeps carry, and its weighted norm
+    over the 8 B reals."""
+    xres0 = rows.x - dtp.to_storage(_joint_planes(rows, J0), rows.x.dtype)
+    return xres0, _wnorm(xres0, rows.w) / rows.x.size
+
+
+def _final_res(rows: ne.RowPlanes, J):
+    """res_1: the weighted norm of the data less the model under ``J``
+    (all clusters, summed in the model-eval dtype) on the planes of
+    ``rows``, over the 8 B reals. ONE expression for every plan
+    (:func:`sagefit`, :func:`_jit_refine`, :func:`_jit_res`), so a tile's
+    res_1 does not depend on which of them ran it."""
+    return _wnorm(rows.x - _joint_planes(rows, J), rows.w) / rows.x.size
+
+
+#: the solver modes whose cluster solves take the sweep's planes as they
+#: are (:func:`rtr.rtr_rows`); the others get rows (:func:`_cluster_solve`)
+_RTR_MODES = (int(SolverMode.RTR_OSLM_LBFGS),
+              int(SolverMode.RTR_OSRLM_RLBFGS))
+
+
+def _cluster_solve(mode: int, rows: ne.RowPlanes, coh_m, cmask_m, wt_base,
+                   J_m, n_stations: int, nu_cj, config: SageConfig,
                    itermax, itcap: int, admm_m, os_cfg, last):
     """One cluster's per-chunk solve by solver mode (lmfit.c:906-962).
 
+    ``rows``: the cluster's row data on planes, ``rows.x`` the running
+    residual with this cluster's model added and ``rows.w`` the
+    sqrt-weights. The RTR family (modes 4 and 5) takes them as they are
+    (:func:`rtr.rtr_rows`); the LM family and NSD take rows, so for them
+    the planes of ``rows.x`` are laid out as ``[B, 8]`` here, beside the
+    cluster's ``coh_m [B, 2, 2]`` (None for the RTR family, which reads
+    neither it nor ``wt_base [B, 8]``).
     ``last`` (traced bool) is the is-last-EM-iteration switch; ``os_cfg``
     is an lm.OSConfig or None (static). Returns
     (Jn [K,N,2,2], nu_new scalar, init_cost [K], final_cost [K],
@@ -384,6 +455,8 @@ def _cluster_solve(mode: int, xdummy, coh_m, sta1, sta2, cidx_m, cmask_m,
                              jones_mode=config.jones_mode)
     nbase = int(config.nbase)
     zero_i = jnp.zeros((), jnp.int32)
+    sta1, sta2, cidx_m = rows.sta1, rows.sta2, rows.chunk_id
+    xdummy = None if mode in _RTR_MODES else rows.to_rows(rows.x)
 
     def plain_lm(os=None):
         Jn, info = lm_mod.lm_solve(
@@ -408,10 +481,9 @@ def _cluster_solve(mode: int, xdummy, coh_m, sta1, sta2, cidx_m, cmask_m,
                                     kernel=config.kernel,
                                     dtype_policy=config.dtype_policy,
                                     jones_mode=config.jones_mode)
-        Jn, info = rtr_mod.rtr_solve(
-            xdummy, coh_m, sta1, sta2, cidx_m, wt_base, J_m, n_stations,
-            chunk_mask=cmask_m, config=rtr_cfg, itmax_dynamic=itermax,
-            admm=admm_m, row_period=nbase)
+        Jn, info = rtr_mod.rtr_rows(
+            rows, J_m, n_stations, chunk_mask=cmask_m, config=rtr_cfg,
+            itmax_dynamic=itermax, admm=admm_m, row_period=nbase)
         return (Jn, nu_cj, info["init_cost"], info["final_cost"],
                 info["iters"], info["cg_iters"], info["row_passes"])
 
@@ -420,8 +492,8 @@ def _cluster_solve(mode: int, xdummy, coh_m, sta1, sta2, cidx_m, cmask_m,
                                     kernel=config.kernel,
                                     dtype_policy=config.dtype_policy,
                                     jones_mode=config.jones_mode)
-        Jn, nu_new, info = rtr_mod.rtr_solve_robust(
-            xdummy, coh_m, sta1, sta2, cidx_m, wt_base, J_m, n_stations,
+        Jn, nu_new, info = rtr_mod.rtr_rows_robust(
+            rows, J_m, n_stations,
             nu0=nu_cj, nulow=config.nulow, nuhigh=config.nuhigh,
             # 2 rounds/call: the reference robust RTR updates weights once
             # before and once after the TR loop (rtr_solve_robust.c:1625,
@@ -462,15 +534,18 @@ def _cluster_solve(mode: int, xdummy, coh_m, sta1, sta2, cidx_m, cmask_m,
                         lambda: plain_lm(os_cfg))
 
 
-def _visit_solve(cj, xdummy, coh_m, cidx_m, cmask_m, J_m, nu_cj,
-                 sta1, sta2, wt_base, n_stations: int,
+def _visit_solve(cj, rows: ne.RowPlanes, coh, cmask_m, J_m, nu_cj,
+                 wt_base, n_stations: int,
                  config: SageConfig, nerr_prev, weighted, last, key, admm,
                  os_id, total_iter: int, iter_bar: int):
     """The solve half of one cluster visit (shared by the plain and the
-    residual-fused sweeps): per-cluster gathers already done, ``xdummy``
-    = residual + this cluster's model. Returns (Jn, nu_new, dcost,
-    its, cgs, rps)."""
+    residual-fused sweeps): ``rows`` the cluster's rows with ``rows.x``
+    = residual + this cluster's model, ``coh [M, B, 2, 2]`` and
+    ``wt_base [B, 8]`` what the solvers that take rows read
+    (:func:`_cluster_solve`). Returns (Jn, nu_new, dcost, its, cgs,
+    rps)."""
     mode = int(config.solver_mode)
+    coh_m = None if mode in _RTR_MODES else jnp.take(coh, cj, axis=0)
     itermax = jnp.where(
         weighted,
         (0.2 * jnp.take(nerr_prev, cj) * total_iter).astype(jnp.int32)
@@ -491,9 +566,8 @@ def _visit_solve(cj, xdummy, coh_m, cidx_m, cmask_m, J_m, nu_cj,
 
     itcap = int(config.max_iter) + iter_bar  # static while-loop cap
     Jn, nu_new, init_cost, final_cost, its, cgs, rps = _cluster_solve(
-        mode, xdummy, coh_m, sta1, sta2, cidx_m, cmask_m, wt_base, J_m,
-        n_stations, nu_cj, config, itermax, itcap, admm_m,
-        os_cfg, last)
+        mode, rows, coh_m, cmask_m, wt_base, J_m, n_stations, nu_cj,
+        config, itermax, itcap, admm_m, os_cfg, last)
     init_res = jnp.sum(init_cost)
     final_res = jnp.sum(final_cost)
     dcost = jnp.where(init_res > 0,
@@ -502,21 +576,44 @@ def _visit_solve(cj, xdummy, coh_m, cidx_m, cmask_m, J_m, nu_cj,
     return Jn, nu_new, dcost, its, cgs, rps
 
 
-def _cluster_update(cj, state, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
+def _sweep_planes(coh, wt_base, sta1, sta2, chunk_idx, kmax: int,
+                  n_stations: int, config: SageConfig) -> ne.RowPlanes:
+    """What a sweep reads of a program's row data, on planes, ONCE a
+    program: the coherencies of all clusters (``c [8, M, *rows]``), the
+    sqrt-weights (``w [8, *rows]``) and the station indices. The
+    running residual (the sweep's carry, ``[8, *rows]`` in the storage
+    dtype) has the same layout, which ``config.nbase`` and ``kmax``
+    decide (:func:`sweep_rows`)."""
+    with jax.named_scope("update"):
+        return ne.RowPlanes(None, coh, wt_base, sta1, sta2, chunk_idx,
+                            kmax, n_stations, config.nbase)
+
+
+def _visit_planes(cj, coh, wt_base, sta1, sta2, chunk_idx, kmax: int,
+                  n_stations: int, config: SageConfig) -> ne.RowPlanes:
+    """Cluster ``cj``'s slice of :func:`_sweep_planes`' for a program
+    that visits that one cluster: only its coherencies are laid out."""
+    with jax.named_scope("update"):
+        return ne.RowPlanes(None, jnp.take(coh, cj, axis=0), wt_base,
+                            sta1, sta2, jnp.take(chunk_idx, cj, axis=0),
+                            kmax, n_stations, config.nbase)
+
+
+def _cluster_update(cj, state, rows: ne.RowPlanes, coh, chunk_mask,
                     wt_base, n_stations: int, config: SageConfig,
                     nerr_prev, weighted, last, key, admm, os_id,
                     total_iter: int, iter_bar: int):
     """Visit one cluster: add model back to residual, solve, re-subtract
-    (lmfit.c:890-981). ``state`` = (J, xres, nerr_acc, nuM, tk) with
-    ``tk`` an i32[N_TK] counter vector: [0] executed inner-solver
-    iterations (the tile record's solver_iters), [1] rejected group steps
-    (always 0 here — only :func:`_group_update` can reject), [2]
-    executed inner CG trips (LM's PCG under SageConfig.inner="cg", RTR's
-    truncated-CG bodies), [3] RTR's row passes
-    (:func:`_cluster_solve`)."""
+    (lmfit.c:890-981). ``rows``: cluster ``cj``'s rows with the weights
+    (a slice of :func:`_sweep_planes`' or its own). ``state`` = (J, xres,
+    nerr_acc, nuM, tk) with ``xres [8, *rows]`` the running residual on
+    planes and ``tk`` an i32[N_TK] counter vector: [0] executed
+    inner-solver iterations (the tile record's solver_iters), [1]
+    rejected group steps (always 0 here — only :func:`_group_update` can
+    reject), [2] executed inner CG trips (LM's PCG under
+    SageConfig.inner="cg", RTR's truncated-CG bodies), [3] RTR's row
+    passes (:func:`_cluster_solve`)."""
     J, xres, nerr_acc, nuM, tk = state
-    coh_m = jnp.take(coh, cj, axis=0)
-    cidx_m = jnp.take(chunk_idx, cj, axis=0)
     cmask_m = jnp.take(chunk_mask, cj, axis=0)
     J_m = jnp.take(J, cj, axis=0)
 
@@ -524,34 +621,33 @@ def _cluster_update(cj, state, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
     # from normal_eq.py): the model and running-residual update, the
     # inner solve with its loop control, the normal-equation assembly
     with jax.named_scope("update"):
-        xdummy = xres + _model8(J_m, coh_m, sta1, sta2, cidx_m,
-                                out_dtype=xres.dtype)
+        xdummy = xres + _cluster_model(rows, J_m, xres.dtype)
     with jax.named_scope("inner"):
         Jn, nu_new, dcost, its, cgs, rps = _visit_solve(
-            cj, xdummy, coh_m, cidx_m, cmask_m, J_m, jnp.take(nuM, cj),
-            sta1, sta2, wt_base, n_stations, config, nerr_prev, weighted,
+            cj, rows.with_x(xdummy), coh, cmask_m, J_m, jnp.take(nuM, cj),
+            wt_base, n_stations, config, nerr_prev, weighted,
             last, key, admm, os_id, total_iter, iter_bar)
     with jax.named_scope("update"):
         nuM = nuM.at[cj].set(nu_new)
         nerr_acc = nerr_acc.at[cj].set(dcost)
-        xres = xdummy - _model8(Jn, coh_m, sta1, sta2, cidx_m,
-                                out_dtype=xres.dtype)
+        xres = xdummy - _cluster_model(rows, Jn, xres.dtype)
         J = J.at[cj].set(Jn)
     tk = tk.at[0].add(its).at[2].add(cgs).at[3].add(rps)
     return J, xres, nerr_acc, nuM, tk
 
 
-def _sweep_g1(perm, state, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
+def _sweep_g1(perm, state, rows: ne.RowPlanes, coh, chunk_mask,
               wt_base, n_stations: int, config: SageConfig, nerr_prev,
               weighted, last, key, admm, os_id, total_iter: int,
               iter_bar: int):
-    """One EM sweep over all M clusters at group width 1.
+    """One EM sweep over all M clusters at group width 1, on the
+    program's planes ``rows`` (:func:`_sweep_planes`).
 
     With ``config.fuse_residual`` the loop carries the AUGMENTED
     residual xd = xres + model(current cluster): each visit solves on
     xd, then one fused pass replaces it by
     (xd - model_new) + model(next cluster) — the re-subtract and the
-    next add-back become a single read+write of the [B, 8] buffer
+    next add-back become a single read+write of the residual's planes
     instead of two (and the final visit's masked add costs nothing).
     The +/- association order matches the unfused path exactly, so the
     residual stream is bit-preserving; see SageConfig.fuse_residual for
@@ -563,7 +659,7 @@ def _sweep_g1(perm, state, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
         def cluster_step(cj, inner):
             cj_eff = cj if perm is None else jnp.take(perm, cj)
             return _cluster_update(
-                cj_eff, inner, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
+                cj_eff, inner, rows.cluster(cj_eff), coh, chunk_mask,
                 wt_base, n_stations, config, nerr_prev, weighted, last,
                 key, admm, os_id, total_iter, iter_bar)
         return jax.lax.fori_loop(0, M, cluster_step, state)
@@ -572,25 +668,21 @@ def _sweep_g1(perm, state, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
         jc = jnp.minimum(j, M - 1)
         return jc if perm is None else jnp.take(perm, jc)
 
-    def gather(cm):
-        return (jnp.take(coh, cm, axis=0), jnp.take(chunk_idx, cm, axis=0),
-                jnp.take(chunk_mask, cm, axis=0))
-
     c0 = cl_of(0)
-    coh0, cidx0, _ = gather(c0)
     with jax.named_scope("update"):
-        xd = xres + _model8(jnp.take(J0_, c0, axis=0), coh0, sta1, sta2,
-                            cidx0, out_dtype=xres.dtype)
+        xd = xres + _cluster_model(rows.cluster(c0),
+                                   jnp.take(J0_, c0, axis=0), xres.dtype)
 
     def body(j, inner):
         J, xd, nerr_acc, nuM, tk = inner
         cj = cl_of(j)
-        coh_m, cidx_m, cmask_m = gather(cj)
+        rows_m = rows.cluster(cj)
         J_m = jnp.take(J, cj, axis=0)
         with jax.named_scope("inner"):
             Jn, nu_new, dcost, its, cgs, rps = _visit_solve(
-                cj, xd, coh_m, cidx_m, cmask_m, J_m, jnp.take(nuM, cj),
-                sta1, sta2, wt_base, n_stations, config, nerr_prev,
+                cj, rows_m.with_x(xd), coh,
+                jnp.take(chunk_mask, cj, axis=0), J_m, jnp.take(nuM, cj),
+                wt_base, n_stations, config, nerr_prev,
                 weighted, last, key, admm, os_id, total_iter, iter_bar)
         with jax.named_scope("update"):
             nuM = nuM.at[cj].set(nu_new)
@@ -600,11 +692,9 @@ def _sweep_g1(perm, state, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
             # for j < M-1, so the update never aliases; the clamped last
             # step's self-model is dropped by the where)
             cn = cl_of(j + 1)
-            coh_n, cidx_n, _ = gather(cn)
-            model_next = _model8(jnp.take(J, cn, axis=0), coh_n, sta1,
-                                 sta2, cidx_n, out_dtype=xd.dtype)
-            model_new = _model8(Jn, coh_m, sta1, sta2, cidx_m,
-                                out_dtype=xd.dtype)
+            model_next = _cluster_model(rows.cluster(cn),
+                                        jnp.take(J, cn, axis=0), xd.dtype)
+            model_new = _cluster_model(rows_m, Jn, xd.dtype)
             xd = (xd - model_new) + jnp.where(j + 1 < M, model_next, 0.0)
         return (J, xd, nerr_acc, nuM,
                 tk.at[0].add(its).at[2].add(cgs).at[3].add(rps))
@@ -615,8 +705,8 @@ def _sweep_g1(perm, state, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
     return J, xd, nerr_acc, nuM, tk
 
 
-def _omega_trial(w, Jo_g, Jn_g, coh_g, cidx_g, sta1, sta2, xres, vm,
-                 model_old, wt_base, res_old, anchor):
+def _omega_trial(w, Jo_g, Jn_g, rows: ne.RowPlanes, cjs, xres, valid,
+                 model_old, res_old, anchor):
     """One damped block-Jacobi group step at relaxation ``w``: apply
     J(omega) = J_old + w (J_solved - J_old) jointly and test the
     weighted residual L2 against entry/anchor. Module-level so the
@@ -627,18 +717,19 @@ def _omega_trial(w, Jo_g, Jn_g, coh_g, cidx_g, sta1, sta2, xres, vm,
     cond-cost; the PR 3 phantom-bytes class)."""
     Jr_g = Jo_g + w * (Jn_g - Jo_g)
     model_new = jax.vmap(
-        lambda Jm, cm, cim: _model8(Jm, cm, sta1, sta2, cim,
-                                    out_dtype=xres.dtype)
-    )(Jr_g, coh_g, cidx_g)
-    xnew = xres + dtp.to_storage(
-        jnp.einsum("g,gbx->bx", vm, model_old - model_new,
-                   **dtp.pet(xres.dtype)), xres.dtype)
-    rn = jnp.sum(dtp.acc(xnew * wt_base) ** 2)
+        lambda Jm, cj: _cluster_model(rows.cluster(cj), Jm, xres.dtype)
+    )(Jr_g, cjs)
+    # the live members' model deltas, summed over the group axis of
+    # their planes in the accumulation dtype
+    delta = jnp.where(valid.reshape((-1,) + (1,) * xres.ndim),
+                      dtp.acc(model_old - model_new), 0.0)
+    xnew = xres + dtp.to_storage(jnp.sum(delta, axis=0), xres.dtype)
+    rn = jnp.sum(dtp.acc(xnew * rows.w) ** 2)
     ok = (rn <= res_old * (1.0 + 1e-9)) | (rn <= 1.05 * anchor)
     return ok, xnew, Jr_g
 
 
-def _group_update(cjs, state, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
+def _group_update(cjs, state, rows: ne.RowPlanes, coh, chunk_mask,
                   wt_base, n_stations: int, config: SageConfig,
                   nerr_prev, weighted, last, key, admm, os_id,
                   total_iter: int, iter_bar: int, res_anchor=None):
@@ -647,7 +738,10 @@ def _group_update(cjs, state, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
     ``cjs`` [G] holds distinct cluster indices; padded slots carry the
     out-of-range index M — their scatter updates are dropped (JAX's
     default OOB-scatter semantics) and their residual contribution is
-    masked. Every member's solve sees the residual AS OF GROUP ENTRY
+    masked. ``rows``: the program's planes (:func:`_sweep_planes`); the
+    running residual in ``state`` and the members' models are
+    ``[8, *rows]`` planes, the group a leading axis of them. Every
+    member's solve sees the residual AS OF GROUP ENTRY
     (block-Jacobi); the group's model deltas then apply jointly:
     xres += sum_g (model(J_old_g) - model(J_new_g)).
 
@@ -680,8 +774,8 @@ def _group_update(cjs, state, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
     valid = cjs < M
 
     def solve_one(cj):
-        coh_m = jnp.take(coh, cj, axis=0)      # OOB clips; masked below
-        cidx_m = jnp.take(chunk_idx, cj, axis=0)
+        rows_m = rows.cluster(cj)               # OOB clamps; masked below
+        coh_m = None if mode in _RTR_MODES else jnp.take(coh, cj, axis=0)
         cmask_m = jnp.take(chunk_mask, cj, axis=0)
         J_m = jnp.take(J, cj, axis=0)
         itermax = jnp.where(
@@ -703,13 +797,12 @@ def _group_update(cjs, state, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
                 key=jax.random.fold_in(key, cj),
                 randomize=config.randomize)
         with jax.named_scope("update"):
-            xdummy = xres + _model8(J_m, coh_m, sta1, sta2, cidx_m,
-                                    out_dtype=xres.dtype)
+            xdummy = xres + _cluster_model(rows_m, J_m, xres.dtype)
         itcap = int(config.max_iter) + iter_bar
         with jax.named_scope("inner"):
             (Jn, nu_new, init_cost, final_cost, its, cgs,
              rps) = _cluster_solve(
-                mode, xdummy, coh_m, sta1, sta2, cidx_m, cmask_m, wt_base,
+                mode, rows_m.with_x(xdummy), coh_m, cmask_m, wt_base,
                 J_m, n_stations, jnp.take(nuM, cj, mode="clip"), config,
                 itermax, itcap, admm_m, os_cfg, last)
         return Jn, nu_new, init_cost, final_cost, its, cgs, rps, xdummy
@@ -720,22 +813,18 @@ def _group_update(cjs, state, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
     # was accepted
     with jax.named_scope("update"):
         Jo_g = jnp.take(J, cjs, axis=0)     # entering Jones (clipped)
-        coh_g = jnp.take(coh, cjs, axis=0)
-        cidx_g = jnp.take(chunk_idx, cjs, axis=0)
         # entering models fall out of the solves' add-back (xdummy - xres):
         # no second RIME evaluation needed
         model_old = xd_g - xres[None]
-        vm = valid.astype(xres.dtype)
-        res_old = jnp.sum(dtp.acc(xres * wt_base) ** 2)
+        res_old = jnp.sum(dtp.acc(xres * rows.w) ** 2)
         anchor = res_old if res_anchor is None else res_anchor
 
         def try_omega(w):
             # forwards to the module-level body: the cond branches below
             # must not inline the model evaluations (priceability contract,
             # see _omega_trial)
-            return _omega_trial(w, Jo_g, Jn_g, coh_g, cidx_g, sta1, sta2,
-                                xres, vm, model_old, wt_base, res_old,
-                                anchor)
+            return _omega_trial(w, Jo_g, Jn_g, rows, cjs, xres, valid,
+                                model_old, res_old, anchor)
 
         # first passing factor wins (largest safe step); the cond chain
         # skips the smaller-step model evaluations when omega=1 passes —
@@ -941,7 +1030,6 @@ def sagefit(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0, n_stations: int,
     """
     M, B = coh.shape[0], coh.shape[1]
     kmax = J0.shape[1]
-    n = B * 8
     # dtype policy: the [B]-data, weights and the running residual ride
     # the storage dtype (identity under "f32"); the EM state (nerr,
     # nuM, costs) lives in the accumulator dtype
@@ -963,10 +1051,12 @@ def sagefit(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0, n_stations: int,
     # host-driven programs further down) are what a profiler trace of a
     # solve is split by: metadata only, the programs are unchanged
     with jax.named_scope("sage/prelude"):
-        xres0 = x8 - dtp.to_storage(
-            full_model8(J0, coh, sta1, sta2, chunk_idx, config.nbase),
-            x8.dtype)
-        res_0 = jnp.linalg.norm(dtp.acc(xres0 * wt_base)) / n
+        # the program's row data go to planes ONCE, here: the data, the
+        # weights and all clusters' coherencies; the sweeps' running
+        # residual stays on them to the end
+        rows = ne.RowPlanes(x8, coh, wt_base, sta1, sta2, chunk_idx, kmax,
+                            n_stations, config.nbase)
+        xres0, res_0 = _prelude(rows, J0)
 
     total_iter = M * config.max_iter
     iter_bar = int(-(-0.8 * total_iter // M))  # ceil(0.8/M * total), host-side
@@ -984,7 +1074,7 @@ def sagefit(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0, n_stations: int,
         if Gi == 1:
             J, xres, nerr_new, nuM, tk = _sweep_g1(
                 perm, (J, xres, jnp.zeros((M,), dtype), nuM, tk),
-                x8, coh, sta1, sta2, chunk_idx, chunk_mask, wt_base,
+                rows, coh, chunk_mask, wt_base,
                 n_stations, config, nerr, weighted, last, kci, admm,
                 os_id, total_iter, iter_bar)
         else:
@@ -992,12 +1082,12 @@ def sagefit(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0, n_stations: int,
                     else jnp.arange(M, dtype=jnp.int32))
             order_pad, n_groups = _pad_order(base, M, Gi)
             # sweep-entry anchor for the group-step safeguard
-            anchor = jnp.sum(dtp.acc(xres * wt_base) ** 2)
+            anchor = jnp.sum(dtp.acc(xres * rows.w) ** 2)
 
             def group_step(g, inner):
                 cjs = jax.lax.dynamic_slice(order_pad, (g * Gi,), (Gi,))
                 return _group_update(
-                    cjs, inner, x8, coh, sta1, sta2, chunk_idx,
+                    cjs, inner, rows, coh,
                     chunk_mask, wt_base, n_stations, config, nerr,
                     weighted, last, kci, admm, os_id, total_iter,
                     iter_bar, res_anchor=anchor)
@@ -1056,9 +1146,7 @@ def sagefit(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0, n_stations: int,
                                                        2, 2)
 
     with jax.named_scope("sage/final"):
-        xres_f = x8 - full_model8(J, coh, sta1, sta2, chunk_idx,
-                                  config.nbase)
-        res_1 = jnp.linalg.norm(dtp.acc(xres_f * wt_base)) / n
+        res_1 = _final_res(rows, J)
     return J, {"res_0": res_0, "res_1": res_1, "mean_nu": mean_nu,
                "nerr": nerr, "solver_iters": tk[0],
                "rejected_groups": tk[1], "cg_iters": tk[2],
@@ -1075,15 +1163,19 @@ def sagefit(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0, n_stations: int,
                                     "iter_bar", "os_nsub"),
                    donate_argnums=(1, 2, 3, 4))
 @jax.named_scope("sage/sweep")
-def _jit_cluster_update(cj, J, xres, nerr_acc, nuM, x8, coh, sta1, sta2,
+def _jit_cluster_update(cj, J, xres, nerr_acc, nuM, coh, sta1, sta2,
                         chunk_idx, chunk_mask, wt_base, nerr_prev, weighted,
                         last, key, admm, os_ids, n_stations, config,
                         total_iter, iter_bar, os_nsub):
+    """One cluster visit as a bounded execution; ``xres [8, *rows]`` the
+    running residual on planes (:func:`_jit_prelude`'s), in and out."""
     os_id = None if os_ids is None else (os_ids, os_nsub)
     return _cluster_update(cj, (J, xres, nerr_acc, nuM,
                                 jnp.zeros((N_TK,), jnp.int32)),
-                           x8, coh, sta1,
-                           sta2, chunk_idx, chunk_mask, wt_base, n_stations,
+                           _visit_planes(cj, coh, wt_base, sta1, sta2,
+                                         chunk_idx, J.shape[1], n_stations,
+                                         config),
+                           coh, chunk_mask, wt_base, n_stations,
                            config, nerr_prev, weighted, last, key, admm,
                            os_id, total_iter, iter_bar)
 
@@ -1093,7 +1185,7 @@ def _jit_cluster_update(cj, J, xres, nerr_acc, nuM, x8, coh, sta1, sta2,
                                     "iter_bar", "os_nsub"),
                    donate_argnums=(1, 2, 3, 4))
 @jax.named_scope("sage/sweep")
-def _jit_group_update(cjs, J, xres, nerr_acc, nuM, x8, coh, sta1, sta2,
+def _jit_group_update(cjs, J, xres, nerr_acc, nuM, coh, sta1, sta2,
                       chunk_idx, chunk_mask, wt_base, nerr_prev, weighted,
                       last, key, os_ids, n_stations, config, total_iter,
                       iter_bar, os_nsub, res_anchor):
@@ -1104,8 +1196,9 @@ def _jit_group_update(cjs, J, xres, nerr_acc, nuM, x8, coh, sta1, sta2,
     os_id = None if os_ids is None else (os_ids, os_nsub)
     return _group_update(cjs, (J, xres, nerr_acc, nuM,
                                jnp.zeros((N_TK,), jnp.int32)),
-                         x8, coh, sta1,
-                         sta2, chunk_idx, chunk_mask, wt_base, n_stations,
+                         _sweep_planes(coh, wt_base, sta1, sta2, chunk_idx,
+                                       J.shape[1], n_stations, config),
+                         coh, chunk_mask, wt_base, n_stations,
                          config, nerr_prev, weighted, last, key, None,
                          os_id, total_iter, iter_bar,
                          res_anchor=res_anchor)
@@ -1116,47 +1209,60 @@ def _jit_group_update(cjs, J, xres, nerr_acc, nuM, x8, coh, sta1, sta2,
                                     "iter_bar", "os_nsub"),
                    donate_argnums=(0, 1, 2))
 @jax.named_scope("sage/sweep")
-def _jit_em_sweep(J, xres, nuM, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
+def _jit_em_sweep(J, xres, nuM, coh, sta1, sta2, chunk_idx, chunk_mask,
                   wt_base, nerr_prev, weighted, last, kci, perm, os_ids,
                   n_stations, config, total_iter, iter_bar, os_nsub):
     """One full EM sweep over all clusters as a single device execution
     (used by sagefit_host once a timed per-cluster sweep proves the fused
     program fits the runtime's per-execution wall-clock limit)."""
     os_id = None if os_ids is None else (os_ids, os_nsub)
+    return _em_sweep(J, xres, nuM, coh, sta1, sta2, chunk_idx, chunk_mask,
+                     wt_base, nerr_prev, weighted, last, kci, perm, os_id,
+                     n_stations, config, total_iter, iter_bar)
+
+
+def _em_sweep(J, xres, nuM, coh, sta1, sta2, chunk_idx, chunk_mask, wt_base,
+              nerr_prev, weighted, last, kci, perm, os_id, n_stations,
+              config, total_iter, iter_bar):
+    """:func:`_jit_em_sweep`'s body (and, under ``vmap``, a tile's of
+    :func:`_jit_em_sweep_tiles`): the program's planes made once, then
+    the sweep at the effective group width, ``xres [8, *rows]`` in and
+    out."""
     M = chunk_mask.shape[0]
     G = _eff_inflight(config, M)
+    rows = _sweep_planes(coh, wt_base, sta1, sta2, chunk_idx, J.shape[1],
+                         n_stations, config)
+    state = (J, xres, jnp.zeros((M,), dtp.acc_dtype(xres.dtype)), nuM,
+             jnp.zeros((N_TK,), jnp.int32))
 
     if G == 1:
         return _sweep_g1(
-            perm, (J, xres, jnp.zeros((M,), dtp.acc_dtype(x8.dtype)), nuM,
-                   jnp.zeros((N_TK,), jnp.int32)),
-            x8, coh, sta1, sta2, chunk_idx, chunk_mask, wt_base,
+            perm, state, rows, coh, chunk_mask, wt_base,
             n_stations, config, nerr_prev, weighted, last, kci, None,
             os_id, total_iter, iter_bar)
 
     order_pad, n_groups = _pad_order(perm, M, G)
-    anchor = jnp.sum(dtp.acc(xres * wt_base) ** 2)   # sweep-entry safeguard ref
+    anchor = jnp.sum(dtp.acc(xres * rows.w) ** 2)   # sweep-entry safeguard ref
 
     def group_step(g, inner):
         cjs = jax.lax.dynamic_slice(order_pad, (g * G,), (G,))
-        return _group_update(cjs, inner, x8, coh, sta1, sta2, chunk_idx,
+        return _group_update(cjs, inner, rows, coh,
                              chunk_mask, wt_base, n_stations, config,
                              nerr_prev, weighted, last, kci, None, os_id,
                              total_iter, iter_bar, res_anchor=anchor)
 
-    return jax.lax.fori_loop(
-        0, n_groups, group_step,
-        (J, xres, jnp.zeros((M,), dtp.acc_dtype(x8.dtype)), nuM,
-         jnp.zeros((N_TK,), jnp.int32)))
+    return jax.lax.fori_loop(0, n_groups, group_step, state)
 
 
 @functools.partial(jax.jit, static_argnames=("row_period",))
 @jax.named_scope("sage/prelude")
 def _jit_prelude(x8, coh, sta1, sta2, chunk_idx, J0, wt_base, row_period=0):
-    xres0 = x8 - dtp.to_storage(
-        full_model8(J0, coh, sta1, sta2, chunk_idx, row_period), x8.dtype)
-    return xres0, jnp.linalg.norm(dtp.acc(xres0 * wt_base)) \
-        / (x8.shape[0] * 8)
+    """The sweeps' entering residual ON PLANES (``[8, *rows]``, the
+    handle the host passes from one sweep program to the next) and
+    res_0."""
+    rows = ne.RowPlanes(x8, coh, wt_base, sta1, sta2, chunk_idx,
+                        J0.shape[1], J0.shape[2], row_period)
+    return _prelude(rows, J0)
 
 
 @functools.partial(jax.jit, static_argnames=("n_stations", "config",
@@ -1190,31 +1296,32 @@ def _jit_refine(x8, coh, sta1, sta2, chunk_idx, J, wt_base, mean_nu,
             Jn = ne.jones_from_params(p1.reshape(shape), mode, Jref).reshape(
                 M, kmax, n_stations, 2, 2)
     with jax.named_scope("sage/final"):
-        res = jnp.linalg.norm(dtp.acc(
-            (x8 - full_model8(Jn, coh, sta1, sta2, chunk_idx, config.nbase))
-            * wt_base)) / (x8.shape[0] * 8)
+        res = _final_res(
+            ne.RowPlanes(x8, coh, wt_base, sta1, sta2, chunk_idx, kmax,
+                         n_stations, config.nbase), Jn)
     return Jn, res, k, passes
 
 
 @functools.partial(jax.jit, static_argnames=("row_period",))
 @jax.named_scope("sage/final")
 def _jit_res(x8, coh, sta1, sta2, chunk_idx, J, wt_base, row_period=0):
-    return jnp.linalg.norm(dtp.acc(
-        (x8 - full_model8(J, coh, sta1, sta2, chunk_idx, row_period))
-        * wt_base)) / (x8.shape[0] * 8)
+    return _final_res(
+        ne.RowPlanes(x8, coh, wt_base, sta1, sta2, chunk_idx, J.shape[1],
+                     J.shape[2], row_period), J)
 
 
 @jax.jit
 def _jit_wres2(xres, wt_base):
     """Weighted residual L2^2 — the sweep-entry anchor the host group
-    path feeds the group-step safeguard."""
-    return jnp.sum(dtp.acc(xres * wt_base) ** 2)
+    path feeds the group-step safeguard; ``xres [8, *rows]`` on planes,
+    ``wt_base [B, 8]``."""
+    w = jnp.moveaxis(wt_base, -1, 0).reshape(xres.shape)
+    return jnp.sum(dtp.acc(xres * w) ** 2)
 
 
 @jax.jit
 def _jit_wres2_tiles(xres, wt_base):
-    return jax.vmap(lambda x, w: jnp.sum(dtp.acc(x * w) ** 2))(xres,
-                                                               wt_base)
+    return jax.vmap(_jit_wres2.__wrapped__)(xres, wt_base)
 
 
 def sagefit_host(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
@@ -1325,7 +1432,7 @@ def sagefit_host(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
         if fused:
             t_sweep = time.perf_counter()
             J, xres, nerr_acc, nuM, tk = _call("em_sweep", _jit_em_sweep,
-                J, xres, nuM, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
+                J, xres, nuM, coh, sta1, sta2, chunk_idx, chunk_mask,
                 wt_base, nerr, jnp.asarray(weighted), jnp.asarray(last),
                 kci, jnp.asarray(order, jnp.int32), os_ids,
                 n_stations, cfg_i, total_iter, iter_bar, os_nsub)
@@ -1342,7 +1449,7 @@ def sagefit_host(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
                     J, xres, nerr_acc, nuM, tk = _call(
                         "cluster_update", _jit_cluster_update,
                         jnp.asarray(int(cj), jnp.int32), J, xres,
-                        nerr_acc, nuM, x8, coh, sta1, sta2, chunk_idx,
+                        nerr_acc, nuM, coh, sta1, sta2, chunk_idx,
                         chunk_mask, wt_base, nerr, jnp.asarray(weighted),
                         jnp.asarray(last), kci, None, os_ids, n_stations,
                         cfg_i, total_iter, iter_bar, os_nsub)
@@ -1356,7 +1463,7 @@ def sagefit_host(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
                     J, xres, nerr_acc, nuM, tk = _call(
                         "group_update", _jit_group_update,
                         jnp.asarray(opad[g * Gi:(g + 1) * Gi]), J, xres,
-                        nerr_acc, nuM, x8, coh, sta1, sta2, chunk_idx,
+                        nerr_acc, nuM, coh, sta1, sta2, chunk_idx,
                         chunk_mask, wt_base, nerr, jnp.asarray(weighted),
                         jnp.asarray(last), kci, os_ids, n_stations,
                         cfg_i, total_iter, iter_bar, os_nsub, anchor)
@@ -1462,42 +1569,19 @@ def _jit_sagefit_tiles(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
                                     "iter_bar", "os_nsub"),
                    donate_argnums=(0, 1, 2))
 @jax.named_scope("sage/sweep")
-def _jit_em_sweep_tiles(J, xres, nuM, x8, coh, sta1, sta2, chunk_idx,
+def _jit_em_sweep_tiles(J, xres, nuM, coh, sta1, sta2, chunk_idx,
                         chunk_mask, wt_base, nerr_prev, weighted, last,
                         keys, perm, os_ids, n_stations, config, total_iter,
                         iter_bar, os_nsub):
     """One EM sweep over all clusters for T tiles at once (vmapped
     :func:`_jit_em_sweep`; per-tile visiting order ``perm`` [T, M])."""
-    def one(J_t, xres_t, nuM_t, x8_t, coh_t, wt_t, nerr_t, key_t, perm_t):
-        os_id = None if os_ids is None else (os_ids, os_nsub)
-        M = chunk_mask.shape[0]
-        G = _eff_inflight(config, M)
-
-        if G == 1:
-            return _sweep_g1(
-                perm_t, (J_t, xres_t,
-                         jnp.zeros((M,), dtp.acc_dtype(x8.dtype)), nuM_t,
-                         jnp.zeros((N_TK,), jnp.int32)),
-                x8_t, coh_t, sta1, sta2, chunk_idx, chunk_mask, wt_t,
-                n_stations, config, nerr_t, weighted, last, key_t, None,
-                os_id, total_iter, iter_bar)
-
-        order_pad, n_groups = _pad_order(perm_t, M, G)
-        anchor = jnp.sum(dtp.acc(xres_t * wt_t) ** 2)   # per-tile sweep anchor
-
-        def group_step(g, inner):
-            cjs = jax.lax.dynamic_slice(order_pad, (g * G,), (G,))
-            return _group_update(cjs, inner, x8_t, coh_t, sta1, sta2,
-                                 chunk_idx, chunk_mask, wt_t, n_stations,
-                                 config, nerr_t, weighted, last, key_t,
-                                 None, os_id, total_iter, iter_bar,
-                                 res_anchor=anchor)
-        return jax.lax.fori_loop(
-            0, n_groups, group_step,
-            (J_t, xres_t, jnp.zeros((M,), dtp.acc_dtype(x8.dtype)), nuM_t,
-             jnp.zeros((N_TK,), jnp.int32)))
-    return jax.vmap(one)(J, xres, nuM, x8, coh, wt_base, nerr_prev, keys,
-                         perm)
+    os_id = None if os_ids is None else (os_ids, os_nsub)
+    return jax.vmap(
+        lambda J_t, xres_t, nuM_t, coh_t, wt_t, nerr_t, key_t, perm_t:
+        _em_sweep(J_t, xres_t, nuM_t, coh_t, sta1, sta2, chunk_idx,
+                  chunk_mask, wt_t, nerr_t, weighted, last, key_t, perm_t,
+                  os_id, n_stations, config, total_iter, iter_bar)
+    )(J, xres, nuM, coh, wt_base, nerr_prev, keys, perm)
 
 
 @functools.partial(jax.jit, static_argnames=("row_period",))
@@ -1647,7 +1731,7 @@ def sagefit_host_tiles(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
         if fused:
             J, xres, nerr_acc, nuM, tk = _call(
                 "em_sweep_tiles", _jit_em_sweep_tiles,
-                J, xres, nuM, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
+                J, xres, nuM, coh, sta1, sta2, chunk_idx, chunk_mask,
                 wt_base, nerr, jnp.asarray(weighted), jnp.asarray(last),
                 kci, order, os_ids, n_stations, cfg_i, total_iter,
                 iter_bar, os_nsub)
@@ -1662,7 +1746,7 @@ def sagefit_host_tiles(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
                 for cj in range(M):
                     J, xres, nerr_acc, nuM, tk = _call(
                         "cluster_update_tiles", _jit_cluster_update_tiles,
-                        order[:, cj], J, xres, nerr_acc, nuM, x8, coh,
+                        order[:, cj], J, xres, nerr_acc, nuM, coh,
                         sta1, sta2, chunk_idx, chunk_mask, wt_base, nerr,
                         jnp.asarray(weighted), jnp.asarray(last), kci,
                         os_ids, n_stations, cfg_i, total_iter,
@@ -1678,7 +1762,7 @@ def sagefit_host_tiles(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
                     J, xres, nerr_acc, nuM, tk = _call(
                         "group_update_tiles", _jit_group_update_tiles,
                         opad[:, g * Gi:(g + 1) * Gi], J, xres, nerr_acc,
-                        nuM, x8, coh, sta1, sta2, chunk_idx, chunk_mask,
+                        nuM, coh, sta1, sta2, chunk_idx, chunk_mask,
                         wt_base, nerr, jnp.asarray(weighted),
                         jnp.asarray(last), kci, os_ids, n_stations,
                         cfg_i, total_iter, iter_bar, os_nsub, anchor)
@@ -1740,23 +1824,25 @@ def sagefit_host_tiles(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
                                     "iter_bar", "os_nsub"),
                    donate_argnums=(1, 2, 3, 4))
 @jax.named_scope("sage/sweep")
-def _jit_cluster_update_tiles(cj, J, xres, nerr_acc, nuM, x8, coh, sta1,
+def _jit_cluster_update_tiles(cj, J, xres, nerr_acc, nuM, coh, sta1,
                               sta2, chunk_idx, chunk_mask, wt_base,
                               nerr_prev, weighted, last, keys, os_ids,
                               n_stations, config, total_iter, iter_bar,
                               os_nsub):
     """Vmapped :func:`_jit_cluster_update`: one cluster visit (per-tile
     cluster index ``cj`` [T]) across all tiles in one execution."""
-    def one(cj_t, J_t, xres_t, nerr_acc_t, nuM_t, x8_t, coh_t, wt_t,
+    def one(cj_t, J_t, xres_t, nerr_acc_t, nuM_t, coh_t, wt_t,
             nerr_t, key_t):
         os_id = None if os_ids is None else (os_ids, os_nsub)
         return _cluster_update(cj_t, (J_t, xres_t, nerr_acc_t, nuM_t,
                                       jnp.zeros((N_TK,), jnp.int32)),
-                               x8_t, coh_t, sta1, sta2, chunk_idx,
-                               chunk_mask, wt_t, n_stations, config,
+                               _visit_planes(cj_t, coh_t, wt_t, sta1, sta2,
+                                             chunk_idx, J_t.shape[1],
+                                             n_stations, config),
+                               coh_t, chunk_mask, wt_t, n_stations, config,
                                nerr_t, weighted, last, key_t, None, os_id,
                                total_iter, iter_bar)
-    return jax.vmap(one)(cj, J, xres, nerr_acc, nuM, x8, coh, wt_base,
+    return jax.vmap(one)(cj, J, xres, nerr_acc, nuM, coh, wt_base,
                          nerr_prev, keys)
 
 
@@ -1765,7 +1851,7 @@ def _jit_cluster_update_tiles(cj, J, xres, nerr_acc, nuM, x8, coh, sta1,
                                     "iter_bar", "os_nsub"),
                    donate_argnums=(1, 2, 3, 4))
 @jax.named_scope("sage/sweep")
-def _jit_group_update_tiles(cjs, J, xres, nerr_acc, nuM, x8, coh, sta1,
+def _jit_group_update_tiles(cjs, J, xres, nerr_acc, nuM, coh, sta1,
                             sta2, chunk_idx, chunk_mask, wt_base,
                             nerr_prev, weighted, last, keys, os_ids,
                             n_stations, config, total_iter, iter_bar,
@@ -1773,16 +1859,19 @@ def _jit_group_update_tiles(cjs, J, xres, nerr_acc, nuM, x8, coh, sta1,
     """Vmapped :func:`_jit_group_update`: one in-flight group visit
     (per-tile index rows ``cjs`` [T, G]) across all tiles;
     ``res_anchor`` [T] carries each tile's sweep-entry safeguard ref."""
-    def one(cjs_t, J_t, xres_t, na_t, nuM_t, x8_t, coh_t, wt_t, nerr_t,
+    def one(cjs_t, J_t, xres_t, na_t, nuM_t, coh_t, wt_t, nerr_t,
             key_t, anch_t):
         os_id = None if os_ids is None else (os_ids, os_nsub)
         return _group_update(cjs_t, (J_t, xres_t, na_t, nuM_t,
-                                     jnp.zeros((N_TK,), jnp.int32)), x8_t,
-                             coh_t, sta1, sta2, chunk_idx, chunk_mask,
+                                     jnp.zeros((N_TK,), jnp.int32)),
+                             _sweep_planes(coh_t, wt_t, sta1, sta2,
+                                           chunk_idx, J_t.shape[1],
+                                           n_stations, config),
+                             coh_t, chunk_mask,
                              wt_t, n_stations, config, nerr_t, weighted,
                              last, key_t, None, os_id, total_iter,
                              iter_bar, res_anchor=anch_t)
-    return jax.vmap(one)(cjs, J, xres, nerr_acc, nuM, x8, coh, wt_base,
+    return jax.vmap(one)(cjs, J, xres, nerr_acc, nuM, coh, wt_base,
                          nerr_prev, keys, res_anchor)
 
 

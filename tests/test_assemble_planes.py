@@ -139,9 +139,8 @@ def make_hess(monkeypatch):
     monkeypatch.setattr(rtr, "_tcg", spy)
     monkeypatch.setattr(jax.lax, "while_loop",
                         lambda cond, body, init: body(init))
-    rtr._rtr_rows(rows, pb["x8"], pb["coh"], pb["sta1"], pb["sta2"],
-                  pb["cid"], pb["wt"], J0, N, None, rtr.RTRConfig(itmax=1),
-                  None, None, nu, NB)
+    rtr._rtr_rows(rows, J0, N, None, rtr.RTRConfig(itmax=1), None, None,
+                  nu, NB)
     p0 = pb["P"].reshape(1, -1)
     e = ne.residual8(pb["x8"], J0, pb["coh"], pb["sta1"], pb["sta2"],
                      pb["cid"]) * pb["wt"]

@@ -109,9 +109,10 @@ def _compiled_cluster_update(one_chip):
     flag = sd((), jnp.bool_)
     return sage._jit_cluster_update.lower(
         sd((), i32),                                    # cj
-        sd((M, 1, N, 2, 2), c64), sd((B, 8), f32),      # J, xres
+        sd((M, 1, N, 2, 2), c64),                       # J
+        sd((8, TILESZ, NB), f32),                       # xres, on planes
         sd((M,), f32), sd((M,), f32),                   # nerr_acc, nuM
-        sd((B, 8), f32), sd((M, B, 2, 2), c64),         # x8, coh
+        sd((M, B, 2, 2), c64),                          # coh
         sd((B,), i32), sd((B,), i32),                   # sta1, sta2
         sd((M, B), i32), sd((M, 1), jnp.bool_),         # chunk idx/mask
         sd((B, 8), f32), sd((M,), f32),                 # wt, nerr_prev
@@ -254,9 +255,11 @@ CHIP_BYTES = int(15.75 * 2 ** 30)
 #: one ``f32[8, 226920, 2, 2]`` temporary tiled ``T(2,128)``: 27 MB of data
 PADDED_TEMP_BYTES = int(1.73 * 2 ** 30)
 #: a ceiling of its own where a program has been given room to lose:
-#: what it compiled to (PR 36) plus ONE such temporary
+#: what it compiled to (refine: PR 36; the two that hold a sweep: PR 41)
+#: plus ONE such temporary
 CEILING = {"refine": int(0.42 * 2 ** 30) + PADDED_TEMP_BYTES,
-           "sagefit": int(4.95 * 2 ** 30) + PADDED_TEMP_BYTES}
+           "sagefit": int(0.49 * 2 ** 30) + PADDED_TEMP_BYTES,
+           "cluster_update": int(0.10 * 2 ** 30) + PADDED_TEMP_BYTES}
 
 
 @functools.cache
@@ -294,8 +297,9 @@ def _need_120(one_chip, name):
         elif name == "cluster_update":
             cfg0 = cfg._replace(max_emiter=0)
             lowered = sage._jit_cluster_update.lower(
-                sd((), i32), J, wt, sd((M,), f32), sd((M,), f32),
-                *data, sd((M, 1), jnp.bool_), wt, sd((M,), f32), flag,
+                sd((), i32), J, sd((8, TILESZ_120, NB), f32),   # cj, J, xres
+                sd((M,), f32), sd((M,), f32),
+                *data[1:], sd((M, 1), jnp.bool_), wt, sd((M,), f32), flag,
                 flag, sd(key.shape, key.dtype), None,
                 sd(np.shape(os_ids), i32), N, cfg0, M * cfg0.max_iter, 2,
                 os_nsub)
@@ -319,32 +323,32 @@ def test_production_tile_fits(one_chip, program):
     program, and the simulation modes' (``-a 3 -p -z`` over 8 x 128
     sources, PR 37: no cell runs it at this size, because the reference
     takes 53 s to make one such tile's sky).  Argument + output + temp
-    as compiled here at PR 39 (PR 36's and PR 34's beside them, with
-    the temporaries the chip's own compile asked for then: PERF.md
-    section 5):
+    as compiled here at PR 41 (PR 39's, PR 36's and PR 34's beside
+    them, with the temporaries the chip's own compile asked for then:
+    PERF.md section 5):
 
-    ==============  ===========  =========  =========  ==============
-    program         -t 120 here  at PR 36   at PR 34   the chip, PR 34
-    ==============  ===========  =========  =========  ==============
-    sagefit          4.95 GiB     5.64 GiB  13.56 GiB  13.48 GiB
-    refine           0.42 GiB     0.42 GiB  13.55 GiB  13.47 GiB
-    cluster_update   5.50 GiB     7.02 GiB   7.02 GiB  not read
-    residual         2.30 GiB     2.30 GiB   2.30 GiB  2.27 GiB
-    simulate         2.29 GiB     2.29 GiB   aborts    not run
-    ==============  ===========  =========  =========  ==============
+    ==============  ===========  ========  =========  =========  ==============
+    program         -t 120 here  at PR 39  at PR 36   at PR 34   the chip, PR 34
+    ==============  ===========  ========  =========  =========  ==============
+    sagefit          0.49 GiB    4.95 GiB   5.64 GiB  13.56 GiB  13.48 GiB
+    refine           0.42 GiB    0.42 GiB   0.42 GiB  13.55 GiB  13.47 GiB
+    cluster_update   0.09 GiB    5.50 GiB   7.02 GiB   7.02 GiB  not read
+    residual         2.30 GiB    2.30 GiB   2.30 GiB   2.30 GiB  2.27 GiB
+    simulate         2.29 GiB    2.29 GiB   2.29 GiB   aborts    not run
+    ==============  ===========  ========  =========  =========  ==============
 
     Arguments are 0.076 GiB.  Until PR 36 nearly all of the solve was
     ``f32[8, 226920, 2, 2]`` temporaries tiled ``T(2,128)``, 1.73 GiB
     for 27 MB of data each, about seven live at once in the joint
-    refine's model passes.  The refine (PR 36) and the sweeps' assembly
-    (PR 39) work on ``[8, (8,) 120, 1891]`` planes (7 MB a cluster, no
-    padding) and hold NO such temporary: each has a ceiling of what it
-    compiled to plus one of them, so the old construction coming back
-    into the refine or into the assembly is what this case notices.
-    What ``sagefit`` and ``cluster_update`` still ask is the sweep's
-    ``update`` (``sage._model8`` holds ``[.., 2, 2]`` temporaries; no
-    promise there beyond these ceilings).  The solve's and the
-    residual's TOGETHER are 7.25 GiB: they fit side by side, though the
-    residual is only dispatched once the solve's result is fetched."""
+    refine's model passes.  The refine (PR 36), the sweeps' assembly
+    (PR 39) and the sweep's running residual with the cluster models it
+    adds and subtracts (PR 41) work on ``[8, (8,) 120, 1891]`` planes
+    (7 MB a cluster, no padding) and hold NO such temporary: each of the
+    three solve programs has a ceiling of what it compiled to plus one
+    of them, so the old construction coming back into the refine, into
+    the assembly or into the sweep's ``update`` is what this case
+    notices.  The solve's and the residual's TOGETHER are 2.74 GiB:
+    they fit side by side, though the residual is only dispatched once
+    the solve's result is fetched."""
     need = _need_120(one_chip, program)
     assert 0 < need < CEILING.get(program, CHIP_BYTES), need / 2 ** 30

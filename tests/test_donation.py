@@ -68,10 +68,13 @@ def _sweep_args(pb, solver_mode):
     iter_bar = int(-(-0.8 * total_iter // M))
     key = jax.random.fold_in(jax.random.PRNGKey(42), 0)
     perm = jnp.arange(M, dtype=jnp.int32)
-    xres = pb["x8"] - sage.full_model8(pb["J0"], pb["coh"], pb["s1"],
-                                       pb["s2"], pb["cidx"])
+    # the carry the host hands from program to program: the prelude's
+    # residual on planes
+    xres, _ = sage._jit_prelude(pb["x8"], pb["coh"], pb["s1"], pb["s2"],
+                                pb["cidx"], pb["J0"], pb["wt"],
+                                row_period=cfg.nbase)
     nuM = jnp.full((M,), 2.0, jnp.float32)
-    args = (pb["J0"], xres, nuM, pb["x8"], pb["coh"], pb["s1"], pb["s2"],
+    args = (pb["J0"], xres, nuM, pb["coh"], pb["s1"], pb["s2"],
             pb["cidx"], pb["cmask"], pb["wt"],
             jnp.zeros((M,), jnp.float32), jnp.asarray(False),
             jnp.asarray(False), key, perm, None)
@@ -126,12 +129,13 @@ def test_donated_cluster_update_bit_identical(problem):
     total_iter = M * cfg.max_iter
     iter_bar = int(-(-0.8 * total_iter // M))
     key = jax.random.fold_in(jax.random.PRNGKey(42), 0)
-    xres = pb["x8"] - sage.full_model8(pb["J0"], pb["coh"], pb["s1"],
-                                       pb["s2"], pb["cidx"])
+    xres, _ = sage._jit_prelude(pb["x8"], pb["coh"], pb["s1"], pb["s2"],
+                                pb["cidx"], pb["J0"], pb["wt"],
+                                row_period=cfg.nbase)
     und = jax.jit(sage._jit_cluster_update.__wrapped__,
                   static_argnames=("n_stations", "config", "total_iter",
                                    "iter_bar", "os_nsub"))
-    common = (pb["x8"], pb["coh"], pb["s1"], pb["s2"], pb["cidx"],
+    common = (pb["coh"], pb["s1"], pb["s2"], pb["cidx"],
               pb["cmask"], pb["wt"], jnp.zeros((M,), jnp.float32),
               jnp.asarray(False), jnp.asarray(False), key, None, None)
     kw = dict(n_stations=N_STA, config=cfg._replace(max_emiter=0),

@@ -1,0 +1,15 @@
+"""``consensus_dev_ms`` in the cell ``admm-f8-fold``: the reader of ``consensus_dev_ms.py``
+under a name of this cell's own, because that entry's list of cells
+exists and is not a ``model_config`` PR's to edit (PR 42; a
+``benchmark`` issue folds the twins into one entry each, with PR 34's
+``.t120`` and PR 37's ``.sub``).  Here the z-sum is a sum over the local subband axis and the ``psum`` is over one device: no wait for another chip is in it."""
+
+import harness
+
+_WAS = harness.load_module("layer_metrics", "consensus_dev_ms")
+NAME, UNIT = "consensus_dev_ms.fold", _WAS.UNIT
+LAYER, MOVES = _WAS.LAYER, _WAS.MOVES
+
+
+def read(run):
+    return _WAS.read(run)

@@ -323,6 +323,21 @@ def _main_consensus(args, dtrace) -> int:
     return 0
 
 
+def sage_config(args):
+    """The J update's solver settings as the parsed arguments give them
+    (``tests/test_chip_compile.py`` compiles the mesh program from the
+    same)."""
+    from sagecal_tpu.solvers import sage
+    return sage.SageConfig(
+        max_emiter=args.max_em_iter, max_iter=args.max_iter,
+        max_lbfgs=args.max_lbfgs, lbfgs_m=args.lbfgs_m,
+        solver_mode=int(SolverMode(args.solver_mode)),
+        nulow=args.nulow, nuhigh=args.nuhigh,
+        randomize=bool(args.randomize),
+        inflight=args.inflight, inner=args.inner, kernel=args.kernel,
+        dtype_policy=getattr(args, "dtype_policy", "f32"))
+
+
 class ConsensusStepper:
     """The consensus interval loop behind a seam a driver can step: the
     ``cli_mpi`` twin of ``pipeline.TileStepper``.
@@ -530,15 +545,7 @@ class ConsensusStepper:
             n_admm=args.admm, npoly=args.npoly, poly_type=args.polytype,
             rho=rho0, adaptive_rho=bool(args.adaptive_rho),
             spatialreg=spatialreg, federated_alpha=args.federated_alpha,
-            sage=sage.SageConfig(
-                max_emiter=args.max_em_iter, max_iter=args.max_iter,
-                max_lbfgs=args.max_lbfgs, lbfgs_m=args.lbfgs_m,
-                solver_mode=int(SolverMode(args.solver_mode)),
-                nulow=args.nulow, nuhigh=args.nuhigh,
-                randomize=bool(args.randomize),
-                inflight=args.inflight, inner=args.inner,
-                kernel=args.kernel,
-                dtype_policy=getattr(args, "dtype_policy", "f32")))
+            sage=sage_config(args))
 
         t0 = self.t0 = mss[0].read_tile(0)
         # host values for the interval's tile record: the row layouts
@@ -557,6 +564,14 @@ class ConsensusStepper:
             raise ValueError(f"{' and '.join(plans)} are different "
                              "execution plans; pick one")
         self.blk_timer = [] if args.block_f else None
+        # what the interval's tile record says of the execution plan, and
+        # how many subbands share one execution of a J update (the fold)
+        self.plan = ("stale" if args.staleness > 0
+                     else "blocked" if args.block_f
+                     else "host-loop" if args.host_loop else "traced")
+        self.fold = (1 if args.staleness > 0
+                     else min(args.block_f, fpad) if args.block_f
+                     else fpad // ndev)
         if args.time_shard == 1:
             raise ValueError("--time-shard 1 is ambiguous: use 0 (off, "
                              "the per-interval loop) or >= 2 time-mesh "
@@ -935,8 +950,8 @@ class ConsensusStepper:
             blk_timer.clear()
         with dtrace.phase("solve", tile=ti):
             with dtrace.phase("dispatch", prog="admm"):
-                JF_r8, Z, rhoF, res0, res1, r1s, duals, Y0F = self.runner(
-                    *args_dev)
+                (JF_r8, Z, rhoF, res0, res1, r1s, duals, Y0F,
+                 trips) = self.runner(*args_dev)
             # the traced plan is ONE device execution per interval:
             # while tracing, time it to its end (the fetch below would
             # block on it anyway)
@@ -968,6 +983,7 @@ class ConsensusStepper:
             r1s = fetch(r1s)[:, :nf]
             duals = fetch(duals)
             Y0F = fetch(Y0F)[:nf]
+            trips = fetch(trips)        # [n_admm, Fpad, 2], a few i32
         JF_r8_5 = np.asarray(JF_r8).reshape(nf, sky.n_clusters, kmax, n, 8)
         if self.worker_writers:
             J_all = utils.jones_r2c_np(JF_r8_5)
@@ -998,6 +1014,7 @@ class ConsensusStepper:
             BZf = np.einsum("fp,mpknr->fmknr", Bpoly, np.asarray(Z))
             primal = float(np.linalg.norm(JF_r8_5 - BZf)
                            / np.sqrt(BZf.size))
+            useful, lockstep_pct = cadmm.lockstep(trips, nf, self.fold)
         rec = {"tile": ti, "res_0": float(res0.mean()),
                "res_1": float(res1.mean()), "primal": primal,
                "dual": float(duals[-1]) if len(duals) else 0.0}
@@ -1110,6 +1127,9 @@ class ConsensusStepper:
                             rho_mean=float(np.asarray(
                                 self._fetch(rhoF))[:nf].mean()),
                             bubble_s=float(bubble), overlap=self.depth,
+                            fold=self.fold, ndev=self.ndev, plan=self.plan,
+                            jupdate_trips=useful,
+                            lockstep_pct=lockstep_pct,
                             sweep_rows=self.sweep_rows,
                             **({} if self.assemble_rows is None else
                                {"assemble_rows": self.assemble_rows}))

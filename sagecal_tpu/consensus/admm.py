@@ -100,6 +100,37 @@ def pad_subbands(arrays, B_poly, nf: int, ndev: int):
     return out, B, fpad
 
 
+def lockstep(trips, nf: int, group: int):
+    """What a fold costs in loop bodies, from a runner's ``trips``
+    [n_admm, Fpad, 2] (host array): ``(useful, pct)``.
+
+    ``useful``: trust-region plus inner-CG iterations the ``nf`` real
+    subbands' J updates needed, summed over subbands and ADMM
+    iterations. ``pct``: of the loop bodies a device executed, the share
+    spent on a slot that had already ended (or is padding). Subbands
+    that share one execution -- ``group`` consecutive slots: ``Fl`` of a
+    device of the mesh, ``--block-f`` of the blocked plan -- run their J
+    update under ``jax.vmap``, where every ``lax.while_loop`` makes the
+    trips of the slowest for all, the finished ones frozen by masks: per
+    ADMM iteration and group ``1 - sum_f t_f / (group x max_f t_f)``
+    with ``t_f`` subband f's two counts added, the mean over groups and
+    iterations, in percent. The loops nest (truncated CG inside the
+    trust region), so the slowest subband of a trip is not always the
+    same one and this is a lower bound. Exactly 0 at ``group`` 1."""
+    t = np.asarray(trips, np.int64).sum(axis=-1)        # [n_admm, Fpad]
+    real = np.arange(t.shape[1]) < nf
+    useful = int(t[:, real].sum())
+    if group <= 1 or t.size == 0:
+        return useful, 0.0
+    short = -t.shape[1] % group         # a ragged last block ran padded
+    t, real = np.pad(t, ((0, 0), (0, short))), np.pad(real, (0, short))
+    tg = np.where(real, t, 0).reshape(t.shape[0], -1, group)
+    top = t.reshape(tg.shape).max(axis=-1)
+    share = np.where(top > 0, tg.sum(axis=-1) / (group * np.maximum(top, 1)),
+                     1.0)
+    return useful, float(100.0 * (1.0 - share.mean()))
+
+
 def _blocks(J_r8):
     """[.., M, K, N, 8] real Jones -> [.., M*K, 2N, 2] complex blocks."""
     J = ne.jones_r2c(J_r8)
@@ -188,9 +219,11 @@ def make_admm_runner(dsky, sta1, sta2, cidx, cmask, n_stations: int,
 
     Returns ``run(x8F, uF, vF, wF, freqF, wtF, fratioF, J0F_r8)`` operating
     on [F, ...] arrays sharded over the mesh "freq" axis; gives back
-    (JF_r8, Z, rhoF, res0, res1, r1_per_admm, dual_per_admm, Y0F_r8)
-    where Y0F is the manifold-projected rho*J of iteration 0 (the MDL
-    input, master :815-822).
+    (JF_r8, Z, rhoF, res0, res1, r1_per_admm, dual_per_admm, Y0F_r8,
+    trips) where Y0F is the manifold-projected rho*J of iteration 0 (the
+    MDL input, master :815-822) and trips [n_admm, F, 2] i32 holds, per
+    ADMM iteration and subband, the ``solver_iters`` and ``cg_iters`` of
+    that subband's own J update (:func:`lockstep` reads them).
 
     B_poly: [Fpad, P] polynomial basis (host numpy, replicated); when the
     staged subband axis Fpad exceeds the real count ``nf_total`` (uneven
@@ -286,11 +319,19 @@ def make_admm_runner(dsky, sta1, sta2, cidx, cmask, n_stations: int,
     sage_cfg = (cfg.sage if not nbase
                 else cfg.sage._replace(nbase=int(nbase)))
 
+    def _trips(info):
+        # [2] i32: the trust-region (or LM) iterations and the inner CG
+        # iterations THIS subband's solve needed. Under the vmap of a
+        # fold a finished subband's loop carry is frozen while the
+        # slowest one's trips run, so these stay its own (lockstep())
+        return jnp.stack([info["solver_iters"],
+                          info["cg_iters"]]).astype(jnp.int32)
+
     def local_solve_plain(x8, u, v, w, wt, J_r8, freq, beam=None):
         coh = coh_for(u, v, w, freq, beam)
         J, info = sage.sagefit(x8, coh, sta1_j, sta2_j, cidx_j, cmask_j,
                                ne.jones_r2c(J_r8), N, wt, config=sage_cfg)
-        return ne.jones_c2r(J), info["res_0"], info["res_1"]
+        return ne.jones_c2r(J), info["res_0"], info["res_1"], _trips(info)
 
     def local_solve_admm(x8, u, v, w, wt, J_r8, freq, Y_r8, BZ_r8, rho_m,
                          beam=None):
@@ -303,7 +344,7 @@ def make_admm_runner(dsky, sta1, sta2, cidx, cmask, n_stations: int,
         J, info = sage.sagefit(x8, coh, sta1_j, sta2_j, cidx_j, cmask_j,
                                ne.jones_r2c(J_r8), N, wt, config=scfg,
                                admm=(Y_r8, BZ_r8, rho_m))
-        return ne.jones_c2r(J), info["res_0"], info["res_1"]
+        return ne.jones_c2r(J), info["res_0"], info["res_1"], _trips(info)
 
     axis = "freq"
 
@@ -434,9 +475,9 @@ def make_admm_runner(dsky, sta1, sta2, cidx, cmask, n_stations: int,
     def iter0_local(x8F, uF, vF, wF, freqF, wtF, fratioF, J0F,
                     beamF=None):
         """ADMM iteration 0 on the LOCAL shard: plain solve + post."""
-        JF, res0, res1 = _per_subband(local_solve_plain)(
+        JF, res0, res1, tk = _per_subband(local_solve_plain)(
             x8F, uF, vF, wF, wtF, J0F, freqF, beamF)
-        return iter0_post(JF, res0, res1, fratioF)
+        return iter0_post(JF, res0, res1, fratioF) + (tk,)
 
     @jax.named_scope(CONSENSUS_SCOPE)
     def body_post(Jr, r0, r1, carry, it, ax=axis):
@@ -487,10 +528,11 @@ def make_admm_runner(dsky, sta1, sta2, cidx, cmask, n_stations: int,
         Fl = x8F.shape[0]
         with jax.named_scope(CONSENSUS_SCOPE):
             BZ = jnp.einsum("fp,mpknr->fmknr", _brow(Fl), carry[2])
-        Jr, r0, r1 = _per_subband(local_solve_admm)(
+        Jr, r0, r1, tk = _per_subband(local_solve_admm)(
             x8F, uF, vF, wF, wtF, carry[0], freqF, carry[1], BZ,
             carry[3], beamF)
-        return body_post(Jr, r0, r1, carry, it)
+        carry, per_iter = body_post(Jr, r0, r1, carry, it)
+        return carry, per_iter + (tk,)
 
     if _return_parts:
         # building blocks for make_admm_runner_blocked (same math,
@@ -505,18 +547,19 @@ def make_admm_runner(dsky, sta1, sta2, cidx, cmask, n_stations: int,
                      *beam_rest):
         # shapes here are the LOCAL shard: [Fl, ...]
         beamF = beam_rest[0] if beam_rest else None
-        carry, res0, res1, Y0F = iter0_local(x8F, uF, vF, wF, freqF, wtF,
-                                             fratioF, J0F, beamF)
+        carry, res0, res1, Y0F, tk0 = iter0_local(
+            x8F, uF, vF, wF, freqF, wtF, fratioF, J0F, beamF)
 
         def body(carry, it):
             return body_local(x8F, uF, vF, wF, freqF, wtF, carry, it,
                               beamF)
 
-        carry, (r0s, r1s, duals) = jax.lax.scan(
+        carry, (r0s, r1s, duals, tks) = jax.lax.scan(
             body, carry, jnp.arange(1, max(cfg.n_admm, 1),
                                     dtype=jnp.int32))
         JF, YF, Z, rhoF = carry[0], carry[1], carry[2], carry[3]
-        return JF, Z, rhoF, res0, res1, r1s, duals, Y0F
+        trips = jnp.concatenate([tk0[None], tks])    # [n_admm, Fl, 2]
+        return JF, Z, rhoF, res0, res1, r1s, duals, Y0F, trips
 
     from jax import shard_map
     spec_f = P(axis)
@@ -527,7 +570,7 @@ def make_admm_runner(dsky, sta1, sta2, cidx, cmask, n_stations: int,
             admm_program, mesh=mesh,
             in_specs=(spec_f,) * nin,
             out_specs=(spec_f, spec_r, spec_f, spec_f, spec_f,
-                       P(None, axis), spec_r, spec_f),
+                       P(None, axis), spec_r, spec_f, P(None, axis)),
             check_vma=False)
         return jax.jit(prog)
 
@@ -541,23 +584,23 @@ def make_admm_runner(dsky, sta1, sta2, cidx, cmask, n_stations: int,
 
     def iter0_flat(x8F, uF, vF, wF, freqF, wtF, fratioF, J0F,
                    *beam_rest):
-        carry, res0, res1, Y0F = iter0_local(
+        carry, res0, res1, Y0F, tk = iter0_local(
             x8F, uF, vF, wF, freqF, wtF, fratioF, J0F,
             beam_rest[0] if beam_rest else None)
-        return carry + (res0, res1, Y0F)
+        return carry + (res0, res1, Y0F, tk)
 
     def body_flat(x8F, uF, vF, wF, freqF, wtF, JF, YF, Z, rhoF, Yhat,
                   Jprev, Zbar, Xd, rho_upper, it, *beam_rest):
         carry = (JF, YF, Z, rhoF, Yhat, Jprev, Zbar, Xd, rho_upper)
-        carry, (r0, r1, dual) = body_local(
+        carry, (r0, r1, dual, tk) = body_local(
             x8F, uF, vF, wF, freqF, wtF, carry, it,
             beam_rest[0] if beam_rest else None)
-        return carry + (r0, r1, dual)
+        return carry + (r0, r1, dual, tk)
 
     beam_specs = (spec_f,) if dobeam else ()
     prog0 = jax.jit(shard_map(
         iter0_flat, mesh=mesh, in_specs=(spec_f,) * 8 + beam_specs,
-        out_specs=carry_specs + (spec_f, spec_f, spec_f),
+        out_specs=carry_specs + (spec_f, spec_f, spec_f, spec_f),
         check_vma=False))
     # the ADMM carry (J/Y/Z/rho accumulators + BB state) is DONATED to
     # each body execution: every iteration rebinds the carry from the
@@ -566,7 +609,7 @@ def make_admm_runner(dsky, sta1, sta2, cidx, cmask, n_stations: int,
     progb = jax.jit(shard_map(
         body_flat, mesh=mesh,
         in_specs=(spec_f,) * 6 + carry_specs + (spec_r,) + beam_specs,
-        out_specs=carry_specs + (spec_f, spec_f, spec_r),
+        out_specs=carry_specs + (spec_f, spec_f, spec_r, spec_f),
         check_vma=False),
         donate_argnums=tuple(range(6, 15)) if donate else ())
 
@@ -603,7 +646,7 @@ def make_admm_runner(dsky, sta1, sta2, cidx, cmask, n_stations: int,
         out = prog0(x8F, uF, vF, wF, freqF, wtF, fratioF, J0F,
                     *beam_rest)
         _t("iter0", t0, out[0])
-        carry, (res0, res1, Y0F) = out[:9], out[9:]
+        carry, (res0, res1, Y0F, tk0) = out[:9], out[9:]
         # per-iteration convergence records are DEFERRED: the means are
         # dispatched on device here (gated, cheap) and fetched in ONE
         # batched transfer after the loop, so tracing never inserts a
@@ -611,15 +654,16 @@ def make_admm_runner(dsky, sta1, sta2, cidx, cmask, n_stations: int,
         pend = []
         if dtrace.active() or obs.active():
             pend.append((0, jnp.mean(res1), None, jnp.mean(carry[3])))
-        r1s, duals = [], []
+        r1s, duals, tks = [], [], [tk0]
         for it in range(1, max(cfg.n_admm, 1)):
             t0 = _time.perf_counter()
             out = progb(x8F, uF, vF, wF, freqF, wtF, *carry,
                         jnp.asarray(it, jnp.int32), *beam_rest)
             _t(f"body[{it}]", t0, out[0])
-            carry, (_, r1, dual) = out[:9], out[9:]
+            carry, (_, r1, dual, tk) = out[:9], out[9:]
             r1s.append(r1)
             duals.append(dual)
+            tks.append(tk)
             if dtrace.active() or obs.active():
                 pend.append((it, jnp.mean(r1), dual,
                              jnp.mean(carry[3])))
@@ -630,7 +674,8 @@ def make_admm_runner(dsky, sta1, sta2, cidx, cmask, n_stations: int,
                  else jnp.zeros((0, F), x8F.dtype))
         duals_a = (jnp.stack(duals) if duals
                    else jnp.zeros((0,), x8F.dtype))
-        return JF, Z, rhoF, res0, res1, r1s_a, duals_a, Y0F
+        return (JF, Z, rhoF, res0, res1, r1s_a, duals_a, Y0F,
+                jnp.stack(tks))
 
     run.consensus_program = prog_cons
     return run
@@ -755,15 +800,15 @@ def make_admm_runner_2d(dsky, sta1, sta2, cidx, cmask, n_stations: int,
         iterations, every consensus step a freq-axis collective.
         Returns (Jnext, outputs) — Jnext is the warm-start carry for
         the next interval in this time shard's block."""
-        JF, res0, res1 = _per_subband(lsp)(x8t, ut, vt, wt_, wtt, Jc,
-                                           freqF)
+        JF, res0, res1, _ = _per_subband(lsp)(x8t, ut, vt, wt_, wtt, Jc,
+                                              freqF)
         carry, res0, res1, Y0F = iter0_post(JF, res0, res1, frt)
         Fl = x8t.shape[0]
 
         def body(carry, it):
             Brow = _brow(Fl)
             BZ = jnp.einsum("fp,mpknr->fmknr", Brow, carry[2])
-            Jr, r0, r1 = _per_subband(lsa)(
+            Jr, r0, r1, _ = _per_subband(lsa)(
                 x8t, ut, vt, wt_, wtt, carry[0], freqF, carry[1], BZ,
                 carry[3])
             return body_post(Jr, r0, r1, carry, it)
@@ -1062,25 +1107,25 @@ def make_admm_runner_stale(dsky, sta1, sta2, cidx, cmask,
 
         def sub_solve0(f):
             t0 = _time.perf_counter()
-            Jb, r0b, r1b = solve0(take(x8F, f), take(uF, f), take(vF, f),
-                                  take(wF, f), take(wtF, f),
-                                  take(J0F, f), take(freqF, f))
+            Jb, r0b, r1b, tkb = solve0(
+                take(x8F, f), take(uF, f), take(vF, f), take(wF, f),
+                take(wtF, f), take(J0F, f), take(freqF, f))
             _t(f"solve0[{f}]", t0, Jb)
-            return Jb, r0b, r1b
+            return Jb, r0b, r1b, tkb
 
         def sub_solveb(f, JF, YF, BZ, rhoF):
             t0 = _time.perf_counter()
-            Jb, r0b, r1b = solveb(take(x8F, f), take(uF, f), take(vF, f),
-                                  take(wF, f), take(wtF, f),
-                                  take(JF, f), take(freqF, f),
-                                  take(YF, f), take(BZ, f),
-                                  take(rhoF, f))
+            Jb, r0b, r1b, tkb = solveb(
+                take(x8F, f), take(uF, f), take(vF, f), take(wF, f),
+                take(wtF, f), take(JF, f), take(freqF, f), take(YF, f),
+                take(BZ, f), take(rhoF, f))
             _t(f"solve[{f}]", t0, Jb)
-            return Jb, r0b, r1b
+            return Jb, r0b, r1b, tkb
 
         # --- iteration 0: synchronous for every subband (the dual
         # seed + manifold averaging need the full subband set)
-        Js, r0s, r1s_l = zip(*[sub_solve0(f) for f in range(F)])
+        Js, r0s, r1s_l, tk_l = zip(*[sub_solve0(f) for f in range(F)])
+        tks = [jnp.concatenate(tk_l)]
         JF = jnp.concatenate(Js)
         res0 = jnp.concatenate(r0s)
         res1 = jnp.concatenate(r1s_l)
@@ -1126,10 +1171,11 @@ def make_admm_runner_stale(dsky, sta1, sta2, cidx, cmask,
             BZ = bz_prog(Z, Brow_full)
             Jr = JF
             r1_new = r1_cur
+            tk_l = [jnp.zeros_like(tk_l[0])] * F    # skipped: no trips
             for f in range(F):
                 if upd_np[f] == 0.0:
                     continue
-                Jb, _r0b, r1b = sub_solveb(f, JF, YF, BZ, rhoF)
+                Jb, _r0b, r1b, tk_l[f] = sub_solveb(f, JF, YF, BZ, rhoF)
                 # in-place-style scatter: one dispatch per subband,
                 # no full-[F] copies (the values land verbatim, so
                 # the S=0 bit-identity gate is untouched)
@@ -1144,6 +1190,7 @@ def make_admm_runner_stale(dsky, sta1, sta2, cidx, cmask,
             _t(f"cons[{it}]", t0, Z)
             r1h.append(r1_cur)
             dualh.append(dual)
+            tks.append(jnp.concatenate(tk_l))
             if dtrace.active() or obs.active():
                 pend.append((it, jnp.mean(r1_cur), dual,
                              jnp.mean(rhoF)))
@@ -1159,7 +1206,8 @@ def make_admm_runner_stale(dsky, sta1, sta2, cidx, cmask,
                  else jnp.zeros((0, F), x8F.dtype))
         duals_a = (jnp.stack(dualh) if dualh
                    else jnp.zeros((0,), x8F.dtype))
-        return JF, Z, rhoF, res0, res1, r1s_a, duals_a, Y0F
+        return (JF, Z, rhoF, res0, res1, r1s_a, duals_a, Y0F,
+                jnp.stack(tks))
 
     run.schedule = schedule
     run.dead = dead_log
@@ -1264,19 +1312,15 @@ def make_admm_runner_blocked(dsky, sta1, sta2, cidx, cmask,
 
         def blockwise(fn, *per_iter):
             """fn(x8, u, v, w, wt, freq, *per-iteration block args)."""
-            Js, r0s, r1s = [], [], []
+            outs = []           # per block: (J, res0, res1, trips)
             for i, sl in enumerate(blocks):
                 t0 = _time.perf_counter()
                 bb = (beam_blocks[i],) if beam_blocks is not None else ()
-                Jb, r0b, r1b = fn(*const_blocks[i],
-                                  *[take(a, sl) for a in per_iter], *bb)
-                _t(f"solve[{i}]", t0, Jb)
-                nreal = sl.stop - sl.start
-                Js.append(Jb[:nreal])
-                r0s.append(r0b[:nreal])
-                r1s.append(r1b[:nreal])
-            return (jnp.concatenate(Js), jnp.concatenate(r0s),
-                    jnp.concatenate(r1s))
+                out = fn(*const_blocks[i],
+                         *[take(a, sl) for a in per_iter], *bb)
+                _t(f"solve[{i}]", t0, out[0])
+                outs.append([o[:sl.stop - sl.start] for o in out])
+            return tuple(jnp.concatenate(o) for o in zip(*outs))
 
         def solve0_re(x8, u, v, w, wt, freq, J0, *bb):
             return solve0(x8, u, v, w, wt, J0, freq, *bb)
@@ -1284,16 +1328,17 @@ def make_admm_runner_blocked(dsky, sta1, sta2, cidx, cmask,
         def solveb_re(x8, u, v, w, wt, freq, J, Y, BZ, rho, *bb):
             return solveb(x8, u, v, w, wt, J, freq, Y, BZ, rho, *bb)
 
-        JF, res0, res1 = blockwise(solve0_re, J0F)
+        JF, res0, res1, tk = blockwise(solve0_re, J0F)
         t0 = _time.perf_counter()
         carry, res0, res1, Y0F = cons0(JF, res0, res1, fratioF)
         _t("cons0", t0, carry[2])
-        r1h, dualh = [], []
+        r1h, dualh, tks = [], [], [tk]
         pend = []       # deferred admm_iter records (no per-iter sync)
         for it in range(1, max(cfg.n_admm, 1)):
             BZ = bz_prog(carry[2], Brow_full)
-            Jr, r0, r1 = blockwise(solveb_re, carry[0], carry[1], BZ,
-                                   carry[3])
+            Jr, r0, r1, tk = blockwise(solveb_re, carry[0], carry[1], BZ,
+                                       carry[3])
+            tks.append(tk)
             t0 = _time.perf_counter()
             carry, (r0, r1, dual) = consb(Jr, r0, r1, carry,
                                           jnp.asarray(it, jnp.int32))
@@ -1309,6 +1354,7 @@ def make_admm_runner_blocked(dsky, sta1, sta2, cidx, cmask,
                  else jnp.zeros((0, F), x8F.dtype))
         duals_a = (jnp.stack(dualh) if dualh
                    else jnp.zeros((0,), x8F.dtype))
-        return JF, Z, rhoF, res0, res1, r1s_a, duals_a, Y0F
+        return (JF, Z, rhoF, res0, res1, r1s_a, duals_a, Y0F,
+                jnp.stack(tks))
 
     return run

@@ -66,6 +66,27 @@ OVERTAKEN = {
        "the cell's per-layer list has grown by host_serial_ms and "
        "chip_wait_ms: new entries go at the end, for every cell"
        for module in ("test_subtract", "test_t120", "test_consensus")},
+    # PR 42 appended a cell, a configuration and nine entries that list
+    # the new cell alone.  These two pin lists by PLACE and fail from the
+    # first entry appended after PR 40's two: the first holds ``workloads``
+    # equal to the five cells there were and ``per_layer[-2:]`` equal to
+    # PR 40's entries; the second runs test_subtract.py's case above on
+    # the manifest less PR 40's two entries only, and that case holds the
+    # LAST of ``configs``, ``workloads`` and ``per_layer``.  What they
+    # guard is held by name in benchmarks/tests/test_fold.py: PR 40's two
+    # entries still list the five older cells and only PR 42's follow
+    # them, every older cell's own per-layer list is unchanged, and the
+    # three place-pinning cases run whole on the manifest less everything
+    # appended since.  PERF.md section 7, Open after PR 40 (1), asks the
+    # next ``benchmark`` issue to hold these lists by name.
+    "test_host_spans.py::"
+    "test_the_two_entries_are_the_lists_last_and_for_all_five_cells":
+        "workloads is no longer the five cells and per_layer[-2:] no "
+        "longer PR 40's two: PR 42's cell and entries go at the end",
+    "test_host_spans.py::test_what_pins_a_cells_list_by_place_holds_less_"
+    "the_new_entries[test_subtract]":
+        "test_subtract.py holds the LAST configuration, cell and entries "
+        "of the manifest, which are PR 42's now",
 }
 
 

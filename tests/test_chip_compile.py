@@ -352,3 +352,92 @@ def test_production_tile_fits(one_chip, program):
     the solve's result is fetched."""
     need = _need_120(one_chip, program)
     assert 0 < need < CEILING.get(program, CHIP_BYTES), need / 2 ** 30
+
+
+# -- the folded consensus program (PR 42) -------------------------------------
+
+#: eight subbands of 18 910 rows: what the program compiled to (0.368 GiB
+#: of temporaries on the parent and with the trips among its outputs,
+#: PERF.md section 5) with a tenth of room, plus ONE subband's
+#: ``f32[8, 18910, 2, 2]`` tiled ``T(2,128)`` (0.144 GiB for 2.4 MB of
+#: data): the ``[B, 2, 2]`` construction coming back under the batch axis
+#: would bring eight of them a live value
+FOLD_TEMP_CEILING = int(0.40 * 2 ** 30) + 8 * B * 2 * 128 * 4
+FOLD_COMPILE_LIMIT_S = 600
+
+
+def test_folded_consensus_program_compiles_and_fits(one_chip):
+    """``cli_mpi``'s default plan for more subbands than devices, as the
+    cell ``admm-f8-fold`` runs it: ``make_admm_runner``'s one program of
+    all ten ADMM iterations of an interval over a ONE-device mesh, the
+    eight J updates of an iteration under ``jax.vmap`` (``Fl`` = 8), at
+    N = 62, 18 910 rows a subband, the configuration's own sky and flags
+    (``benchmarks/configs/lofar62-f8-fold-m8x3.json``) and f32
+    contractions in f32.  It lowers in about 20 s and compiles in about
+    30 s here, at ``-A 10`` as the cell has it (the scan's body compiles
+    once whatever ``-A`` is).  A time limit of its own: past it the
+    process is ended with every thread's traceback, which fails this
+    case and not the suite's clock."""
+    import faulthandler
+    import tempfile
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
+                                    "benchmarks"))
+    import datagen
+    import harness
+    import reference
+    from sagecal_tpu import cli_mpi, skymodel
+    from sagecal_tpu.consensus import admm as cadmm, poly as cpoly
+    from sagecal_tpu.rime import predict as rp
+
+    conf = harness.load_config(
+        "benchmarks/configs/lofar62-f8-fold-m8x3.json")
+    freqs = np.asarray(conf["subband_freqs_hz"])
+    Fl = len(freqs)
+    obs = reference.Observation(conf, 7)
+    assert (obs.n_sta, obs.nrows, Fl) == (N, B, 8)
+    with tempfile.TemporaryDirectory() as tmp:
+        sky_path, cl_path = datagen.write_sky(obs, tmp)
+        args = cli_mpi.build_parser().parse_args(
+            ["-f", "x", "-s", sky_path, "-c", cl_path, *conf["cli"]])
+        sky = skymodel.read_sky_cluster(
+            sky_path, cl_path, obs.ra0, obs.dec0, float(freqs.mean()),
+            bool(args.format))
+    assert args.admm == 10 and sky.n_clusters == M
+    # host constants: nothing is placed on a device that is not there
+    dsky = jax.tree.map(np.asarray, rp.sky_to_device(sky, jnp.float32))
+    kmax = int(sky.nchunk.max())
+    cmask = np.arange(kmax)[None, :] < sky.nchunk[:, None]
+    cidx = rp.chunk_indices(TILESZ, NB, sky.nchunk)
+    _, _, _, s1, s2 = obs.geometry(0)
+    cfg = cadmm.ADMMConfig(
+        n_admm=args.admm, npoly=args.npoly, poly_type=args.polytype,
+        rho=np.full(M, float(conf["cluster_rho"])),
+        sage=cli_mpi.sage_config(args))
+    mesh = Mesh(np.array(list(one_chip.device_set)), ("freq",))
+    sh = NamedSharding(mesh, P("freq"))
+
+    def sd(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sh)
+
+    faulthandler.dump_traceback_later(FOLD_COMPILE_LIMIT_S, exit=True)
+    try:
+        with jax.default_matmul_precision("highest"):
+            runner = cadmm.make_admm_runner(
+                dsky, s1, s2, cidx, cmask, N, obs.fdelta,
+                cpoly.setup_polynomials(freqs, float(freqs.mean()),
+                                        args.npoly, args.polytype),
+                cfg, mesh, Fl, nbase=NB)
+            compiled = runner.lower(
+                sd(Fl, B, 8), sd(Fl, B), sd(Fl, B), sd(Fl, B), sd(Fl),
+                sd(Fl, B, 8), sd(Fl), sd(Fl, M, kmax, N, 8)).compile()
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+    mem = compiled.memory_analysis()
+    # J, Z, rho, res0, res1, r1s, duals, Y0 and the trips
+    assert len(compiled.out_info) == 9
+    assert compiled.out_info[8].shape == (args.admm, Fl, 2)
+    assert 0 < mem.temp_size_in_bytes < FOLD_TEMP_CEILING, \
+        mem.temp_size_in_bytes / 2 ** 30
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < HBM_BYTES

@@ -188,7 +188,7 @@ def test_mesh_admm_roundtrip(ndev):
     sh = NamedSharding(mesh, P("freq"))
     args = [jax.device_put(jnp.asarray(a), sh) for a in
             (x8F, uF, vF, wF, freqs, wtF, fratioF, J0F)]
-    JF_r8, Z, rhoF, res0, res1, r1s, duals, Y0F = runner(*args)
+    JF_r8, Z, rhoF, res0, res1, r1s, duals, Y0F, _ = runner(*args)
 
     JF = utils.jones_r2c_np(np.asarray(JF_r8)).reshape(
         nf, sky.n_clusters, kmax, n, 2, 2)

@@ -369,7 +369,7 @@ def test_multichip_admm_cg_residuals_fall():
              np.broadcast_to(tile.w, (F, B)), freqs,
              np.broadcast_to(wt, (F,) + wt.shape), np.ones(F),
              utils.jones_c2r_np(J0))]
-    JF, Z, rhoF, res0, res1, r1s, duals, Y0F = runner(*args)
+    JF, Z, rhoF, res0, res1, r1s, duals, Y0F, _ = runner(*args)
     res0 = np.asarray(res0)
     res1 = np.asarray(res1)
     assert np.all(np.isfinite(res1))

@@ -155,7 +155,7 @@ def test_mesh_admm_subband_folding():
              np.broadcast_to(wt, (F,) + wt.shape).copy(),
              np.ones(F),
              utils.jones_c2r_np(J0))]
-    JF, Z, rhoF, res0, res1, r1s, duals, Y0F = runner(*args)
+    JF, Z, rhoF, res0, res1, r1s, duals, Y0F, _ = runner(*args)
     jax.block_until_ready(JF)
     assert JF.shape[0] == F          # every folded subband produced output
     assert np.all(np.isfinite(np.asarray(res1)))

@@ -372,7 +372,7 @@ class ConsensusStepper:
         from sagecal_tpu.io import dataset as ds, solutions as sol
         from sagecal_tpu.rime import predict as rp
         from sagecal_tpu.rime import residual as rr
-        from sagecal_tpu.solvers import normal_eq as nesolver, sage
+        from sagecal_tpu.solvers import sage
 
         self.args, self.log = args, log
         if paths is None:
@@ -635,13 +635,13 @@ class ConsensusStepper:
             # storage-dtype writeback emission (out_dtype): the d->h
             # readback ships sdt bytes; identity at "f32"
             return rr.calculate_residuals_pairs(
-                dsky, nesolver.jones_r2c(J_r8), x_r, u, v, w, freq[None],
+                dsky, J_r8, x_r, u, v, w, freq[None],
                 meta0["fdelta"], jnp.asarray(t0.sta1), jnp.asarray(t0.sta2),
                 jnp.asarray(cidx), jnp.asarray(sky.subtract_mask()),
                 out_dtype=sdt, correct_idx=correct_idx,
                 rho=args.mmse_rho, phase_only=bool(args.phase_only),
                 beam=beam_rest[0] if beam_rest else None, dobeam=dobeam,
-                tslot=tslot_rows)
+                tslot=tslot_rows, row_period=int(meta0["nbase"]))
 
         # constructed exactly once per stepper
         self.res_jit = jax.jit(jax.vmap(residual_fn))

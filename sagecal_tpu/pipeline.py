@@ -571,13 +571,14 @@ class FullBatchPipeline:
         # this output share shape AND dtype, so the ring keeps working
         # and the d->h readback ships storage bytes (rr doc)
         return rr.calculate_residuals_pairs(
-            self.dsky, ne.jones_r2c(J_r8), x_r, u, v, w, freqs,
+            self.dsky, J_r8, x_r, u, v, w, freqs,
             meta["fdelta"] / len(meta["freqs"]), sta1, sta2,
             jnp.asarray(self.cidx), sub,
             out_dtype=self.sdt if out_dtype is None else out_dtype,
             correct_idx=self._correct_idx(), rho=self.cfg.mmse_rho,
             beam=beam, dobeam=self.dobeam, tslot=jnp.asarray(self.tslot),
-            phase_only=self.cfg.phase_only)
+            phase_only=self.cfg.phase_only,
+            row_period=int(meta["nbase"]))
 
     def _chan_residual(self, J_r8, x_r, u, v, w, sta1, sta2, freq, beam):
         # the -b 1 channel path assembles its residuals host-side with
@@ -1055,10 +1056,11 @@ class FullBatchPipeline:
                 self.dsky, x_r, u, v, w,
                 jnp.asarray(meta["freqs"], self.rdt),
                 meta["fdelta"] / len(meta["freqs"]), sta1, sta2,
-                mode=mode, J=None if J_r8 is None else ne.jones_r2c(J_r8),
+                mode=mode, J=J_r8,
                 chunk_idx=jnp.asarray(self.cidx), ignore_mask=ignore_mask,
                 beam=beam, dobeam=self.dobeam,
-                tslot=jnp.asarray(self.tslot))
+                tslot=jnp.asarray(self.tslot),
+                row_period=int(meta["nbase"]))
 
         # keyed through the process-wide program cache (serve/cache.py)
         # instead of the old per-instance lazy attribute: a second job

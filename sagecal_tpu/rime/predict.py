@@ -10,7 +10,10 @@ Re-architected TPU-first: instead of a pthread pool over baseline ranges
 calling per-source scalar functions, the whole (cluster, baseline, channel,
 source) product is one vectorized masked computation. Clusters are mapped
 with ``lax.map`` (peak memory [S, B] per cluster) and everything inside
-fuses into a handful of XLA kernels on the MXU/VPU.
+fuses into a handful of XLA kernels on the VPU. The Jones sandwich of
+the model that leaves the solver (:func:`predict_model`) is real
+elementwise arithmetic on planes (``rime/planes.py``), the source sum's
+four correlations handed to it as eight real planes a cluster.
 
 Conventions (identical to reference):
 - u,v,w in SECONDS (meters/c); multiply by frequency for wavelengths.
@@ -32,7 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from sagecal_tpu.rime import envelopes
+from sagecal_tpu.rime import envelopes, planes as pl
 from sagecal_tpu.skymodel import ClusterSky, STYPE_SHAPELET
 
 
@@ -107,8 +110,12 @@ def _spectral_flux(s0, spec_idx, spec_idx1, spec_idx2, f0, freq):
 @jax.named_scope("rime/phasor")
 def _cluster_coherency(csky, u, v, w, freqs, fdelta, per_channel_flux: bool,
                        n0max: int, with_shapelets: bool,
-                       af=None, E=None, tslot=None, sta1=None, sta2=None):
-    """Coherencies of ONE cluster: [B, F, 2, 2] complex.
+                       af=None, E=None, tslot=None, sta1=None, sta2=None,
+                       planes: bool = False):
+    """Coherencies of ONE cluster: [B, F, 2, 2] complex, or with
+    ``planes`` the same numbers as eight real planes [8, F, B]
+    (``rime/planes.py``: the source sum's four correlations, real and
+    imaginary parts, as they come out of the sum).
 
     ``csky`` is a SkyArrays row (arrays [S]); u,v,w [B] seconds; freqs [F].
     Beam (predict_withbeam.c:139-187): ``af`` [F, S, T, N] array-factor
@@ -170,27 +177,33 @@ def _cluster_coherency(csky, u, v, w, freqs, fdelta, per_channel_flux: bool,
             xy = jnp.sum(phasor * b01[None, :], axis=1)
             yx = jnp.sum(phasor * b10[None, :], axis=1)
             yy = jnp.sum(phasor * b11[None, :], axis=1)
+            if planes:
+                return jnp.stack([part for c in (xx, xy, yx, yy)
+                                  for part in (c.real, c.imag)])  # [8, B]
             return jnp.stack([jnp.stack([xx, xy], -1),
                               jnp.stack([yx, yy], -1)], -2)  # [B, 2, 2]
         # element beam: per-source 2x2 sandwich, then sum over sources
         Bm = jnp.stack([jnp.stack([b00, b01], -1),
                         jnp.stack([b10, b11], -1)], -2)      # [S, 2, 2]
         Bm = phasor[..., None, None] * Bm[None]              # [B, S, 2, 2]
-        return jnp.einsum("bsij,bsjk,bslk->bil", E1, Bm, jnp.conj(E2))
+        out = jnp.einsum("bsij,bsjk,bslk->bil", E1, Bm, jnp.conj(E2))
+        return pl.jones_c2r(out).T if planes else out
 
     if af is None:
         out = jax.vmap(lambda f: one_channel(f), out_axes=1)(freqs)
     else:
         out = jax.vmap(one_channel, out_axes=1)(freqs, af)
-    return out  # [B, F, 2, 2]
+    return out  # [B, F, 2, 2], or the planes [8, F, B]
 
 
 def coherencies(sky: SkyArrays, u, v, w, freqs, fdelta,
                 per_channel_flux: bool = False,
                 with_shapelets: bool | None = None,
                 beam=None, dobeam: int = 0,
-                tslot=None, sta1=None, sta2=None):
-    """All-cluster coherencies [M, B, F, 2, 2] (no Jones applied).
+                tslot=None, sta1=None, sta2=None, planes: bool = False):
+    """All-cluster coherencies [M, B, F, 2, 2] (no Jones applied), or
+    with ``planes`` the same numbers as real planes [8, M, F, B], the
+    form :func:`predict_model` takes (``rime/planes.py``).
 
     Equivalent of precalculate_coherencies[_multifreq] (predict.c:653/:890);
     with ``beam`` (a :class:`sagecal_tpu.rime.beam.BeamArrays`) and
@@ -218,15 +231,17 @@ def coherencies(sky: SkyArrays, u, v, w, freqs, fdelta,
             return _cluster_coherency(csky, u, v, w, freqs, fdelta,
                                       per_channel_flux, n0max,
                                       with_shapelets, af=af, E=E,
-                                      tslot=tslot, sta1=sta1, sta2=sta2)
+                                      tslot=tslot, sta1=sta1, sta2=sta2,
+                                      planes=planes)
     else:
         def per_cluster(csky):
             return _cluster_coherency(csky, u, v, w, freqs, fdelta,
                                       per_channel_flux, n0max,
-                                      with_shapelets)
+                                      with_shapelets, planes=planes)
 
     with jax.named_scope("rime/phasor"):    # the map's stacking too
-        return jax.lax.map(per_cluster, sky)
+        out = jax.lax.map(per_cluster, sky)
+        return jnp.moveaxis(out, 1, 0) if planes else out
 
 
 def coherencies_split(sky_pg, sky_rest, u, v, w, freqs, fdelta,
@@ -294,12 +309,12 @@ def model8(coh_m, J_m, sta1, sta2, chunk_idx_m, out_dtype=None):
     (sagecal_tpu.dtypes): the model EVALUATION is complex (c64 — J and
     the coherencies never quantize) and the emitted real stream casts
     to the storage dtype exactly where it joins the [B]-residual
-    traffic; a no-op for f32/f64. The solvers evaluate the same
-    bilinear form on real planes under the same contract
-    (normal_eq.row_model, normal_eq.residual8, the sweep of
-    solvers/sage.py) — this is the rime-layer entry point for embedders
-    that build their own residual streams, and the plain reference the
-    tests hold the planes against.
+    traffic; a no-op for f32/f64. The solvers and
+    :func:`predict_model` evaluate the same bilinear form on real planes
+    under the same contract (planes.row_model, normal_eq.residual8, the
+    sweep of solvers/sage.py) — this is the rime-layer entry point for
+    embedders that build their own residual streams, and the plain
+    reference the tests hold the planes against.
     """
     from sagecal_tpu import dtypes as dtp
     Jp = J_m[chunk_idx_m, sta1]
@@ -311,36 +326,39 @@ def model8(coh_m, J_m, sta1, sta2, chunk_idx_m, out_dtype=None):
 
 
 @jax.named_scope("rime/corrupt")
-def apply_jones(coh_m, J_m, sta1, sta2, chunk_idx_m):
-    """One cluster's corrupted model: J_p C J_q^H per baseline.
+def predict_model(c8, P, sta1, sta2, chunk_idx, cluster_mask=None,
+                  row_period: int = 0):
+    """Sum of corrupted cluster models, sum_m mask_m J_p,m C_m J_q,m^H,
+    on real planes: [8, F, B] (``rime/planes.py``'s order, the model of
+    the residual and of the simulated column).
 
-    coh_m: [B, F, 2, 2]; J_m: [Kmax, N, 2, 2]; chunk_idx_m: [B].
-    Returns [B, F, 2, 2].
-    """
-    Jp = J_m[chunk_idx_m, sta1]            # [B, 2, 2]
-    Jq = J_m[chunk_idx_m, sta2]
-    JqH = jnp.conj(jnp.swapaxes(Jq, -1, -2))
-    return jnp.einsum("bij,bfjk,bkl->bfil", Jp, coh_m, JqH)
-
-
-@jax.named_scope("rime/corrupt")
-def predict_model(coh, J, sta1, sta2, chunk_idx, cluster_mask=None):
-    """Sum of corrupted cluster models: sum_m J_p C_m J_q^H -> [B, F, 2, 2].
-
-    coh: [M, B, F, 2, 2]; J: [M, Kmax, N, 2, 2]; chunk_idx: [M, B];
+    c8: the coherency planes [8, M, F, B] (:func:`coherencies` with
+    ``planes``); P: the stations' Jones planes [M, Kmax, N, 8]
+    (``planes.jones_c2r`` of [M, Kmax, N, 2, 2]); chunk_idx: [M, B];
     cluster_mask: [M] bool (e.g. subtract mask / ignore list).
-    """
-    def body(carry, xs):
-        coh_m, J_m, cidx_m, keep = xs
-        vis = apply_jones(coh_m, J_m, sta1, sta2, cidx_m)
-        return carry + jnp.where(keep, 1.0, 0.0) * vis, None
 
-    M = coh.shape[0]
-    if cluster_mask is None:
-        cluster_mask = jnp.ones((M,), bool)
-    init = jnp.zeros(coh.shape[1:], coh.dtype)
-    out, _ = jax.lax.scan(body, init, (coh, J, chunk_idx, cluster_mask))
-    return out
+    Real multiply-adds with the rows on the minor axes, the clusters and
+    the channels leading axes that the Jones broadcast over, reduced over
+    the clusters (what ``solvers/sage._joint_model`` is to the refine).
+    The layout follows what the input shows: rows ``[tilesz,
+    row_period]`` with one chunk a cluster (``planes.periodic_rows``;
+    ``row_period`` is the tile's ``nbase``, 0 where the caller knows of
+    none) have the Jones gathered for ``row_period`` rows and broadcast
+    over time; any other rows, hybrid chunks among them, are gathered
+    row by row.
+    """
+    M, kmax, N = P.shape[:3]
+    F, B = c8.shape[2:]
+    if cluster_mask is not None:
+        P = jnp.where(jnp.asarray(cluster_mask)[:, None, None, None], P, 0.0)
+    R = row_period if pl.periodic_rows(kmax, row_period, B) else B
+    rows = (B // R, R) if R < B else (B,)
+    slot = (chunk_idx[:, :R] + kmax * jnp.arange(M)[:, None]) * N
+    # [8, M, 1 (channels), (1 (time),) R] against c8 [8, M, F, *rows]
+    jp, jq = (pl.take(P, slot + sta[:R]).reshape(
+        (8, M) + (1,) * len(rows) + (R,)) for sta in (sta1, sta2))
+    v = pl.mm(jp, pl.mm(c8.reshape((8, M, F) + rows), jq, adj_b=True))
+    return jnp.sum(v, axis=1).reshape(8, F, B)
 
 
 def predict_visibilities(sky: SkyArrays, u, v, w, freqs, fdelta,

@@ -7,13 +7,14 @@ per 8-parameter station blocks (mderiv.cu:30 ``kernel_deriv``; CPU
 into block-sparse normal equations, no per-parameter loops. The row
 MODEL, V and its two Wirtinger factors, is sixteen complex
 multiply-adds a row and is written out as real elementwise arithmetic
-on planes with the rows on the minor axis (:func:`row_model`,
-:class:`RowPlanes`): a 2 x 2 product fed to a 128 x 128 systolic array
-at f32 ``highest`` costs a hundred times its arithmetic. So is the
-Gauss-Newton matrix of rows with a period (:func:`plane_equations`:
-a baseline's Gram blocks are 4 x 4, as small); the generic assembly
-of :func:`normal_equations`, :func:`gn_factors` and the constrained
-modes' are batched einsums + scatter-adds over ``[B, 2, 2, 4]`` factors.
+on planes with the rows on the minor axis (``rime/planes.py``:
+:func:`row_model`; here :class:`RowPlanes`): a 2 x 2 product fed to a
+128 x 128 systolic array at f32 ``highest`` costs a hundred times its
+arithmetic. So is the Gauss-Newton matrix of rows with a period
+(:func:`plane_equations`: a baseline's Gram blocks are 4 x 4, as
+small); the generic assembly of :func:`normal_equations`,
+:func:`gn_factors` and the constrained modes' are batched einsums +
+scatter-adds over ``[B, 2, 2, 4]`` factors.
 
 Derivatives (Wirtinger):
   with A = C_b J_q^H:  dV/d(J_p)_{cd}       = e_c e_d^T A   (complex-linear)
@@ -35,85 +36,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from sagecal_tpu import dtypes as dtp
+from sagecal_tpu.rime import planes as pl
+# the plane algebra has ONE home, shared with the programs that leave
+# the solver (rime/predict.predict_model); these are its names here
+from sagecal_tpu.rime.planes import (  # noqa: F401
+    jones_c2r, jones_r2c, periodic_rows, row_grad, row_model, row_tangent)
 
 _EYE2 = jnp.eye(2)
-
-
-def jones_c2r(J):
-    """[..., 2, 2] complex -> [..., 8] real (Re,Im interleaved, row-major)."""
-    flat = J.reshape(J.shape[:-2] + (4,))
-    return jnp.stack([flat.real, flat.imag], axis=-1).reshape(
-        J.shape[:-2] + (8,))
-
-
-def jones_r2c(p):
-    """[..., 8] real -> [..., 2, 2] complex."""
-    pr = p.reshape(p.shape[:-1] + (4, 2))
-    return (pr[..., 0] + 1j * pr[..., 1]).reshape(p.shape[:-1] + (2, 2))
-
-
-def _planes_mm(a, b, adj_a: bool = False, adj_b: bool = False):
-    """The eight real planes of the 2 x 2 complex product op(a) op(b),
-    op = identity or conjugate transpose, by written-out multiply-adds.
-
-    ``a``, ``b``: eight real planes each ((Re, Im) of 00, 01, 10, 11,
-    the :func:`jones_c2r` order) that broadcast against each other."""
-    def entry(m, i, j, adj):
-        k = 2 * (2 * j + i if adj else 2 * i + j)
-        return m[k], (-m[k + 1] if adj else m[k + 1])
-
-    out = []
-    for i in range(2):
-        for j in range(2):
-            (ar, ai), (br, bi) = entry(a, i, 0, adj_a), entry(b, 0, j, adj_b)
-            (cr, ci), (dr, di) = entry(a, i, 1, adj_a), entry(b, 1, j, adj_b)
-            out += [ar * br - ai * bi + cr * dr - ci * di,
-                    ar * bi + ai * br + cr * di + ci * dr]
-    return jnp.stack(out)
-
-
-def row_model(jp8, jq8, c8):
-    """The row model on real planes: (V, A, Bm), eight planes each, of
-    V = J_p C J_q^H and the Wirtinger factors A = C J_q^H, Bm = J_p C it
-    computes on the way.
-
-    ``jp8``, ``jq8``, ``c8``: the gathered Jones of both stations and the
-    coherency as ``[8, *rows]`` real planes (:func:`jones_c2r` order, the
-    ROWS ON THE MINOR AXES; they may broadcast against each other).
-    Real elementwise arithmetic only: nothing here is a contraction, so
-    nothing reaches the matrix unit and the planes tile without the 64x
-    padding of a ``[B, 2, 2]`` array."""
-    a8 = _planes_mm(c8, jq8, adj_b=True)
-    return _planes_mm(jp8, a8), a8, _planes_mm(jp8, c8)
-
-
-def row_grad(g8, a8, bm8):
-    """(G A^H, G^H Bm) on planes: with G the complex form of a row's
-    cost derivative dc/dV, the row's share of dc/dJ_p and of dc/dJ_q
-    (dV = dJ_p A + Bm dJ_q^H, Re tr(G^H dV) = Re tr((G A^H)^H dJ_p)
-    + Re tr((G^H Bm)^H dJ_q))."""
-    return _planes_mm(g8, a8, adj_b=True), _planes_mm(g8, bm8, adj_a=True)
-
-
-def row_tangent(dp8, dq8, a8, bm8):
-    """dV = dJ_p A + Bm dJ_q^H on planes: the row model's derivative
-    along a change (dJ_p, dJ_q) of its two stations' Jones, from the
-    Wirtinger factors :func:`row_model` returned. Exact: V is bilinear
-    in (J_p, conj J_q)."""
-    return _planes_mm(dp8, a8) + _planes_mm(bm8, dq8, adj_b=True)
-
-
-def periodic_rows(kmax: int, row_period: int, B: int) -> bool:
-    """Whether ``B`` rows of clusters with ``kmax`` chunks each lie
-    ``[tilesz, row_period]`` with the stations repeating every
-    ``row_period`` rows (what :class:`RowPlanes` decides its layout
-    by)."""
-    return kmax == 1 and row_period > 0 and B % row_period == 0
-
-
-def _take_planes(P, idx):
-    """Station planes P [K, N, 8], flat station indices -> [8, *idx.shape]."""
-    return jnp.take(P.reshape(-1, 8).T, idx, axis=1)
 
 
 class RowPlanes:
@@ -194,7 +123,7 @@ class RowPlanes:
 
     def gather(self, P):
         """Station planes P [K, N, 8] -> (jp8, jq8) for :func:`row_model`."""
-        jp, jq = _take_planes(P, self.i1), _take_planes(P, self.i2)
+        jp, jq = pl.take(P, self.i1), pl.take(P, self.i2)
         return ((jp[..., None, :], jq[..., None, :]) if self.periodic
                 else (jp, jq))
 
@@ -233,8 +162,8 @@ def residual8(x8, J, coh, sta1, sta2, chunk_id):
     x8: [B, 8]; J: [K, N, 2, 2] complex; coh: [B, 2, 2]; chunk_id: [B].
     """
     P, N = jones_c2r(J), J.shape[-3]
-    v8, _, _ = row_model(_take_planes(P, chunk_id * N + sta1),
-                         _take_planes(P, chunk_id * N + sta2),
+    v8, _, _ = row_model(pl.take(P, chunk_id * N + sta1),
+                         pl.take(P, chunk_id * N + sta2),
                          jones_c2r(coh).T)
     # dtype-policy storage/accumulate contract: the model EMITS the
     # data's storage dtype (a no-op for f32/f64 data), so the residual

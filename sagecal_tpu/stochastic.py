@@ -476,7 +476,7 @@ class _StochasticRunner:
 
         def resid(x8F, u, v, w, sta1, sta2, freqsF, tslot, J_r8, beam):
             res = rr.calculate_residuals_multifreq(
-                self.dsky, ne.jones_r2c(J_r8), _x8f_to_complex(x8F),
+                self.dsky, J_r8, _x8f_to_complex(x8F),
                 u, v, w, freqsF, self.fdelta_chan, sta1, sta2, cidx, sub,
                 correct_idx=correct_idx, rho=self.cfg.mmse_rho,
                 beam=beam, dobeam=self.dobeam,

@@ -170,10 +170,9 @@ def test_refine_program_searches_on_the_line(one_chip):
     assert 0 < text.count(" fusion(") < 967 // 2
 
 
-def _lower_residual_program(one_chip, rows):
+def _lower_residual_program(one_chip, rows, correct_idx=None):
     from problems import make_sky
     from sagecal_tpu.rime import predict as rp, residual as rr
-    from sagecal_tpu.solvers import normal_eq as ne
     sky = make_sky(M, srcs_per_cluster=3)
     dsky = rp.sky_to_device(sky, jnp.float32)
     sd = _spec(one_chip)
@@ -181,9 +180,10 @@ def _lower_residual_program(one_chip, rows):
 
     def residuals(J_r8, x_r, u, v, w, sta1, sta2, cidx):
         return rr.calculate_residuals_pairs(
-            dsky, ne.jones_r2c(J_r8), x_r, u, v, w,
+            dsky, J_r8, x_r, u, v, w,
             jnp.asarray([150e6], f32), 0.18e6, sta1, sta2, cidx,
-            jnp.ones((M,), bool), out_dtype=f32)
+            jnp.ones((M,), bool), out_dtype=f32, row_period=NB,
+            correct_idx=correct_idx)
 
     return jax.jit(residuals, donate_argnums=(1,)).lower(
         sd((M, 1, N, 8), f32), sd((rows, 1, 2, 2, 2), f32),
@@ -191,13 +191,32 @@ def _lower_residual_program(one_chip, rows):
         sd((rows,), i32), sd((rows,), i32), sd((M, rows), i32))
 
 
-def test_residual_program_compiles(one_chip):
+@pytest.mark.parametrize("correct_idx", [None, 0], ids=["plain", "-k"])
+def test_residual_program_compiles(one_chip, correct_idx):
     """The per-tile residual program (pipeline._residuals /
     cli_mpi residual_fn: real pairs in and out, donated input). Its
     complex-subtract-then-restack form aborted the TPU compiler on the
     v5e (rime/residual.calculate_residuals_pairs says why); a CHECK
-    failure there kills this worker, which is the test failing."""
-    _lower_residual_program(one_chip, B).compile()
+    failure there kills this worker, which is the test failing.  Under
+    ``-k`` the residual goes through complex and back around the
+    correction, which is the same sandwich on planes (PR 43)."""
+    _holds_the_three_scopes(_lower_residual_program(
+        one_chip, B, correct_idx).compile().as_text())
+
+
+def _holds_the_three_scopes(text):
+    """The compiled text names the three scopes the benchmark's device
+    metrics read (``phasor_dev_ms*``, ``corrupt_dev_ms*``,
+    ``subtract_dev_ms``), and the Jones sandwich under ``rime/corrupt``
+    is elementwise arithmetic on planes: nothing of it is lowered for
+    the matrix unit (PR 43; its ``[B, F, 2, 2]`` complex ``einsum`` was a
+    ``convolution`` of 2 x 2 operands a row)."""
+    for scope in ("rime/phasor", "rime/corrupt", "rime/residual"):
+        assert scope in text, scope
+    contractions = [ln.strip()[:160] for ln in text.splitlines()
+                    if "rime/corrupt" in ln
+                    and (" convolution(" in ln or " dot(" in ln)]
+    assert not contractions, contractions
 
 
 def _lower_simulate_program(one_chip, tilesz, mode):
@@ -209,7 +228,6 @@ def _lower_simulate_program(one_chip, tilesz, mode):
     from problems import make_sky
     from sagecal_tpu.io import dataset as ds
     from sagecal_tpu.rime import predict as rp, residual as rr
-    from sagecal_tpu.solvers import normal_eq as ne
     sky = make_sky(M, srcs_per_cluster=128)
     dsky = rp.sky_to_device(sky, jnp.float32)
     rows = NB * tilesz
@@ -222,9 +240,9 @@ def _lower_simulate_program(one_chip, tilesz, mode):
     def sim_fn(x_r, u, v, w, sta1, sta2, J_r8, beam):
         return rr.simulate_pairs(
             dsky, x_r, u, v, w, jnp.asarray([150e6], f32), 0.18e6, sta1,
-            sta2, mode=mode, J=ne.jones_r2c(J_r8),
+            sta2, mode=mode, J=J_r8,
             chunk_idx=jnp.asarray(cidx), ignore_mask=ignore_mask,
-            beam=beam, dobeam=0, tslot=jnp.asarray(tslot))
+            beam=beam, dobeam=0, tslot=jnp.asarray(tslot), row_period=NB)
 
     with jax.default_matmul_precision("highest"):
         return jax.jit(sim_fn).lower(
@@ -242,8 +260,8 @@ def test_simulate_program_compiles(one_chip, mode):
     (``Check failed: fusion_util::IsFusibleUnalignedDUS``, exit 134;
     mode 1 never reads ``x``); a CHECK failure there kills this worker,
     which is the test failing."""
-    text = _lower_simulate_program(one_chip, TILESZ, mode).compile().as_text()
-    assert "rime/residual" in text      # the scope subtract_dev_ms reads
+    _holds_the_three_scopes(
+        _lower_simulate_program(one_chip, TILESZ, mode).compile().as_text())
 
 
 # -- the production solve interval: -t 120, 226 920 rows a tile --------------
@@ -254,12 +272,16 @@ B_120 = NB * TILESZ_120
 CHIP_BYTES = int(15.75 * 2 ** 30)
 #: one ``f32[8, 226920, 2, 2]`` temporary tiled ``T(2,128)``: 27 MB of data
 PADDED_TEMP_BYTES = int(1.73 * 2 ** 30)
+#: one ``c64[226920, 1, 2, 2]`` temporary tiled ``T(2,128)``: 7 MB of data
+PADDED_MODEL_BYTES = B_120 * 2 * 128 * 8
 #: a ceiling of its own where a program has been given room to lose:
-#: what it compiled to (refine: PR 36; the two that hold a sweep: PR 41)
-#: plus ONE such temporary
+#: what it compiled to (refine: PR 36; the two that hold a sweep: PR 41;
+#: the two that leave the solver: PR 43) plus ONE such temporary
 CEILING = {"refine": int(0.42 * 2 ** 30) + PADDED_TEMP_BYTES,
            "sagefit": int(0.49 * 2 ** 30) + PADDED_TEMP_BYTES,
-           "cluster_update": int(0.10 * 2 ** 30) + PADDED_TEMP_BYTES}
+           "cluster_update": int(0.10 * 2 ** 30) + PADDED_TEMP_BYTES,
+           "residual": int(0.09 * 2 ** 30) + PADDED_MODEL_BYTES,
+           "simulate": int(0.09 * 2 ** 30) + PADDED_MODEL_BYTES}
 
 
 @functools.cache
@@ -323,19 +345,19 @@ def test_production_tile_fits(one_chip, program):
     program, and the simulation modes' (``-a 3 -p -z`` over 8 x 128
     sources, PR 37: no cell runs it at this size, because the reference
     takes 53 s to make one such tile's sky).  Argument + output + temp
-    as compiled here at PR 41 (PR 39's, PR 36's and PR 34's beside
-    them, with the temporaries the chip's own compile asked for then:
-    PERF.md section 5):
+    as compiled here at PR 43 (PR 41's, PR 39's, PR 36's and PR 34's
+    beside them, with the temporaries the chip's own compile asked for
+    then: PERF.md section 5):
 
-    ==============  ===========  ========  =========  =========  ==============
-    program         -t 120 here  at PR 39  at PR 36   at PR 34   the chip, PR 34
-    ==============  ===========  ========  =========  =========  ==============
-    sagefit          0.49 GiB    4.95 GiB   5.64 GiB  13.56 GiB  13.48 GiB
-    refine           0.42 GiB    0.42 GiB   0.42 GiB  13.55 GiB  13.47 GiB
-    cluster_update   0.09 GiB    5.50 GiB   7.02 GiB   7.02 GiB  not read
-    residual         2.30 GiB    2.30 GiB   2.30 GiB   2.30 GiB  2.27 GiB
-    simulate         2.29 GiB    2.29 GiB   2.29 GiB   aborts    not run
-    ==============  ===========  ========  =========  =========  ==============
+    ==============  ===========  ========  ========  =========  =========  ==============
+    program         -t 120 here  at PR 41  at PR 39  at PR 36   at PR 34   the chip, PR 34
+    ==============  ===========  ========  ========  =========  =========  ==============
+    sagefit          0.49 GiB    0.49 GiB  4.95 GiB   5.64 GiB  13.56 GiB  13.48 GiB
+    refine           0.42 GiB    0.42 GiB  0.42 GiB   0.42 GiB  13.55 GiB  13.47 GiB
+    cluster_update   0.09 GiB    0.09 GiB  5.50 GiB   7.02 GiB   7.02 GiB  not read
+    residual         0.09 GiB    2.30 GiB  2.30 GiB   2.30 GiB   2.30 GiB  2.27 GiB
+    simulate         0.08 GiB    2.29 GiB  2.29 GiB   2.29 GiB   aborts    not run
+    ==============  ===========  ========  ========  =========  =========  ==============
 
     Arguments are 0.076 GiB.  Until PR 36 nearly all of the solve was
     ``f32[8, 226920, 2, 2]`` temporaries tiled ``T(2,128)``, 1.73 GiB
@@ -347,9 +369,14 @@ def test_production_tile_fits(one_chip, program):
     three solve programs has a ceiling of what it compiled to plus one
     of them, so the old construction coming back into the refine, into
     the assembly or into the sweep's ``update`` is what this case
-    notices.  The solve's and the residual's TOGETHER are 2.74 GiB:
-    they fit side by side, though the residual is only dispatched once
-    the solve's result is fetched."""
+    notices.  The residual and the simulate programs' 2.3 GiB were the
+    ``[B, F, 2, 2]`` complex operands of their Jones sandwich, tiled
+    ``T(2,128)``; since PR 43 the sandwich runs on ``[8, 8, 1, 120,
+    1891]`` planes (0.063 GiB of temporaries) and the pairs ``[B, 1, 2,
+    2, 2]`` get a device layout with the rows minor, 7 MB: their ceiling
+    is what they compiled to plus ONE complex ``[B, 1, 2, 2]`` temporary
+    (0.43 GiB), a fifth of what coming back it would notice.  The
+    solve's and the residual's TOGETHER are 0.58 GiB."""
     need = _need_120(one_chip, program)
     assert 0 < need < CEILING.get(program, CHIP_BYTES), need / 2 ** 30
 

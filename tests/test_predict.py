@@ -127,24 +127,96 @@ def test_shapelet_envelope_n0_1():
     np.testing.assert_allclose(got.imag, 0.0, atol=1e-9)
 
 
-def test_apply_jones_and_predict_model():
-    rng = np.random.default_rng(5)
-    N, B, F, M, K = 4, 6, 2, 2, 1
-    coh = rng.normal(size=(M, B, F, 2, 2)) + 1j * rng.normal(size=(M, B, F, 2, 2))
+def _sandwich_reference(coh, J, sta1, sta2, cidx, mask):
+    """The plain reference of ``predict_model``: per cluster the
+    ``[B, F, 2, 2]`` complex ``einsum`` J_p C J_q^H, summed over the
+    clusters the mask keeps (the form the program itself had before its
+    planes)."""
+    out = np.zeros(coh.shape[1:], complex)
+    for m in np.flatnonzero(mask):
+        Jp, Jq = J[m][cidx[m], sta1], J[m][cidx[m], sta2]
+        out += np.einsum("bij,bfjk,blk->bfil", Jp, coh[m], Jq.conj())
+    return out
+
+
+def _sandwich_problem(layout, F, seed=5):
+    """(coh [M,B,F,2,2], J [M,K,N,2,2], sta1, sta2, cidx [M,B], nbase):
+    ``periodic`` rows [tilesz, nbase] with one chunk, ``ragged`` the same
+    less its last two rows (B no multiple of the period), ``hybrid``
+    three chunks in one of the clusters."""
+    rng = np.random.default_rng(seed)
+    N, T, M = 5, 6, 3
+    p, q = np.triu_indices(N, 1)
+    nbase = len(p)
+    B = T * nbase - (2 if layout == "ragged" else 0)
+    sta1, sta2 = np.tile(p, T)[:B], np.tile(q, T)[:B]
+    nchunk = np.array([1, 3, 1] if layout == "hybrid" else [1, 1, 1])
+    K = int(nchunk.max())
+    cidx = rp.chunk_indices(T, nbase, nchunk)[:, :B]
+    coh = rng.normal(size=(M, B, F, 2, 2)) \
+        + 1j * rng.normal(size=(M, B, F, 2, 2))
     J = rng.normal(size=(M, K, N, 2, 2)) + 1j * rng.normal(size=(M, K, N, 2, 2))
-    sta1 = np.array([0, 0, 0, 1, 1, 2], np.int32)
-    sta2 = np.array([1, 2, 3, 2, 3, 3], np.int32)
-    cidx = np.zeros((M, B), np.int32)
-    got = np.asarray(rp.predict_model(
-        jnp.asarray(coh), jnp.asarray(J), jnp.asarray(sta1),
-        jnp.asarray(sta2), jnp.asarray(cidx)))
-    expect = np.zeros((B, F, 2, 2), complex)
-    for m in range(M):
-        for b in range(B):
-            for f in range(F):
-                expect[b, f] += (J[m, 0, sta1[b]] @ coh[m, b, f]
-                                 @ J[m, 0, sta2[b]].conj().T)
-    np.testing.assert_allclose(got, expect, rtol=1e-10)
+    return coh, J, sta1.astype(np.int32), sta2.astype(np.int32), cidx, nbase
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["all", "one-out"])
+@pytest.mark.parametrize("F", [1, 4])
+@pytest.mark.parametrize("layout", ["periodic", "ragged", "hybrid"])
+def test_predict_model_matches_complex_sandwich(layout, F, masked):
+    """``predict_model``'s planes against the complex ``einsum`` form
+    and, for one channel, against ``model8``, to f32 rounding: the same
+    sixteen multiply-adds a row, in another order. With the tile's
+    period given or not (0) the layout differs and the numbers do not."""
+    from sagecal_tpu.rime import planes as pl
+    coh, J, sta1, sta2, cidx, nbase = _sandwich_problem(layout, F)
+    coh, J = coh.astype(np.complex64), J.astype(np.complex64)
+    mask = np.array([True, not masked, True])
+    expect = _sandwich_reference(coh.astype(complex), J.astype(complex),
+                                 sta1, sta2, cidx, mask)
+    c8 = jnp.transpose(pl.jones_c2r(jnp.asarray(coh)), (3, 0, 2, 1))
+    P = pl.jones_c2r(jnp.asarray(J))
+    assert c8.dtype == P.dtype == jnp.float32
+    scale = np.abs(expect).max()
+    got = {}
+    for period in (nbase, 0):
+        v8 = rp.predict_model(c8, P, jnp.asarray(sta1), jnp.asarray(sta2),
+                              jnp.asarray(cidx),
+                              cluster_mask=jnp.asarray(mask) if masked
+                              else None, row_period=period)
+        assert v8.shape == (8, F, len(sta1)) and v8.dtype == jnp.float32
+        got[period] = np.asarray(pl.jones_r2c(jnp.transpose(v8, (2, 1, 0))))
+        assert np.abs(got[period] - expect).max() < 5e-7 * scale
+    np.testing.assert_array_equal(got[nbase], got[0])
+    if F == 1:
+        m8 = sum(np.asarray(rp.model8(
+            jnp.asarray(coh[m, :, 0]), jnp.asarray(J[m]), jnp.asarray(sta1),
+            jnp.asarray(sta2), jnp.asarray(cidx[m])))
+            for m in np.flatnonzero(mask))
+        assert np.abs(np.asarray(pl.jones_c2r(jnp.asarray(got[0][:, 0])))
+                      - m8).max() < 5e-7 * scale
+
+
+def test_coherency_planes_are_the_coherencies():
+    """``coherencies(planes=True)`` hands on the source sum's four
+    correlations as eight real planes [8, M, F, B]: the numbers of the
+    ``[M, B, F, 2, 2]`` complex form, bit for bit."""
+    from sagecal_tpu.rime import planes as pl
+    srcs = {"A": point_source("A", 0.01, 0.005, sI=2.0, sQ=0.3, sU=-0.2,
+                              sV=0.1),
+            "B": point_source("B", -0.02, 0.01, sI=1.0)}
+    sky = make_sky(srcs, [(0, 1, ["A"]), (1, 1, ["B"])])
+    dsky = rp.sky_to_device(sky, jnp.float32)
+    rng = np.random.default_rng(2)
+    u, v, w = (jnp.asarray(rng.normal(size=7) * 1e-6, jnp.float32)
+               for _ in range(3))
+    args = (dsky, u, v, w, jnp.asarray([149e6, 150e6, 151e6], jnp.float32),
+            1e5)
+    coh = rp.coherencies(*args, per_channel_flux=True)
+    c8 = rp.coherencies(*args, per_channel_flux=True, planes=True)
+    assert c8.shape == (8, 2, 3, 7) and c8.dtype == jnp.float32
+    np.testing.assert_array_equal(
+        np.asarray(c8), np.asarray(jnp.transpose(pl.jones_c2r(coh),
+                                                 (3, 0, 2, 1))))
 
 
 def test_chunk_indices():

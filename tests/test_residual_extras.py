@@ -112,6 +112,127 @@ def test_phase_only_correction_runs(tmp_path):
     assert np.abs(full - ph).max() > 1e-6
 
 
+def _pairs_problem(tmp_path, kmax3=False):
+    """A tiny f32 tile as the jit boundaries see it: pairs ``x_r``,
+    solutions as planes ``J_r8``, and the pieces of the plain reference
+    (complex coherencies and Jones in double precision)."""
+    from sagecal_tpu.rime import planes as pl
+    _, sky, _, tile, Jtrue = _tiny_problem(tmp_path, [149e6, 151e6],
+                                           n_sta=6, tilesz=3)
+    dsky = rp.sky_to_device(sky, jnp.float32)
+    nchunk = np.array([1, 3]) if kmax3 else sky.nchunk
+    rng = np.random.default_rng(11)
+    J = (ds.random_jones(2, nchunk, 6, seed=4, scale=0.2) if kmax3
+         else Jtrue).astype(np.complex64)
+    cidx = rp.chunk_indices(tile.tilesz, tile.nbase, nchunk)
+    f32 = lambda a: jnp.asarray(a, jnp.float32)
+    geo = (f32(tile.u), f32(tile.v), f32(tile.w), f32(tile.freqs),
+           tile.fdelta / 2, jnp.asarray(tile.sta1), jnp.asarray(tile.sta2))
+    coh = np.asarray(rp.coherencies(dsky, *geo[:5], per_channel_flux=True),
+                     complex)
+    x = (tile.x + 0.1 * rng.normal(size=tile.x.shape)).astype(np.complex64)
+    x_r = jnp.stack([jnp.asarray(x.real), jnp.asarray(x.imag)], -1)
+    return dict(dsky=dsky, geo=geo, cidx=cidx, coh=coh, x=x.astype(complex),
+                x_r=x_r, J=J.astype(complex), J_r8=pl.jones_c2r(jnp.asarray(J)),
+                sta1=tile.sta1, sta2=tile.sta2, nbase=tile.nbase)
+
+
+def _reference_model(pb, mask):
+    """sum_m mask_m J_p C_m J_q^H as the complex ``einsum`` of
+    [B, F, 2, 2] operands, one cluster after another: the form the
+    programs had before their planes, kept here as the plain
+    reference."""
+    out = np.zeros(pb["coh"].shape[1:], complex)
+    for m in np.flatnonzero(mask):
+        Jp = pb["J"][m][pb["cidx"][m], pb["sta1"]]
+        Jq = pb["J"][m][pb["cidx"][m], pb["sta2"]]
+        out += np.einsum("bij,bfjk,blk->bfil", Jp, pb["coh"][m], Jq.conj())
+    return out
+
+
+def _reference_correction(pb, res, m, phase_only):
+    Jm = jnp.asarray(pb["J"][m])
+    if phase_only:
+        Jm = jax.vmap(mf.extract_phases)(Jm)
+    G = np.asarray(rr.mmse_inverse(Jm, 1e-9))
+    Gp = G[pb["cidx"][m], pb["sta1"]]
+    Gq = G[pb["cidx"][m], pb["sta2"]]
+    return np.einsum("bij,bfjk,blk->bfil", Gp, res, Gq.conj())
+
+
+def _pairs(c):
+    return np.stack([c.real, c.imag], -1)
+
+
+#: the residual program's cases: (hybrid chunks, -k cluster, -J, storage)
+_RESIDUAL_CASES = {
+    "plain": (False, None, False, None),
+    "hybrid": (True, None, False, None),
+    "correct": (False, 0, False, None),
+    "correct-hybrid": (True, 1, False, None),
+    "correct-phase-only": (False, 0, True, None),
+    "bf16": (False, None, False, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", list(_RESIDUAL_CASES))
+def test_residual_pairs_match_complex_reference(tmp_path, case):
+    """``calculate_residuals_pairs`` (pairs in, solutions as planes, the
+    tile's period given) against x - sum_m J_p C_m J_q^H by complex
+    ``einsum`` in double precision, to f32 rounding; under ``-k`` (with
+    and without ``-J``) the residual corrected the same way; under bf16
+    storage the same number rounded once to bf16."""
+    kmax3, k, phase_only, sdt = _RESIDUAL_CASES[case]
+    pb = _pairs_problem(tmp_path, kmax3)
+    mask = np.array([True, False])
+    x_r = pb["x_r"] if sdt is None else pb["x_r"].astype(sdt)
+    got = rr.calculate_residuals_pairs(
+        pb["dsky"], pb["J_r8"], x_r, *pb["geo"], jnp.asarray(pb["cidx"]),
+        jnp.asarray(mask), out_dtype=sdt, correct_idx=k,
+        phase_only=phase_only, row_period=pb["nbase"])
+    assert got.shape == x_r.shape and got.dtype == x_r.dtype
+    x = np.asarray(x_r.astype(jnp.float32), float)
+    expect = (x[..., 0] + 1j * x[..., 1]) - _reference_model(pb, mask)
+    if k is not None:
+        expect = _reference_correction(pb, expect, k, phase_only)
+    scale = np.abs(expect).max()
+    # f32: a few ulp of the largest term; bf16: half an ulp of its 8 bits
+    tol = 2e-6 if sdt is None else 2.0 ** -8
+    assert np.abs(np.asarray(got, float) - _pairs(expect)).max() \
+        < tol * scale
+    if k is None and sdt is None:
+        # the complex entry point is the same model, read as complex
+        cplx = rr.calculate_residuals_multifreq(
+            pb["dsky"], jnp.asarray(pb["J"], jnp.complex64),
+            jnp.asarray(pb["x"], jnp.complex64), *pb["geo"],
+            jnp.asarray(pb["cidx"]), jnp.asarray(mask))
+        np.testing.assert_array_equal(_pairs(np.asarray(cplx)),
+                                      np.asarray(got))
+
+
+@pytest.mark.parametrize("solutions", [True, False],
+                         ids=["with-p", "without-p"])
+@pytest.mark.parametrize("mode", [1, 2, 3])
+def test_simulate_pairs_match_complex_reference(tmp_path, mode, solutions):
+    """``simulate_pairs`` modes 1, 2, 3 (replace, add, subtract), with
+    one cluster on the ignore list, against the complex ``einsum``
+    reference; without solutions the model is the clusters' plain
+    sum."""
+    pb = _pairs_problem(tmp_path)
+    keep = np.array([False, True])
+    got = rr.simulate_pairs(
+        pb["dsky"], pb["x_r"], *pb["geo"], mode=mode,
+        J=pb["J_r8"] if solutions else None,
+        chunk_idx=jnp.asarray(pb["cidx"]), ignore_mask=keep,
+        row_period=pb["nbase"])
+    model = _reference_model(pb, keep) if solutions \
+        else pb["coh"][keep].sum(0)
+    expect = {1: model, 2: pb["x"] + model, 3: pb["x"] - model}[mode]
+    assert got.shape == pb["x_r"].shape and got.dtype == jnp.float32
+    assert np.abs(np.asarray(got, float) - _pairs(expect)).max() \
+        < 2e-6 * np.abs(expect).max()
+
+
 @pytest.mark.slow
 def test_per_channel_bandpass_mode(tmp_path):
     """-b 1 CLI end-to-end: per-channel solve converges and writes

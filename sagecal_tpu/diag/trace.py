@@ -60,7 +60,13 @@ event            meaning / required extra fields
                  "flat": sage.sweep_rows), ``assemble_rows`` (the
                  row layout the sweeps' Gauss-Newton matrix was
                  assembled from, "periodic" or "generic":
-                 sage.assemble_rows), ``plan`` and
+                 sage.assemble_rows), ``kmax``, ``chunk_slots`` and
+                 ``chunk_slots_live`` (the hybrid time chunks of the
+                 cluster file: the most a cluster has, the slots
+                 ``M * kmax`` that the Jones ``[M, kmax, N, 2, 2]``
+                 carries and every cluster's solve factorises, and
+                 the ``sum(nchunk)`` of them that are solutions:
+                 pipeline.py), ``plan`` and
                  ``solve_dispatches`` (what sagefit_host's last sweep
                  executed, "promoted", "fused" or "per_cluster", and
                  the device executions the solve issued), ``minutes``,

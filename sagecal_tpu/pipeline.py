@@ -60,13 +60,16 @@ RES_RATIO = 5.0  # fullbatch_mode.cpp:239
 
 
 def _emit_tile_record(ti, res_0, res_1, mean_nu, info, minutes,
-                      bubble_s=None, overlap=None):
+                      bubble_s=None, overlap=None, cmask=None):
     """Per-solve-interval convergence record (gated on an active tracer
     / metrics registry so the extra device->host syncs never run
     otherwise). ``bubble_s`` / ``overlap`` are the overlapped-execution
     accounting pair: host seconds blocked on data movement for this
     tile, and the prefetch depth it ran under (0 = synchronous
-    reference loop)."""
+    reference loop). ``cmask`` is the pipeline's ``[M, kmax]`` mask of
+    live hybrid chunks: the record says how many chunk slots every
+    cluster's Jones carries and how many of them the cluster file
+    asked for."""
     if not (dtrace.active() or obs.active()):
         return
     trips = lm_mod.executed_trips(info)
@@ -98,6 +101,12 @@ def _emit_tile_record(ti, res_0, res_1, mean_nu, info, minutes,
         for k in ("plan", "refine_rows", "sweep_rows", "assemble_rows"):
             if k in info:
                 rec[k] = info[k]
+    if cmask is not None:
+        # J is [M, kmax, N, 2, 2]: every cluster carries the chunk
+        # slots of the one with the most
+        rec["kmax"] = int(cmask.shape[1])
+        rec["chunk_slots"] = int(cmask.size)
+        rec["chunk_slots_live"] = int(cmask.sum())
     dtrace.emit("tile", **rec)
 
 
@@ -865,7 +874,8 @@ class FullBatchPipeline:
             history.append({"tile": ti, "res_0": res_0, "res_1": res_1,
                             "mean_nu": mean_nu, "minutes": minutes})
             _emit_tile_record(ti, res_0, res_1, mean_nu, None, minutes,
-                              bubble_s=stg["bubble"], overlap=depth)
+                              bubble_s=stg["bubble"], overlap=depth,
+                              cmask=self.cmask)
 
         def solve_solo(stg, boosted):
             t0 = time.time()
@@ -1582,7 +1592,8 @@ class TileStepper:
                 rec["degraded"] = True
             self.history.append(rec)
             _emit_tile_record(ti, res_0, res_1, mean_nu, info, dt,
-                              bubble_s=bubble, overlap=self.depth)
+                              bubble_s=bubble, overlap=self.depth,
+                              cmask=p.cmask)
         return rec
 
     def _observe_stream_latency(self, ti, t_arr):

@@ -20,7 +20,10 @@ import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SUITE = "benchmarks/tests"
-LIMIT_S = 1100      # 140 s alone (PR 30), 520-640 s beside 5 workers (PR 40)
+# 140 s alone (PR 30), 520-640 s beside 5 workers (PR 40); 1001 s beside 5
+# workers with PR 44's test_hybrid.py at six tiny runs, which was cut to
+# three for it (the whole suite has 1470 s)
+LIMIT_S = 1250
 PYTEST = [sys.executable, "-m", "pytest", SUITE, "-q",
           "-p", "no:cacheprovider", "-p", "no:randomly"]
 
@@ -87,6 +90,32 @@ OVERTAKEN = {
     "the_new_entries[test_subtract]":
         "test_subtract.py holds the LAST configuration, cell and entries "
         "of the manifest, which are PR 42's now",
+    # PR 44 appended a cell, a configuration and eight entries that list
+    # the new cell alone.  test_fold.py holds PR 42's as the LAST of
+    # their lists and ``workloads`` as six cells (three cases), and runs
+    # test_subtract.py's case on the manifest less PR 40's and PR 42's
+    # entries only (the fourth).  benchmarks/tests/test_hybrid.py runs
+    # each of the first three whole on the manifest less this PR's cell,
+    # configuration and entries
+    # (test_what_pr42_pins_by_place_holds_less_this_prs_entries), runs
+    # test_subtract.py's, test_t120.py's and test_consensus.py's case
+    # less everything appended since they were written
+    # (test_what_older_cells_pin_by_place_holds_less_everything_since),
+    # and holds every older cell's list, PR 40's and PR 42's entries and
+    # the order of cells and configurations by name
+    # (test_the_older_cells_lists_are_as_pr42_held_them).
+    **{f"test_fold.py::{case}":
+       "PR 42's cell, configuration and nine entries are no longer the "
+       "LAST of their lists, nor the cells six: PR 44's go at the end"
+       for case in (
+           "test_the_cell_is_files_and_entries",
+           "test_the_configuration_is_the_sources_at_eight_subbands",
+           "test_pr40s_entries_still_list_the_older_cells_and_only_ours_"
+           "follow")},
+    "test_fold.py::test_what_pins_lists_by_place_holds_less_what_was_"
+    "appended_since[test_subtract]":
+        "test_subtract.py holds the LAST configuration, cell and entries "
+        "of the manifest, which are PR 44's now",
 }
 
 

@@ -170,25 +170,30 @@ def test_refine_program_searches_on_the_line(one_chip):
     assert 0 < text.count(" fusion(") < 967 // 2
 
 
-def _lower_residual_program(one_chip, rows, correct_idx=None):
+def _lower_residual_program(one_chip, rows, correct_idx=None, m=M, kmax=1,
+                            kept=()):
+    """The per-tile residual program over ``m`` clusters of ``kmax`` chunk
+    slots, the clusters ``kept`` solved and not subtracted."""
     from problems import make_sky
     from sagecal_tpu.rime import predict as rp, residual as rr
-    sky = make_sky(M, srcs_per_cluster=3)
+    sky = make_sky(m, srcs_per_cluster=3)
     dsky = rp.sky_to_device(sky, jnp.float32)
     sd = _spec(one_chip)
     f32, i32 = jnp.float32, jnp.int32
+    subtract = np.ones((m,), bool)
+    subtract[list(kept)] = False
 
     def residuals(J_r8, x_r, u, v, w, sta1, sta2, cidx):
         return rr.calculate_residuals_pairs(
             dsky, J_r8, x_r, u, v, w,
             jnp.asarray([150e6], f32), 0.18e6, sta1, sta2, cidx,
-            jnp.ones((M,), bool), out_dtype=f32, row_period=NB,
+            jnp.asarray(subtract), out_dtype=f32, row_period=NB,
             correct_idx=correct_idx)
 
     return jax.jit(residuals, donate_argnums=(1,)).lower(
-        sd((M, 1, N, 8), f32), sd((rows, 1, 2, 2, 2), f32),
+        sd((m, kmax, N, 8), f32), sd((rows, 1, 2, 2, 2), f32),
         sd((rows,), f32), sd((rows,), f32), sd((rows,), f32),
-        sd((rows,), i32), sd((rows,), i32), sd((M, rows), i32))
+        sd((rows,), i32), sd((rows,), i32), sd((m, rows), i32))
 
 
 @pytest.mark.parametrize("correct_idx", [None, 0], ids=["plain", "-k"])
@@ -284,6 +289,47 @@ CEILING = {"refine": int(0.42 * 2 ** 30) + PADDED_TEMP_BYTES,
            "simulate": int(0.09 * 2 ** 30) + PADDED_MODEL_BYTES}
 
 
+def _lower_solve_program(one_chip, name, tilesz, m=M, kmax=1):
+    """``sagefit``, ``refine`` or ``cluster_update`` lowered for the
+    described chip at ``tilesz * NB`` rows, ``m`` clusters of ``kmax``
+    chunk slots, with the solver cells' flags (``-e 4 -g 2 -l 10 -m 7
+    -j 5``, N 62).  The sweep's running residual is handed to the
+    per-cluster update in the layout ``sage.sweep_rows`` says."""
+    from sagecal_tpu.config import SolverMode
+    from sagecal_tpu.solvers import lm as lm_mod, sage
+    sd = _spec(one_chip)
+    f32, i32, c64 = jnp.float32, jnp.int32, jnp.complex64
+    rows = tilesz * NB
+    cfg = sage.SageConfig(nbase=NB)._replace(
+        max_emiter=4, max_iter=2, max_lbfgs=10, lbfgs_m=7,
+        solver_mode=int(SolverMode.RTR_OSRLM_RLBFGS))
+    os_ids, os_nsub = lm_mod.os_subset_ids(tilesz, NB)
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    flag = sd((), jnp.bool_)
+    # x8, coh, sta1, sta2, chunk idx: what every solve program is handed
+    data = (sd((rows, 8), f32), sd((m, rows, 2, 2), c64),
+            sd((rows,), i32), sd((rows,), i32), sd((m, rows), i32))
+    J, wt = sd((m, kmax, N, 2, 2), c64), sd((rows, 8), f32)
+    cmask = sd((m, kmax), jnp.bool_)
+    if name == "sagefit":
+        return sage._jit_sagefit.lower(
+            *data, cmask, J, N, wt, sd((), f32), cfg,
+            sd(np.shape(os_ids), i32), os_nsub, sd(key.shape, key.dtype))
+    if name == "refine":
+        return sage._jit_refine.lower(
+            *data, J, wt, sd((), f32), N, cfg._replace(max_emiter=0), True)
+    assert name == "cluster_update", name
+    cfg0 = cfg._replace(max_emiter=0)
+    xres = (sd((8, tilesz, NB), f32)
+            if sage.sweep_rows(cfg0, kmax, rows) == "periodic"
+            else sd((8, rows), f32))
+    return sage._jit_cluster_update.lower(
+        sd((), i32), J, xres, sd((m,), f32), sd((m,), f32),
+        *data[1:], cmask, wt, sd((m,), f32), flag, flag,
+        sd(key.shape, key.dtype), None, sd(np.shape(os_ids), i32), N,
+        cfg0, m * cfg0.max_iter, 2, os_nsub)
+
+
 @functools.cache
 def _need_120(one_chip, name):
     """Bytes (argument + output + temp) the program ``name`` asks of a
@@ -291,44 +337,13 @@ def _need_120(one_chip, name):
     2 -l 10 -m 7 -j 5``, N 62, M 8) and f32 contractions in f32 as
     ``utils.setup_backend`` gives every entry point (at the one-pass
     default the solve asks 0.44 GiB less); compiled once a session."""
-    from sagecal_tpu.config import SolverMode
-    from sagecal_tpu.solvers import lm as lm_mod, sage
-    sd = _spec(one_chip)
-    f32, i32, c64 = jnp.float32, jnp.int32, jnp.complex64
-    rows = B_120
-    cfg = sage.SageConfig(nbase=NB)._replace(
-        max_emiter=4, max_iter=2, max_lbfgs=10, lbfgs_m=7,
-        solver_mode=int(SolverMode.RTR_OSRLM_RLBFGS))
-    os_ids, os_nsub = lm_mod.os_subset_ids(TILESZ_120, NB)
-    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
-    flag = sd((), jnp.bool_)
-    # x8, coh, sta1, sta2, chunk idx: what every solve program is handed
-    data = (sd((rows, 8), f32), sd((M, rows, 2, 2), c64),
-            sd((rows,), i32), sd((rows,), i32), sd((M, rows), i32))
-    J, wt = sd((M, 1, N, 2, 2), c64), sd((rows, 8), f32)
     with jax.default_matmul_precision("highest"):
-        if name == "sagefit":
-            lowered = sage._jit_sagefit.lower(
-                *data, sd((M, 1), jnp.bool_), J, N, wt, sd((), f32), cfg,
-                sd(np.shape(os_ids), i32), os_nsub,
-                sd(key.shape, key.dtype))
-        elif name == "refine":
-            lowered = sage._jit_refine.lower(
-                *data, J, wt, sd((), f32), N, cfg._replace(max_emiter=0),
-                True)
-        elif name == "cluster_update":
-            cfg0 = cfg._replace(max_emiter=0)
-            lowered = sage._jit_cluster_update.lower(
-                sd((), i32), J, sd((8, TILESZ_120, NB), f32),   # cj, J, xres
-                sd((M,), f32), sd((M,), f32),
-                *data[1:], sd((M, 1), jnp.bool_), wt, sd((M,), f32), flag,
-                flag, sd(key.shape, key.dtype), None,
-                sd(np.shape(os_ids), i32), N, cfg0, M * cfg0.max_iter, 2,
-                os_nsub)
-        elif name == "simulate":
+        if name == "simulate":
             lowered = _lower_simulate_program(one_chip, TILESZ_120, 3)
+        elif name == "residual":
+            lowered = _lower_residual_program(one_chip, B_120)
         else:
-            lowered = _lower_residual_program(one_chip, rows)
+            lowered = _lower_solve_program(one_chip, name, TILESZ_120)
         mem = lowered.compile().memory_analysis()
     return (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
@@ -468,3 +483,77 @@ def test_folded_consensus_program_compiles_and_fits(one_chip):
         mem.temp_size_in_bytes / 2 ** 30
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < HBM_BYTES
+
+
+# -- the hybrid cluster file (PR 44): 16 clusters, kmax 5, flat rows ----------
+
+M_HYB, KMAX_HYB = 16, 5
+#: one ``f32[18910, 2, 2, 4, 4]`` Gram-block temporary of the generic
+#: assembly, tiled ``T(4,128)``: 4.8 MB of data
+PADDED_GRAM_BYTES = B * 2 * 2 * 4 * 128 * 4
+#: temporaries each program compiled to here at PR 44, GiB
+HYBRID_TEMP = {"sagefit": 0.447, "residual": 0.145,
+               "cluster_update": 0.410, "refine": 0.172}
+#: the two that a warm tile of the cell runs are tier-1 (32 s); the
+#: host-driven plan's two (tiles 0 and 1) cost 33 s more and are slow
+HYBRID_PROGRAMS = ["sagefit", "residual",
+                   pytest.param("cluster_update", marks=pytest.mark.slow),
+                   pytest.param("refine", marks=pytest.mark.slow)]
+
+
+@pytest.mark.parametrize("program", HYBRID_PROGRAMS)
+def test_hybrid_tile_compiles_and_fits(one_chip, program):
+    """Upstream's hybrid cluster file at the cell ``cal-m16x3-hybrid``'s
+    shape: one cluster with more than one chunk takes every program off
+    the ``[tilesz, nbase]`` planes (``planes.periodic_rows``) onto flat
+    rows and the generic scatter assembly, ``[B, 2, 2]`` complex
+    products among them, the form the TPU compiler aborted on twice (PR
+    23, PR 37).  Each compiles for the described v5e (a CHECK failure
+    kills this worker, which is the test failing) under the scopes the
+    periodic path has, so ``benchmarks/scopes.py`` reads it by the same
+    names.  Temporaries as compiled here at PR 44, with f32 contractions
+    in f32 (at ``-t 120``, 226 920 rows: PERF.md section 6):
+
+    ==============  =========  =========
+    program         -t 10      -t 120
+    ==============  =========  =========
+    sagefit         0.447 GiB  4.48 GiB
+    cluster_update  0.410 GiB  3.96 GiB
+    refine          0.172 GiB  2.60 GiB
+    residual        0.145 GiB  2.06 GiB
+    ==============  =========  =========
+
+    What they are: ``f32[18910, 2, 2, 4, 4]`` Gram blocks and the
+    scatter's ``f32[5, 62, 62, 2, 2, 4, 4]`` tiled ``T(4,128)`` (148 MiB
+    for 4.8 MB of data each, five and one of them in the solve), and the
+    Jones gathered a row, ``f32[16, 18910, 8]`` tiled ``T(8,128)`` (148
+    MiB for 9.7 MB).  The ceiling is what a program compiled to with a
+    tenth of room plus ONE more such block: another padded ``[B]``-long
+    temporary held live is what this case notices."""
+    from sagecal_tpu.solvers import sage
+    cfg = sage.SageConfig(nbase=NB)
+    assert sage.sweep_rows(cfg, KMAX_HYB, B) == "flat"
+    assert sage.assemble_rows(cfg, KMAX_HYB, B) == "generic"
+    with jax.default_matmul_precision("highest"):
+        if program == "residual":       # cluster 5: the negative id
+            lowered = _lower_residual_program(
+                one_chip, B, m=M_HYB, kmax=KMAX_HYB, kept=(5,))
+        else:
+            lowered = _lower_solve_program(one_chip, program, TILESZ,
+                                           m=M_HYB, kmax=KMAX_HYB)
+        compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    ceiling = int(1.1 * HYBRID_TEMP[program] * 2 ** 30) + PADDED_GRAM_BYTES
+    assert 0 < mem.temp_size_in_bytes < ceiling, \
+        mem.temp_size_in_bytes / 2 ** 30
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < HBM_BYTES
+    text = compiled.as_text()
+    want = {"sagefit": ("sage/sweep", "/assemble/", "/inner/", "/update/",
+                        "sage/refine", "/restrict/"),
+            "cluster_update": ("sage/sweep", "/assemble/", "/inner/",
+                               "/update/"),
+            "refine": ("sage/refine", "/restrict/", "/linesearch/"),
+            "residual": ("rime/phasor", "rime/corrupt", "rime/residual")}
+    for scope in want[program]:
+        assert scope in text, f"no operation under {scope}"

@@ -552,9 +552,8 @@ class ConsensusStepper:
         # the J updates carry their running residual on and assemble
         # their Gauss-Newton matrix from
         cfg_rows = cfg.sage._replace(nbase=int(meta0["nbase"]))
-        self.sweep_rows = sage.sweep_rows(cfg_rows, kmax, len(t0.sta1))
-        self.assemble_rows = sage.assemble_rows(cfg_rows, kmax,
-                                                len(t0.sta1))
+        self.sweep_rows = sage.sweep_rows(cfg_rows, len(t0.sta1))
+        self.assemble_rows = sage.assemble_rows(cfg_rows, len(t0.sta1))
         plans = [nm for nm, on in (("--block-f", args.block_f),
                                    ("--host-loop", args.host_loop),
                                    ("--time-shard", args.time_shard > 1),

@@ -23,6 +23,7 @@ the residual and the simulated column) both evaluate the form through
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
 
 
 def jones_c2r(J):
@@ -89,14 +90,55 @@ def row_tangent(dp8, dq8, a8, bm8):
     return mm(dp8, a8) + mm(bm8, dq8, adj_b=True)
 
 
-def periodic_rows(kmax: int, row_period: int, B: int) -> bool:
-    """Whether ``B`` rows of clusters with ``kmax`` chunks each lie
-    ``[tilesz, row_period]`` with the stations repeating every
-    ``row_period`` rows (what ``normal_eq.RowPlanes`` and
-    ``predict.predict_model`` decide their layout by)."""
-    return kmax == 1 and row_period > 0 and B % row_period == 0
+def periodic_rows(row_period: int, B: int) -> bool:
+    """Whether ``B`` rows lie ``[tilesz, row_period]``: the stations
+    repeat every ``row_period`` rows and the hybrid chunk of a row is
+    its timeslot's, whatever the clusters' chunk counts (what
+    ``rime/predict.chunk_indices`` builds; :func:`check_chunk_rows`
+    holds a concrete map to it). The ONE decision ``normal_eq.RowPlanes``,
+    ``normal_eq.normal_equations``, ``predict.predict_model`` and the
+    ``*_rows`` records of ``solvers/sage.py`` take their layout by; a
+    caller with another chunk map passes ``row_period=0``."""
+    return row_period > 0 and B % row_period == 0
+
+
+def check_chunk_rows(chunk_id, row_period: int):
+    """Hold a CONCRETE chunk map ``[(M,) B]`` to what ``row_period``
+    promises (:func:`periodic_rows`): inside each run of ``row_period``
+    rows, one timeslot, the chunk is constant. Raises ValueError where
+    it is not; a caller with such a map passes ``row_period=0`` and
+    keeps flat rows."""
+    c = np.asarray(chunk_id)
+    if not periodic_rows(row_period, c.shape[-1]):
+        return
+    c = c.reshape(c.shape[:-1] + (-1, row_period))
+    if (c != c[..., :1]).any():
+        raise ValueError(
+            f"chunk map varies inside a timeslot of {row_period} rows: "
+            "row_period (nbase) promises rows [tilesz, nbase] whose chunk "
+            "is their timeslot's (rime.predict.chunk_indices); pass "
+            "row_period=0 (nbase=0) for any other map")
 
 
 def take(P, idx):
     """Station planes P [K, N, 8], flat station indices -> [8, *idx.shape]."""
     return jnp.take(P.reshape(-1, 8).T, idx, axis=1)
+
+
+def gather_period(P, idx, tchunk=None):
+    """The Jones of one station of each row of ``[tilesz, R]`` rows, to
+    broadcast against the rows' planes: station planes ``P [K, N, 8]``
+    -> ``[8, *lead, 1, R]`` with one chunk a cluster (``idx
+    [*lead, R]``, ``tchunk`` None: gathered for ``R`` rows, every
+    timeslot the same), ``[8, *lead, tilesz, R]`` with ``kmax`` (``idx
+    [*lead, kmax, R]``, the flat station index per chunk and baseline;
+    ``tchunk [*lead, tilesz]``, the chunk of a timeslot): gathered for
+    ``kmax x R`` rows, a timeslot then picks its chunk's by ``kmax - 1``
+    selects along the time axis."""
+    j = take(P, idx)
+    if tchunk is None:
+        return j[..., None, :]
+    out = j[..., :1, :]
+    for k in range(1, idx.shape[-2]):
+        out = jnp.where((tchunk == k)[..., None], j[..., k:k + 1, :], out)
+    return out

@@ -341,22 +341,32 @@ def predict_model(c8, P, sta1, sta2, chunk_idx, cluster_mask=None,
     the channels leading axes that the Jones broadcast over, reduced over
     the clusters (what ``solvers/sage._joint_model`` is to the refine).
     The layout follows what the input shows: rows ``[tilesz,
-    row_period]`` with one chunk a cluster (``planes.periodic_rows``;
-    ``row_period`` is the tile's ``nbase``, 0 where the caller knows of
-    none) have the Jones gathered for ``row_period`` rows and broadcast
-    over time; any other rows, hybrid chunks among them, are gathered
+    row_period]`` (``planes.periodic_rows``; ``row_period`` is the
+    tile's ``nbase``, 0 where the caller knows of none or the chunk of a
+    row is not its timeslot's) have the Jones gathered for
+    ``row_period`` rows a chunk and broadcast over the chunk's
+    timeslots (``planes.gather_period``); any other rows are gathered
     row by row.
     """
     M, kmax, N = P.shape[:3]
     F, B = c8.shape[2:]
     if cluster_mask is not None:
         P = jnp.where(jnp.asarray(cluster_mask)[:, None, None, None], P, 0.0)
-    R = row_period if pl.periodic_rows(kmax, row_period, B) else B
+    R = row_period if pl.periodic_rows(row_period, B) else B
     rows = (B // R, R) if R < B else (B,)
-    slot = (chunk_idx[:, :R] + kmax * jnp.arange(M)[:, None]) * N
-    # [8, M, 1 (channels), (1 (time),) R] against c8 [8, M, F, *rows]
-    jp, jq = (pl.take(P, slot + sta[:R]).reshape(
-        (8, M) + (1,) * len(rows) + (R,)) for sta in (sta1, sta2))
+    off = kmax * jnp.arange(M)[:, None]
+    if kmax > 1 and R < B:
+        # per (chunk, baseline) [M, 1 (channels), kmax, R], a timeslot
+        # then picks its chunk's: [8, M, 1, T, R]
+        slot = ((off + jnp.arange(kmax)) * N)[:, None, :, None]
+        jp, jq = (pl.gather_period(P, slot + sta[:R],
+                                   chunk_idx[:, None, ::R])
+                  for sta in (sta1, sta2))
+    else:
+        slot = (chunk_idx[:, :R] + off) * N
+        # [8, M, 1 (channels), (1 (time),) R] against c8 [8, M, F, *rows]
+        jp, jq = (pl.take(P, slot + sta[:R]).reshape(
+            (8, M) + (1,) * len(rows) + (R,)) for sta in (sta1, sta2))
     v = pl.mm(jp, pl.mm(c8.reshape((8, M, F) + rows), jq, adj_b=True))
     return jnp.sum(v, axis=1).reshape(8, F, B)
 

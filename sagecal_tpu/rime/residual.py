@@ -14,8 +14,9 @@ that policy arrives here as ``subtract_mask``.
 Every function here takes the solutions ``J`` as ``[M, Kmax, N, 2, 2]``
 complex or as their real planes ``[M, Kmax, N, 8]`` (``planes.jones_c2r``,
 the form the jit boundaries carry them in), and ``row_period``, the
-tile's ``nbase`` where its rows lie ``[tilesz, nbase]`` (0: no period
-known), which :func:`predict.predict_model` lays its planes out by.
+tile's ``nbase`` where its rows lie ``[tilesz, nbase]`` and the chunk
+of a row is its timeslot's (0: no period known, or another chunk map),
+which :func:`predict.predict_model` lays its planes out by.
 """
 
 from __future__ import annotations

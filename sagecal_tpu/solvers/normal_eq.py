@@ -56,28 +56,48 @@ class RowPlanes:
 
     ``x``, ``w``: data and sqrt-weights ``[8, *rows]`` in their (storage)
     dtype (None where none were given); ``c``: the coherency planes
-    ``[8, (M,) *rows]``. With one chunk and a
-    ``row_period`` (rows laid out ``[tilesz, nbase]``, stations
-    repeating every ``nbase``: the invariant of
-    :func:`normal_equations`) ``rows`` is ``(tilesz, nbase)``: the
-    Jones are gathered for ``nbase`` rows and broadcast over time, and a
-    per-row gradient is summed over time before ``nbase`` rows are
-    scattered; otherwise ``rows`` is ``(B,)``."""
+    ``[8, (M,) *rows]``. With a ``row_period`` that divides the rows
+    (:func:`periodic_rows`: rows laid out ``[tilesz, nbase]``, stations
+    repeating every ``nbase``, the chunk of a row its timeslot's)
+    ``rows`` is ``(tilesz, nbase)``: the Jones are gathered for ``nbase``
+    rows a chunk and broadcast over the chunk's timeslots, and a per-row
+    gradient is summed over a chunk's timeslots before ``kmax x nbase``
+    rows are scattered; otherwise ``rows`` is ``(B,)``. A chunk map
+    that is not a tracer is held to the promise
+    (``planes.check_chunk_rows``)."""
 
     def __init__(self, x8, coh, wt, sta1, sta2, chunk_id, kmax: int,
                  n_stations: int, row_period: int = 0):
         B, lead = coh.shape[-3], coh.shape[:-3]
-        self.periodic = periodic_rows(kmax, row_period, B)
+        self.periodic = periodic_rows(row_period, B)
         R = row_period if self.periodic else B
         self.rows = (B // R, R) if self.periodic else (B,)
+        #: chunks a cluster; ``kmax`` counts all clusters' slots
+        self.chunks = kmax
+        #: per timeslot, its chunk within the cluster [(M,) tilesz], and
+        #: whether timeslot t is of chunk k [(M,) chunks, tilesz, 1]: set
+        #: where planes of several chunks need them
+        self.tchunk = self.of_chunk = None
+        if self.periodic and kmax > 1:
+            if not isinstance(chunk_id, jax.core.Tracer):
+                pl.check_chunk_rows(chunk_id, R)
+            self.tchunk = chunk_id[..., ::R]
+            self.of_chunk = (self.tchunk[..., None, :] == jnp.arange(
+                kmax, dtype=chunk_id.dtype)[:, None])[..., None]
         if lead:
             chunk_id = chunk_id + kmax * jnp.arange(
                 lead[0], dtype=chunk_id.dtype)[:, None]
             kmax *= lead[0]
         self.kmax, self.n_stations, self.chunk_id = kmax, n_stations, chunk_id
         self.sta1, self.sta2 = sta1, sta2
-        self.i1 = (chunk_id * n_stations + sta1)[..., :R]
-        self.i2 = (chunk_id * n_stations + sta2)[..., :R]
+        if self.tchunk is None:
+            self.i1 = (chunk_id * n_stations + sta1)[..., :R]
+            self.i2 = (chunk_id * n_stations + sta2)[..., :R]
+        else:
+            # per (chunk, baseline): [(M,) chunks, R]
+            slot = jnp.arange(kmax, dtype=chunk_id.dtype).reshape(
+                lead + (self.chunks, 1)) * n_stations
+            self.i1, self.i2 = slot + sta1[:R], slot + sta2[:R]
         self.x, self.w = (None if a is None else self.planes(a)
                           for a in (x8, wt))
         self.c = self.planes(jones_c2r(coh))
@@ -99,11 +119,13 @@ class RowPlanes:
         data are the caller's to give (:meth:`with_x`). Slices of what
         this instance holds: nothing is laid out again."""
         out = copy.copy(self)
-        out.kmax = self.kmax // self.c.shape[1]
+        out.kmax = self.chunks
         off = m * out.kmax
         out.chunk_id = self.chunk_id[m] - off
         out.i1 = self.i1[m] - off * self.n_stations
         out.i2 = self.i2[m] - off * self.n_stations
+        if self.tchunk is not None:
+            out.tchunk, out.of_chunk = self.tchunk[m], self.of_chunk[m]
         out.c = self.c[:, m]
         return out
 
@@ -123,19 +145,28 @@ class RowPlanes:
 
     def gather(self, P):
         """Station planes P [K, N, 8] -> (jp8, jq8) for :func:`row_model`."""
-        jp, jq = pl.take(P, self.i1), pl.take(P, self.i2)
-        return ((jp[..., None, :], jq[..., None, :]) if self.periodic
-                else (jp, jq))
+        if self.periodic:
+            return (pl.gather_period(P, self.i1, self.tchunk),
+                    pl.gather_period(P, self.i2, self.tchunk))
+        return pl.take(P, self.i1), pl.take(P, self.i2)
 
     def time_sum(self, a):
         """The part of :meth:`station_sum` that is elementwise with the
-        rows: [8, (M,) *rows] -> [8, (M,) R]."""
-        return jnp.sum(a, axis=-2) if self.periodic else a
+        rows: [W, (M,) *rows] -> [W, (M,) R], the sum over time, with one
+        chunk a cluster; [W, (M,) chunks, R], the sums over each chunk's
+        timeslots (a masked sum along the time axis: elementwise and a
+        reduction, no contraction), with several."""
+        if not self.periodic:
+            return a
+        if self.tchunk is None:
+            return jnp.sum(a, axis=-2)
+        return jnp.sum(jnp.where(self.of_chunk, a[..., None, :, :], 0),
+                       axis=-2)
 
     def station_sum(self, gp, gq):
-        """Per-row shares [W, (M,) R] of the first and of the second
-        station (after :meth:`time_sum`) -> [K, N, W] (W = 8 for a
-        gradient's planes)."""
+        """Per-row shares [W, (M,) (chunks,) R] of the first and of the
+        second station (after :meth:`time_sum`) -> [K, N, W] (W = 8 for
+        a gradient's planes)."""
         W = gp.shape[0]
         out = jnp.zeros((self.kmax * self.n_stations, W), gp.dtype)
         out = (out.at[self.i1].add(jnp.moveaxis(gp, 0, -1))
@@ -146,14 +177,21 @@ class RowPlanes:
         """One cluster's [8, *rows] -> per-chunk sums [K]."""
         if self.kmax == 1:
             return jnp.sum(a).reshape(1)
-        return jax.ops.segment_sum(jnp.sum(a, axis=0), self.chunk_id,
-                                   num_segments=self.kmax)
+        if self.tchunk is None:
+            return jax.ops.segment_sum(jnp.sum(a, axis=0), self.chunk_id,
+                                       num_segments=self.kmax)
+        # the baselines first, then the timeslots of a chunk
+        return jnp.sum(jnp.where(self.of_chunk[..., 0],
+                                 jnp.sum(a, axis=(0, -1)), 0), axis=-1)
 
     def select(self, take, new, old):
         """One cluster's rows of the chunks where ``take`` [K] holds from
         ``new``, the others from ``old`` (both [8, *rows])."""
-        return jnp.where(take[0] if self.kmax == 1
-                         else take[self.chunk_id], new, old)
+        if self.kmax == 1:
+            return jnp.where(take[0], new, old)
+        if self.tchunk is None:
+            return jnp.where(take[self.chunk_id], new, old)
+        return jnp.where(take[self.tchunk][:, None], new, old)
 
 
 def residual8(x8, J, coh, sta1, sta2, chunk_id):
@@ -544,9 +582,9 @@ def _gram_planes(w2, a8, bm8, tsum):
 @jax.named_scope("assemble")     # sage/sweep/assemble in a solve
 def plane_equations(rows: RowPlanes, P, w8=None, cost_w8=None):
     """:func:`normal_equations` from row data in plane form: the weighted
-    Gauss-Newton (JTJ [1, 8N, 8N], JTe [1, 8N], cost [1]) of ONE cluster
-    with ONE chunk whose rows lie ``[tilesz, nbase]``
-    (``rows.periodic``), at the stations' Jones ``P [1, N, 8]`` (real
+    Gauss-Newton (JTJ [K, 8N, 8N], JTe [K, 8N], cost [K]) of ONE cluster
+    with ``K`` chunks whose rows lie ``[tilesz, nbase]``
+    (``rows.periodic``), at the stations' Jones ``P [K, N, 8]`` (real
     planes, :func:`jones_c2r` order).
 
     ``w8``: the sqrt-weight planes ``[8, *rows.rows]`` JTJ and JTe use
@@ -555,23 +593,24 @@ def plane_equations(rows: RowPlanes, P, w8=None, cost_w8=None):
     (:func:`normal_equations`' ``cost_wt``).
 
     Real elementwise arithmetic on planes with the rows on the minor
-    axes and a sum over time, nothing else at row size: the Jones are
-    gathered for ``nbase`` rows and broadcast over time, ``A = C J_q^H``,
-    ``Bm = J_p C`` and ``V`` come from :func:`row_model`, JTe is
-    :func:`row_grad` of the twice-weighted residual, and the Gram blocks
-    of a baseline are :func:`_gram_planes`' written-out products in the
-    accumulation dtype. Only the ``nbase``-sized blocks are placed: the
-    station-diagonal ones by one segment sum, the cross blocks by one
-    scatter of ``nbase`` rows of 64 into ``[N, N]`` station pairs, which
-    a transposition brings to ``[8N, 8N]`` and a second one
-    symmetrizes. A caller that keeps JTJ alone (``rtr.make_hess``)
-    leaves V, the residual, JTe and the cost to the compiler's
-    dead-code pass."""
+    axes and a sum over each chunk's timeslots, nothing else at row
+    size: the Jones are gathered for ``nbase`` rows a chunk and broadcast
+    over time, ``A = C J_q^H``, ``Bm = J_p C`` and ``V`` come from
+    :func:`row_model`, JTe is :func:`row_grad` of the twice-weighted
+    residual, and the Gram blocks of a baseline are :func:`_gram_planes`'
+    written-out products in the accumulation dtype. Only the
+    ``K x nbase`` blocks are placed: the station-diagonal ones by one
+    segment sum, the cross blocks by one scatter of ``K x nbase`` rows of
+    64 into ``[K, N, N]`` station pairs, which a transposition brings to
+    ``[K, 8N, 8N]`` and a second one symmetrizes. A caller that keeps
+    JTJ alone (``rtr.make_hess``) leaves V, the residual, JTe and the
+    cost to the compiler's dead-code pass."""
     if not rows.periodic or rows.c.ndim != 3:
         raise ValueError("plane_equations wants one cluster's rows laid "
-                         "[tilesz, nbase] with one chunk "
-                         "(RowPlanes.periodic)")
-    N = rows.n_stations
+                         "[tilesz, nbase] (RowPlanes.periodic)")
+    N, K = rows.n_stations, rows.kmax
+    # the chunks lead the station-sized arrays where there are several
+    lead = (K,) if K > 1 else ()
     w8 = rows.w if w8 is None else w8
     jp8, jq8 = rows.gather(P)
     v8, a8, bm8 = row_model(jp8, jq8, rows.c)
@@ -588,17 +627,22 @@ def plane_equations(rows: RowPlanes, P, w8=None, cost_w8=None):
     dt = pq.dtype
     # station-diagonal blocks: ten entries a block summed to the
     # stations, mirrored afterwards (so D is symmetric to the last digit)
-    D = rows.station_sum(pp, qq).reshape(N, 2, 10)[..., _TRI_OF]
+    D = rows.station_sum(pp, qq).reshape(lead + (N, 2, 10))[..., _TRI_OF]
     eye2, eyeN = jnp.eye(2, dtype=dt), jnp.eye(N, dtype=dt)
-    Dfull = (D[:, :, :, None, :]
-             * eye2[None, :, None, :, None]).reshape(N, 8, 8)
-    # cross blocks: [(a, i), (o, j)] of baseline (p, q) at (p, q); the
-    # other triangle is the transpose of the whole matrix
-    U = jnp.zeros((N * N, 64), dt).at[rows.i1 * N + rows.i2].add(pq.T)
-    Mx = U.reshape(N, N, 8, 8).transpose(0, 2, 1, 3).reshape(8 * N, 8 * N)
-    JTJ = Mx + Mx.T + (Dfull[:, :, None, :]
-                       * eyeN[:, None, :, None]).reshape(8 * N, 8 * N)
-    return JTJ[None], JTe.reshape(1, 8 * N), cost
+    Dfull = (D[..., None, :]
+             * eye2[None, :, None, :, None]).reshape(lead + (N, 8, 8))
+    # cross blocks: [(a, i), (o, j)] of baseline (p, q) at (p, q) of its
+    # chunk; the other triangle is the transpose of the whole matrix
+    pair = rows.i1 * N + rows.i2
+    if K > 1:       # both hold the chunk's offset: once is enough
+        pair = pair - (jnp.arange(K, dtype=pair.dtype) * N)[:, None]
+    U = jnp.zeros((K * N * N, 64), dt).at[pair].add(jnp.moveaxis(pq, 0, -1))
+    Mx = jnp.swapaxes(U.reshape(lead + (N, N, 8, 8)), -3, -2).reshape(
+        lead + (8 * N, 8 * N))
+    JTJ = Mx + jnp.swapaxes(Mx, -1, -2) + (
+        Dfull[..., None, :] * eyeN[:, None, :, None]).reshape(
+            lead + (8 * N, 8 * N))
+    return JTJ.reshape(K, 8 * N, 8 * N), JTe.reshape(K, 8 * N), cost
 
 
 @jax.named_scope("assemble")     # sage/sweep/assemble in a solve
@@ -618,13 +662,14 @@ def normal_equations(x8, J, coh, sta1, sta2, chunk_id, wt, n_stations: int,
 
     ``row_period``: the visibility rows' baseline period — callers lay
     rows out as [tilesz, nbase] with sta1/sta2 repeating every ``nbase``
-    rows (the same invariant :func:`lm.os_subset_ids` builds on). When
-    set and a cluster has a single hybrid chunk (kmax == 1, every
-    timeslot in chunk 0: :func:`periodic_rows`), the rows go to plane
-    form and the equations are :func:`plane_equations`': real
+    rows (the same invariant :func:`lm.os_subset_ids` builds on) and the
+    hybrid chunk of a row its timeslot's (``rime.predict.chunk_indices``).
+    When set and dividing the rows (:func:`periodic_rows`), the rows go
+    to plane form and the equations are :func:`plane_equations`': real
     elementwise arithmetic on ``[tilesz, nbase]`` planes summed over
-    time, ``nbase``-sized blocks placed. 0, several chunks or a row
-    count the period does not divide take the generic assembly below.
+    each chunk's timeslots, ``kmax x nbase`` blocks placed. 0 (any other
+    chunk map) or a row count the period does not divide take the
+    generic assembly below.
 
     The generic assembly: the per-baseline real Jacobians are never
     materialized. The Wirtinger blocks have only 16 independent reals
@@ -648,7 +693,7 @@ def normal_equations(x8, J, coh, sta1, sta2, chunk_id, wt, n_stations: int,
                                          row_period=row_period)
     N = n_stations
     B = x8.shape[0]
-    if periodic_rows(kmax, row_period, B):
+    if periodic_rows(row_period, B):
         rows = RowPlanes(x8, coh, wt, sta1, sta2, chunk_id, kmax, N,
                          row_period)
         return plane_equations(

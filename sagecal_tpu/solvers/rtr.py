@@ -475,15 +475,15 @@ def _rtr_rows(rows: ne.RowPlanes, J0, n_stations: int, chunk_mask,
         normal matrix is assembled ONCE per outer iteration from the
         analytic Wirtinger factors and each tCG product is a single
         batched [K,8N,8N]@[K,8N] matvec on the matrix unit. On rows
-        with a period (one chunk, ``[tilesz, nbase]``: every cluster
-        solve of a calibration with ``nbase`` set) the assembly is
-        normal_eq.plane_equations on the planes this solve holds
+        with a period (``[tilesz, nbase]``, any chunk count: every
+        cluster solve of a calibration with ``nbase`` set) the assembly
+        is normal_eq.plane_equations on the planes this solve holds
         (``rows``, the point's station planes, the curvature weights as
         planes): its own evaluation of the row model's Wirtinger
-        factors, elementwise, and a sum over time, of which XLA keeps
-        what JTJ needs. Several chunks, ``inner="cg"``,
-        ``kernel="pallas"`` and the constrained modes take the
-        ``[B, 8]`` assemblies of normal_eq / sweep_pallas.
+        factors, elementwise, and a sum over each chunk's timeslots, of
+        which XLA keeps what JTJ needs. Rows without a period,
+        ``inner="cg"``, ``kernel="pallas"`` and the constrained modes
+        take the ``[B, 8]`` assemblies of normal_eq / sweep_pallas.
 
         Curvature model per residual element e (e already includes wt):
           gaussian  sum e^2:          f'' = 2          -> weights wt

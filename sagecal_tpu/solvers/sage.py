@@ -118,35 +118,35 @@ _PLAN_KEYS = ("plan", "solve_dispatches", "refine_rows", "assemble_rows",
               "sweep_rows")
 
 
-def sweep_rows(config, kmax: int, B: int) -> str:
+def sweep_rows(config, B: int) -> str:
     """The row layout a solve's sweeps carry their running residual and
     evaluate a cluster's model on, which :class:`normal_eq.RowPlanes`
-    decides from its input: ``"periodic"`` (one chunk a cluster and
-    ``config.nbase`` dividing the ``B`` rows: ``[8, tilesz, nbase]``
-    planes, the Jones gathered for ``nbase`` rows) or ``"flat"``
-    (``[8, B]``)."""
-    return "periodic" if ne.periodic_rows(kmax, config.nbase, B) else "flat"
+    decides from its input: ``"periodic"`` (``config.nbase`` dividing
+    the ``B`` rows, whatever the clusters' chunk counts: ``[8, tilesz,
+    nbase]`` planes, the Jones gathered for ``nbase`` rows a chunk) or
+    ``"flat"`` (``[8, B]``, a Jones gathered a row)."""
+    return "periodic" if ne.periodic_rows(config.nbase, B) else "flat"
 
 
-def assemble_rows(config, kmax: int, B: int):
+def assemble_rows(config, B: int):
     """The row layout the per-cluster solves' dense Gauss-Newton matrix
     is assembled from, which ``normal_eq.normal_equations`` and
-    ``rtr.make_hess`` decide from their input: ``"periodic"`` (one
-    chunk a cluster and ``config.nbase`` dividing the ``B`` rows: the
-    assembly on ``[tilesz, nbase]`` planes, ``normal_eq.plane_equations``)
-    or ``"generic"`` (the ``[B, 8]`` scatter assembly). None where the
-    solves assemble no such matrix or another code does (NSD,
-    ``inner="cg"``, ``kernel="pallas"``, a constrained Jones mode, a
-    reduced storage dtype)."""
+    ``rtr.make_hess`` decide from their input: ``"periodic"``
+    (``config.nbase`` dividing the ``B`` rows, whatever the clusters'
+    chunk counts: the assembly on ``[tilesz, nbase]`` planes,
+    ``normal_eq.plane_equations``) or ``"generic"`` (the ``[B, 8]``
+    scatter assembly). None where the solves assemble no such matrix or
+    another code does (NSD, ``inner="cg"``, ``kernel="pallas"``, a
+    constrained Jones mode, a reduced storage dtype)."""
     if (config.inner != "chol" or config.kernel != "xla"
             or config.jones_mode != "full" or config.dtype_policy != "f32"
             or int(config.solver_mode) == int(SolverMode.NSD_RLBFGS)):
         return None
-    return "periodic" if ne.periodic_rows(kmax, config.nbase, B) \
+    return "periodic" if ne.periodic_rows(config.nbase, B) \
         else "generic"
 
 
-def _plan_info(info: dict, plan: str, n0: int, config, J0, x8) -> dict:
+def _plan_info(info: dict, plan: str, n0: int, config, x8) -> dict:
     """``info`` with what a host-driven solve ran: ``plan`` is what its
     LAST sweep executed ("promoted": the whole solve as one program,
     "fused": a program a sweep, "per_cluster": a program a cluster or
@@ -154,20 +154,19 @@ def _plan_info(info: dict, plan: str, n0: int, config, J0, x8) -> dict:
     :func:`_call` since ``n0``, ``refine_rows`` (where a refine ran) the
     row layout its model passes worked on, which the mechanism decides
     from its input (``"periodic"``: ``[tilesz, nbase]`` planes, the
-    Jones gathered for ``nbase`` rows; ``"flat"``: ``[B]``),
+    Jones gathered for ``nbase`` rows a chunk; ``"flat"``: ``[B]``),
     ``sweep_rows`` (:func:`sweep_rows`, where a sweep ran) the same of
     the sweeps' running residual and cluster models, and
     ``assemble_rows`` (:func:`assemble_rows`, where it says one) of the
-    sweeps' assembly, all here from the shapes of
-    ``J0 [(T,) M, kmax, N, 2, 2]`` and ``x8 [(T,) B, 8]``.
-    Host values: nothing is fetched."""
+    sweeps' assembly, all here from ``config.nbase`` and the shape of
+    ``x8 [(T,) B, 8]``. Host values: nothing is fetched."""
     out = {**info, "plan": plan, "solve_dispatches": _dispatched() - n0}
-    kmax, B = J0.shape[-4], x8.shape[-2]
+    B = x8.shape[-2]
     if config.max_lbfgs > 0:
-        out["refine_rows"] = sweep_rows(config, kmax, B)
+        out["refine_rows"] = sweep_rows(config, B)
     if config.max_emiter > 0:
-        out["sweep_rows"] = sweep_rows(config, kmax, B)
-    rows = assemble_rows(config, kmax, B)
+        out["sweep_rows"] = sweep_rows(config, B)
+    rows = assemble_rows(config, B)
     if rows is not None:
         out["assemble_rows"] = rows
     return out
@@ -249,10 +248,17 @@ class SageConfig(NamedTuple):
     inflight_warm: bool = False
     # row baseline period of the [tilesz, nbase] visibility layout
     # (io.dataset / rime.predict build all rows this way — the same
-    # invariant lm.os_subset_ids hard-codes). Forwarded to the solvers'
-    # normal-equation assembly, whose baseline-major aggregation needs
-    # it for single-chunk clusters; 0 = unknown (generic scatter path,
-    # identical results).
+    # invariant lm.os_subset_ids hard-codes), which also promises that
+    # the hybrid chunk of a row is its timeslot's
+    # (rime.predict.chunk_indices, which every caller in this package
+    # builds its map with). The sweeps, the assembly, the refine and the
+    # residual keep [tilesz, nbase] planes by it, whatever the chunk
+    # counts; 0 = unknown, or another chunk map (flat rows and the
+    # generic scatter assembly, the same result). normal_eq.RowPlanes
+    # raises on a CONCRETE map that breaks the promise; a TRACED one (a
+    # jit argument, as in every program here) cannot be looked at, and
+    # a foreign map handed that way with nbase set is solved as if each
+    # timeslot had its first row's chunk: silently another answer.
     nbase: int = 0
     # fold each cluster visit's residual re-subtract and the NEXT
     # visit's add-back into ONE pass over the running residual's planes
@@ -582,8 +588,8 @@ def _sweep_planes(coh, wt_base, sta1, sta2, chunk_idx, kmax: int,
     program: the coherencies of all clusters (``c [8, M, *rows]``), the
     sqrt-weights (``w [8, *rows]``) and the station indices. The
     running residual (the sweep's carry, ``[8, *rows]`` in the storage
-    dtype) has the same layout, which ``config.nbase`` and ``kmax``
-    decide (:func:`sweep_rows`)."""
+    dtype) has the same layout, which ``config.nbase`` decides
+    (:func:`sweep_rows`)."""
     with jax.named_scope("update"):
         return ne.RowPlanes(None, coh, wt_base, sta1, sta2, chunk_idx,
                             kmax, n_stations, config.nbase)
@@ -1395,7 +1401,7 @@ def sagefit_host(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
                         config._replace(fuse="auto", promote="auto"),
                         os_ids if os_id is not None else None,
                         os_nsub, key)
-        return J, _plan_info(info, "promoted", n0, config, J0, x8)
+        return J, _plan_info(info, "promoted", n0, config, x8)
     xres, res_0 = _call("prelude", _jit_prelude, x8, coh, sta1, sta2,
                         chunk_idx, J0, wt_base, row_period=config.nbase)
     # the per-sweep/per-cluster programs DONATE their state carries
@@ -1528,7 +1534,7 @@ def sagefit_host(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
          "rejected_groups": tk_total[1], "cg_iters": tk_total[2],
          "row_passes": tk_total[3],
          "lbfgs_iters": lbfgs_k, "refine_passes": passes},
-        "fused" if ran_fused else "per_cluster", n0, config, J0, x8)
+        "fused" if ran_fused else "per_cluster", n0, config, x8)
 
 
 # ---------------------------------------------------------------------------
@@ -1695,7 +1701,7 @@ def sagefit_host_tiles(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
                         config._replace(fuse="auto", promote="auto"),
                         os_ids if os_id is not None else None,
                         os_nsub, keys)
-        return J, _plan_info(info, "promoted", n0, config, J0, x8)
+        return J, _plan_info(info, "promoted", n0, config, x8)
     xres, res_0 = _call("prelude_tiles", _jit_prelude_tiles, x8, coh,
                         sta1, sta2, chunk_idx, J0, wt_base,
                         row_period=config.nbase)
@@ -1816,7 +1822,7 @@ def sagefit_host_tiles(x8, coh, sta1, sta2, chunk_idx, chunk_mask, J0,
          "cg_iters": tk_total[:, 2],
          "row_passes": tk_total[:, 3],
          "lbfgs_iters": lbfgs_k, "refine_passes": passes},
-        "fused" if ran_fused else "per_cluster", n0, config, J0, x8)
+        "fused" if ran_fused else "per_cluster", n0, config, x8)
 
 
 @functools.partial(jax.jit,

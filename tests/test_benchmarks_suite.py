@@ -22,8 +22,13 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SUITE = "benchmarks/tests"
 # 140 s alone (PR 30), 520-640 s beside 5 workers (PR 40); 1001 s beside 5
 # workers with PR 44's test_hybrid.py at six tiny runs, which was cut to
-# three for it (the whole suite has 1470 s)
-LIMIT_S = 1250
+# three for it (the whole suite has 1470 s).  PR 45: 827 s alone on the
+# parent's tree in this sandbox, and beside five workers anything from
+# 1150 s to past 1250 s on the same tree (two whole runs here; the
+# driver's whole tier-1 run of PR 45's tree took 915 s): this run is
+# tier-1's longest chain, so its limit leaves a cold run that room and
+# ``NOT_RUN`` keeps the one tiny run known to fail out of it
+LIMIT_S = 1350
 PYTEST = [sys.executable, "-m", "pytest", SUITE, "-q",
           "-p", "no:cacheprovider", "-p", "no:randomly"]
 
@@ -116,7 +121,30 @@ OVERTAKEN = {
     "appended_since[test_subtract]":
         "test_subtract.py holds the LAST configuration, cell and entries "
         "of the manifest, which are PR 44's now",
+    # PR 45 keeps a hybrid tile on the [tilesz, nbase] planes: the tiny
+    # cell's records say ``periodic`` three times and
+    # ``flat_row_passes.hyb`` reads 0, which is what that reader was
+    # written to see ("a program that keeps the planes inside a chunk
+    # brings this to 0").  This case pinned the slow state (``> 0`` and
+    # the line ``sweep_rows flat, assemble_rows generic, refine_rows
+    # flat``); everything else it guards is held by
+    # ``tests/test_hybrid_cell.py``, and it is one of ``NOT_RUN`` below.
+    # PERF.md section 7 has the edit for the next ``benchmark`` issue.
+    "test_hybrid.py::test_sound_tiny_cell_is_correct_and_reports_the_eight":
+        "flat_row_passes.hyb is 0 and the layouts are periodic: a hybrid "
+        "chunk is a run of whole timeslots and stays on planes (PR 45)",
 }
+
+
+#: Overtaken cases that the one run leaves out (``--deselect``): each is
+#: a whole traced tiny run (80 s alone on the planes' programs, more
+#: beside five workers) that ends in the assertion known to fail, inside
+#: the one time limit this file shares with the whole suite.  Reported as
+#: expected failures like the others; ``tests/test_hybrid_cell.py`` makes
+#: the same run, on another worker, and holds the rest of what it guards.
+NOT_RUN = ["test_hybrid.py::test_sound_tiny_cell_is_correct_and_reports_"
+           "the_eight"]
+assert set(NOT_RUN) <= set(OVERTAKEN)
 
 
 def _junit_key(node_id):
@@ -132,10 +160,11 @@ def suite(tmp_path_factory):
     a run that met its time limit has no results."""
     xml = tmp_path_factory.mktemp("benchmarks_suite") / "junit.xml"
     try:
-        run = subprocess.run(PYTEST + [f"--junitxml={xml}"], cwd=ROOT,
-                             env=_env(), timeout=LIMIT_S, text=True,
-                             stdout=subprocess.PIPE,
-                             stderr=subprocess.STDOUT)
+        run = subprocess.run(
+            PYTEST + [f"--junitxml={xml}"]
+            + [f"--deselect={SUITE}/{n}" for n in NOT_RUN], cwd=ROOT,
+            env=_env(), timeout=LIMIT_S, text=True, stdout=subprocess.PIPE,
+            stderr=subprocess.STDOUT)
         rc, out = run.returncode, run.stdout
     except subprocess.TimeoutExpired as e:
         out = e.stdout or ""
@@ -159,12 +188,15 @@ def test_the_benchmarks_own_tests_ran_to_their_end(suite):
     results, rc, tail = suite
     assert not COLLECT_ERROR, COLLECT_ERROR
     assert IDS and rc in (0, 1), tail
-    assert {_junit_key(i) for i in IDS} <= set(results), tail
+    assert {_junit_key(i) for i in IDS if i not in NOT_RUN} \
+        <= set(results), tail
 
 
 @pytest.mark.parametrize("node_id", IDS)
 def test_benchmark(suite, node_id):
     results, _, tail = suite
+    if node_id in NOT_RUN:
+        pytest.xfail(OVERTAKEN[node_id])
     outcome, message = results.get(_junit_key(node_id),
                                    ("error", "no result of this test:\n"
                                     + tail))

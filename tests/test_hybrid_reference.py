@@ -262,15 +262,16 @@ def test_the_references_solutions_file_in_the_programs_reader(hyb, files):
 
 def test_the_tile_record_counts_the_chunk_slots(solved):
     """``kmax``, ``chunk_slots`` (M * kmax) and ``chunk_slots_live``
-    (sum(nchunk)) beside the layouts the solve ran on: flat rows and the
-    generic assembly, because one cluster has more than one chunk."""
+    (sum(nchunk)) beside the layouts the solve ran on: the ``[tilesz,
+    nbase]`` planes, a chunk being a run of whole timeslots (PR 45; flat
+    rows and the generic assembly before)."""
     tiles = [r for r in dtrace.read(solved["diag"]) if r.get("ev") == "tile"]
     assert len(tiles) == N_TILES
     for r in tiles:
         assert (r["kmax"], r["chunk_slots"], r["chunk_slots_live"]) \
             == (3, 12, 7)
-        assert r["sweep_rows"] == r["refine_rows"] == "flat"
-        assert r["assemble_rows"] == "generic"
+        assert r["sweep_rows"] == r["refine_rows"] == "periodic"
+        assert r["assemble_rows"] == "periodic"
         assert r["res_1"] < r["res_0"]
 
 

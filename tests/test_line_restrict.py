@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import pytest
 
 from sagecal_tpu.config import SolverMode
+from sagecal_tpu.rime import predict as rp
 from sagecal_tpu.solvers import lbfgs as lb, normal_eq as ne, sage
 
 N, M, TSZ = 5, 2, 4
@@ -26,10 +27,13 @@ B = len(PAIRS) * TSZ
 STEPS = (0.0, 0.1, 1.0, 10.0)
 
 
-def _problem(kmax, rdt=jnp.float64, seed=3):
+def _problem(kmax, rdt=jnp.float64, seed=3, by_timeslot=False):
     """A tiny observation: M clusters, ``kmax`` hybrid chunks with a
     mixed ``chunk_idx`` (cluster 0 changes chunk mid-tile, cluster 1
-    alternates), a tenth of the rows flagged to zero weight."""
+    alternates row by row: a map for flat rows only) or, ``by_timeslot``,
+    the map ``rime.predict.chunk_indices`` makes of ``kmax`` and one
+    chunk (what a row period promises); a tenth of the rows flagged to
+    zero weight."""
     cdt = jnp.complex128 if rdt == jnp.float64 else jnp.complex64
     rng = np.random.default_rng(seed)
     sta1 = jnp.asarray(np.tile([p[0] for p in PAIRS], TSZ), jnp.int32)
@@ -37,7 +41,9 @@ def _problem(kmax, rdt=jnp.float64, seed=3):
     coh = jnp.asarray(rng.normal(size=(M, B, 2, 2))
                       + 1j * rng.normal(size=(M, B, 2, 2)), cdt)
     cidx = np.zeros((M, B), np.int32)
-    if kmax > 1:
+    if by_timeslot:
+        cidx = rp.chunk_indices(TSZ, len(PAIRS), np.array([kmax, 1]))
+    elif kmax > 1:
         cidx[0] = (np.arange(B) * kmax) // B
         cidx[1] = np.arange(B) % kmax
     cidx = jnp.asarray(cidx)

@@ -29,6 +29,13 @@ NU = 2.5
 ROWS = {"periodic": NBASE, "flat": 0}
 
 
+def _rows_problem(kmax, rows, *args):
+    """The tiny observation for a layout: a row period promises a chunk
+    map by timeslot; flat rows keep the map that alternates row by
+    row."""
+    return _problem(kmax, *args, by_timeslot=rows == "periodic")
+
+
 def _reference(pb, robust, mode, nu=NU):
     """(model, cost_fn, line_func, shape, Jref) the plain way."""
     kmax = pb["kmax"]
@@ -84,7 +91,7 @@ def _point(pb, mode, scale=0.05):
 def test_joint_pass_is_the_plain_construction(robust, mode, kmax, rows):
     """Cost, gradient and restriction (float64, rtol 1e-10) against the
     sum of ``model8`` under ``jax.grad`` and ``jax.jvp``."""
-    pb = _problem(kmax)
+    pb = _rows_problem(kmax, rows)
     _model, cost_ref, line_ref, _shape, _Jref = _reference(pb, robust, mode)
     cost, grad, line = _planes(pb, robust, mode, rows)
     p = _point(pb, mode)
@@ -106,12 +113,12 @@ def test_joint_pass_is_the_plain_construction(robust, mode, kmax, rows):
 
 @pytest.mark.parametrize("kmax, rows, layout", [
     (1, "periodic", "periodic"), (1, "flat", "flat"),
-    (2, "periodic", "flat"), (2, "flat", "flat")])
+    (2, "periodic", "periodic"), (2, "flat", "flat")])
 def test_full_model8_is_the_joint_model(kmax, rows, layout):
     """One definition of the sum of all clusters' corrupted models: the
     rows the callers of ``full_model8`` get are the refine's planes
     transposed, in the layout the input decides."""
-    pb = _problem(kmax)
+    pb = _rows_problem(kmax, rows)
     model, _cost, _line, shape, _Jref = _reference(pb, False, "full")
     p = _point(pb, "full")
     J = ne.jones_r2c(p.reshape(shape)).reshape(M, kmax, N, 2, 2)
@@ -165,9 +172,9 @@ def test_sagefit_refines_as_the_plain_construction_does(rows):
 @pytest.mark.parametrize("kmax, rows", [(1, "periodic"), (1, "flat"),
                                         (2, "periodic")])
 def test_info_names_the_row_layout(driver, kmax, rows):
-    """``refine_rows``: what the mechanism decided from ``kmax``,
-    ``nbase`` and ``B``, as a host value beside ``plan``."""
-    pb = _problem(kmax)
+    """``refine_rows``: what the mechanism decided from ``nbase`` and
+    ``B``, whatever ``kmax``, as a host value beside ``plan``."""
+    pb = _rows_problem(kmax, rows)
     cfg = _cfg(nbase=ROWS[rows])
     args = [pb["x8"], pb["coh"], pb["sta1"], pb["sta2"], pb["cidx"],
             pb["cmask"], pb["J0"], N, pb["wt"]]
@@ -175,11 +182,20 @@ def test_info_names_the_row_layout(driver, kmax, rows):
         for i in (0, 1, 6, 8):
             args[i] = jnp.stack([args[i], args[i]])
     _J, info = getattr(sage, driver)(*args, config=cfg)
-    want = "periodic" if (kmax, rows) == (1, "periodic") else "flat"
-    assert info["refine_rows"] == want
+    assert info["refine_rows"] == rows
     _J, info = getattr(sage, driver)(*args,
                                      config=cfg._replace(max_lbfgs=0))
     assert "refine_rows" not in info
+
+
+def test_a_map_that_varies_inside_a_timeslot_is_refused_a_period():
+    """The map that alternates row by row, handed with ``nbase`` set, is
+    refused where it is concrete; at ``row_period=0`` it refines on flat
+    rows (``test_joint_pass_is_the_plain_construction``'s k2-flat)."""
+    pb = _problem(2)
+    with pytest.raises(ValueError, match="inside a timeslot"):
+        _planes(pb, True, "full", "periodic")
+    assert _planes(pb, True, "full", "flat")
 
 
 def test_tile_record_carries_refine_rows(tmp_path):

@@ -402,11 +402,11 @@ def _reference_cost(x8, coh, sta1, sta2, chunk_id, wt, K, N, mode, Jref,
     return cost
 
 
-# name: (chunks, row_period given): two chunks take the general scatter;
-# one chunk with the rows' period sums over time first and scatters
-# nbase rows; one chunk without a period scatters every row
-_ROW_LAYOUTS = {"K2": (2, True), "K1-period": (1, True),
-                "K1-flat": (1, False)}
+# name: (chunks, row_period given): with the rows' period a chunk's
+# timeslots are summed first and chunks x nbase rows scattered; without
+# one every row is scattered
+_ROW_LAYOUTS = {"K2": (2, True), "K2-flat": (2, False),
+                "K1-period": (1, True), "K1-flat": (1, False)}
 _GRAD_CASES = [(c, m) for c in ("gauss", "robust") for m in
                ("full", "diag", "phase")] + [("admm", "full"),
                                              ("robust-admm", "full")]
@@ -447,7 +447,7 @@ def test_written_out_gradient_matches_autodiff(layout, cost, mode):
                 jnp.asarray(rng.uniform(1.0, 5.0, size=K)))
     rows = ne.RowPlanes(x8, coh, wt, sta1, sta2, chunk_id, K, N,
                         nbase if period else 0)
-    assert rows.periodic == (layout == "K1-period")
+    assert rows.periodic == period
     row_pass, egrad = rtr_mod.make_row_pass(
         rows, K, N, admm=admm, robust_nu=nu, mode=mode, Jref=Jref)
     ck, e, shares = jax.jit(row_pass)(p)

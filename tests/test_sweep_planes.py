@@ -35,7 +35,7 @@ B = NBASE * TSZ
 RTR, LM = int(SolverMode.RTR_OSRLM_RLBFGS), int(SolverMode.LM_LBFGS)
 #: id -> (kmax, nbase handed to the solve, the layout it must decide on)
 LAYOUTS = {"k1-periodic": (1, NBASE, "periodic"), "k1-flat": (1, 0, "flat"),
-           "k2": (2, NBASE, "flat")}
+           "k2": (2, NBASE, "periodic"), "k2-flat": (2, 0, "flat")}
 DTYPES = {"f64": jnp.float64, "f32": jnp.float32}
 #: relative to the largest entry: rounding at f64, a trajectory of a
 #: dozen trust-region steps at f32, storage quantization at bf16
@@ -43,9 +43,12 @@ TOL = {"f64": 1e-9, "f32": 2e-3, "bf16": 0.1}
 
 
 @functools.lru_cache(maxsize=None)
-def _problem(kmax, dt="f64", seed=3):
-    """A tiny observation: M clusters, ``kmax`` hybrid chunks with a mixed
-    ``chunk_idx``, a tenth of the rows flagged to zero weight."""
+def _problem(kmax, dt="f64", seed=3, nbase=0):
+    """A tiny observation: M clusters, ``kmax`` hybrid chunks, a tenth of
+    the rows flagged to zero weight. Under a row period (``nbase``) the
+    chunk map is ``rime.predict.chunk_indices``' of ``kmax``, ``kmax``
+    and one chunk, the last cluster's other slots masked; on flat rows a
+    mixed ``chunk_idx`` that also varies inside a timeslot."""
     rdt = DTYPES[dt]
     cdt = jnp.complex128 if dt == "f64" else jnp.complex64
     rng = np.random.default_rng(seed)
@@ -54,7 +57,12 @@ def _problem(kmax, dt="f64", seed=3):
     coh = jnp.asarray(rng.normal(size=(M, B, 2, 2))
                       + 1j * rng.normal(size=(M, B, 2, 2)), cdt)
     cidx = np.zeros((M, B), np.int32)
-    if kmax > 1:
+    cmask = np.ones((M, kmax), bool)
+    if kmax > 1 and nbase:
+        nchunk = np.array([kmax, kmax, 1])
+        cidx = rp.chunk_indices(TSZ, NBASE, nchunk)
+        cmask = np.arange(kmax) < nchunk[:, None]
+    elif kmax > 1:
         cidx[0] = (np.arange(B) * kmax) // B
         cidx[1] = np.arange(B) % kmax
         cidx[2] = (np.arange(B) // NBASE) % kmax
@@ -67,7 +75,7 @@ def _problem(kmax, dt="f64", seed=3):
     wt = np.ones((B, 8))
     wt[rng.random(B) < 0.1] = 0.0
     return dict(x8=x8.astype(rdt), coh=coh, sta1=sta1, sta2=sta2,
-                cidx=jnp.asarray(cidx), cmask=jnp.ones((M, kmax), bool),
+                cidx=jnp.asarray(cidx), cmask=jnp.asarray(cmask),
                 J0=jnp.asarray(J0, cdt), Jt=jnp.asarray(Jt, cdt),
                 wt=jnp.asarray(wt, rdt), kmax=kmax)
 
@@ -163,7 +171,7 @@ def _plain_solve(layout, dt, sweeps, with_admm=False):
     """(J, res_0, res_1) of ``sweeps`` plain sweeps in the natural
     order."""
     kmax, nbase, _ = LAYOUTS[layout]
-    pb = _problem(kmax, dt)
+    pb = _problem(kmax, dt, nbase=nbase)
     cfg = _cfg(nbase)
     x8, xres, nuM = _plain_entry(pb, cfg)
     res_0 = jnp.linalg.norm(xres * pb["wt"]) / (8 * B)
@@ -203,7 +211,7 @@ def test_cluster_model_is_model8(layout, dt):
     against ``rime.predict.model8``, every cluster (float64: 1e-12)."""
     kmax, nbase, want = LAYOUTS[layout]
     store = jnp.bfloat16 if dt == "bf16" else None
-    pb = _problem(kmax, "f32" if store else dt)
+    pb = _problem(kmax, "f32" if store else dt, nbase=nbase)
     rows = ne.RowPlanes(None, pb["coh"], None, pb["sta1"], pb["sta2"],
                         pb["cidx"], kmax, N, nbase)
     assert ("periodic" if rows.periodic else "flat") == want
@@ -217,6 +225,23 @@ def test_cluster_model_is_model8(layout, dt):
                {"f64": 1e-12, "f32": 1e-5, "bf16": 1e-2}[dt], m)
 
 
+def test_a_map_that_varies_inside_a_timeslot_is_refused_a_period():
+    """The mixed chunk map of the flat layouts, handed with ``nbase``
+    set, is refused where it is concrete: by ``RowPlanes`` itself,
+    through the one check it makes (``planes.check_chunk_rows``)."""
+    from sagecal_tpu.rime import planes
+    pb = _problem(2)
+    args = (None, pb["coh"], None, pb["sta1"], pb["sta2"], pb["cidx"], 2, N)
+    with pytest.raises(ValueError, match="inside a timeslot"):
+        ne.RowPlanes(*args, NBASE)
+    with pytest.raises(ValueError, match="inside a timeslot"):
+        planes.check_chunk_rows(np.asarray(pb["cidx"]), NBASE)
+    assert not ne.RowPlanes(*args, 0).periodic
+    planes.check_chunk_rows(np.asarray(pb["cidx"]), 0)
+    planes.check_chunk_rows(
+        np.asarray(_problem(2, nbase=NBASE)["cidx"]), NBASE)
+
+
 # -- (b) one EM sweep ---------------------------------------------------------
 
 @functools.lru_cache(maxsize=None)
@@ -224,7 +249,7 @@ def _sweep_program(layout, dt, mode, fused):
     """``sage._em_sweep`` as one program of (J, xres planes, nuM, wt
     rows, perm), and what it starts from."""
     kmax, nbase, _ = LAYOUTS[layout]
-    pb = _problem(kmax, "f32" if dt == "bf16" else dt)
+    pb = _problem(kmax, "f32" if dt == "bf16" else dt, nbase=nbase)
     cfg = _cfg(nbase, mode, fuse_residual=fused,
                dtype_policy="bf16" if dt == "bf16" else "f32")
     iter_bar = int(-(-0.8 * M * cfg.max_iter // M))
@@ -244,6 +269,7 @@ def _sweep_program(layout, dt, mode, fused):
         LAYOUTS, (True, False), ("natural", "permuted"))],
     ("k1-periodic", "f64", LM, True, "permuted"),
     ("k2", "f64", LM, True, "permuted"),
+    ("k2-flat", "f64", LM, True, "permuted"),
     ("k1-periodic", "f32", RTR, True, "permuted"),
     ("k1-flat", "f32", RTR, True, "permuted"),
     ("k1-periodic", "bf16", RTR, True, "natural")],
@@ -297,7 +323,7 @@ def test_solve_is_the_plain_solve(driver, layout, dt):
     float64; one at float32, where a second sweep's trust region takes
     another branch on one rounding or the other."""
     kmax, nbase, want = LAYOUTS[layout]
-    pb = _problem(kmax, dt)
+    pb = _problem(kmax, dt, nbase=nbase)
     sweeps = 2 if dt == "f64" else 1
     Jw, r0, r1 = _plain_solve(layout, dt, sweeps,
                               with_admm=driver == "admm")
@@ -319,7 +345,7 @@ def test_residuals_do_not_depend_on_the_plan(layout, lbfgs):
     ``_jit_sagefit``'s own): bit for bit, which ``tests/test_overlap.py``
     counts on when the learner promotes between its two runs."""
     kmax, nbase, _ = LAYOUTS[layout]
-    pb = _problem(kmax, "f32")
+    pb = _problem(kmax, "f32", nbase=nbase)
     out = {}
     for plan in ("per_cluster", "fused", "promoted"):
         J, info = _drive(f"sagefit_host-{plan}", pb,
@@ -337,11 +363,11 @@ def test_residuals_do_not_depend_on_the_plan(layout, lbfgs):
 @pytest.mark.parametrize("driver", ["sagefit_host", "sagefit_host_tiles"])
 @pytest.mark.parametrize("layout", LAYOUTS)
 def test_info_names_the_sweep_layout(driver, layout):
-    """``sweep_rows``: what the mechanism decided from ``kmax``,
-    ``nbase`` and ``B``, as a host value beside ``plan``; absent from a
-    solve of no sweeps."""
+    """``sweep_rows``: what the mechanism decided from ``nbase`` and
+    ``B``, whatever ``kmax``, as a host value beside ``plan``; absent
+    from a solve of no sweeps."""
     kmax, nbase, want = LAYOUTS[layout]
-    pb = _problem(kmax)
+    pb = _problem(kmax, nbase=nbase)
     args = [pb["x8"], pb["coh"], pb["sta1"], pb["sta2"], pb["cidx"],
             pb["cmask"], pb["J0"], N, pb["wt"]]
     if driver == "sagefit_host_tiles":
@@ -349,7 +375,7 @@ def test_info_names_the_sweep_layout(driver, layout):
             args[i] = jnp.stack([args[i], args[i]])
     cfg = _cfg(nbase)._replace(max_emiter=1, promote="off", fuse="on")
     J, info = getattr(sage, driver)(*args, config=cfg)
-    assert info["sweep_rows"] == want == sage.sweep_rows(cfg, kmax, B)
+    assert info["sweep_rows"] == want == sage.sweep_rows(cfg, B)
     assert "sweep_rows" in sage._PLAN_KEYS
     if driver == "sagefit_host_tiles":
         # the planes under a leading tile axis: the one-tile solve twice

@@ -108,8 +108,10 @@ def build_parser() -> argparse.ArgumentParser:
            "host-prepare tile t+N on a background thread while tile t "
            "solves, residual/solution writes on an ordered writer "
            "thread (bit-identical outputs; default 1 = double-"
-           "buffered). 0 = fully synchronous reference loop — the "
-           "debugging escape hatch")
+           "buffered). The simulation modes -a 1|2|3 overlap the same "
+           "way (two tiles ahead at most), and keep one tile's program "
+           "queued behind the one that runs. 0 = fully synchronous "
+           "reference loop — the debugging escape hatch")
     a("--prior-cache", choices=("off", "read", "readwrite"),
       default="off",
       help="warm-start solution prior store (serve/priors.py): read = "

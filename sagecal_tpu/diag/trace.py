@@ -46,7 +46,8 @@ event            meaning / required extra fields
 ``tile``         one solve interval's convergence summary (pipeline.py /
                  cli_mpi.py): ``tile``, ``res_0``, ``res_1`` (a
                  simulated tile, ``run_simulation``, solves nothing and
-                 carries only ``tile`` and the overlap pair); optional
+                 carries ``tile``, the overlap pair, ``mode`` and
+                 ``clusters_in_model``); optional
                  ``mean_nu``, ``solver_iters``, ``cg_iters`` (inner CG
                  trips under them: LM's PCG, RTR's truncated-CG
                  bodies), ``row_passes`` (RTR's evaluations of the
@@ -107,7 +108,13 @@ the device), ``solve`` (the whole solve, to its read-backs), ``residual``
 the ordered writer, back-pressure included), ``record`` (info read-backs,
 history, ``tile`` and ``admm_iter`` records, log lines), ``primal`` (the
 consensus loop's ``B Z`` and norms on the host), and the simulation
-loop's ``stage``, ``predict``, ``fetch``, ``write``.  ``dispatch`` is one
+loop's ``stage``, ``predict`` (a tile's program dispatched), ``fetch``
+(the wait for the program and the copy: under ``--prefetch`` that of the
+tile BEFORE, whose program ran while this one was queued behind it; the
+last tile's is under a third root, ``drain``, once a run, after the
+dataset has ended) and ``write`` (``stage`` and ``write`` are the
+reader's and the writer's, ``bg``, unless ``--prefetch 0``).
+``dispatch`` is one
 device execution enqueued (``solvers/sage.py:_call`` makes one per
 execution, ``prog=`` its label; the mesh runner and the residual
 programs have theirs).  ``wait`` is the ONE name under which a host

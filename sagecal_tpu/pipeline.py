@@ -21,6 +21,7 @@ static per dataset); host streams tiles and writes residuals back.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import time
 
@@ -1082,49 +1083,141 @@ class FullBatchPipeline:
             "sim", lambda: jax.jit(sim_fn),
             pcache.token(ignore_mask, mode))
         sim_jit = self._sim_jit
-        # a synchronous loop, in the calibrate path's vocabulary: the
-        # phases io / stage / predict (a dispatch) / fetch (the wait
-        # for the device and the copy) / write, and per tile one
-        # ``tile`` record with bubble_s = io + write at overlap 0; the
-        # loop's thread is in "io" or in the root "step" at every instant
-        tiles = iter(ms.tiles())
-        while True:
-            with dtrace.phase("io") as ph_io:   # the tile id comes out
+        # the calibrate path's overlap under the calibrate path's
+        # vocabulary. At --prefetch 0 a synchronous loop: "io" (the
+        # next() on the dataset's tiles), then under the root "step"
+        # stage / predict (a dispatch) / fetch (the wait for the device
+        # and the copy) / write. At depth N the reader thread reads and
+        # stages N tiles ahead ("read" and "stage", bg), a tile's
+        # program is dispatched BEFORE the one before it is waited for
+        # (one runs, one is queued behind it: the chip goes from one to
+        # the next with no host in between), and the conversion and the
+        # write go to the ordered writer ("write", bg). Either way the
+        # loop's thread holds one root "io" and one root "step" a tile
+        # (overlapped, the last tile's program is waited for under a
+        # root "drain" once the dataset has ended), and a tile is on
+        # disk, in the order read, when this returns. Per tile one
+        # ``tile`` record: bubble_s is what the loop's thread was
+        # blocked on data movement, the io wait plus the write (depth
+        # 0) or plus the writer's back-pressure.
+        # No more than two tiles ahead and one write queued, whatever
+        # --prefetch says: with the tile being staged, the two whose
+        # programs are dispatched and the one being written that is
+        # depth + 5 <= 7 tiles between the read and the disk, under the
+        # eight disk tiles the benchmark's smallest dataset cycles
+        depth = min(self._prefetch_depth(None), 2)
+        bg = depth > 0      # stage and write are other threads'
+        scopes = _thread_scopes()
+        aw = sched.AsyncWriter(enabled=bg, maxsize=1, context=scopes)
+
+        def stage(ti, tile):
+            # transfers alone: an eager jnp operation here would queue
+            # behind the program that runs (sched.py, PERF.md section 5)
+            with dtrace.phase("stage", tile=ti, bg=bg):
+                J_r8 = None
+                if blocks_iter:
+                    J_r8 = jnp.asarray(utils.jones_c2r_np(
+                        blocks_iter[min(ti, len(blocks_iter) - 1)]),
+                        self.rdt)
+                return (jnp.asarray(utils.c2r(tile.x), self.rdt),
+                        jnp.asarray(tile.u, self.rdt),
+                        jnp.asarray(tile.v, self.rdt),
+                        jnp.asarray(tile.w, self.rdt),
+                        jnp.asarray(tile.sta1), jnp.asarray(tile.sta2),
+                        J_r8, self._tile_beam(tile))
+
+        # ms.tiles() is the seam a dataset overrides, so the reader
+        # pulls it and does not call read_tile(i)
+        rows = iter(ms.tiles())
+        pulled = []     # the tile read and not yet staged, or what
+        #                 the read raised
+
+        def produce(_j):
+            # the Prefetcher tries a transient failure again: the read
+            # is made once and kept until the tile is staged, so that a
+            # second try stages the SAME tile; and a generator that
+            # raised has ended, its next() would read as the end of the
+            # data, so what it raised is raised again
+            if not pulled:
                 try:
-                    ti, tile = next(tiles)
+                    pulled.append(next(rows))
                 except StopIteration:
-                    ph_io.drop()
-                    break
-                ph_io.set_tile(ti)
-            with dtrace.phase("step", tile=ti):
-                with dtrace.phase("stage"):
-                    J_r8 = None
-                    if blocks_iter:
-                        J_r8 = jnp.asarray(utils.jones_c2r_np(
-                            blocks_iter[min(ti, len(blocks_iter) - 1)]),
-                            self.rdt)
-                    args = (jnp.asarray(utils.c2r(tile.x), self.rdt),
-                            jnp.asarray(tile.u, self.rdt),
-                            jnp.asarray(tile.v, self.rdt),
-                            jnp.asarray(tile.w, self.rdt),
-                            jnp.asarray(tile.sta1), jnp.asarray(tile.sta2),
-                            J_r8, self._tile_beam(tile))
-                with dtrace.phase("predict"):
-                    out_r = sim_jit(*args)
-                with dtrace.phase("fetch"):
-                    # blocked on the program's execution; the copy is
-                    # fetch's own
-                    sched.wait_device(out_r)
-                    out = np.asarray(out_r)
-                with dtrace.phase("write") as ph_write:
-                    tile.x = utils.r2c(out).astype(np.complex128)
-                    ms.write_tile(ti, tile)
-                if dtrace.active():
-                    dtrace.emit("tile", tile=ti, overlap=0,
-                                bubble_s=ph_io.dur_s + ph_write.dur_s,
-                                mode=mode,
-                                clusters_in_model=clusters_in_model)
-                log(f"Timeslot: {ti} simulated (mode={mode})")
+                    raise sched.EndOfStream from None
+                except BaseException as e:
+                    pulled.append(e)
+            if isinstance(pulled[0], BaseException):
+                raise pulled[0]
+            ti, tile = pulled[0]
+            # depth 0: the read alone is "io", the step stages
+            args = stage(ti, tile) if bg else None
+            pulled.clear()
+            return ti, tile, args
+
+        def write(ti, tile, out):
+            with dtrace.phase("write", tile=ti, bg=bg) as ph:
+                tile.x = utils.r2c(out).astype(np.complex128)
+                ms.write_tile(ti, tile)
+            return ph.dur_s
+
+        def finish(ti, tile, out_r, io_wait):
+            with dtrace.phase("fetch", tile=ti):
+                # blocked on the program's execution; the copy is
+                # fetch's own (begun at the dispatch when overlapped)
+                sched.wait_device(out_r)
+                out = np.asarray(out_r)
+            blocked = (aw.submit(write, ti, tile, out) if bg
+                       else write(ti, tile, out))
+            if dtrace.active():
+                dtrace.emit("tile", tile=ti, overlap=depth,
+                            bubble_s=io_wait + blocked, mode=mode,
+                            clusters_in_model=clusters_in_model)
+            log(f"Timeslot: {ti} simulated (mode={mode})")
+
+        source = sched.Prefetcher(produce, None, depth=depth,
+                                  context=scopes)
+        try:
+            flying = None       # the tile dispatched and not yet fetched
+            for _j, (ti, tile, args), io_wait in source:
+                aw.check()      # writer failure -> fail at the boundary
+                with dtrace.phase("step", tile=ti):
+                    if args is None:
+                        args = stage(ti, tile)
+                    with dtrace.phase("predict"):
+                        out_r = sim_jit(*args)
+                        if bg:
+                            # now, so that the copy does not queue
+                            # behind the next tile's program
+                            sched.start_host_copy(out_r)
+                    if flying is not None:
+                        finish(*flying)
+                    flying = (ti, tile, out_r, io_wait)
+                    if not bg:
+                        finish(*flying)
+                        flying = None
+            if flying is not None:
+                with dtrace.phase("drain", tile=flying[0]):
+                    finish(*flying)
+        finally:
+            source.close()      # a loop that failed leaves the reader here
+            # every write has run when this returns; a failed one raises
+            aw.close()
+
+
+def _thread_scopes():
+    """A zero-arg context factory (``sched``'s ``context=``) that gives a
+    reader or a writer thread what is thread-local on the CALLING one:
+    the tracer its records go to (``serve`` routes a job's by
+    ``dtrace.scope``) and jax's default device (``fleet.device_scope``:
+    a reader that stages onto another device costs a silent copy a
+    tile). ``run_simulation`` is called inside its job's scopes and
+    starts its two threads itself."""
+    tracer, device = dtrace.get(), jax.config.jax_default_device
+
+    @contextlib.contextmanager
+    def scopes():
+        with dtrace.scope(tracer), jax.default_device(device):
+            yield
+    return scopes
 
 
 class _WarmTileProfile:

@@ -133,6 +133,24 @@ OVERTAKEN = {
     "test_hybrid.py::test_sound_tiny_cell_is_correct_and_reports_the_eight":
         "flat_row_passes.hyb is 0 and the layouts are periodic: a hybrid "
         "chunk is a run of whole timeslots and stays on planes (PR 45)",
+    # PR 46 overlaps the simulation loop: the reader thread stages, the
+    # ordered writer writes, and ``bubble_s`` is what the loop's thread
+    # was blocked on the two.  These two cases pinned the synchronous
+    # loop: the first looks for ``step/stage`` and ``step/write`` among
+    # the LOOP thread's paths (they are ``(other threads) read/stage``
+    # and ``(other threads) write`` now), the second ends in
+    # ``bubble_ms.predict`` within half of ``io_ms.predict`` (the loop
+    # no longer stands in the read and the write).  Everything else they
+    # guard is held by ``tests/test_predict_cell.py``; both still run,
+    # and fail in that one assertion.  PERF.md section 7 has the edit for
+    # the next ``benchmark`` issue.
+    "test_host_spans.py::test_a_rehearsal_cell_prints_both_tables"
+    "[cells.json-predict-tiny-1-paths1]":
+        "step/stage and step/write are the reader's and the writer's: "
+        "the simulation loop stages ahead and writes behind (PR 46)",
+    "test_scopes.py::test_tiny_cell_traced_end_to_end[predict-tiny]":
+        "bubble_ms.predict is the loop thread's blocked seconds, no "
+        "longer io + write = io_ms.predict (PR 46)",
 }
 
 

@@ -190,8 +190,16 @@ def test_setup_backend_hands_the_annotator_and_installs_the_listeners():
 # the simulation loop's vocabulary; the Prefetcher's own io phase
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("prefetch", [0, 1])
 def test_run_simulation_emits_its_phases_and_one_tile_record_per_tile(
-        tmp_path):
+        tmp_path, prefetch):
+    """``--prefetch 0`` is the synchronous loop (``bubble_s`` = io +
+    write); at ``--prefetch 1`` the reader stages ahead, a tile's
+    program is dispatched before the one before it is waited for, and
+    the write is the ordered writer's (``bubble_s`` = the io wait + the
+    submit's back-pressure). Either way the loop's thread holds one
+    root ``io`` and one root ``step`` a tile; overlapped, the last tile
+    is fetched under one root ``drain`` once the dataset has ended."""
     from sagecal_tpu import cli
     from test_diag import _make_sim_dataset
 
@@ -199,27 +207,63 @@ def test_run_simulation_emits_its_phases_and_one_tile_record_per_tile(
     tr = tmp_path / "sim.jsonl"
     rc = cli.main(["-d", str(msdir), "-s", str(sky_file),
                    "-c", str(sky_file) + ".cluster", "-a", "1",
-                   "--diag", str(tr)])
+                   "--prefetch", str(prefetch), "--diag", str(tr)])
     assert rc == 0
     recs = trace.read(str(tr))
     phases = [r for r in recs if r["ev"] == "phase"]
     for name in ("io", "stage", "predict", "fetch", "write"):
         got = [r for r in phases if r["name"] == name]
         assert len(got) == 3, (name, len(got))
-        if name != "io":        # the tile id comes out of next()
+        if name != "io" or prefetch:    # the tile id comes out of next()
             assert [r["tile"] for r in got] == [0, 1, 2]
     tiles = [r for r in recs if r["ev"] == "tile"]
     assert [r["tile"] for r in tiles] == [0, 1, 2]
-    by = {(r["name"], r.get("tile")): r["dur_s"] for r in phases}
+    assert all(r["overlap"] == prefetch for r in tiles)
+    # the loop's thread: a root io and a root step a tile, then the drain
+    loop = {r["thread"] for r in phases if r["name"] == "step"}
+    assert len(loop) == 1
+    roots = [r["name"] for r in sorted(phases, key=lambda r: r["tm"])
+             if r["parent"] is None and r["thread"] in loop]
+    assert roots == ["io", "step"] * 3 + ["drain"] * prefetch
+    by = {(r["name"], r.get("tile")): r for r in phases}
     ios = [r["dur_s"] for r in phases if r["name"] == "io"]
-    for k, r in enumerate(tiles):
-        assert r["overlap"] == 0
-        assert r["bubble_s"] == pytest.approx(ios[k] + by[("write", k)])
+    if prefetch == 0:
+        assert {r["thread"] for r in phases} == loop
+        for k, r in enumerate(tiles):
+            # the read's seconds are taken inside the io span
+            assert r["bubble_s"] == pytest.approx(
+                ios[k] + by[("write", k)]["dur_s"], abs=1e-4)
+            assert r["bubble_s"] >= by[("write", k)]["dur_s"]
+    else:
+        for name, thread in (("stage", "prefetch-read"),
+                             ("write", "async-writer")):
+            got = [r for r in phases if r["name"] == name]
+            assert all(r["bg"] and r["thread"] == thread for r in got)
+        # the job handed over in tile order: the k-th submit is tile k's
+        submits = sorted((r for r in phases if r["name"] == "submit"),
+                         key=lambda r: r["tm"])
+        assert len(submits) == 3
+        for k, r in enumerate(tiles):
+            # the blocked seconds lie inside the two spans they were
+            # taken in, and are not the writer's own seconds
+            assert 0.0 <= r["bubble_s"] <= (
+                ios[k] + submits[k]["dur_s"] + 1e-4)
+            assert r["bubble_s"] >= ios[k] - 1e-3
+        # one program in flight: tile k is dispatched before tile k-1
+        # is waited for, and every block on the device is a fetch's
+        for k in (1, 2):
+            fetch = by[("fetch", k - 1)]
+            assert by[("predict", k)]["tm"] <= fetch["tm"] - fetch["dur_s"]
+        ids = {r["id"]: r for r in phases}
+        waits = [r for r in phases if r["name"] == "wait"]
+        assert len(waits) == 3
+        assert all(ids[r["parent"]]["name"] == "fetch" for r in waits)
     # every record of the run lies on the perf_counter clock, in order
     tms = [r["tm"] for r in recs if r["ev"] != "phase"]
     assert tms == sorted(tms)
     st = trace.overlap_stats(recs)
-    assert st["tiles"] == 3 and st["overlap"] == 0 and st["bubble_s"] > 0
+    assert st["tiles"] == 3 and st["overlap"] == prefetch
+    assert st["bubble_s"] == pytest.approx(sum(r["bubble_s"] for r in tiles))
 
 
 def test_prefetcher_emits_the_consumers_io_phase_with_absolute_tile_ids(
@@ -420,7 +464,10 @@ def _cover(recs):
     assert len({r["thread"] for r in steps}) == 1
     roots = [r for r in ph.values() if r["parent"] is None
              and r["thread"] == steps[0]["thread"] and not r.get("bg")]
-    assert {r["name"] for r in roots} <= {"io", "step", "arrival_wait"}
+    # "drain": the overlapped simulation loop's last fetch, once a run
+    assert {r["name"] for r in roots} <= {"io", "step", "arrival_wait",
+                                          "drain"}
+    assert sum(r["name"] == "drain" for r in roots) <= 1
     shares = []
     for a, b in zip(steps, steps[1:]):
         t0, t1 = a["tm"] - a["dur_s"], b["tm"] - b["dur_s"]
@@ -494,6 +541,21 @@ def _make_calibrate(root):
     _make_sim_dataset(type(root)(root), n_tiles=4)
 
 
+def _make_simulate(root):
+    """More and larger tiles than ``_make_calibrate``: an overlapped
+    simulated tile of that size is a cycle of 4 ms, of which one turn of
+    the reader or the writer at the interpreter, between two spans of
+    the loop's, is a large share, and its four tiles are three cycles.
+    Read here (PR 46, four runs each): on an idle host the least
+    uncovered share of a cycle is 1.0-1.1 % on that dataset and
+    0.6-0.7 % on this one; beside eight busy processes 0.9, 1.0, 1.1 and
+    2.6 % of the 5 % allowed on that one (its cycles up to 40 %) and
+    0.6-1.0 % on this one."""
+    from test_diag import _make_sim_dataset
+    os.makedirs(root)
+    _make_sim_dataset(type(root)(root), n_stations=30, tilesz=8, n_tiles=24)
+
+
 def _make_consensus(root):
     import test_consensus_stepper as tcs
     tcs.make_observation(str(root))
@@ -502,7 +564,7 @@ def _make_consensus(root):
 @pytest.mark.parametrize("make, drive, parents", [
     (_make_calibrate, _calibrate, {"solve", "write"}),
     (_make_consensus, _consensus, {"solve", "stage", "write"}),
-    (_make_calibrate, _simulate, {"fetch"}),
+    (_make_simulate, _simulate, {"fetch"}),
 ], ids=["calibrate", "consensus", "simulate"])
 def test_step_and_io_cover_the_cycle_and_the_tracer_changes_nothing(
         tmp_path, make, drive, parents):

@@ -325,3 +325,256 @@ def test_sched_slow_writer_backpressure_bounded():
     assert time.perf_counter() - t0 >= 0.1
     assert blocked >= 0.1
     aw.close()
+
+
+# ---------------------------------------------------------------------------
+# the simulation loop (-a 1|2|3, pipeline.run_simulation): read and
+# stage ahead, one program in flight, the write behind
+# ---------------------------------------------------------------------------
+
+SIM_TILES = 5
+
+
+@pytest.fixture(scope="module")
+def sim_obs(tmp_path_factory):
+    """Five tiles, a solutions file with an interval a tile, an ignore
+    list that names the second cluster."""
+    from sagecal_tpu.io import solutions as sol
+
+    tmp = tmp_path_factory.mktemp("simloop")
+    msdir, skyf, clusf = _make_dataset(tmp, n_tiles=SIM_TILES)
+    sky = skymodel.read_sky_cluster(skyf, clusf, (41 / 60) * math.pi / 12,
+                                    40 * math.pi / 180, 150e6)
+    solf = str(tmp / "given.solutions")
+    with sol.SolutionWriter(solf, 150e6, 1e6, 0.5, 8, sky.n_clusters,
+                            sky.n_eff_clusters) as wr:
+        for i in range(SIM_TILES):
+            wr.write_interval(ds.random_jones(
+                sky.n_clusters, sky.nchunk, 8, seed=7 + i, scale=0.3),
+                sky.nchunk)
+    ignoref = tmp / "ignore.txt"
+    ignoref.write_text("1\n")
+    return {"ms": msdir, "sky": skyf, "clusters": clusf, "solutions": solf,
+            "ignore": str(ignoref)}
+
+
+def _run_sim(o, tmp_path, prefetch, mode=1, extra=()):
+    """``-a mode -p`` at ``--prefetch`` on a copy of the dataset: the
+    output column's tiles, as written."""
+    import shutil
+    msdir = str(tmp_path / f"sim-{mode}-{prefetch}.ms")
+    shutil.copytree(o["ms"], msdir)
+    cfg = cli.config_from_args(cli.build_parser().parse_args(
+        ["-d", msdir, "-s", o["sky"], "-c", o["clusters"], "-t", "4",
+         "-a", str(mode), "-p", o["solutions"],
+         "--prefetch", str(prefetch), *extra]))
+    pipeline.run(cfg, log=lambda *a: None)
+    return _corrected(msdir, SIM_TILES)
+
+
+@pytest.mark.parametrize("mode,ignore", [(1, False), (2, False), (3, True)],
+                         ids=["a1-p", "a2-p", "a3-p-z"])
+def test_simulation_output_is_bit_identical_across_prefetch(
+        sim_obs, tmp_path, mode, ignore):
+    extra = ("-z", sim_obs["ignore"]) if ignore else ()
+    want = _run_sim(sim_obs, tmp_path, 0, mode, extra)
+    assert all(np.abs(x).mean() > 0.1 for x in want)
+    for depth in (1, 2):
+        got = _run_sim(sim_obs, tmp_path, depth, mode, extra)
+        for a, b in zip(want, got):
+            assert np.array_equal(a, b)
+
+
+def test_simulation_writer_failure_raises_and_stops_the_writes(
+        sim_obs, tmp_path, monkeypatch):
+    """A ``write_tile`` that raises on tile 2 fails ``run_simulation``
+    with that exception and its frames; no later tile is written."""
+    real_write = ds.SimMS.write_tile
+    calls = []
+
+    def failing_write(self, i, tile, column=None):
+        calls.append(i)
+        if i == 2:
+            raise RuntimeError("injected MS write failure")
+        return real_write(self, i, tile, column=column)
+
+    import traceback
+    monkeypatch.setattr(ds.SimMS, "write_tile", failing_write)
+    for depth in (1, 0):
+        calls.clear()
+        before = set(threading.enumerate())     # other tests' threads
+        with pytest.raises(RuntimeError, match="injected MS write") as ei:
+            _run_sim(sim_obs, tmp_path, depth)
+        tb = "".join(traceback.format_tb(ei.value.__traceback__))
+        assert "failing_write" in tb
+        assert calls == [0, 1, 2]
+        # the reader and the writer it started are gone
+        assert not [t.name for t in set(threading.enumerate()) - before]
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_simulation_writes_in_the_order_read_and_all_before_it_returns(
+        sim_obs, tmp_path, monkeypatch, depth):
+    """A slow writer: ``write_tile`` is called in the order ``tiles()``
+    yielded, one call at a time, and the last call has returned when
+    ``run_simulation`` does."""
+    real_write, real_tiles = ds.SimMS.write_tile, ds.SimMS.tiles
+    read, began, ended = [], [], []
+
+    def tiles(self):
+        for i, tile in real_tiles(self):
+            read.append(i)
+            yield i, tile
+
+    def slow_write(self, i, tile, column=None):
+        assert len(began) == len(ended)     # one writer, one job at a time
+        began.append(i)
+        time.sleep(0.05)
+        out = real_write(self, i, tile, column=column)
+        ended.append(i)
+        return out
+
+    monkeypatch.setattr(ds.SimMS, "tiles", tiles)
+    monkeypatch.setattr(ds.SimMS, "write_tile", slow_write)
+    _run_sim(sim_obs, tmp_path, depth)
+    assert read == list(range(SIM_TILES))
+    assert began == read and ended == read
+
+
+@pytest.mark.parametrize("depth", [1, 0])
+def test_simulation_stages_the_same_tile_again_after_a_transient_failure(
+        sim_obs, tmp_path, monkeypatch, depth):
+    """``sched.Prefetcher`` tries a production that failed transiently
+    again: the second try has to stage the tile the first one read, not
+    the next one. Every tile is written, and what is written is what a
+    run without the fault writes."""
+    from sagecal_tpu import faults, utils
+
+    (tmp_path / "want").mkdir()
+    want = _run_sim(sim_obs, tmp_path / "want", 0)
+    real_c2r, real_write = utils.c2r, ds.SimMS.write_tile
+    staged, written = [], []
+
+    def c2r(x):     # the first call of stage
+        staged.append(len(staged))
+        if len(staged) == 3:
+            raise faults.TransientFault("injected staging failure")
+        return real_c2r(x)
+
+    def write_tile(self, i, tile, column=None):
+        written.append(i)
+        return real_write(self, i, tile, column=column)
+
+    monkeypatch.setattr(faults, "RETRY_BASE_S", 1e-3)
+    monkeypatch.setattr(ds.SimMS, "write_tile", write_tile)
+    monkeypatch.setattr(utils, "c2r", c2r)
+    if depth == 0:
+        # the step stages, outside the retried read: the failure is the
+        # run's, as it was before the loop was overlapped
+        with pytest.raises(faults.TransientFault, match="staging"):
+            _run_sim(sim_obs, tmp_path, depth)
+        assert written == [0, 1]
+        return
+    got = _run_sim(sim_obs, tmp_path, depth)
+    assert len(staged) == SIM_TILES + 1
+    assert written == list(range(SIM_TILES))
+    for a, b in zip(want, got):
+        assert np.array_equal(a, b)
+
+
+@pytest.mark.parametrize("depth", [1, 0])
+def test_simulation_read_failure_is_raised_and_not_read_as_the_end(
+        sim_obs, tmp_path, monkeypatch, depth):
+    """``tiles()`` is a generator: one that raised has ended, and the
+    ``next()`` of the Prefetcher's second try would read as a clean end
+    of the data. The failure is the run's; the tiles before it are
+    written."""
+    from sagecal_tpu import faults
+
+    real_write, real_tiles = ds.SimMS.write_tile, ds.SimMS.tiles
+    asked, written = [], []
+
+    def tiles(self):
+        for i, tile in real_tiles(self):
+            asked.append(i)
+            if i == 3:
+                raise OSError("flaky read")
+            yield i, tile
+
+    def write_tile(self, i, tile, column=None):
+        written.append(i)
+        return real_write(self, i, tile, column=column)
+
+    monkeypatch.setattr(faults, "RETRY_BASE_S", 1e-3)
+    monkeypatch.setattr(ds.SimMS, "tiles", tiles)
+    monkeypatch.setattr(ds.SimMS, "write_tile", write_tile)
+    with pytest.raises(OSError, match="flaky read"):
+        _run_sim(sim_obs, tmp_path, depth)
+    assert asked == [0, 1, 2, 3]
+    assert written == [0, 1, 2][:len(written)]    # in order, none after
+    if depth == 0:
+        assert written == [0, 1, 2]
+
+
+def test_simulation_keeps_under_eight_tiles_between_read_and_disk(
+        sim_obs, tmp_path, monkeypatch):
+    """A dataset that cycles its disk tiles, as the benchmark's does,
+    under a slow writer and ``--prefetch 5``: never more than seven
+    tiles read and not yet on disk, so a disk tile of eight is not read
+    again while the write of its last cycle is pending."""
+    real_write, real_read = ds.SimMS.write_tile, ds.SimMS.read_tile
+    cycles = 4 * SIM_TILES
+    read, ended, most = [], [], [0]
+
+    def tiles(self):
+        for k in range(cycles):
+            read.append(k)
+            most[0] = max(most[0], len(read) - len(ended))
+            yield k % SIM_TILES, real_read(self, k % SIM_TILES)
+
+    def slow_write(self, i, tile, column=None):
+        time.sleep(0.03)
+        out = real_write(self, i, tile, column=column)
+        ended.append(i)
+        return out
+
+    monkeypatch.setattr(ds.SimMS, "tiles", tiles)
+    monkeypatch.setattr(ds.SimMS, "write_tile", slow_write)
+    _run_sim(sim_obs, tmp_path, 5)
+    assert ended == [k % SIM_TILES for k in range(cycles)]
+    assert 4 <= most[0] <= 7, most
+
+
+def test_simulation_threads_take_the_callers_tracer_and_device(
+        sim_obs, tmp_path, monkeypatch):
+    """``serve`` runs a simulation job inside thread-local scopes (the
+    job's tracer, its device): the reader and the writer that
+    ``run_simulation`` starts have to be inside them too."""
+    import jax
+    from sagecal_tpu import utils
+    from sagecal_tpu.diag import trace as dtrace
+
+    device = jax.devices()[-1]
+    seen = []
+    real_c2r = utils.c2r
+
+    def c2r(x):     # called by stage, on the reader's thread
+        seen.append((threading.current_thread().name,
+                     jax.config.jax_default_device))
+        return real_c2r(x)
+
+    monkeypatch.setattr(utils, "c2r", c2r)
+    tracer = dtrace.Tracer(str(tmp_path / "job.jsonl"))
+    try:
+        with dtrace.scope(tracer), jax.default_device(device):
+            _run_sim(sim_obs, tmp_path, 1)
+    finally:
+        tracer.close()
+    assert seen == [("prefetch-read", device)] * SIM_TILES
+    phases = [r for r in dtrace.read(str(tmp_path / "job.jsonl"))
+              if r["ev"] == "phase"]
+    for name, thread in (("stage", "prefetch-read"),
+                         ("write", "async-writer")):
+        got = [r for r in phases if r["name"] == name]
+        assert len(got) == SIM_TILES
+        assert all(r["thread"] == thread for r in got)

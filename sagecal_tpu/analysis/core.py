@@ -81,8 +81,8 @@ RULES = {
 # — the injection/retry layer wraps every I/O seam's hot loop, and its
 # ``faults.active()`` gate is blessed alongside ``dtrace.active()`` /
 # ``obs.active()`` by _is_active_gate's ``.active`` suffix match;
-# ops/ joined in ISSUE 11 — the Pallas kernel bodies (coh_pallas,
-# sweep_pallas) ARE the hottest per-row code in the tree, and a
+# ops/ joined in ISSUE 11 — the Pallas kernel bodies (coh_pallas)
+# ARE the hottest per-row code in the tree, and a
 # reduced-dtype kernel accumulator is exactly the storage-accum bug
 # class: pl.pallas_call joined _TRACE_WRAPPERS so kernel bodies count
 # as traced)

@@ -123,24 +123,18 @@ def build_parser() -> argparse.ArgumentParser:
     a("--dtype-policy", choices=("f32", "bf16", "f16"), default="f32",
       help="storage dtype for the [B]-data (visibilities, weights, "
            "staged residual tiles, Wirtinger factors) with f32 "
-           "accumulation everywhere; f32 = bit-frozen default "
-           "(MIGRATION.md 'Dtype policy' for the per-policy tolerance "
-           "envelopes)")
+           "accumulation everywhere; f32 = the default, held to the "
+           "references' limits and not to bits (MIGRATION.md 'Dtype "
+           "policy' for the per-policy tolerance envelopes)")
     a("--inner", choices=("chol", "cg"), default="chol",
       help="inner linear solver for the damped Gauss-Newton step: "
            "chol = dense [K,8N,8N] assembly + batched Cholesky "
-           "(bit-reference); cg = matrix-free preconditioned CG "
+           "(the default); cg = matrix-free preconditioned CG "
            "(never forms the normal matrix; MIGRATION.md 'Inner "
            "linear solver')")
-    a("--kernel", choices=("xla", "pallas"), default="xla",
-      help="row-pass kernel for the per-cluster solve assembly: xla = "
-           "bit-frozen default; pallas = fused-sweep kernel (one "
-           "streaming [B]-pass per damping/TR iteration + B-"
-           "independent blocks matvec per cg trip; interpret-mode on "
-           "CPU; MIGRATION.md 'Pallas kernels')")
     a("--jones", choices=("full", "diag", "phase"), default="full",
       help="Jones parameterization for the solve: full = 2x2 complex "
-           "per station (bit-frozen default); diag = diagonal-only "
+           "per station (the default); diag = diagonal-only "
            "(4 real params/station, 4x4 Gram blocks); phase = "
            "phase-only per polarization (2 real params/station, 2x2 "
            "Gram blocks, retraction J*exp(i*theta)). Distinct from "
@@ -236,7 +230,6 @@ def config_from_args(args) -> RunConfig:
         solve_promote=args.solve_promote,
         cluster_inflight=args.inflight,
         solver_inner=args.inner,
-        solver_kernel=args.kernel,
         jones_mode=args.jones,
         dtype_policy=args.dtype_policy,
         tile_bucket=args.tile_bucket,

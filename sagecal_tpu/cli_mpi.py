@@ -81,7 +81,7 @@ def build_parser() -> argparse.ArgumentParser:
       help="solution prior store (serve/priors.py): 'read' seeds J0 "
            "and the per-cluster rho schedule from a matching banked "
            "run, 'readwrite' also banks this run's final solutions; "
-           "'off' (default) keeps the cold start bit-frozen. An "
+           "'off' (default) is the cold start, as without a store. An "
            "explicit -q/-G always wins over the prior.")
     a("-T", "--max-timeslots", type=int, default=0)
     a("-K", "--skip-timeslots", type=int, default=0)
@@ -148,17 +148,13 @@ def build_parser() -> argparse.ArgumentParser:
     a("--dtype-policy", choices=("f32", "bf16", "f16"), default="f32",
       help="storage dtype for visibilities/weights/Wirtinger factors "
            "with f32 accumulation (sagecal_tpu.dtypes; MIGRATION.md "
-           "'Dtype policy'). f32 = bit-frozen default")
+           "'Dtype policy'). f32 = the default, held to the "
+           "references' limits and not to bits")
     a("--inner", choices=("chol", "cg"), default="chol",
       help="inner linear solver for the per-cluster J-updates: chol = "
-           "dense [K,8N,8N] assembly (bit-reference); cg = matrix-free "
+           "dense [K,8N,8N] assembly (the default); cg = matrix-free "
            "preconditioned Krylov — melts the B-independent "
            "factorization floor at north-star N/M (PERF.md round 7)")
-    a("--kernel", choices=("xla", "pallas"), default="xla",
-      help="row-pass kernel for the per-cluster solve assembly: xla = "
-           "bit-frozen default; pallas = fused-sweep kernel "
-           "(ops/sweep_pallas.py; interpret-mode on CPU; PERF.md "
-           "round 11 for the measured cg trip-price melt)")
     a("--jones", choices=("full", "diag", "phase"), default="full",
       help="Jones parameterization (MIGRATION.md 'Jones modes'). "
            "Consensus ADMM requires 'full': the y/bz consensus "
@@ -334,7 +330,7 @@ def sage_config(args):
         solver_mode=int(SolverMode(args.solver_mode)),
         nulow=args.nulow, nuhigh=args.nuhigh,
         randomize=bool(args.randomize),
-        inflight=args.inflight, inner=args.inner, kernel=args.kernel,
+        inflight=args.inflight, inner=args.inner,
         dtype_policy=getattr(args, "dtype_policy", "f32"))
 
 
@@ -539,8 +535,6 @@ class ConsensusStepper:
                           max(int(vals[4]), 1))
             spatial_coords = csp.cluster_polar_coords(sky)
         self.spatialreg = spatialreg
-        from sagecal_tpu.ops import sweep_pallas
-        sweep_pallas.check_kernel(args.kernel)
         cfg = self.cfg = cadmm.ADMMConfig(
             n_admm=args.admm, npoly=args.npoly, poly_type=args.polytype,
             rho=rho0, adaptive_rho=bool(args.adaptive_rho),

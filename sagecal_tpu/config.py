@@ -150,19 +150,12 @@ class RunConfig:
     cluster_inflight: int = 1
     # --inner : inner linear solver for the damped Gauss-Newton step /
     # RTR Hessian operator (sage.SageConfig.inner): "chol" dense
-    # [K, 8N, 8N] assembly (bit-reference), "cg" matrix-free
+    # [K, 8N, 8N] assembly (the default), "cg" matrix-free
     # preconditioned Krylov — see MIGRATION.md "Inner linear solver"
     solver_inner: str = "chol"
-    # --kernel : row-pass kernel for the per-cluster solve assembly
-    # (sage.SageConfig.kernel): "xla" (bit-frozen default) | "pallas"
-    # (ops/sweep_pallas.py fused-sweep kernel — one streaming [B]-pass
-    # per damping/TR iteration + a B-independent blocks matvec per
-    # PCG/tCG trip; interpret-mode on CPU, compiled Mosaic on TPU;
-    # tolerance-gated parity — MIGRATION.md "Pallas kernels")
-    solver_kernel: str = "xla"
     # --jones : constrained-Jones parameterization for every solver
     # path (sage.SageConfig.jones_mode; normal_eq.JONES_MODES): "full"
-    # (2x2 complex, bit-frozen default) | "diag" (diagonal Jones, 4
+    # (2x2 complex, the default) | "diag" (diagonal Jones, 4
     # real params/station) | "phase" (phase-only diagonal, 2 real
     # params/station — retraction J = J0 * exp(i theta)). Non-full
     # modes shrink the per-baseline Gram blocks the assemblies emit
@@ -173,7 +166,7 @@ class RunConfig:
     jones_mode: str = "full"
     # --dtype-policy : storage dtype for the [B]-proportional data
     # (visibilities, weights, staged residual tiles, Wirtinger
-    # factors): "f32" (identity, bit-frozen default) | "bf16" | "f16".
+    # factors): "f32" (identity, the default) | "bf16" | "f16".
     # Accumulation stays f32 everywhere (sagecal_tpu.dtypes;
     # MIGRATION.md "Dtype policy" for the per-policy tolerance
     # envelopes and what never quantizes: solutions J, consensus
@@ -183,7 +176,7 @@ class RunConfig:
     # timeslots (whole zero-weight timeslot blocks; serve/cache.py) so
     # jobs whose shapes differ only in tilesz share one set of
     # compiled programs in the service's compile cache. 0 = off (exact
-    # shapes, the bit-frozen default); -1 = next power of two; an
+    # shapes, the default); -1 = next power of two; an
     # explicit value must be >= tilesz. Changing the bucket changes
     # the OS-subset partition, so outputs are bit-identical to a solo
     # run AT THE SAME BUCKET (MIGRATION.md "Service mode")

@@ -82,12 +82,12 @@ def pallas_cost(jfn, args, kwargs=None) -> dict:
 
     XLA's cost analysis cannot see inside a Mosaic-compiled
     ``pallas_call`` — on TPU the kernel lowers to an opaque custom call
-    priced at ~zero, silently dropping the fused sweep's traffic from
-    every per-trip figure. This walks the (pre-lowering) jaxpr instead:
-    each pallas_call carries its author's ``cost_estimate``
-    (ops/sweep_pallas.py provides one; absent that, bytes fall back to
-    the operand+result aval sizes — the same each-buffer-moves-once
-    convention as XLA's own figure, with flops unknown = 0).
+    priced at ~zero, silently dropping the kernel's traffic from every
+    figure. This walks the (pre-lowering) jaxpr instead: each
+    pallas_call carries its author's ``cost_estimate`` (absent that,
+    as in ops/coh_pallas.py, bytes fall back to the operand+result
+    aval sizes — the same each-buffer-moves-once convention as XLA's
+    own figure, with flops unknown = 0).
     INTERPRET-mode calls are skipped: the interpreter lowering is plain
     HLO, which cost_analysis already prices — adding the estimate there
     would double-count."""

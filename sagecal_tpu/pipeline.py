@@ -229,8 +229,6 @@ class FullBatchPipeline:
             coh_path = "pallas" if sky_rest is None else \
                 "pallas (hybrid: shapelet/disk/ring via XLA)"
         log(f"Coherency path: {coh_path}")
-        from sagecal_tpu.ops import sweep_pallas
-        sweep_pallas.check_kernel(getattr(cfg, "solver_kernel", "xla"))
         mode = effective_solver_mode(int(cfg.solver_mode), self.n)
         self.base_cfg = sage.SageConfig(
             max_emiter=cfg.max_em_iter, max_iter=cfg.max_iter,
@@ -242,7 +240,6 @@ class FullBatchPipeline:
             promote=getattr(cfg, "solve_promote", "auto"),
             inflight=max(1, int(getattr(cfg, "cluster_inflight", 1))),
             inner=getattr(cfg, "solver_inner", "chol"),
-            kernel=getattr(cfg, "solver_kernel", "xla"),
             jones_mode=getattr(cfg, "jones_mode", "full"),
             dtype_policy=self.dtype_policy,
             # rows are [tilesz, nbase] (io.dataset layout): lets the

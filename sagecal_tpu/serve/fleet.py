@@ -220,7 +220,6 @@ def _job_tokens(job) -> None:
             int(cfg.solver_mode), cfg.max_em_iter, cfg.max_iter,
             cfg.max_lbfgs, cfg.lbfgs_m, cfg.linsolv,
             getattr(cfg, "solver_inner", "chol"),
-            getattr(cfg, "solver_kernel", "xla"),
             getattr(cfg, "jones_mode", "full"),
             getattr(cfg, "dtype_policy", "f32"),
             int(cfg.beam_mode), bool(cfg.per_channel_bfgs),

@@ -77,19 +77,6 @@ class LMConfig(NamedTuple):
     inner: str = "chol"
     cg_tol: float = 0.1        # forcing eta: stop at ||r|| <= eta ||JTe||
     cg_maxiter: int = 25       # static PCG trip cap per damping iteration
-    # row-pass kernel for the normal-equation / matrix-free assembly:
-    # "xla" (bit-frozen default) or "pallas" — the fused-sweep kernel
-    # (ops/sweep_pallas.py): one streaming [B]-pass per damping
-    # iteration emitting per-baseline Gram blocks; under inner="chol"
-    # the damped system assembles+factors+solves straight from those
-    # blocks (sweep_pallas.solve_damped_blocks — the dense [K,8N,8N]
-    # matrix is never CARRIED across iterations), and under inner="cg"
-    # each PCG trip is a B-INDEPENDENT O(nbase) blocks matvec.
-    # Applies when the problem is single-chunk baseline-major
-    # (sweep_pallas.supported); falls back to the XLA path otherwise.
-    # Parity is tolerance-gated, not bit (MIGRATION.md "Pallas
-    # kernels")
-    kernel: str = "xla"
     # storage dtype policy (sagecal_tpu.dtypes): "f32" is the identity
     # (bit-frozen default); "bf16"/"f16" quantize the [B]-data and
     # Wirtinger-factor storage while every accumulator stays f32 —
@@ -107,12 +94,7 @@ class LMConfig(NamedTuple):
 
 class LMState(NamedTuple):
     p: jax.Array        # [K, 8N] real parameters
-    JTJ: jax.Array      # inner="chol": [K, 8N, 8N] normal matrix at p
-                        # (kernel="pallas": sweep_pallas.GNBlocks — the
-                        # B-independent per-baseline blocks; the dense
-                        # matrix only ever exists inside the fused
-                        # assemble+factor+solve, sweep_pallas.
-                        # solve_damped_blocks);
+    JTJ: jax.Array      # inner="chol": [K, 8N, 8N] normal matrix at p;
                         # inner="cg": normal_eq.GNFactors (matrix-free op)
     JTe: jax.Array      # [K, 8N] gradient at p
     mu: jax.Array       # [K]
@@ -255,11 +237,9 @@ def _solve_damped_cg(fac, JTe, mu, jitter, rho, sta1, sta2, chunk_id,
     zeroes, so they start converged) — the LM body passes its live mask
     so already-stopped chunks never drive extra trips under vmap.
 
-    ``fac`` is either normal_eq.GNFactors (kernel="xla": each matvec is
-    one [B]-row pass over the Wirtinger factors) or
-    sweep_pallas.GNBlocks (kernel="pallas": each matvec is one
-    B-independent O(nbase) pass over the per-baseline Gram blocks) —
-    the branch is trace-time static."""
+    ``fac`` is normal_eq.GNFactors or, in a constrained Jones mode,
+    GNFactorsMode: each matvec is one [B]-row pass over the Wirtinger
+    factors; the branch is trace-time static."""
     shift = mu + jitter + rho                          # [K], always > 0
     Lfac = ne.gn_precond_factor(fac.D, shift)
     b = JTe if active is None else jnp.where(active[:, None], JTe, 0.0)
@@ -267,13 +247,7 @@ def _solve_damped_cg(fac, JTe, mu, jitter, rho, sta1, sta2, chunk_id,
     tol2 = (eta * eta) * bnorm2
     tiny = jnp.asarray(1e-30, b.dtype)
 
-    if type(fac).__name__ == "GNBlocks":
-        from sagecal_tpu.ops import sweep_pallas as swp
-
-        def matvec(v):
-            return swp.gn_matvec_blocks(fac, v, sta1, sta2, n_stations,
-                                        shift=shift)
-    elif type(fac).__name__ == "GNFactorsMode":
+    if type(fac).__name__ == "GNFactorsMode":
         def matvec(v):
             return ne.gn_matvec_mode(fac, v, sta1, sta2, chunk_id,
                                      kmax, n_stations, shift=shift)
@@ -393,14 +367,6 @@ def lm_solve(x8, coh, sta1, sta2, chunk_id, wt, J0, n_stations: int,
     if chunk_mask is None:
         chunk_mask = jnp.ones((kmax,), bool)
     inner_cg = config.inner == "cg"
-    # kernel="pallas": the fused-sweep row pass (ops/sweep_pallas) when
-    # the problem shape supports it; anything else falls back to the
-    # XLA assembly silently (same results contract, different traffic)
-    swp = None
-    if config.kernel == "pallas":
-        from sagecal_tpu.ops import sweep_pallas as swp_mod
-        if swp_mod.supported(kmax, row_period, x8.shape[0]):
-            swp = swp_mod
 
     rho_aug = 0.0
     if admm is not None:
@@ -431,13 +397,6 @@ def lm_solve(x8, coh, sta1, sta2, chunk_id, wt, J0, n_stations: int,
         _tilesz = x8.shape[0] // row_period
         os_ntper = -(-_tilesz // int(os.n_subsets))
 
-    # fused block-Cholesky stage (kernel="pallas", inner="chol"): carry
-    # the B-independent per-baseline Gram blocks instead of the dense
-    # [K, 8N, 8N] matrix; the damped system assembles, factors and
-    # solves inside sweep_pallas.solve_damped_blocks each trip (the
-    # reduced OS fast path keeps its dense subset-sliced carry)
-    blocks_chol = swp is not None and not inner_cg and not os_ntper
-
     def nrm_eq(p, w=None, cw=None, os_subset=None):
         """Normal equations + acceptance cost from ONE row pass: ``w``
         weights JTJ/JTe (subset weights under OS), ``cw`` the cost
@@ -457,22 +416,11 @@ def lm_solve(x8, coh, sta1, sta2, chunk_id, wt, J0, n_stations: int,
                 op = op + admm_rho * jnp.eye(op.shape[-1], dtype=op.dtype)
                 cost = aug_cost(p, cost)
             return op, JTe, cost
-        if inner_cg or blocks_chol:
-            if swp is not None:
-                op, JTe, cost = swp.gn_blocks(
-                    x8, J, coh, sta1, sta2, chunk_id,
-                    wt if w is None else w, n_stations, kmax,
-                    row_period, cost_wt=cw, jones=mode)
-            else:
-                op, JTe, cost = ne.gn_factors_mode(
-                    x8, J, coh, sta1, sta2, chunk_id,
-                    wt if w is None else w, n_stations, kmax,
-                    mode=mode, cost_wt=cw, row_period=row_period)
-        elif swp is not None:
-            op, JTe, cost = swp.normal_equations_fused(
+        if inner_cg:
+            op, JTe, cost = ne.gn_factors_mode(
                 x8, J, coh, sta1, sta2, chunk_id,
-                wt if w is None else w, n_stations, kmax, row_period,
-                cost_wt=cw, jones=mode)
+                wt if w is None else w, n_stations, kmax,
+                mode=mode, cost_wt=cw, row_period=row_period)
         else:
             op, JTe, cost = ne.normal_equations_mode(
                 x8, J, coh, sta1, sta2, chunk_id,
@@ -481,9 +429,9 @@ def lm_solve(x8, coh, sta1, sta2, chunk_id, wt, J0, n_stations: int,
         if admm is not None:
             d = p - admm_bz
             JTe = JTe - admm_y - admm_rho * d
-            if not inner_cg and not blocks_chol:
-                # the blocks/matrix-free operators are never formed
-                # densely: their ADMM rho-term rides the solve shift
+            if not inner_cg:
+                # the matrix-free operator is never formed densely: its
+                # ADMM rho-term rides the solve shift
                 op = op + admm_rho * jnp.eye(op.shape[-1], dtype=op.dtype)
             cost = aug_cost(p, cost)
         return op, JTe, cost
@@ -519,7 +467,7 @@ def lm_solve(x8, coh, sta1, sta2, chunk_id, wt, J0, n_stations: int,
     else:
         JTJ0, JTe0, cost0 = nrm_eq(p0)
         live0 = jnp.ones((kmax,), bool)
-    if inner_cg or blocks_chol:
+    if inner_cg:
         # max diag of the (never-formed) dense matrix: the matrix
         # diagonal lives entirely in the station-diagonal blocks D, and
         # the chol path's ADMM += rho I rides the diag as a uniform
@@ -544,14 +492,6 @@ def lm_solve(x8, coh, sta1, sta2, chunk_id, wt, J0, n_stations: int,
                 s.JTJ, s.JTe, s.mu, config.jitter, rho_aug, sta1, sta2,
                 chunk_id, kmax, n_stations, row_period, config.cg_tol,
                 config.cg_maxiter, active=~s.stop & chunk_mask)
-        elif blocks_chol:
-            # fused assemble+factor+solve from the per-baseline blocks
-            # (the dense matrix exists only inside this call); same
-            # nonfinite -> boosted-jitter retry -> dp = 0 semantics
-            dp, ok = swp.solve_damped_blocks(
-                s.JTJ, s.JTe, s.mu, config.jitter, sta1, sta2,
-                n_stations, rho=rho_aug, reduced=reduced)
-            trips = jnp.zeros((), jnp.int32)
         else:
             dp, ok = _solve_damped(s.JTJ, s.JTe, s.mu, config.jitter,
                                    reduced=reduced)
@@ -596,19 +536,7 @@ def lm_solve(x8, coh, sta1, sta2, chunk_id, wt, J0, n_stations: int,
             adopt = accept | (~s.live & chunk_mask)
         else:
             adopt = accept
-        if (inner_cg or blocks_chol) and swp is not None:
-            # the blocks operator is per-(chunk, baseline) and
-            # B-independent: the per-chunk adopt select broadcasts over
-            # each leaf's leading K axis — a rejected chunk keeps its
-            # entering blocks, exactly the dense path's kept JTJ (and
-            # under the fused-chol stage this select is [K, nbase]-sized
-            # where the dense carry's was [K, 8N, 8N])
-            JTJ = jax.tree.map(
-                lambda new, old: jnp.where(
-                    adopt.reshape(adopt.shape + (1,) * (new.ndim - 1)),
-                    new, old),
-                JTJn, s.JTJ)
-        elif inner_cg:
+        if inner_cg:
             # the matrix-free operator carries per-ROW factors (MA/MB/w2
             # over [B]) next to the per-chunk D blocks: the per-chunk
             # adopt select maps onto rows through chunk_id — rows of a

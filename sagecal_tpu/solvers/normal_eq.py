@@ -139,7 +139,7 @@ class RowPlanes:
         """One cluster's row data back as rows, ``(x8 [B, 8], coh
         [B, 2, 2], wt [B, 8])``, for the assemblies that take those
         (:func:`normal_equations`, :func:`gn_factors`, the constrained
-        modes', ``ops/sweep_pallas``)."""
+        modes')."""
         return (self.to_rows(self.x), jones_r2c(self.to_rows(self.c)),
                 self.to_rows(self.w))
 

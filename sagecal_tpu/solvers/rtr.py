@@ -75,14 +75,6 @@ class RTRConfig(NamedTuple):
     # SAME linear operator to fp reordering, so unlike lm.py's
     # inexact-Newton path this changes traffic, not trajectory class.
     inner: str = "chol"
-    # row-pass kernel (lm.LMConfig.kernel): "xla" (bit-frozen default)
-    # or "pallas" — the fused-sweep assembly (ops/sweep_pallas.py).
-    # Under inner="cg" the tCG Hessian products then run on the
-    # B-independent per-baseline Gram blocks (one O(nbase) pass per
-    # product instead of a full [B]-row pass); under inner="chol" the
-    # dense assembly's [B]-pass fuses. Single-chunk baseline-major
-    # problems only (sweep_pallas.supported); XLA fallback otherwise
-    kernel: str = "xla"
     # storage dtype policy (sagecal_tpu.dtypes; see lm.LMConfig): the
     # [B]-data and Wirtinger-factor storage quantize under bf16/f16
     # while the manifold point, tangent vectors and every accumulator
@@ -434,14 +426,6 @@ def _rtr_rows(rows: ne.RowPlanes, J0, n_stations: int, chunk_mask,
         return project_tangent_mode(p, egrad(p, shares), kmax, n_stations,
                                     mode)
 
-    # kernel="pallas": fused-sweep assembly + blocks tCG products when
-    # the shape supports it (see RTRConfig.kernel); XLA otherwise
-    swp = None
-    if config.kernel == "pallas":
-        from sagecal_tpu.ops import sweep_pallas as swp_mod
-        if swp_mod.supported(kmax, row_period, rows.chunk_id.shape[0]):
-            swp = swp_mod
-
     admm_rho2 = None if admm is None else 2.0 * admm[2]
     station_planes = _mode_p2planes(mode, Jref, kmax, n_stations)
 
@@ -456,7 +440,7 @@ def _rtr_rows(rows: ne.RowPlanes, J0, n_stations: int, chunk_mask,
 
     # the one assembly of a full-Jones f32/f64 solve on rows with a
     # period: straight from the planes the solve already holds
-    planes_hess = (config.inner == "chol" and swp is None
+    planes_hess = (config.inner == "chol"
                    and mode == "full" and rows.periodic
                    and not dtp.is_reduced(rows.x.dtype))
     if not planes_hess:
@@ -482,8 +466,8 @@ def _rtr_rows(rows: ne.RowPlanes, J0, n_stations: int, chunk_mask,
         planes): its own evaluation of the row model's Wirtinger
         factors, elementwise, and a sum over each chunk's timeslots, of
         which XLA keeps what JTJ needs. Rows without a period,
-        ``inner="cg"``, ``kernel="pallas"`` and the constrained modes
-        take the ``[B, 8]`` assemblies of normal_eq / sweep_pallas.
+        ``inner="cg"`` and the constrained modes take the ``[B, 8]``
+        assemblies of normal_eq.
 
         Curvature model per residual element e (e already includes wt):
           gaussian  sum e^2:          f'' = 2          -> weights wt
@@ -508,23 +492,6 @@ def _rtr_rows(rows: ne.RowPlanes, J0, n_stations: int, chunk_mask,
         Jm = p_to_J(p)
         wt_eff = wt if robust_nu is None else rows.to_rows(w8)
         if config.inner == "cg":
-            if swp is not None:
-                # blocks operator: the fused sweep contracts the time
-                # axis into per-baseline Gram blocks ONCE per outer TR
-                # point, so every tCG product is a B-independent
-                # O(nbase) pass (sweep_pallas.gn_matvec_blocks)
-                fac, _, _ = swp.gn_blocks(x8, Jm, coh, sta1, sta2,
-                                          chunk_id, wt_eff, n_stations,
-                                          kmax, row_period, jones=mode)
-
-                def hv(v):
-                    Hv = 2.0 * swp.gn_matvec_blocks(fac, v, sta1, sta2,
-                                                    n_stations)
-                    if admm_rho2 is not None:
-                        Hv = Hv + admm_rho2 * v
-                    return project_tangent_mode(p, Hv, kmax, n_stations,
-                                                mode)
-                return hv
             # matrix-free operator: JTJ @ v straight from the Wirtinger
             # factors (one [B]-pass per product), never forming the
             # [K, 8N, 8N] matrix; the unused JTe/cost outputs are
@@ -552,11 +519,7 @@ def _rtr_rows(rows: ne.RowPlanes, J0, n_stations: int, chunk_mask,
                 return project_tangent_mode(p, Hv, kmax, n_stations,
                                             mode)
             return hv
-        if swp is not None:
-            JTJ, _, _ = swp.normal_equations_fused(
-                x8, Jm, coh, sta1, sta2, chunk_id, wt_eff, n_stations,
-                kmax, row_period, jones=mode)
-        elif mode == "full":
+        if mode == "full":
             JTJ, _, _ = ne.normal_equations(
                 x8, Jm, coh, sta1, sta2, chunk_id, wt_eff, n_stations,
                 kmax, row_period=row_period)

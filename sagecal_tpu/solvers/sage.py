@@ -136,9 +136,9 @@ def assemble_rows(config, B: int):
     chunk counts: the assembly on ``[tilesz, nbase]`` planes,
     ``normal_eq.plane_equations``) or ``"generic"`` (the ``[B, 8]``
     scatter assembly). None where the solves assemble no such matrix or
-    another code does (NSD, ``inner="cg"``, ``kernel="pallas"``, a
-    constrained Jones mode, a reduced storage dtype)."""
-    if (config.inner != "chol" or config.kernel != "xla"
+    another code does (NSD, ``inner="cg"``, a constrained Jones mode, a
+    reduced storage dtype)."""
+    if (config.inner != "chol"
             or config.jones_mode != "full" or config.dtype_policy != "f32"
             or int(config.solver_mode) == int(SolverMode.NSD_RLBFGS)):
         return None
@@ -300,19 +300,6 @@ class SageConfig(NamedTuple):
     inner: str = "chol"
     cg_tol: float = 0.1           # inexact-Newton forcing eta (lm.py)
     cg_maxiter: int = 25          # static PCG trip cap per damping iter
-    # row-pass kernel for the per-cluster normal-equation assembly and
-    # the inner="cg" matvec (--kernel; lm.LMConfig.kernel /
-    # rtr.RTRConfig.kernel): "xla" is the bit-frozen default; "pallas"
-    # runs the fused-sweep kernel (ops/sweep_pallas.py) — ONE streaming
-    # [B]-pass per damping/TR iteration emitting per-baseline Gram
-    # blocks, and a B-independent O(nbase) blocks matvec per PCG/tCG
-    # trip. Requires the baseline-major layout with a bounded hybrid-
-    # chunk count (sweep_pallas.supported — nbase set, kmax <=
-    # MAX_CHUNKS); other shapes fall back to the XLA path. Parity is
-    # tolerance-gated
-    # (MIGRATION.md "Pallas kernels"; the measured floor/trip-price
-    # deltas: older chip record, in git before PR 32)
-    kernel: str = "xla"
     # storage dtype policy (--dtype-policy; sagecal_tpu.dtypes): "f32"
     # is the bit-frozen identity; "bf16"/"f16" store the visibility
     # data, running residual and Wirtinger factors in the reduced dtype
@@ -456,7 +443,6 @@ def _cluster_solve(mode: int, rows: ne.RowPlanes, coh_m, cmask_m, wt_base,
     lm_cfg = lm_mod.LMConfig(itmax=itcap, inner=config.inner,
                              cg_tol=config.cg_tol,
                              cg_maxiter=config.cg_maxiter,
-                             kernel=config.kernel,
                              dtype_policy=config.dtype_policy,
                              jones_mode=config.jones_mode)
     nbase = int(config.nbase)
@@ -484,7 +470,6 @@ def _cluster_solve(mode: int, rows: ne.RowPlanes, coh_m, cmask_m, wt_base,
 
     if mode == int(SolverMode.RTR_OSLM_LBFGS):
         rtr_cfg = rtr_mod.RTRConfig(itmax=itcap, inner=config.inner,
-                                    kernel=config.kernel,
                                     dtype_policy=config.dtype_policy,
                                     jones_mode=config.jones_mode)
         Jn, info = rtr_mod.rtr_rows(
@@ -495,7 +480,6 @@ def _cluster_solve(mode: int, rows: ne.RowPlanes, coh_m, cmask_m, wt_base,
 
     if mode == int(SolverMode.RTR_OSRLM_RLBFGS):
         rtr_cfg = rtr_mod.RTRConfig(itmax=itcap, inner=config.inner,
-                                    kernel=config.kernel,
                                     dtype_policy=config.dtype_policy,
                                     jones_mode=config.jones_mode)
         Jn, nu_new, info = rtr_mod.rtr_rows_robust(

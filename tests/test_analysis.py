@@ -543,8 +543,7 @@ def test_storage_accum_pallas_kernel_flagged(tmp_path):
 def test_storage_accum_pallas_kernel_clean_twin(tmp_path):
     """The blessed kernel shape: quantize-at-load then upcast — the
     block read rounds to storage and IMMEDIATELY casts to the acc
-    dtype, so every accumulation below is f32 (ops/sweep_pallas.py's
-    q() boundary)."""
+    dtype, so every accumulation below is f32."""
     f, _ = _lint(tmp_path, """
     from jax.experimental import pallas as pl
     from sagecal_tpu import dtypes as dtp
@@ -567,7 +566,6 @@ def test_ops_scope_is_hot():
     """ISSUE 11 scope widening: ops/ (the Pallas kernels) is hot-path
     territory for the dtype/storage rules."""
     assert core.is_hot_path("sagecal_tpu/ops/coh_pallas.py")
-    assert core.is_hot_path("sagecal_tpu/ops/sweep_pallas.py")
 
 
 # ---------------------------------------------------------------------------

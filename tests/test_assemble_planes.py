@@ -372,8 +372,8 @@ def test_assemble_rows_follows_the_input(rows, nbase, want):
     """The period and the row count decide, whatever the chunk counts."""
     cfg = sage.SageConfig(nbase=nbase)
     assert sage.assemble_rows(cfg, rows) == want
-    for other in (dict(inner="cg"), dict(kernel="pallas"),
-                  dict(jones_mode="diag"), dict(dtype_policy="bf16")):
+    for other in (dict(inner="cg"), dict(jones_mode="diag"),
+                  dict(dtype_policy="bf16")):
         assert sage.assemble_rows(cfg._replace(**other), rows) is None
 
 

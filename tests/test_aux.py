@@ -6,6 +6,9 @@ import math
 import os
 import re
 import shlex
+import shutil
+import subprocess
+import sys
 
 import numpy as np
 import jax
@@ -334,3 +337,50 @@ def test_readme_command_line_is_accepted(module, argv):
 
 def test_readme_shows_every_entry_point():
     assert {m for m, _ in _COMMANDS} == set(_ENTRY_POINTS)
+
+
+# --- tools_dev/hlo_same.py ---------------------------------------------------
+
+_ROOT = os.path.dirname(_README)
+#: one multiply-add of ``planes.mm`` and the same with its first product
+#: doubled: the smallest edit that changes what the simulate program adds
+_MM_LINE = "out += [ar * br - ai * bi + cr * dr - ci * di,"
+_MM_EDITED = "out += [2.0 * ar * br - ai * bi + cr * dr - ci * di,"
+
+
+@pytest.mark.parametrize("edited, rc, verdict", [(False, 0, "same"),
+                                                 (True, 1, "DIFFERENT")])
+def test_hlo_same_tells_a_changed_program_from_an_unchanged(tmp_path, edited,
+                                                            rc, verdict):
+    """The witness a PR shows for "the chip's programs are the same
+    text": 0 for this tree against itself, 1 against a copy with one
+    product of ``rime/planes.py`` doubled, on the simulate program at two
+    timeslots. It compiles for a described v5e in processes of its own,
+    which a command that allows one load of the TPU library refuses
+    while another worker holds it: skipped there."""
+    other = _ROOT
+    if edited:
+        other = str(tmp_path / "tree")
+        shutil.copytree(os.path.join(_ROOT, "sagecal_tpu"),
+                        os.path.join(other, "sagecal_tpu"),
+                        ignore=shutil.ignore_patterns("__pycache__"))
+        os.mkdir(os.path.join(other, "tests"))
+        for name in ("test_chip_compile.py", "problems.py"):
+            shutil.copy(os.path.join(_ROOT, "tests", name),
+                        os.path.join(other, "tests", name))
+        planes = os.path.join(other, "sagecal_tpu", "rime", "planes.py")
+        text = open(planes).read()
+        assert text.count(_MM_LINE) == 1
+        with open(planes, "w") as f:
+            f.write(text.replace(_MM_LINE, _MM_EDITED))
+    run = subprocess.run(
+        [sys.executable, os.path.join(_ROOT, "tools_dev", "hlo_same.py"),
+         _ROOT, other, "simulate:2"],
+        text=True, stdout=subprocess.PIPE, stderr=subprocess.PIPE,
+        timeout=600,
+        # the texts it keeps of two programs that differ go with the test
+        env={**os.environ, "TMPDIR": str(tmp_path)})
+    if "libtpu" in run.stderr and "lockfile" in run.stderr:
+        pytest.skip("another process holds the TPU library")
+    assert run.returncode == rc, run.stderr[-2000:]
+    assert run.stdout.startswith(f"simulate:2: {verdict}"), run.stdout

@@ -11,11 +11,13 @@ run.
 
 The topology is described inside a module-scoped fixture, never at
 import: one process at a time may load the TPU library, and under
-pytest-xdist every worker imports every test file. Keep all such cases
-in THIS file (a second file would land on another worker, whose fixture
-then skips). ``sweep_pallas.sweep_blocks`` stays OUT while its TPU
-compile does not return (PERF.md "Bring-up on v5e"): there is no
-per-test time limit installed, so one such case would hang the suite.
+pytest-xdist every worker imports every test file. A second file of
+such cases lands on another worker, whose fixture skips unless the
+command allows several loads of the library (the driver's sets
+``ALLOW_MULTIPLE_LIBTPU_LOAD=1``): ``tests/test_chip_variants.py`` is the
+one such file, and uses this file's helpers. There is no per-test time
+limit installed: a compile that may not return gets one of its own
+(``test_folded_consensus_program_compiles_and_fits``).
 """
 
 import functools
@@ -37,14 +39,18 @@ HBM_BYTES = 16 * 2 ** 30        # one v5e chip
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -78,19 +84,6 @@ def test_coherency_kernel_compiles(one_chip, S):
     compiled = coh_pallas.coherencies_points.lower(
         sd((3, B), f32), sd((M, 3, S), f32), sd((M, 1, 4, S), f32),
         sd((M, 11, S), f32), sd((1,), f32), sd((), f32),
-        interpret=False).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-@pytest.mark.parametrize("md", [4, 2, 1])
-def test_blocks_matvec_kernel_compiles(one_chip, md):
-    from sagecal_tpu.ops import sweep_pallas
-    sd = _spec(one_chip)
-    f32, i32, K = jnp.float32, jnp.int32, 1
-    compiled = sweep_pallas._matvec_blocks_jit.lower(
-        sd((K, NB, 2, md, md), f32), sd((K, NB, 2, md, md), f32),
-        sd((K, NB, 2, 2, md, md), f32), sd((K, N * 2 * md), f32),
-        sd((NB,), i32), sd((NB,), i32), n_stations=N,
         interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
@@ -289,12 +282,13 @@ CEILING = {"refine": int(0.42 * 2 ** 30) + PADDED_TEMP_BYTES,
            "simulate": int(0.09 * 2 ** 30) + PADDED_MODEL_BYTES}
 
 
-def _lower_solve_program(one_chip, name, tilesz, m=M, kmax=1):
+def _lower_solve_program(one_chip, name, tilesz, m=M, kmax=1, **override):
     """``sagefit``, ``refine`` or ``cluster_update`` lowered for the
     described chip at ``tilesz * NB`` rows, ``m`` clusters of ``kmax``
     chunk slots, with the solver cells' flags (``-e 4 -g 2 -l 10 -m 7
-    -j 5``, N 62).  The sweep's running residual is handed to the
-    per-cluster update in the layout ``sage.sweep_rows`` says."""
+    -j 5``, N 62) but for the ``SageConfig`` fields ``override`` names.
+    The sweep's running residual is handed to the per-cluster update in
+    the layout ``sage.sweep_rows`` says."""
     from sagecal_tpu.config import SolverMode
     from sagecal_tpu.solvers import lm as lm_mod, sage
     sd = _spec(one_chip)
@@ -302,7 +296,7 @@ def _lower_solve_program(one_chip, name, tilesz, m=M, kmax=1):
     rows = tilesz * NB
     cfg = sage.SageConfig(nbase=NB)._replace(
         max_emiter=4, max_iter=2, max_lbfgs=10, lbfgs_m=7,
-        solver_mode=int(SolverMode.RTR_OSRLM_RLBFGS))
+        solver_mode=int(SolverMode.RTR_OSRLM_RLBFGS))._replace(**override)
     os_ids, os_nsub = lm_mod.os_subset_ids(tilesz, NB)
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
     flag = sd((), jnp.bool_)

@@ -116,6 +116,39 @@ def test_program_cost_and_classification():
     assert roofline.roofline_fields(mm, 1.0, dev)["bound"] == "compute"
 
 
+def test_pallas_cost_prices_compiled_kernels_only():
+    """A COMPILED pallas_call is priced by the jaxpr walk: from its
+    author's ``cost_estimate`` where it has one, else from the sizes of
+    its operands and results (``ops/coh_pallas.py`` gives none); an
+    interpret-mode call is skipped, because cost_analysis already prices
+    its HLO lowering; and ``program_cost`` folds the walk in."""
+    from jax.experimental import pallas as pl
+
+    def twice(x, interpret, estimate):
+        def kernel(x_ref, o_ref):
+            o_ref[...] = 2.0 * x_ref[...]
+        return pl.pallas_call(
+            kernel, out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+            interpret=interpret, cost_estimate=estimate)(x)
+
+    x = jnp.ones((8, 128), jnp.float32)
+    est = pl.CostEstimate(flops=1024, transcendentals=0,
+                          bytes_accessed=12345)
+    assert roofline.pallas_cost(lambda a: twice(a, False, est), (x,)) == {
+        "flops": 1024.0, "bytes_accessed": 12345.0}
+    # no estimate: the operand and the result, each moved once
+    assert roofline.pallas_cost(lambda a: twice(a, False, None), (x,)) == {
+        "flops": 0.0, "bytes_accessed": 2.0 * 8 * 128 * 4}
+    # under a cond's branches too (the tuple-of-jaxprs case)
+    both = roofline.pallas_cost(
+        lambda a: jax.lax.cond(a[0, 0] > 0, lambda: twice(a, False, est),
+                               lambda: a), (x,))
+    assert both["bytes_accessed"] == 12345.0
+    interp = jax.jit(lambda a: twice(a, True, est))
+    assert roofline.pallas_cost(interp, (x,)) == roofline.zero_cost()
+    assert roofline.program_cost(interp, (x,))["bytes_accessed"] > 0
+
+
 def test_cost_algebra():
     a = {"flops": 2.0, "bytes_accessed": 10.0}
     b = {"flops": 3.0, "bytes_accessed": 5.0}

@@ -235,7 +235,7 @@ def test_donated_ring_never_reads_a_donated_slot():
 
 
 def test_program_log_keeps_no_live_buffers(problem):
-    """jaxlint use-after-donate regression (ANALYSIS.md, PR 4): the
+    """jaxlint use-after-donate regression (found by the lint, PR 4): the
     sage program log stored the raw args of every logged program;
     several of those programs DONATE their carries, so the log pinned —
     and a later cost accounting re-read — buffers XLA had

@@ -9,8 +9,6 @@ The tentpole contract, tested from the algebra up:
   rho-shift and under the OS subset masking;
 - jones_mode="full" is bit-frozen: the mode entry points reproduce
   the pre-mode path bit-for-bit, solver included;
-- the pallas sweep's reduced per-baseline Gram blocks agree with the
-  xla mode path per mode (interpret lane), chol/matvec included;
 - on constrained-truth data every constrained solver path converges
   (the quality envelope of the constrained modes);
 - consensus ADMM refuses constrained modes loudly (the y/bz vectors
@@ -32,7 +30,6 @@ from sagecal_tpu.solvers import lm as lm_mod
 from sagecal_tpu.solvers import normal_eq as ne
 from sagecal_tpu.solvers import robust as rb
 from sagecal_tpu.solvers import rtr as rtr_mod
-from sagecal_tpu.ops import sweep_pallas as swp
 
 REL = 5e-12          # x64 machine-epsilon-scale relative parity
 
@@ -48,7 +45,7 @@ def _rel(a, b):
 
 def _geometry(N=5, nb=8, T=6, K=2, seed=0):
     """Tiled rows (row_period = nb), time-contiguous chunks — the
-    layout every solver path and the pallas sweep agree on."""
+    layout every solver path agrees on."""
     rng = np.random.default_rng(seed)
     s1 = np.array([0, 1, 2, 3, 4, 0, 1, 2])[:nb]
     s2 = np.array([1, 2, 3, 4, 0, 2, 3, 4])[:nb]
@@ -306,94 +303,12 @@ def test_full_mode_solver_bit_identical():
 
 
 # ---------------------------------------------------------------------------
-# pallas (interpret lane) vs xla, per mode
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("mode", (
-    pytest.param("full", marks=pytest.mark.slow),
-    pytest.param("diag", marks=pytest.mark.slow),
-    "phase",
-))
-def test_pallas_matches_xla_per_mode(mode):
-    """The trace-time-specialized sweep emits the SAME reduced system
-    as the xla mode path: dense assembly, per-baseline Gram blocks,
-    block matvec under a rho-shift, and the fused block-chol solve.
-
-    The phase leg (the headline 2x2 melt) stays in the CI fail-fast
-    lane; full/diag ride the slow set per the round-17 tier-1 budget
-    rule (the full leg re-checks what test_sweep_pallas already
-    gates pre-mode)."""
-    g = _geometry()
-    wt = _weights("irls", g)
-    cwt = jnp.asarray(np.abs(g["rng"].normal(size=(g["B"], 8))) + 0.1)
-    Jc = ne.jones_constrain(g["Jfull"], mode)
-    npar = ne.jones_npar(mode)
-    JTJx, JTex, costx = ne.normal_equations_mode(
-        g["x8"], Jc, g["coh"], g["sta1"], g["sta2"], g["chunk"], wt,
-        g["N"], g["K"], mode, cost_wt=cwt)
-    JTJp, JTep, costp = swp.normal_equations_fused(
-        g["x8"], g["Jfull"], g["coh"], g["sta1"], g["sta2"],
-        g["chunk"], wt, g["N"], g["K"], g["nb"], cost_wt=cwt,
-        interpret=True, jones=mode)
-    TOL = 1e-10
-    assert _rel(JTJx, JTJp) < TOL
-    assert _rel(JTex, JTep) < TOL
-    assert _rel(costx, costp) < TOL
-    fac, JTe2, cost2 = swp.gn_blocks(
-        g["x8"], g["Jfull"], g["coh"], g["sta1"], g["sta2"],
-        g["chunk"], wt, g["N"], g["K"], g["nb"], cost_wt=cwt,
-        interpret=True, jones=mode)
-    assert fac.D.shape[-1] == ne.jones_mdim(mode)   # reduced blocks
-    assert _rel(JTex, JTe2) < TOL and _rel(costx, cost2) < TOL
-    v = jnp.asarray(g["rng"].normal(size=(g["K"], npar * g["N"])))
-    sh = jnp.asarray([0.3, 0.7][:g["K"]])
-    y = swp.gn_matvec_blocks(fac, v, g["sta1"], g["sta2"], g["N"],
-                             shift=sh, interpret=True)
-    yref = (np.einsum("kij,kj->ki", np.asarray(JTJx), np.asarray(v))
-            + np.asarray(sh)[:, None] * np.asarray(v))
-    assert _rel(yref, y) < TOL
-    mu = jnp.asarray([0.5, 0.9][:g["K"]])
-    dp, ok = swp.solve_damped_blocks(fac, JTex, mu, 1e-9, g["sta1"],
-                                     g["sta2"], g["N"])
-    Aref = (np.asarray(JTJx) + (np.asarray(mu) + 1e-9)[:, None, None]
-            * np.eye(npar * g["N"]))
-    dpref = np.linalg.solve(Aref, np.asarray(JTex)[..., None])[..., 0]
-    assert _rel(dpref, dp) < 1e-8 and bool(np.all(np.asarray(ok)))
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("mode", ("full", "diag", "phase"))
-def test_pallas_vmap_visits_per_mode(mode):
-    """The sweep dispatch stays correct under vmap over visits (the
-    multi-visit batching lane), per mode."""
-    g = _geometry(K=1)
-    wt = jnp.ones((g["B"], 8))
-    V = 2
-    rng = g["rng"]
-    Jv = jnp.asarray(rng.normal(size=(V, 1, g["N"], 2, 2))
-                     + 1j * rng.normal(size=(V, 1, g["N"], 2, 2)))
-    x8v = jnp.asarray(rng.normal(size=(V, g["B"], 8)))
-
-    def f(x8, J):
-        return swp._sweep_dispatch(x8, J, g["coh"], g["sta1"],
-                                   g["sta2"], g["chunk"], wt, wt,
-                                   g["nb"], 1, 0, True, mode)
-
-    outs_v = jax.vmap(f)(x8v, Jv)
-    for i in range(V):
-        outs_s = f(x8v[i], Jv[i])
-        for j in range(len(outs_s)):
-            assert float(np.abs(np.asarray(outs_v[j][i])
-                                - np.asarray(outs_s[j])).max()) < 1e-10
-
-
-# ---------------------------------------------------------------------------
 # constrained-truth convergence envelope
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("mode", ("diag", "phase"))
 def test_constrained_truth_convergence_fast(mode):
-    """CI fail-fast subset: the default (xla, chol) constrained solve
+    """CI fail-fast subset: the default (chol) constrained solve
     drives a constrained truth to numerical zero."""
     g = _geometry(K=1)
     x8, J0, _ = _constrained_truth(g, mode)
@@ -410,22 +325,20 @@ def test_constrained_truth_convergence_fast(mode):
 @pytest.mark.slow
 @pytest.mark.parametrize("mode", ("diag", "phase"))
 def test_constrained_convergence_grid_lm(mode):
-    """The full kernel x inner x OS grid converges on a constrained
-    truth — every reduced-block lane, not just the default."""
+    """The full inner x OS grid converges on a constrained truth —
+    every reduced-block lane, not just the default."""
     g = _geometry(K=1)
     x8, J0, _ = _constrained_truth(g, mode)
     wt = jnp.ones((g["B"], 8))
-    for kernel in ("xla", "pallas"):
-        for inner in ("chol", "cg"):
-            cfg = lm_mod.LMConfig(itmax=25, jones_mode=mode,
-                                  inner=inner, kernel=kernel)
-            _, info = lm_mod.lm_solve(x8, g["coh"], g["sta1"],
-                                      g["sta2"], g["chunk"], wt, J0,
-                                      g["N"], config=cfg,
-                                      row_period=g["nb"])
-            init = float(np.ravel(np.asarray(info["init_cost"]))[0])
-            final = float(np.ravel(np.asarray(info["final_cost"]))[0])
-            assert final < 1e-6 * init, (kernel, inner, final / init)
+    for inner in ("chol", "cg"):
+        cfg = lm_mod.LMConfig(itmax=25, jones_mode=mode, inner=inner)
+        _, info = lm_mod.lm_solve(x8, g["coh"], g["sta1"],
+                                  g["sta2"], g["chunk"], wt, J0,
+                                  g["N"], config=cfg,
+                                  row_period=g["nb"])
+        init = float(np.ravel(np.asarray(info["init_cost"]))[0])
+        final = float(np.ravel(np.asarray(info["final_cost"]))[0])
+        assert final < 1e-6 * init, (inner, final / init)
     osid, nsub = lm_mod.os_subset_ids(g["T"], g["nb"], 3)
     os = lm_mod.OSConfig(os_id=jnp.asarray(osid), n_subsets=nsub,
                          key=jax.random.PRNGKey(0))
@@ -447,23 +360,21 @@ def test_constrained_convergence_rtr_nsd(mode):
     g = _geometry(K=1)
     x8, J0, _ = _constrained_truth(g, mode)
     wt = jnp.ones((g["B"], 8))
-    for kernel in ("xla", "pallas"):
-        cfg = rtr_mod.RTRConfig(itmax=30, kernel=kernel,
-                                jones_mode=mode)
-        Jf, info = rtr_mod.rtr_solve(x8, g["coh"], g["sta1"],
-                                     g["sta2"], g["chunk"], wt, J0,
-                                     g["N"], config=cfg,
-                                     row_period=g["nb"])
-        final = float(np.ravel(np.asarray(info["final_cost"]))[0])
-        init = float(np.ravel(np.asarray(info["init_cost"]))[0])
-        assert final < 1e-6 * init, (kernel, final / init)
-        # the retracted solution STAYS in the constraint set
-        Jf = np.asarray(Jf)
-        assert np.abs(Jf[..., 0, 1]).max() == 0.0
-        assert np.abs(Jf[..., 1, 0]).max() == 0.0
-        if mode == "phase":
-            assert _rel(np.abs(np.diagonal(Jf, axis1=-2, axis2=-1)),
-                        np.ones((1, g["N"], 2))) < 1e-6
+    cfg = rtr_mod.RTRConfig(itmax=30, jones_mode=mode)
+    Jf, info = rtr_mod.rtr_solve(x8, g["coh"], g["sta1"],
+                                 g["sta2"], g["chunk"], wt, J0,
+                                 g["N"], config=cfg,
+                                 row_period=g["nb"])
+    final = float(np.ravel(np.asarray(info["final_cost"]))[0])
+    init = float(np.ravel(np.asarray(info["init_cost"]))[0])
+    assert final < 1e-6 * init, final / init
+    # the retracted solution STAYS in the constraint set
+    Jf = np.asarray(Jf)
+    assert np.abs(Jf[..., 0, 1]).max() == 0.0
+    assert np.abs(Jf[..., 1, 0]).max() == 0.0
+    if mode == "phase":
+        assert _rel(np.abs(np.diagonal(Jf, axis1=-2, axis2=-1)),
+                    np.ones((1, g["N"], 2))) < 1e-6
     ncfg = rtr_mod.NSDConfig(itmax=50, jones_mode=mode)
     _, _, info = rtr_mod.nsd_solve_robust(x8, g["coh"], g["sta1"],
                                           g["sta2"], g["chunk"], wt,

@@ -9,7 +9,7 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from sagecal_tpu import cli, pipeline, skymodel
+from sagecal_tpu import cli, cli_mpi, pipeline, skymodel
 from sagecal_tpu.config import SimulationMode
 from sagecal_tpu.io import dataset as ds, solutions as sol
 from sagecal_tpu.rime import predict as rp
@@ -217,3 +217,31 @@ def test_coherency_kernel_failure_raises_no_fallback(monkeypatch,
     with pytest.raises(Exception):
         pipe.run(log=lines.append)
     assert not any("XLA path" in ln for ln in lines)
+
+
+@pytest.mark.parametrize("parser, ms", [(cli, "-d"), (cli_mpi, "-f")],
+                         ids=["cli", "cli_mpi"])
+def test_parser_refuses_the_removed_kernel_option(capsys, parser, ms):
+    """``--kernel`` went with the fused-sweep path (PR 47): no alias, no
+    flag that is parsed and ignored, in either executable."""
+    with pytest.raises(SystemExit) as e:
+        parser.build_parser().parse_args(
+            [ms, "x.ms", "-s", "sky.txt", "-c", "sky.txt.cluster",
+             "--kernel", "xla"])
+    assert e.value.code == 2
+    assert "--kernel" in capsys.readouterr().err
+
+
+@pytest.mark.parametrize("config", ["config.RunConfig",
+                                    "solvers.sage.SageConfig",
+                                    "solvers.lm.LMConfig",
+                                    "solvers.rtr.RTRConfig"])
+def test_no_config_takes_the_removed_kernel_field(config):
+    """The field went from all four configs: an embedder that still
+    passes it is told so, by the constructor, and not served silently."""
+    import importlib
+    module, name = config.rsplit(".", 1)
+    cls = getattr(importlib.import_module("sagecal_tpu." + module), name)
+    for field in ("kernel", "solver_" + "kernel"):
+        with pytest.raises(TypeError, match="unexpected keyword"):
+            cls(**{field: "xla"})

@@ -166,8 +166,7 @@ def test_sage_residual_never_catastrophic():
     assert float(info["res_1"]) <= float(info["res_0"])
 
 
-@pytest.mark.slow  # ~33 s (round-17 tier-1 rebalance, wave 2;
-# the stricter kernel-parity gates in test_sweep_pallas stay fast)
+@pytest.mark.slow  # ~33 s (round-17 tier-1 rebalance, wave 2)
 def test_fused_residual_sweep_parity():
     """SageConfig.fuse_residual folds each visit's re-subtract and the
     next visit's add-back into one pass over the running residual; the

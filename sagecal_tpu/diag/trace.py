@@ -67,7 +67,12 @@ event            meaning / required extra fields
                  ``M * kmax`` that the Jones ``[M, kmax, N, 2, 2]``
                  carries and every cluster's solve factorises, and
                  the ``sum(nchunk)`` of them that are solutions:
-                 pipeline.py), ``plan`` and
+                 pipeline.py), ``coh_path`` ("xla" or "pallas": what
+                 the run's ``Coherency path:`` line says),
+                 ``beam_mode`` (``-B``) and, with a beam,
+                 ``beam_elements`` (``Emax``, the element slots a
+                 station carries) and ``beam_sources`` (the live
+                 sources whose gains the tables hold), ``plan`` and
                  ``solve_dispatches`` (what sagefit_host's last sweep
                  executed, "promoted", "fused" or "per_cluster", and
                  the device executions the solve issued), ``minutes``,
@@ -122,7 +127,10 @@ thread is blocked on the device's execution, on the loop's thread (under
 ``solve``, ``fetch``) and on a background one (under ``stage``,
 ``write``; ``sched.wait_device``): a span's seconds less its ``wait``
 children are the host's own.  Background threads keep ``read``,
-``stage``, ``write``, ``arrival_wait`` with ``bg``.  A span's self time
+``stage``, ``write``, ``arrival_wait`` with ``bg``.  Under ``stage``
+with ``-B``: ``beam`` (``pipeline._tile_beam``: the tile's ``gmst`` track
+made on the host and copied; the beam's other leaves were staged at
+construction).  A span's self time
 is its ``dur_s`` less its children's, by ``id``/``parent``.
 
 Records are kept in memory and written by :meth:`Tracer.close` (so

@@ -61,7 +61,8 @@ RES_RATIO = 5.0  # fullbatch_mode.cpp:239
 
 
 def _emit_tile_record(ti, res_0, res_1, mean_nu, info, minutes,
-                      bubble_s=None, overlap=None, cmask=None):
+                      bubble_s=None, overlap=None, cmask=None,
+                      coh=None):
     """Per-solve-interval convergence record (gated on an active tracer
     / metrics registry so the extra device->host syncs never run
     otherwise). ``bubble_s`` / ``overlap`` are the overlapped-execution
@@ -70,7 +71,8 @@ def _emit_tile_record(ti, res_0, res_1, mean_nu, info, minutes,
     reference loop). ``cmask`` is the pipeline's ``[M, kmax]`` mask of
     live hybrid chunks: the record says how many chunk slots every
     cluster's Jones carries and how many of them the cluster file
-    asked for."""
+    asked for. ``coh`` is the pipeline's ``coh_record``: which coherency
+    path it chose and, with a beam, the beam's sizes."""
     if not (dtrace.active() or obs.active()):
         return
     trips = lm_mod.executed_trips(info)
@@ -108,6 +110,7 @@ def _emit_tile_record(ti, res_0, res_1, mean_nu, info, minutes,
         rec["kmax"] = int(cmask.shape[1])
         rec["chunk_slots"] = int(cmask.size)
         rec["chunk_slots_live"] = int(cmask.sum())
+    rec.update(coh or {})
     dtrace.emit("tile", **rec)
 
 
@@ -229,6 +232,20 @@ class FullBatchPipeline:
             coh_path = "pallas" if sky_rest is None else \
                 "pallas (hybrid: shapelet/disk/ring via XLA)"
         log(f"Coherency path: {coh_path}")
+        # host values for every ``tile`` record (diag/trace.py)
+        self.coh_record = dict(
+            coh_path="pallas" if self.use_pallas else "xla",
+            beam_mode=self.dobeam)
+        # the beam's static leaves (stations, elements, pattern, pointing)
+        # staged ONCE, after the precession above; a tile restages its
+        # gmst track alone (_tile_beam), as cli_mpi does
+        self._beam_static = None
+        if self.dobeam:
+            self._beam_static = bm.beam_to_device(
+                self.beam_info, meta["freq0"], self.rdt)
+            self.coh_record.update(
+                beam_elements=int(self.beam_info.elem_mask.shape[1]),
+                beam_sources=int(np.sum(sky.smask)))
         mode = effective_solver_mode(int(cfg.solver_mode), self.n)
         self.base_cfg = sage.SageConfig(
             max_emiter=cfg.max_em_iter, max_iter=cfg.max_iter,
@@ -549,16 +566,21 @@ class FullBatchPipeline:
         self.precessed = True
         log(f"Precessed source/beam coordinates to JD {jd:.5f}")
 
-    def _tile_beam(self, tile):
-        """Per-tile device beam tables (times change per tile)."""
+    def _tile_beam(self, tile, ti=None):
+        """A tile's device beam: the leaves staged at construction with
+        this tile's ``gmst`` track, the one leaf that changes."""
         if not self.dobeam:
             return None
         if tile.time_mjd is None and not self._warned_no_times:
             self.log("WARNING: dataset tiles carry no timestamps; beam "
                      "az/el will be evaluated at the J2000 placeholder epoch")
             self._warned_no_times = True
-        return bm.beam_to_device(self.beam_info, self.ms.meta["freq0"],
-                                 self.rdt, time_jd=tile.time_jd)
+        with dtrace.phase("beam", tile=ti):
+            tj = tile.time_jd
+            gmst = coords.jd2gmst_np(
+                self.beam_info.time_jd if tj is None else tj)
+            return self._beam_static._replace(
+                gmst=jnp.asarray(gmst, self.rdt))
 
     def _correct_idx(self):
         """-k cluster id -> padded-array index (or None)."""
@@ -821,7 +843,7 @@ class FullBatchPipeline:
                        sta1=jnp.asarray(tile.sta1),
                        sta2=jnp.asarray(tile.sta2),
                        # staged once: solve + residual write reuse it
-                       beam=self._tile_beam(tile), bubble=0.0)
+                       beam=self._tile_beam(tile, ti), bubble=0.0)
             if write_residuals:
                 # the residual program DONATES its staged visibility
                 # input; the ring keeps overlapped staging from ever
@@ -873,7 +895,7 @@ class FullBatchPipeline:
                             "mean_nu": mean_nu, "minutes": minutes})
             _emit_tile_record(ti, res_0, res_1, mean_nu, None, minutes,
                               bubble_s=stg["bubble"], overlap=depth,
-                              cmask=self.cmask)
+                              cmask=self.cmask, coh=self.coh_record)
 
         def solve_solo(stg, boosted):
             t0 = time.time()
@@ -1121,7 +1143,7 @@ class FullBatchPipeline:
                         jnp.asarray(tile.v, self.rdt),
                         jnp.asarray(tile.w, self.rdt),
                         jnp.asarray(tile.sta1), jnp.asarray(tile.sta2),
-                        J_r8, self._tile_beam(tile))
+                        J_r8, self._tile_beam(tile, ti))
 
         # ms.tiles() is the seam a dataset overrides, so the reader
         # pulls it and does not call read_tile(i)
@@ -1490,7 +1512,7 @@ class TileStepper:
                    wt=lm_mod.make_weights(flags, p.sdt),
                    sta1=jnp.asarray(sta1_np),
                    sta2=jnp.asarray(sta2_np),
-                   beam=p._tile_beam(tile))
+                   beam=p._tile_beam(tile, ti))
         if self.stage_xr:
             # residual input staged ahead; DONATED to the residual
             # program (ring: no read-after-donate, no aliasing)
@@ -1683,7 +1705,7 @@ class TileStepper:
             self.history.append(rec)
             _emit_tile_record(ti, res_0, res_1, mean_nu, info, dt,
                               bubble_s=bubble, overlap=self.depth,
-                              cmask=p.cmask)
+                              cmask=p.cmask, coh=p.coh_record)
         return rec
 
     def _observe_stream_latency(self, ti, t_arr):

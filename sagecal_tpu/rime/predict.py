@@ -208,8 +208,9 @@ def coherencies(sky: SkyArrays, u, v, w, freqs, fdelta,
     Equivalent of precalculate_coherencies[_multifreq] (predict.c:653/:890);
     with ``beam`` (a :class:`sagecal_tpu.rime.beam.BeamArrays`) and
     ``dobeam`` != 0 this is precalculate_coherencies[_multifreq]_withbeam
-    (predict_withbeam.c:522/:690) — beam tables are computed per cluster
-    and folded into the source sum.
+    (predict_withbeam.c:522/:690) — the array-factor tables of all
+    clusters are made first (scope ``rime/beam``), the element beam's per
+    cluster, and both are folded into the source sum.
     ``fdelta`` is the smearing bandwidth PER CHANNEL (callers pass total
     bandwidth for channel-averaged single-freq solves, total/Nchan for
     multifreq, matching predict.c:943).
@@ -222,25 +223,38 @@ def coherencies(sky: SkyArrays, u, v, w, freqs, fdelta,
         else:
             with_shapelets = bool(np.any(np.asarray(sky.sh_n0) > 0))
     n0max = int(np.sqrt(sky.sh_modes.shape[-1]).round())
-    if beam is not None and dobeam:
+    af_all = None
+    with_beam = beam is not None and bool(dobeam)
+    if with_beam:
         from sagecal_tpu.rime import beam as beam_mod
+        if dobeam in (beam_mod.DOBEAM_ARRAY, beam_mod.DOBEAM_FULL):
+            # every cluster's array-factor table, made BEFORE the map
+            # over clusters and under a scope of its own: a scope nested
+            # inside ``rime/phasor`` could not be read apart (the
+            # profile's readers take the first of these names in an
+            # operation's path).  The element beam's [S, T, N, 2, 2]
+            # stays a cluster's own, inside the map.
+            with jax.named_scope("rime/beam"):
+                af_all = jax.lax.map(
+                    lambda csky: beam_mod.cluster_beam(
+                        beam, csky.ra, csky.dec, jnp.atleast_1d(freqs),
+                        beam_mod.DOBEAM_ARRAY)[0],
+                    sky)        # [M, F, S, T, N]
 
-        def per_cluster(csky):
-            af, E = beam_mod.cluster_beam(beam, csky.ra, csky.dec,
-                                          jnp.atleast_1d(freqs), dobeam)
-            return _cluster_coherency(csky, u, v, w, freqs, fdelta,
-                                      per_channel_flux, n0max,
-                                      with_shapelets, af=af, E=E,
-                                      tslot=tslot, sta1=sta1, sta2=sta2,
-                                      planes=planes)
-    else:
-        def per_cluster(csky):
-            return _cluster_coherency(csky, u, v, w, freqs, fdelta,
-                                      per_channel_flux, n0max,
-                                      with_shapelets, planes=planes)
+    def per_cluster(xs):
+        csky, af = xs
+        E = None
+        if with_beam and dobeam != beam_mod.DOBEAM_ARRAY:
+            E = beam_mod.cluster_beam(beam, csky.ra, csky.dec,
+                                      jnp.atleast_1d(freqs),
+                                      beam_mod.DOBEAM_ELEMENT)[1]
+        return _cluster_coherency(csky, u, v, w, freqs, fdelta,
+                                  per_channel_flux, n0max, with_shapelets,
+                                  af=af, E=E, tslot=tslot, sta1=sta1,
+                                  sta2=sta2, planes=planes)
 
     with jax.named_scope("rime/phasor"):    # the map's stacking too
-        out = jax.lax.map(per_cluster, sky)
+        out = jax.lax.map(per_cluster, (sky, af_all))
         return jnp.moveaxis(out, 1, 0) if planes else out
 
 

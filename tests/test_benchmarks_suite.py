@@ -151,6 +151,34 @@ OVERTAKEN = {
     "test_scopes.py::test_tiny_cell_traced_end_to_end[predict-tiny]":
         "bubble_ms.predict is the loop thread's blocked seconds, no "
         "longer io + write = io_ms.predict (PR 46)",
+    # PR 48 appended a cell, a configuration and fifteen entries that list
+    # the new cell alone.  test_hybrid.py holds PR 44's as the LAST of
+    # their lists (``configs[-2:]``, ``workloads``: one case), runs
+    # test_fold.py's three place-pinning cases on the manifest less
+    # PR 44's entries only (three), and test_subtract.py's less everything
+    # up to PR 44's (one).  benchmarks/tests/test_beam_cell.py runs each
+    # of the five whole on the manifest less this PR's cell,
+    # configuration and entries
+    # (test_what_pr44_pins_by_place_holds_less_this_prs_entries), and
+    # holds every older cell's list, PR 40's, PR 42's and PR 44's entries
+    # and the order of cells and configurations by name
+    # (test_the_older_cells_lists_are_as_pr44_held_them).
+    **{"test_hybrid.py::test_what_pr42_pins_by_place_holds_less_this_prs_"
+       f"entries[{case}]":
+       "PR 44's cell, configuration and eight entries are no longer the "
+       "LAST of their lists: PR 48's go at the end"
+       for case in (
+           "test_the_cell_is_files_and_entries",
+           "test_the_configuration_is_the_sources_at_eight_subbands",
+           "test_pr40s_entries_still_list_the_older_cells_and_only_ours_"
+           "follow")},
+    "test_hybrid.py::test_what_older_cells_pin_by_place_holds_less_"
+    "everything_since[test_subtract]":
+        "test_subtract.py holds the LAST configuration, cell and entries "
+        "of the manifest, which are PR 48's now",
+    "test_hybrid.py::test_the_older_cells_lists_are_as_pr42_held_them":
+        "configs[-2:] and workloads end with PR 44's no longer: PR 48's "
+        "cell and configuration go at the end",
 }
 
 

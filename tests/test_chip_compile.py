@@ -569,3 +569,102 @@ def test_hybrid_tile_compiles_and_fits(one_chip, program):
             "residual": ("rime/phasor", "rime/corrupt", "rime/residual")}
     for scope in want[program]:
         assert scope in text, f"no operation under {scope}"
+
+
+# -- the array beam, -B 1 (PR 48): 8 x 128 sources, 48 element slots ----------
+
+EMAX_BEAM = 48
+#: temporaries each program compiled to here at PR 48, GiB
+BEAM_TEMP = {("coh", 10): 0.0, ("residual", 10): 0.0014,
+             ("coh", 120): 0.3269, ("residual", 120): 0.3857}
+
+
+def _lower_beam_program(one_chip, name, tilesz):
+    """The two programs of a ``dosage-beam`` tile that form coherencies
+    under ``-B 1``, as the pipeline builds them on lofar62-m8x128's sky:
+    ``coh`` (``_build_solver``'s ``coh_fn`` off the Pallas kernel:
+    complex ``[M, B, 2, 2]``) and ``residual`` (``_residuals``: pairs in
+    and out, donated), the beam a ``BeamArrays`` argument of 62 stations
+    with 48 element slots and the tile's ``gmst`` track."""
+    from problems import make_sky
+    from sagecal_tpu.io import dataset as ds
+    from sagecal_tpu.rime import beam as bm, predict as rp, residual as rr
+    sky = make_sky(M, srcs_per_cluster=128)
+    dsky = rp.sky_to_device(sky, jnp.float32)
+    rows = NB * tilesz
+    tslot = jnp.asarray(ds.row_tslot(rows, NB))
+    cidx = jnp.asarray(rp.chunk_indices(tilesz, NB, sky.nchunk))
+    sd = _spec(one_chip)
+    f32, i32 = jnp.float32, jnp.int32
+    freq = jnp.asarray([150e6], f32)
+    beam = bm.BeamArrays(
+        longitude=sd((N,), f32), latitude=sd((N,), f32),
+        gmst=sd((tilesz,), f32), ra0=sd((), f32), dec0=sd((), f32),
+        freq0=sd((), f32), elem_xyz=sd((N, EMAX_BEAM, 3), f32),
+        elem_mask=sd((N, EMAX_BEAM), jnp.bool_), n_elem=sd((N,), f32),
+        patt_theta=sd((28, 2), f32), patt_phi=sd((28, 2), f32),
+        elem_beta=sd((), f32))
+    uvw = (sd((rows,), f32),) * 3
+    sta = (sd((rows,), i32),) * 2
+    if name == "coh":
+        def coh_fn(u, v, w, sta1, sta2, beam):
+            return rp.coherencies(dsky, u, v, w, freq, 0.18e6, beam=beam,
+                                  dobeam=1, tslot=tslot, sta1=sta1,
+                                  sta2=sta2)[:, :, 0]
+        return jax.jit(coh_fn).lower(*uvw, *sta, beam)
+    assert name == "residual", name
+
+    def residuals(J_r8, x_r, u, v, w, sta1, sta2, beam):
+        return rr.calculate_residuals_pairs(
+            dsky, J_r8, x_r, u, v, w, freq, 0.18e6, sta1, sta2, cidx,
+            jnp.ones((M,), bool), out_dtype=f32, beam=beam, dobeam=1,
+            tslot=tslot, row_period=NB)
+    return jax.jit(residuals, donate_argnums=(1,)).lower(
+        sd((M, 1, N, 8), f32), sd((rows, 1, 2, 2, 2), f32), *uvw, *sta,
+        beam)
+
+
+@pytest.mark.parametrize("tilesz", [
+    TILESZ, pytest.param(TILESZ_120, marks=pytest.mark.slow)])
+@pytest.mark.parametrize("program", ["coh", "residual"])
+def test_beam_programs_compile_and_fit(one_chip, program, tilesz):
+    """``-B 1`` takes the calibrate path off the Pallas coherency kernel:
+    the solve's coherencies come from ``rp.coherencies`` through
+    ``lax.map`` as COMPLEX ``[M, B, 2, 2]``, with the beam's tables
+    ``[M, 1, S, T, N]`` made first under ``rime/beam`` and gathered to
+    rows inside the source sum; the residual program makes them again.
+    Complex arrays stacked on a minor axis of two are where the TPU
+    compiler aborted twice (PR 23, PR 37: a CHECK failure kills this
+    worker, which is the test failing).  Both compile for the described
+    v5e and name the scope the cell's ``beam_dev_ms.beam`` reads.
+    Temporaries as compiled here, f32 contractions in f32 (``-t 120`` is
+    the fit guard, ``slow``):
+
+    ========  ==========  ==========
+    program   -t 10       -t 120
+    ========  ==========  ==========
+    coh       0.0000 GiB  0.3269 GiB
+    residual  0.0014 GiB  0.3857 GiB
+    ========  ==========  ==========
+
+    The ceiling is what a program compiled to with a tenth of room plus
+    one cluster's cosines over the elements, ``f32[S, T, N, Emax]``
+    (15 MB at ``-t 10``, 183 MB at ``-t 120``): a second one held live,
+    or all eight clusters' at once (a ``vmap`` in the ``lax.map``'s
+    place), is what this case notices."""
+    with jax.default_matmul_precision("highest"):
+        compiled = _lower_beam_program(one_chip, program, tilesz).compile()
+    mem = compiled.memory_analysis()
+    one_table = 128 * tilesz * N * EMAX_BEAM * 4
+    print(f"beam {program} -t {tilesz}: temp "
+          f"{mem.temp_size_in_bytes / 2 ** 30:.4f} GiB")
+    ceiling = int(1.1 * BEAM_TEMP[program, tilesz] * 2 ** 30) + one_table
+    assert 0 <= mem.temp_size_in_bytes < ceiling, \
+        mem.temp_size_in_bytes / 2 ** 30
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < HBM_BYTES
+    text = compiled.as_text()
+    want = ("rime/beam", "rime/phasor") + (
+        ("rime/corrupt", "rime/residual") if program == "residual" else ())
+    for scope in want:
+        assert scope in text, scope

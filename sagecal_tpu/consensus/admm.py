@@ -314,6 +314,22 @@ def make_admm_runner(dsky, sta1, sta2, cidx, cmask, n_stations: int,
                               beam=beam, dobeam=dobeam, tslot=tslot_j,
                               sta1=sta1_j, sta2=sta2_j)[:, :, 0]
 
+    def coh_subbands(uF, vF, wF, freqF, beamF=None):
+        """``[Fl, M, B, 2, 2]``: the local subbands' coherencies, made
+        ONCE an interval (they do not change over its ADMM iterations)
+        and a subband at a time, BEFORE the vmap of the solves that take
+        them: each subband's source sum then compiles as the residual
+        program's does, rows x sources on full register tiles. Under
+        that vmap the sum's contraction is a batched ``dot`` the TPU
+        compiler lowers as a dilated ``convolution`` (``admm-f8-fold``:
+        ``rime/phasor`` 26.9 ms an interval against 5.2; PERF.md section
+        6, PR 49)."""
+        if uF.shape[0] == 1:
+            return coh_for(*jax.tree.map(
+                lambda x: x[0], (uF, vF, wF, freqF, beamF)))[None]
+        return jax.lax.map(lambda a: coh_for(*a),
+                           (uF, vF, wF, freqF, beamF))
+
     # rows are [tilesz, nbase] per subband: forward the baseline period
     # to the solvers' normal-equation assembly (normal_eq row_period)
     sage_cfg = (cfg.sage if not nbase
@@ -327,15 +343,12 @@ def make_admm_runner(dsky, sta1, sta2, cidx, cmask, n_stations: int,
         return jnp.stack([info["solver_iters"],
                           info["cg_iters"]]).astype(jnp.int32)
 
-    def local_solve_plain(x8, u, v, w, wt, J_r8, freq, beam=None):
-        coh = coh_for(u, v, w, freq, beam)
+    def local_solve_plain(x8, coh, wt, J_r8):
         J, info = sage.sagefit(x8, coh, sta1_j, sta2_j, cidx_j, cmask_j,
                                ne.jones_r2c(J_r8), N, wt, config=sage_cfg)
         return ne.jones_c2r(J), info["res_0"], info["res_1"], _trips(info)
 
-    def local_solve_admm(x8, u, v, w, wt, J_r8, freq, Y_r8, BZ_r8, rho_m,
-                         beam=None):
-        coh = coh_for(u, v, w, freq, beam)
+    def local_solve_admm(x8, coh, wt, J_r8, Y_r8, BZ_r8, rho_m):
         # ADMM iterations k>0 always warm-start from the previous
         # iterate, so cluster groups (inflight>1) skip the cold-start
         # width restriction; iteration 0 (local_solve_plain, sage_cfg
@@ -466,17 +479,14 @@ def make_admm_runner(dsky, sta1, sta2, cidx, cmask, n_stations: int,
             lead = args[0].shape[0]
             if lead != 1:
                 return jax.vmap(fn)(*args)
-            sq = [None if a is None
-                  else jax.tree.map(lambda x: x[0], a) for a in args]
-            out = fn(*sq)
+            out = fn(*jax.tree.map(lambda x: x[0], args))
             return jax.tree.map(lambda x: x[None], out)
         return call
 
-    def iter0_local(x8F, uF, vF, wF, freqF, wtF, fratioF, J0F,
-                    beamF=None):
+    def iter0_local(x8F, cohF, wtF, fratioF, J0F):
         """ADMM iteration 0 on the LOCAL shard: plain solve + post."""
         JF, res0, res1, tk = _per_subband(local_solve_plain)(
-            x8F, uF, vF, wF, wtF, J0F, freqF, beamF)
+            x8F, cohF, wtF, J0F)
         return iter0_post(JF, res0, res1, fratioF) + (tk,)
 
     @jax.named_scope(CONSENSUS_SCOPE)
@@ -523,21 +533,21 @@ def make_admm_runner(dsky, sta1, sta2, cidx, cmask, n_stations: int,
         return (Jr, YF, Z, rhoF, Yhat, J5, Zbar, Xd, rho_upper), \
             (r0, r1, dual)
 
-    def body_local(x8F, uF, vF, wF, freqF, wtF, carry, it, beamF=None):
+    def body_local(x8F, cohF, wtF, carry, it):
         """One ADMM iteration k>0 on the LOCAL shard (slave :686-770)."""
         Fl = x8F.shape[0]
         with jax.named_scope(CONSENSUS_SCOPE):
             BZ = jnp.einsum("fp,mpknr->fmknr", _brow(Fl), carry[2])
         Jr, r0, r1, tk = _per_subband(local_solve_admm)(
-            x8F, uF, vF, wF, wtF, carry[0], freqF, carry[1], BZ,
-            carry[3], beamF)
+            x8F, cohF, wtF, carry[0], carry[1], BZ, carry[3])
         carry, per_iter = body_post(Jr, r0, r1, carry, it)
         return carry, per_iter + (tk,)
 
     if _return_parts:
         # building blocks for make_admm_runner_blocked (same math,
         # different execution granularity)
-        return dict(local_solve_plain=local_solve_plain,
+        return dict(coh_subbands=coh_subbands,
+                    local_solve_plain=local_solve_plain,
                     local_solve_admm=local_solve_admm,
                     iter0_post=iter0_post, body_post=body_post,
                     _brow=_brow, _per_subband=_per_subband,
@@ -546,13 +556,12 @@ def make_admm_runner(dsky, sta1, sta2, cidx, cmask, n_stations: int,
     def admm_program(x8F, uF, vF, wF, freqF, wtF, fratioF, J0F,
                      *beam_rest):
         # shapes here are the LOCAL shard: [Fl, ...]
-        beamF = beam_rest[0] if beam_rest else None
+        cohF = coh_subbands(uF, vF, wF, freqF, *beam_rest)
         carry, res0, res1, Y0F, tk0 = iter0_local(
-            x8F, uF, vF, wF, freqF, wtF, fratioF, J0F, beamF)
+            x8F, cohF, wtF, fratioF, J0F)
 
         def body(carry, it):
-            return body_local(x8F, uF, vF, wF, freqF, wtF, carry, it,
-                              beamF)
+            return body_local(x8F, cohF, wtF, carry, it)
 
         carry, (r0s, r1s, duals, tks) = jax.lax.scan(
             body, carry, jnp.arange(1, max(cfg.n_admm, 1),
@@ -585,16 +594,16 @@ def make_admm_runner(dsky, sta1, sta2, cidx, cmask, n_stations: int,
     def iter0_flat(x8F, uF, vF, wF, freqF, wtF, fratioF, J0F,
                    *beam_rest):
         carry, res0, res1, Y0F, tk = iter0_local(
-            x8F, uF, vF, wF, freqF, wtF, fratioF, J0F,
-            beam_rest[0] if beam_rest else None)
+            x8F, coh_subbands(uF, vF, wF, freqF, *beam_rest), wtF,
+            fratioF, J0F)
         return carry + (res0, res1, Y0F, tk)
 
     def body_flat(x8F, uF, vF, wF, freqF, wtF, JF, YF, Z, rhoF, Yhat,
                   Jprev, Zbar, Xd, rho_upper, it, *beam_rest):
         carry = (JF, YF, Z, rhoF, Yhat, Jprev, Zbar, Xd, rho_upper)
         carry, (r0, r1, dual, tk) = body_local(
-            x8F, uF, vF, wF, freqF, wtF, carry, it,
-            beam_rest[0] if beam_rest else None)
+            x8F, coh_subbands(uF, vF, wF, freqF, *beam_rest), wtF,
+            carry, it)
         return carry + (r0, r1, dual, tk)
 
     beam_specs = (spec_f,) if dobeam else ()
@@ -793,6 +802,7 @@ def make_admm_runner_2d(dsky, sta1, sta2, cidx, cmask, n_stations: int,
     body_post = parts["body_post"]
     _brow = parts["_brow"]
     _per_subband = parts["_per_subband"]
+    coh_subbands = parts["coh_subbands"]
 
     def one_interval(Jc, x8t, ut, vt, wt_, wtt, frt, freqF, J0F):
         """One solution interval's FULL ADMM chain on the local freq
@@ -800,8 +810,8 @@ def make_admm_runner_2d(dsky, sta1, sta2, cidx, cmask, n_stations: int,
         iterations, every consensus step a freq-axis collective.
         Returns (Jnext, outputs) — Jnext is the warm-start carry for
         the next interval in this time shard's block."""
-        JF, res0, res1, _ = _per_subband(lsp)(x8t, ut, vt, wt_, wtt, Jc,
-                                              freqF)
+        cohF = coh_subbands(ut, vt, wt_, freqF)
+        JF, res0, res1, _ = _per_subband(lsp)(x8t, cohF, wtt, Jc)
         carry, res0, res1, Y0F = iter0_post(JF, res0, res1, frt)
         Fl = x8t.shape[0]
 
@@ -809,8 +819,7 @@ def make_admm_runner_2d(dsky, sta1, sta2, cidx, cmask, n_stations: int,
             Brow = _brow(Fl)
             BZ = jnp.einsum("fp,mpknr->fmknr", Brow, carry[2])
             Jr, r0, r1, _ = _per_subband(lsa)(
-                x8t, ut, vt, wt_, wtt, carry[0], freqF, carry[1], BZ,
-                carry[3])
+                x8t, cohF, wtt, carry[0], carry[1], BZ, carry[3])
             return body_post(Jr, r0, r1, carry, it)
 
         carry, (r0s, r1s, duals) = jax.lax.scan(
@@ -1043,6 +1052,7 @@ def make_admm_runner_stale(dsky, sta1, sta2, cidx, cmask,
     K = int(np.asarray(cmask).shape[1])
     N = n_stations
 
+    coh_prog = jax.jit(parts["coh_subbands"])
     solve0 = jax.jit(_per_subband(local_solve_plain))
     solveb = jax.jit(_per_subband(local_solve_admm))
     cons0 = jax.jit(lambda JF, res0, res1, fratioF: iter0_post(
@@ -1105,20 +1115,22 @@ def make_admm_runner_stale(dsky, sta1, sta2, cidx, cmask,
         def take(a, f):
             return jax.tree.map(lambda x: x[f:f + 1], a)
 
+        # each subband's coherencies ONCE an interval, not a round
+        cohs = [coh_prog(take(uF, f), take(vF, f), take(wF, f),
+                         take(freqF, f)) for f in range(F)]
+
         def sub_solve0(f):
             t0 = _time.perf_counter()
             Jb, r0b, r1b, tkb = solve0(
-                take(x8F, f), take(uF, f), take(vF, f), take(wF, f),
-                take(wtF, f), take(J0F, f), take(freqF, f))
+                take(x8F, f), cohs[f], take(wtF, f), take(J0F, f))
             _t(f"solve0[{f}]", t0, Jb)
             return Jb, r0b, r1b, tkb
 
         def sub_solveb(f, JF, YF, BZ, rhoF):
             t0 = _time.perf_counter()
             Jb, r0b, r1b, tkb = solveb(
-                take(x8F, f), take(uF, f), take(vF, f), take(wF, f),
-                take(wtF, f), take(JF, f), take(freqF, f), take(YF, f),
-                take(BZ, f), take(rhoF, f))
+                take(x8F, f), cohs[f], take(wtF, f), take(JF, f),
+                take(YF, f), take(BZ, f), take(rhoF, f))
             _t(f"solve[{f}]", t0, Jb)
             return Jb, r0b, r1b, tkb
 
@@ -1259,6 +1271,7 @@ def make_admm_runner_blocked(dsky, sta1, sta2, cidx, cmask,
     # best plan) takes the axis-free call, avoiding the unit-vmap
     # layout penalty
     _per_subband = parts["_per_subband"]
+    coh_prog = jax.jit(parts["coh_subbands"])
     solve0 = jax.jit(_per_subband(local_solve_plain))
     solveb = jax.jit(_per_subband(local_solve_admm))
     # donate the block-solved Jones and the ADMM carry into the
@@ -1300,35 +1313,30 @@ def make_admm_runner_blocked(dsky, sta1, sta2, cidx, cmask,
                                           (short,) + ab.shape[1:])])
             return ab
 
-        # constant per-tile inputs: slice/pad each block ONCE, not per
-        # ADMM iteration
-        const_blocks = [tuple(take(a, sl)
-                              for a in (x8F, uF, vF, wF, wtF, freqF))
-                        for sl in blocks]
-        beam_blocks = None
-        if beamF is not None:
-            beam_blocks = [jax.tree.map(lambda a: take(a, sl), beamF)
-                           for sl in blocks]
+        # constant per-tile inputs: slice/pad each block ONCE, and make
+        # its coherencies ONCE, not per ADMM iteration
+        const_blocks = []           # per block: (x8, coh, wt)
+        for sl in blocks:
+            bb = (() if beamF is None
+                  else (jax.tree.map(lambda a: take(a, sl), beamF),))
+            const_blocks.append((
+                take(x8F, sl),
+                coh_prog(*(take(a, sl) for a in (uF, vF, wF, freqF)),
+                         *bb),
+                take(wtF, sl)))
 
         def blockwise(fn, *per_iter):
-            """fn(x8, u, v, w, wt, freq, *per-iteration block args)."""
+            """fn(x8, coh, wt, *per-iteration block args)."""
             outs = []           # per block: (J, res0, res1, trips)
             for i, sl in enumerate(blocks):
                 t0 = _time.perf_counter()
-                bb = (beam_blocks[i],) if beam_blocks is not None else ()
                 out = fn(*const_blocks[i],
-                         *[take(a, sl) for a in per_iter], *bb)
+                         *[take(a, sl) for a in per_iter])
                 _t(f"solve[{i}]", t0, out[0])
                 outs.append([o[:sl.stop - sl.start] for o in out])
             return tuple(jnp.concatenate(o) for o in zip(*outs))
 
-        def solve0_re(x8, u, v, w, wt, freq, J0, *bb):
-            return solve0(x8, u, v, w, wt, J0, freq, *bb)
-
-        def solveb_re(x8, u, v, w, wt, freq, J, Y, BZ, rho, *bb):
-            return solveb(x8, u, v, w, wt, J, freq, Y, BZ, rho, *bb)
-
-        JF, res0, res1, tk = blockwise(solve0_re, J0F)
+        JF, res0, res1, tk = blockwise(solve0, J0F)
         t0 = _time.perf_counter()
         carry, res0, res1, Y0F = cons0(JF, res0, res1, fratioF)
         _t("cons0", t0, carry[2])
@@ -1336,7 +1344,7 @@ def make_admm_runner_blocked(dsky, sta1, sta2, cidx, cmask,
         pend = []       # deferred admm_iter records (no per-iter sync)
         for it in range(1, max(cfg.n_admm, 1)):
             BZ = bz_prog(carry[2], Brow_full)
-            Jr, r0, r1, tk = blockwise(solveb_re, carry[0], carry[1], BZ,
+            Jr, r0, r1, tk = blockwise(solveb, carry[0], carry[1], BZ,
                                        carry[3])
             tks.append(tk)
             t0 = _time.perf_counter()

@@ -10,7 +10,9 @@ Re-architected TPU-first: instead of a pthread pool over baseline ranges
 calling per-source scalar functions, the whole (cluster, baseline, channel,
 source) product is one vectorized masked computation. Clusters are mapped
 with ``lax.map`` (peak memory [S, B] per cluster) and everything inside
-fuses into a handful of XLA kernels on the VPU. The Jones sandwich of
+fuses into a handful of XLA kernels on the VPU; the sum over a cluster's
+sources is ONE contraction of eight real weight rows with the phasors'
+real and imaginary planes. The Jones sandwich of
 the model that leaves the solver (:func:`predict_model`) is real
 elementwise arithmetic on planes (``rime/planes.py``), the source sum's
 four correlations handed to it as eight real planes a cluster.
@@ -115,7 +117,8 @@ def _cluster_coherency(csky, u, v, w, freqs, fdelta, per_channel_flux: bool,
     """Coherencies of ONE cluster: [B, F, 2, 2] complex, or with
     ``planes`` the same numbers as eight real planes [8, F, B]
     (``rime/planes.py``: the source sum's four correlations, real and
-    imaginary parts, as they come out of the sum).
+    imaginary parts, as they come out of the sum; the complex form is
+    made from them).
 
     ``csky`` is a SkyArrays row (arrays [S]); u,v,w [B] seconds; freqs [F].
     Beam (predict_withbeam.c:139-187): ``af`` [F, S, T, N] array-factor
@@ -173,15 +176,20 @@ def _cluster_coherency(csky, u, v, w, freqs, fdelta, per_channel_flux: bool,
         b10 = (sU - 1j * sV).astype(cdtype)
         b11 = (sI - sQ).astype(cdtype)
         if E is None:
-            xx = jnp.sum(phasor * b00[None, :], axis=1)
-            xy = jnp.sum(phasor * b01[None, :], axis=1)
-            yx = jnp.sum(phasor * b10[None, :], axis=1)
-            yy = jnp.sum(phasor * b11[None, :], axis=1)
-            if planes:
-                return jnp.stack([part for c in (xx, xy, yx, yy)
-                                  for part in (c.real, c.imag)])  # [8, B]
-            return jnp.stack([jnp.stack([xx, xy], -1),
-                              jnp.stack([yx, yy], -1)], -2)  # [B, 2, 2]
+            # the sum over the sources is a contraction, and is handed to
+            # the compiler as ONE: eight real weight rows against (Re, Im)
+            # of the phasors.  The TPU compiler then makes cos, sin and
+            # sinc once a pair on register tiles of rows x sources; four
+            # ``jnp.sum(phasor * b, axis=1)`` it compiles with ONE row to
+            # a register tile, an eighth of the vector unit at work
+            # (tests/test_chip_compile.py holds it; PERF.md section 6).
+            b = jnp.stack([b00, b01, b10, b11])              # [4, S]
+            wts = jnp.stack([b.real, -b.imag, b.imag, b.real], 1)
+            p8 = jnp.einsum(
+                "kcs,cbs->kb", wts.reshape(8, 2, -1),        # [8, (Re,Im), S]
+                jnp.stack([phasor.real, phasor.imag]),
+                precision="highest")                         # [8, B]
+            return p8 if planes else pl.jones_r2c(p8.T)      # [B, 2, 2]
         # element beam: per-source 2x2 sandwich, then sum over sources
         Bm = jnp.stack([jnp.stack([b00, b01], -1),
                         jnp.stack([b10, b11], -1)], -2)      # [S, 2, 2]

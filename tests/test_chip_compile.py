@@ -22,6 +22,7 @@ limit installed: a compile that may not return gets one of its own
 
 import functools
 import os
+import re
 import sys
 
 import jax
@@ -668,3 +669,74 @@ def test_beam_programs_compile_and_fit(one_chip, program, tilesz):
         ("rime/corrupt", "rime/residual") if program == "residual" else ())
     for scope in want:
         assert scope in text, scope
+
+
+# -- the source sum's register tiles (PR 49) ----------------------------------
+
+def _phasor_arrays(text):
+    """``(line, dims, minor_to_major, tile)`` of every f32 array that an
+    operation under ``rime/phasor`` makes in the compiled ``text``."""
+    got = []
+    for ln in text.splitlines():
+        if "rime/phasor" not in ln or " = " not in ln:
+            continue
+        made = ln.split(" = ", 1)[1].split("(%", 1)[0]
+        for dims, order, tile in re.findall(
+                r"f32\[([\d,]+)\]\{([\d,]+):T\((\d+,\d+)\)", made):
+            got.append((ln.strip()[:100],
+                        [int(d) for d in dims.split(",")],
+                        [int(d) for d in order.split(",")],
+                        tuple(int(d) for d in tile.split(","))))
+    return got
+
+
+@pytest.mark.parametrize("program", [
+    "simulate", "residual",
+    pytest.param("residual:120", marks=pytest.mark.slow), "beam-residual",
+    "admm-fold", "admm-mesh"])
+def test_source_sum_fills_its_registers(topo, one_chip, program):
+    """The sum over a cluster's sources is handed to the compiler as a
+    contraction (``rime/predict._cluster_coherency``, PR 49).  Written as
+    four ``jnp.sum(phasor * b, axis=1)`` it compiled, in every program but
+    the beam's, to ``cos`` and ``sin`` on ``f32[1, rows, S]`` arrays tiled
+    ``T(1,128)``: ONE row (or one source) to a register tile, an eighth of
+    every vector register at work, 22.9 ms a tile of ``predict-m8x128``
+    where the beam program's full tiles took 5.1 ms for the same pairs
+    (PERF.md section 6, PR 49).  The pin: every ``cos`` under
+    ``rime/phasor`` is made on an array of rows x sources whose register
+    tile holds more than one of its sublanes (``T(8,128)``; ``T(4,128)``
+    where a cluster has three sources), and no operation under that scope
+    makes an array of rows x sources tiled ``T(1,128)``.  Which of the two
+    the compiler lays on the lanes is its own choice and both fill the
+    registers: the sources at 128 of them (the beam program's layout on
+    the parent), the rows at three.  The two consensus programs are held too
+    (``admm-f4-mesh``'s, under ``shard_map``, and ``admm-f8-fold``'s,
+    eight subbands on one chip): they make their subbands' coherencies
+    once an interval, a subband at a time, BEFORE the ``vmap`` of the
+    solves (``consensus/admm.py``, ``coh_subbands``), because the
+    contraction under that ``vmap`` is a batched ``dot`` the compiler
+    lowers as a dilated ``convolution``, five times the time of the sums
+    it replaced."""
+    name, _, tilesz = program.partition(":")
+    rows = NB * int(tilesz or TILESZ)
+    with jax.default_matmul_precision("highest"):
+        if name == "simulate":
+            low, S = _lower_simulate_program(one_chip, TILESZ, 3), 128
+        elif name == "residual":
+            low, S = _lower_residual_program(one_chip, rows), 3
+        elif name == "beam-residual":
+            low, S = _lower_beam_program(one_chip, "residual", TILESZ), 128
+        else:
+            low, S = _lower_consensus_program(
+                topo.devices[:1 if name == "admm-fold" else 4],
+                "lofar62-f8-fold-m8x3.json" if name == "admm-fold"
+                else "lofar62-f4-m8x3.json")[0], 3
+        arrays = _phasor_arrays(low.compile().as_text())
+    cos = [a for a in arrays if " cosine" in a[0]]
+    assert cos, "no cos under rime/phasor"
+    for line, dims, order, tile in cos:
+        assert sorted(dims[d] for d in order[:2]) == sorted((rows, S)), line
+        assert tile[0] > 1 and tile[1] == 128, line
+    one_row = [line for line, dims, _, tile in arrays
+               if tile == (1, 128) and rows in dims and S in dims]
+    assert not one_row, one_row

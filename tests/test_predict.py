@@ -219,6 +219,67 @@ def test_coherency_planes_are_the_coherencies():
                                                  (3, 0, 2, 1))))
 
 
+@pytest.mark.parametrize("planes", [True, False], ids=["planes", "complex"])
+@pytest.mark.parametrize("F", [1, 3])
+@pytest.mark.parametrize("S", [3, 128])
+def test_source_sum_against_float64_sum(S, F, planes):
+    """The sum over a cluster's sources is a contraction on real planes
+    (PR 49).  Against a float64 sum written out here (fringe phase,
+    ``|sinc|`` smearing, spectral scaling, the Stokes weights of the four
+    correlations) from the SAME f32 inputs: S sources of four non-zero
+    Stokes in one cluster and S - 1 in the other (so one slot of the
+    padded sky is masked), and one live source masked by hand."""
+    rng = np.random.default_rng(100 * S + F)
+    srcs, names = {}, []
+    for i in range(S):
+        ll, mm = rng.normal(0, 0.01, 2)
+        I = 1 + rng.random()
+        srcs[f"s{i}"] = point_source(
+            f"s{i}", ll, mm, sI=I, sQ=0.3 * I * rng.normal(),
+            sU=0.2 * I * rng.normal(), sV=0.1 * I * rng.normal(),
+            si=-0.7 * rng.random() - 0.1)
+        names.append(f"s{i}")
+    sky = make_sky(srcs, [(0, 1, names), (1, 1, names[:-1])])
+    dsky = rp.sky_to_device(sky, jnp.float32)
+    assert not bool(dsky.smask[1, S - 1])
+    dsky = dsky._replace(smask=dsky.smask.at[0, 1].set(False))
+    B, fdelta = 11, 2e5
+    u, v, w = (jnp.asarray(rng.normal(size=B) * 2e-6, jnp.float32)
+               for _ in range(3))
+    freqs = jnp.asarray(np.linspace(149e6, 151e6, F), jnp.float32)
+    got = np.asarray(rp.coherencies(dsky, u, v, w, freqs, fdelta,
+                                    per_channel_flux=True, planes=planes))
+
+    f8 = lambda a: np.asarray(a, np.float64)
+    want = np.zeros((2, B, F, 2, 2), complex)
+    for m in range(2):
+        G = 2 * np.pi * (np.outer(f8(u), f8(dsky.ll[m]))
+                         + np.outer(f8(v), f8(dsky.mm[m]))
+                         + np.outer(f8(w), f8(dsky.nn[m])))      # [B, S]
+        x = np.where(G == 0, 1.0, G * fdelta / 2)   # a padded slot: G = 0
+        smear = np.where(G == 0, 1.0, np.abs(np.sin(x) / x))
+        live = np.asarray(dsky.smask[m])
+        for f, freq in enumerate(f8(freqs)):
+            scale = np.exp(f8(dsky.spec_idx[m])
+                           * np.log(freq / f8(dsky.f0[m])))
+            I, Q, U, V = (f8(a[m]) * scale for a in
+                          (dsky.sI0, dsky.sQ0, dsky.sU0, dsky.sV0))
+            P = np.exp(1j * G * freq) * smear * live
+            want[m, :, f] = np.stack(
+                [np.stack([P @ (I + Q), P @ (U + 1j * V)], -1),
+                 np.stack([P @ (U - 1j * V), P @ (I - Q)], -1)], -2)
+    if planes:
+        from sagecal_tpu.rime import planes as pl
+        assert got.shape == (8, 2, F, B) and got.dtype == np.float32
+        want = np.asarray(pl.jones_c2r(jnp.asarray(want))).transpose(
+            3, 0, 2, 1)
+    else:
+        assert got.shape == want.shape and got.dtype == np.complex64
+    # f32 rounding: phases of a few radians to 1e-7 of themselves, then
+    # S terms of order one added up
+    assert np.abs(got - want).max() < 4e-6 * np.abs(want).max()
+
+
 def test_chunk_indices():
     ci = rp.chunk_indices(tilesz=10, nbase=3, nchunk=np.array([1, 3]))
     assert ci.shape == (2, 30)

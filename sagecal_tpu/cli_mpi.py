@@ -638,6 +638,8 @@ class ConsensusStepper:
 
         # constructed exactly once per stepper
         self.res_jit = jax.jit(jax.vmap(residual_fn))
+        # its one input that no interval changes
+        self._freqs_dev = jnp.asarray(freqs, rdt)
 
         self.writer = None
         if args.solutions_file and is_writer:
@@ -816,9 +818,10 @@ class ConsensusStepper:
         packing when cflags exist, plain mean else), solve-scoped
         uv-cut flags (predict.c:876 rule; originals restored before
         write-back), optional -W whitening, and the per-subband
-        unflagged fraction that scales rho (master :646-650)."""
-        import jax.numpy as jnp
-        from sagecal_tpu.diag import trace as dtrace
+        unflagged fraction that scales rho (master :646-650). Numpy
+        throughout but under -W: the uv cut and the weights run nothing
+        on a device and read nothing back, so the reader's thread never
+        stands behind the solve that is running."""
         from sagecal_tpu.rime import predict as rp
         from sagecal_tpu.solvers import lm as lm_mod
         args, rdt = self.args, self.rdt
@@ -826,16 +829,13 @@ class ConsensusStepper:
         uvcut_on = args.uvmin > 0.0 or args.uvmax < 1e9
         orig_flags = [t.flags for t in tiles]
         for t in tiles:
-            # the read-backs queue behind the solve on the first chip:
-            # that part of "stage" is a wait, not the reader's work (the
-            # uv cut's is the long one: PERF.md section 5, PR 40)
             if uvcut_on:
-                with dtrace.phase("wait"):
-                    t.flags = rp.apply_uvcut(t.flags, t,
-                                             args.uvmin, args.uvmax)
+                t.flags = rp.apply_uvcut(t.flags, t, args.uvmin, args.uvmax)
             x8_t, flags_t, good = t.solve_input()
             fr_l.append(good)
             if args.whiten:
+                import jax.numpy as jnp
+                from sagecal_tpu.diag import trace as dtrace
                 from sagecal_tpu.solvers import robust as rb
                 x8_d = rb.whiten_data(
                     jnp.asarray(x8_t, rdt), jnp.asarray(t.u, rdt),
@@ -843,9 +843,7 @@ class ConsensusStepper:
                 with dtrace.phase("wait"):
                     x8_t = np.asarray(x8_d)
             x8_l.append(x8_t)
-            wt_d = lm_mod.make_weights(jnp.asarray(flags_t, jnp.int32), rdt)
-            with dtrace.phase("wait"):
-                wt_l.append(np.asarray(wt_d))
+            wt_l.append(lm_mod.make_weights_np(flags_t, np.dtype(rdt)))
         if uvcut_on:
             for t, fl in zip(tiles, orig_flags):
                 t.flags = fl
@@ -859,9 +857,13 @@ class ConsensusStepper:
         return [m.read_tile(self.start + i) for m in self.mss]
 
     def stage(self, i, tiles):
-        """Interval ``i``'s solve inputs on the mesh: ``_prep_tiles``,
-        ``pad_subbands``, host to device. Everything but the warm-start
-        J0, which is the previous step's to give (``step`` stages it)."""
+        """Interval ``i``'s inputs on the devices: the solve's
+        (``_prep_tiles``, ``pad_subbands``, host to device) and those of
+        the residual that do not wait for the solve (the data column and
+        the geometry of the unpadded subbands). Everything but the
+        warm-start J0 and the solved Jones, which are ``step``'s to
+        carry. Copies only: no device execution, no read-back."""
+        import jax.numpy as jnp
         from sagecal_tpu.consensus import admm as cadmm
         from sagecal_tpu.diag import trace as dtrace
         ti = self.start + i
@@ -892,7 +894,12 @@ class ConsensusStepper:
                                           axis=0)])
                 beam_dev = self._beam_static_dev._replace(
                     gmst=self._to_device(gmstF))
-        return dict(args_dev=args_dev, nbytes=nbytes, uvw=(uF, vF, wF),
+            res_dev = None
+            if self.is_writer:
+                xF_r = np.stack([utils.c2r(t.x) for t in tiles])
+                res_dev = [jnp.asarray(xF_r, sdt), jnp.asarray(uF, rdt),
+                           jnp.asarray(vF, rdt), jnp.asarray(wF, rdt)]
+        return dict(args_dev=args_dev, nbytes=nbytes, res_dev=res_dev,
                     fratio=fratioF, gmst=gmstF, beam=beam_dev)
 
     # -- device-owner half ----------------------------------------------------
@@ -919,7 +926,6 @@ class ConsensusStepper:
         aw = self.aw
         aw.check()      # async write failure -> fail at this boundary
         bubble = io_wait
-        uF, vF, wF = staged["uvw"]
         fratioF = staged["fratio"]
 
         # the warm-start chain is this thread's: J0 is staged here, the
@@ -968,15 +974,16 @@ class ConsensusStepper:
                 + f" (blocks of {args.block_f} subbands, "
                 f"{nblk} solve executions + 1 consensus each)")
         with dtrace.phase("fetch", tile=ti):
+            outs = (JF_r8, Z, res0, res1, r1s, duals, Y0F, trips)
+            # one process: every copy is started before the first is
+            # waited for, where eight np.asarray were eight round trips
+            JF_r8, Z, res0, res1, r1s, duals, Y0F, trips = (
+                [self._fetch(a) for a in outs] if self.multihost
+                else jax.device_get(outs))
             # slice padded subband rows off every per-subband output
-            fetch = self._fetch
-            JF_r8 = fetch(JF_r8)[:nf]
-            Z = fetch(Z)
-            res0, res1 = fetch(res0)[:nf], fetch(res1)[:nf]
-            r1s = fetch(r1s)[:, :nf]
-            duals = fetch(duals)
-            Y0F = fetch(Y0F)[:nf]
-            trips = fetch(trips)        # [n_admm, Fpad, 2], a few i32
+            # (trips stays [n_admm, Fpad, 2], a few i32)
+            JF_r8, res0, res1 = JF_r8[:nf], res0[:nf], res1[:nf]
+            r1s, Y0F = r1s[:, :nf], Y0F[:nf]
         JF_r8_5 = np.asarray(JF_r8).reshape(nf, sky.n_clusters, kmax, n, 8)
         if self.worker_writers:
             J_all = utils.jones_r2c_np(JF_r8_5)
@@ -986,11 +993,11 @@ class ConsensusStepper:
                     ww.write_interval(J_all[f], sky.nchunk)
             bubble += aw.submit(_write_workers)
 
-        with dtrace.phase("primal"):
-            if args.mdl and ti == self.start and is_writer:
-                # model-order report from iteration-0 rho*J (master
-                # :815-822)
-                from sagecal_tpu.consensus import mdl as mdlmod
+        if args.mdl and ti == self.start and is_writer:
+            # model-order report from iteration-0 rho*J (master
+            # :815-822)
+            from sagecal_tpu.consensus import mdl as mdlmod
+            with dtrace.phase("primal"):
                 res = mdlmod.minimum_description_length(
                     np.asarray(Y0F), np.broadcast_to(
                         np.asarray(self.rho0, float), (sky.n_clusters,)),
@@ -998,46 +1005,31 @@ class ConsensusStepper:
                     polytype=args.polytype, kstart=1, kfinish=args.npoly)
                 mdlmod.report(res)
 
-            res0 = np.asarray(res0)
-            res1_it0 = np.asarray(res1)     # iteration 0's plain solve
-            res1 = np.asarray(r1s)[-1] if cfg.n_admm > 1 else res1_it0
-            duals = np.asarray(duals)
-            # the consensus primal residual ||J - BZ|| (the reference
-            # master's convergence axis)
-            BZf = np.einsum("fp,mpknr->fmknr", Bpoly, np.asarray(Z))
-            primal = float(np.linalg.norm(JF_r8_5 - BZf)
-                           / np.sqrt(BZf.size))
-            useful, lockstep_pct = cadmm.lockstep(trips, nf, self.fold)
-        rec = {"tile": ti, "res_0": float(res0.mean()),
-               "res_1": float(res1.mean()), "primal": primal,
-               "dual": float(duals[-1]) if len(duals) else 0.0}
-        self.history.append(rec)
+        res0 = np.asarray(res0)
+        res1_it0 = np.asarray(res1)     # iteration 0's plain solve
+        res1 = np.asarray(r1s)[-1] if cfg.n_admm > 1 else res1_it0
+        duals = np.asarray(duals)
 
-        if dtrace.active() or obs.active():
+        if ((dtrace.active() or obs.active()) and not args.host_loop
+                and not args.block_f and not args.staleness):
             # per-ADMM-iteration convergence records from the fetched
             # telemetry. The host-loop, blocked and stale runners
             # already emit live per-iteration records (admm.py feeds
             # BOTH the trace and the obs gauges there), so only the
-            # fully traced mesh program needs the post-hoc emission.
+            # fully traced mesh program needs the post-hoc emission:
+            # one record an ADMM iteration, iteration 0 (the plain
+            # solve, no dual yet) included, as the host loop's
             with dtrace.phase("record"):
-                if (not args.host_loop and not args.block_f
-                        and not args.staleness):
-                    # one record an ADMM iteration, iteration 0 (the
-                    # plain solve, no dual yet) included, as the host
-                    # loop's
-                    r1_it = [res1_it0] + list(np.asarray(r1s))
-                    for k, r1k in enumerate(r1_it):
-                        r1m = float(r1k.mean())
-                        du = float(duals[k - 1]) if k else 0.0
-                        dtrace.emit("admm_iter", interval=ti, iter=k,
-                                    r1_mean=r1m, dual=du)
-                        if obs.active():
-                            obs.inc("admm_iterations_total")
-                            obs.set_gauge("admm_primal_residual", r1m)
-                            obs.set_gauge("admm_dual_residual", du)
-                if obs.active():
-                    obs.inc("tiles_solved_total")
-                    obs.set_gauge("consensus_primal_residual", primal)
+                r1_it = [res1_it0] + list(np.asarray(r1s))
+                for k, r1k in enumerate(r1_it):
+                    r1m = float(r1k.mean())
+                    du = float(duals[k - 1]) if k else 0.0
+                    dtrace.emit("admm_iter", interval=ti, iter=k,
+                                r1_mean=r1m, dual=du)
+                    if obs.active():
+                        obs.inc("admm_iterations_total")
+                        obs.set_gauge("admm_primal_residual", r1m)
+                        obs.set_gauge("admm_dual_residual", du)
 
         # warm-start the next interval; per-subband divergence reset
         # (slave :680-683 res_ratio check; fullbatch warm-start analogue)
@@ -1060,19 +1052,20 @@ class ConsensusStepper:
         # residuals + write back (slave :832-869); multi-host: process 0
         # owns all outputs (shared-filesystem assumption, like the
         # reference's slaves-glob-the-same-paths setup)
+        BZf = None
         if is_writer:
             if args.use_global_solution:
                 # evaluate BZ at each subband: smooth consensus solutions
+                BZf = np.einsum("fp,mpknr->fmknr", Bpoly, np.asarray(Z))
                 J_res = BZf.reshape(nf, sky.n_clusters, kmax, n, 8)
             else:
                 J_res = JF_r8_5
             with dtrace.phase("residual", tile=ti):
-                xF_r = np.stack([utils.c2r(t.x) for t in tiles])
-                with dtrace.phase("carry"):     # host to device
-                    rargs = [jnp.asarray(J_res, rdt),
-                             jnp.asarray(xF_r, sdt), jnp.asarray(uF, rdt),
-                             jnp.asarray(vF, rdt), jnp.asarray(wF, rdt),
-                             jnp.asarray(freqs, rdt)]
+                # host to device: the solved Jones alone, the rest came
+                # staged with the interval
+                with dtrace.phase("carry"):
+                    rargs = [jnp.asarray(J_res, rdt), *staged["res_dev"],
+                             self._freqs_dev]
                     if self.dobeam:
                         # residual beam: the UNPADDED nf subbands with
                         # this tile's gmst track
@@ -1098,6 +1091,25 @@ class ConsensusStepper:
             # the ordered writer thread
             sched.start_host_copy(res_r)
             bubble += aw.submit(_write_res)
+
+        # numbers the history and the tile record hold and no log line
+        # prints: made here, while the devices run the residual program,
+        # and not between the solve's end and its dispatch
+        with dtrace.phase("primal"):
+            # the consensus primal residual ||J - BZ|| (the reference
+            # master's convergence axis)
+            if BZf is None:
+                BZf = np.einsum("fp,mpknr->fmknr", Bpoly, np.asarray(Z))
+            primal = float(np.linalg.norm(JF_r8_5 - BZf)
+                           / np.sqrt(BZf.size))
+            useful, lockstep_pct = cadmm.lockstep(trips, nf, self.fold)
+        rec = {"tile": ti, "res_0": float(res0.mean()),
+               "res_1": float(res1.mean()), "primal": primal,
+               "dual": float(duals[-1]) if len(duals) else 0.0}
+        self.history.append(rec)
+        if obs.active():
+            obs.inc("tiles_solved_total")
+            obs.set_gauge("consensus_primal_residual", primal)
 
         if self.spatial_file is not None:
             self._write_spatial_model(np.asarray(Z))

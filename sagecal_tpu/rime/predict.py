@@ -296,16 +296,29 @@ def apply_uvcut(rowflags, tile, uvmin: float, uvmax: float):
     """Host-side uv-window on a COPY of a tile's row flags (the shared
     gate for every mode: full window -> unchanged input). Returns int8
     [nrows]; callers must never write the result back into the tile
-    (the cut is solve-scoped, Data::loadData semantics)."""
+    (the cut is solve-scoped, Data::loadData semantics).
+
+    :func:`uvcut_flags`'s rule, the same operations in the same order,
+    in numpy: no device execution and no read-back, so a reader thread
+    that stages the next tile never queues behind the program that is
+    solving this one. The arithmetic is in the dtype the device form
+    computes in, what ``jnp.asarray`` makes of the tile's float64
+    geometry (float32 unless ``jax_enable_x64``). Upstream's
+    predict.c:876 computes in double; this follows the device form
+    instead so that a row at the cut's edge falls on the side it falls
+    on in ``pipeline.py``, which keeps the flags on the device
+    (tests/test_predict.py holds the two forms together)."""
+    rowflags = np.asarray(rowflags)
     if not (uvmin > 0.0 or uvmax < 1e9):
-        return np.asarray(rowflags)
-    import numpy as _np
-    return _np.asarray(uvcut_flags(
-        jnp.asarray(_np.asarray(rowflags), jnp.int32),
-        jnp.asarray(_np.asarray(tile.u, _np.float64)),
-        jnp.asarray(_np.asarray(tile.v, _np.float64)),
-        jnp.asarray(_np.asarray(tile.freqs, _np.float64)),
-        uvmin, uvmax), _np.int8)
+        return rowflags
+    dt = np.dtype(jax.dtypes.canonicalize_dtype(np.float64))
+    u, v, freqs = (np.asarray(a, np.float64).astype(dt, copy=False)
+                   for a in (tile.u, tile.v, np.atleast_1d(tile.freqs)))
+    uvdist = np.sqrt(u * u + v * v) * freqs[0]
+    with np.errstate(over="ignore"):    # -y beyond the dtype: no row is out
+        out = ((uvdist < dt.type(uvmin))
+               | (uvdist * freqs[-1] > dt.type(uvmax) * freqs[0]))
+    return np.where((rowflags == 0) & out, 2, rowflags).astype(np.int8)
 
 
 def chunk_indices(tilesz: int, nbase: int, nchunk: np.ndarray) -> np.ndarray:

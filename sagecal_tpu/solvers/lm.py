@@ -613,3 +613,12 @@ def make_weights(flags, dtype=jnp.float32, extra=None):
     if extra is not None:
         w = w * extra
     return w
+
+
+def make_weights_np(flags, dtype=np.float32):
+    """:func:`make_weights` without ``extra``, on the host: the same
+    [B, 8] array in numpy, for staging code that must not queue a device
+    execution and a read-back behind a running solve (cli_mpi's reader
+    thread)."""
+    return np.repeat((np.asarray(flags) == 0).astype(dtype)[:, None], 8,
+                     axis=1)

@@ -110,6 +110,181 @@ def test_stepping_by_hand_writes_the_same_bytes_as_main(tmp_path):
             assert np.abs(ra).mean() < 0.1 * np.abs(raw).mean()
 
 
+def _step_all(root, extra=(), on_step=None):
+    """Step the observation under ``root`` to its end as a driver
+    would; returns the stepper (closed) and what it wrote."""
+    st = cli_mpi.ConsensusStepper(
+        cli_mpi.build_parser().parse_args(argv(root) + list(extra)),
+        log=lambda *x: None)
+    pf = sched.Prefetcher(
+        lambda i: (lambda t: (t, st.stage(i, t)))(st.read(i)),
+        st.n_intervals, depth=st.depth)
+    try:
+        for i, (tiles, staged), wait in pf:
+            if on_step:
+                on_step(st, i, staged)
+            st.step(st.start + i, tiles, staged, wait)
+    finally:
+        pf.close()
+        st.close()
+    out = [open(os.path.join(root, "zsol.txt"), "rb").read()]
+    for k in range(NF):
+        out.append(open(os.path.join(root, f"sb{k}.ms.solutions"),
+                        "rb").read())
+        ms = ds.SimMS(os.path.join(root, f"sb{k}.ms"),
+                      data_column="CORRECTED_DATA")
+        out += [ms.read_tile(t).x.tobytes() for t in range(N_TILES)]
+    return st, out
+
+
+# -x 120 leaves a seventh of the rows out of the solve; -P 1 a consensus
+# that two subbands do not meet exactly, so ``primal`` is a number
+UVCUT = ["-x", "120", "-P", "1"]
+
+
+def test_stage_runs_nothing_on_a_device_and_reads_nothing_back(
+        tmp_path, monkeypatch):
+    """The reader's half of an interval under ``-x``: no ``wait`` span
+    under ``stage`` in the trace, no call of the two device forms whose
+    results used to be read back (``rime.predict.uvcut_flags``,
+    ``lm.make_weights``) from the reader's thread, and the residual's
+    data inputs staged with the interval in the dtypes and shapes
+    ``res_jit`` compiled for: the steps after the first compile nothing.
+
+    On the CPU backend ``np.asarray`` of a ``jax.Array`` goes through
+    the buffer protocol (a spy on ``jax.Array.__array__`` is never
+    called) and ``jax.transfer_guard_device_to_host("disallow")`` does
+    not fire on the tree before PR 50 either, so the read-backs are held
+    by their sources and their spans; on that tree this test fails on
+    both."""
+    import threading
+    from sagecal_tpu.diag import guard, trace
+    from sagecal_tpu.solvers import lm as lm_mod
+
+    root = str(tmp_path / "obs")
+    make_observation(root)
+    calls = []
+
+    def spy(name, fn):
+        def inner(*a, **k):
+            calls.append((name, threading.current_thread().name))
+            return fn(*a, **k)
+        return inner
+    monkeypatch.setattr(rp, "uvcut_flags", spy("uvcut_flags",
+                                               rp.uvcut_flags))
+    monkeypatch.setattr(lm_mod, "make_weights",
+                        spy("make_weights", lm_mod.make_weights))
+    logged, seen = [], []
+
+    def on_step(st, i, staged):
+        logged.append(guard.compiles_logged())
+        seen.append(staged)
+
+    diag = str(tmp_path / "diag.jsonl")
+    trace.enable(diag)
+    try:
+        st, _ = _step_all(root, UVCUT, on_step)
+        logged.append(guard.compiles_logged())
+    finally:
+        trace.disable()
+    assert st.depth == 1
+    assert not [c for c in calls if c[1] != "MainThread"], calls
+
+    ph = {r["id"]: r for r in trace.read(diag) if r["ev"] == "phase"}
+    stages = [r for r in ph.values() if r["name"] == "stage"]
+    assert len(stages) == N_TILES
+    assert {r["thread"] for r in stages} == {"prefetch-read"}
+    under_stage = [r for r in ph.values() if r["parent"] is not None
+                   and ph[r["parent"]]["name"] == "stage"]
+    assert not under_stage, under_stage
+    # the residual's carry is the solved Jones', and it is still there
+    carries = [r for r in ph.values() if r["name"] == "carry"
+               and r["parent"] is not None
+               and ph[r["parent"]]["name"] == "residual"]
+    assert len(carries) == N_TILES
+
+    # what the reader staged for the residual: unpadded subbands, the
+    # data column in the storage dtype, the geometry in the pipeline's
+    nrows = st.t0.nrows
+    for staged in seen:
+        x_r, u, v, w = staged["res_dev"]
+        assert all(isinstance(a, jax.Array) for a in (x_r, u, v, w))
+        assert x_r.shape == (NF, nrows, 1, 2, 2, 2) and x_r.dtype == st.sdt
+        assert u.shape == v.shape == w.shape == (NF, nrows)
+        assert u.dtype == v.dtype == w.dtype == st.rdt
+        assert "uvw" not in staged
+    assert st._freqs_dev.shape == (NF,) and st._freqs_dev.dtype == st.rdt
+    # logged[k]: before step k; the first step compiles, no other does
+    assert logged[1] > logged[0]
+    assert logged[1] == logged[2] == logged[3], logged
+
+
+def _device_form_staging(monkeypatch):
+    """``apply_uvcut`` and the weights as they were staged until PR 50:
+    on the device, and read back."""
+    from sagecal_tpu.solvers import lm as lm_mod
+
+    from test_predict import _device_uvcut
+
+    def make_weights_np(flags, dtype):
+        return np.asarray(lm_mod.make_weights(
+            jnp.asarray(flags, jnp.int32), dtype))
+    monkeypatch.setattr(rp, "apply_uvcut", _device_uvcut)
+    monkeypatch.setattr(lm_mod, "make_weights_np", make_weights_np)
+
+
+#: ``history`` of ``argv(root) + UVCUT`` on the tree before PR 50 (its
+#: commit 3f74ccf, this observation's seeds, x64 on the CPU)
+PARENT_HISTORY = [
+    {"tile": 0, "res_0": 0.036468088400320134,
+     "res_1": 0.0013268116207259678, "primal": 0.017227996846369432,
+     "dual": 0.0056057856696589406},
+    {"tile": 1, "res_0": 0.0054959613087502005,
+     "res_1": 0.0003712185856553853, "primal": 0.002191364207020298,
+     "dual": 0.0006118288925093115},
+    {"tile": 2, "res_0": 0.0007957928103041912,
+     "res_1": 0.0003396320482975097, "primal": 0.0009001271540460012,
+     "dual": 6.458535230297722e-05}]
+
+
+def test_host_staging_writes_the_parents_bytes(tmp_path, monkeypatch):
+    """One seeded observation under ``-x`` stepped three ways: with the
+    reader ahead (``--prefetch 1``), inline (``--prefetch 0``), and with
+    the uv cut and the weights made on the device and read back as
+    before PR 50. Residual columns, solutions files and the Z file are
+    the same bytes and ``history`` the same numbers in all three, and
+    ``history`` is the one the parent tree printed for this seed (to
+    1e-9, a thousand times finer than the log's six digits: another
+    CPU's last bits may differ, a moved order of operations would
+    not hide there)."""
+    src = str(tmp_path / "src")
+    make_observation(src)
+    runs = []
+    for name, extra, device_form in (("ahead", ["--prefetch", "1"], False),
+                                     ("inline", ["--prefetch", "0"], False),
+                                     ("device", ["--prefetch", "1"], True)):
+        root = str(tmp_path / name)
+        shutil.copytree(src, root)
+        with monkeypatch.context() as mp:
+            if device_form:
+                _device_form_staging(mp)
+            st, out = _step_all(root, UVCUT + extra)
+        runs.append((st.history, out))
+    (h0, out0), rest = runs[0], runs[1:]
+    for h, out in rest:
+        assert h == h0
+        assert out == out0
+    assert [set(r) for r in h0] == [set(r) for r in PARENT_HISTORY]
+    for got, want in zip(h0, PARENT_HISTORY):
+        for key, val in want.items():
+            assert got[key] == pytest.approx(val, rel=1e-9), (key, got)
+    # the cut was on: a run without it is another result
+    root = str(tmp_path / "nocut")
+    shutil.copytree(src, root)
+    st, out = _step_all(root, ["-P", "1"])
+    assert st.history[0]["res_0"] != h0[0]["res_0"] and out != out0
+
+
 def _parts(basis, rho, n_clusters, n_sta, nf):
     """The consensus halves of ``make_admm_runner`` with every subband
     local (``ax=None``): nothing of the solve is built or run."""

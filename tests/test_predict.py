@@ -297,6 +297,124 @@ def test_uvcut():
     assert list(out) == [2, 0, 2]
 
 
+def _device_uvcut(rowflags, tile, uvmin, uvmax):
+    """``apply_uvcut`` as it was while it ran on a device (until PR 50):
+    the form ``pipeline.py`` still computes its flags in."""
+    return np.asarray(rp.uvcut_flags(
+        jnp.asarray(np.asarray(rowflags), jnp.int32),
+        jnp.asarray(np.asarray(tile.u, np.float64)),
+        jnp.asarray(np.asarray(tile.v, np.float64)),
+        jnp.asarray(np.asarray(tile.freqs, np.float64)),
+        uvmin, uvmax), np.int8)
+
+
+def _cell_tile(seed=50, n_sta=62, tilesz=10, freqs=(150e6,)):
+    """u, v (seconds) of a tile of the consensus cells' shape: 1891
+    baselines x 10 slots, a dense core inside a few kilometres, a
+    tenth of the rows flagged 1 or 2 beforehand."""
+    import types
+    rng = np.random.default_rng(seed)
+    r = 400.0 * np.exp(rng.normal(0.0, 1.3, n_sta))
+    th = rng.uniform(0, 2 * np.pi, n_sta)
+    p, q = np.triu_indices(n_sta, 1)
+    rot = 2 * np.pi * np.arange(tilesz)[:, None] * 10.0 / 86164.0
+    dx, dy = (r * np.cos(th))[p] - (r * np.cos(th))[q], \
+        (r * np.sin(th))[p] - (r * np.sin(th))[q]
+    u = (dx * np.cos(rot) - dy * np.sin(rot)).ravel() / ds.C_M_S
+    v = 0.8 * (dx * np.sin(rot) + dy * np.cos(rot)).ravel() / ds.C_M_S
+    flags = rng.choice(np.array([0, 1, 2], np.int8), u.size,
+                       p=[0.9, 0.05, 0.05])
+    return types.SimpleNamespace(u=u, v=v, freqs=np.asarray(freqs, float),
+                                 flags=flags)
+
+
+def _edge_rows(edge, freq0, dt, steps=4):
+    """u (v = 0) whose uv distance in ``dt`` walks ``steps`` steps of
+    the dtype either side of ``edge`` wavelengths at ``freq0``."""
+    u0 = dt.type(dt.type(edge) / dt.type(freq0))
+    us = [u0]
+    for _ in range(steps):
+        us.insert(0, np.nextafter(us[0], dt.type(0)))
+        us.append(np.nextafter(us[-1], dt.type(np.inf)))
+    return np.asarray(us, np.float64)       # exact: every dt is a double
+
+
+@pytest.mark.parametrize("x64", [True, False], ids=["x64", "x32"])
+@pytest.mark.parametrize("case", ["cell", "edges", "edges2ch", "full"])
+def test_host_uvcut_is_the_device_rule_flag_for_flag(case, x64):
+    """``apply_uvcut`` (numpy, the consensus reader's thread) against
+    ``uvcut_flags`` (jnp, what ``pipeline.py`` keeps on the device): one
+    rule in two forms, in the dtype ``jnp.asarray`` gives the tile's
+    float64 geometry. On a tile of the cells' shape under ``-x 30``
+    with rows already flagged 1 and 2; on rows built to lie within four
+    steps of the computing dtype either side of both edges; on a full
+    window, which hands the input back unchanged."""
+    import jax
+    import types
+    with jax.enable_x64(x64):
+        dt = np.dtype(jax.dtypes.canonicalize_dtype(np.float64))
+        assert dt == (np.float64 if x64 else np.float32)
+        if case == "full":
+            tile = _cell_tile()
+            out = rp.apply_uvcut(tile.flags, tile, 0.0, 1e9)
+            assert out is tile.flags
+            return
+        if case == "cell":
+            tile, uvmin, uvmax = _cell_tile(), 30.0, 1e9
+        else:
+            freqs = (150e6,) if case == "edges" else (149e6, 151e6)
+            uvmin, uvmax = 30.1, 2500.3     # neither is a float32
+            # the upper test is uvdist * f_last > uvmax * f_0
+            u = np.concatenate([
+                _edge_rows(uvmin, freqs[0], dt),
+                _edge_rows(uvmax * freqs[0] / freqs[-1], freqs[0], dt)])
+            tile = types.SimpleNamespace(
+                u=np.concatenate([u, 0 * u]), v=np.concatenate([0 * u, -u]),
+                freqs=np.asarray(freqs), flags=np.zeros(2 * u.size, np.int8))
+        got = rp.apply_uvcut(tile.flags, tile, uvmin, uvmax)
+        want = _device_uvcut(tile.flags, tile, uvmin, uvmax)
+    assert got.dtype == want.dtype == np.int8 and got.shape == want.shape
+    assert got.tobytes() == want.tobytes()
+    assert got is not tile.flags and got.flags.writeable
+    # the cut cuts, and leaves what was flagged as it was
+    was = tile.flags != 0
+    assert (got[was] == tile.flags[was]).all()
+    assert set(got[~was]) == {0, 2}
+    if case == "cell":
+        assert 0.002 < (got[~was] == 2).mean() < 0.2
+    else:
+        # each edge is straddled: both sides of it among its nine rows
+        for rows in np.split(got, 4):
+            assert set(rows) == {0, 2}, rows
+
+
+def test_host_uvcut_window_beyond_the_dtype_cuts_nothing():
+    """``-y`` past float32's range: the product overflows to infinity in
+    both forms and no row is outside it."""
+    import jax
+    with jax.enable_x64(False):
+        tile = _cell_tile()
+        got = rp.apply_uvcut(tile.flags, tile, 0.0, 1e35)
+        want = _device_uvcut(tile.flags, tile, 0.0, 1e35)
+    assert got.tobytes() == want.tobytes() == tile.flags.tobytes()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float64, jnp.float32, jnp.bfloat16],
+                         ids=["f64", "f32", "bf16"])
+def test_host_weights_are_the_device_weights(dtype):
+    """``lm.make_weights_np`` is ``np.asarray(lm.make_weights(...))``:
+    dtype, shape, bytes, and an array of its own."""
+    from sagecal_tpu.solvers import lm
+    flags = _cell_tile().flags
+    want = np.asarray(lm.make_weights(jnp.asarray(flags, jnp.int32), dtype))
+    got = lm.make_weights_np(flags, np.dtype(dtype))
+    assert got.dtype == want.dtype == np.dtype(dtype)
+    assert got.shape == want.shape == (flags.size, 8)
+    assert got.tobytes() == want.tobytes()
+    assert got.flags.c_contiguous and got.flags.writeable
+    assert float(got.astype(np.float64).sum()) == 8 * (flags == 0).sum()
+
+
 def test_simulate_roundtrip_consistency():
     s = point_source("P1", 0.01, 0.005, sI=1.0)
     sky = make_sky({"P1": s}, [(0, 1, ["P1"])])

@@ -1,0 +1,17 @@
+"""``host_serial_ms`` in the cell ``predict-extended``: the reader of ``host_serial_ms.py``
+under a name of this cell's own, because that entry lists its cells and a
+list that exists is not a ``model_config`` PR's to edit (PR 51, as PR 37's
+``.sub``, PR 34's ``.t120``, PR 44's ``.hyb`` and PR 48's ``.beam`` readers;
+a ``benchmark`` issue folds the entries).
+The host's own work a tile, which is ``predict-m8x128``'s: the tile is
+the device's here, not the writer's."""
+
+import harness
+
+_WAS = harness.load_module("layer_metrics", "host_serial_ms")
+NAME, UNIT = "host_serial_ms.ext", _WAS.UNIT
+LAYER, MOVES = _WAS.LAYER, _WAS.MOVES
+
+
+def read(run):
+    return _WAS.read(run)

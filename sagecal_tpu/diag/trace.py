@@ -46,8 +46,9 @@ event            meaning / required extra fields
 ``tile``         one solve interval's convergence summary (pipeline.py /
                  cli_mpi.py): ``tile``, ``res_0``, ``res_1`` (a
                  simulated tile, ``run_simulation``, solves nothing and
-                 carries ``tile``, the overlap pair, ``mode`` and
-                 ``clusters_in_model``); optional
+                 carries ``tile``, the overlap pair, ``mode``,
+                 ``clusters_in_model`` and the ``coh_path`` ..
+                 ``shapelet_slots`` fields below); optional
                  ``mean_nu``, ``solver_iters``, ``cg_iters`` (inner CG
                  trips under them: LM's PCG, RTR's truncated-CG
                  bodies), ``row_passes`` (RTR's evaluations of the
@@ -72,7 +73,16 @@ event            meaning / required extra fields
                  ``beam_mode`` (``-B``) and, with a beam,
                  ``beam_elements`` (``Emax``, the element slots a
                  station carries) and ``beam_sources`` (the live
-                 sources whose gains the tables hold), ``plan`` and
+                 sources whose gains the tables hold),
+                 ``sources_point``, ``sources_gaussian``,
+                 ``sources_disk``, ``sources_ring``,
+                 ``sources_shapelet`` (the model's live sources by
+                 kind), ``shapelet_n0max`` (the largest shapelet
+                 order, 0 without one) and ``shapelet_slots`` (the
+                 source slots for which the compiled source sum
+                 evaluates the shapelet basis: ``M x Smax`` where the
+                 model holds a shapelet, else 0: pipeline.source_kinds),
+                 ``plan`` and
                  ``solve_dispatches`` (what sagefit_host's last sweep
                  executed, "promoted", "fused" or "per_cluster", and
                  the device executions the solve issued), ``minutes``,

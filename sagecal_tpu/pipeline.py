@@ -114,6 +114,27 @@ def _emit_tile_record(ti, res_0, res_1, mean_nu, info, minutes,
     dtrace.emit("tile", **rec)
 
 
+def source_kinds(sky: skymodel.ClusterSky) -> dict:
+    """What kinds of source the model holds, for the ``tile`` records:
+    the live sources of each kind, the largest shapelet order, and the
+    source slots for which the XLA source sum evaluates the shapelet
+    basis: ``rime/predict.coherencies`` compiles it in or out for the
+    whole model (``with_shapelets``), so one shapelet source makes it
+    ``n0max^2`` modes for every one of the ``M x Smax`` slots."""
+    live = np.asarray(sky.smask, bool)
+    stype = np.asarray(sky.stype)
+    out = {f"sources_{name}": int(np.sum(live & (stype == code)))
+           for name, code in (("point", skymodel.STYPE_POINT),
+                              ("gaussian", skymodel.STYPE_GAUSSIAN),
+                              ("disk", skymodel.STYPE_DISK),
+                              ("ring", skymodel.STYPE_RING),
+                              ("shapelet", skymodel.STYPE_SHAPELET))}
+    n0 = np.asarray(sky.sh_n0)
+    out["shapelet_n0max"] = int(n0.max()) if n0.size else 0
+    out["shapelet_slots"] = int(live.size) if out["shapelet_n0max"] else 0
+    return out
+
+
 def effective_solver_mode(mode: int, n_stations: int) -> int:
     """LMCUT downgrade (fullbatch_mode.cpp:397)."""
     if n_stations <= LMCUT and mode == int(SolverMode.RTR_OSLM_LBFGS):
@@ -235,7 +256,7 @@ class FullBatchPipeline:
         # host values for every ``tile`` record (diag/trace.py)
         self.coh_record = dict(
             coh_path="pallas" if self.use_pallas else "xla",
-            beam_mode=self.dobeam)
+            beam_mode=self.dobeam, **source_kinds(sky))
         # the beam's static leaves (stations, elements, pattern, pointing)
         # staged ONCE, after the precession above; a tile restages its
         # gmst track alone (_tile_beam), as cli_mpi does
@@ -1189,7 +1210,8 @@ class FullBatchPipeline:
             if dtrace.active():
                 dtrace.emit("tile", tile=ti, overlap=depth,
                             bubble_s=io_wait + blocked, mode=mode,
-                            clusters_in_model=clusters_in_model)
+                            clusters_in_model=clusters_in_model,
+                            **self.coh_record)
             log(f"Timeslot: {ti} simulated (mode={mode})")
 
         source = sched.Prefetcher(produce, None, depth=depth,

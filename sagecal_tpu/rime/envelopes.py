@@ -13,6 +13,7 @@ lanes produce finite garbage that gets masked by zero flux downstream.
 
 from __future__ import annotations
 
+import jax
 import numpy as np
 import jax.numpy as jnp
 
@@ -148,6 +149,11 @@ def shapelet_sign_tables(n0max: int):
     return sign, is_imag
 
 
+# a scope of its own below ``rime/phasor`` (PERF.md section 3; metadata
+# only): the basis is ``n0max^2`` modes for every source slot of a model
+# that holds one shapelet, and the trace reads it apart as
+# ``rime/phasor/shapelet``.  The other envelopes fuse into the source sum.
+@jax.named_scope("shapelet")
 def shapelet(u, v, w, eX, eY, eP, beta, modes, n0, n0max: int,
              cxi, sxi, cphi, sphi, use_projection):
     """predict.c:142 — complex envelope 2*pi*(Re + i*Im)*a*b.

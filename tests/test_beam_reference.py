@@ -309,7 +309,9 @@ def test_the_model_with_gains(obs, files):
     assert not any("SYNTHETIC" in ln for ln in log), log
     assert pipe.precessed and pipe.coh_record == {
         "coh_path": "xla", "beam_mode": 1, "beam_elements": 6,
-        "beam_sources": 6}
+        "beam_sources": 6, "sources_point": 6, "sources_gaussian": 0,
+        "sources_disk": 0, "sources_ring": 0, "sources_shapelet": 0,
+        "shapelet_n0max": 0, "shapelet_slots": 0}
     tile = ms.read_tile(1)
     assert np.array_equal(tile.time_mjd, obs.time_mjd(1))
     beam = pipe._tile_beam(tile, 1)

@@ -179,6 +179,26 @@ OVERTAKEN = {
     "test_hybrid.py::test_the_older_cells_lists_are_as_pr42_held_them":
         "configs[-2:] and workloads end with PR 44's no longer: PR 48's "
         "cell and configuration go at the end",
+    # PR 51 appended a cell, a configuration and eight entries that list
+    # the new cell alone.  test_beam_cell.py holds PR 48's as the LAST of
+    # their lists (``workloads``, ``configs[-3:]``, the counts of eight:
+    # one case) and runs the five cases test_hybrid.py pins by place on
+    # the manifest less PR 48's entries only.
+    # benchmarks/tests/test_extended.py runs each of the six whole on the
+    # manifest less this PR's cell, configuration and entries
+    # (test_what_pr48_pins_by_place_holds_less_this_prs_entries), and
+    # holds every older cell's list, PR 48's entries and the order of
+    # cells and configurations by name
+    # (test_the_older_cells_lists_are_as_pr48_held_them).
+    **{"test_beam_cell.py::test_what_pr44_pins_by_place_holds_less_this_"
+       f"prs_entries[{case}]":
+       "PR 48's cell, configuration and fifteen entries are no longer "
+       "the LAST of their lists: PR 51's go at the end"
+       for case in ("fold-cell", "fold-configuration", "fold-pr40",
+                    "subtract", "older-lists")},
+    "test_beam_cell.py::test_the_older_cells_lists_are_as_pr44_held_them":
+        "workloads and configs end with PR 48's no longer, and are nine: "
+        "PR 51's cell and configuration go at the end",
 }
 
 

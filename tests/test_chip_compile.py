@@ -218,16 +218,17 @@ def _holds_the_three_scopes(text):
     assert not contractions, contractions
 
 
-def _lower_simulate_program(one_chip, tilesz, mode):
+def _lower_simulate_program(one_chip, tilesz, mode, sky=None):
     """``run_simulation``'s ``sim_fn`` as the pipeline builds it for
-    ``-a <mode> -p -z`` on an 8 x 128 sky: pairs in and out, the tile's
-    Jones as [M, K, N, 8], no beam, and the chunk map, the timeslots,
-    the channel and the ignore mask (one cluster, the target, left out)
-    closed over as constants."""
+    ``-a <mode> -p -z`` on an 8 x 128 sky (of flat points, or the
+    ``ClusterSky`` given): pairs in and out, the tile's Jones as
+    [M, K, N, 8], no beam, and the chunk map, the timeslots, the channel
+    and the ignore mask (one cluster, the target, left out) closed over
+    as constants."""
     from problems import make_sky
     from sagecal_tpu.io import dataset as ds
     from sagecal_tpu.rime import predict as rp, residual as rr
-    sky = make_sky(M, srcs_per_cluster=128)
+    sky = sky or make_sky(M, srcs_per_cluster=128)
     dsky = rp.sky_to_device(sky, jnp.float32)
     rows = NB * tilesz
     cidx = rp.chunk_indices(tilesz, NB, sky.nchunk)
@@ -740,3 +741,73 @@ def test_source_sum_fills_its_registers(topo, one_chip, program):
     one_row = [line for line, dims, _, tile in arrays
                if tile == (1, 128) and rows in dims and S in dims]
     assert not one_row, one_row
+
+
+# -- a sky that is not points (PR 51) -----------------------------------------
+
+def _estimated_cycles(text):
+    """``(cycles, operation's source name)`` of every instruction of the
+    compiled ``text`` that carries the TPU compiler's own estimate."""
+    got = []
+    for ln in text.splitlines():
+        cycles = re.search(r'"estimated_cycles":"(\d+)"', ln)
+        if cycles:
+            name = re.search(r'op_name="([^"]*)"', ln)
+            got.append((int(cycles.group(1)), name.group(1) if name else ""))
+    return got
+
+
+def test_extended_sky_simulate_program_compiles_and_fits(one_chip, tmp_path):
+    """The simulate program of ``run_simulation`` (``-a 1 -p -F 1``) at
+    the sky of ``lofar62-m8x128-ext``, read through the program's own
+    reader from the text the benchmark's reference writes: 18 910 rows,
+    8 x 128 sources, four of them shapelets, ``n0max`` 10.  It compiles
+    for the described v5e and fits with room to spare; the shapelet
+    basis has a scope of its own below ``rime/phasor``; and the
+    compiler's ``estimated_cycles`` for ONE trip of the map over clusters
+    are printed under that scope beside the rest.  As compiled here (cpu,
+    PR 51): 54 425 492 cycles a trip, 43 390 706 in operations named
+    under ``rime/phasor``, 41 584 471 under ``rime/phasor/shapelet`` (two
+    ``multiply_reduce`` fusions of 19 576 694 each over ``f32[18910, 128,
+    10]``), 0.481 GiB of temporaries; the same program on 8 x 128 points
+    is 382 545 a trip and has none.  ``with_shapelets`` is one static flag
+    for the whole model: four shapelet sources make the program evaluate
+    a hundred modes for every one of 1024 source slots.  The ``perf_opt``
+    that evaluates the basis where there is a shapelet has its CPU
+    witness here: the scope's cycles fall by two orders."""
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
+                                    "benchmarks"))
+    import harness
+    import reference_extended as rx
+    from sagecal_tpu import skymodel
+    conf = harness.load_config("benchmarks/configs/lofar62-m8x128-ext.json")
+    lines, clusters, modes = rx.draw_sky(conf)
+    sky_path = tmp_path / "sky.txt"
+    sky_path.write_text("\n".join(lines) + "\n")
+    (tmp_path / "sky.txt.cluster").write_text("\n".join(clusters) + "\n")
+    for name, text in modes.items():
+        (tmp_path / (name + ".fits.modes")).write_text(text)
+    sky = skymodel.read_sky_cluster(
+        str(sky_path), str(sky_path) + ".cluster", conf["ra0_rad"],
+        conf["dec0_rad"], conf["freq_hz"], format_3=True)
+    assert sky.smask.shape == (M, 128) and sky.smask.all()
+    assert sky.sh_modes.shape[-1] == 100 and (sky.sh_n0 > 0).sum() == 4
+    compiled = _lower_simulate_program(one_chip, TILESZ, 1, sky=sky).compile()
+    mem = compiled.memory_analysis()
+    need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert 0 < need < 2 * 2 ** 30, need / 2 ** 30
+    text = compiled.as_text()
+    _holds_the_three_scopes(text)
+    cycles = _estimated_cycles(text)
+    total = sum(c for c, _ in cycles)
+    phasor = sum(c for c, name in cycles if "rime/phasor" in name)
+    basis = sum(c for c, name in cycles if "(shapelet)" in name)
+    print(f"extended simulate -t {TILESZ}: temp "
+          f"{mem.temp_size_in_bytes / 2 ** 30:.4f} GiB; estimated_cycles a "
+          f"trip of the map: {total} in all, {phasor} named under "
+          f"rime/phasor, {basis} under rime/phasor/shapelet, "
+          f"{phasor - basis} the rest of the source sum")
+    assert basis > 0, "no operation names the scope rime/phasor/shapelet"
+    # most of the program, as long as every slot evaluates the basis
+    assert basis > 0.5 * total

@@ -80,8 +80,10 @@ event            meaning / required extra fields
                  kind), ``shapelet_n0max`` (the largest shapelet
                  order, 0 without one) and ``shapelet_slots`` (the
                  source slots for which the compiled source sum
-                 evaluates the shapelet basis: ``M x Smax`` where the
-                 model holds a shapelet, else 0: pipeline.source_kinds),
+                 evaluates the shapelet basis: ``M x S_sh``, the
+                 model's compact pack of its shapelet sources, ``S_sh``
+                 the most any cluster holds, 0 without one:
+                 pipeline.source_kinds),
                  ``plan`` and
                  ``solve_dispatches`` (what sagefit_host's last sweep
                  executed, "promoted", "fused" or "per_cluster", and

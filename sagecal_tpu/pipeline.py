@@ -118,9 +118,9 @@ def source_kinds(sky: skymodel.ClusterSky) -> dict:
     """What kinds of source the model holds, for the ``tile`` records:
     the live sources of each kind, the largest shapelet order, and the
     source slots for which the XLA source sum evaluates the shapelet
-    basis: ``rime/predict.coherencies`` compiles it in or out for the
-    whole model (``with_shapelets``), so one shapelet source makes it
-    ``n0max^2`` modes for every one of the ``M x Smax`` slots."""
+    basis: ``M x S_sh``, the compact pack of the model's shapelet
+    sources (``rime/predict.shapelet_slots``), ``S_sh`` the most any
+    cluster holds and 0 where the model has none."""
     live = np.asarray(sky.smask, bool)
     stype = np.asarray(sky.stype)
     out = {f"sources_{name}": int(np.sum(live & (stype == code)))
@@ -131,7 +131,7 @@ def source_kinds(sky: skymodel.ClusterSky) -> dict:
                               ("shapelet", skymodel.STYPE_SHAPELET))}
     n0 = np.asarray(sky.sh_n0)
     out["shapelet_n0max"] = int(n0.max()) if n0.size else 0
-    out["shapelet_slots"] = int(live.size) if out["shapelet_n0max"] else 0
+    out["shapelet_slots"] = int(rp.shapelet_slots(sky).size)
     return out
 
 
@@ -297,7 +297,7 @@ class FullBatchPipeline:
         # dataset handle) alive through a cached bound method; the LRU
         # bound in serve.cache caps that retention.
         self._ckey = pcache.token(
-            [np.asarray(a) for a in self.dsky],
+            [np.asarray(a) for a in jax.tree.leaves(self.dsky)],
             dict(freq0=meta["freq0"], fdelta=meta["fdelta"],
                  freqs=list(meta["freqs"]), tilesz=self.tilesz_eff,
                  nbase=int(meta["nbase"]), n=self.n),

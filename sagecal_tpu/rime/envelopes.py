@@ -4,7 +4,9 @@ Capability parity with reference ``src/lib/Radio/predict.c``
 (``gaussian_contrib``:193, ``ring_contrib``:222, ``disk_contrib``:237,
 ``shapelet_contrib``:142 with Hermite recursion ``H_e``:31) — re-designed as
 masked array ops over a [..., S] source grid instead of per-source function
-pointers, so one fused XLA computation evaluates every morphology.
+pointers, so one fused XLA computation evaluates every morphology; the
+shapelet basis alone is evaluated on a compact pack of the model's shapelet
+sources, [S_sh, B], and placed into the grid.
 
 All inputs are in wavelengths (u·f/c etc. — callers pass u_sec * freq).
 Padded sources must carry eX=eY=0; every division here is guarded so padded
@@ -17,9 +19,7 @@ import jax
 import numpy as np
 import jax.numpy as jnp
 
-from sagecal_tpu.skymodel import (
-    STYPE_DISK, STYPE_GAUSSIAN, STYPE_POINT, STYPE_RING, STYPE_SHAPELET,
-)
+from sagecal_tpu.skymodel import STYPE_DISK, STYPE_GAUSSIAN, STYPE_RING
 
 
 def _project_uv(u, v, w, cxi, sxi, cphi, sphi, use_projection, negate):
@@ -115,7 +115,9 @@ def _hermite_basis(x, n0max: int):
     """Shapelet 1-D basis B_n(x) = H_n(x) exp(-x^2/2)/sqrt(2^(n+1) n!).
 
     Same normalization as predict.c:86-92 (note its sqrt(2<<n * n!) =
-    sqrt(2^(n+1) n!)). Returns [..., n0max]. Physicists' Hermite recursion
+    sqrt(2^(n+1) n!)). Returns [n0max, *x.shape], the mode index on the
+    LEADING axis, so that ``x``'s own minor axis (the rows) stays the
+    minor axis of every array made here. Physicists' Hermite recursion
     unrolled at trace time (n0max is static).
     """
     hs = [jnp.ones_like(x)]
@@ -130,7 +132,7 @@ def _hermite_basis(x, n0max: int):
             fact *= n
         norms.append(1.0 / np.sqrt(float(2 ** (n + 1)) * fact))
     expv = jnp.exp(-0.5 * x * x)
-    return jnp.stack([h * (expv * nrm) for h, nrm in zip(hs, norms)], axis=-1)
+    return jnp.stack([h * (expv * nrm) for h, nrm in zip(hs, norms)], axis=0)
 
 
 def shapelet_sign_tables(n0max: int):
@@ -150,19 +152,24 @@ def shapelet_sign_tables(n0max: int):
 
 
 # a scope of its own below ``rime/phasor`` (PERF.md section 3; metadata
-# only): the basis is ``n0max^2`` modes for every source slot of a model
-# that holds one shapelet, and the trace reads it apart as
-# ``rime/phasor/shapelet``.  The other envelopes fuse into the source sum.
+# only): the trace reads the basis apart as ``rime/phasor/shapelet``.  The
+# other envelopes fuse into the source sum.
 @jax.named_scope("shapelet")
-def shapelet(u, v, w, eX, eY, eP, beta, modes, n0, n0max: int,
-             cxi, sxi, cphi, sphi, use_projection):
+def shapelet(u, v, w, eX, eY, eP, beta, modes, cxi, sxi, cphi, sphi,
+             use_projection):
     """predict.c:142 — complex envelope 2*pi*(Re + i*Im)*a*b.
 
-    ``modes`` is [..., n0max^2] zero-padded; ``n0`` the per-source live mode
-    count (modes beyond n0^2 are zero so no explicit mask is needed).
+    ``modes`` is [n0max, n0max, ...]: a source's coefficients ``c[n2,
+    n1]`` on two LEADING axes, zero beyond its own ``n0`` (so no explicit
+    mask is needed), the rest broadcasting like the source's other
+    parameters.  The caller lays the sources on a leading axis and the
+    rows on the minor one (u, v, w [1, B], a source's parameters [S_sh,
+    1]): the result is [S_sh, B], and no array made here has the modes or
+    the sources on the minor axis with the rows above them.
     Evaluates the Fourier-domain Hermite basis at (-ut, vt) as the reference
     does (it decomposes f(-l, m)).
     """
+    n0max = modes.shape[0]
     up, vp = _project_uv(u, v, w, cxi, sxi, cphi, sphi, use_projection,
                          negate=True)
     a = 1.0 / jnp.where(eX != 0, eX, 1.0)
@@ -171,28 +178,34 @@ def shapelet(u, v, w, eX, eY, eP, beta, modes, n0, n0max: int,
     ut = a * (cosph * up - sinph * vp)
     vt = b * (sinph * up + cosph * vp)
 
-    bu = _hermite_basis(-ut * beta, n0max)          # [..., n0max] (n1 axis)
-    bv = _hermite_basis(vt * beta, n0max)           # [..., n0max] (n2 axis)
+    bu = _hermite_basis(-ut * beta, n0max)          # [n0max, ...] (n1 axis)
+    bv = _hermite_basis(vt * beta, n0max)           # [n0max, ...] (n2 axis)
     sign, is_imag = shapelet_sign_tables(n0max)
-    # mode value for (n1, n2): sign * bu[n1] * bv[n2]
-    grid = bu[..., None, :] * bv[..., :, None]      # [..., n2, n1]
-    grid = grid * jnp.asarray(sign.T, grid.dtype)   # sign[n1,n2] -> [n2,n1]
-    m = modes.reshape(modes.shape[:-1] + (n0max, n0max))  # [..., n2, n1]
-    contrib = m * grid
-    imag_mask = jnp.asarray(is_imag.T, grid.dtype)
-    realsum = jnp.sum(contrib * (1.0 - imag_mask), axis=(-1, -2))
-    imagsum = jnp.sum(contrib * imag_mask, axis=(-1, -2))
-    return 2.0 * jnp.pi * (realsum + 1j * imagsum) * a * b
+    # sum_n2 bv[n2] * sum_n1 (sign * modes)[n2, n1] * bu[n1], the real and
+    # the imaginary parity of n1 + n2 apart: multiply-adds on [S_sh, B]
+    sums = [jnp.zeros_like(ut), jnp.zeros_like(ut)]
+    for n2 in range(n0max):
+        inner = [0.0, 0.0]
+        for n1 in range(n0max):
+            part = int(is_imag[n1, n2])
+            inner[part] = inner[part] + (float(sign[n1, n2])
+                                         * modes[n2, n1]) * bu[n1]
+        sums = [s + bv[n2] * i for s, i in zip(sums, inner)]
+    return 2.0 * jnp.pi * (sums[0] + 1j * sums[1]) * a * b
 
 
 def apply_envelopes(phasor, stype, u, v, w, eX, eY, eP, cxi, sxi, cphi, sphi,
-                    use_projection, sh_beta, sh_modes, sh_n0, n0max: int,
-                    with_shapelets: bool = True):
+                    use_projection, shapelets=None):
     """Multiply a per-source phasor by its morphology envelope.
 
-    ``phasor`` and all source params broadcast to a common [..., S] shape;
-    u,v,w are in wavelengths. ``with_shapelets`` statically elides the
-    (expensive) shapelet basis when the model has none.
+    ``phasor`` [B, S]; the source params [1, S]; u, v, w [B, 1] in
+    wavelengths.  ``shapelets`` is the cluster's row of the model's
+    compact pack of shapelet sources (``rime/predict.ShapeletPack``:
+    [S_sh] parameters, [n0max, n0max, S_sh] modes, ``slot`` the source
+    slot of each, -1 where the pack is padding), or None where the model
+    has none or the caller elides them: the basis is evaluated for the
+    pack alone, rows on the minor axis, and its envelope placed into the
+    phasors of its slots.
     """
     env = jnp.ones_like(phasor)
     env = jnp.where(stype == STYPE_GAUSSIAN,
@@ -204,10 +217,14 @@ def apply_envelopes(phasor, stype, u, v, w, eX, eY, eP, cxi, sxi, cphi, sphi,
     env = jnp.where(stype == STYPE_DISK,
                     disk(u, v, w, eX, cxi, sxi, cphi, sphi).astype(env.dtype),
                     env)
-    out = phasor * env
-    if with_shapelets:
-        sh = shapelet(u, v, w, eX, eY, eP, sh_beta, sh_modes, sh_n0, n0max,
-                      cxi, sxi, cphi, sphi, use_projection)
-        out = jnp.where(stype == STYPE_SHAPELET, phasor * sh.astype(out.dtype),
-                        out)
-    return out
+    if shapelets is not None:
+        p = shapelets
+        col = lambda a: a[:, None]
+        sh = shapelet(u.T, v.T, w.T, col(p.eX), col(p.eY), col(p.eP),
+                      col(p.beta), p.modes[..., None], col(p.cxi),
+                      col(p.sxi), col(p.cphi), col(p.sphi),
+                      col(p.use_projection)).astype(env.dtype)   # [S_sh, B]
+        slots = jnp.arange(phasor.shape[-1])
+        for k in range(p.slot.shape[0]):     # a padding's slot is -1: nowhere
+            env = jnp.where(slots == p.slot[k], sh[k][:, None], env)
+    return phasor * env

@@ -41,8 +41,33 @@ from sagecal_tpu.rime import envelopes, planes as pl
 from sagecal_tpu.skymodel import ClusterSky, STYPE_SHAPELET
 
 
+class ShapeletPack(NamedTuple):
+    """The model's shapelet sources alone, packed compactly: [M, S_sh]
+    arrays, ``S_sh`` the most live shapelet sources (``sh_n0`` > 0) any
+    cluster holds, 0 where the model has none.  ``slot`` is the source
+    slot a packed source came from, -1 where a cluster holds fewer than
+    ``S_sh`` (its other values are then harmless padding); ``modes`` is
+    [M, n0max, n0max, S_sh], a source's ``c[n2, n1]`` zero beyond its own
+    order.  What ``envelopes.shapelet`` needs and no more: the basis is
+    evaluated for these slots only, and ``S_sh`` is read from a shape, so
+    a sky that enters a program as an argument says it too."""
+
+    slot: jax.Array
+    eX: jax.Array
+    eY: jax.Array
+    eP: jax.Array
+    cxi: jax.Array
+    sxi: jax.Array
+    cphi: jax.Array
+    sphi: jax.Array
+    use_projection: jax.Array
+    beta: jax.Array
+    modes: jax.Array
+
+
 class SkyArrays(NamedTuple):
-    """Device-resident padded sky model (pytree of [M, Smax] arrays)."""
+    """Device-resident padded sky model (pytree of [M, Smax] arrays, and
+    the compact pack of its shapelet sources)."""
 
     ll: jax.Array
     mm: jax.Array
@@ -70,10 +95,44 @@ class SkyArrays(NamedTuple):
     cphi: jax.Array
     sphi: jax.Array
     use_projection: jax.Array
-    sh_n0: jax.Array
-    sh_beta: jax.Array
-    sh_modes: jax.Array
+    shapelets: ShapeletPack
     smask: jax.Array
+
+
+def shapelet_slots(sky: ClusterSky) -> np.ndarray:
+    """[M, S_sh] int32: per cluster the slots of its live shapelet sources
+    in their order, -1 where it has fewer than the cluster with the most.
+    The slots the source sum evaluates the shapelet basis for."""
+    is_sh = (np.asarray(sky.smask, bool)
+             & (np.asarray(sky.stype) == STYPE_SHAPELET)
+             & (np.asarray(sky.sh_n0) > 0))
+    slot = np.full((len(is_sh), int(is_sh.sum(axis=1).max(initial=0))), -1,
+                   np.int32)
+    for m, row in enumerate(is_sh):
+        idx = np.flatnonzero(row)
+        slot[m, :len(idx)] = idx
+    return slot
+
+
+def _pack_shapelets(sky: ClusterSky, f) -> ShapeletPack:
+    slot = shapelet_slots(sky)
+    live, at = slot >= 0, np.maximum(slot, 0)
+    take = lambda a, fill: np.where(
+        live, np.take_along_axis(np.asarray(a), at, 1), fill)
+    n0max = int(np.sqrt(sky.sh_modes.shape[-1]).round())
+    modes = np.where(live[:, :, None],
+                     np.take_along_axis(sky.sh_modes, at[:, :, None], 1), 0.0)
+    return ShapeletPack(
+        slot=jnp.asarray(slot),
+        eX=f(take(sky.eX, 1.0)), eY=f(take(sky.eY, 1.0)),
+        eP=f(take(sky.eP, 0.0)),
+        cxi=f(take(sky.cxi, 1.0)), sxi=f(take(sky.sxi, 0.0)),
+        cphi=f(take(sky.cphi, 1.0)), sphi=f(take(sky.sphi, 0.0)),
+        use_projection=jnp.asarray(take(sky.use_projection, False), bool),
+        beta=f(take(sky.sh_beta, 1.0)),
+        # [M, S_sh, n2 * n1] -> [M, n2, n1, S_sh]
+        modes=f(np.moveaxis(
+            modes.reshape(slot.shape + (n0max, n0max)), 1, -1)))
 
 
 def sky_to_device(sky: ClusterSky, real_dtype=jnp.float32) -> SkyArrays:
@@ -89,8 +148,7 @@ def sky_to_device(sky: ClusterSky, real_dtype=jnp.float32) -> SkyArrays:
         eX=f(sky.eX), eY=f(sky.eY), eP=f(sky.eP),
         cxi=f(sky.cxi), sxi=f(sky.sxi), cphi=f(sky.cphi), sphi=f(sky.sphi),
         use_projection=jnp.asarray(sky.use_projection, bool),
-        sh_n0=jnp.asarray(sky.sh_n0, jnp.int32),
-        sh_beta=f(sky.sh_beta), sh_modes=f(sky.sh_modes),
+        shapelets=_pack_shapelets(sky, f),
         smask=jnp.asarray(sky.smask, bool),
     )
 
@@ -111,7 +169,7 @@ def _spectral_flux(s0, spec_idx, spec_idx1, spec_idx2, f0, freq):
 # flux), ``rime/corrupt`` the Jones sandwich J_p C J_q^H.
 @jax.named_scope("rime/phasor")
 def _cluster_coherency(csky, u, v, w, freqs, fdelta, per_channel_flux: bool,
-                       n0max: int, with_shapelets: bool,
+                       with_shapelets: bool,
                        af=None, E=None, tslot=None, sta1=None, sta2=None,
                        planes: bool = False):
     """Coherencies of ONE cluster: [B, F, 2, 2] complex, or with
@@ -152,8 +210,7 @@ def _cluster_coherency(csky, u, v, w, freqs, fdelta, per_channel_flux: bool,
             csky.eX[None, :], csky.eY[None, :], csky.eP[None, :],
             csky.cxi[None, :], csky.sxi[None, :], csky.cphi[None, :],
             csky.sphi[None, :], csky.use_projection[None, :],
-            csky.sh_beta[None, :], csky.sh_modes[None, :, :],
-            csky.sh_n0[None, :], n0max, with_shapelets)
+            csky.shapelets if with_shapelets else None)
         if af_f is not None:
             aft = jnp.moveaxis(af_f, 0, -1)             # [T, N, S]
             phasor = phasor * (aft[tslot, sta1]
@@ -222,15 +279,11 @@ def coherencies(sky: SkyArrays, u, v, w, freqs, fdelta,
     ``fdelta`` is the smearing bandwidth PER CHANNEL (callers pass total
     bandwidth for channel-averaged single-freq solves, total/Nchan for
     multifreq, matching predict.c:943).
-    ``with_shapelets`` defaults to auto-detect (static) from the model.
+    The shapelet basis is traced where the model's pack holds a source
+    (``S_sh`` > 0, static: a shape); ``with_shapelets`` False elides it.
     """
-    if with_shapelets is None:
-        if isinstance(sky.sh_n0, jax.core.Tracer):
-            # under jit we cannot inspect values; keep the general path
-            with_shapelets = True
-        else:
-            with_shapelets = bool(np.any(np.asarray(sky.sh_n0) > 0))
-    n0max = int(np.sqrt(sky.sh_modes.shape[-1]).round())
+    with_shapelets = (with_shapelets is not False
+                      and sky.shapelets.slot.shape[-1] > 0)
     af_all = None
     with_beam = beam is not None and bool(dobeam)
     if with_beam:
@@ -257,7 +310,7 @@ def coherencies(sky: SkyArrays, u, v, w, freqs, fdelta,
                                       jnp.atleast_1d(freqs),
                                       beam_mod.DOBEAM_ELEMENT)[1]
         return _cluster_coherency(csky, u, v, w, freqs, fdelta,
-                                  per_channel_flux, n0max, with_shapelets,
+                                  per_channel_flux, with_shapelets,
                                   af=af, E=E, tslot=tslot, sta1=sta1,
                                   sta2=sta2, planes=planes)
 

@@ -199,6 +199,19 @@ OVERTAKEN = {
     "test_beam_cell.py::test_the_older_cells_lists_are_as_pr44_held_them":
         "workloads and configs end with PR 48's no longer, and are nine: "
         "PR 51's cell and configuration go at the end",
+    # PR 52 evaluates the shapelet basis on the model's compact pack of
+    # shapelet sources: the tiny cell's ``shapelet_slots.ext`` and its
+    # records' ``shapelet_slots`` read ``M x S_sh`` = 3 x 1, which is what
+    # that counter was written to show ("4 once the basis is evaluated
+    # where there is a shapelet").  This case pinned the slow state (24:
+    # 3 clusters x 8 slots for 2 shapelets); everything else it guards is
+    # held by ``tests/test_extended_cell.py``, on the same traced tiny run.
+    # It still runs, and fails in that one number.  PERF.md section 7 has
+    # the edit for the next ``benchmark`` issue.
+    "test_extended.py::test_sound_tiny_cell_traced_reports_the_eight_and_"
+    "the_record_fields":
+        "shapelet_slots.ext is 3 (M x S_sh), not 24 (M x Smax): the basis "
+        "is evaluated on the pack of the model's shapelet sources (PR 52)",
 }
 
 

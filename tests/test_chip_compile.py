@@ -757,24 +757,38 @@ def _estimated_cycles(text):
     return got
 
 
-def test_extended_sky_simulate_program_compiles_and_fits(one_chip, tmp_path):
+@pytest.mark.parametrize("tilesz", [TILESZ, TILESZ_120])
+def test_extended_sky_simulate_program_compiles_and_fits(one_chip, tmp_path,
+                                                         tilesz):
     """The simulate program of ``run_simulation`` (``-a 1 -p -F 1``) at
     the sky of ``lofar62-m8x128-ext``, read through the program's own
-    reader from the text the benchmark's reference writes: 18 910 rows,
-    8 x 128 sources, four of them shapelets, ``n0max`` 10.  It compiles
-    for the described v5e and fits with room to spare; the shapelet
-    basis has a scope of its own below ``rime/phasor``; and the
-    compiler's ``estimated_cycles`` for ONE trip of the map over clusters
-    are printed under that scope beside the rest.  As compiled here (cpu,
-    PR 51): 54 425 492 cycles a trip, 43 390 706 in operations named
-    under ``rime/phasor``, 41 584 471 under ``rime/phasor/shapelet`` (two
-    ``multiply_reduce`` fusions of 19 576 694 each over ``f32[18910, 128,
-    10]``), 0.481 GiB of temporaries; the same program on 8 x 128 points
-    is 382 545 a trip and has none.  ``with_shapelets`` is one static flag
-    for the whole model: four shapelet sources make the program evaluate
-    a hundred modes for every one of 1024 source slots.  The ``perf_opt``
-    that evaluates the basis where there is a shapelet has its CPU
-    witness here: the scope's cycles fall by two orders."""
+    reader from the text the benchmark's reference writes: 18 910 rows
+    (226 920 at ``-t 120``), 8 x 128 sources, four of them shapelets,
+    ``n0max`` 10.  It compiles for the described v5e and fits with room
+    to spare; the shapelet basis has a scope of its own below
+    ``rime/phasor``; and the compiler's ``estimated_cycles`` for ONE trip
+    of the map over clusters are printed under that scope beside the
+    rest.  The basis is evaluated on the model's compact pack of shapelet
+    sources, ``[S_sh, B]`` with ``S_sh`` 1 here (``rime/predict.
+    ShapeletPack``, PR 52).  As compiled here (cpu, PR 52), ``-t 10``:
+    1 597 312 cycles a trip, 1 357 818 in operations named under
+    ``rime/phasor``, 28 803 under ``rime/phasor/shapelet`` (multiply-adds
+    on ``f32[18910]{0:T(1024)}``), 0.0041 GiB of temporaries; what is left
+    are the two fusions of the Gaussian, disk and ring envelopes with the
+    fringe (890 396 and 370 209).  ``-t 120``: 17 649 060 a trip, 65 285
+    under the scope, 0.328 GiB.  While one static flag compiled the basis
+    in for every one of the model's 1024 source slots (PR 51) it was
+    54 425 492 cycles a trip, 41 584 471 under the scope in two
+    ``multiply_reduce`` fusions over ``f32[18910, 128, 10]`` tiled
+    ``T(1,128)``, 0.481 GiB of temporaries at ``-t 10`` and 5.8 GiB at
+    ``-t 120`` (sized), which this chip could not have held beside a
+    beam's tables; the same program on 8 x 128 points is 382 545 a trip
+    and has no temporaries.  Held here: the scope is there and is under a
+    twentieth of the trip, the temporaries are under 0.05 GiB a 18 910
+    rows, and no operation under ``rime/phasor`` makes an array tiled
+    ``T(1,128)`` with the rows ABOVE its minor axis (one row to a
+    register tile: PR 49's finding, and the first suspect if the basis is
+    ever slow again)."""
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
                                     "benchmarks"))
     import harness
@@ -792,22 +806,28 @@ def test_extended_sky_simulate_program_compiles_and_fits(one_chip, tmp_path):
         conf["dec0_rad"], conf["freq_hz"], format_3=True)
     assert sky.smask.shape == (M, 128) and sky.smask.all()
     assert sky.sh_modes.shape[-1] == 100 and (sky.sh_n0 > 0).sum() == 4
-    compiled = _lower_simulate_program(one_chip, TILESZ, 1, sky=sky).compile()
+    rows = NB * tilesz
+    compiled = _lower_simulate_program(one_chip, tilesz, 1, sky=sky).compile()
     mem = compiled.memory_analysis()
     need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert 0 < need < 2 * 2 ** 30, need / 2 ** 30
+    assert mem.temp_size_in_bytes < 0.05 * 2 ** 30 * rows / (NB * TILESZ)
     text = compiled.as_text()
     _holds_the_three_scopes(text)
     cycles = _estimated_cycles(text)
     total = sum(c for c, _ in cycles)
     phasor = sum(c for c, name in cycles if "rime/phasor" in name)
     basis = sum(c for c, name in cycles if "(shapelet)" in name)
-    print(f"extended simulate -t {TILESZ}: temp "
+    print(f"extended simulate -t {tilesz}: temp "
           f"{mem.temp_size_in_bytes / 2 ** 30:.4f} GiB; estimated_cycles a "
           f"trip of the map: {total} in all, {phasor} named under "
           f"rime/phasor, {basis} under rime/phasor/shapelet, "
           f"{phasor - basis} the rest of the source sum")
     assert basis > 0, "no operation names the scope rime/phasor/shapelet"
-    # most of the program, as long as every slot evaluates the basis
-    assert basis > 0.5 * total
+    # a corner of the program, now that only the pack evaluates the basis
+    assert basis < 0.05 * total
+    one_row = [line for line, dims, order, tile in _phasor_arrays(text)
+               if tile == (1, 128) and rows in dims
+               and dims[order[0]] != rows]
+    assert not one_row, one_row

@@ -118,7 +118,7 @@ def test_shapelet_envelope_n0_1():
     w = np.zeros(1)
     got = np.asarray(env.shapelet(
         jnp.asarray(u), jnp.asarray(vv), jnp.asarray(w),
-        eX, eY, 0.0, beta, jnp.asarray([[mode0]]), 1, 1,
+        eX, eY, 0.0, beta, jnp.asarray([[mode0]]),
         1.0, 0.0, 1.0, 0.0, jnp.asarray(False)))
     def b0(x):
         return np.exp(-0.5 * x * x) / np.sqrt(2.0)

@@ -822,35 +822,42 @@ class ConsensusStepper:
         throughout but under -W: the uv cut and the weights run nothing
         on a device and read nothing back, so the reader's thread never
         stands behind the solve that is running."""
+        from sagecal_tpu.diag import trace as dtrace
         from sagecal_tpu.rime import predict as rp
         from sagecal_tpu.solvers import lm as lm_mod
         args, rdt = self.args, self.rdt
         x8_l, wt_l, fr_l = [], [], []
         uvcut_on = args.uvmin > 0.0 or args.uvmax < 1e9
         orig_flags = [t.flags for t in tiles]
-        for t in tiles:
+        with dtrace.phase("pack"):
+            for t in tiles:
+                if uvcut_on:
+                    t.flags = rp.apply_uvcut(t.flags, t, args.uvmin,
+                                             args.uvmax)
+                x8_t, flags_t, good = t.solve_input()
+                fr_l.append(good)
+                x8_l.append(x8_t)
+                wt_l.append(lm_mod.make_weights_np(flags_t, np.dtype(rdt)))
             if uvcut_on:
-                t.flags = rp.apply_uvcut(t.flags, t, args.uvmin, args.uvmax)
-            x8_t, flags_t, good = t.solve_input()
-            fr_l.append(good)
-            if args.whiten:
-                import jax.numpy as jnp
-                from sagecal_tpu.diag import trace as dtrace
-                from sagecal_tpu.solvers import robust as rb
-                x8_d = rb.whiten_data(
-                    jnp.asarray(x8_t, rdt), jnp.asarray(t.u, rdt),
-                    jnp.asarray(t.v, rdt), t.freq0)
+                for t, fl in zip(tiles, orig_flags):
+                    t.flags = fl
+        if args.whiten:
+            # -W alone runs on a device: beside "pack", not under it
+            import jax.numpy as jnp
+            from sagecal_tpu.solvers import robust as rb
+            for k, t in enumerate(tiles):
+                with dtrace.phase("copy"):
+                    x8_d, u_d, v_d = (jnp.asarray(x8_l[k], rdt),
+                                      jnp.asarray(t.u, rdt),
+                                      jnp.asarray(t.v, rdt))
+                x8_d = rb.whiten_data(x8_d, u_d, v_d, t.freq0)
                 with dtrace.phase("wait"):
-                    x8_t = np.asarray(x8_d)
-            x8_l.append(x8_t)
-            wt_l.append(lm_mod.make_weights_np(flags_t, np.dtype(rdt)))
-        if uvcut_on:
-            for t, fl in zip(tiles, orig_flags):
-                t.flags = fl
-        return (np.stack(x8_l), np.stack([t.u for t in tiles]),
-                np.stack([t.v for t in tiles]),
-                np.stack([t.w for t in tiles]), np.stack(wt_l),
-                np.array(fr_l))
+                    x8_l[k] = np.asarray(x8_d)
+        with dtrace.phase("pack"):
+            return (np.stack(x8_l), np.stack([t.u for t in tiles]),
+                    np.stack([t.v for t in tiles]),
+                    np.stack([t.w for t in tiles]), np.stack(wt_l),
+                    np.array(fr_l))
 
     def read(self, i):
         """All subbands' tiles of selected interval ``i``."""
@@ -870,35 +877,42 @@ class ConsensusStepper:
         nf, rdt, sdt = self.nf, self.rdt, self.sdt
         with dtrace.phase("stage", tile=ti, bg=self.depth > 0):
             x8F, uF, vF, wF, wtF, fratioF = self._prep_tiles(tiles)
-            padded, _, _ = cadmm.pad_subbands(
-                (x8F, uF, vF, wF, self.freqs, wtF, fratioF), self.Bpoly,
-                nf, self.ndev)
-            # dtype policy: visibilities + weights stage in the storage
-            # dtype; geometry/frequencies keep the pipeline dtype
-            pdts = (sdt, rdt, rdt, rdt, rdt, sdt, rdt)
-            args_dev = [self._to_device(np.asarray(a, np.dtype(d)))
-                        for a, d in zip(padded, pdts)]
-            nbytes = int(sum(np.asarray(a).size * np.dtype(d).itemsize
-                             for a, d in zip(padded, pdts)))
+            with dtrace.phase("pack"):
+                padded, _, _ = cadmm.pad_subbands(
+                    (x8F, uF, vF, wF, self.freqs, wtF, fratioF),
+                    self.Bpoly, nf, self.ndev)
+                # dtype policy: visibilities + weights stage in the
+                # storage dtype; geometry/frequencies keep the pipeline
+                # dtype
+                pdts = (sdt, rdt, rdt, rdt, rdt, sdt, rdt)
+                padded = [np.asarray(a, np.dtype(d))
+                          for a, d in zip(padded, pdts)]
+                nbytes = int(sum(a.nbytes for a in padded))
+                xF_r = None
+                if self.is_writer:
+                    xF_r = np.stack([utils.c2r(t.x) for t in tiles])
+            with dtrace.phase("copy"):
+                args_dev = [self._to_device(a) for a in padded]
+                res_dev = None
+                if xF_r is not None:
+                    res_dev = [jnp.asarray(xF_r, sdt),
+                               jnp.asarray(uF, rdt), jnp.asarray(vF, rdt),
+                               jnp.asarray(wF, rdt)]
             gmstF = beam_dev = None
             if self.dobeam:
                 # only the per-tile gmst time track crosses host->device
                 # here; the static tables were staged once at set-up
                 from sagecal_tpu import coords as _coords
-                gmstF = np.stack(
-                    [np.asarray(_coords.jd2gmst_np(t.time_jd))
-                     for t in tiles]).astype(np.dtype(rdt))
-                if self.fpad > nf:  # padded mesh slots reuse subband 0's
-                    gmstF = np.concatenate(
-                        [gmstF, np.repeat(gmstF[:1], self.fpad - nf,
-                                          axis=0)])
-                beam_dev = self._beam_static_dev._replace(
-                    gmst=self._to_device(gmstF))
-            res_dev = None
-            if self.is_writer:
-                xF_r = np.stack([utils.c2r(t.x) for t in tiles])
-                res_dev = [jnp.asarray(xF_r, sdt), jnp.asarray(uF, rdt),
-                           jnp.asarray(vF, rdt), jnp.asarray(wF, rdt)]
+                with dtrace.phase("beam"):
+                    gmstF = np.stack(
+                        [np.asarray(_coords.jd2gmst_np(t.time_jd))
+                         for t in tiles]).astype(np.dtype(rdt))
+                    if self.fpad > nf:  # padded mesh slots reuse subband 0's
+                        gmstF = np.concatenate(
+                            [gmstF, np.repeat(gmstF[:1], self.fpad - nf,
+                                              axis=0)])
+                    beam_dev = self._beam_static_dev._replace(
+                        gmst=self._to_device(gmstF))
         return dict(args_dev=args_dev, nbytes=nbytes, res_dev=res_dev,
                     fratio=fratioF, gmst=gmstF, beam=beam_dev)
 
@@ -988,9 +1002,10 @@ class ConsensusStepper:
         if self.worker_writers:
             J_all = utils.jones_r2c_np(JF_r8_5)
 
-            def _write_workers(J_all=J_all):
-                for f, ww in enumerate(self.worker_writers):
-                    ww.write_interval(J_all[f], sky.nchunk)
+            def _write_workers(ti=ti, J_all=J_all):
+                with dtrace.phase("solutions", tile=ti):
+                    for f, ww in enumerate(self.worker_writers):
+                        ww.write_interval(J_all[f], sky.nchunk)
             bubble += aw.submit(_write_workers)
 
         if args.mdl and ti == self.start and is_writer:
@@ -1081,12 +1096,15 @@ class ConsensusStepper:
                     # blocked on the residual program's execution;
                     # the copy and the disk are write's own
                     sched.wait_device(res_r)
-                    # fetch through float64 (numpy-side r2c has no
-                    # ml_dtypes bf16 path; the MS is complex128)
-                    res_np = utils.r2c(np.asarray(res_r, np.float64))
+                    with dtrace.phase("convert"):
+                        # fetch through float64 (numpy-side r2c has no
+                        # ml_dtypes bf16 path; the MS is complex128)
+                        res_np = utils.r2c(np.asarray(
+                            res_r, np.float64)).astype(np.complex128)
                     for f, (msx, t) in enumerate(zip(mss, tiles)):
-                        t.x = res_np[f].astype(np.complex128)
-                        msx.write_tile(ti, t)
+                        t.x = res_np[f]
+                        with dtrace.phase("put", sub=f):
+                            msx.write_tile(ti, t)
             # non-blocking d->h copy now; fetch + per-subband write on
             # the ordered writer thread
             sched.start_host_copy(res_r)
@@ -1120,7 +1138,10 @@ class ConsensusStepper:
                 Zr.transpose(0, 2, 1, 3, 4).reshape(
                     sky.n_clusters, kmax * args.npoly, n, 8))
             nchunk_poly = sky.nchunk * args.npoly
-            bubble += aw.submit(self.writer.write_interval, Zj, nchunk_poly)
+            def _write_z(ti=ti, Zj=Zj, nchunk_poly=nchunk_poly):
+                with dtrace.phase("solutions", tile=ti):
+                    self.writer.write_interval(Zj, nchunk_poly)
+            bubble += aw.submit(_write_z)
 
         if dtrace.active():
             # interval summary: bubble_s is the host seconds this step
@@ -1129,8 +1150,6 @@ class ConsensusStepper:
             with dtrace.phase("record"):
                 dtrace.emit("tile", tile=ti, res_0=rec["res_0"],
                             res_1=rec["res_1"], primal=primal,
-                            rho_mean=float(np.asarray(
-                                self._fetch(rhoF))[:nf].mean()),
                             bubble_s=float(bubble), overlap=self.depth,
                             fold=self.fold, ndev=self.ndev, plan=self.plan,
                             jupdate_trips=useful,
@@ -1285,8 +1304,7 @@ def _consensus_time_sharded(args, dtrace, *, mss, meta0, freqs, sky,
                 JF_r8_5 - BZf.reshape(JF_r8_5.shape))
                 / np.sqrt(BZf.size))
             dtrace.emit("tile", tile=ti, res_0=float(res0.mean()),
-                        res_1=float(res1.mean()), primal=primal,
-                        rho_mean=float(rhoT[i][:nf].mean()))
+                        res_1=float(res1.mean()), primal=primal)
             if obs.active():
                 obs.inc("tiles_solved_total")
                 obs.set_gauge("consensus_primal_residual", primal)

@@ -193,8 +193,11 @@ def _emit_deferred(pend, interval):
     if not pend:
         return
     from sagecal_tpu import sched as _sched
-    _sched.start_host_copy(*[x for rec in pend for x in rec[1:]
-                             if x is not None])
+    scalars = [x for rec in pend for x in rec[1:] if x is not None]
+    _sched.start_host_copy(*scalars)
+    # the last iteration's scalars end with the solve: what blocks here
+    # is the device, not the host
+    _sched.wait_device(*scalars)
     for it, r1m, dual, rhom in pend:
         r1 = float(np.asarray(r1m))
         du = 0.0 if dual is None else float(np.asarray(dual))

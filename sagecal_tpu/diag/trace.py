@@ -30,6 +30,27 @@ event            meaning / required extra fields
                  ``thread`` (the thread's name); optional ``tile`` (what
                  the spans of one tile share: a span without one takes
                  its parent's), ``prog`` (a ``dispatch``'s program),
+                 ``sub`` (a ``put``'s subband, cli_mpi),
+                 ``cause`` (the ``id`` of the span ON ANOTHER THREAD
+                 that handed this span its work or produced what it
+                 waited for: a writer job's root names the ``submit``
+                 that queued it, a consumer's ``io`` the producer's
+                 ``read``) and ``queued_s`` (the seconds between that
+                 hand-over and this span's entry: how long the job lay
+                 in the writer's queue, how long the staged tile had
+                 lain ready when the loop took it, 0 where the loop
+                 was already waiting).  Both or neither; absent on the
+                 inline ``--prefetch 0`` path, where nothing is handed
+                 over.  How they reach a span: a thread that runs
+                 handed-over work does so inside :func:`handed`
+                 (``sched.AsyncWriter``'s worker), and every ROOT span
+                 entered there takes them; a span that learns its
+                 cause inside its body (``io``, out of the queue's
+                 ``get``) is told by :meth:`_Phase.caused_by`, as
+                 ``set_tile`` tells it its tile.  A hand-over is
+                 ``(id, instant, tile)``, made by :meth:`_Phase.hand`
+                 (``None`` from the null phase: no tracer, nothing
+                 handed).
                  ``bg`` (True when the phase ran on
                  a background prefetch/writeback thread — under
                  overlapped execution the "io" phase records the
@@ -88,7 +109,7 @@ event            meaning / required extra fields
                  ``solve_dispatches`` (what sagefit_host's last sweep
                  executed, "promoted", "fused" or "per_cluster", and
                  the device executions the solve issued), ``minutes``,
-                 ``primal``, ``rho_mean``, and the
+                 ``primal``, and the
                  overlap accounting pair ``bubble_s`` (host seconds
                  blocked on data movement for this tile: io wait +
                  write wait/backpressure) / ``overlap`` (the prefetch
@@ -144,6 +165,34 @@ with ``-B``: ``beam`` (``pipeline._tile_beam``: the tile's ``gmst`` track
 made on the host and copied; the beam's other leaves were staged at
 construction).  A span's self time
 is its ``dur_s`` less its children's, by ``id``/``parent``.
+
+Inside the reader's and the writer's jobs (the threads that set the
+pace where the device does not).  The writer's thread runs every job
+under a root that carries ``cause`` and ``queued_s``: ``write`` (a
+residual or simulated tile), ``solutions`` (a solutions file's rows: a
+root where that is the whole job, a child where another job writes
+them), ``put`` (a tile handed to the dataset with nothing to convert),
+``job`` (anything else: made by :func:`handed` for a job that opened no
+root of its own).  Under ``write``: ``wait`` (as before), ``convert``
+(the read-back's pairs made complex, the cast to the dataset's
+complex128; cli_mpi: all subbands at once) and ``put`` (the dataset's
+``write_tile``; cli_mpi: one a subband, ``sub=``).  Under ``put``, inside
+``io.dataset.SimMS.write_tile``: ``keep`` (the ``np.load`` of the file
+that is there and the copy of its other columns), ``savez``,
+``replace``; inside ``io.casams.CasaMS.write_tile``: ``keep`` (the
+``getcol`` of what the rows hold) and ``putcol``.  On the reader's
+thread, under ``read``: ``load`` (``SimMS.read_tile``: the ``np.load``
+and the columns read out of it) and ``stage``, under which ``pack`` (host
+arithmetic: ``solve_input``, padding, the uv cut and the weights where
+they are numpy, ``c2r``), ``copy`` (the ONE name under which a host
+thread hands arrays to the device: the ``jnp.asarray`` / ``device_put``
+group; the runtime may hold the thread there behind a running program),
+``dispatch`` (``prog="weights"``: the uv cut and the weights where they
+are eager device operations, ``TileStepper``) and ``beam``.  On the
+loop's thread ``solve`` holds ``dispatch`` with ``prog="coh"`` (the
+coherencies' program, ``pipeline._build_solver``) beside ``sage._call``'s,
+and ``consensus/admm._emit_deferred`` blocks on its batched fetch under
+``wait``.
 
 Records are kept in memory and written by :meth:`Tracer.close` (so
 :func:`disable`), whenever :data:`FLUSH_AT` of them are held, and by an
@@ -317,6 +366,17 @@ class Tracer:
         atexit.unregister(self.flush)
 
 
+def _open_stack() -> list:
+    """This thread's stack of open spans (made, with the thread's name,
+    at its first span)."""
+    stack = getattr(_OPEN, "stack", None)
+    if stack is None:
+        stack = _OPEN.stack = []
+        _OPEN.thread = threading.current_thread().name
+        _OPEN.hand = None
+    return stack
+
+
 class _Phase:
     """Context manager timing one host phase: a profiler annotation
     ``sagecal/<name>`` around the body (when an annotator is set) and
@@ -324,14 +384,27 @@ class _Phase:
     span's ``id``, its ``parent`` on this thread and the ``thread``."""
 
     __slots__ = ("_tr", "_name", "_fields", "_t0", "_ann", "_less",
-                 "_id", "_parent", "dur_s")
+                 "_id", "_parent", "_hand", "dur_s")
 
     def __init__(self, tracer, name, fields):
         self._tr = tracer
         self._name = name
         self._fields = fields
         self._less = 0.0
+        self._hand = None       # (cause, instant, tile) handed to it
         self.dur_s = 0.0        # the span's seconds, once it has ended
+
+    def hand(self, at: float):
+        """What a thread that takes work from this span is given: this
+        span's id, the instant ``at`` (``time.perf_counter()``) of the
+        hand-over, and its tile."""
+        return self._id, at, self._fields.get("tile")
+
+    def caused_by(self, hand) -> None:
+        """The span learned inside its body which span of another
+        thread produced what it waited for (``hand``: that span's
+        :meth:`hand`, or None)."""
+        self._hand = hand
 
     def drop(self) -> None:
         """Emit no record for this span (the wait turned out to be for
@@ -356,10 +429,7 @@ class _Phase:
                           **self._fields)
 
     def __enter__(self):
-        stack = getattr(_OPEN, "stack", None)
-        if stack is None:
-            stack = _OPEN.stack = []
-            _OPEN.thread = threading.current_thread().name
+        stack = _open_stack()
         f = self._fields
         self._id = next(_IDS)
         self._parent = None
@@ -368,6 +438,13 @@ class _Phase:
             self._parent = above._id
             if "tile" not in f and "tile" in above._fields:
                 f["tile"] = above._fields["tile"]
+        else:
+            # a root on a thread that runs handed-over work
+            self._hand = _OPEN.hand
+            if self._hand is not None:
+                _OPEN.taken = True
+                if "tile" not in f and self._hand[2] is not None:
+                    f["tile"] = self._hand[2]
         stack.append(self)
         self._ann = None
         if _ANNOTATOR is not None:
@@ -385,6 +462,10 @@ class _Phase:
         _OPEN.stack.pop()
         self.dur_s = t1 - self._t0 - self._less
         if self._tr is not None:
+            if self._hand is not None:
+                self._fields["cause"] = self._hand[0]
+                self._fields["queued_s"] = max(
+                    0.0, self._t0 - self._hand[1])
             self._tr.emit("phase", name=self._name, dur_s=self.dur_s,
                           tm=t1, id=self._id, parent=self._parent,
                           thread=_OPEN.thread, **self._fields)
@@ -412,8 +493,44 @@ class _NullPhase:
     def carve(self, name, dur_s) -> None:
         pass
 
+    def hand(self, at):
+        return None
+
+    def caused_by(self, hand) -> None:
+        pass
+
 
 _NULL_PHASE = _NullPhase()
+
+
+class _Handed:
+    """Context manager around handed-over work on the thread that runs
+    it: every root span entered inside takes the hand-over's ``cause``
+    and ``queued_s``, and work that entered none is recorded as one
+    root ``job`` of its own (a record alone: no annotation)."""
+
+    __slots__ = ("_hand", "_t0")
+
+    def __init__(self, hand):
+        self._hand = hand
+
+    def __enter__(self):
+        _open_stack()
+        _OPEN.hand, _OPEN.taken = self._hand, False
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        _OPEN.hand = None
+        tr = _current()
+        if not _OPEN.taken and tr is not None:
+            cause, at, tile = self._hand
+            tr.emit("phase", name="job", dur_s=t1 - self._t0, tm=t1,
+                    id=next(_IDS), parent=None, thread=_OPEN.thread,
+                    cause=cause, queued_s=max(0.0, self._t0 - at),
+                    **({} if tile is None else {"tile": tile}))
+        return False
 
 
 def enable(path, **run_meta) -> Tracer:
@@ -459,6 +576,16 @@ def phase(name: str, **fields):
     if t is None and not _PROFILING:
         return _NULL_PHASE
     return _Phase(t, name, fields)
+
+
+def handed(hand):
+    """Around work another thread handed over (``hand``: the handing
+    span's :meth:`_Phase.hand`): the roots entered inside carry its
+    ``cause`` and ``queued_s``. The shared null context for ``None``,
+    what the null phase hands: no tracer was active at the hand-over."""
+    if hand is None:
+        return _NULL_PHASE
+    return _Handed(hand)
 
 
 def overlap_stats(recs: list) -> dict:

@@ -44,6 +44,7 @@ import os
 import numpy as np
 
 from sagecal_tpu.analysis import threadsan
+from sagecal_tpu.diag import trace as dtrace
 from sagecal_tpu.io.dataset import (VisTile, generate_baselines,
                                     _tiles_prefetch_impl, C_M_S)
 
@@ -292,18 +293,22 @@ class CasaMS:
 
     def _write_tile_locked(self, i: int, tile: VisTile) -> None:
         r0, nr, slot0, _ = self._tile_rows(i)
-        a1 = np.asarray(self._ts.getcol("ANTENNA1", r0, nr))
-        a2 = np.asarray(self._ts.getcol("ANTENNA2", r0, nr))
-        pos, swapped = self._row_positions(a1, a2, r0, slot0,
-                                           self._ddid(r0, nr))
+        # "keep": what the rows hold is read, the rows absent from the
+        # tile keep it
+        with dtrace.phase("keep"):
+            a1 = np.asarray(self._ts.getcol("ANTENNA1", r0, nr))
+            a2 = np.asarray(self._ts.getcol("ANTENNA2", r0, nr))
+            ddid = self._ddid(r0, nr)
+            out = np.asarray(self._ts.getcol(self.out_column, r0, nr))
+        pos, swapped = self._row_positions(a1, a2, r0, slot0, ddid)
         sel = pos >= 0
         F = len(self.meta["freqs"])
-        out = np.asarray(self._ts.getcol(self.out_column, r0, nr))
         xw = tile.x[pos[sel]]
         sw = swapped[sel]
         xw[sw] = np.conj(np.swapaxes(xw[sw], -1, -2))  # back to V_qp
         out[sel] = xw.reshape(-1, F, 4).astype(out.dtype)
-        self._ts.putcol(self.out_column, out, r0, nr)
+        with dtrace.phase("putcol"):
+            self._ts.putcol(self.out_column, out, r0, nr)
 
     def beam_info(self):
         """LOFAR_ANTENNA_FIELD -> BeamInfo, or None for a non-LOFAR MS

@@ -27,6 +27,7 @@ import os
 import numpy as np
 
 from sagecal_tpu import faults
+from sagecal_tpu.diag import trace as dtrace
 
 C_M_S = 299792458.0
 OMEGA_E = 7.2921150e-5  # earth angular velocity rad/s
@@ -360,23 +361,26 @@ class SimMS:
         # ms_read: the transient-read chaos seam (sagecal_tpu.faults);
         # recovery lives in the caller's retry layer (sched.Prefetcher)
         faults.inject("ms_read", key=i)
-        z = np.load(os.path.join(self.path, f"tile{i:05d}.npz"))
-        key = self._col_key(self.data_column)
-        if key not in z.files:
-            have = [k for k in z.files if k == "x" or k.startswith("x_")]
-            raise ValueError(
-                f"{self.path}: column {self.data_column!r} not present "
-                f"in tile {i} (stored data keys: {have})")
-        m = self.meta
-        return VisTile(
-            u=z["u"], v=z["v"], w=z["w"], x=z[key], flags=z["flags"],
-            sta1=z["sta1"], sta2=z["sta2"],
-            freqs=np.asarray(m["freqs"]), freq0=m["freq0"],
-            fdelta=m["fdelta"], tdelta=m["tdelta"], dec0=m["dec0"],
-            ra0=m["ra0"], n_stations=m["n_stations"], nbase=m["nbase"],
-            tilesz=m["tilesz"],
-            time_mjd=z["time_mjd"] if "time_mjd" in z.files else None,
-            cflags=z["cflags"] if "cflags" in z.files else None)
+        # "load": the file opened and the columns read out of it
+        with dtrace.phase("load", tile=i):
+            z = np.load(os.path.join(self.path, f"tile{i:05d}.npz"))
+            key = self._col_key(self.data_column)
+            if key not in z.files:
+                have = [k for k in z.files
+                        if k == "x" or k.startswith("x_")]
+                raise ValueError(
+                    f"{self.path}: column {self.data_column!r} not "
+                    f"present in tile {i} (stored data keys: {have})")
+            m = self.meta
+            return VisTile(
+                u=z["u"], v=z["v"], w=z["w"], x=z[key], flags=z["flags"],
+                sta1=z["sta1"], sta2=z["sta2"],
+                freqs=np.asarray(m["freqs"]), freq0=m["freq0"],
+                fdelta=m["fdelta"], tdelta=m["tdelta"], dec0=m["dec0"],
+                ra0=m["ra0"], n_stations=m["n_stations"],
+                nbase=m["nbase"], tilesz=m["tilesz"],
+                time_mjd=z["time_mjd"] if "time_mjd" in z.files else None,
+                cflags=z["cflags"] if "cflags" in z.files else None)
 
     def write_tile(self, i: int, tile: VisTile,
                    column: str | None = None) -> None:
@@ -391,13 +395,15 @@ class SimMS:
         key = self._col_key(column or self.out_column)
         kw = {}
         path = os.path.join(self.path, f"tile{i:05d}.npz")
-        if os.path.exists(path):
-            with np.load(path) as z:
-                # keep every other data column AND stored per-tile
-                # metadata the caller's VisTile may not carry
-                kw = {k: z[k] for k in z.files
-                      if ((k == "x" or k.startswith("x_")) and k != key)
-                      or k in ("time_mjd", "cflags")}
+        with dtrace.phase("keep"):
+            if os.path.exists(path):
+                with np.load(path) as z:
+                    # keep every other data column AND stored per-tile
+                    # metadata the caller's VisTile may not carry
+                    kw = {k: z[k] for k in z.files
+                          if ((k == "x" or k.startswith("x_"))
+                              and k != key)
+                          or k in ("time_mjd", "cflags")}
         if tile.time_mjd is not None:
             kw["time_mjd"] = tile.time_mjd
         if tile.cflags is not None:
@@ -407,9 +413,11 @@ class SimMS:
         # tile file and take the pristine DATA column with it (the tmp
         # name ends in .npz so np.savez does not append a suffix)
         tmp = path + ".tmp.npz"
-        np.savez(tmp, u=tile.u, v=tile.v, w=tile.w, flags=tile.flags,
-                 sta1=tile.sta1, sta2=tile.sta2, **kw)
-        os.replace(tmp, path)
+        with dtrace.phase("savez"):
+            np.savez(tmp, u=tile.u, v=tile.v, w=tile.w, flags=tile.flags,
+                     sta1=tile.sta1, sta2=tile.sta2, **kw)
+        with dtrace.phase("replace"):
+            os.replace(tmp, path)
 
     def tiles(self):
         for i in range(self.n_tiles):

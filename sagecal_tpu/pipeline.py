@@ -421,7 +421,8 @@ class FullBatchPipeline:
         def solve(x8, u, v, w, sta1, sta2, wt, J0_r8, beam, tile_idx=0):
             # host-driven EM: one bounded device execution per cluster
             # solve
-            coh = coh_fn(u, v, w, sta1, sta2, beam)
+            with dtrace.phase("dispatch", prog="coh"):
+                coh = coh_fn(u, v, w, sta1, sta2, beam)
             # jitted conversion: complex stays on-device
             J0 = _jones_r2c_j(jnp.asarray(J0_r8, self.rdt))
             # fresh subset draws + cluster permutations per tile
@@ -798,15 +799,23 @@ class FullBatchPipeline:
             # from the copy and the disk
             sched.wait_device(res_r)
             n_rows = tile.x.shape[0]
-            # fetch through float64: numpy-side r2c on ml_dtypes bf16
-            # arrays is not supported, and the MS stores complex128
-            x = utils.r2c(np.asarray(res_r, np.float64)).astype(
-                np.complex128)
-            # tile-bucket padding rows (zero weight, never solved on)
-            # are sliced off before the MS sees them
-            tile.x = x[:n_rows]
-            self.ms.write_tile(ti, tile)
+            with dtrace.phase("convert"):
+                # fetch through float64: numpy-side r2c on ml_dtypes
+                # bf16 arrays is not supported, and the MS stores
+                # complex128
+                x = utils.r2c(np.asarray(res_r, np.float64)).astype(
+                    np.complex128)
+                # tile-bucket padding rows (zero weight, never solved
+                # on) are sliced off before the MS sees them
+                tile.x = x[:n_rows]
+            self._put_tile(ti, tile)
         obs.observe("tile_write_seconds", time.perf_counter() - t_write)
+
+    def _put_tile(self, ti, tile):
+        """A tile handed to the dataset under "put": a child of "write",
+        the root of a writer job that has nothing to convert."""
+        with dtrace.phase("put", tile=ti):
+            self.ms.write_tile(ti, tile)
 
     def _run_batched(self, write_residuals, solution_path, max_tiles, log,
                      prefetch=None):
@@ -889,7 +898,7 @@ class FullBatchPipeline:
                                      else min(state["res_prev"], res_1))
             if writer:
                 stg["bubble"] += aw.submit(
-                    writer.write_interval,
+                    _write_solutions, writer, ti,
                     state["J"] if state["first"] else Jnew, sky.nchunk)
             if write_residuals:
                 with dtrace.phase("residual", tile=ti):
@@ -1154,17 +1163,22 @@ class FullBatchPipeline:
             # transfers alone: an eager jnp operation here would queue
             # behind the program that runs (sched.py, PERF.md section 5)
             with dtrace.phase("stage", tile=ti, bg=bg):
-                J_r8 = None
-                if blocks_iter:
-                    J_r8 = jnp.asarray(utils.jones_c2r_np(
-                        blocks_iter[min(ti, len(blocks_iter) - 1)]),
-                        self.rdt)
-                return (jnp.asarray(utils.c2r(tile.x), self.rdt),
-                        jnp.asarray(tile.u, self.rdt),
-                        jnp.asarray(tile.v, self.rdt),
-                        jnp.asarray(tile.w, self.rdt),
-                        jnp.asarray(tile.sta1), jnp.asarray(tile.sta2),
-                        J_r8, self._tile_beam(tile, ti))
+                with dtrace.phase("pack"):
+                    J_r8 = None
+                    if blocks_iter:
+                        J_r8 = utils.jones_c2r_np(
+                            blocks_iter[min(ti, len(blocks_iter) - 1)])
+                    x_r = utils.c2r(tile.x)
+                with dtrace.phase("copy"):
+                    if J_r8 is not None:
+                        J_r8 = jnp.asarray(J_r8, self.rdt)
+                    args = (jnp.asarray(x_r, self.rdt),
+                            jnp.asarray(tile.u, self.rdt),
+                            jnp.asarray(tile.v, self.rdt),
+                            jnp.asarray(tile.w, self.rdt),
+                            jnp.asarray(tile.sta1),
+                            jnp.asarray(tile.sta2), J_r8)
+                return args + (self._tile_beam(tile, ti),)
 
         # ms.tiles() is the seam a dataset overrides, so the reader
         # pulls it and does not call read_tile(i)
@@ -1195,8 +1209,9 @@ class FullBatchPipeline:
 
         def write(ti, tile, out):
             with dtrace.phase("write", tile=ti, bg=bg) as ph:
-                tile.x = utils.r2c(out).astype(np.complex128)
-                ms.write_tile(ti, tile)
+                with dtrace.phase("convert"):
+                    tile.x = utils.r2c(out).astype(np.complex128)
+                self._put_tile(ti, tile)
             return ph.dur_s
 
         def finish(ti, tile, out_r, io_wait):
@@ -1242,6 +1257,13 @@ class FullBatchPipeline:
             source.close()      # a loop that failed leaves the reader here
             # every write has run when this returns; a failed one raises
             aw.close()
+
+
+def _write_solutions(writer, ti, J, nchunk):
+    """Writer-queue job: one interval's rows of the solutions file,
+    under "solutions"."""
+    with dtrace.phase("solutions", tile=ti):
+        writer.write_interval(J, nchunk)
 
 
 def _thread_scopes():
@@ -1498,48 +1520,62 @@ class TileStepper:
         # shared staging decision (VisTile.solve_input): native
         # per-channel-flag packing when applicable, plain mean else;
         # stored uv-cut rows survive either way
-        x8_np, rowflags, _good = tile.solve_input(uvtaper_m=cfg.uvtaper)
-        if pad:
-            # tile-bucket padding (serve/cache.py): geometry rows
-            # repeat real rows (finite uvw, in-range stations), data
-            # rows are zero, and the row flag 1 gives them ZERO weight
-            # — they enter no reduction, exactly like the sharded
-            # path's mesh padding
-            u_np = pcache.pad_rows_repeat(u_np, pad)
-            v_np = pcache.pad_rows_repeat(v_np, pad)
-            w_np = pcache.pad_rows_repeat(w_np, pad)
-            sta1_np = pcache.pad_rows_repeat(sta1_np, pad)
-            sta2_np = pcache.pad_rows_repeat(sta2_np, pad)
-            x8_np = pcache.pad_rows_zero(x8_np, pad)
-            rowflags = np.concatenate(
-                [rowflags, np.ones(pad, np.asarray(rowflags).dtype)])
-        u = jnp.asarray(u_np, p.rdt)
-        v = jnp.asarray(v_np, p.rdt)
-        w = jnp.asarray(w_np, p.rdt)
-        # dtype-policy storage staging (see the batched driver)
-        x8 = jnp.asarray(x8_np, p.sdt)
-        flags = rp.uvcut_flags(jnp.asarray(rowflags, jnp.int32), u, v,
-                               jnp.asarray(tile.freqs, p.rdt),
-                               cfg.uvmin, cfg.uvmax)
-        if cfg.whiten:
-            # -W: uv-density whitening of the solve input only
-            # (fullbatch_mode.cpp applies whiten_data to the averaged x)
-            from sagecal_tpu.solvers import robust as rb
-            x8 = rb.whiten_data(x8, u, v, meta["freq0"])
+        # "pack" is the host's arithmetic, "copy" the arrays handed to
+        # the device, "dispatch" the eager device operations on them:
+        # where the reader's seconds go is read by those three names
+        with dtrace.phase("pack"):
+            x8_np, rowflags, _good = tile.solve_input(
+                uvtaper_m=cfg.uvtaper)
+            if pad:
+                # tile-bucket padding (serve/cache.py): geometry rows
+                # repeat real rows (finite uvw, in-range stations), data
+                # rows are zero, and the row flag 1 gives them ZERO
+                # weight — they enter no reduction, exactly like the
+                # sharded path's mesh padding
+                u_np = pcache.pad_rows_repeat(u_np, pad)
+                v_np = pcache.pad_rows_repeat(v_np, pad)
+                w_np = pcache.pad_rows_repeat(w_np, pad)
+                sta1_np = pcache.pad_rows_repeat(sta1_np, pad)
+                sta2_np = pcache.pad_rows_repeat(sta2_np, pad)
+                x8_np = pcache.pad_rows_zero(x8_np, pad)
+                rowflags = np.concatenate(
+                    [rowflags, np.ones(pad, np.asarray(rowflags).dtype)])
+            xr_np = None
+            if self.stage_xr:
+                xr_np = utils.c2r(
+                    tile.x if not pad else pcache.pad_rows_zero(tile.x,
+                                                                pad))
+        with dtrace.phase("copy"):
+            u = jnp.asarray(u_np, p.rdt)
+            v = jnp.asarray(v_np, p.rdt)
+            w = jnp.asarray(w_np, p.rdt)
+            # dtype-policy storage staging (see the batched driver)
+            x8 = jnp.asarray(x8_np, p.sdt)
+            rowflags_d = jnp.asarray(rowflags, jnp.int32)
+            freqs_d = jnp.asarray(tile.freqs, p.rdt)
+            sta1 = jnp.asarray(sta1_np)
+            sta2 = jnp.asarray(sta2_np)
+            x_r = None if xr_np is None else jnp.asarray(xr_np, p.sdt)
+        with dtrace.phase("dispatch", prog="weights"):
+            flags = rp.uvcut_flags(rowflags_d, u, v, freqs_d,
+                                   cfg.uvmin, cfg.uvmax)
+            if cfg.whiten:
+                # -W: uv-density whitening of the solve input only
+                # (fullbatch_mode.cpp applies whiten_data to the
+                # averaged x)
+                from sagecal_tpu.solvers import robust as rb
+                x8 = rb.whiten_data(x8, u, v, meta["freq0"])
+            wt = lm_mod.make_weights(flags, p.sdt)
         # beam_stage: the beam-table staging chaos seam; it fires
         # BEFORE the ring stages this tile's residual input below, so
         # the reader-thread retry can safely re-run the whole stage
         faults.inject("beam_stage", key=ti)
-        stg = dict(u=u, v=v, w=w, x8=x8, flags=flags,
-                   wt=lm_mod.make_weights(flags, p.sdt),
-                   sta1=jnp.asarray(sta1_np),
-                   sta2=jnp.asarray(sta2_np),
-                   beam=p._tile_beam(tile, ti))
-        if self.stage_xr:
+        stg = dict(u=u, v=v, w=w, x8=x8, flags=flags, wt=wt,
+                   sta1=sta1, sta2=sta2, beam=p._tile_beam(tile, ti))
+        if x_r is not None:
             # residual input staged ahead; DONATED to the residual
             # program (ring: no read-after-donate, no aliasing)
-            x_r = tile.x if not pad else pcache.pad_rows_zero(tile.x, pad)
-            self.ring.stage(ti, jnp.asarray(utils.c2r(x_r), p.sdt))
+            self.ring.stage(ti, x_r)
         return stg
 
     # -- device-owner half --------------------------------------------------
@@ -1652,8 +1688,8 @@ class TileStepper:
             bubble += self._step_per_channel(ti, tile, stg, info)
         else:
             if self.writer:
-                bubble += self.aw.submit(self.writer.write_interval,
-                                         self.J, sky.nchunk)
+                bubble += self.aw.submit(_write_solutions, self.writer,
+                                         ti, self.J, sky.nchunk)
 
             if self.write_residuals:
                 with dtrace.phase("residual", tile=ti):
@@ -1893,10 +1929,10 @@ class TileStepper:
             tile.x = np.moveaxis(
                 utils.r2c(resC)[:, :, 0], 0, 1
             ).astype(np.complex128)
-            bubble += self.aw.submit(ms.write_tile, ti, tile)
+            bubble += self.aw.submit(p._put_tile, ti, tile)
         self.J = utils.jones_r2c_np(np.asarray(JC_r8[-1]))
         if self.writer:
-            bubble += self.aw.submit(self.writer.write_interval,
+            bubble += self.aw.submit(_write_solutions, self.writer, ti,
                                      self.J, sky.nchunk)
         return bubble
 

@@ -124,6 +124,12 @@ class Prefetcher:
     consumer side emits the portion of its own block that overlapped
     the wait-for-arrival (so the io bubble stays an honest measure of
     read/stage cost, not of the tenant's data rate).
+
+    The hand-over (diag/trace.py): a produced item carries the
+    producer's span and the instant it was put, and the consumer's
+    ``io`` records them as ``cause`` and ``queued_s``: how long the item
+    had lain ready when the loop took it (0 where the loop was already
+    waiting). ``poll()`` has no ``io`` span and records neither.
     """
 
     #: poll() sentinels (serve scheduler protocol)
@@ -267,15 +273,17 @@ class Prefetcher:
                     except EndOfStream:
                         ph.drop()
                         break
-                obs.observe("prefetch_read_seconds",
-                            time.perf_counter() - t0)
-                if not self._put((i, item, t_arr)):
+                t1 = time.perf_counter()
+                obs.observe("prefetch_read_seconds", t1 - t0)
+                # the hand-over: the consumer's "io" names this span as
+                # its cause and says how long the item lay ready
+                if not self._put((i, item, t_arr, ph.hand(t1))):
                     return
                 i += 1
         except BaseException as e:      # surface in the consumer
-            self._put((None, e, 0.0))
+            self._put((None, e, 0.0, None))
             return
-        self._put((None, None, 0.0))
+        self._put((None, None, 0.0, None))
 
     # -- consumer ----------------------------------------------------------
 
@@ -306,13 +314,14 @@ class Prefetcher:
                 # the consumer's "io" phase: its wait for the next item
                 with dtrace.phase("io", tile=self.tile0 + k) as ph:
                     t0 = time.monotonic()
-                    i, item, t_arr = self._q.get()
+                    i, item, t_arr, hand = self._q.get()
                     wait = time.monotonic() - t0
                     if i is None:
                         ph.drop()       # the end marker is no tile
                         if item is not None:
                             raise item
                         return
+                    ph.caused_by(hand)
                     # split the block: the part spent while the item
                     # had not yet ARRIVED is arrival wait (the tenant's
                     # data rate, counted by the producer's metric
@@ -354,7 +363,7 @@ class Prefetcher:
             self._poll_next += 1
             return i, item, time.perf_counter() - t0
         try:
-            i, item, _t_arr = self._q.get_nowait()
+            i, item, _t_arr, _hand = self._q.get_nowait()
         except queue.Empty:
             return self.EMPTY
         if i is None:
@@ -391,6 +400,14 @@ class AsyncWriter:
 
     ``submit`` returns the seconds it spent blocked on a full queue
     (writer backpressure — bubble time for the caller's accounting).
+
+    While a tracer is on, a queued job carries its hand-over (the
+    caller's ``submit`` span, the instant of the put) and the worker
+    runs it inside ``dtrace.handed``: every root span the job opens on
+    the writer's thread says which ``submit`` caused it and how long it
+    lay queued (``cause``, ``queued_s``: diag/trace.py), a job that
+    opens none is recorded as one root ``job``. Inline, nothing is
+    handed over: the job's spans are children of ``submit``.
     """
 
     _STOP = object()
@@ -432,15 +449,21 @@ class AsyncWriter:
                 with self._exc_lock:
                     failed = self._exc is not None
                 if not failed:          # fail-stop: drain, don't run
-                    fn, args, kwargs = job
-                    # writer_thread: the thread-death injection point;
-                    # then bounded transient retry — submitted jobs are
-                    # idempotent (atomic MS tile writes, single-call
-                    # solution/checkpoint writes), so a flaky disk
-                    # recovers here instead of failing the run
-                    faults.inject("writer_thread")
-                    faults.retry_transient(fn, args, kwargs,
-                                           what="write")
+                    fn, args, kwargs, hand = job
+                    # every root span of the job carries the "submit"
+                    # that queued it and the seconds it lay queued; a
+                    # job that opens none is one root "job" (diag/
+                    # trace.py; the null context without a tracer)
+                    with dtrace.handed(hand):
+                        # writer_thread: the thread-death injection
+                        # point; then bounded transient retry —
+                        # submitted jobs are idempotent (atomic MS tile
+                        # writes, single-call solution/checkpoint
+                        # writes), so a flaky disk recovers here instead
+                        # of failing the run
+                        faults.inject("writer_thread")
+                        faults.retry_transient(fn, args, kwargs,
+                                               what="write")
             except BaseException as e:
                 with self._exc_lock:
                     if self._exc is None:   # first failure wins
@@ -461,10 +484,10 @@ class AsyncWriter:
     def submit(self, fn, *args, **kwargs) -> float:
         # the caller's "submit" span: the hand-over, back-pressure and,
         # at depth 0, the inline job
-        with dtrace.phase("submit"):
-            return self._submit(fn, args, kwargs)
+        with dtrace.phase("submit") as ph:
+            return self._submit(fn, args, kwargs, ph)
 
-    def _submit(self, fn, args, kwargs) -> float:
+    def _submit(self, fn, args, kwargs, ph) -> float:
         self.check()
         if not self.enabled:
             # inline (--prefetch 0) execution keeps the SAME transient
@@ -473,7 +496,9 @@ class AsyncWriter:
             faults.retry_transient(fn, args, kwargs, what="write")
             return 0.0
         t0 = time.perf_counter()
-        self._q.put((fn, args, kwargs))
+        # the hand-over: this "submit" span and the instant of the put
+        # (None from the null phase: no tracer, nothing handed)
+        self._q.put((fn, args, kwargs, ph.hand(t0)))
         wait = time.perf_counter() - t0
         if wait > 1e-3:
             # writer backpressure: the producer outran the disk and

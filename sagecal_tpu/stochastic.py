@@ -521,16 +521,18 @@ class _StochasticRunner:
         """Fetch dispatched residual outputs, assemble the channel
         window of every (minibatch, band) slice, write the tile."""
         with dtrace.phase("write", tile=ti, bg=bg):
-            xout = np.array(tile.x)
-            for r0, nrow, c0, nc, out in jobs:
-                # fetch through float64: numpy-side r2c has no ml_dtypes
-                # bf16 path, and the MS stores complex128
-                res = utils.r2c(np.asarray(out, np.float64).reshape(
-                    self.bmb, self.fpad, 4, 2))
-                xout[r0:r0 + nrow, c0:c0 + nc] = res.reshape(
-                    self.bmb, self.fpad, 2, 2)[:nrow, :nc]
-            tile.x = xout
-            self.ms.write_tile(ti, tile)
+            with dtrace.phase("convert"):
+                xout = np.array(tile.x)
+                for r0, nrow, c0, nc, out in jobs:
+                    # fetch through float64: numpy-side r2c has no
+                    # ml_dtypes bf16 path, and the MS stores complex128
+                    res = utils.r2c(np.asarray(out, np.float64).reshape(
+                        self.bmb, self.fpad, 4, 2))
+                    xout[r0:r0 + nrow, c0:c0 + nc] = res.reshape(
+                        self.bmb, self.fpad, 2, 2)[:nrow, :nc]
+                tile.x = xout
+            with dtrace.phase("put"):
+                self.ms.write_tile(ti, tile)
 
     def solution_writer(self):
         if not self.cfg.solutions_file:

@@ -464,9 +464,12 @@ def _cover(recs):
     assert len({r["thread"] for r in steps}) == 1
     roots = [r for r in ph.values() if r["parent"] is None
              and r["thread"] == steps[0]["thread"] and not r.get("bg")]
-    # "drain": the overlapped simulation loop's last fetch, once a run
+    # "drain": the overlapped simulation loop's last fetch, once a run;
+    # "load": a tile read for its shapes at set-up, before the loop
     assert {r["name"] for r in roots} <= {"io", "step", "arrival_wait",
-                                          "drain"}
+                                          "drain", "load"}
+    assert all(r["tm"] <= steps[0]["tm"] - steps[0]["dur_s"]
+               for r in roots if r["name"] == "load")
     assert sum(r["name"] == "drain" for r in roots) <= 1
     shares = []
     for a, b in zip(steps, steps[1:]):
@@ -600,6 +603,290 @@ def test_step_and_io_cover_the_cycle_and_the_tracer_changes_nothing(
     recs = [r for r in trace.read(str(tr)) if r["ev"] == "phase"]
     assert all("tile" in r for r in recs), [
         r["name"] for r in recs if "tile" not in r]
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 53: the hand-over (cause, queued_s) and the spans inside the
+# writer's and the reader's jobs
+# ---------------------------------------------------------------------------
+
+def _paths(phases):
+    """{id: "step/solve/wait"} by ``id`` / ``parent``."""
+    by = {r["id"]: r for r in phases}
+    out = {}
+
+    def path(r):
+        if r["id"] not in out:
+            up = by.get(r["parent"])
+            out[r["id"]] = (path(up) + "/" if up else "") + r["name"]
+        return out[r["id"]]
+    for r in phases:
+        path(r)
+    return out
+
+
+#: the paths each loop's records held on the parent of PR 53 (its tree,
+#: these drivers, ``--prefetch 1``): every one of them is still there,
+#: under its name and in its place
+PARENT_PATHS = {
+    "calibrate": {
+        "io", "read", "read/stage", "step", "step/carry", "step/solve",
+        "step/solve/dispatch", "step/solve/wait", "step/residual",
+        "step/residual/carry", "step/residual/dispatch", "step/submit",
+        "step/record", "write", "write/wait"},
+    "consensus": {
+        "io", "read", "read/stage", "step", "step/carry", "step/solve",
+        "step/solve/dispatch", "step/solve/wait", "step/fetch",
+        "step/record", "step/residual", "step/residual/carry",
+        "step/residual/dispatch", "step/submit", "step/primal", "write",
+        "write/wait"},
+    "simulate": {
+        "io", "read", "read/stage", "step", "step/predict", "step/fetch",
+        "step/fetch/wait", "step/submit", "drain", "drain/fetch",
+        "drain/fetch/wait", "drain/submit", "write"},
+}
+#: what PR 53 puts under them
+NEW_PATHS = {
+    "calibrate": {
+        "read/load", "read/stage/pack", "read/stage/copy",
+        "read/stage/dispatch", "step/solve/dispatch", "write/convert",
+        "write/put", "write/put/keep", "write/put/savez",
+        "write/put/replace", "solutions", "job"},
+    "consensus": {
+        # "load" alone: a tile read for its shapes at set-up
+        "load", "read/load", "read/stage/pack", "read/stage/copy",
+        "write/convert", "write/put", "write/put/keep", "write/put/savez",
+        "write/put/replace", "solutions"},
+    "simulate": {
+        "read/load", "read/stage/pack", "read/stage/copy", "write/convert",
+        "write/put", "write/put/keep", "write/put/savez",
+        "write/put/replace"},
+}
+
+LOOPS = [(_make_calibrate, _calibrate), (_make_consensus, _consensus),
+         (_make_simulate, _simulate)]
+LOOP_IDS = ["calibrate", "consensus", "simulate"]
+
+
+@pytest.mark.parametrize("make, drive", LOOPS, ids=LOOP_IDS)
+def test_every_handed_span_names_its_cause_on_another_thread(
+        tmp_path, request, make, drive):
+    """Overlapped, with a tracer on: every root span of the writer's
+    thread carries the ``id`` of a ``submit`` on the loop's thread and
+    the seconds its job lay queued; every ``io`` that yielded a tile
+    carries the ``id`` of the reader's span that produced it; no other
+    span carries either field; and every path the parent's records
+    held is still there."""
+    loop = request.node.callspec.id
+    root = tmp_path / "obs"
+    make(root)
+    tr = tmp_path / "diag.jsonl"
+    drive(str(root), ["--diag", str(tr), "--prefetch", "1"])
+    phases = _phases(tr)
+    by = {r["id"]: r for r in phases}
+    paths = _paths(phases)
+    main, = {r["thread"] for r in phases if r["name"] == "step"}
+
+    caused = [r for r in phases if "cause" in r]
+    assert all(("cause" in r) == ("queued_s" in r) for r in phases)
+    for r in caused:
+        up = by[r["cause"]]             # in the same file
+        assert up["thread"] != r["thread"]
+        assert r["queued_s"] >= 0.0 and r["parent"] is None
+    # the writer's thread: every root is a handed job's
+    roots = [r for r in phases if r["thread"] == "async-writer"
+             and r["parent"] is None]
+    assert roots and {r["name"] for r in roots} <= {"write", "solutions",
+                                                    "put", "job"}
+    for r in roots:
+        up = by[r["cause"]]
+        assert up["name"] == "submit" and up["thread"] == main
+        # handed over inside the submit, taken up after the hand-over
+        start = r["tm"] - r["dur_s"]
+        assert up["tm"] - up["dur_s"] <= start - r["queued_s"] <= up["tm"]
+        # the simulation loop hands tile k - 1 over inside tile k's
+        # step, while tile k's program runs
+        assert up["tile"] - r["tile"] in (
+            (0, 1) if loop == "simulate" else (0,))
+    # one root a submit: no job is lost and none is named twice
+    submits = [r for r in phases if r["name"] == "submit"]
+    assert sorted(r["cause"] for r in roots) == sorted(
+        r["id"] for r in submits)
+    # the loop's io: the reader's span that produced the tile
+    ios = [r for r in phases if r["name"] == "io"]
+    assert ios and all(r["thread"] == main for r in ios)
+    for r in ios:
+        up = by[r["cause"]]
+        assert up["name"] == "read" and up["thread"] == "prefetch-read"
+        assert up["tile"] == r["tile"]
+        # the producer's record ended before the item was taken
+        assert up["tm"] <= r["tm"]
+    assert {r["id"] for r in caused} == {r["id"] for r in roots + ios}
+    # a tile the reader had ready lay queued, for no longer than from
+    # its producer's end to the entry of the io that took it
+    assert any(r["queued_s"] > 0 for r in ios)
+    for r in ios:
+        ready = (r["tm"] - r["dur_s"]) - by[r["cause"]]["tm"]
+        assert r["queued_s"] <= max(0.0, ready) + 1e-6
+
+    have = set(paths.values())
+    assert PARENT_PATHS[loop] <= have, PARENT_PATHS[loop] - have
+    assert NEW_PATHS[loop] <= have, NEW_PATHS[loop] - have
+    assert have <= PARENT_PATHS[loop] | NEW_PATHS[loop], (
+        have - PARENT_PATHS[loop] - NEW_PATHS[loop])
+    if loop == "calibrate":
+        progs = {r["prog"] for r in phases
+                 if paths[r["id"]] == "step/solve/dispatch"}
+        assert "coh" in progs and len(progs) > 1
+        assert {r["prog"] for r in phases
+                if paths[r["id"]] == "read/stage/dispatch"} == {"weights"}
+    if loop == "consensus":
+        import test_consensus_stepper as tcs
+        puts = [r for r in phases if paths[r["id"]] == "write/put"]
+        assert sorted({r["sub"] for r in puts}) == list(range(tcs.NF))
+        # the interval's record reads nothing back for a field nothing
+        # reads (PR 53)
+        tiles = [r for r in trace.read(str(tr)) if r["ev"] == "tile"]
+        assert tiles and not any("rho_mean" in r for r in tiles)
+
+
+@pytest.mark.parametrize("make, drive", LOOPS, ids=LOOP_IDS)
+def test_the_inline_loop_hands_nothing_over(tmp_path, request, make,
+                                            drive):
+    """``--prefetch 0``: no record carries ``cause`` or ``queued_s``,
+    every span is the loop thread's, and the job's spans are children
+    of the span that ran it inline (``step/submit/write``; the
+    simulation loop's ``step/write``)."""
+    loop = request.node.callspec.id
+    root = tmp_path / "obs"
+    make(root)
+    tr = tmp_path / "diag.jsonl"
+    drive(str(root), ["--diag", str(tr), "--prefetch", "0"])
+    phases = _phases(tr)
+    assert not any("cause" in r or "queued_s" in r for r in phases)
+    assert len({r["thread"] for r in phases}) == 1
+    have = set(_paths(phases).values())
+    write = "step/write" if loop == "simulate" else "step/submit/write"
+    assert {write, write + "/convert", write + "/put",
+            write + "/put/savez", "io/load"} <= have, have
+    if loop != "simulate":
+        assert "step/submit/solutions" in have
+
+
+@pytest.mark.parametrize("make, drive", LOOPS, ids=LOOP_IDS)
+def test_with_no_tracer_no_phase_is_made_and_nothing_is_handed_over(
+        tmp_path, monkeypatch, make, drive):
+    """Untraced and unprofiled: ``run_simulation``, ``TileStepper.step``
+    and ``ConsensusStepper.step`` construct no ``_Phase`` at all (every
+    site gets the shared null context), and the queued job's hand-over
+    is ``None``, the one test its worker pays."""
+    from sagecal_tpu import sched
+
+    def never(self, *a, **k):
+        raise AssertionError("a _Phase was made with nothing listening")
+    monkeypatch.setattr(trace._Phase, "__init__", never)
+    handed = []
+    real = trace.handed
+    monkeypatch.setattr(trace, "handed",
+                        lambda hand: handed.append(hand) or real(hand))
+    items = []
+    real_put = sched.Prefetcher._put
+
+    def put(self, item):
+        items.append(item)
+        return real_put(self, item)
+    monkeypatch.setattr(sched.Prefetcher, "_put", put)
+    root = tmp_path / "obs"
+    make(root)
+    drive(str(root), ["--prefetch", "1"])
+    assert handed and all(h is None for h in handed)
+    assert trace.handed(None) is trace._NULL_PHASE
+    assert items and all(len(it) == 4 and it[3] is None for it in items)
+
+
+def test_a_job_that_raises_still_closes_its_root(tmp_path):
+    """The writer's worker: a failing job's own root, and the ``job``
+    root of one that opened none, are recorded with their cause; the
+    failure still surfaces at ``close``."""
+    from sagecal_tpu import sched
+
+    def spanned():
+        with trace.phase("write", tile=9, bg=True):
+            with trace.phase("put"):
+                raise OSError("disk gone")
+
+    def bare():
+        raise ValueError("no span here")
+
+    for k, job in enumerate((spanned, bare)):
+        path = tmp_path / f"w{k}.jsonl"
+        trace.enable(str(path))
+        aw = sched.AsyncWriter(enabled=True)
+        with trace.phase("step", tile=4):
+            aw.submit(job)
+        with pytest.raises((OSError, ValueError)):
+            aw.close()
+        trace.disable()
+        by = {r["name"]: r for r in _phases(path)}
+        root = by["job" if job is bare else "write"]
+        assert root["cause"] == by["submit"]["id"]
+        assert root["queued_s"] >= 0 and root["parent"] is None
+        assert root["thread"] == "async-writer"
+        # its own tile where it has one, else the submit's
+        assert root["tile"] == (4 if job is bare else 9)
+        assert ("job" in by) == (job is bare)
+        if job is spanned:
+            assert by["put"]["parent"] == root["id"]
+
+
+def test_queued_s_is_how_long_the_item_lay_ready(tmp_path):
+    """The Prefetcher's own case: a consumer slower than the producer
+    finds every later item ready (``queued_s`` about its own delay), one
+    faster than the producer waits and finds none (0)."""
+    from sagecal_tpu import sched
+
+    def slow(i):
+        time.sleep(0.03)
+        return i
+
+    for name, produce, delay in (("ahead", lambda i: i, 0.03),
+                                 ("behind", slow, 0.0)):
+        path = tmp_path / f"{name}.jsonl"
+        trace.enable(str(path))
+        try:
+            for _i, _x, _w in sched.Prefetcher(produce, 4, depth=1):
+                time.sleep(delay)
+        finally:
+            trace.disable()
+        phases = _phases(path)
+        by = {r["id"]: r for r in phases}
+        ios = sorted((r for r in phases if r["name"] == "io"),
+                     key=lambda r: r["tile"])
+        assert [by[r["cause"]]["tile"] for r in ios] == [0, 1, 2, 3]
+        assert all(by[r["cause"]]["thread"] == "prefetch-read"
+                   for r in ios)
+        if name == "ahead":
+            assert all(r["queued_s"] > 0.005 for r in ios[1:])
+        else:
+            assert all(r["queued_s"] < 0.02 for r in ios)
+            assert sum(r["queued_s"] == 0.0 for r in ios) >= 3
+
+
+def test_casams_write_tile_says_keep_and_putcol(tmp_path):
+    """The casacore backend through the suite's in-memory fake: what
+    the rows hold is read under ``keep`` and written under ``putcol``."""
+    import test_casams as tc
+
+    ct, ref = tc.build_fake_ms()
+    ms = tc.open_ms(ct, ref["tilesz"])
+    tile = ms.read_tile(1)
+    path = tmp_path / "casa.jsonl"
+    trace.enable(str(path))
+    with trace.phase("put", tile=1):
+        ms.write_tile(1, tile)
+    trace.disable()
+    assert sorted(_paths(_phases(path)).values()) == [
+        "put", "put/keep", "put/putcol"]
 
 
 # ---------------------------------------------------------------------------

@@ -23,6 +23,9 @@ CELLS = "tests/rehearsal/hybrid-planes-cells.json"
 HYB = ["assemble_dev_s.hyb", "bubble_ms.hyb", "chunk_slots_idle_pct.hyb",
        "flat_row_passes.hyb", "refine_dev_s.hyb", "residual_ms.hyb",
        "solve_s.hyb", "sweep_dev_s.hyb"]
+#: PR 53: the accepted readers of ``host_serial_ms`` and ``chip_wait_ms``
+#: under this cell's name, so it has a ``[host]`` and a ``[wait]`` table
+TWINS = ["chip_wait_ms.hyb", "host_serial_ms.hyb"]
 
 
 def test_tiny_hybrid_cell_runs_on_planes():
@@ -45,8 +48,10 @@ def test_tiny_hybrid_cell_runs_on_planes():
     assert line["correct"] is True and line["failed"] == 0, line
     assert line["device"]["platform"] == "cpu"
     got = line["metrics"]
-    assert sorted(n for n in got if n.endswith(".hyb")) == HYB
-    assert all(got[n]["value"] is not None for n in HYB)
+    assert sorted(n for n in got if n.endswith(".hyb")) == sorted(
+        HYB + TWINS)
+    assert all(got[n]["value"] is not None for n in HYB + TWINS)
+    assert "[host] step/solve/dispatch" in out and "[wait] rows add up" in out
     # 4 clusters x kmax 5, 5 + 3 + 1 + 1 live
     assert got["chunk_slots_idle_pct.hyb"]["value"] == pytest.approx(50.0)
     assert got["flat_row_passes.hyb"]["value"] == 0
